@@ -6,19 +6,6 @@ Reference: ArulselvanMadhavan/FlexFlow (studied at /root/reference);
 see SURVEY.md for the full capability map.
 """
 
-import jax as _jax
-
-# Sharding-invariant RNG. On jax 0.4.x `jax_threefry_partitionable`
-# defaults to False, which makes jax.random values under GSPMD depend on
-# the OUTPUT SHARDING of the jitted computation that draws them: the
-# same init key produced different row-parallel weights under TP=2 than
-# on one device (tests/test_parallel.py::
-# test_ffmodel_tp_loss_matches_single_device — the whole
-# layout-equivalence contract rests on init being layout-invariant).
-# Newer jax flipped the default to True; pin it here for every entry
-# point (tests, bench, CLI), not just the test harness.
-_jax.config.update("jax_threefry_partitionable", True)
-
 from .config import FFConfig, init, get_config
 from .core import (
     DataType,

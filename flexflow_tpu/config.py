@@ -10,6 +10,7 @@ process topology from ``jax.distributed``.
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Any, Dict, Optional
 
 import jax
@@ -152,3 +153,19 @@ def get_config() -> FFConfig:
     if _global_config is None:
         _global_config = FFConfig()
     return _global_config
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a process this
+    repo owns (``chip_smoke.py``, ``python -m flexflow_tpu``, bench
+    children) — never at package import, never from the tests. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already honours it and
+    nothing is set here; otherwise the cache lives at ONE fixed path
+    inside the checkout (the path is part of the cache key, so a
+    directory that moves never hits). Returns the directory in use."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(repo, ".jax_cache")
+        )
+    return jax.config.jax_compilation_cache_dir

@@ -29,10 +29,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax (0.4.x)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 # Canonical axis order; see module docstring.
 AXIS_ORDER = ("data", "expert", "pipe", "seq", "model")
@@ -115,58 +112,34 @@ class MachineSpec:
 
 
 def shard_map_unchecked(fn, mesh, in_specs, out_specs, manual_axes=None):
-    """``shard_map`` with the static replication checker OFF, across jax
-    versions — the ONE compat shim for every collective primitive in the
-    repo (ring/Ulysses attention, the pipeline stage loop, the ring
-    ragged paged attention serving kernel). New jax spells the knob
-    ``check_vma``; 0.4.x (this container) spells it ``check_rep``.
+    """``shard_map`` with the static replication checker OFF
+    (``check_vma=False``) — the one entry for every collective primitive
+    in the repo (ring/Ulysses attention, the pipeline stage loop, the
+    ring ragged paged attention serving kernel).
 
-    Why the checker is off: jax 0.4.37's replication checker mis-types
-    scan carries when these collectives run inside a layer scan over a
-    mesh with unrelated (expert/pipe) axes — the carry enters untyped
-    (None) and leaves typed replicated-over-the-unused-axes, which the
-    scan fixpoint rejects. Every caller is an exact layout transform
-    tested against a dense reference, so disabling the *static* check
-    is sound (the math, not the checker, is the contract).
+    Why the checker is off: these collectives run inside a layer scan
+    over a mesh with unrelated (expert/pipe) axes, where the carry
+    enters untyped and leaves typed replicated-over-the-unused-axes,
+    which the scan fixpoint rejects. Every caller is an exact layout
+    transform tested against a dense reference, so disabling the
+    *static* check is sound (the math, not the checker, is the
+    contract).
 
-    ``manual_axes`` selects the partial-manual mode (only those axes
-    run manually; the rest stay under GSPMD): new jax names the MANUAL
-    set (``axis_names``), 0.4.x names the complement (``auto``).
+    ``manual_axes`` selects the partial-manual mode: only those axes
+    run manually (``axis_names``); the rest stay under GSPMD.
     """
+    kw = {}
     if manual_axes is not None:
-        try:
-            return shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                axis_names=frozenset(manual_axes), check_vma=False,
-            )
-        except TypeError:
-            auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-            return shard_map(
-                fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=False, auto=auto,
-            )
-    try:
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+        kw["axis_names"] = frozenset(manual_axes)
+    return shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False, **kw,
+    )
 
 
 def set_mesh(mesh: Mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where it exists (JAX >= 0.6); on older releases
-    (this container ships 0.4.x, where the attribute is missing and
-    every call site died with AttributeError) the ``Mesh`` object's own
-    context manager provides the same ambient-mesh scoping the call
-    sites need."""
-    fn = getattr(jax, "set_mesh", None)
-    return fn(mesh) if fn is not None else mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def single_device_spec() -> MachineSpec:

@@ -29,6 +29,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core.mesh import DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS
+from .transformer import seeded_normal
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,7 +136,7 @@ def init_params(key, cfg: LLaMAConfig) -> Dict[str, Any]:
     ks = jax.random.split(key, 8)
 
     def norm_init(std, k, shape):
-        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dt)
+        return seeded_normal(k, std, shape=shape, dtype=dt)
 
     std = 0.02
     params = {

@@ -225,6 +225,24 @@ def alibi_slopes(num_heads: int) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Parameters
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _seeded_normal(key, scale, floor, *, shape, dtype):
+    draw = jnp.maximum(jax.random.normal(key, shape, jnp.float32), floor)
+    return (draw * scale).astype(dtype)
+
+
+def seeded_normal(key, scale, *, shape, dtype):
+    """``normal(key) * scale`` cast to ``dtype`` as ONE program: the
+    float32 draw fuses into the cast and is never materialised, so a
+    stacked bf16 weight at 7B widths costs its own bytes and not a
+    float32 copy twice its size beside it. Values are bit-for-bit those
+    of the eager draw-then-scale-then-cast: ``floor`` is -inf at run
+    time — an identity the compiler cannot see through, which keeps it
+    from merging ``scale`` into the draw's own sqrt(2) factor (one
+    rounding where the eager chain has two)."""
+    return _seeded_normal(key, scale, -math.inf, shape=shape, dtype=dtype)
+
+
 def init_params(key, cfg: DecoderConfig) -> Dict[str, Any]:
     L, D, F = cfg.num_hidden_layers, cfg.hidden_size, cfg.intermediate_size
     H, KV, dk = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -233,7 +251,7 @@ def init_params(key, cfg: DecoderConfig) -> Dict[str, Any]:
     std = 0.02
 
     def w(k, shape, scale=std):
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dt)
+        return seeded_normal(k, scale, shape=shape, dtype=dt)
 
     ones = lambda shape: jnp.ones(shape, dt)
     zeros = lambda shape: jnp.zeros(shape, dt)

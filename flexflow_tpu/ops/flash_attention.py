@@ -34,7 +34,28 @@ LANES = 128
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is what the CPU backend gets (tests on the virtual
+    mesh); on a TPU Mosaic compiles the kernel. Any other backend has
+    no Pallas path here — an error, not a silent interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default JAX backend is {backend!r}"
+    )
+
+
+def _block_positions(block, block_len, shape, axis):
+    """Absolute positions of block ``block``'s lines along ``axis``, as an
+    int32 iota already in ``shape``. In-bounds masks are compared in the
+    shape they are used in: Mosaic cannot reshape an i1 vector
+    ("infer-vector-layout: unsupported shape cast")."""
+    return block * block_len + jax.lax.broadcasted_iota(
+        jnp.int32, shape, axis
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +91,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         v = v_ref[0].astype(jnp.float32)
         # zero padded K/V rows — 0·exp(NEG_INF)=0 still, but NaN padding
         # from out-of-bounds block reads would poison the products
-        kvalid = (kpos < total_k).reshape(block_k, 1)
+        kvalid = _block_positions(j, block_k, (block_k, 1), 0) < total_k
         k = jnp.where(kvalid, k, 0.0)
         v = jnp.where(kvalid, v, 0.0)
         s = jax.lax.dot_general(
@@ -95,11 +116,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     def _():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
         o_ref[0] = (o_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:, :1] + jnp.log(l)).reshape(block_q)
+        lse_ref[0] = m_scr[:, :1] + jnp.log(l)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    """q (N, S, dk), k/v (N, T, dk) → (out (N, S, dk), lse (N, S))."""
+    """q (N, S, dk), k/v (N, T, dk) → (out (N, S, dk), lse (N, S, 1)).
+    The per-row statistics (lse here, delta in the backward) travel as
+    COLUMNS: a (1, bq) block of an (N, S) array is refused by the TPU
+    lowering (second-to-last block dim neither a multiple of 8 nor the
+    array's), and a (bq,) row would need a lane↔sublane relayout against
+    the (bq, bk) scores; a (1, bq, 1) block is what the body uses."""
     N, S, dk = q.shape
     T = k.shape[1]
     bq, bk = min(block_q, S), min(block_k, T)
@@ -111,7 +137,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
         ),
         out_shape=(
             jax.ShapeDtypeStruct((N, S, dk), q.dtype),
-            jax.ShapeDtypeStruct((N, S), jnp.float32),
+            jax.ShapeDtypeStruct((N, S, 1), jnp.float32),
         ),
         grid_spec=pl.GridSpec(
             grid=grid,
@@ -122,7 +148,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
             ],
             out_specs=(
                 pl.BlockSpec((1, bq, dk), lambda n, i, j: (n, i, 0)),
-                pl.BlockSpec((1, bq), lambda n, i, j: (n, i)),
+                pl.BlockSpec((1, bq, 1), lambda n, i, j: (n, i, 0)),
             ),
             scratch_shapes=[
                 pltpu.VMEM((bq, dk), jnp.float32),
@@ -169,8 +195,8 @@ def _bwd_kv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # out-of-bounds block rows read unspecified values: 0·NaN from a
         # padded lse/delta would poison ds even where p is masked to 0
         qvalid = qpos < total_q                     # (bq, 1)
-        lse = jnp.where(qvalid, lse_ref[0].reshape(block_q, 1), 0.0)
-        delta = jnp.where(qvalid, delta_ref[0].reshape(block_q, 1), 0.0)
+        lse = jnp.where(qvalid, lse_ref[0], 0.0)
+        delta = jnp.where(qvalid, delta_ref[0], 0.0)
         do = jnp.where(qvalid, do, 0.0)
         q = jnp.where(qvalid, q, 0.0)  # ds.T @ q contracts the q rows
         s = jax.lax.dot_general(
@@ -227,9 +253,9 @@ def _bwd_q_kernel(q_ref, k_ref, do_ref, lse_ref, delta_ref, v_ref,
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
         qvalid = qpos < total_q
-        kvalid = (kpos < total_k).reshape(block_k, 1)
-        lse = jnp.where(qvalid, lse_ref[0].reshape(block_q, 1), 0.0)
-        delta = jnp.where(qvalid, delta_ref[0].reshape(block_q, 1), 0.0)
+        kvalid = _block_positions(j, block_k, (block_k, 1), 0) < total_k
+        lse = jnp.where(qvalid, lse_ref[0], 0.0)
+        delta = jnp.where(qvalid, delta_ref[0], 0.0)
         do = jnp.where(qvalid, do, 0.0)
         k = jnp.where(kvalid, k, 0.0)  # ds @ k contracts the kv rows
         v = jnp.where(kvalid, v, 0.0)  # do @ v.T feeds ds at padded cols
@@ -260,8 +286,9 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k):
     T = k.shape[1]
     bq, bk = min(block_q, S), min(block_k, T)
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # (N, S)
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+        keepdims=True,
+    )  # (N, S, 1)
 
     dkv = pl.pallas_call(
         functools.partial(
@@ -279,8 +306,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k):
                 pl.BlockSpec((1, bk, dk), lambda n, j, i: (n, j, 0)),
                 pl.BlockSpec((1, bk, dk), lambda n, j, i: (n, j, 0)),
                 pl.BlockSpec((1, bq, dk), lambda n, j, i: (n, i, 0)),
-                pl.BlockSpec((1, bq), lambda n, j, i: (n, i)),
-                pl.BlockSpec((1, bq), lambda n, j, i: (n, i)),
+                pl.BlockSpec((1, bq, 1), lambda n, j, i: (n, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda n, j, i: (n, i, 0)),
             ],
             out_specs=(
                 pl.BlockSpec((1, bk, dk), lambda n, j, i: (n, j, 0)),
@@ -306,8 +333,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k):
                 pl.BlockSpec((1, bq, dk), lambda n, i, j: (n, i, 0)),
                 pl.BlockSpec((1, bk, dk), lambda n, i, j: (n, j, 0)),
                 pl.BlockSpec((1, bq, dk), lambda n, i, j: (n, i, 0)),
-                pl.BlockSpec((1, bq), lambda n, i, j: (n, i)),
-                pl.BlockSpec((1, bq), lambda n, i, j: (n, i)),
+                pl.BlockSpec((1, bq, 1), lambda n, i, j: (n, i, 0)),
+                pl.BlockSpec((1, bq, 1), lambda n, i, j: (n, i, 0)),
                 pl.BlockSpec((1, bk, dk), lambda n, i, j: (n, j, 0)),
             ],
             out_specs=pl.BlockSpec((1, bq, dk), lambda n, i, j: (n, i, 0)),

@@ -39,10 +39,9 @@ from ..core.mesh import (
     shard_map_unchecked as _shard_map_unchecked,
 )
 
-# The check_rep/check_vma compat shim previously copy-pasted here and in
-# parallel/pipeline.py lives in core.mesh.shard_map_unchecked now — ONE
-# shim for every collective primitive (see its docstring for why the
-# static replication checker is off on jax 0.4.x).
+# core.mesh.shard_map_unchecked is the one shard_map entry for every
+# collective primitive (see its docstring for why the static replication
+# checker is off).
 
 
 def _online_block(q, k, v, o, m, l, qpos, kpos, scale, causal, kv_len=None):

@@ -98,6 +98,19 @@ def _wire_session(session_id: Optional[object]):
     return str(session_id)
 
 
+_THIS_HOST = ("", "127.0.0.1", "localhost", "::1")
+
+
+def _holds_tpu() -> bool:
+    """True once this process has initialised a TPU backend (and so
+    holds the chip) — asked without initialising one."""
+    import jax
+    from jax._src import xla_bridge  # no public "is a backend up?" query
+
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu")
+
+
 def _build_member(serving, ctx, index: int, role: str,
                   endpoint: Optional[str] = None):
     """One replica (or standby) behind the configured transport —
@@ -117,6 +130,17 @@ def _build_member(serving, ctx, index: int, role: str,
                 )
             ep = serving.replica_endpoints[index]
         host, _, port = ep.rpartition(":")
+        if host in _THIS_HOST and _holds_tpu():
+            raise RuntimeError(
+                f"socket replica {index} at {ep} is a process on this "
+                "host, and this process has already initialised the TPU "
+                "backend: a chip belongs to one process at a time, so "
+                "that replica server can never get one (it would fail "
+                "or hang at start-up). Drive local replicas in-process "
+                "(replica_transport='inproc' or 'loopback', one device "
+                "each), or start the manager in a process that does "
+                "not touch the TPU."
+            )
         return RemoteReplica(
             index, SocketTransport(host or "127.0.0.1", int(port)),
             serving, role=role,

@@ -808,6 +808,7 @@ class InferenceEngine:
                     f"unknown fused_decode entry {name!r} (expected "
                     "'rope_kv_write', 'sampling' and/or 'whole_step')"
                 )
+        self._refuse_uncompilable()
         # Whole-step megakernel (serve/kernels.whole_step_decode):
         # capability-gated at construction. The VMEM gate below picks a
         # sub-block tile count per step shape (1 = untiled walk);
@@ -945,6 +946,27 @@ class InferenceEngine:
         self.cache = self._alloc_cache()
         if self.whole_step_on:
             self._whole_step_vmem_gate()
+
+    def _refuse_uncompilable(self) -> None:
+        """On a TPU, an option whose Pallas kernel the chip's compiler
+        refuses (serve/kernels.TPU_REFUSED) is an error naming the
+        option and the compiler's message — never interpret mode, never
+        a quiet switch to another path. The CPU backend runs these in
+        interpret mode, which is what the tests cover."""
+        if (
+            self.mesh.devices.flat[0].platform != "tpu"
+            or "whole_step" not in self.serving.fused_decode
+        ):
+            return
+        from .kernels import TPU_REFUSED
+
+        option = "fused_decode='whole_step'"
+        raise NotImplementedError(
+            f"{option} is not compiled for the TPU: the compiler "
+            f"refuses its kernel — {TPU_REFUSED[option]}. It has "
+            "only run in interpret mode on the CPU; drop the option "
+            "(the per-layer kernels='pallas' path compiles and runs)."
+        )
 
     @staticmethod
     def _whole_step_vmem_budget() -> int:
@@ -1231,6 +1253,24 @@ class InferenceEngine:
         if self.retrace_guard is not None:
             fn = self.retrace_guard.instrument(fn, key=key)
         return jax.jit(fn, donate_argnums=donate_argnums)
+
+    def _carry(self, last_tokens):
+        """The sampled-token carry as a step's own output would present
+        it. Sharding-in-types puts the array's mesh into its abstract
+        type, so a host-built first carry (single-device sharding, empty
+        mesh) and a previous step's output (NamedSharding on
+        ``self.mesh``) are two tracing-cache keys — every pipelined step
+        program would trace and compile twice. Place the host-built one
+        on the mesh, replicated, so the first dispatch and the steady
+        state present one type."""
+        sh = getattr(last_tokens, "sharding", None)
+        if isinstance(sh, NamedSharding) and sh.mesh == self.mesh:
+            return last_tokens
+        # ffcheck: disable=FF107 -- host→device placement of the FIRST carry only (R int32s, asynchronous, nothing is fetched); every later dispatch returns above with the previous step's output
+        return jax.device_put(
+            jnp.asarray(last_tokens, dtype=jnp.int32),
+            NamedSharding(self.mesh, P()),
+        )
 
     def _poison_donated(self, donated: Any, key: Any) -> None:
         """Donation-sanitizer hook: after a donated dispatch the OLD
@@ -1528,7 +1568,7 @@ class InferenceEngine:
             out = step(
                 self.params,
                 self.cache,
-                last_tokens,
+                self._carry(last_tokens),
                 jnp.asarray(host_tokens, dtype=jnp.int32),
                 jnp.asarray(use_last, dtype=jnp.bool_),
                 jnp.asarray(positions, dtype=jnp.int32),
@@ -1576,7 +1616,7 @@ class InferenceEngine:
             out = step(
                 self.params,
                 self.cache,
-                last_tokens,
+                self._carry(last_tokens),
                 jnp.asarray(host_tokens, dtype=jnp.int32),
                 jnp.asarray(use_last, dtype=jnp.bool_),
                 jnp.asarray(positions, dtype=jnp.int32),

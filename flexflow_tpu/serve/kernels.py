@@ -81,8 +81,8 @@ page-locally (running amax, rescale-on-growth, offset-0 reset). The
 only unspecified bytes are the shared scratch page's, which both paths
 write with padding garbage and neither ever reads.
 
-On non-TPU backends the Pallas kernels fall back to ``interpret=True``
-so tests run on the CPU mesh.
+On the CPU backend the Pallas kernels run with ``interpret=True`` so
+tests run on the CPU mesh; any backend other than tpu/cpu is an error.
 """
 from __future__ import annotations
 
@@ -95,11 +95,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..ops.flash_attention import _block_positions, _interpret
+
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Serving options whose kernel the TPU's compiler refuses today, with
+# the first line of what it says (each compiled for a described v5e at
+# Mistral-7B widths, PR 23; tests/test_chip_compile.py holds the kernels
+# that DO compile). These have only ever run in interpret mode on the
+# CPU. On a TPU the engine raises at construction when one is selected
+# (InferenceEngine._refuse_uncompilable): it neither drops to interpret
+# mode nor to another path. Repair the kernel, add its compile test,
+# then delete its line here.
+TPU_REFUSED = {
+    "fused_decode='whole_step'": (
+        "The Pallas TPU lowering currently requires that the last two "
+        "dimensions of your block shape are divisible by 8 and 128 "
+        "respectively, or be equal to the respective dimensions of the "
+        "overall array — the walk's per-layer (1, D) blocks of (L, D) "
+        "stacks and its weight sub-tiles narrower than 128 lanes"
+    ),
+}
 
 
 def _decode_kernel(
@@ -124,10 +141,10 @@ def _decode_kernel(
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    pos = s * block_s + jax.lax.broadcasted_iota(jnp.int32, (block_s,), 0)
-    valid = pos < seq_ref[r]
+    def valid(shape, axis):
+        return _block_positions(s, block_s, shape, axis) < seq_ref[r]
 
-    @pl.when(jnp.any(valid))
+    @pl.when(s * block_s < seq_ref[r])  # any line of this block valid
     def _():
         q = q_ref[0].astype(jnp.float32)                    # (KV, G, dk)
         # Mosaic batched matmul needs both batch dims leading: lay K/V
@@ -136,17 +153,18 @@ def _decode_kernel(
         v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
         # zero out-of-bounds/invalid rows: p is 0 there, but 0·NaN from
         # block padding would still poison the PV product
-        v = jnp.where(valid[None, :, None], v, 0.0)
+        v = jnp.where(valid(v.shape, 1), v, 0.0)
         # scores (KV, G, CS): batch over KV heads, contract dk
         scores = jax.lax.dot_general(
             q, k,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale
-        scores = jnp.where(valid[None, None, :], scores, NEG_INF)
+        ok = valid(scores.shape, 2)
+        scores = jnp.where(ok, scores, NEG_INF)
         m_new = jnp.maximum(m_scr[:], scores.max(axis=-1))
         p = jnp.exp(scores - m_new[..., None])
-        p = jnp.where(valid[None, None, :], p, 0.0)
+        p = jnp.where(ok, p, 0.0)
         corr = jnp.exp(m_scr[:] - m_new)
         l_scr[:] = l_scr[:] * corr + p.sum(axis=-1)
         pv = jax.lax.dot_general(
@@ -232,16 +250,18 @@ def _verify_kernel(
 
     # When S1 % block_s != 0 the mask block's tail is out-of-bounds
     # padding with unspecified contents on TPU — bound it explicitly.
-    pos = s * block_s + jax.lax.broadcasted_iota(jnp.int32, (block_s,), 0)
-    mask = mask_ref[0] & (pos < total_s)[None, :]  # (C, CS)
+    def inbounds(shape, axis):
+        return _block_positions(s, block_s, shape, axis) < total_s
+
+    mask = mask_ref[0]
+    mask = mask & inbounds(mask.shape, 1)  # (C, CS)
 
     @pl.when(jnp.any(mask))
     def _():
         q = q_ref[0].astype(jnp.float32)           # (C, KV, G, dk)
         k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (KV, CS, dk)
         v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        inb = (pos < total_s)
-        v = jnp.where(inb[None, :, None], v, 0.0)
+        v = jnp.where(inbounds(v.shape, 1), v, 0.0)
         C = q.shape[0]
         # (KV, C*G, dk) grouped layout so one batched dot serves all KV heads
         qkv = q.transpose(1, 0, 2, 3).reshape(q.shape[1], -1, q.shape[-1])
@@ -485,6 +505,18 @@ def _rope_rotate(x, cos, sin):
     return out.astype(x.dtype)
 
 
+def _lanes_to_major(x):
+    """(n,) → (n, 1, 1), exactly, without the lane→leading-dim reshape
+    Mosaic refuses ("unsupported shape cast"): broadcast the lane vector
+    down a new leading dim, keep the diagonal, sum the lanes back out —
+    each sum is one value plus zeros."""
+    n = x.shape[0]
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (n, 1, n), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (n, 1, n), 2))
+    return jnp.sum(jnp.where(diag, x[None, None, :], 0.0), axis=-1,
+                   keepdims=True)
+
+
 def _build_ragged_paged_kernel(
     *,
     quant: bool,
@@ -510,9 +542,9 @@ def _build_ragged_paged_kernel(
     hand-maintained copies."""
 
     def _attend(q, k, v, ks, vs, mask, o_scr, m_scr, l_scr):
-        # q (C, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV,) f32
-        # (quant only); one batched dot per KV head over the grouped
-        # (KV, C*G, dk) query layout
+        # q (C, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV, 1, 1)
+        # f32 (quant only); one batched dot per KV head over the
+        # grouped (KV, C*G, dk) query layout
         KV, G = q.shape[1], q.shape[2]
         qkv = q.transpose(1, 0, 2, 3).reshape(KV, C * G, q.shape[-1])
         scores = jax.lax.dot_general(
@@ -521,7 +553,7 @@ def _build_ragged_paged_kernel(
             preferred_element_type=jnp.float32,
         )                                           # (KV, C*G, ps)
         if quant:
-            scores = scores * (ks[:, None, None] * scale)  # dequant K
+            scores = scores * (ks * scale)          # dequant K
         else:
             scores = scores * scale
         scores = scores.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
@@ -538,7 +570,7 @@ def _build_ragged_paged_kernel(
             preferred_element_type=jnp.float32,
         )  # (KV, C*G, dk)
         if quant:
-            pv = pv * vs[:, None, None]             # dequant V
+            pv = pv * vs                            # dequant V
         pv = pv.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
         o_scr[:] = o_scr[:] * corr[..., None] + pv
         m_scr[:] = m_new
@@ -569,7 +601,10 @@ def _build_ragged_paged_kernel(
         post-write scale row."""
         vf = lines.astype(jnp.float32)                 # (C, KV, dk)
         amax = jnp.max(jnp.abs(vf), axis=-1)           # (C, KV)
-        page_amax = jnp.where(belongs[:, None], amax, 0.0).max(axis=0)
+        # int32 flags, compared after the reshape: Mosaic cannot reshape
+        # an i1 vector
+        bvec = jnp.stack([b.astype(jnp.int32) for b in belongs])
+        page_amax = jnp.where(bvec[:, None] != 0, amax, 0.0).max(axis=0)
         first = belongs[0] & (offs[0] == 0)
         for c in range(1, C):
             first = first | (belongs[c] & (offs[c] == 0))
@@ -595,7 +630,7 @@ def _build_ragged_paged_kernel(
         k_ref = refs[i]; i += 1         # (1, ps, KV, dk) via index map
         v_ref = refs[i]; i += 1
         if quant:
-            ks_ref = refs[i]; i += 1    # (1, KV) f32 page scales
+            ks_ref = refs[i]; i += 1    # (1, KV, 1, 1) f32 page scales
             vs_ref = refs[i]; i += 1
         mask_ref = refs[i]; i += 1      # (1, C, ps)
         out_ref = refs[i]; i += 1       # (1, C, KV, G, dk)
@@ -636,14 +671,14 @@ def _build_ragged_paged_kernel(
         k_ref = refs[i]; i += 1         # (1, ps, KV, dk) page block
         v_ref = refs[i]; i += 1
         if quant:
-            ks_ref = refs[i]; i += 1    # (1, KV) f32
+            ks_ref = refs[i]; i += 1    # (1, 1, KV) f32
             vs_ref = refs[i]; i += 1
         mask_ref = refs[i]; i += 1      # (1, C, ps)
         out_ref = refs[i]; i += 1       # (1, C, KV, G, dk)
         k_out = refs[i]; i += 1         # (1, ps, KV, dk) aliased pool
         v_out = refs[i]; i += 1
         if quant:
-            ks_out = refs[i]; i += 1    # (1, KV) aliased scale row
+            ks_out = refs[i]; i += 1    # (1, 1, KV) aliased scale row
             vs_out = refs[i]; i += 1
         o_scr, m_scr, l_scr = refs[i:i + 3]
         q_scr = refs[i + 3]             # (C, KV, G, dk) roped q, q dtype
@@ -681,13 +716,16 @@ def _build_ragged_paged_kernel(
         belongs = [lg_ref[r, c] == p for c in range(C)]
         offs = [off_ref[r, c] for c in range(C)]
         if quant:
-            bvec = jnp.stack(belongs)
-            ks_new = _quant_commit(k_out, ks_ref[0], k_scr[:], bvec, offs)
-            vs_new = _quant_commit(v_out, vs_ref[0], vn_ref[0], bvec, offs)
-            ks_out[0] = ks_new
-            vs_out[0] = vs_new
+            ks_new = _quant_commit(k_out, ks_ref[0, 0], k_scr[:], belongs,
+                                   offs)
+            vs_new = _quant_commit(v_out, vs_ref[0, 0], vn_ref[0], belongs,
+                                   offs)
+            ks_out[0, 0] = ks_new
+            vs_out[0, 0] = vs_new
+            ks_att = _lanes_to_major(ks_new)
+            vs_att = _lanes_to_major(vs_new)
         else:
-            ks_new = vs_new = None
+            ks_att = vs_att = None
             for c in range(C):
                 @pl.when(belongs[c])
                 def _(c=c):
@@ -703,14 +741,107 @@ def _build_ragged_paged_kernel(
             # block — the fresh K/V never left VMEM
             k = _unpack_codes(k_out[0], pack).transpose(1, 0, 2)
             v = _unpack_codes(v_out[0], pack).transpose(1, 0, 2)
-            _attend(q, k, v, ks_new, vs_new, mask, o_scr, m_scr, l_scr)
+            _attend(q, k, v, ks_att, vs_att, mask, o_scr, m_scr, l_scr)
 
         _finalize(p, out_ref, o_scr, l_scr)
 
     return fused_kernel if fused else plain_kernel
 
 
+# Scoped VMEM: what one kernel instance may keep in fast memory. The
+# compiler's own default scope is 16 MiB on a v5e, and the ragged paged
+# kernel at the mixed step's C=128 needs 17 MiB there (refused with
+# "Scoped allocation ... exceeded scoped vmem limit" once the step
+# around it is 24 layers deep), so the kernel states its need. The
+# ceiling stays well inside the 128 MiB a v5e/v6e core has.
+_VMEM_SCOPE_DEFAULT = 16 << 20
+_VMEM_SCOPE_CEILING = 96 << 20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer: the last two dims pad to the dtype's
+    (sublane, 128-lane) tile; bools are held as int32."""
+    item = 4 if dtype == jnp.bool_ else jnp.dtype(dtype).itemsize
+    *lead, sub, lane = (1, 1) + tuple(shape)
+    tile = 8 * max(1, 4 // item)
+    return (math.prod(lead) * (-(-sub // tile) * tile)
+            * (-(-lane // 128) * 128) * item)
+
+
+def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
+    """(P+1, KV) per-page scales as (P+1, 1, KV): a one-page block of the
+    2-D array is (1, KV), whose second-to-last dim the TPU lowering
+    refuses (neither a multiple of 8 nor the array's own); the same
+    block of the 3-D view ends in the array's own (1, KV)."""
+    return scale.astype(jnp.float32)[:, None, :]
+
+
+def _ragged_vmem_limit(specs, arrays, scratch, C, H, width) -> int:
+    """``vmem_limit_bytes`` of a ragged paged kernel: its blocks double-
+    buffered, its scratch, and the attention body's float32
+    intermediates — each one (C, H, max(dk, ps)) tile, about a dozen
+    live at the widest point (q and its grouped transpose, the scores,
+    their masked/exponentiated/transposed forms, pv)."""
+    need = 2 * sum(
+        _vmem_bytes(spec.block_shape, a.dtype)
+        for spec, a in zip(specs, arrays)
+    )
+    need += sum(_vmem_bytes(s.shape, s.dtype) for s in scratch)
+    need += 12 * _vmem_bytes((C * H, width), jnp.float32)
+    if need > _VMEM_SCOPE_CEILING:
+        raise ValueError(
+            f"ragged paged attention at C={C} rows x H={H} heads needs "
+            f"{need >> 20} MiB of VMEM per grid step (ceiling "
+            f"{_VMEM_SCOPE_CEILING >> 20} MiB) — lower prefill_chunk / "
+            "max_tokens_per_step"
+        )
+    return max(need, _VMEM_SCOPE_DEFAULT)
+
+
 def ragged_paged_attention(
+    q: jnp.ndarray,           # (R, C, H, dk)
+    k_pool: jnp.ndarray,      # (P+1, ps, KV, dk)
+    v_pool: jnp.ndarray,      # (P+1, ps, KV, dk)
+    page_table: jnp.ndarray,  # (R, NP) int32
+    mask: jnp.ndarray,        # (R, C, NP*ps) bool
+    *,
+    scale: Optional[float] = None,
+    k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
+    v_scale: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """:func:`_ragged_paged_attention` placed on the ambient mesh. The
+    compiler cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), and heads are independent, so on a mesh whose
+    ``model`` degree divides the KV heads — the tensor-parallel serving
+    layout, Q heads and pool KV heads sharded alike — each shard runs
+    the kernel on its own heads under a shard_map over that axis."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..core.mesh import MODEL_AXIS, shard_map_unchecked
+
+    def body(q, k_pool, v_pool, page_table, mask, k_scale=None,
+             v_scale=None):
+        return _ragged_paged_attention(
+            q, k_pool, v_pool, page_table, mask,
+            scale=scale, k_scale=k_scale, v_scale=v_scale,
+        )
+
+    heads = P(None, None, MODEL_AXIS, None)
+    operands = [q, k_pool, v_pool, page_table, mask]
+    in_specs = [heads, heads, heads, P(), P()]
+    if k_scale is not None:
+        operands += [k_scale, v_scale]
+        in_specs += [P(None, MODEL_AXIS), P(None, MODEL_AXIS)]
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = 1 if mesh.empty else mesh.shape.get(MODEL_AXIS, 1)
+    if tp == 1 or k_pool.shape[2] % tp:
+        return body(*operands)
+    # every mesh axis manual (Mosaic refuses a partial-manual context);
+    # the specs name only ``model``, so the others see replicas
+    return shard_map_unchecked(body, None, tuple(in_specs), heads)(*operands)
+
+
+def _ragged_paged_attention(
     q: jnp.ndarray,           # (R, C, H, dk)
     k_pool: jnp.ndarray,      # (P+1, ps, KV, dk)
     v_pool: jnp.ndarray,      # (P+1, ps, KV, dk)
@@ -755,31 +886,45 @@ def ragged_paged_attention(
         quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack
     )
     if k_scale is not None:
-        in_specs += [
-            pl.BlockSpec((1, KV), lambda r, p, pt: (pt[r, p], 0)),
-            pl.BlockSpec((1, KV), lambda r, p, pt: (pt[r, p], 0)),
-        ]
+        # per-page scales as (P+1, KV, 1, 1): the block hands the body a
+        # (KV, 1, 1) value that broadcasts over the (KV, C*G, ps) scores
+        # as it is — Mosaic has no relayout from a (1, KV) lane vector
+        # to that leading dim ("unsupported shape cast")
+        scale_spec = pl.BlockSpec(
+            (1, KV, 1, 1), lambda r, p, pt: (pt[r, p], 0, 0, 0)
+        )
+        in_specs += [scale_spec, scale_spec]
         operands += [
-            k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)
+            k_scale.astype(jnp.float32)[:, :, None, None],
+            v_scale.astype(jnp.float32)[:, :, None, None],
         ]
     in_specs.append(pl.BlockSpec((1, C, ps), lambda r, p, pt: (r, 0, p)))
     operands.append(mask)
+    out_shape = jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype)
+    out_spec = pl.BlockSpec(
+        (1, C, KV, G, dk), lambda r, p, pt: (r, 0, 0, 0, 0)
+    )
+    scratch = [
+        pltpu.VMEM((C, KV, G, dk), jnp.float32),
+        pltpu.VMEM((C, KV, G), jnp.float32),
+        pltpu.VMEM((C, KV, G), jnp.float32),
+    ]
 
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, C, KV, G, dk), lambda r, p, pt: (r, 0, 0, 0, 0)
+            out_specs=out_spec,
+            scratch_shapes=scratch,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ragged_vmem_limit(
+                in_specs + [out_spec], operands + [out_shape], scratch,
+                C, H, max(dk, ps),
             ),
-            scratch_shapes=[
-                pltpu.VMEM((C, KV, G, dk), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-            ],
         ),
         interpret=_interpret(),
     )(page_table.astype(jnp.int32), *operands)
@@ -1057,10 +1202,9 @@ def ring_ragged_paged_attention(
     fn = shard_map_unchecked(
         body, mesh, tuple(in_specs), out_specs, manual_axes={SEQ_AXIS}
     )
-    # partial-manual shard_map has no eager impl on jax 0.4.x — jit the
-    # call (a no-op inside the engine's already-jitted step programs,
-    # where this runs in production; standalone/test callers get the
-    # same compiled path)
+    # jit the call: a no-op inside the engine's already-jitted step
+    # programs, where this runs in production; standalone/test callers
+    # get the same compiled path
     return jax.jit(fn)(*operands)
 
 
@@ -1162,28 +1306,30 @@ def fused_rope_paged_attention(
                      lambda r, p, pt, lg, of: (pt[r, p], 0, 0, 0)),
     ]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, KV), lambda r, p, pt, lg, of: (pt[r, p], 0)),
-            pl.BlockSpec((1, KV), lambda r, p, pt, lg, of: (pt[r, p], 0)),
-        ]
-        operands += [
-            k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)
-        ]
+        scale_spec = pl.BlockSpec(
+            (1, 1, KV), lambda r, p, pt, lg, of: (pt[r, p], 0, 0)
+        )
+        in_specs += [scale_spec, scale_spec]
+        operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
         aliases[idx0 + 2] = 3
         aliases[idx0 + 3] = 4
         out_shapes += [
-            jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v_scale.shape, jnp.float32),
+            jax.ShapeDtypeStruct(operands[-2].shape, jnp.float32),
+            jax.ShapeDtypeStruct(operands[-1].shape, jnp.float32),
         ]
-        out_specs += [
-            pl.BlockSpec((1, KV), lambda r, p, pt, lg, of: (pt[r, p], 0)),
-            pl.BlockSpec((1, KV), lambda r, p, pt, lg, of: (pt[r, p], 0)),
-        ]
+        out_specs += [scale_spec, scale_spec]
     in_specs.append(
         pl.BlockSpec((1, C, ps), lambda r, p, pt, lg, of: (r, 0, p))
     )
     operands.append(mask)
 
+    scratch = [
+        pltpu.VMEM((C, KV, G, dk), jnp.float32),
+        pltpu.VMEM((C, KV, G), jnp.float32),
+        pltpu.VMEM((C, KV, G), jnp.float32),
+        pltpu.VMEM((C, KV, G, dk), q.dtype),     # roped q
+        pltpu.VMEM((C, KV, dk), k_new.dtype),    # roped k
+    ]
     outs = pl.pallas_call(
         kernel,
         out_shape=out_shapes,
@@ -1192,13 +1338,13 @@ def fused_rope_paged_attention(
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((C, KV, G, dk), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-                pltpu.VMEM((C, KV, G, dk), q.dtype),     # roped q
-                pltpu.VMEM((C, KV, dk), k_new.dtype),    # roped k
-            ],
+            scratch_shapes=scratch,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ragged_vmem_limit(
+                in_specs + out_specs, operands + out_shapes, scratch,
+                C, H, max(dk, ps),
+            ),
         ),
         input_output_aliases=aliases,
         interpret=_interpret(),
@@ -1206,7 +1352,8 @@ def fused_rope_paged_attention(
       off.astype(jnp.int32), *operands)
     if quant:
         out, k_pool, v_pool, ks, vs = outs
-        return out.reshape(R, C, H, dk), k_pool, v_pool, ks, vs
+        return (out.reshape(R, C, H, dk), k_pool, v_pool,
+                ks.reshape(k_scale.shape), vs.reshape(v_scale.shape))
     out, k_pool, v_pool = outs
     return out.reshape(R, C, H, dk), k_pool, v_pool, None, None
 

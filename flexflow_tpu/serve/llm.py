@@ -237,7 +237,7 @@ class LLM:
             pspecs = quant.quantize_pspecs(pspecs, params)
         memory_kind = None
         if offload:
-            if jax.devices()[0].platform == "tpu":
+            if self.mesh.devices.flat[0].platform == "tpu":
                 memory_kind = "pinned_host"
             else:
                 import warnings

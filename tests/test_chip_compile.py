@@ -1,0 +1,233 @@
+"""Chip-compiler tests: the serving main path's kernels and step program
+compiled for a TPU v5e that is DESCRIBED, not attached (the TPU compiler
+ships with the installation; nothing here executes).
+
+This is the one file that may describe the topology. The description
+loads the TPU library, which one process at a time may hold, so it
+happens inside a module-scoped fixture — never at import, never in a
+skipif/parametrize argument — and every compile runs in the test's own
+process. Shapes are Mistral-7B's published widths (models/mistral.py);
+only depth is cut. A compile that passes is not a chip run:
+``chip_smoke.py`` is the run.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from flexflow_tpu.models import mistral
+from flexflow_tpu.serve import kernels
+
+R, PAGE, PAGES_PER_SLOT = 16, 128, 16          # slots, tokens/page, NP
+NUM_PAGES = R * PAGES_PER_SLOT                 # worst-case pool
+CACHE_LEN = PAGE * PAGES_PER_SLOT              # 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip(one_chip, monkeypatch):
+    """Steer the kernels to Mosaic (the default backend here is the CPU,
+    whose branch is interpret mode) and keep the persistent compile
+    cache off: a described-device executable is written to it but can
+    never be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from flexflow_tpu.ops import flash_attention
+
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(flash_attention, "_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield functools.partial(_shape, sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _shape(shape, dtype, *, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sds):
+    return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+def _attention_args(sds, C, cfg, pool_dtype=jnp.bfloat16, dk_pool=None):
+    H, KV, dk = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                 cfg.head_dim)
+    pool = sds((NUM_PAGES + 1, PAGE, KV, dk_pool or dk), pool_dtype)
+    return (
+        sds((R, C, H, dk), jnp.bfloat16), pool, pool,
+        sds((R, PAGES_PER_SLOT), jnp.int32),
+        sds((R, C, CACHE_LEN), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_ragged_paged_attention_bf16_compiles(chip, C):
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
+    _, text = _compile(
+        kernels.ragged_paged_attention, *_attention_args(chip, C, cfg)
+    )
+    assert "tpu_custom_call" in text
+
+
+def _step_args(sds, cfg, C, kv_quant=None):
+    params = _on(
+        jax.eval_shape(
+            functools.partial(mistral.init_params, cfg=cfg),
+            jax.random.PRNGKey(0),
+        ),
+        sds,
+    )
+    cache = _on(
+        jax.eval_shape(
+            functools.partial(
+                mistral.init_paged_kv_cache, cfg, NUM_PAGES, PAGE,
+                jnp.bfloat16, kv_quant=kv_quant,
+            )
+        ),
+        sds,
+    )
+    return (
+        params, cache,
+        sds((R, C), jnp.int32), sds((R, C), jnp.int32), sds((R,), jnp.int32),
+        sds((R, PAGES_PER_SLOT), jnp.int32),
+    )
+
+
+def _step(cfg, **kw):
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return mistral.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=CACHE_LEN, **kw,
+        )
+
+    return step
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_mistral_paged_pallas_step_compiles(chip, C):
+    """The step program chip_smoke.py runs: published widths, 2 layers,
+    decode (C=1) and the mixed step (C=128)."""
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16, num_hidden_layers=2)
+    compiled, text = _compile(
+        _step(cfg, kernels="pallas"), *_step_args(chip, cfg, C)
+    )
+    assert "tpu_custom_call" in text
+    # weights + pool + temporaries of this cut fit one 16 GB chip
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+# --- kernels repaired in PR 23 (refused by the chip's compiler before) ---
+
+
+@pytest.mark.parametrize("C", [1, 128])
+@pytest.mark.parametrize("kv_quant, dk_pool", [("int8", 128), ("int4", 64)])
+def test_ragged_paged_attention_quantized_pool_compiles(
+    chip, C, kv_quant, dk_pool
+):
+    """Per-page scale blocks: (1, KV) of a (P+1, KV) array was refused at
+    lowering; the (P+1, 1, KV) view's block is legal."""
+    from flexflow_tpu.serve.kv_quant import resolve_spec
+
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
+    args = _attention_args(chip, C, cfg, resolve_spec(kv_quant).dtype, dk_pool)
+    scale = chip((NUM_PAGES + 1, cfg.num_key_value_heads), jnp.float32)
+
+    def fn(q, kp, vp, pt, mask, ks, vs):
+        return kernels.ragged_paged_attention(
+            q, kp, vp, pt, mask, k_scale=ks, v_scale=vs
+        )
+
+    _, text = _compile(fn, *args, scale, scale)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("C", [1, 128])
+@pytest.mark.parametrize("kv_quant", [None, "int8", "int4"])
+def test_mistral_fused_rope_step_compiles(chip, C, kv_quant):
+    """fused_decode=("rope_kv_write",): RoPE + the (quantizing) KV write
+    inside the ragged paged kernel. On a bf16 pool the C=128 mixed step
+    was refused for 17.1 MB of scoped VMEM until the kernel stated its
+    limit; on quantized pools the in-kernel commit reshaped an i1 vector
+    and moved the per-page scale from lanes to a leading dim, both
+    refused ("unsupported shape cast")."""
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16, num_hidden_layers=2)
+    kw = dict(kernels="pallas", fused_rope=True)
+    if kv_quant:
+        kw["kv_quant"] = kv_quant
+    _, text = _compile(_step(cfg, **kw), *_step_args(chip, cfg, C, kv_quant))
+    assert "tpu_custom_call" in text
+
+
+def _dense_args(sds, cfg, S1=2049):
+    H, KV, dk = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                 cfg.head_dim)
+    cache = sds((R, S1, KV, dk), jnp.bfloat16)
+    return H, dk, cache
+
+
+def test_dense_decode_attention_compiles(chip):
+    """kernels="pallas" on the dense layout: the in-bounds mask is built
+    from an integer iota in its final shape (the i1 reshape was refused)."""
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
+    H, dk, cache = _dense_args(chip, cfg)
+    _, text = _compile(
+        kernels.decode_attention,
+        chip((R, H, dk), jnp.bfloat16), cache, cache, chip((R,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_dense_verify_attention_compiles(chip):
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
+    H, dk, cache = _dense_args(chip, cfg)
+    C = 64  # max_spec_tree_tokens
+    _, text = _compile(
+        kernels.verify_attention,
+        chip((R, C, H, dk), jnp.bfloat16), cache, cache,
+        chip((R, C, cache.shape[1]), jnp.bool_),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_forward_and_backward_compile(chip):
+    """ops/flash_attention.py (training): the row statistics travel as
+    (N, S, 1) columns — (1, bq) blocks of an (N, S) array were refused."""
+    from flexflow_tpu.ops import flash_attention as fa
+
+    q = chip((1, 2048, 32, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert text.count("tpu_custom_call") == 3  # forward, dK/dV, dQ

@@ -24,14 +24,6 @@ def _free_port():
     return port
 
 
-@pytest.mark.skip(
-    reason="XLA:CPU cannot run cross-process collectives: the worker "
-    "dies in compile() with 'INVALID_ARGUMENT: Multiprocess computations "
-    "aren't implemented on the CPU backend' (jaxlib 0.4.37). The "
-    "single-process multi-device DP equivalence is covered by "
-    "test_llama.py::test_layout_equivalence[degrees0]; this test needs "
-    "TPU/GPU (or a CPU collectives plugin) to run."
-)
 def test_two_process_dp_matches_single_process():
     port = _free_port()
     procs = []

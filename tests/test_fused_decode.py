@@ -184,6 +184,20 @@ def test_step_fused_rope_parity_llama(tiny, kv_quant):
     assert bool(jnp.all(unf[0] == fus[0])), "logits diverge"
     for name in unf[1]:
         a, b = unf[1][name], fus[1][name]
+        if name == "k" and kv_quant is None:
+            # The committed K is RoPE's ``x*cos + rot*sin``. The unfused
+            # step compiles it in the step's XLA:CPU fusion, the fused
+            # one inside the interpreted kernel body, and under jax 0.9
+            # LLVM contracts the multiply-add into an FMA in one and not
+            # the other: one rounding where the other has two (about 1%
+            # of the values, exactly 1 ulp; with
+            # XLA_FLAGS=--xla_cpu_max_isa=AVX, which has no FMA, the
+            # bytes are identical). Rounding order only, so: 1 ulp.
+            np.testing.assert_array_max_ulp(
+                np.asarray(a[:, :scratch]), np.asarray(b[:, :scratch]),
+                maxulp=1,
+            )
+            continue
         assert bool(jnp.all(a[:, :scratch] == b[:, :scratch])), (
             f"cache[{name}] non-scratch bytes diverge"
         )

@@ -256,7 +256,7 @@ def test_churn_whole_step_zero_retraces(tiny):
     counts = guard.compile_counts()
     whole_keys = [k for k in counts if k[0] == "whole_step"]
     assert whole_keys, counts
-    assert {k[1] for k in whole_keys} == {"greedy", "topk"}, counts
+    assert {k[3] for k in whole_keys} == {"greedy", "topk"}, counts
     assert all(counts[k] == 1 for k in whole_keys), counts
 
     # the guard is a pure observer on the whole-step path too
