@@ -1,0 +1,61 @@
+"""Closed loop: ``clients`` callers, each sending its next request the
+moment its last one completes — callers that wait for a reply. A slow
+server therefore receives less load; the rate is an outcome.
+
+Lengths: every seed draws the same multiset, round by round, in another
+order (``harness/lengths.py``). Each client's FIRST request is cut to a seeded share of
+its lengths (the shares evenly spaced over the clients), so that the
+clients are out of phase from the first step and the window opens on a
+steady state after seconds, not after a round of full requests. Those
+first requests are warm-up and never samples.
+
+Samples: the full requests that COMPLETE inside the window. A request's
+due time is the moment its client saw the previous one complete.
+"""
+from benchmarks.harness import lengths
+from benchmarks.harness.loop import Sent
+
+ROUNDS = 64  # request lengths drawn per client; a window uses far fewer
+
+
+class Generator:
+    def __init__(self, traffic, rng, vocab, seconds):
+        self.warmup_s = float(traffic["warmup_s"])
+        self.vocab = vocab
+        n = int(traffic["clients"])
+        # round by round the clients hold one length from each of n
+        # strata, in a seeded order: whatever part of the rounds a
+        # window sees, it sees the same work under every seed
+        rounds = [list(zip(lengths.block(traffic["prompt_tokens"], n, r, rng),
+                           lengths.block(traffic["answer_tokens"], n, r, rng)))
+                  for r in range(ROUNDS)]
+        shares = lengths.unit_block(n, 0, rng)
+        self.queues = []
+        for c in range(n):
+            queue = [rounds[r][c] for r in range(ROUNDS)]
+            p, a = queue[0]
+            queue[0] = (max(8, round(p * shares[c])), max(2, round(a * shares[c])))
+            self.queues.append(queue)
+        # each client draws its own tokens, in its own order, whatever
+        # order the clients complete in
+        self.rngs = rng.spawn(n)
+        self.round = [0] * n
+        self.ready = []
+
+    def start(self, t0):
+        self.ready = [(c, t0) for c in range(len(self.queues))]
+
+    def due(self, now):
+        out = []
+        for c, since in self.ready:
+            i = self.round[c]
+            # past the drawn rounds, go round again without the cut first one
+            prompt, answer = self.queues[c][i and 1 + (i - 1) % (ROUNDS - 1)]
+            self.round[c] = i + 1
+            out.append(Sent(lengths.tokens(self.rngs[c], prompt, self.vocab),
+                            answer, due=since, judged=i > 0, client=c))
+        self.ready = []
+        return out
+
+    def completed(self, sent, now):
+        self.ready.append((sent.client, now))
