@@ -30,6 +30,7 @@ in the environment. Compile events are logged at
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..logging_utils import get_logger
@@ -94,8 +95,10 @@ class RetraceGuard:
         """Wrap a to-be-jitted function so each trace (= compile) is
         recorded under ``key`` before tracing proceeds. The wrapper
         preserves positional arguments, so ``donate_argnums`` indices
-        are unchanged."""
+        are unchanged, and keeps the function's name, which is the
+        program's name in HLO and in a profile (``jit_<name>``)."""
 
+        @functools.wraps(fn)
         def traced(*args, **kwargs):
             self.record(key, args, kwargs)
             return fn(*args, **kwargs)

@@ -13,8 +13,17 @@ timeline captures — rebuilt TPU-native over the serve stack:
   RequestManager, the engine's dispatch chokepoint, SpecInfer and the
   ClusterManager; RPC retries, heartbeat gaps and health transitions
   become events too. Disabled (the default, :data:`NULL_TRACER`) the
-  layer costs one attribute read per emission site — proven free in
-  tests.
+  layer costs one attribute read per event site — proven free in
+  tests. The six phases of a scheduler step (``tracer.STEP_SPANS``:
+  admit, reserve, build, dispatch, flush, flush_wait) go through the
+  one span primitive ``tracer.span(name)``, which is a
+  ``jax.profiler.TraceAnnotation("ff.<name>")`` on EITHER tracer: an
+  xprof capture of a serving process shows them beside the device's
+  operations with no set-up (the profiler session is the switch), and
+  an attached tracer also buffers them with a duration. The buffer's
+  wall stamps are ``time.perf_counter()``, the clock ``ProfileInfo``
+  uses, so a request's events and stamps compare; the profiler's
+  clock is the session's own and only annotations can be put on it.
 * :mod:`.export` — Chrome/Perfetto ``trace_event`` JSON (one lane per
   replica; a migrated request is ONE trace id hopping lanes) and a
   Prometheus text snapshot mechanically derived from

@@ -185,6 +185,9 @@ PROFILE_EXCLUDED = {
     "start_time": "flexflow_request_latency_seconds_sum",
     "finish_time": "flexflow_request_latency_seconds_sum",
     "first_token_time": "flexflow_request_ttft_seconds_sum",
+    "admit_time": "flexflow_request_queue_wait_seconds_sum",
+    "prefill_dispatched_time":
+        "flexflow_request_first_token_lag_seconds_sum",
     "tree_width": "per-request shape, no meaningful sum",
     "tree_depth": "per-request shape, no meaningful sum",
     "draft_flops_per_token": "per-request draft pricing, no meaningful sum",
@@ -323,6 +326,11 @@ def prometheus_text(
                 sum(p.latency_s for p in profiles))
         out.add("flexflow_request_ttft_seconds_sum", "counter",
                 sum(p.ttft_s for p in profiles))
+        # TTFT's three parts (ProfileInfo.admit_time /
+        # prefill_dispatched_time split it exactly)
+        for part in ("queue_wait", "prefill_dispatch", "first_token_lag"):
+            out.add(f"flexflow_request_{part}_seconds_sum", "counter",
+                    sum(getattr(p, part + "_s") for p in profiles))
         out.add(
             "flexflow_request_first_token_observed_total", "counter",
             sum(1 for p in profiles if p.first_token_time),

@@ -4,19 +4,35 @@ self-driving serving loop reads).
 
 Design constraints, in order:
 
-1. **Disabled mode must be free.** Every emission site in the serve
-   stack guards on ``tracer.enabled`` (a plain bool attribute read)
-   before building ANY argument, and the module-level
-   :data:`NULL_TRACER` never records — with tracing off, the scheduler
-   step loop does no observability work beyond that attribute check
-   (tests/test_observability.py proves it: zero obs-frame allocations,
-   identical dispatched-program counts).
-2. **Dual clock.** Every event carries BOTH a wall-clock stamp
-   (``time.perf_counter()``, what the Chrome/Perfetto export renders)
-   and a deterministic step stamp (the owner's scheduler / cluster
-   step counter, what tests assert on). Nothing in the trace pipeline
-   ever *decides* anything off wall time.
-3. **Wire-safe events.** An event is one flat dict of codec-safe
+1. **Disabled mode must be free.** Every EVENT site in the serve stack
+   guards on ``tracer.enabled`` (a plain bool attribute read) before
+   building ANY argument, and the module-level :data:`NULL_TRACER`
+   never records — with tracing off no event dict is built and no
+   buffer is touched (tests/test_observability.py proves it: zero
+   obs-frame allocations, identical dispatched-program counts).
+2. **One span primitive, on the profiler's clock.** ``tracer.span(name)``
+   is called UNGUARDED: on either tracer it is a
+   ``jax.profiler.TraceAnnotation("ff." + name)``, which the runtime
+   records only while a profiler session is open — the session is the
+   switch (``jax.profiler.start_trace``, xprof's capture, the
+   benchmark's ``--trace 1``), so a capture of a serving process shows
+   the spans with no set-up and one code path serves attached and
+   unattached managers. With no session an annotation is a fraction of
+   a microsecond (PERF.md has the chip host's reading). The scheduler
+   step is cut into the six :data:`STEP_SPANS`; a layer's self time is
+   its span less what its children cover.
+3. **Dual clock in the buffer.** Every buffered event carries BOTH a
+   wall-clock stamp and a deterministic step stamp (the owner's
+   scheduler / cluster step counter, what tests assert on). The wall
+   stamp ``t`` is ``time.perf_counter()`` — the clock ``ProfileInfo``'s
+   stamps use, so a request's events and its stamps compare. It is NOT
+   the profiler's clock and cannot be: an xplane's times are relative
+   to its session's start, which Python cannot read. A span therefore
+   lives in both places — as an annotation on the profiler's clock
+   (beside the device's operations) and, with a live tracer, as a
+   buffered event with a ``perf_counter`` duration. Nothing in the
+   trace pipeline ever *decides* anything off wall time.
+4. **Wire-safe events.** An event is one flat dict of codec-safe
    primitives (str/int/float/None — see serve/cluster/transport.py),
    so a remote replica's events ride the PR-12 RPC envelope unchanged
    and the client stitches one cross-host timeline
@@ -33,7 +49,32 @@ from __future__ import annotations
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["TraceBuffer", "Tracer", "NullTracer", "NULL_TRACER"]
+import jax
+
+__all__ = ["TraceBuffer", "Tracer", "NullTracer", "NULL_TRACER",
+           "STEP_SPANS"]
+
+#: The phases of one ``RequestManager.step`` (serve/request_manager.py).
+#: In a profile they read ``ff.step.admit`` … on the Python thread's
+#: line; ``step.flush_wait`` (the host waiting on the device for a
+#: finished step's tokens) nests inside ``step.flush``, and a flush
+#: forced by admission or page reservation nests inside that phase.
+STEP_SPANS = (
+    "step.admit",       # scheduler: slot grants, reclaiming flushes
+    "step.reserve",     # cache: page reservation for the step's lines
+    "step.build",       # scheduler: rows, BatchConfig, the key split
+    "step.dispatch",    # engine: device_puts + the jitted step's call
+    "step.flush",       # scheduler: the oldest step's host bookkeeping
+    "step.flush_wait",  # engine: the blocking fetch inside the flush
+)
+# profiler names, built once: a span site builds no string per call
+_ANNOTATION_NAMES = {name: "ff." + name for name in STEP_SPANS}
+
+
+def _annotation(name: str) -> jax.profiler.TraceAnnotation:
+    return jax.profiler.TraceAnnotation(
+        _ANNOTATION_NAMES.get(name) or "ff." + name
+    )
 
 
 def _zero() -> int:
@@ -43,11 +84,13 @@ def _zero() -> int:
 class NullTracer:
     """The disabled tracer: ``enabled`` is False and stays False.
 
-    Emission sites check ``tracer.enabled`` BEFORE building event
+    Event sites check ``tracer.enabled`` BEFORE building event
     arguments, so on the hot path a disabled run costs one attribute
-    read and one branch — the record methods below exist only so that
-    an unguarded call is still safe (and so tests can monkeypatch them
-    to raise, proving the guards hold)."""
+    read and one branch — ``event`` exists only so that an unguarded
+    call is still safe (and so tests can monkeypatch it to raise,
+    proving the guards hold). ``span`` is the bare profiler annotation:
+    nothing is buffered, and nothing is recorded at all unless a
+    profiler session is open."""
 
     __slots__ = ()
     enabled = False
@@ -56,21 +99,8 @@ class NullTracer:
     def event(self, name: str, **kw: Any) -> None:
         return None
 
-    def span(self, name: str, **kw: Any) -> "_NullSpan":
-        return _NULL_SPAN
-
-
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    def span(self, name: str, **kw: Any) -> jax.profiler.TraceAnnotation:
+        return _annotation(name)
 
 #: The process-wide disabled tracer every serve component starts with.
 NULL_TRACER = NullTracer()
@@ -177,14 +207,16 @@ class Tracer:
 
     def span(self, name: str, *, trace_id: int = -1,
              lane: Optional[str] = None, **attrs: Any) -> "_Span":
-        """Context manager recording ``name`` with its measured wall
-        duration (step stamped at ENTRY — the deterministic clock of a
-        span is when it began)."""
+        """Context manager: the same ``ff.<name>`` profiler annotation
+        the null tracer returns, and on exit ``name`` recorded into the
+        buffer with its measured wall duration (step stamped at ENTRY —
+        the deterministic clock of a span is when it began)."""
         return _Span(self, name, trace_id, lane, attrs)
 
 
 class _Span:
-    __slots__ = ("_tr", "_name", "_tid", "_lane", "_attrs", "_t0", "_s0")
+    __slots__ = ("_tr", "_name", "_tid", "_lane", "_attrs", "_t0", "_s0",
+                 "_annotation")
 
     def __init__(self, tracer: Tracer, name: str, trace_id: int,
                  lane: Optional[str], attrs: Dict[str, Any]):
@@ -193,13 +225,16 @@ class _Span:
         self._tid = trace_id
         self._lane = lane
         self._attrs = attrs
+        self._annotation = _annotation(name)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         self._s0 = self._tr.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tr.event(
             self._name,
             trace_id=self._tid,

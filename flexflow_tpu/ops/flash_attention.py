@@ -119,6 +119,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = m_scr[:, :1] + jnp.log(l)
 
 
+def _variant(causal: bool) -> str:
+    """Kernel-name suffix: what a profile or the HLO shows of a
+    ``pallas_call`` is its ``name`` (``ff_flash_fwd_causal`` …)."""
+    return "_causal" if causal else ""
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
     """q (N, S, dk), k/v (N, T, dk) → (out (N, S, dk), lse (N, S, 1)).
     The per-row statistics (lse here, delta in the backward) travel as
@@ -156,6 +162,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
                 pltpu.VMEM((bq, LANES), jnp.float32),
             ],
         ),
+        name=f"ff_flash_fwd{_variant(causal)}",
         interpret=_interpret(),
     )(q, k, v)
 
@@ -318,6 +325,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k):
                 pltpu.VMEM((bk, dk), jnp.float32),
             ],
         ),
+        name=f"ff_flash_bwd_dkv{_variant(causal)}",
         interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
@@ -340,6 +348,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k):
             out_specs=pl.BlockSpec((1, bq, dk), lambda n, i, j: (n, i, 0)),
             scratch_shapes=[pltpu.VMEM((bq, dk), jnp.float32)],
         ),
+        name=f"ff_flash_bwd_dq{_variant(causal)}",
         interpret=_interpret(),
     )(q, k, do, lse, delta, v)
     return dq, dkv[0], dkv[1]

@@ -114,11 +114,24 @@ class ProfileInfo:
     request_manager.h:271-277: llm_decoding_steps + start/finish).
     ``first_token_time`` is stamped when the host observes the request's
     first sampled token (TTFT as a client would measure it — with the
-    dispatch-ahead pipeline that is the flush, not the device sample)."""
+    dispatch-ahead pipeline that is the flush, not the device sample).
+
+    All stamps are ``time.perf_counter()`` of the serving process, and
+    ``start <= admit <= prefill_dispatched <= first_token <= finish``:
+    TTFT is exactly queue wait + prefill dispatch + first-token lag
+    (the three properties below)."""
 
     start_time: float = 0.0
     finish_time: float = 0.0
     first_token_time: float = 0.0
+    # The request's FIRST slot grant (a preempted request's re-admission
+    # does not move it): start -> admit is the time spent queued.
+    admit_time: float = 0.0
+    # The dispatch of the step that carried the prompt's final chunk —
+    # the step whose sample became the first token. A request preempted
+    # before its first token overwrites it when its recompute's final
+    # chunk goes out; once first_token_time is set it is final.
+    prefill_dispatched_time: float = 0.0
     # Prompt tokens served from the prefix cache at admission (prefill
     # started past them); 0 on a miss or with caching off.
     cached_prefix_len: int = 0
@@ -186,6 +199,30 @@ class ProfileInfo:
         if not self.first_token_time:
             return 0.0
         return max(0.0, self.first_token_time - self.start_time)
+
+    @property
+    def queue_wait_s(self) -> float:
+        """Registration to the first slot grant (0 until admitted)."""
+        if not self.admit_time:
+            return 0.0
+        return max(0.0, self.admit_time - self.start_time)
+
+    @property
+    def prefill_dispatch_s(self) -> float:
+        """First slot grant to the dispatch of the prompt's final chunk:
+        the chunked prefill as the host paced it (0 until dispatched)."""
+        if not (self.admit_time and self.prefill_dispatched_time):
+            return 0.0
+        return max(0.0, self.prefill_dispatched_time - self.admit_time)
+
+    @property
+    def first_token_lag_s(self) -> float:
+        """Dispatch of the final chunk to the host seeing its sample:
+        the steps queued ahead of it on the device, its own device time
+        and the flush's lag (0 until the first token)."""
+        if not (self.prefill_dispatched_time and self.first_token_time):
+            return 0.0
+        return max(0.0, self.first_token_time - self.prefill_dispatched_time)
 
     def tpot_s(self, n_output_tokens: int) -> float:
         """Time per output token over the decode phase (first token →

@@ -244,6 +244,11 @@ _PROFILE_LATEST = (
     "cached_prefix_len", "host_hit_tokens", "tree_width", "tree_depth",
     "context_shards",
 )
+#: stamps a request keeps from the FIRST home that set them
+_PROFILE_FIRST_HOME = (
+    "start_time", "admit_time", "prefill_dispatched_time",
+    "first_token_time",
+)
 
 
 class _RequestView:
@@ -285,10 +290,8 @@ class _RequestView:
         self._profile_base = {
             f: getattr(self._profile, f) for f in _PROFILE_COUNTERS
         }
-        self._profile_base["start_time"] = self._profile.start_time
-        self._profile_base["first_token_time"] = (
-            self._profile.first_token_time
-        )
+        for f in _PROFILE_FIRST_HOME:
+            self._profile_base[f] = getattr(self._profile, f)
 
     @property
     def output_tokens(self) -> List[int]:
@@ -313,12 +316,11 @@ class _RequestView:
         for f in _PROFILE_LATEST:
             if server.get(f):
                 setattr(p, f, server[f])
-        # times: the FIRST home's start/first-token stamps win; finish
-        # follows the latest home
-        if not base["start_time"] and server.get("start_time"):
-            p.start_time = server["start_time"]
-        if not base["first_token_time"] and server.get("first_token_time"):
-            p.first_token_time = server["first_token_time"]
+        # times: the FIRST home's start/admit/dispatch/first-token
+        # stamps win; finish follows the latest home
+        for f in _PROFILE_FIRST_HOME:
+            if not base[f] and server.get(f):
+                setattr(p, f, server[f])
         if server.get("finish_time"):
             p.finish_time = server["finish_time"]
 

@@ -698,6 +698,43 @@ class ServingConfig:
         )
 
 
+def program_name(key: Any) -> str:
+    """The stable name of the program compiled under the step key
+    ``key`` (:meth:`InferenceEngine._jit`): what JAX calls its module
+    (``jit_<name>``) in lowered HLO and on a profile's ``XLA Modules``
+    line. Every per-step program starts ``ff_step_``; ``c<n>`` is the
+    chunk — ``ff_step_c1`` the pipelined decode step, ``ff_step_c128``
+    the pipelined mixed step at ``mixed_chunk=128``."""
+    if isinstance(key, str):  # commit, copy_page, reorder, ...
+        return f"ff_{key}"
+
+    def flags(**on):
+        return "".join(f"_{word}" for word, yes in on.items() if yes)
+
+    def head(mode, cap):  # the sampling head a fused step compiled in
+        return f"_{mode}{cap or ''}" if mode else ""
+
+    kind, *rest = key
+    if kind == "mixed_fused":
+        chunk, with_logits, *mode_cap = rest
+        return (f"ff_step_c{chunk}" + flags(logits=with_logits)
+                + (head(*mode_cap) if mode_cap else ""))
+    if kind == "step_sampled":
+        chunk, with_mask, mode, cap, with_logits = rest
+        return (f"ff_step_sampled_c{chunk}"
+                + flags(logits=with_logits, mask=with_mask) + head(mode, cap))
+    if kind == "whole_step":
+        chunk, tiles, mode, cap, with_logits = rest
+        return (f"ff_step_whole_c{chunk}_t{tiles}"
+                + flags(logits=with_logits) + head(mode, cap))
+    if kind == "whole_step_tree":
+        return f"ff_step_whole_tree_c{rest[0]}"
+    if kind == "speculate":
+        return "ff_speculate_" + "_".join(str(p) for p in rest)
+    chunk, all_logits, with_mask = key  # the sync step (_get_step)
+    return f"ff_step_sync_c{chunk}" + flags(logits=all_logits, mask=with_mask)
+
+
 class InferenceEngine:
     """Owns device-resident params + KV cache and the jitted step fns.
 
@@ -1246,13 +1283,24 @@ class InferenceEngine:
     def _jit(self, fn: Callable, *, key: Any,
              donate_argnums: Tuple[int, ...] = ()) -> Callable:
         """Every step program (``_steps``/``_commit``) is compiled
-        through this chokepoint so the retrace sentinel can observe it:
-        the guard wraps ``fn`` to record each trace — which is exactly
-        one XLA compile — under ``key`` and, in strict mode, raises on
-        any recompile of a known key (analysis/retrace.py)."""
+        through this chokepoint, which does two things. It NAMES the
+        program from its key (:func:`program_name`), so a profile's
+        ``XLA Modules`` line and the lowered HLO read
+        ``jit_ff_step_c1`` / ``jit_ff_step_c128`` instead of the name
+        of whatever closure was jitted. And it lets the retrace
+        sentinel observe it: the guard wraps the function (keeping its
+        name) to record each trace — which is exactly one XLA compile —
+        under ``key`` and, in strict mode, raises on any recompile of a
+        known key (analysis/retrace.py)."""
+
+        @functools.wraps(fn)
+        def program(*args, **kwargs):
+            return fn(*args, **kwargs)
+
+        program.__name__ = program.__qualname__ = program_name(key)
         if self.retrace_guard is not None:
-            fn = self.retrace_guard.instrument(fn, key=key)
-        return jax.jit(fn, donate_argnums=donate_argnums)
+            program = self.retrace_guard.instrument(program, key=key)
+        return jax.jit(program, donate_argnums=donate_argnums)
 
     def _carry(self, last_tokens):
         """The sampled-token carry as a step's own output would present

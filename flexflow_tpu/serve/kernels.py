@@ -221,6 +221,7 @@ def decode_attention(
                 pltpu.VMEM((KV, G), jnp.float32),
             ],
         ),
+        name="ff_decode_attention",
         interpret=_interpret(),
     )(seq_lens.astype(jnp.int32), qg, k_cache, v_cache)
     return out.reshape(R, H, dk)
@@ -337,6 +338,7 @@ def verify_attention(
                 pltpu.VMEM((C, KV, G), jnp.float32),
             ],
         ),
+        name=f"ff_verify_attention_c{C}",
         interpret=_interpret(),
     )(qg, k_cache, v_cache, mask)
     return out.reshape(R, C, H, dk)
@@ -398,6 +400,16 @@ def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
     ps = pool.shape[1]
     flat = jnp.take(pool, page_table.reshape(-1), axis=0)
     return flat.reshape((R, NP * ps) + pool.shape[2:])
+
+
+def _quant_suffix(quant: bool, pack: int) -> str:
+    """Kernel-name suffix of a quantized pool's variant. Every
+    ``pallas_call`` here carries ``name=``: the kernel and its variant
+    (``ff_ragged_paged_c128``, ``ff_ragged_paged_c1_int8``, …), so a
+    profile or the HLO says which kernel ran without reading shapes."""
+    if not quant:
+        return ""
+    return "_int4" if pack == 2 else "_int8"
 
 
 def _unpack_codes(block: jnp.ndarray, pack: int) -> jnp.ndarray:
@@ -926,6 +938,8 @@ def _ragged_paged_attention(
                 C, H, max(dk, ps),
             ),
         ),
+        name=f"ff_ragged_paged_c{C}"
+             + _quant_suffix(k_scale is not None, pack),
         interpret=_interpret(),
     )(page_table.astype(jnp.int32), *operands)
     return out.reshape(R, C, H, dk)
@@ -1347,6 +1361,7 @@ def fused_rope_paged_attention(
             ),
         ),
         input_output_aliases=aliases,
+        name=f"ff_rope_ragged_paged_c{C}{_quant_suffix(quant, pack)}",
         interpret=_interpret(),
     )(page_table.astype(jnp.int32), logical.astype(jnp.int32),
       off.astype(jnp.int32), *operands)
@@ -1747,6 +1762,7 @@ def whole_step_decode(
             scratch_shapes=[pltpu.VMEM((R, C, D), x0.dtype)],
         ),
         input_output_aliases=aliases,
+        name=f"ff_whole_step_c{C}",
         interpret=_interpret(),
     )(*operands)
     logits, toks = outs[0], outs[1]
@@ -2094,6 +2110,7 @@ def _whole_step_decode_tiled(
             ],
         ),
         input_output_aliases=aliases,
+        name=f"ff_whole_step_tiled_c{C}_t{K}",
         interpret=_interpret(),
     )(*operands)
     logits, toks = outs[0], outs[1]

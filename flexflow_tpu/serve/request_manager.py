@@ -163,12 +163,15 @@ class RequestManager:
         self.stats = SchedulerStats()
         self._log = get_logger("serve")
         # Observability (flexflow_tpu/obs): request-lifecycle tracing +
-        # failure flight recorder. Disabled by default — every emission
+        # failure flight recorder. Disabled by default — every EVENT
         # site below guards on ``tracer.enabled`` (one attribute read)
-        # before building any event, so a no-obs run does no extra
-        # per-step host work (tests/test_observability.py proves it).
-        # obs.attach_observability wires a live tracer in; the engine
-        # shares it so dispatch events land on the same lane.
+        # before building any event, so a no-obs run builds no event
+        # and touches no buffer (tests/test_observability.py proves
+        # it). The phase spans of a step (obs.tracer.STEP_SPANS) are
+        # called unguarded: with the null tracer each is a bare
+        # profiler annotation, recorded only while a profiler session
+        # is open. obs.attach_observability wires a live tracer in; the
+        # engine shares it so dispatch events land on the same lane.
         self.tracer = NULL_TRACER
         self.flight_recorder = None
         # rid -> cluster-wide trace id (bound at submission; local runs
@@ -358,6 +361,8 @@ class RequestManager:
         self._admit_counter += 1
         if profile is not None:
             req.profile = profile
+        if not req.profile.admit_time:
+            req.profile.admit_time = time.perf_counter()
         req.profile.context_shards = getattr(self.engine, "cp_shards", 1)
         self.requests[rid] = req
         self.slots[slot] = rid
@@ -497,45 +502,46 @@ class RequestManager:
         if not self._paged:
             return
         lines_fn = lines_fn or self._lines_needed
-        while True:
-            active = sorted(
-                (
-                    self.requests[rid]
-                    for rid in self.slots
-                    if rid is not None
-                    and self.requests[rid].status
-                    in (RequestStatus.PREFILLING, RequestStatus.DECODING)
-                ),
-                key=lambda r: r.admit_seq,
-            )
-            for req in active:
-                if self._ensure_pages(req, lines_fn(req)):
-                    continue
-                # free in-flight state before touching slot ownership;
-                # flushed completions may already release enough pages
-                self._flush_all()
-                if req.status not in (
-                    RequestStatus.PREFILLING, RequestStatus.DECODING
-                ) or self._ensure_pages(req, lines_fn(req)):
-                    break  # flush resolved it; re-derive the active set
-                victims = [
-                    r for r in active
-                    if r is not req
-                    and r.status
-                    in (RequestStatus.PREFILLING, RequestStatus.DECODING)
-                ]
-                if not victims:
-                    self._fail_request(
-                        req,
-                        "KV page pool exhausted by this request alone — "
-                        "raise ServingConfig.max_cached_tokens (or lower "
-                        "max_sequence_length/page_size)",
-                    )
+        with self.tracer.span("step.reserve"):
+            while True:
+                active = sorted(
+                    (
+                        self.requests[rid]
+                        for rid in self.slots
+                        if rid is not None
+                        and self.requests[rid].status
+                        in (RequestStatus.PREFILLING, RequestStatus.DECODING)
+                    ),
+                    key=lambda r: r.admit_seq,
+                )
+                for req in active:
+                    if self._ensure_pages(req, lines_fn(req)):
+                        continue
+                    # free in-flight state before touching slot ownership;
+                    # flushed completions may already release enough pages
+                    self._flush_all()
+                    if req.status not in (
+                        RequestStatus.PREFILLING, RequestStatus.DECODING
+                    ) or self._ensure_pages(req, lines_fn(req)):
+                        break  # flush resolved it; re-derive the active set
+                    victims = [
+                        r for r in active
+                        if r is not req
+                        and r.status
+                        in (RequestStatus.PREFILLING, RequestStatus.DECODING)
+                    ]
+                    if not victims:
+                        self._fail_request(
+                            req,
+                            "KV page pool exhausted by this request alone — "
+                            "raise ServingConfig.max_cached_tokens (or lower "
+                            "max_sequence_length/page_size)",
+                        )
+                        break  # active set changed; re-derive
+                    self._preempt(victims[-1])
                     break  # active set changed; re-derive
-                self._preempt(victims[-1])
-                break  # active set changed; re-derive
-            else:
-                return
+                else:
+                    return
 
     def _attach_paging_metadata(self, bc: BatchConfig):
         """Record the page table + ragged lengths on the batch
@@ -678,6 +684,10 @@ class RequestManager:
             req.pipeline_refs = 0
             req.admit_seq = self._admit_counter
             self._admit_counter += 1
+            if not req.profile.admit_time:
+                # the FIRST grant: a preempted request's re-admission
+                # is recompute time, not queue wait
+                req.profile.admit_time = time.perf_counter()
             req.profile.cached_prefix_len = matched
             req.profile.context_shards = getattr(self.engine, "cp_shards", 1)
             # tokens of this prefix that came back from the HOST tier
@@ -889,8 +899,9 @@ class RequestManager:
         # the host-side decode head is its own dispatched program — the
         # figure the fused sampling epilogue's one-program step beats
         self.engine.count_dispatch("host_sample")
-        # ffcheck: disable=FF107 -- blocking sync-scheduler decode head: this path trades latency for simplicity by design (the pipelined path samples on device)
-        return np.asarray(jax.device_get(toks))
+        with self.tracer.span("step.flush_wait"):
+            # ffcheck: disable=FF107 -- blocking sync-scheduler decode head: this path trades latency for simplicity by design (the pipelined path samples on device)
+            return np.asarray(jax.device_get(toks))
 
     def _append_token(self, req: Request, token: int):
         if len(req.tokens) == req.prompt_len and not req.profile.first_token_time:
@@ -938,46 +949,60 @@ class RequestManager:
             >= self.engine.serving.max_sequence_length
         )
 
+    @staticmethod
+    def _stamp_prefill_dispatched(finals: List[Request]) -> None:
+        """``ProfileInfo.prefill_dispatched_time`` for the rows whose
+        final prompt chunk the step just dispatched carried (and that
+        have no first token yet: a preempted request's recompute moves
+        the stamp, so it is always the dispatch whose sample became the
+        first token)."""
+        if finals:
+            now = time.perf_counter()
+            for req in finals:
+                req.profile.prefill_dispatched_time = now
+
     def _dispatch_decode(self, decoding: List[Request]):
         """Dispatch one fused decode step WITHOUT waiting for the
         previous one: decode rows that sampled in the previous dispatch
         take their input token from the on-device sampled tokens; rows
         entering the pipeline take it from host state. Positions advance
         deterministically, so no host sync is needed."""
-        R = self.engine.num_slots
-        scratch = self.engine.scratch_pos
-        host_tokens = np.zeros((R, 1), np.int32)
-        use_last = np.zeros((R,), bool)
-        positions = np.full((R, 1), scratch, np.int32)
-        greedy, temp, topp, topk = self._decode_head_params(decoding)
-        snapshot = []
-        last = self._inflight[-1][0] if self._inflight else None
-        for req in decoding:
-            s = req.slot
-            positions[s, 0] = len(req.tokens) - 1 + req.inflight
-            if s in self._prev_dispatch_slots and last is not None:
-                use_last[s] = True
-            else:
-                host_tokens[s, 0] = req.tokens[-1]
-            req.inflight += 1
-            req.pipeline_refs += 1
-            snapshot.append((req.request_id, s, 1, True))
-        if last is None:
-            last = jnp.zeros((R,), jnp.int32)
-        self._key, sub = jax.random.split(self._key)
-        t0 = time.perf_counter()
-        toks = self.engine.run_decode(
-            last, host_tokens, use_last, positions, sub, greedy, temp, topp,
-            topk,
-        )
-        # decode_step_ms (bench serve_megakernel; ROADMAP 5b): the
-        # engine call's host wall time — dispatch cost on this
-        # pipelined path (the device runs ahead; no sync is added)
-        self.stats.note_decode_step_ms((time.perf_counter() - t0) * 1e3)
-        self._mirror_dispatch(
-            last, host_tokens, use_last, positions,
-            np.zeros((R,), np.int32), sub, greedy, temp, topp, topk,
-        )
+        with self.tracer.span("step.build"):
+            R = self.engine.num_slots
+            scratch = self.engine.scratch_pos
+            host_tokens = np.zeros((R, 1), np.int32)
+            use_last = np.zeros((R,), bool)
+            positions = np.full((R, 1), scratch, np.int32)
+            greedy, temp, topp, topk = self._decode_head_params(decoding)
+            snapshot = []
+            last = self._inflight[-1][0] if self._inflight else None
+            for req in decoding:
+                s = req.slot
+                positions[s, 0] = len(req.tokens) - 1 + req.inflight
+                if s in self._prev_dispatch_slots and last is not None:
+                    use_last[s] = True
+                else:
+                    host_tokens[s, 0] = req.tokens[-1]
+                req.inflight += 1
+                req.pipeline_refs += 1
+                snapshot.append((req.request_id, s, 1, True))
+            if last is None:
+                last = jnp.zeros((R,), jnp.int32)
+            self._key, sub = jax.random.split(self._key)
+        with self.tracer.span("step.dispatch"):
+            t0 = time.perf_counter()
+            toks = self.engine.run_decode(
+                last, host_tokens, use_last, positions, sub, greedy, temp,
+                topp, topk,
+            )
+            # decode_step_ms (bench serve_megakernel; ROADMAP 5b): the
+            # engine call's host wall time — dispatch cost on this
+            # pipelined path (the device runs ahead; no sync is added)
+            self.stats.note_decode_step_ms((time.perf_counter() - t0) * 1e3)
+            self._mirror_dispatch(
+                last, host_tokens, use_last, positions,
+                np.zeros((R,), np.int32), sub, greedy, temp, topp, topk,
+            )
         self._inflight.append((toks, snapshot))
         self._prev_dispatch_slots = {s for _, s, _, _ in snapshot}
         self._step_counter += 1
@@ -1002,86 +1027,92 @@ class RequestManager:
         device, so the next iteration schedules them as decode rows fed
         by device feedback — an admission never costs a pipeline
         drain."""
-        eng = self.engine
-        sc = eng.serving
-        R = eng.num_slots
-        C = sc.mixed_chunk
-        bc = BatchConfig.empty(R, C, eng.scratch_pos)
-        bc.qlens = np.zeros((R,), np.int32)
-        bc.prefill_offsets = np.zeros((R,), np.int32)
-        use_last = np.zeros((R,), bool)
-        snapshot = []
-        sampled_slots = set()
-        last = self._inflight[-1][0] if self._inflight else None
-        greedy, temp, topp, topk = self._decode_head_params(
-            list(decoding) + list(prefilling)
-        )
-        for req in decoding:
-            s = req.slot
-            bc.positions[s, 0] = len(req.tokens) - 1 + req.inflight
-            if s in self._prev_dispatch_slots and last is not None:
-                use_last[s] = True
-            else:
-                bc.tokens[s, 0] = req.tokens[-1]
-            bc.logits_idx[s] = 0
-            bc.active[s] = True
-            bc.qlens[s] = 1
-            req.inflight += 1
-            req.pipeline_refs += 1
-            snapshot.append((req.request_id, s, 1, True))
-            sampled_slots.add(s)
-        spent = 0
-        tr = self.tracer
-        for req in sorted(prefilling, key=lambda r: r.admit_seq):
-            n = min(C, len(req.tokens) - req.n_sched)
-            if n <= 0:
-                continue
-            s = req.slot
-            off = req.n_sched
-            bc.tokens[s, :n] = req.tokens[off : off + n]
-            bc.positions[s, :n] = np.arange(off, off + n)
-            bc.logits_idx[s] = n - 1
-            bc.active[s] = True
-            bc.qlens[s] = n
-            bc.prefill_offsets[s] = off
-            final = off + n >= len(req.tokens)
-            req.n_sched += n
-            req.pipeline_refs += 1
-            spent += n
-            if final:
-                # prompt fully dispatched: this step samples the first
-                # output token on device — decode from the next step on
-                req.status = RequestStatus.DECODING
+        with self.tracer.span("step.build"):
+            eng = self.engine
+            sc = eng.serving
+            R = eng.num_slots
+            C = sc.mixed_chunk
+            bc = BatchConfig.empty(R, C, eng.scratch_pos)
+            bc.qlens = np.zeros((R,), np.int32)
+            bc.prefill_offsets = np.zeros((R,), np.int32)
+            use_last = np.zeros((R,), bool)
+            snapshot = []
+            sampled_slots = set()
+            last = self._inflight[-1][0] if self._inflight else None
+            greedy, temp, topp, topk = self._decode_head_params(
+                list(decoding) + list(prefilling)
+            )
+            for req in decoding:
+                s = req.slot
+                bc.positions[s, 0] = len(req.tokens) - 1 + req.inflight
+                if s in self._prev_dispatch_slots and last is not None:
+                    use_last[s] = True
+                else:
+                    bc.tokens[s, 0] = req.tokens[-1]
+                bc.logits_idx[s] = 0
+                bc.active[s] = True
+                bc.qlens[s] = 1
                 req.inflight += 1
+                req.pipeline_refs += 1
+                snapshot.append((req.request_id, s, 1, True))
                 sampled_slots.add(s)
-                if (
-                    self.prefix_cache is not None
-                    and self.prefix_cache.policy == "prefill"
-                ):
-                    # every prompt line's write is dispatched — publish
-                    # the prompt now so concurrent same-prefix
-                    # admissions hit before this request even finishes
-                    self._cache_insert(
-                        s, req.tokens[: req.prompt_len], req.prompt_len
+            spent = 0
+            finals = []  # rows whose sample of this step is their first token
+            tr = self.tracer
+            for req in sorted(prefilling, key=lambda r: r.admit_seq):
+                n = min(C, len(req.tokens) - req.n_sched)
+                if n <= 0:
+                    continue
+                s = req.slot
+                off = req.n_sched
+                bc.tokens[s, :n] = req.tokens[off : off + n]
+                bc.positions[s, :n] = np.arange(off, off + n)
+                bc.logits_idx[s] = n - 1
+                bc.active[s] = True
+                bc.qlens[s] = n
+                bc.prefill_offsets[s] = off
+                final = off + n >= len(req.tokens)
+                req.n_sched += n
+                req.pipeline_refs += 1
+                spent += n
+                if final:
+                    # prompt fully dispatched: this step samples the first
+                    # output token on device — decode from the next step on
+                    req.status = RequestStatus.DECODING
+                    req.inflight += 1
+                    sampled_slots.add(s)
+                    if not req.profile.first_token_time:
+                        finals.append(req)
+                    if (
+                        self.prefix_cache is not None
+                        and self.prefix_cache.policy == "prefill"
+                    ):
+                        # every prompt line's write is dispatched — publish
+                        # the prompt now so concurrent same-prefix
+                        # admissions hit before this request even finishes
+                        self._cache_insert(
+                            s, req.tokens[: req.prompt_len], req.prompt_len
+                        )
+                snapshot.append((req.request_id, s, n, final))
+                if tr.enabled:
+                    tr.event(
+                        "prefill_chunk",
+                        trace_id=self.trace_of(req.request_id),
+                        rid=req.request_id, n=n, offset=off, final=final,
                     )
-            snapshot.append((req.request_id, s, n, final))
-            if tr.enabled:
-                tr.event(
-                    "prefill_chunk",
-                    trace_id=self.trace_of(req.request_id),
-                    rid=req.request_id, n=n, offset=off, final=final,
-                )
-        if last is None:
-            last = jnp.zeros((R,), jnp.int32)
-        self._key, sub = jax.random.split(self._key)
-        toks = eng.run_mixed(
-            last, bc.tokens, use_last, bc.positions, bc.logits_idx,
-            sub, greedy, temp, topp, topk,
-        )
-        self._mirror_dispatch(
-            last, bc.tokens, use_last, bc.positions, bc.logits_idx,
-            sub, greedy, temp, topp, topk,
-        )
+            if last is None:
+                last = jnp.zeros((R,), jnp.int32)
+            self._key, sub = jax.random.split(self._key)
+        with self.tracer.span("step.dispatch"):
+            toks = eng.run_mixed(
+                last, bc.tokens, use_last, bc.positions, bc.logits_idx,
+                sub, greedy, temp, topp, topk,
+            )
+            self._mirror_dispatch(
+                last, bc.tokens, use_last, bc.positions, bc.logits_idx,
+                sub, greedy, temp, topp, topk,
+            )
+        self._stamp_prefill_dispatched(finals)
         self._inflight.append((toks, snapshot))
         self._prev_dispatch_slots = sampled_slots
         self._step_counter += 1
@@ -1104,42 +1135,44 @@ class RequestManager:
         request finished by an earlier flush skips the bookkeeping but
         still drains its pipeline refs — its slot/pages are released at
         the flush that drains the last reference."""
-        toks, snapshot = self._inflight.pop(0)
-        # ffcheck: disable=FF107 -- the pipeline flush IS the designed sync point: it drains steps the device already finished, dispatch_ahead steps behind
-        toks = np.asarray(jax.device_get(toks))
-        self.stats.flushes += 1
-        tr = self.tracer
-        if tr.enabled:
-            tr.event("flush", entries=len(snapshot))
-        for rid, slot, ntoks, samples in snapshot:
-            req = self.requests.get(rid)
-            if req is None:
-                continue
-            req.pipeline_refs = max(0, req.pipeline_refs - 1)
-            if samples:
-                req.inflight = max(0, req.inflight - 1)
-            alive = (
-                req.status
-                in (RequestStatus.PREFILLING, RequestStatus.DECODING)
-                and req.slot == slot
-            )
-            if alive:
-                req.n_cached += ntoks
+        with self.tracer.span("step.flush"):
+            toks, snapshot = self._inflight.pop(0)
+            with self.tracer.span("step.flush_wait"):
+                # ffcheck: disable=FF107 -- the pipeline flush IS the designed sync point: it drains steps the device already finished, dispatch_ahead steps behind
+                toks = np.asarray(jax.device_get(toks))
+            self.stats.flushes += 1
+            tr = self.tracer
+            if tr.enabled:
+                tr.event("flush", entries=len(snapshot))
+            for rid, slot, ntoks, samples in snapshot:
+                req = self.requests.get(rid)
+                if req is None:
+                    continue
+                req.pipeline_refs = max(0, req.pipeline_refs - 1)
                 if samples:
-                    req.profile.llm_decoding_steps += 1
-                    self._append_token(req, toks[slot])
-            if (
-                req.status in TERMINAL_STATUSES
-                and req.slot == slot
-                and req.pipeline_refs == 0
-                and req.request_id not in self.hold_finished
-            ):
-                self._release_slot(req)
-        # the flush just blocked on device_get — every async spill
-        # copy enqueued before it has landed; convert the handles
-        # to host buffers and release their device memory
-        for cache in self._prefix_caches():
-            cache.harvest()
+                    req.inflight = max(0, req.inflight - 1)
+                alive = (
+                    req.status
+                    in (RequestStatus.PREFILLING, RequestStatus.DECODING)
+                    and req.slot == slot
+                )
+                if alive:
+                    req.n_cached += ntoks
+                    if samples:
+                        req.profile.llm_decoding_steps += 1
+                        self._append_token(req, toks[slot])
+                if (
+                    req.status in TERMINAL_STATUSES
+                    and req.slot == slot
+                    and req.pipeline_refs == 0
+                    and req.request_id not in self.hold_finished
+                ):
+                    self._release_slot(req)
+            # the flush just blocked on device_get — every async spill
+            # copy enqueued before it has landed; convert the handles
+            # to host buffers and release their device memory
+            for cache in self._prefix_caches():
+                cache.harvest()
 
     def _flush_all(self):
         if self._inflight:
@@ -1234,10 +1267,12 @@ class RequestManager:
         progression never drain the pipeline. The blocking sync path
         remains for SpecInfer/triage managers, for the flush-on-admit
         baseline scheduler, and as the idle drain."""
-        self._admit_pending()
+        with self.tracer.span("step.admit"):
+            self._admit_pending()
+            if self.supports_fast_decode:
+                self._reclaim_slots_for_admission()
         sc = self.engine.serving
         if self.supports_fast_decode:
-            self._reclaim_slots_for_admission()
             prefilling = self._active(RequestStatus.PREFILLING)
             decoding = self._active(RequestStatus.DECODING)
             if decoding and not prefilling:
@@ -1277,12 +1312,20 @@ class RequestManager:
     def _step_sync(self) -> bool:
         self._flush_all()
         self._reserve_active_pages()
-        bc = self._prepare_batch()
+        with self.tracer.span("step.build"):
+            bc = self._prepare_batch()
         if bc is None:
             return bool(self.pending)
         prefilling = self._active(RequestStatus.PREFILLING)
         decoding = self._active(RequestStatus.DECODING)
         decode_only = bool(decoding) and not prefilling
+        # rows whose final prompt chunk rides this batch: its sample is
+        # their first token
+        finals = [
+            r for r in prefilling
+            if r.n_cached + int(bc.qlens[r.slot]) >= len(r.tokens)
+            and not r.profile.first_token_time
+        ]
         t0 = time.perf_counter()
         fused = self.engine.serving.fused_decode
         if (
@@ -1294,15 +1337,25 @@ class RequestManager:
             # (R, V) logits never reach the host. Same single key split
             # per step as the unfused path, so generations are bitwise
             # identical.
-            greedy, temp, topp, topk = self._decode_head_params(
-                [self.requests[r] for r in self.slots if r is not None]
-            )
-            self._key, sub = jax.random.split(self._key)
-            toks = self.engine.run_sampled(bc, sub, greedy, temp, topp, topk)
-            # ffcheck: disable=FF107 -- blocking sync scheduler: one fetch per step by design
-            sampled = np.asarray(jax.device_get(toks))
+            with self.tracer.span("step.build"):
+                greedy, temp, topp, topk = self._decode_head_params(
+                    [self.requests[r] for r in self.slots if r is not None]
+                )
+                self._key, sub = jax.random.split(self._key)
+            with self.tracer.span("step.dispatch"):
+                toks = self.engine.run_sampled(
+                    bc, sub, greedy, temp, topp, topk
+                )
+            self._stamp_prefill_dispatched(finals)
+            # the sync scheduler's blocking fetch: the same wait as the
+            # pipelined flush's, with no flush round it
+            with self.tracer.span("step.flush_wait"):
+                # ffcheck: disable=FF107 -- blocking sync scheduler: one fetch per step by design
+                sampled = np.asarray(jax.device_get(toks))
         else:
-            logits = self._run_batch(bc)
+            with self.tracer.span("step.dispatch"):
+                logits = self._run_batch(bc)
+            self._stamp_prefill_dispatched(finals)
             sampled = self._sample(logits)
         if decode_only:
             # decode_step_ms, sync path: the full blocking step wall

@@ -1105,7 +1105,8 @@ class SpecInferManager(RequestManager):
         the pipeline is drained and one full speculate→verify→commit
         round runs per W×D bucket present among the decoding requests
         (adaptive controllers group them; non-adaptive = one bucket)."""
-        self._admit_pending()
+        with self.tracer.span("step.admit"):
+            self._admit_pending()
         sc = self.engine.serving
         if self._active(RequestStatus.PREFILLING):
             # the prefill phase mirrors decode rows into every SSM —
@@ -1113,7 +1114,8 @@ class SpecInferManager(RequestManager):
             # or the mirror would write K/V computed over cache holes
             self._sync_ssm_caches(self._active(RequestStatus.DECODING))
             if sc.continuous_batching and not sc.inference_debugging:
-                self._reclaim_slots_for_admission()
+                with self.tracer.span("step.admit"):
+                    self._reclaim_slots_for_admission()
                 self._reserve_active_pages(
                     lambda r: self._lines_needed(r, sc.mixed_chunk)
                 )

@@ -96,6 +96,9 @@ def test_ragged_paged_attention_bf16_compiles(chip, C):
         kernels.ragged_paged_attention, *_attention_args(chip, C, cfg)
     )
     assert "tpu_custom_call" in text
+    # the kernel's name= is its HLO instruction's name: what an xplane's
+    # XLA Ops event shows of it (PERF.md section 3)
+    assert f"%ff_ragged_paged_c{C}" in text
 
 
 def _step_args(sds, cfg, C, kv_quant=None):
@@ -141,6 +144,7 @@ def test_mistral_paged_pallas_step_compiles(chip, C):
         _step(cfg, kernels="pallas"), *_step_args(chip, cfg, C)
     )
     assert "tpu_custom_call" in text
+    assert f"%ff_ragged_paged_c{C}" in text  # inside the layer scan too
     # weights + pool + temporaries of this cut fit one 16 GB chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
