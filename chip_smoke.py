@@ -33,16 +33,16 @@ import time
 import numpy as np
 
 SEED = 0
-# Depth. One layer is 436 MB of bf16 weights and 4 KB per cached token,
-# and the step program holds the page pool TWICE (the layer scan writes
-# its pools to a fresh buffer; the donated one only takes the result).
-# Compiled for a described v5e (tests/test_chip_compile.py has the
-# program, /opt/skills/guides/on-chip-measurement section 2 the method),
-# the C=128 step with a 16k-token pool needs, of the 15.49 GB the chip
-# leaves a program: N=24 14.5 GB through Pallas but 16.8 GB through the
-# XLA reference it is compared with (2.6 GB of gathered cache and
-# scores), N=21 15.1 GB, N=20 14.6 GB. 20 is the largest with room to
-# spare for both paths.
+# Depth. One layer is 436 MB of bf16 weights and 4 KB per cached token;
+# the page pool is the layer loop's carry, held once (PR 28; before, the
+# step program held it twice and N=24 needed 16.8 GB through the XLA
+# reference). Compiled for a described v5e (tests/test_chip_compile.py
+# has the program, /opt/skills/guides/on-chip-measurement section 2 the
+# method), the C=128 step with a 16k-token pool needs, of the 15.49 GB
+# the chip leaves a program: N=20 10.7 GB through Pallas and 11.8 GB
+# through the XLA reference it is compared with (1.2 GB of gathered
+# cache and scores), N=24 12.7 and 13.8 GB. 24 would fit; the depth stays
+# where every recorded run was made.
 LAYERS = 20
 POOL_TOKENS = 16384
 # Pallas-vs-XLA (and tensor=4-vs-one-chip) logit tolerance, as a share of

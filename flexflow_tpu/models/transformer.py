@@ -969,11 +969,57 @@ def _page_lookup(page_table, cache_positions, page_size):
     return phys, cache_positions % page_size
 
 
+def _layer_of(a, layer):
+    """Layer ``layer`` of a stacked (L, ...) pool or scale array: a
+    read-only slice, which the compiler fuses into the gather that reads
+    it. Per-layer operands (``layer`` None) pass through."""
+    if layer is None or a is None:
+        return a
+    return lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+
+
+def _write_kv_lines(k_pool, v_pool, k_scale, v_scale, layer, phys, off,
+                    k, v, qmax):
+    """Commit the step's new K/V lines at their table-resolved (page,
+    offset) — of the per-layer pools, or with ``layer`` of that layer's
+    pages inside the stacked pools, in place (R*C lines, no layer
+    sliced out or put back). Quantizing when ``qmax`` is set
+    (serve/kv_quant.py). Returns the four arrays."""
+    if qmax is not None:
+        from ..serve.kv_quant import quant_line_write
+
+        k_pool, k_scale = quant_line_write(k_pool, k_scale, phys, off, k,
+                                           qmax, layer)
+        v_pool, v_scale = quant_line_write(v_pool, v_scale, phys, off, v,
+                                           qmax, layer)
+        return k_pool, v_pool, k_scale, v_scale
+    at = (phys, off) if layer is None else (layer, phys, off)
+    k_pool = k_pool.at[at].set(k.astype(k_pool.dtype))
+    v_pool = v_pool.at[at].set(v.astype(v_pool.dtype))
+    return k_pool, v_pool, k_scale, v_scale
+
+
+def _pallas_pools(k_pool, v_pool, k_scale, v_scale, layer):
+    """The pools as a Pallas paged kernel takes them: ``(k, v,
+    keywords)``. With ``layer`` each stack goes as its free
+    (L*(P+1), ...) view, in which layer l's pages are rows ``l*(P+1)``
+    on, and the keywords carry that row offset for the kernel's page
+    index maps."""
+    if layer is None:
+        return k_pool, v_pool, dict(k_scale=k_scale, v_scale=v_scale)
+    k, v, ks, vs = (
+        a if a is None else a.reshape((-1,) + a.shape[2:])
+        for a in (k_pool, v_pool, k_scale, v_scale)
+    )
+    return k, v, dict(k_scale=ks, v_scale=vs,
+                      row_offset=layer * k_pool.shape[1])
+
+
 def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                       phys, off, page_table, kernels: str = "xla",
                       k_scale=None, v_scale=None, qmax=None,
                       *, fused_rope: bool = False, logical=None,
-                      cp_mesh=None):
+                      cp_mesh=None, layer=None):
     """Paged twin of :func:`serve_block`: scatter new K/V at the
     table-resolved (page, offset); attend over the virtual cache read
     through the table (``jnp.take`` gather, or the fused ragged paged
@@ -982,6 +1028,15 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     quantizes in-step and reads dequantize at the page scales (fused
     in-kernel on the Pallas path). Returns
     ``(x, k_pool, v_pool, k_scale, v_scale)``.
+
+    ``layer`` (the layer loop of :func:`serve_step_paged`): the pools
+    and scales are every layer's, stacked (L, P+1, ...), and this block
+    addresses its own pages inside them, returning the stacks updated in
+    place. The XLA path writes at ``[layer, page, offset]`` and reads a
+    read-only slice; the Pallas kernels take the (L*(P+1), ...) view and
+    the row offset ``layer*(P+1)`` in their page index maps; the ring
+    path, whose pool rows are sharded, takes its layer out and puts it
+    back (``dynamic_update_slice``: one layer moved, never the stack).
 
     ``fused_rope`` (megakernel decode step): on the Pallas path RoPE —
     or, for non-RoPE position schemes, just the quantizing KV commit —
@@ -1001,95 +1056,79 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
         # (and the whole-step megakernel) anchors on; ONE shared body
         return _block_paged_xla(
             cfg, p, x, rope, bias, mask, k_pool, v_pool, phys, off,
-            page_table, k_scale, v_scale, qmax,
+            page_table, k_scale, v_scale, qmax, layer=layer,
         )
+    if cp_mesh is not None and layer is not None:
+        # the ring's pool rows are sharded over ``seq``, which the rows
+        # view would break: serve the layer as a per-layer pool and put
+        # it back into the stack in place
+        pools = (k_pool, v_pool, k_scale, v_scale)
+        x, *new = serve_block_paged(
+            cfg, p, x, rope, bias, mask,
+            *(_layer_of(a, layer) for a in pools[:2]), phys, off,
+            page_table, kernels, *(_layer_of(a, layer) for a in pools[2:]),
+            qmax, fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
+        )
+        return (x, *(
+            a if a is None
+            else lax.dynamic_update_index_in_dim(a, b, layer, 0)
+            for a, b in zip(pools, new)
+        ))
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
     q, k, v = _project_qkv(cfg, p, h)
-    if (fused_rope and kernels == "pallas" and bias is None
-            and cp_mesh is not None):
+    cos, sin = rope if rope is not None else (None, None)
+    fused = fused_rope and kernels == "pallas" and bias is None
+    if fused and cp_mesh is not None:
         # ring fused prologue: RoPE + the resident-line commit move
         # inside the per-shard shard_map body (full-precision pools;
         # quantized raises loudly in the kernel and is excluded at
         # ServingConfig validation)
-        cos, sin = rope if rope is not None else (None, None)
         attn, k_pool, v_pool = _pk.ring_ragged_paged_attention(
             q, k_pool, v_pool, page_table, mask, cp_mesh,
             fused=dict(k_new=k, v_new=v, cos=cos, sin=sin,
                        phys=phys, off=off),
         )
-        attn = attn.reshape(R, C, -1)
-        attn = _mm(attn, p["wo"])
-        if cfg.out_bias:
-            attn = attn + p["bo"]
-        if cfg.parallel_block:
-            if cfg.parallel_two_norms:
-                h2 = _norm(cfg, x, p["mlp_norm_scale"],
-                           p.get("mlp_norm_bias"))
-            else:
-                h2 = h
-            return (x + attn + _ffn(cfg, p, h2), k_pool, v_pool,
-                    k_scale, v_scale)
-        x = x + attn
-        h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-        return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
-    if fused_rope and kernels == "pallas" and bias is None:
-        cos, sin = rope if rope is not None else (None, None)
-        attn, k_pool, v_pool, k_scale, v_scale = (
-            _pk.fused_rope_paged_attention(
-                q, k, v, cos, sin, k_pool, v_pool, page_table,
-                logical, off, mask,
-                k_scale=k_scale, v_scale=v_scale, qmax=qmax,
-            )
+    elif fused:
+        k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale, v_scale,
+                                           layer)
+        attn, *new = _pk.fused_rope_paged_attention(
+            q, k, v, cos, sin, k_rows, v_rows, page_table, logical, off,
+            mask, qmax=qmax, **kw,
         )
-        attn = attn.reshape(R, C, -1)
-        attn = _mm(attn, p["wo"])
-        if cfg.out_bias:
-            attn = attn + p["bo"]
-        if cfg.parallel_block:
-            if cfg.parallel_two_norms:
-                h2 = _norm(cfg, x, p["mlp_norm_scale"],
-                           p.get("mlp_norm_bias"))
-            else:
-                h2 = h
-            return (x + attn + _ffn(cfg, p, h2), k_pool, v_pool,
-                    k_scale, v_scale)
-        x = x + attn
-        h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-        return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
-    if rope is not None:
-        cos, sin = rope
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    if qmax is not None:
-        from ..serve.kv_quant import quant_line_write
-
-        k_pool, k_scale = quant_line_write(k_pool, k_scale, phys, off, k, qmax)
-        v_pool, v_scale = quant_line_write(v_pool, v_scale, phys, off, v, qmax)
+        k_pool, v_pool, k_scale, v_scale = (
+            b if b is None else b.reshape(a.shape)
+            for a, b in zip((k_pool, v_pool, k_scale, v_scale), new)
+        )
     else:
-        k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-    if cp_mesh is not None:
-        if bias is not None:
-            # ALiBi's additive bias needs per-key-position terms the
-            # ring program does not carry yet (same exclusion as the
-            # Pallas kernel); sliding-window masks are fine — they are
-            # mask refinements, already folded in before this call.
-            raise NotImplementedError(
-                "ring context parallelism is not composed with ALiBi "
-                "position bias — serve this family with "
-                "kv_shard='context' on a seq-degree-1 mesh (the table-"
-                "gather layout), or use a RoPE/learned-position family"
+        if rope is not None:
+            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        k_pool, v_pool, k_scale, v_scale = _write_kv_lines(
+            k_pool, v_pool, k_scale, v_scale, layer, phys, off, k, v, qmax
+        )
+        if cp_mesh is not None:
+            if bias is not None:
+                # ALiBi's additive bias needs per-key-position terms the
+                # ring program does not carry yet (same exclusion as the
+                # Pallas kernel); sliding-window masks are fine — they
+                # are mask refinements, already folded in before this
+                # call.
+                raise NotImplementedError(
+                    "ring context parallelism is not composed with ALiBi "
+                    "position bias — serve this family with "
+                    "kv_shard='context' on a seq-degree-1 mesh (the table-"
+                    "gather layout), or use a RoPE/learned-position family"
+                )
+            attn = _pk.ring_ragged_paged_attention(
+                q, k_pool, v_pool, page_table, mask, cp_mesh,
+                k_scale=k_scale, v_scale=v_scale,
             )
-        attn = _pk.ring_ragged_paged_attention(
-            q, k_pool, v_pool, page_table, mask, cp_mesh,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-        attn = attn.reshape(R, C, -1)
-    else:  # kernels == "pallas", bias None (the xla path returned above)
-        attn = _pk.ragged_paged_attention(
-            q, k_pool, v_pool, page_table, mask,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-        attn = attn.reshape(R, C, -1)
+        else:  # kernels == "pallas", bias None (the xla path returned above)
+            k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale,
+                                               v_scale, layer)
+            attn = _pk.ragged_paged_attention(
+                q, k_rows, v_rows, page_table, mask, **kw
+            )
+    attn = attn.reshape(R, C, -1)
     attn = _mm(attn, p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
@@ -1180,12 +1219,13 @@ def _ffn_reduced(cfg: DecoderConfig, p, h, reduce_fn):
 def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
                      k_pool, v_pool, phys, off, page_table,
                      k_scale=None, v_scale=None, qmax=None,
-                     reduce_fn=None):
+                     reduce_fn=None, layer=None):
     """One block of the UNFUSED XLA paged step on values — the shared
     body of :func:`serve_block_paged`'s XLA path AND the whole-step
     decode megakernel / TP walk (:func:`serve_step_whole`); one
     definition is what makes whole-step decode bitwise the unfused XLA
-    step (see the llama twin for the full rationale)."""
+    step (see the llama twin for the full rationale). ``layer``: the
+    pools are the stacked ones (see :func:`serve_block_paged`)."""
     from ..serve import kernels as _pk
 
     R, C, D = x.shape
@@ -1194,22 +1234,20 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
     if rope is not None:
         cos, sin = rope
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    k_pool, v_pool, k_scale, v_scale = _write_kv_lines(
+        k_pool, v_pool, k_scale, v_scale, layer, phys, off, k, v, qmax
+    )
+    k_l, v_l = _layer_of(k_pool, layer), _layer_of(v_pool, layer)
     if qmax is not None:
-        from ..serve.kv_quant import quant_line_write
-
-        k_pool, k_scale = quant_line_write(k_pool, k_scale, phys, off, k,
-                                           qmax)
-        v_pool, v_scale = quant_line_write(v_pool, v_scale, phys, off, v,
-                                           qmax)
+        k_virt = _pk.dequant_pages(
+            k_l, _layer_of(k_scale, layer), page_table, q.dtype
+        )
+        v_virt = _pk.dequant_pages(
+            v_l, _layer_of(v_scale, layer), page_table, q.dtype
+        )
     else:
-        k_pool = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        v_pool = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
-    if qmax is not None:
-        k_virt = _pk.dequant_pages(k_pool, k_scale, page_table, q.dtype)
-        v_virt = _pk.dequant_pages(v_pool, v_scale, page_table, q.dtype)
-    else:
-        k_virt = _pk.gather_pages(k_pool, page_table)
-        v_virt = _pk.gather_pages(v_pool, page_table)
+        k_virt = _pk.gather_pages(k_l, page_table)
+        v_virt = _pk.gather_pages(v_l, page_table)
     attn = _attend_paged_xla(cfg, q, k_virt, v_virt, bias, mask)
     attn = _mm_reduced(attn, p["wo"], reduce_fn)
     if cfg.out_bias:
@@ -1302,55 +1340,34 @@ def serve_step_paged(
     n = cfg.num_hidden_layers
     if num_layers is not None:
         n = min(num_layers, n)
-    sliced = n < cfg.num_hidden_layers
-    layers = (
-        jax.tree.map(lambda a: a[:n], params["layers"])
-        if sliced else params["layers"]
-    )
-
+    qmax, scales = None, (None, None)
     if kv_quant is not None:
         from ..serve.kv_quant import resolve_spec
 
         qmax = resolve_spec(kv_quant).qmax
+        scales = (cache["k_scale"], cache["v_scale"])
 
-        def scan_body_q(h, xs):
-            p_l, kc, vc, ks, vs = xs
-            h, kc, vc, ks, vs = serve_block_paged(
-                cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
-                page_table, kernels, ks, vs, qmax,
-                fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
-            )
-            return h, (kc, vc, ks, vs)
+    # The stacked pools are the loop's CARRY, updated in place: a layer
+    # addresses its own pages inside them (serve_block_paged, ``layer``).
+    # Scanned in as xs and out as ys they are two buffers: a copy of the
+    # whole pool a step, a slice out and a write-back of every layer
+    # (tests/test_chip_compile.py holds the compiled step to this).
+    def scan_body(carry, l):
+        h, kc, vc, ks, vs = carry
+        p_l = jax.tree.map(lambda a: _layer_of(a, l), params["layers"])
+        return serve_block_paged(
+            cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
+            page_table, kernels, ks, vs, qmax,
+            fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
+            layer=l,
+        ), None
 
-        x, (k_new, v_new, ks_new, vs_new) = lax.scan(
-            scan_body_q, x,
-            (layers, cache["k"][:n], cache["v"][:n],
-             cache["k_scale"][:n], cache["v_scale"][:n]),
-        )
-        if sliced:
-            k_new = jnp.concatenate([k_new, cache["k"][n:]], axis=0)
-            v_new = jnp.concatenate([v_new, cache["v"][n:]], axis=0)
-            ks_new = jnp.concatenate([ks_new, cache["k_scale"][n:]], axis=0)
-            vs_new = jnp.concatenate([vs_new, cache["v_scale"][n:]], axis=0)
-        new_cache = {"k": k_new, "v": v_new,
-                     "k_scale": ks_new, "v_scale": vs_new}
-    else:
-        def scan_body(h, xs):
-            p_l, kc, vc = xs
-            h, kc, vc, _, _ = serve_block_paged(
-                cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
-                page_table, kernels,
-                fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
-            )
-            return h, (kc, vc)
-
-        x, (k_new, v_new) = lax.scan(
-            scan_body, x, (layers, cache["k"][:n], cache["v"][:n])
-        )
-        if sliced:
-            k_new = jnp.concatenate([k_new, cache["k"][n:]], axis=0)
-            v_new = jnp.concatenate([v_new, cache["v"][n:]], axis=0)
-        new_cache = {"k": k_new, "v": v_new}
+    (x, k_new, v_new, *scales), _ = lax.scan(
+        scan_body, (x, cache["k"], cache["v"], *scales), jnp.arange(n)
+    )
+    new_cache = {"k": k_new, "v": v_new}
+    if qmax is not None:
+        new_cache["k_scale"], new_cache["v_scale"] = scales
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
     if not all_logits:
         x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)

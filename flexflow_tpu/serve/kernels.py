@@ -820,6 +820,7 @@ def ragged_paged_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
     v_scale: Optional[jnp.ndarray] = None,
+    row_offset=None,          # int32 scalar: pool row of table entry 0
 ) -> jnp.ndarray:
     """:func:`_ragged_paged_attention` placed on the ambient mesh. The
     compiler cannot partition a Mosaic kernel ("wrap the call in a
@@ -831,19 +832,25 @@ def ragged_paged_attention(
 
     from ..core.mesh import MODEL_AXIS, shard_map_unchecked
 
-    def body(q, k_pool, v_pool, page_table, mask, k_scale=None,
-             v_scale=None):
+    optional = []  # names of the operands after the five every call has
+
+    def body(q, k_pool, v_pool, page_table, mask, *rest):
         return _ragged_paged_attention(
             q, k_pool, v_pool, page_table, mask,
-            scale=scale, k_scale=k_scale, v_scale=v_scale,
+            scale=scale, **dict(zip(optional, rest)),
         )
 
     heads = P(None, None, MODEL_AXIS, None)
     operands = [q, k_pool, v_pool, page_table, mask]
     in_specs = [heads, heads, heads, P(), P()]
     if k_scale is not None:
+        optional += ["k_scale", "v_scale"]
         operands += [k_scale, v_scale]
         in_specs += [P(None, MODEL_AXIS), P(None, MODEL_AXIS)]
+    if row_offset is not None:
+        optional.append("row_offset")
+        operands.append(jnp.asarray(row_offset, jnp.int32))
+        in_specs.append(P())
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else mesh.shape.get(MODEL_AXIS, 1)
     if tp == 1 or k_pool.shape[2] % tp:
@@ -863,6 +870,7 @@ def _ragged_paged_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
     v_scale: Optional[jnp.ndarray] = None,
+    row_offset=None,          # int32 scalar: pool row of table entry 0
 ) -> jnp.ndarray:
     """Fused ragged paged attention: grid (request, logical page); the
     K/V BlockSpec index maps read the scalar-prefetched page table so
@@ -874,7 +882,13 @@ def _ragged_paged_attention(
     packed int4 nibbles when the pool's trailing dim is dk/2) and the
     same index maps additionally DMA each page's per-KV-head scales;
     dequant — and, packed, the nibble unpack — happens in VMEM so the
-    full-precision cache never exists in HBM. Returns (R, C, H, dk)."""
+    full-precision cache never exists in HBM. Returns (R, C, H, dk).
+
+    ``row_offset`` is a second prefetched scalar the page index maps add
+    to every table entry: the pools (and scales) may then be the
+    (L*(P+1), ...) view of every layer's pages with layer l's at rows
+    ``l*(P+1)`` on — how the serving step's layer loop reads its carried
+    pool without slicing a layer out (models/transformer.py)."""
     R, C, H, dk = q.shape
     _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
     NP = page_table.shape[1]
@@ -883,38 +897,44 @@ def _ragged_paged_attention(
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
     qg = q.reshape(R, C, KV, G, dk)
     grid = (R, NP)
+    prefetch = [page_table.astype(jnp.int32)]
+    if row_offset is not None:
+        prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
+
+    def page(r, p, pt, *base):
+        # the paged gather: block row = page_table[r, p] (+ row_offset)
+        row = pt[r, p] + base[0][0] if base else pt[r, p]
+        return (row, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, C, KV, G, dk),
-                     lambda r, p, pt: (r, 0, 0, 0, 0)),
-        # the paged gather: block row = page_table[r, p]
-        pl.BlockSpec((1, ps, KV, dkp),
-                     lambda r, p, pt: (pt[r, p], 0, 0, 0)),
-        pl.BlockSpec((1, ps, KV, dkp),
-                     lambda r, p, pt: (pt[r, p], 0, 0, 0)),
+        pl.BlockSpec((1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)),
+        pl.BlockSpec((1, ps, KV, dkp), page),
+        pl.BlockSpec((1, ps, KV, dkp), page),
     ]
     operands = [qg, k_pool, v_pool]
-    kernel = _build_ragged_paged_kernel(
+    body = _build_ragged_paged_kernel(
         quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack
     )
+
+    def kernel(*refs):  # the body knows one prefetched ref, the table
+        body(refs[0], *refs[len(prefetch):])
+
     if k_scale is not None:
         # per-page scales as (P+1, KV, 1, 1): the block hands the body a
         # (KV, 1, 1) value that broadcasts over the (KV, C*G, ps) scores
         # as it is — Mosaic has no relayout from a (1, KV) lane vector
         # to that leading dim ("unsupported shape cast")
-        scale_spec = pl.BlockSpec(
-            (1, KV, 1, 1), lambda r, p, pt: (pt[r, p], 0, 0, 0)
-        )
+        scale_spec = pl.BlockSpec((1, KV, 1, 1), page)
         in_specs += [scale_spec, scale_spec]
         operands += [
             k_scale.astype(jnp.float32)[:, :, None, None],
             v_scale.astype(jnp.float32)[:, :, None, None],
         ]
-    in_specs.append(pl.BlockSpec((1, C, ps), lambda r, p, pt: (r, 0, p)))
+    in_specs.append(pl.BlockSpec((1, C, ps), lambda r, p, *_: (r, 0, p)))
     operands.append(mask)
     out_shape = jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype)
     out_spec = pl.BlockSpec(
-        (1, C, KV, G, dk), lambda r, p, pt: (r, 0, 0, 0, 0)
+        (1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)
     )
     scratch = [
         pltpu.VMEM((C, KV, G, dk), jnp.float32),
@@ -926,7 +946,7 @@ def _ragged_paged_attention(
         kernel,
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -941,7 +961,7 @@ def _ragged_paged_attention(
         name=f"ff_ragged_paged_c{C}"
              + _quant_suffix(k_scale is not None, pack),
         interpret=_interpret(),
-    )(page_table.astype(jnp.int32), *operands)
+    )(*prefetch, *operands)
     return out.reshape(R, C, H, dk)
 
 
@@ -1239,6 +1259,7 @@ def fused_rope_paged_attention(
     k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
     v_scale: Optional[jnp.ndarray] = None,
     qmax: Optional[float] = None,
+    row_offset=None,          # int32 scalar: pool row of table entry 0
 ):
     """Megakernel decode-step prologue fused into ragged paged
     attention: one ``pallas_call`` applies RoPE to Q/K, commits the
@@ -1262,10 +1283,18 @@ def fused_rope_paged_attention(
     Intended for decode / small mixed chunks: the per-line commit
     unrolls over C, and every page in a row's table is written back
     (identity for untouched pages) — decode (C=1) is the case whose
-    dispatch and HBM round-trips this removes."""
+    dispatch and HBM round-trips this removes.
+
+    ``row_offset`` shifts every table entry, as in
+    :func:`_ragged_paged_attention`: the pools may be the view of every
+    layer's pages, written in place through the aliased outputs. Here
+    the table itself is shifted — the body reads only ``logical`` and
+    ``off``, the table serves the index maps alone."""
     R, C, H, dk = q.shape
     _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
     NP = page_table.shape[1]
+    if row_offset is not None:
+        page_table = page_table + row_offset
     G = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
     quant = qmax is not None

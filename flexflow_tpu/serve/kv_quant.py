@@ -151,6 +151,7 @@ def quant_line_write(
     off: jnp.ndarray,    # (R, C) int32 in-page offset per new line
     vals: jnp.ndarray,   # (R, C, KV, dk) full-precision lines to commit
     qmax: float,
+    layer=None,          # int32 scalar: kq/scale are (L, P+1, ...) stacks
 ):
     """Commit full-precision K/V lines into a quantized page pool
     (the quantized twin of ``pool.at[phys, off].set(...)``) — running
@@ -169,8 +170,18 @@ def quant_line_write(
     touched pages' nibbles, requantizes on code VALUES, and repacks —
     arithmetically identical to the int8 path per code, so every
     determinism guarantee above carries over unchanged.
+
+    With ``layer`` the pool and scales are every layer's, stacked, and
+    the commit touches that layer's pages inside them — the same values
+    at ``[layer, page]`` as the per-layer call writes at ``[page]``, with
+    no layer sliced out (the serving step's layer loop carries the
+    stack; models/transformer.py).
     """
-    P1, ps, KV, dkp = kq.shape
+    at = () if layer is None else (layer,)  # index prefix of the layer
+    scales = scale
+    if at:
+        scale = jax.lax.dynamic_index_in_dim(scales, layer, keepdims=False)
+    P1, ps, KV, dkp = kq.shape[len(at):]
     R, C = phys.shape
     pack = vals.shape[-1] // dkp  # 1 (int8) or 2 (packed int4 nibbles)
     vf = vals.astype(jnp.float32)
@@ -204,22 +215,24 @@ def quant_line_write(
             old[pages] / jnp.maximum(new[pages], 1e-30),
             0.0,
         )                                               # (R*C, KV)
-        content = _codes(kq[pages])                     # (R*C, ps, KV, dk)
+        content = _codes(kq[at + (pages,)])             # (R*C, ps, KV, dk)
         requant = jnp.round(content * ratio[:, None, :, None])
-        kq = kq.at[pages].set(_store(requant))
+        kq = kq.at[at + (pages,)].set(_store(requant))
     else:
         ratio = jnp.where(
             new > 0.0, old / jnp.maximum(new, 1e-30), 0.0
         )                                               # (P1, KV)
-        requant = jnp.round(_codes(kq) * ratio[:, None, :, None])
-        kq = _store(requant)
+        requant = jnp.round(
+            _codes(kq[layer] if at else kq) * ratio[:, None, :, None]
+        )
+        kq = kq.at[layer].set(_store(requant)) if at else _store(requant)
 
     # quantize the new lines at their page's (final) scale and scatter
     s_line = new[phys]                                  # (R, C, KV)
     q = jnp.round(vf / jnp.maximum(s_line[..., None], 1e-30))
     q = jnp.clip(q, -qmax, qmax)
-    kq = kq.at[phys, off].set(_store(q))
-    return kq, new
+    kq = kq.at[at + (phys, off)].set(_store(q))
+    return kq, scales.at[layer].set(new) if at else new
 
 
 def quant_commit_lines(

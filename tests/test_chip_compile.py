@@ -11,6 +11,7 @@ only depth is cut. A compile that passes is not a chip run:
 ``chip_smoke.py`` is the run.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,8 +74,8 @@ def _on(tree, sds):
     return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     return compiled, compiled.as_text()
 
 
@@ -135,19 +136,48 @@ def _step(cfg, **kw):
     return step
 
 
+def _assert_pool_in_place(compiled, text, pool):
+    """The donated pool is the layer loop's carry, updated in place: the
+    program copies no whole pool and its temporaries are less than one.
+    (As scanned inputs and outputs the pools were two buffers: two
+    ``copy`` of a whole pool a step, a slice and a write-back a layer,
+    a second pool among the temporaries; PERF.md, PR 28.)"""
+    dims = ",".join(map(str, pool.shape))
+    assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool.size * pool.dtype.itemsize
+
+
 @pytest.mark.parametrize("C", [1, 128])
 def test_mistral_paged_pallas_step_compiles(chip, C):
     """The step program chip_smoke.py runs: published widths, 2 layers,
-    decode (C=1) and the mixed step (C=128)."""
+    decode (C=1) and the mixed step (C=128), the pool donated as the
+    engine donates it."""
     cfg = mistral.mistral_7b(dtype=jnp.bfloat16, num_hidden_layers=2)
-    compiled, text = _compile(
-        _step(cfg, kernels="pallas"), *_step_args(chip, cfg, C)
-    )
+    args = _step_args(chip, cfg, C)
+    compiled, text = _compile(_step(cfg, kernels="pallas"), *args, donate=(1,))
     assert "tpu_custom_call" in text
     assert f"%ff_ragged_paged_c{C}" in text  # inside the layer scan too
     # weights + pool + temporaries of this cut fit one 16 GB chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    _assert_pool_in_place(compiled, text, args[1]["k"])
+
+
+@pytest.mark.parametrize("C", [1, 128])
+@pytest.mark.parametrize("arm", [
+    {"kv_quant": "int8"}, {"fused_rope": True}, {"num_layers": 2},
+], ids=lambda arm: next(iter(arm)))
+def test_mistral_paged_step_arms_keep_pool_in_place(chip, C, arm):
+    """The arms no benchmark cell runs address their layer inside the
+    same carry: quantized pool, in-kernel RoPE and KV write through the
+    aliased pool outputs, early-exit draft (3 layers)."""
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16, num_hidden_layers=3)
+    args = _step_args(chip, cfg, C, arm.get("kv_quant"))
+    compiled, text = _compile(
+        _step(cfg, kernels="pallas", **arm), *args, donate=(1,)
+    )
+    _assert_pool_in_place(compiled, text, args[1]["k"])
 
 
 # --- kernels repaired in PR 23 (refused by the chip's compiler before) ---

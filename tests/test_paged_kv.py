@@ -472,3 +472,203 @@ class TestRaggedKernel:
                 for o in rm.generate(prompts, max_new_tokens=8)
             ]
         assert outs["pallas"] == outs["xla"]
+
+
+# ---------------------------------------------------------------------------
+# the layer loop carries the stacked pools in place (models/transformer.py)
+
+
+_LOOP_ARMS = {
+    "bf16": {},
+    "int8": {"kv_quant": "int8"},
+    "early_exit": {"num_layers": 2},
+    "sliding_window": {},
+    "fused_rope": {"fused_rope": True},
+}
+
+
+def _loop_case(arm, C):
+    """A generic-decoder step's arguments at a tiny size: three layers,
+    four slots with 3/17/9/30 lines already cached in pages drawn out of
+    order, and a pool of noise, so an untouched row that moved shows."""
+    from flexflow_tpu.models import transformer as T
+
+    cfg = T.DecoderConfig(
+        vocab_size=97, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=256, dtype=jnp.bfloat16,
+        sliding_window=24 if arm == "sliding_window" else 0,
+    )
+    R, ps, NP, pages = 4, 8, 6, 20
+    rng = np.random.default_rng(7)
+    params = T.init_params(jax.random.PRNGKey(1), cfg)
+    cache = T.init_paged_kv_cache(
+        cfg, pages, ps, cfg.dtype, kv_quant=_LOOP_ARMS[arm].get("kv_quant")
+    )
+    for name, a in cache.items():
+        if a.dtype == jnp.int8:
+            cache[name] = jnp.asarray(rng.integers(-100, 100, a.shape), a.dtype)
+        elif "scale" in name:
+            cache[name] = jnp.asarray(rng.uniform(0.01, 0.05, a.shape), a.dtype)
+        elif name != "pos":
+            cache[name] = jnp.asarray(rng.normal(size=a.shape), a.dtype)
+    ctx = np.array([3, 17, 9, 30])
+    table = np.full((R, NP), pages, np.int32)  # unallocated: the scratch page
+    free = iter(rng.permutation(pages))
+    for r in range(R):
+        for j in range(-(-(ctx[r] + C) // ps)):
+            table[r, j] = next(free)
+    if "pos" in cache:
+        pos = np.zeros(cache["pos"].shape, np.int32)
+        for r in range(R):
+            t = np.arange(ctx[r])
+            pos[table[r, t // ps], t % ps] = t
+        cache["pos"] = jnp.asarray(pos)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (R, C)), jnp.int32)
+    positions = jnp.asarray(ctx[:, None] + np.arange(C)[None], jnp.int32)
+    idx = jnp.full((R,), C - 1, jnp.int32)
+    return T, cfg, params, cache, tokens, positions, idx, jnp.asarray(table)
+
+
+def _sliced_pool_step(T, cfg, params, cache, tokens, positions, idx, table, *,
+                      cache_len, kernels, kv_quant=None, fused_rope=False,
+                      num_layers=None):
+    """``serve_step_paged`` as it was before the pools became the loop's
+    carry: ``serve_block_paged`` per layer on that layer's SLICE of the
+    pools, the slices scanned in and stacked back out. (A scan, not a
+    Python loop: unrolled, the CPU compiler fuses bf16 roundings another
+    way and nothing is bitwise.)"""
+    from flexflow_tpu.serve.kv_quant import resolve_spec
+
+    n = num_layers or cfg.num_hidden_layers
+    qmax = resolve_spec(kv_quant).qmax if kv_quant else None
+    names = ("k", "v") + (("k_scale", "v_scale") if kv_quant else ())
+    x = T._embed_in(cfg, params, tokens, positions)
+    rope = T.rope_freqs(cfg, positions)
+    phys, off, mask, bias, pos_pool = T._paged_serve_context(
+        cfg, cache, positions, positions, None, table, cache_len
+    )
+
+    def body(h, xs):
+        p_l, pools = xs
+        pools = list(pools) + [None, None]
+        h, *pools = T.serve_block_paged(
+            cfg, p_l, h, rope, bias, mask, pools[0], pools[1], phys, off,
+            table, kernels, pools[2], pools[3], qmax, fused_rope=fused_rope,
+            logical=positions // cache["k"].shape[2],
+        )
+        return h, tuple(pools[:len(names)])
+
+    x, pools = jax.lax.scan(
+        body, x,
+        (jax.tree.map(lambda a: a[:n], params["layers"]),
+         tuple(cache[name][:n] for name in names)),
+    )
+    new_cache = {
+        name: jnp.concatenate([pool, cache[name][n:]])
+        for name, pool in zip(names, pools)
+    }
+    if pos_pool is not None:
+        new_cache["pos"] = pos_pool
+    x = T._norm(cfg, x, params["final_norm_scale"],
+                params.get("final_norm_bias"))
+    x = jnp.take_along_axis(x, idx[:, None, None], axis=1)
+    return T._lm_logits(cfg, params, x)[:, 0], new_cache
+
+
+@pytest.mark.parametrize("C", [1, 8])
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+@pytest.mark.parametrize("arm", list(_LOOP_ARMS))
+def test_layer_loop_matches_sliced_pools(arm, kernels, C):
+    """The step's layer loop carries the stacked pools and each layer
+    addresses its own pages inside them; logits AND pools must equal the
+    per-layer walk on sliced pools: bitwise on the XLA path, to the
+    kernel tests' tolerance on the interpret-mode Pallas path. The
+    early-exit draft leaves the layers it skips untouched."""
+    T, cfg, params, cache, *args = _loop_case(arm, C)
+    kw = dict(cache_len=6 * 8, kernels=kernels, **_LOOP_ARMS[arm])
+    got_logits, got = jax.jit(
+        lambda p, c, *a: T.serve_step_paged(
+            p, c, *a[:3], None, None, a[3], cfg=cfg, **kw)
+    )(params, cache, *args)
+    want_logits, want = jax.jit(
+        lambda p, c, *a: _sliced_pool_step(T, cfg, p, c, *a, **kw)
+    )(params, cache, *args)
+
+    def same(a, b):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        a, b = (np.asarray(t.astype(jnp.float32)) for t in (a, b))
+        if kernels == "xla":
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, atol=1e-2, rtol=1e-2)
+
+    same(got_logits, want_logits)
+    assert set(got) == set(want) == set(cache)
+    for name in cache:
+        same(got[name], want[name])
+        assert got[name].dtype == cache[name].dtype
+    if arm == "early_exit":
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(got[name][2:].astype(jnp.float32)),
+                np.asarray(cache[name][2:].astype(jnp.float32)),
+            )
+
+
+@pytest.mark.parametrize("kernel", ["plain", "int8", "fused_rope"])
+def test_ragged_kernel_row_offset_matches_sliced_pool(kernel):
+    """A kernel wrapper given every layer's pages as one (L*(P+1), ...)
+    view and a layer's row offset reads (and, fused, writes) what the
+    same call on that layer's slice does."""
+    from flexflow_tpu.serve import kernels as K
+
+    rng = np.random.default_rng(3)
+    L, layer, C = 3, 2, 4
+    R, H, KV, dk, P1, ps, NP = 3, 8, 4, 16, 9, 16, 4
+    q = jnp.asarray(rng.normal(size=(R, C, H, dk)), jnp.float32)
+    pt = jnp.asarray(rng.integers(0, P1, size=(R, NP)), jnp.int32)
+    mask = jnp.asarray(rng.random(size=(R, C, NP * ps)) < 0.4)
+    mask = mask.at[:, :, 0].set(True)
+    shape = (L, P1, ps, KV, dk)
+    scales = {}
+    if kernel == "int8":
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+                  for _ in range(2))
+        scales = {
+            name: jnp.asarray(rng.random(size=(L, P1, KV)) * 0.02, jnp.float32)
+            for name in ("k_scale", "v_scale")
+        }
+    else:
+        kp, vp = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                  for _ in range(2))
+
+    def rows(a):
+        return a.reshape((-1,) + a.shape[2:])
+
+    def call(kp, vp, scales, **kw):
+        if kernel != "fused_rope":
+            return (K.ragged_paged_attention(q, kp, vp, pt, mask, **scales,
+                                             **kw),)
+        k_new, v_new = (jnp.asarray(rng_new.normal(size=(R, C, KV, dk)),
+                                    jnp.float32) for _ in range(2))
+        # each row's C new lines land in its own logical page 1
+        pt1 = pt.at[:, 1].set(jnp.arange(R))
+        logical = jnp.ones((R, C), jnp.int32)
+        off = jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32), (R, C))
+        return K.fused_rope_paged_attention(
+            q, k_new, v_new, None, None, kp, vp, pt1, logical, off, mask,
+            **kw)[:3]
+
+    rng_new = np.random.default_rng(5)
+    want = call(kp[layer], vp[layer],
+                {name: s[layer] for name, s in scales.items()})
+    rng_new = np.random.default_rng(5)
+    got = call(rows(kp), rows(vp),
+               {name: rows(s) for name, s in scales.items()},
+               row_offset=layer * P1)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    for pool, stack, layer_pool in zip(got[1:], (kp, vp), want[1:]):
+        pool = np.asarray(pool).reshape(shape)
+        np.testing.assert_array_equal(pool[layer], np.asarray(layer_pool))
+        np.testing.assert_array_equal(pool[:layer], np.asarray(stack[:layer]))
