@@ -165,6 +165,18 @@ class SchedulerStats:
     # cp_shards/shard_balance.
     whole_step_fallbacks: int = 0
     whole_step_vmem_est: int = 0
+    # A family with per-slot state beside the page pool (the engine's
+    # model declares ``SLOT_STATE``; models/minicpm_sala.py): the bytes
+    # of that state (a gauge: held at any context length), the rows of
+    # pipelined steps that held a real position, those of them that
+    # started at position 0 and so from a zero state (a new request, a
+    # preempted one's recompute), and those whose last position lay at
+    # or past the family's ``dense_len``, so that the step took the
+    # block choice.
+    slot_state_bytes: int = 0
+    real_rows: int = 0
+    state_resets: int = 0
+    sparse_rows: int = 0
 
     def record_step(
         self,
@@ -189,6 +201,15 @@ class SchedulerStats:
         self.decode_tokens += int(decode_tokens)
         if num_slots > 0:
             self.occupancy_sum += active_slots / num_slots
+
+    def note_rows(self, first, count, dense_len: int) -> None:
+        """Count one step's rows for a family with per-slot state:
+        ``first`` (R,) each row's first position, ``count`` (R,) its
+        real positions (0: the row is padding)."""
+        rows = count > 0
+        self.real_rows += int(rows.sum())
+        self.state_resets += int((rows & (first == 0)).sum())
+        self.sparse_rows += int((rows & (first + count > dense_len)).sum())
 
     def note_decode_step_ms(self, ms: float) -> None:
         """Record one decode-step wall sample (bounded reservoir)."""

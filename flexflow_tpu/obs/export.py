@@ -111,10 +111,12 @@ SCHED_COUNTERS = frozenset({
     "spec_rounds", "spec_drafted", "spec_accepted", "spec_resizes",
     "verify_skipped_rounds", "spec_reprobes",
     "ring_steps", "compiles", "retraces", "whole_step_fallbacks",
+    "real_rows", "state_resets", "sparse_rows",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({
     "host_bytes", "cp_shards", "shard_balance", "whole_step_vmem_est",
+    "slot_state_bytes",
 })
 #: SchedulerStats fields NOT exported verbatim — each maps to the
 #: derived snapshot() gauge that replaces it on the scrape surface.

@@ -980,6 +980,11 @@ class InferenceEngine:
                     "kv_layout='paged' is not composed with pipeline "
                     "parallelism yet — use kv_layout='dense' with pipe>1"
                 )
+        # A family whose cache holds more than pages (per-slot recurrent
+        # state) says here which of the options above it cannot serve yet
+        validate = getattr(model, "validate_serving", None)
+        if validate is not None:
+            validate(cfg, self.serving, self.mesh)
         self.cache = self._alloc_cache()
         if self.whole_step_on:
             self._whole_step_vmem_gate()
@@ -1195,6 +1200,12 @@ class InferenceEngine:
             init_kw = dict(kv_quant=sc.kv_quant)
             if extra_rows:
                 init_kw["extra_rows"] = extra_rows
+            if getattr(self.model, "SLOT_STATE", ()):
+                # per-slot state beside the pool (a recurrent state, a
+                # per-position index): the family sizes it by slots and
+                # by the longest context a slot may hold
+                init_kw.update(num_slots=sc.max_requests_per_batch,
+                               cache_len=sc.cache_len)
             init = functools.partial(
                 self.model.init_paged_kv_cache,
                 self.cfg,
@@ -1259,15 +1270,24 @@ class InferenceEngine:
                 total += int(self.cache[name].nbytes)
         return total / lines
 
+    def slot_state_bytes(self) -> int:
+        """Bytes of the cache held per SLOT and not per page (a family's
+        ``SLOT_STATE`` entries: recurrent states, compressed keys):
+        constant in the context length, held whether a slot is in use
+        or not. 0 for a family whose cache is pages alone."""
+        return sum(int(self.cache[name].nbytes)
+                   for name in getattr(self.model, "SLOT_STATE", ()))
+
     def kv_allocated_bytes(self) -> int:
         """Bytes of KV HBM backing ALLOCATED pages (paged layout): the
-        footprint proportional-to-live-tokens claim, measured."""
+        footprint proportional-to-live-tokens claim, measured. Per-slot
+        state counts whole: it is held at any length."""
         if not self.paged:
             return self.kv_cache_bytes()
         return int(
             self.pager.used_pages * self.serving.page_size
             * self.kv_bytes_per_line()
-        )
+        ) + self.slot_state_bytes()
 
     @property
     def scratch_pos(self) -> int:

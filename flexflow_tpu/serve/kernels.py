@@ -538,6 +538,7 @@ def _build_ragged_paged_kernel(
     qmax: float = 0.0,
     has_rope: bool = True,
     pack: int = 1,
+    group_mask: bool = False,
 ):
     """ONE builder for every Pallas variant of the ragged paged kernel
     (see the module-docstring matrix): ``quant`` folds the per-page
@@ -551,7 +552,23 @@ def _build_ragged_paged_kernel(
     + KV page write through aliased pool outputs, packing through the
     same nibble layout). The quant, pack and fused axes compose, so
     the kernel variants share one attention body instead of
-    hand-maintained copies."""
+    hand-maintained copies. ``group_mask`` (block-sparse attention,
+    :func:`sparse_paged_attention`): the mask block is (KV*C, ps), one
+    (C, ps) mask a KV group, for layers whose groups attend different
+    keys."""
+
+    def _masked(mask, x, fill):
+        # x (C, KV, G, ps). One mask: (C, ps), every head alike. A mask
+        # a group: a list of KV (C, ps) masks, each loaded from its own
+        # rows of the (KV*C, ps) block and applied to its own group, so
+        # Mosaic sees only the mask layout the one-mask path has (a
+        # (KV, C, ps) block, or a one-row slice of a loaded i1 vector,
+        # is a "layout with implicit dimension" at C=1)
+        if not group_mask:
+            return jnp.where(mask[:, None, None, :], x, fill)
+        return jnp.concatenate(
+            [jnp.where(m[:, None, None, :], x[:, kv:kv + 1], fill)
+             for kv, m in enumerate(mask)], axis=1)
 
     def _attend(q, k, v, ks, vs, mask, o_scr, m_scr, l_scr):
         # q (C, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV, 1, 1)
@@ -569,10 +586,10 @@ def _build_ragged_paged_kernel(
         else:
             scores = scores * scale
         scores = scores.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
-        scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
+        scores = _masked(mask, scores, NEG_INF)
         m_new = jnp.maximum(m_scr[:], scores.max(axis=-1))
         prob = jnp.exp(scores - m_new[..., None])
-        prob = jnp.where(mask[:, None, None, :], prob, 0.0)
+        prob = _masked(mask, prob, 0.0)
         corr = jnp.exp(m_scr[:] - m_new)
         l_scr[:] = l_scr[:] * corr + prob.sum(axis=-1)
         pk = prob.transpose(1, 0, 2, 3).reshape(KV, C * G, -1)
@@ -654,9 +671,15 @@ def _build_ragged_paged_kernel(
         def _():
             _init(o_scr, m_scr, l_scr)
 
-        mask = mask_ref[0]  # (C, ps) — already bounded: S_virt = NP*ps
+        if group_mask:  # one (C, ps) mask a KV group
+            mask = [mask_ref[0, kv * C:(kv + 1) * C]
+                    for kv in range(q_ref.shape[2])]
+            some = functools.reduce(jnp.logical_or, map(jnp.any, mask))
+        else:
+            mask = mask_ref[0]  # (C, ps) — already bounded: S_virt = NP*ps
+            some = jnp.any(mask)
 
-        @pl.when(jnp.any(mask))
+        @pl.when(some)
         def _():
             q = q_ref[0].astype(jnp.float32)
             k = _unpack_codes(k_ref[0], pack).transpose(1, 0, 2)
@@ -871,6 +894,7 @@ def _ragged_paged_attention(
     k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
     v_scale: Optional[jnp.ndarray] = None,
     row_offset=None,          # int32 scalar: pool row of table entry 0
+    group_mask: bool = False,  # mask is (R, KV, C, NP*ps): one a KV group
 ) -> jnp.ndarray:
     """Fused ragged paged attention: grid (request, logical page); the
     K/V BlockSpec index maps read the scalar-prefetched page table so
@@ -913,7 +937,8 @@ def _ragged_paged_attention(
     ]
     operands = [qg, k_pool, v_pool]
     body = _build_ragged_paged_kernel(
-        quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack
+        quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack,
+        group_mask=group_mask,
     )
 
     def kernel(*refs):  # the body knows one prefetched ref, the table
@@ -930,8 +955,9 @@ def _ragged_paged_attention(
             k_scale.astype(jnp.float32)[:, :, None, None],
             v_scale.astype(jnp.float32)[:, :, None, None],
         ]
-    in_specs.append(pl.BlockSpec((1, C, ps), lambda r, p, *_: (r, 0, p)))
-    operands.append(mask)
+    rows = KV * C if group_mask else C  # (R, KV, C, S) as (R, KV*C, S)
+    in_specs.append(pl.BlockSpec((1, rows, ps), lambda r, p, *_: (r, 0, p)))
+    operands.append(mask.reshape(R, rows, -1))
     out_shape = jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype)
     out_spec = pl.BlockSpec(
         (1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)
@@ -958,11 +984,34 @@ def _ragged_paged_attention(
                 C, H, max(dk, ps),
             ),
         ),
-        name=f"ff_ragged_paged_c{C}"
-             + _quant_suffix(k_scale is not None, pack),
+        name=("ff_sparse_paged" if group_mask else "ff_ragged_paged")
+             + f"_c{C}" + _quant_suffix(k_scale is not None, pack),
         interpret=_interpret(),
     )(*prefetch, *operands)
     return out.reshape(R, C, H, dk)
+
+
+def sparse_paged_attention(
+    q: jnp.ndarray,           # (R, C, H, dk)
+    k_pool: jnp.ndarray,      # (P+1, ps, KV, dk)
+    v_pool: jnp.ndarray,
+    page_table: jnp.ndarray,  # (R, NP) int32
+    mask: jnp.ndarray,        # (R, KV, C, NP*ps) bool: a mask a KV group
+    *,
+    row_offset=None,
+) -> jnp.ndarray:
+    """Block-sparse paged attention (``ff_sparse_paged_c<C>``): the
+    ragged paged kernel with one mask a KV group, for layers whose
+    groups each attend their own chosen blocks of the context
+    (models/minicpm_sala.py). A first version: the choice is a mask
+    over the dense paged read. A page no query of the chunk chose in
+    either group is still fetched, and skipped by the body's
+    ``any(mask)`` guard — exact, the page DMAs not saved. One chip:
+    no ``shard_map`` over a ``model`` axis."""
+    return _ragged_paged_attention(
+        q, k_pool, v_pool, page_table, mask, row_offset=row_offset,
+        group_mask=True,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -161,6 +161,10 @@ class RequestManager:
         # input (device feedback instead of a host token).
         self._prev_dispatch_slots: set = set()
         self.stats = SchedulerStats()
+        # per-slot state beside the pool (SchedulerStats.note_rows)
+        self._slot_state = bool(getattr(engine.model, "SLOT_STATE", ()))
+        if self._slot_state:
+            self.stats.slot_state_bytes = engine.slot_state_bytes()
         self._log = get_logger("serve")
         # Observability (flexflow_tpu/obs): request-lifecycle tracing +
         # failure flight recorder. Disabled by default — every EVENT
@@ -1010,6 +1014,9 @@ class RequestManager:
             "decode", active_slots=len(decoding), num_slots=R,
             decode_tokens=len(decoding),
         )
+        if self._slot_state:
+            self.stats.note_rows(positions[:, 0], positions[:, 0] != scratch,
+                                 self.engine.cfg.dense_len)
         tr = self.tracer
         if tr.enabled:
             tr.event("decode_step", rows=len(decoding))
@@ -1121,6 +1128,9 @@ class RequestManager:
             prefill_tokens=spent, decode_tokens=len(decoding),
             budget=C * max(1, len(prefilling)),
         )
+        if self._slot_state:
+            self.stats.note_rows(bc.positions[:, 0], bc.qlens,
+                                 eng.cfg.dense_len)
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,

@@ -555,6 +555,12 @@ class SpecInferManager(RequestManager):
                 "SpecInferManager needs at least one SSM engine (or "
                 "SpecConfig(draft='early_exit') to self-speculate)"
             )
+        for eng in [llm_engine] + self.ssms:
+            # a family with per-slot recurrent state has no rollback for
+            # the tree verify's commit: refused here, by name
+            validate = getattr(eng.model, "validate_serving", None)
+            if validate is not None:
+                validate(eng.cfg, eng.serving, eng.mesh, specinfer=True)
         super().__init__(llm_engine, tokenizer, eos_token_id, seed, output_file)
         for ssm_engine in self.ssms:
             assert (

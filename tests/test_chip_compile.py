@@ -265,3 +265,47 @@ def test_flash_attention_forward_and_backward_compile(chip):
 
     _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
     assert text.count("tpu_custom_call") == 3  # forward, dK/dV, dQ
+
+
+# --- the hybrid family: two kinds of layer, per-slot state beside the pool ---
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_minicpm_sala_hybrid_step_compiles_in_place(chip, C):
+    """models/minicpm_sala.py at published widths, five layers (sparse,
+    two lightning, two sparse: every kind of run and transition), the
+    benchmark cell's 4 slots of 146 pages: both attention kernels are in
+    the program by name, and the loop's carry is updated in place: no
+    copy of a K/V pool, of the lightning states or of the compressed
+    keys, and temporaries under one pool (a conditional that took the
+    compressed keys as an operand copied all of them, twice a step)."""
+    from flexflow_tpu.models import minicpm_sala as sala
+
+    S, L = sala.SPARSE, sala.LIGHTNING
+    cfg = sala.config(num_hidden_layers=5, mixer_types=(S, L, L, S, S),
+                      dtype=jnp.bfloat16)
+    slots, pages, cache_len = 4, 146, 18624
+    params = _on(jax.eval_shape(
+        functools.partial(sala.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        sala.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        num_slots=slots, cache_len=cache_len)), chip)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return sala.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas")
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    assert f"%ff_ragged_paged_c{C}" in text    # no row above dense_len
+    assert f"%ff_sparse_paged_c{C}" in text    # some row above it
+    for name in ("k", "v", "state", "kbar"):
+        dims = ",".join(map(str, cache[name].shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), name
+    pool = cache["k"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool.size * pool.dtype.itemsize
