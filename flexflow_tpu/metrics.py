@@ -14,6 +14,7 @@ from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 ACCURACY = "accuracy"
 CATEGORICAL_CROSSENTROPY = "categorical_crossentropy"
@@ -177,6 +178,14 @@ class SchedulerStats:
     real_rows: int = 0
     state_resets: int = 0
     sparse_rows: int = 0
+    # The ragged paged attention kernel's grid over the pipelined steps
+    # of a paged engine (note_attn_steps), a layer's call: grid steps
+    # (rows x logical pages), those of them that hold a real query and
+    # a key it may see (the kernel computes these and skips the rest),
+    # and those of the live ones on rows that take the narrow body.
+    attn_steps_grid: int = 0
+    attn_steps_live: int = 0
+    attn_steps_narrow: int = 0
 
     def record_step(
         self,
@@ -210,6 +219,27 @@ class SchedulerStats:
         self.real_rows += int(rows.sum())
         self.state_resets += int((rows & (first == 0)).sum())
         self.sparse_rows += int((rows & (first + count > dense_len)).sum())
+
+    def note_attn_steps(self, first, count, page_size: int, num_pages: int,
+                        narrow: int, window: int = 0) -> None:
+        """Count one step's grid of the ragged paged kernel by the
+        kernel's own rule (serve/kernels._build_ragged_paged_kernel)
+        under the causal mask: ``first`` (R,) each row's first
+        position, ``count`` (R,) its real queries (0: a padding row),
+        ``num_pages`` the table's logical pages a row, ``narrow`` the
+        chunk's ``narrow_query_extent``, ``window`` the model's
+        sliding window (0: none). A page is live when some real query
+        of the row may see a key of it: the pages from the first
+        query's oldest visible key to the last query's own. A block
+        choice on top of the causal mask (models/minicpm_sala.py) can
+        only skip more."""
+        first, count = np.asarray(first), np.asarray(count)
+        low = np.maximum(first - window + 1, 0) if window else 0
+        high = np.minimum((first + count - 1) // page_size, num_pages - 1)
+        live = np.where(count > 0, high - low // page_size + 1, 0)
+        self.attn_steps_grid += count.size * num_pages
+        self.attn_steps_live += int(live.sum())
+        self.attn_steps_narrow += int(live[count <= narrow].sum())
 
     def note_decode_step_ms(self, ms: float) -> None:
         """Record one decode-step wall sample (bounded reservoir)."""

@@ -42,7 +42,10 @@ engine's ordinary step programs:
   the ragged paged kernel every family runs (``ff_ragged_paged_c<C>``);
   otherwise the choice is computed and applied as a mask a KV group
   over the dense paged read (``ff_sparse_paged_c<C>``,
-  serve/kernels.sparse_paged_attention): exact, nothing saved yet.
+  serve/kernels.sparse_paged_attention): exact, no page fetch saved
+  yet. Both kernels are told each row's real queries (``q_len``), so
+  the padding columns beside a decoding row's one query, which choose
+  every block, keep none of its pages alive.
   The lightning layers are plain XLA: the chunked form for C > 1 (a
   decay-masked ``q k^T`` times ``v``, plus ``q S`` from the carried
   state, then the state's update), the recurrence itself for C = 1;
@@ -537,7 +540,7 @@ def _sparse_mixer(cfg, p, u, k_pool, v_pool, kbar, chosen_last, layer, ctx):
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, None, None, layer)
             fn = _pk.sparse_paged_attention if group_mask else _pk.ragged_paged_attention
             return fn(q, k_rows, v_rows, ctx["page_table"], mask,
-                      row_offset=kw["row_offset"])
+                      row_offset=kw["row_offset"], q_len=ctx["q_len"])
         k_virt = _pk.gather_pages(_layer_of(k_pool, layer), ctx["page_table"])
         v_virt = _pk.gather_pages(_layer_of(v_pool, layer), ctx["page_table"])
         qg = q.reshape(R, C, KV, H // KV, d)
@@ -614,7 +617,7 @@ def serve_step_paged(
     if mask is not None or cache_positions is not None or any(
             v for v in unsupported.values()):
         _no_state_rollback()
-    from ..serve.kernels import paged_serve_mask
+    from ..serve.kernels import paged_serve_mask, real_query_lengths
 
     R, C = tokens.shape
     ps = cache["k"].shape[2]
@@ -623,7 +626,8 @@ def serve_step_paged(
     x = x * jnp.asarray(cfg.scale_emb, x.dtype)
     real = positions < cache_len
     first = positions[:, 0]
-    last = first + jnp.sum(real, axis=1) - 1
+    q_len = real_query_lengths(positions, cache_len)  # real columns lead
+    last = first + q_len - 1
     phys, off = _page_lookup(page_table, positions, ps)
     sparse_row = real[:, 0] & (last >= cfg.dense_len)
     ctx = dict(
@@ -632,6 +636,7 @@ def serve_step_paged(
         kernels=kernels, sparse_row=sparse_row,
         any_sparse=jnp.any(sparse_row),
         last_col=jnp.maximum(last - first, 0),
+        q_len=q_len,
         causal=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
     )
     fresh = real[:, 0] & (first == 0)

@@ -1019,7 +1019,7 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                       phys, off, page_table, kernels: str = "xla",
                       k_scale=None, v_scale=None, qmax=None,
                       *, fused_rope: bool = False, logical=None,
-                      cp_mesh=None, layer=None):
+                      cp_mesh=None, layer=None, q_len=None):
     """Paged twin of :func:`serve_block`: scatter new K/V at the
     table-resolved (page, offset); attend over the virtual cache read
     through the table (``jnp.take`` gather, or the fused ragged paged
@@ -1047,7 +1047,12 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     step is the CPU-parity fallback. On a sequence-sharded mesh
     (``cp_mesh``) the fused prologue joins the RING body instead
     (PR-11's exclusion, lifted — serve/kernels.
-    ring_ragged_paged_attention's ``fused`` mode)."""
+    ring_ragged_paged_attention's ``fused`` mode).
+
+    ``q_len`` (R,): the real queries of each row
+    (serve/kernels.real_query_lengths), for the plain Pallas kernel,
+    which then computes for those alone; the other paths take every
+    column."""
     from ..serve import kernels as _pk
 
     R, C, D = x.shape
@@ -1126,7 +1131,7 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale,
                                                v_scale, layer)
             attn = _pk.ragged_paged_attention(
-                q, k_rows, v_rows, page_table, mask, **kw
+                q, k_rows, v_rows, page_table, mask, q_len=q_len, **kw
             )
     attn = attn.reshape(R, C, -1)
     attn = _mm(attn, p["wo"])
@@ -1330,6 +1335,14 @@ def serve_step_paged(
         )
     if cache_positions is None:
         cache_positions = positions
+    # the causal mask built below follows from the positions, and so do
+    # the real queries of a row; an explicit mask (a token tree) already
+    # leaves its padding columns empty, and every column counts
+    q_len = None
+    if mask is None:
+        from ..serve.kernels import real_query_lengths
+
+        q_len = real_query_lengths(positions, cache_len)
     x = _embed_in(cfg, params, tokens, positions)
     rope = rope_freqs(cfg, positions) if cfg.positions == "rope" else None
     phys, off, mask, bias, pos_pool = _paged_serve_context(
@@ -1359,7 +1372,7 @@ def serve_step_paged(
             cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
             page_table, kernels, ks, vs, qmax,
             fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
-            layer=l,
+            layer=l, q_len=q_len,
         ), None
 
     (x, k_new, v_new, *scales), _ = lax.scan(

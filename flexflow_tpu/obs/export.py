@@ -112,6 +112,7 @@ SCHED_COUNTERS = frozenset({
     "verify_skipped_rounds", "spec_reprobes",
     "ring_steps", "compiles", "retraces", "whole_step_fallbacks",
     "real_rows", "state_resets", "sparse_rows",
+    "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({

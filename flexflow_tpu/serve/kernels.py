@@ -27,9 +27,13 @@ index maps read the scalar-prefetched table to DMA the right physical
 page, so no (R, S) virtual cache is ever materialised in HBM. One
 kernel serves decode (C=1), chunked prefill and tree verify (C>1, any
 mask) — the single ragged kernel for mixed batches the paper argues
-for. :func:`ragged_paged_attention_xla` is the shape-identical
-``jnp.take``-based fallback (via :func:`gather_pages`) used on CPU and
-as the correctness reference.
+for. Told each row's real queries (``q_len``,
+:func:`real_query_lengths`) it works in proportion to them: a padding
+column keeps no page alive, and a row of a few real queries (a decode
+row of a mixed step) is computed at :func:`narrow_query_extent` and
+not at the chunk's width. :func:`ragged_paged_attention_xla` is the
+shape-identical ``jnp.take``-based fallback (via :func:`gather_pages`)
+used on CPU and as the correctness reference.
 
 :func:`fused_rope_paged_attention` — the **megakernel decode step**
 prologue (MPK, "Mega-Kernelizing Tensor Programs", PAPERS.md): RoPE on
@@ -387,6 +391,30 @@ def paged_serve_mask(
     return mask
 
 
+def real_query_lengths(positions: jnp.ndarray, cache_len: int) -> jnp.ndarray:
+    """How many leading columns of each row may hold a real query:
+    positions (R, C) → (R,) int32, one past the last column whose
+    position is not the scratch position (``cache_len``; 0: the row is
+    all padding). Every dispatch fills a row's real columns first, so
+    this is their count; were one ever to leave a gap, the columns up
+    to its last real one count, and no real query is dropped. The
+    ``q_len`` operand of :func:`ragged_paged_attention`, derived here
+    and nowhere else."""
+    cols = jnp.arange(1, positions.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(positions < cache_len, cols, 0), axis=1)
+
+
+def narrow_query_extent(C: int) -> int:
+    """The query extent of the ragged paged kernel's narrow body at
+    chunk ``C``: a row with at most this many real queries (a decode row
+    of a mixed step, the tail of a prompt) is computed at this extent
+    and not at ``C``. One float32 sublane tile; 0 where the chunk is no
+    wider (no narrow body). Static, from ``C`` alone: the host's count of
+    the rows that take it (``SchedulerStats.note_attn_steps``) reads it
+    here too."""
+    return 8 if C > 8 else 0
+
+
 # ---------------------------------------------------------------------------
 # Ragged paged attention (paged KV pool + per-request page table)
 
@@ -555,7 +583,12 @@ def _build_ragged_paged_kernel(
     hand-maintained copies. ``group_mask`` (block-sparse attention,
     :func:`sparse_paged_attention`): the mask block is (KV*C, ps), one
     (C, ps) mask a KV group, for layers whose groups attend different
-    keys."""
+    keys. The plain kernel called with a ``q_len_ref`` (the prefetched
+    per-row count of real queries) works for those alone: columns from
+    ``q_len[r]`` on keep no page alive and come out zero, and a row of
+    at most :func:`narrow_query_extent` real queries runs the same step
+    at that query extent."""
+    narrow = narrow_query_extent(C)
 
     def _masked(mask, x, fill):
         # x (C, KV, G, ps). One mask: (C, ps), every head alike. A mask
@@ -571,49 +604,58 @@ def _build_ragged_paged_kernel(
              for kv, m in enumerate(mask)], axis=1)
 
     def _attend(q, k, v, ks, vs, mask, o_scr, m_scr, l_scr):
-        # q (C, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV, 1, 1)
+        # q (n, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV, 1, 1)
         # f32 (quant only); one batched dot per KV head over the
-        # grouped (KV, C*G, dk) query layout
-        KV, G = q.shape[1], q.shape[2]
-        qkv = q.transpose(1, 0, 2, 3).reshape(KV, C * G, q.shape[-1])
+        # grouped (KV, n*G, dk) query layout. n is the chunk C or the
+        # narrow body's extent: the accumulators' first n rows are
+        # read and written (slices of the loads and stores; Mosaic
+        # refuses a sliced VIEW of a scratch whose minor extent, G, is
+        # under a lane tile)
+        n, KV, G = q.shape[:3]
+        qkv = q.transpose(1, 0, 2, 3).reshape(KV, n * G, q.shape[-1])
         scores = jax.lax.dot_general(
             qkv, k,
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                           # (KV, C*G, ps)
+        )                                           # (KV, n*G, ps)
         if quant:
             scores = scores * (ks * scale)          # dequant K
         else:
             scores = scores * scale
-        scores = scores.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
+        scores = scores.reshape(KV, n, G, -1).transpose(1, 0, 2, 3)
         scores = _masked(mask, scores, NEG_INF)
-        m_new = jnp.maximum(m_scr[:], scores.max(axis=-1))
+        m_new = jnp.maximum(m_scr[:n], scores.max(axis=-1))
         prob = jnp.exp(scores - m_new[..., None])
         prob = _masked(mask, prob, 0.0)
-        corr = jnp.exp(m_scr[:] - m_new)
-        l_scr[:] = l_scr[:] * corr + prob.sum(axis=-1)
-        pk = prob.transpose(1, 0, 2, 3).reshape(KV, C * G, -1)
+        corr = jnp.exp(m_scr[:n] - m_new)
+        l_scr[:n] = l_scr[:n] * corr + prob.sum(axis=-1)
+        pk = prob.transpose(1, 0, 2, 3).reshape(KV, n * G, -1)
         pv = jax.lax.dot_general(
             pk, v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )  # (KV, C*G, dk)
+        )  # (KV, n*G, dk)
         if quant:
             pv = pv * vs                            # dequant V
-        pv = pv.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
-        o_scr[:] = o_scr[:] * corr[..., None] + pv
-        m_scr[:] = m_new
+        pv = pv.reshape(KV, n, G, -1).transpose(1, 0, 2, 3)
+        o_scr[:n] = o_scr[:n] * corr[..., None] + pv
+        m_scr[:n] = m_new
 
-    def _init(o_scr, m_scr, l_scr):
-        o_scr[:] = jnp.zeros_like(o_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    def _init(o_scr, m_scr, l_scr, n=C):
+        o_scr[:n] = jnp.zeros((n,) + o_scr.shape[1:], o_scr.dtype)
+        m_scr[:n] = jnp.full((n,) + m_scr.shape[1:], NEG_INF, m_scr.dtype)
+        l_scr[:n] = jnp.zeros((n,) + l_scr.shape[1:], l_scr.dtype)
 
-    def _finalize(p, out_ref, o_scr, l_scr):
+    def _finalize(p, out_ref, o_scr, l_scr, n=C):
+        # the rows past a narrow body's n never attended and are what
+        # _init and this divide would give them: zero
         @pl.when(p == pl.num_programs(1) - 1)
         def _():
-            l = jnp.maximum(l_scr[:], 1e-20)
-            out_ref[0] = (o_scr[:] / l[..., None]).astype(out_ref.dtype)
+            l = jnp.maximum(l_scr[:n], 1e-20)
+            out_ref[0, :n] = (o_scr[:n] / l[..., None]).astype(out_ref.dtype)
+            if n < C:
+                out_ref[0, n:] = jnp.zeros(
+                    (C - n,) + out_ref.shape[2:], out_ref.dtype)
 
     def _quant_commit(pool_out, scale_in, lines, belongs, offs):
         """In-kernel ``kv_quant.quant_line_write`` restricted to the
@@ -652,7 +694,7 @@ def _build_ragged_paged_kernel(
                 pool_out[0, offs[c]] = q[c]
         return new
 
-    def plain_kernel(*refs):
+    def plain_kernel(*refs, q_len_ref=None):
         # (pt, q, k, v, [ks, vs], mask) -> out; o/m/l scratch
         i = 1  # refs[0] is the scalar-prefetched page table
         q_ref = refs[i]; i += 1         # (1, C, KV, G, dk)
@@ -667,28 +709,45 @@ def _build_ragged_paged_kernel(
 
         p = pl.program_id(1)
 
-        @pl.when(p == 0)
-        def _():
-            _init(o_scr, m_scr, l_scr)
+        def step(n, q_len=None):
+            # this grid step over the row's first n queries: n is a
+            # leading extent of the query, mask and output blocks and of
+            # the accumulators, so taking their first n rows is free
+            @pl.when(p == 0)
+            def _():
+                _init(o_scr, m_scr, l_scr, n)
 
-        if group_mask:  # one (C, ps) mask a KV group
-            mask = [mask_ref[0, kv * C:(kv + 1) * C]
-                    for kv in range(q_ref.shape[2])]
+            # one (n, ps) mask, or one a KV group from its own rows of
+            # the (KV*C, ps) block — already bounded: S_virt = NP*ps
+            groups = q_ref.shape[2] if group_mask else 1
+            mask = [mask_ref[0, g * C:g * C + n] for g in range(groups)]
+            if q_len is not None:  # a padding query keeps no page alive
+                real = jax.lax.broadcasted_iota(
+                    jnp.int32, mask[0].shape, 0) < q_len
+                mask = [m & real for m in mask]
             some = functools.reduce(jnp.logical_or, map(jnp.any, mask))
+
+            @pl.when(some)
+            def _():
+                q = q_ref[0, :n].astype(jnp.float32)
+                k = _unpack_codes(k_ref[0], pack).transpose(1, 0, 2)
+                v = _unpack_codes(v_ref[0], pack).transpose(1, 0, 2)
+                ks = ks_ref[0] if quant else None
+                vs = vs_ref[0] if quant else None
+                _attend(q, k, v, ks, vs, mask if group_mask else mask[0],
+                        o_scr, m_scr, l_scr)
+
+            _finalize(p, out_ref, o_scr, l_scr, n)
+
+        if q_len_ref is None:
+            step(C)
         else:
-            mask = mask_ref[0]  # (C, ps) — already bounded: S_virt = NP*ps
-            some = jnp.any(mask)
-
-        @pl.when(some)
-        def _():
-            q = q_ref[0].astype(jnp.float32)
-            k = _unpack_codes(k_ref[0], pack).transpose(1, 0, 2)
-            v = _unpack_codes(v_ref[0], pack).transpose(1, 0, 2)
-            ks = ks_ref[0] if quant else None
-            vs = vs_ref[0] if quant else None
-            _attend(q, k, v, ks, vs, mask, o_scr, m_scr, l_scr)
-
-        _finalize(p, out_ref, o_scr, l_scr)
+            q_len = q_len_ref[pl.program_id(0)]
+            if not narrow:
+                step(C, q_len)
+            else:
+                pl.when(q_len > narrow)(lambda: step(C, q_len))
+                pl.when(q_len <= narrow)(lambda: step(narrow, q_len))
 
     def fused_kernel(*refs):
         # (pt, logical, off, q_raw, k_new, v_new, [cos, sin],
@@ -844,6 +903,7 @@ def ragged_paged_attention(
     k_scale: Optional[jnp.ndarray] = None,  # (P+1, KV) f32 (quantized pool)
     v_scale: Optional[jnp.ndarray] = None,
     row_offset=None,          # int32 scalar: pool row of table entry 0
+    q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
 ) -> jnp.ndarray:
     """:func:`_ragged_paged_attention` placed on the ambient mesh. The
     compiler cannot partition a Mosaic kernel ("wrap the call in a
@@ -874,6 +934,10 @@ def ragged_paged_attention(
         optional.append("row_offset")
         operands.append(jnp.asarray(row_offset, jnp.int32))
         in_specs.append(P())
+    if q_len is not None:  # replicated, as the table is
+        optional.append("q_len")
+        operands.append(q_len)
+        in_specs.append(P())
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else mesh.shape.get(MODEL_AXIS, 1)
     if tp == 1 or k_pool.shape[2] % tp:
@@ -895,6 +959,7 @@ def _ragged_paged_attention(
     v_scale: Optional[jnp.ndarray] = None,
     row_offset=None,          # int32 scalar: pool row of table entry 0
     group_mask: bool = False,  # mask is (R, KV, C, NP*ps): one a KV group
+    q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
 ) -> jnp.ndarray:
     """Fused ragged paged attention: grid (request, logical page); the
     K/V BlockSpec index maps read the scalar-prefetched page table so
@@ -912,7 +977,17 @@ def _ragged_paged_attention(
     to every table entry: the pools (and scales) may then be the
     (L*(P+1), ...) view of every layer's pages with layer l's at rows
     ``l*(P+1)`` on — how the serving step's layer loop reads its carried
-    pool without slicing a layer out (models/transformer.py)."""
+    pool without slicing a layer out (models/transformer.py).
+
+    ``q_len`` (:func:`real_query_lengths`) is a third: row r's columns
+    from ``q_len[r]`` on are padding. A page is then computed only if a
+    real query of the row may see a key of it (a padding query sits at
+    the scratch position, past every key, and would keep every page of
+    the virtual cache alive), a row of at most
+    :func:`narrow_query_extent` real queries is computed at that extent,
+    and padding columns come out zero. A real query's result is the
+    same, to the bit at the chunk's extent. ``None``: every column
+    counts, the kernel as it was."""
     R, C, H, dk = q.shape
     _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
     NP = page_table.shape[1]
@@ -924,10 +999,12 @@ def _ragged_paged_attention(
     prefetch = [page_table.astype(jnp.int32)]
     if row_offset is not None:
         prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
+    if q_len is not None:  # last, so the index maps' ``base`` stays put
+        prefetch.append(q_len.astype(jnp.int32))
 
     def page(r, p, pt, *base):
         # the paged gather: block row = page_table[r, p] (+ row_offset)
-        row = pt[r, p] + base[0][0] if base else pt[r, p]
+        row = pt[r, p] + base[0][0] if row_offset is not None else pt[r, p]
         return (row, 0, 0, 0)
 
     in_specs = [
@@ -941,8 +1018,9 @@ def _ragged_paged_attention(
         group_mask=group_mask,
     )
 
-    def kernel(*refs):  # the body knows one prefetched ref, the table
-        body(refs[0], *refs[len(prefetch):])
+    def kernel(*refs):  # the body knows the table and the query lengths
+        body(refs[0], *refs[len(prefetch):],
+             q_len_ref=None if q_len is None else refs[len(prefetch) - 1])
 
     if k_scale is not None:
         # per-page scales as (P+1, KV, 1, 1): the block hands the body a
@@ -999,6 +1077,7 @@ def sparse_paged_attention(
     mask: jnp.ndarray,        # (R, KV, C, NP*ps) bool: a mask a KV group
     *,
     row_offset=None,
+    q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
 ) -> jnp.ndarray:
     """Block-sparse paged attention (``ff_sparse_paged_c<C>``): the
     ragged paged kernel with one mask a KV group, for layers whose
@@ -1006,11 +1085,14 @@ def sparse_paged_attention(
     (models/minicpm_sala.py). A first version: the choice is a mask
     over the dense paged read. A page no query of the chunk chose in
     either group is still fetched, and skipped by the body's
-    ``any(mask)`` guard — exact, the page DMAs not saved. One chip:
-    no ``shard_map`` over a ``model`` axis."""
+    ``any(mask)`` guard — exact, the page DMAs not saved. With
+    ``q_len`` that guard reads the row's real queries only: a padding
+    query chooses every block, and 127 of them beside a decoding
+    row's one kept all of its pages alive. One chip: no ``shard_map``
+    over a ``model`` axis."""
     return _ragged_paged_attention(
         q, k_pool, v_pool, page_table, mask, row_offset=row_offset,
-        group_mask=True,
+        group_mask=True, q_len=q_len,
     )
 
 

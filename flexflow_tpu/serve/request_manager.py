@@ -965,6 +965,21 @@ class RequestManager:
             for req in finals:
                 req.profile.prefill_dispatched_time = now
 
+    def _note_attn_steps(self, first, count, chunk: int) -> None:
+        """Count a pipelined step's attention grid (a paged engine's:
+        ``SchedulerStats.note_attn_steps``) from what the step is
+        handed: each row's first position and its real queries."""
+        eng = self.engine
+        if eng.paged:
+            from .kernels import narrow_query_extent  # Pallas: not at import
+
+            sc = eng.serving
+            self.stats.note_attn_steps(
+                first, count, sc.page_size, sc.pages_per_slot,
+                narrow_query_extent(chunk),
+                getattr(eng.cfg, "sliding_window", None) or 0,
+            )
+
     def _dispatch_decode(self, decoding: List[Request]):
         """Dispatch one fused decode step WITHOUT waiting for the
         previous one: decode rows that sampled in the previous dispatch
@@ -1014,9 +1029,11 @@ class RequestManager:
             "decode", active_slots=len(decoding), num_slots=R,
             decode_tokens=len(decoding),
         )
+        real = positions[:, 0] != scratch
         if self._slot_state:
-            self.stats.note_rows(positions[:, 0], positions[:, 0] != scratch,
+            self.stats.note_rows(positions[:, 0], real,
                                  self.engine.cfg.dense_len)
+        self._note_attn_steps(positions[:, 0], real, 1)
         tr = self.tracer
         if tr.enabled:
             tr.event("decode_step", rows=len(decoding))
@@ -1131,6 +1148,7 @@ class RequestManager:
         if self._slot_state:
             self.stats.note_rows(bc.positions[:, 0], bc.qlens,
                                  eng.cfg.dense_len)
+        self._note_attn_steps(bc.positions[:, 0], bc.qlens, C)
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,

@@ -102,6 +102,56 @@ def test_ragged_paged_attention_bf16_compiles(chip, C):
     assert f"%ff_ragged_paged_c{C}" in text
 
 
+@pytest.mark.parametrize("C", [1, 128])
+def test_ragged_paged_attention_with_query_lengths_compiles(
+    chip, C, monkeypatch
+):
+    """The kernel told each row's real queries (``q_len``: a third
+    prefetched scalar, the padding-blind guard, at C=128 the narrow
+    body beside the chunk-wide one) at Mistral-7B widths, under the
+    ``vmem_limit_bytes`` the kernel states without it: the limit may
+    not rise."""
+    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
+    limits = []
+    stated = kernels._ragged_vmem_limit
+    monkeypatch.setattr(
+        kernels, "_ragged_vmem_limit",
+        lambda *a: limits.append(stated(*a)) or limits[-1],
+    )
+    args = _attention_args(chip, C, cfg) + (chip((R,), jnp.int32),)
+
+    def fn(q, kp, vp, pt, mask, q_len, use):
+        return kernels.ragged_paged_attention(
+            q, kp, vp, pt, mask, q_len=q_len if use else None)
+
+    _compile(functools.partial(fn, use=False), *args)
+    _, text = _compile(functools.partial(fn, use=True), *args)
+    assert text.count("tpu_custom_call") == 1
+    assert f"%ff_ragged_paged_c{C}" in text
+    assert limits[1] <= limits[0]
+
+
+def test_sparse_paged_attention_with_query_lengths_compiles(chip):
+    """``ff_sparse_paged_c128`` with ``q_len`` at MiniCPM-SALA's widths
+    (2 KV heads of 16 query heads, a mask a group, the cell's 146 pages
+    a slot, the layer's row offset)."""
+    slots, pages, KV, G, dk = 4, 146, 2, 16, 128
+    pool = chip((3 * (slots * pages + 1), PAGE, KV, dk), jnp.bfloat16)
+
+    def fn(q, kp, vp, pt, mask, q_len):
+        return kernels.sparse_paged_attention(
+            q, kp, vp, pt, mask, row_offset=slots * pages + 1, q_len=q_len)
+
+    _, text = _compile(
+        fn, chip((slots, 128, KV * G, dk), jnp.bfloat16), pool, pool,
+        chip((slots, pages), jnp.int32),
+        chip((slots, KV, 128, pages * PAGE), jnp.bool_),
+        chip((slots,), jnp.int32),
+    )
+    assert text.count("tpu_custom_call") == 1
+    assert "%ff_sparse_paged_c128" in text
+
+
 def _step_args(sds, cfg, C, kv_quant=None):
     params = _on(
         jax.eval_shape(
@@ -158,6 +208,9 @@ def test_mistral_paged_pallas_step_compiles(chip, C):
     compiled, text = _compile(_step(cfg, kernels="pallas"), *args, donate=(1,))
     assert "tpu_custom_call" in text
     assert f"%ff_ragged_paged_c{C}" in text  # inside the layer scan too
+    # ONE kernel call a layer (the scan's body holds it once), whatever
+    # the rows' query lengths: the narrow body is a branch inside it
+    assert text.count("tpu_custom_call") == 1
     # weights + pool + temporaries of this cut fit one 16 GB chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
@@ -303,6 +356,9 @@ def test_minicpm_sala_hybrid_step_compiles_in_place(chip, C):
         chip((slots, pages), jnp.int32), donate=(1,))
     assert f"%ff_ragged_paged_c{C}" in text    # no row above dense_len
     assert f"%ff_sparse_paged_c{C}" in text    # some row above it
+    # one call of each in the sparse layers' loop body, per run of them
+    # (two runs in this order), nothing else made into a kernel
+    assert text.count("tpu_custom_call") == 4
     for name in ("k", "v", "state", "kbar"):
         dims = ",".join(map(str, cache[name].shape))
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), name

@@ -2,6 +2,7 @@
 decode and tree-verify attention must match the dense XLA reference —
 the TPU analog of the reference's op kernel tests (tests/ops/,
 SURVEY.md §4)."""
+import functools
 import math
 
 import numpy as np
@@ -94,3 +95,182 @@ def test_llama_generation_pallas_equals_xla():
                                 cache_dtype=jnp.float32, kernels=kern))
         outs[kern] = [r.output_tokens for r in m.generate(prompts, max_new_tokens=6)]
     assert outs["xla"] == outs["pallas"], outs
+
+
+# ---------------------------------------------------------------------------
+# The ragged paged kernel told how many leading columns of a row are
+# real (``q_len``): padding columns keep no page alive, a row of a few
+# real queries takes the narrow body, real queries come out the same.
+
+_VARIANTS = ("plain", "group_mask", "int8")
+_PS, _NP = 8, 6                       # page size, logical pages a row
+_CACHE_LEN = _PS * _NP - 1            # the scratch position
+
+
+def _mixed_rows(C):
+    """(first position, real queries) a row, shaped like a mixed step:
+    whole chunks, a prompt's tail above and under the narrow extent, one
+    query rows (one of them on a page boundary), idle rows."""
+    if C == 1:
+        return [(20, 1), (0, 0), (16, 1), (7, 1), (0, 0), (46, 1)]
+    return [(16, C), (8, 11), (24, 5), (20, 1), (0, 0), (16, 1), (0, C),
+            (30, 8), (0, 0), (45, 1)]
+
+
+@functools.lru_cache(maxsize=None)
+def _q_len_case(variant, C):
+    """One batch through the kernel with and without ``q_len`` and
+    through the XLA reference: (positions, q_len, mask, outputs)."""
+    from flexflow_tpu.serve import kernels as K
+
+    rng = np.random.default_rng(C + len(variant))
+    rows = _mixed_rows(C)
+    R, P = len(rows), len(rows) * _NP
+    pos = np.full((R, C), _CACHE_LEN, np.int32)
+    for r, (first, n) in enumerate(rows):
+        pos[r, :n] = np.arange(first, first + n)
+    pos = jnp.asarray(pos)
+    q = jnp.asarray(rng.normal(size=(R, C, H, dk)), jnp.float32)
+    pt = jnp.asarray(rng.permutation(P).reshape(R, _NP), jnp.int32)
+    mask = K.paged_serve_mask(None, pos, _NP, _PS, _CACHE_LEN)   # (R, C, S)
+    kw = {}
+    if variant == "int8":
+        kp, vp = (jnp.asarray(rng.integers(-127, 128, size=(P + 1, _PS, KV, dk)),
+                              jnp.int8) for _ in range(2))
+        kw = dict(k_scale=jnp.asarray(rng.random((P + 1, KV)) * 0.02, jnp.float32),
+                  v_scale=jnp.asarray(rng.random((P + 1, KV)) * 0.02, jnp.float32))
+    else:
+        kp, vp = (jnp.asarray(rng.normal(size=(P + 1, _PS, KV, dk)), jnp.float32)
+                  for _ in range(2))
+    if variant == "group_mask":
+        # a mask a KV group: each real query keeps its own page and a
+        # random half of the others; a padding query keeps every key,
+        # as models/minicpm_sala.choose_blocks leaves it
+        keep = rng.random((R, KV, C, _NP)) < 0.5
+        own = (np.asarray(pos) // _PS)[:, None, :, None] == np.arange(_NP)
+        keep = keep | own | (np.asarray(pos) >= _CACHE_LEN)[:, None, :, None]
+        mask = mask[:, None] & jnp.asarray(np.repeat(keep, _PS, axis=-1))
+        call = functools.partial(K.sparse_paged_attention, q, kp, vp, pt, mask)
+        ref = jnp.stack([
+            K.ragged_paged_attention_xla(q, kp, vp, pt, mask[:, g])
+            .reshape(R, C, KV, H // KV, dk)[:, :, g] for g in range(KV)
+        ], axis=2).reshape(R, C, H, dk)
+    else:
+        call = functools.partial(K.ragged_paged_attention, q, kp, vp, pt, mask, **kw)
+        ref = K.ragged_paged_attention_xla(q, kp, vp, pt, mask, **kw)
+    q_len = K.real_query_lengths(pos, _CACHE_LEN)
+    outs = dict(
+        none=call(), q_len=call(q_len=q_len),
+        full=call(q_len=jnp.full((R,), C, jnp.int32)), ref=ref,
+    )
+    return (np.asarray(pos), np.asarray(q_len), np.asarray(mask),
+            {k: np.asarray(v) for k, v in outs.items()})
+
+
+_Q_LEN_CASES = [(v, C) for v in _VARIANTS for C in (1, 16)]
+q_len_cases = pytest.mark.parametrize("variant, C", _Q_LEN_CASES)
+
+
+@q_len_cases
+def test_q_len_is_the_rows_real_queries(variant, C):
+    pos, q_len, _, _ = _q_len_case(variant, C)
+    assert q_len.tolist() == [n for _, n in _mixed_rows(C)]
+    assert q_len.dtype == np.int32
+
+
+@q_len_cases
+def test_q_len_real_queries_unchanged(variant, C):
+    """Every real query's row is bitwise the ``q_len=None`` kernel's
+    (interpret mode), at the chunk's extent and at the narrow one, and
+    the XLA reference's within this file's tolerance."""
+    _, q_len, _, outs = _q_len_case(variant, C)
+    for r, n in enumerate(q_len):
+        np.testing.assert_array_equal(outs["q_len"][r, :n], outs["none"][r, :n])
+        np.testing.assert_allclose(outs["q_len"][r, :n], outs["ref"][r, :n],
+                                   atol=2e-5)
+
+
+@q_len_cases
+def test_q_len_padding_queries_are_zero(variant, C):
+    _, q_len, _, outs = _q_len_case(variant, C)
+    assert np.isfinite(outs["q_len"]).all()
+    for r, n in enumerate(q_len):
+        assert not outs["q_len"][r, n:].any()
+
+
+@q_len_cases
+def test_q_len_none_is_every_column(variant, C):
+    """``q_len=None`` is the kernel as it was: no third prefetched
+    scalar, every column attends, and ``q_len = C`` (every column real)
+    gives its result to the bit, padding columns' too."""
+    _, _, _, outs = _q_len_case(variant, C)
+    np.testing.assert_array_equal(outs["full"], outs["none"])
+    np.testing.assert_allclose(outs["none"], outs["ref"], atol=2e-5)
+
+
+def test_q_len_none_traces_the_old_operands():
+    """A caller that passes no ``q_len`` gets the old ``pallas_call``:
+    one prefetched scalar (two with a row offset), no per-row branch."""
+    from flexflow_tpu.serve import kernels as K
+
+    def prefetched(**kw):
+        jaxpr = jax.make_jaxpr(functools.partial(K.ragged_paged_attention, **kw))(
+            jnp.zeros((2, 16, H, dk)), jnp.zeros((5, _PS, KV, dk)),
+            jnp.zeros((5, _PS, KV, dk)), jnp.zeros((2, _NP), jnp.int32),
+            jnp.zeros((2, 16, _NP * _PS), bool))
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return call.params["grid_mapping"].num_index_operands
+
+    assert prefetched() == 1
+    assert prefetched(row_offset=3) == 2
+    assert prefetched(row_offset=3, q_len=jnp.zeros((2,), jnp.int32)) == 3
+
+
+@q_len_cases
+@pytest.mark.parametrize("window", [0, 12])
+def test_attn_step_counters_equal_the_masks_count(variant, C, window):
+    """``SchedulerStats.note_attn_steps`` counts a step's grid from the
+    rows' first positions and query counts; the same count made from the
+    causal mask itself: a page is live when some REAL query of the row
+    sees a key of it."""
+    from flexflow_tpu.metrics import SchedulerStats
+    from flexflow_tpu.serve import kernels as K
+
+    pos, q_len, _, _ = _q_len_case(variant, C)
+    mask = np.asarray(K.paged_serve_mask(None, jnp.asarray(pos), _NP, _PS, _CACHE_LEN))
+    if window:
+        keys = np.arange(_NP * _PS)
+        mask = mask & (keys[None, None, :] > pos[:, :, None] - window)
+    narrow = K.narrow_query_extent(C)
+    pages = mask.reshape(mask.shape[0], C, _NP, _PS).any(axis=-1)   # (R, C, NP)
+    live = np.array([pages[r, :n].any(axis=0).sum() for r, n in enumerate(q_len)])
+    stats = SchedulerStats()
+    for _ in range(2):  # counters add up over steps
+        stats.note_attn_steps(pos[:, 0], q_len, _PS, _NP, narrow, window)
+    assert stats.attn_steps_grid == 2 * len(q_len) * _NP
+    assert stats.attn_steps_live == 2 * live.sum()
+    assert stats.attn_steps_narrow == 2 * live[q_len <= narrow].sum()
+    assert 0 < stats.attn_steps_live < stats.attn_steps_grid
+
+
+def test_paged_pallas_step_counts_its_attention_grid():
+    """A paged Pallas engine serving requests counts every pipelined
+    step's grid, and generates what the XLA path generates."""
+    from flexflow_tpu.models import mistral
+    from flexflow_tpu.serve import LLM, ServingConfig
+
+    cfg = mistral.tiny(dtype=jnp.float32)  # sliding window 8: one page
+    params = mistral.init_params(jax.random.PRNGKey(5), cfg)
+    prompts = [[7, 8, 9, 10, 11, 12, 13], [20, 21, 22], list(range(30, 50))]
+    outs, stats = {}, {}
+    for kern in ("xla", "pallas"):
+        m = LLM(mistral, cfg, params, tokenizer=None)
+        m.compile(ServingConfig(max_requests_per_batch=4, max_sequence_length=64,
+                                prefill_chunk=16, cache_dtype=jnp.float32,
+                                kernels=kern, kv_layout="paged", page_size=8))
+        outs[kern] = [r.output_tokens for r in m.generate(prompts, max_new_tokens=6)]
+        stats[kern] = m.rm.stats
+    assert outs["xla"] == outs["pallas"], outs
+    s = stats["pallas"]
+    assert s.attn_steps_grid == s.steps * 4 * m.engine.serving.pages_per_slot
+    assert 0 < s.attn_steps_narrow <= s.attn_steps_live < s.attn_steps_grid
