@@ -13,7 +13,6 @@ model.cc:4049-4200). The TPU framework's equivalents:
   serve-search offline ServingConfig search over the serving cost model
   spec-distill distill a draft from target logits + rank the draft
                ladder by measured accept-rate-per-draft-GFLOP
-  bench        the headline benchmark (bench.py)
 
 Reference-style degree flags are accepted with either one or two
 leading dashes (-tensor-parallelism-degree / --tensor-parallelism-degree).
@@ -99,7 +98,6 @@ def cmd_serve(args):
         fused_decode=tuple(
             s for s in (args.fused_decode or "").split(",") if s
         ),
-        quantized_allreduce=args.quantized_allreduce,
         replicas=args.replicas,
         router_policy=args.router_policy,
         prefill_replicas=args.prefill_replicas,
@@ -301,10 +299,6 @@ def cmd_serve_search(args):
     if sc.prefill_replicas:
         flags += [f"--prefill-replicas {sc.prefill_replicas}",
                   f"--decode-replicas {sc.decode_replicas}"]
-    if "whole_step" in sc.fused_decode:
-        flags.append("--fused-decode whole_step --pallas")
-    if sc.quantized_allreduce:
-        flags.append(f"--quantized-allreduce {sc.quantized_allreduce}")
     if best.speculation:
         flags.append("--spec")
     print("serve with: python -m flexflow_tpu serve " + " ".join(flags))
@@ -431,10 +425,6 @@ def cmd_spec_distill(args):
               f"(load as an SSM spec)")
 
 
-def cmd_bench(args):
-    _load_repo_module("bench.py", "bench").main()
-
-
 def main(argv=None):
     p = argparse.ArgumentParser(prog="flexflow_tpu")
     sub = p.add_subparsers(dest="cmd", required=True)
@@ -513,22 +503,13 @@ def main(argv=None):
                         "(complete) or as soon as prefill ends (prefill)")
     s.add_argument("--fused-decode", default=None,
                    help="megakernel decode-step fusions, comma-separated "
-                        "(rope_kv_write,sampling,whole_step): fold RoPE "
-                        "+ the KV page write into the ragged paged "
-                        "Pallas kernel (requires --kv-layout paged; "
-                        "active with --pallas), the greedy/top-k "
-                        "sampling epilogue into the step program, "
-                        "and/or run the WHOLE decode step as one "
-                        "persistent layer-walking Pallas program "
-                        "(paged layouts); each fusion is "
-                        "bitwise-identical to the unfused step")
-    s.add_argument("--quantized-allreduce", default=None,
-                   choices=["exact", "int8"],
-                   help="whole_step TP decode collectives "
-                        "(serve/collectives.py, EQuARX-style): 'exact' "
-                        "= lax.psum (bitwise the GSPMD reduction), "
-                        "'int8' = quantized codes + per-block scales "
-                        "(~1/4 the reduce bytes, documented tolerance)")
+                        "(rope_kv_write,sampling): fold RoPE + the KV "
+                        "page write into the ragged paged Pallas "
+                        "kernel (requires --kv-layout paged; active "
+                        "with --pallas) and/or the greedy/top-k "
+                        "sampling epilogue into the step program; "
+                        "each fusion is bitwise-identical to the "
+                        "unfused step")
     s.add_argument("--replicas", type=int, default=1,
                    help="cluster serving (serve/cluster/): drive this "
                         "many engine replicas — each its own mesh and "
@@ -744,16 +725,11 @@ def main(argv=None):
     sdp.add_argument("--seed", type=int, default=0)
     sdp.set_defaults(fn=cmd_spec_distill)
 
-    b = sub.add_parser("bench", help="headline benchmark (one JSON line)")
-    b.set_defaults(fn=cmd_bench)
-
     args = p.parse_args(argv)
-    if args.fn is not cmd_bench:
-        # this process owns its compiles; bench.py's children, which
-        # hold the device, enable the cache themselves
-        from .config import enable_compile_cache
+    # this process owns its compiles
+    from .config import enable_compile_cache
 
-        enable_compile_cache()
+    enable_compile_cache()
     args.fn(args)
 
 

@@ -170,20 +170,6 @@ HOT_ROOTS = {
     "set_pools",
     "rebuild_routing",
     "on_cluster_step",
-    # whole-step decode megakernel (serve/kernels.whole_step_decode +
-    # serve/collectives.py + engine._run_whole): the one-program layer
-    # walk IS the decode hot path, and the quantized TP collectives run
-    # inside it once per row-parallel matmul per layer — a blocking
-    # transfer in the walk builder, the collective quantize/dequant, or
-    # the dispatch wrapper would serialize every decode step
-    "whole_step_decode",
-    "whole_step_vmem_bytes",
-    "tp_allreduce",
-    "quantize_blocks",
-    "dequantize_blocks",
-    "_run_whole",
-    "_get_whole_step",
-    "_serve_whole_fn",
     # self-driving serving (serve/autotune/): the autoscaler's per-step
     # hook, its evaluation + decision paths and the estimator's
     # observation fold all run INSIDE ClusterManager.step — host-side

@@ -1,4 +1,4 @@
-"""Unity-searched training benchmark — the BASELINE.md north-star #2 path.
+"""Unity-searched training benchmark.
 
 Builds the flagship LLaMA-style LM through the graph IR (embedding →
 fused decoder stack → lm head), lets ``compile(auto_parallel=True)``

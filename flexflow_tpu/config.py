@@ -157,8 +157,8 @@ def get_config() -> FFConfig:
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for a process this
-    repo owns (``chip_smoke.py``, ``python -m flexflow_tpu``, bench
-    children) — never at package import, never from the tests. Where
+    repo owns (``chip_smoke.py``, ``python -m flexflow_tpu``,
+    ``benchmarks/run.py``) — never at package import, never from the tests. Where
     ``JAX_COMPILATION_CACHE_DIR`` is set JAX already honours it and
     nothing is set here; otherwise the cache lives at ONE fixed path
     inside the checkout (the path is part of the cache key, so a

@@ -156,16 +156,6 @@ class SchedulerStats:
     # warmup and retraces stays 0.
     compiles: int = 0
     retraces: int = 0
-    # Whole-step megakernel VMEM gate (serve/engine._whole_step_vmem_
-    # gate): times the gate fell back to the per-layer path because a
-    # step shape's working set exceeded the budget at EVERY legal
-    # sub-block tiling (counter — healthy serving keeps it 0: over-
-    # budget layers get a tile count, not a fallback), and the gate's
-    # priced decode working-set estimate in bytes (gauge). Mirrored
-    # from the engine at the scheduler's stats chokepoint, like
-    # cp_shards/shard_balance.
-    whole_step_fallbacks: int = 0
-    whole_step_vmem_est: int = 0
     # A family with per-slot state beside the page pool (the engine's
     # model declares ``SLOT_STATE``; models/minicpm_sala.py): the bytes
     # of that state (a gauge: held at any context length), the rows of
@@ -340,8 +330,6 @@ class SchedulerStats:
             "decode_step_ms_p99": round(self.decode_step_ms_p99, 3),
             "compiles": self.compiles,
             "retraces": self.retraces,
-            "whole_step_fallbacks": self.whole_step_fallbacks,
-            "whole_step_vmem_est": self.whole_step_vmem_est,
         }
 
     def report(self) -> str:
@@ -367,8 +355,7 @@ class SchedulerStats:
             f"bal={s['shard_balance']:.2f} "
             f"dstep_ms={s['decode_step_ms_p50']:.2f}/"
             f"{s['decode_step_ms_p99']:.2f} "
-            f"compiles={s['compiles']} retraces={s['retraces']} "
-            f"ws_fallback={s['whole_step_fallbacks']}"
+            f"compiles={s['compiles']} retraces={s['retraces']}"
         )
 
 

@@ -786,11 +786,9 @@ def serve_debug_activations(
 #: (ServingConfig.fused_decode; the engine validates requests against
 #: this). "rope_kv_write": serve_step_paged folds RoPE + the KV page
 #: write into the ragged paged Pallas kernel (the megakernel decode
-#: step). "whole_step": the FULL decode step runs as one persistent
-#: layer-walking Pallas program (:func:`serve_step_whole`). The
-#: "sampling" epilogue fusion is model-agnostic — it lives in the
-#: engine's step program — so it is not listed here.
-FUSED_DECODE = ("rope_kv_write", "whole_step")
+#: step). The "sampling" epilogue fusion is model-agnostic — it lives
+#: in the engine's step program — so it is not listed here.
+FUSED_DECODE = ("rope_kv_write",)
 
 
 def init_paged_kv_cache(
@@ -878,51 +876,14 @@ def _page_lookup(page_table: jnp.ndarray, cache_positions: jnp.ndarray,
     return phys, cache_positions % page_size
 
 
-def _mm_reduced(x, w, reduce_fn):
-    """``_mm`` with a tensor-parallel partial-sum chokepoint: the
-    reduction applies to the f32 matmul output BEFORE the model-dtype
-    cast — exactly where GSPMD inserts its all-reduce for a
-    row-parallel matmul, so the collective-explicit whole-step walk
-    stays bitwise the GSPMD-scheduled step. ``None`` is literally
-    :func:`_mm` (the single-shard path is untouched)."""
-    if reduce_fn is None:
-        return _mm(x, w)
-    out = jnp.matmul(x, w, preferred_element_type=jnp.float32)
-    return reduce_fn(out).astype(x.dtype)
-
-
-def _attend_paged_xla(cfg: LLaMAConfig, q, k_virt, v_virt, mask):
-    """:func:`serve_attention` with head counts derived from the
-    OPERANDS instead of cfg — op-for-op identical on the single-shard
-    path (where they agree), and what lets the same body serve the
-    TP-local head shards of the whole-step walk."""
-    R, C, H, dk = q.shape
-    KV = k_virt.shape[2]
-    G = H // KV
-    qg = q.reshape(R, C, KV, G, dk)
-    scores = jnp.einsum(
-        "rckgd,rskd->rkgcs", qg, k_virt, preferred_element_type=jnp.float32
-    ) / math.sqrt(cfg.head_dim)
-    scores = jnp.where(mask[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("rkgcs,rskd->rckgd", probs, v_virt)
-    return out.reshape(R, C, H * dk)
-
-
 def _block_paged_xla(cfg: LLaMAConfig, p, x, cos, sin, mask,
                      k_pool, v_pool, phys, off, page_table,
-                     k_scale=None, v_scale=None, qmax=None,
-                     reduce_fn=None):
+                     k_scale=None, v_scale=None, qmax=None):
     """One block of the UNFUSED XLA paged step, on values: project,
     RoPE, commit K/V at the table-resolved (page, offset) — quantizing
     at the page scales when ``qmax`` is set — gather the virtual cache
-    through the table, attend, out-project, FFN. This is the ONE
-    definition shared by :func:`serve_block_paged`'s ``kernels="xla"``
-    path and the whole-step decode megakernel / TP walk
-    (:func:`serve_step_whole`) — sharing the body is what makes
-    whole-step decode BITWISE the unfused XLA step. ``reduce_fn`` is
-    the row-parallel partial reduction of the collective-explicit TP
-    walk (see :func:`_mm_reduced`); None on the single-shard path."""
+    through the table, attend, out-project, FFN: the body of
+    :func:`serve_block_paged`'s ``kernels="xla"`` path."""
     dk = cfg.head_dim
     R, C, D = x.shape
     h = _rms(x, p["attn_norm"], cfg.rms_norm_eps)
@@ -949,12 +910,10 @@ def _block_paged_xla(cfg: LLaMAConfig, p, x, cos, sin, mask,
     else:
         k_virt = _pk.gather_pages(k_pool, page_table)
         v_virt = _pk.gather_pages(v_pool, page_table)
-    attn = _attend_paged_xla(cfg, q, k_virt, v_virt, mask)
-    x = x + _mm_reduced(attn, p["wo"], reduce_fn)
+    attn = serve_attention(cfg, q, k_virt, v_virt, mask)
+    x = x + _mm(attn, p["wo"])
     h2 = _rms(x, p["ffn_norm"], cfg.rms_norm_eps)
-    ffn = _mm_reduced(
-        jax.nn.silu(_mm(h2, p["w1"])) * _mm(h2, p["w3"]), p["w2"], reduce_fn
-    )
+    ffn = _mm(jax.nn.silu(_mm(h2, p["w1"])) * _mm(h2, p["w3"]), p["w2"])
     return x + ffn, k_pool, v_pool, k_scale, v_scale
 
 
@@ -992,7 +951,7 @@ def serve_block_paged(cfg: LLaMAConfig, p, x, cos, sin, mask,
 
     if cp_mesh is None and kernels != "pallas":
         # the unfused XLA path — the CPU-parity reference every fusion
-        # (and the whole-step megakernel) anchors on; ONE shared body
+        # anchors on
         return _block_paged_xla(
             cfg, p, x, cos, sin, mask, k_pool, v_pool, phys, off,
             page_table, k_scale, v_scale, qmax,
@@ -1185,393 +1144,6 @@ def serve_step_paged(
     else:
         logits = jnp.matmul(x, head, preferred_element_type=jnp.float32)
     return logits, new_cache
-
-
-# ---------------------------------------------------------------------------
-# Whole-step decode megakernel (ServingConfig.fused_decode=("whole_step",);
-# serve/kernels.whole_step_decode carries the program design). The model
-# family's half of the contract: the weight layout for blocked streaming
-# and the step entry point that binds this family's block/head math —
-# the SAME ``_block_paged_xla`` body the unfused XLA step runs, which is
-# the bitwise guarantee.
-
-
-def whole_step_weight_layout(
-    params: Dict[str, Any], cfg: LLaMAConfig
-) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
-    """Weight layout for blocked HBM→VMEM streaming: returns
-    ``(layer_arrays, head_arrays)`` — every per-layer tensor as one
-    stacked ``(L, ...)`` array (already this family's storage layout;
-    the hook VALIDATES and names the streams rather than copying) plus
-    the resident epilogue params. Raises ValueError for layouts the
-    walk cannot stream — weight-only quantized params ({"q","scale"}
-    dicts have no single streamable block per layer yet) — so the
-    engine fails at construction, not mid-serve."""
-    L = cfg.num_hidden_layers
-    layer_arrays = {}
-    for name, a in params["layers"].items():
-        if isinstance(a, dict):
-            raise ValueError(
-                "whole_step is not composed with weight-only "
-                f"quantization (layer tensor {name!r} is a quantized "
-                "{'q','scale'} pair) — serve full-precision params or "
-                "drop the whole_step fusion"
-            )
-        if a.shape[0] != L:
-            raise ValueError(
-                f"layer tensor {name!r} leading dim {a.shape[0]} != "
-                f"num_hidden_layers {L}"
-            )
-        layer_arrays[name] = a
-    head_arrays = {"final_norm": params["final_norm"]}
-    if cfg.tie_word_embeddings:
-        head_arrays["embed"] = params["embed"]
-    else:
-        if isinstance(params["lm_head"], dict):
-            raise ValueError(
-                "whole_step is not composed with a weight-only "
-                "quantized lm_head"
-            )
-        head_arrays["lm_head"] = params["lm_head"]
-    return layer_arrays, head_arrays
-
-
-def _whole_head_fn(cfg: LLaMAConfig, head, x, logits_idx):
-    """Epilogue on values — op-for-op :func:`serve_step_paged`'s tail
-    (final norm → logits row select → LM head)."""
-    x = _rms(x, head["final_norm"], cfg.rms_norm_eps)
-    hm = head["embed"].T if cfg.tie_word_embeddings else head["lm_head"]
-    x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-    return jnp.matmul(x, hm, preferred_element_type=jnp.float32)[:, 0]
-
-
-def _whole_head_all_fn(cfg: LLaMAConfig, head, x, logits_idx):
-    """ALL-positions epilogue twin — op-for-op
-    :func:`serve_step_paged`'s ``all_logits=True`` tail (final norm →
-    LM head over every chunk column, no row select). The spec
-    draft/verify fold dispatches the whole-step walk with this head:
-    the verifier needs logits at every tree node, the draft pass at
-    every frontier column."""
-    del logits_idx
-    x = _rms(x, head["final_norm"], cfg.rms_norm_eps)
-    hm = head["embed"].T if cfg.tie_word_embeddings else head["lm_head"]
-    return jnp.matmul(x, hm, preferred_element_type=jnp.float32)
-
-
-def whole_step_tile_roles(
-    cfg: LLaMAConfig,
-) -> Dict[str, Tuple[str, Optional[str]]]:
-    """Sub-block streaming roles for this family
-    (serve/kernels._whole_step_decode_tiled): which per-layer weight
-    each canonical column-tiled role names, plus its bias (LLaMA
-    projections are bias-free). w1 gates, w3 lifts, w2 closes — the
-    SwiGLU naming of :func:`_block_paged_xla`."""
-    return {
-        "q": ("wq", None), "k": ("wk", None), "v": ("wv", None),
-        "o": ("wo", None), "gate": ("w1", None), "up": ("w3", None),
-        "down": ("w2", None),
-    }
-
-
-def _whole_tile_plan(cfg: LLaMAConfig, qmax):
-    """Closure bundle for the sub-block streaming walk — the SAME ops
-    :func:`_block_paged_xla` runs, split at the projection boundaries
-    so the kernel can column-tile each matmul (the elementwise and
-    residual pieces act slice-locally, so the tiled walk stays bitwise
-    the unfused step)."""
-    from ..serve import kernels as _pk
-
-    def pre_fn(p, x):
-        return _rms(x, p["attn_norm"], cfg.rms_norm_eps)
-
-    def attend_fn(p, q, k, v, cs, sn, mask, kb, vb, ks, vs, ph, of, pt):
-        dk = cfg.head_dim
-        R, C, _ = q.shape
-        q = q.reshape(R, C, -1, dk)
-        k = k.reshape(R, C, -1, dk)
-        v = v.reshape(R, C, -1, dk)
-        q = apply_rope(q, cs, sn)
-        k = apply_rope(k, cs, sn)
-        if qmax is not None:
-            from ..serve.kv_quant import quant_line_write
-
-            kb, ks = quant_line_write(kb, ks, ph, of, k, qmax)
-            vb, vs = quant_line_write(vb, vs, ph, of, v, qmax)
-        else:
-            kb = kb.at[ph, of].set(k.astype(kb.dtype))
-            vb = vb.at[ph, of].set(v.astype(vb.dtype))
-        if qmax is not None:
-            k_virt = _pk.dequant_pages(kb, ks, pt, q.dtype)
-            v_virt = _pk.dequant_pages(vb, vs, pt, q.dtype)
-        else:
-            k_virt = _pk.gather_pages(kb, pt)
-            v_virt = _pk.gather_pages(vb, pt)
-        attn = _attend_paged_xla(cfg, q, k_virt, v_virt, mask)
-        return attn, kb, vb, ks, vs
-
-    def mid_fn(p, x, h, x2):
-        return _rms(x2, p["ffn_norm"], cfg.rms_norm_eps)
-
-    def act_fn(g, u):
-        return jax.nn.silu(g) * u
-
-    return {
-        "roles": whole_step_tile_roles(cfg),
-        "mm_fn": _mm,
-        "pre_fn": pre_fn,
-        "attend_fn": attend_fn,
-        "mid_fn": mid_fn,
-        "act_fn": act_fn,
-    }
-
-
-def serve_step_whole(
-    params: Dict[str, Any],
-    cache: Dict[str, jnp.ndarray],
-    tokens: jnp.ndarray,      # (R, C) int32 — C=1 decode, C>1 mixed
-    positions: jnp.ndarray,   # (R, C) int32
-    logits_idx: jnp.ndarray,  # (R,) int32 (zeros at C=1)
-    page_table: jnp.ndarray,  # (R, NP) int32
-    *,
-    cfg: LLaMAConfig,
-    cache_len: int,
-    kv_quant: Optional[str] = None,
-    tp_mesh=None,
-    collective: str = "exact",
-    tiles: int = 1,
-    mask: Optional[jnp.ndarray] = None,       # (R, C, cache_len+1) bool
-    cache_positions: Optional[jnp.ndarray] = None,  # (R, C) cache lines
-    all_logits: bool = False,
-    num_layers: Optional[int] = None,
-):
-    """The WHOLE serving step as one program (ROADMAP 5a/5b,
-    MPK-style): embedding, all L layers (QKV → RoPE + KV page commit →
-    ragged paged attention → out-proj → MLP), final norm, LM head and
-    the greedy sampling epilogue. ``C == 1`` is the decode step;
-    ``C > 1`` is the whole-step MIXED step — chunked prefill and decode
-    rows walk the same persistent program, each row's head read at its
-    own ``logits_idx``. Single-shard meshes run it as ONE persistent
-    Pallas program whose grid walks the layers with double-buffered
-    weight streaming (serve/kernels.whole_step_decode); ``tiles > 1``
-    (the engine's VMEM gate, for layers whose working set exceeds the
-    budget) streams each projection weight in output-column sub-tiles
-    over an inner grid dimension instead of falling back. TP meshes
-    run the collective-explicit walk — the same per-layer body under a
-    manual ``model``-axis shard_map with ONE
-    ``serve/collectives.tp_allreduce`` per row-parallel matmul
-    (quantized EQuARX codes when ``collective="int8"``, literally
-    ``lax.psum`` in "exact" mode), still one dispatched program.
-
-    Returns ``(logits (R, V) f32, greedy_tokens (R,) int32,
-    new_cache)``. Bitwise contract: logits, greedy tokens and
-    non-scratch pool bytes are identical to
-    :func:`serve_step_paged`(kernels="xla") on the same backend (exact
-    collective mode; "int8" is a documented-tolerance trade) — at any
-    tile count, because tiles split only matmul OUTPUT columns.
-
-    The SPECULATION FOLD rides the same four optional kwargs
-    :func:`serve_step_paged` grew for it: an explicit tree ``mask``,
-    ``cache_positions`` for slack-line K/V placement, ``all_logits``
-    (logits at every chunk column — the all-positions head twin
-    :func:`_whole_head_all_fn`) and ``num_layers`` (the early-exit
-    draft walks only the first k grid steps; deeper pool rows pass
-    through untouched for the verify pass to own). With them the
-    draft pass and the verify pass of one SpecInfer round become two
-    dispatches of this ONE persistent program — same streamed weight
-    blocks, bitwise the unfused spec round. Not composed with
-    sub-block streaming (``tiles > 1``) or the TP walk."""
-    R, C = tokens.shape
-    ps = cache["k"].shape[2]
-    spec_fold = all_logits or num_layers is not None
-    if spec_fold and tiles > 1:
-        raise ValueError(
-            "the whole-step speculation fold (all_logits/num_layers) is "
-            "not composed with sub-block streaming (tiles > 1) — the "
-            "tiled walk's epilogue emits the single decode logits row"
-        )
-    if cache_positions is None:
-        cache_positions = positions
-    x = jnp.take(params["embed"], tokens.astype(jnp.int32), axis=0)
-    cos, sin = rope_freqs(cfg, positions)
-    mask = _paged_mask(mask, positions, page_table, ps, cache_len)
-    phys, off = _page_lookup(page_table, cache_positions, ps)
-    qmax = None
-    if kv_quant is not None:
-        from ..serve.kv_quant import resolve_spec
-
-        qmax = resolve_spec(kv_quant).qmax
-    from ..core.mesh import MODEL_AXIS
-
-    if tp_mesh is not None and tp_mesh.shape.get(MODEL_AXIS, 1) > 1:
-        if tiles > 1:
-            raise ValueError(
-                "whole-step sub-block streaming (tiles > 1) is not "
-                "composed with the TP walk — the collective-explicit "
-                "path is per-layer XLA, not one kernel"
-            )
-        if spec_fold:
-            raise ValueError(
-                "the whole-step speculation fold (all_logits/num_layers) "
-                "is not composed with the TP walk — the engine routes "
-                "TP spec rounds through the unfused paged step"
-            )
-        return _serve_step_whole_tp(
-            params, cache, x, cos, sin, mask, phys, off, page_table,
-            logits_idx, cfg=cfg, qmax=qmax, mesh=tp_mesh,
-            collective=collective,
-        )
-    layer_arrays, head_arrays = whole_step_weight_layout(params, cfg)
-    from ..serve import kernels as _pk
-
-    n = cfg.num_hidden_layers
-    if num_layers is not None:
-        n = min(num_layers, n)
-    sliced = n < cfg.num_hidden_layers
-    walk_cache = cache
-    if sliced:
-        # early-exit draft fold: the grid walks only the first n layers
-        # — slice the weight streams AND the pool rows (the walk derives
-        # L from the pool), then hand the deeper rows back untouched
-        # below, exactly serve_step_paged's num_layers contract
-        layer_arrays = {k: a[:n] for k, a in layer_arrays.items()}
-        walk_cache = {k: a[:n] for k, a in cache.items()}
-
-    def block_fn(p_l, xv, cs, sn, mk, kb, vb, ks, vs, ph, of, pt):
-        return _block_paged_xla(
-            cfg, p_l, xv, cs, sn, mk, kb, vb, ph, of, pt, ks, vs, qmax
-        )
-
-    if all_logits:
-        def head_fn(head, xv, li):
-            return _whole_head_all_fn(cfg, head, xv, li)
-    else:
-        def head_fn(head, xv, li):
-            return _whole_head_fn(cfg, head, xv, li)
-
-    plan = _whole_tile_plan(cfg, qmax) if tiles > 1 else None
-    logits, toks, new_cache = _pk.whole_step_decode(
-        layer_arrays, head_arrays, x, cos, sin, walk_cache, page_table,
-        phys, off, mask, logits_idx.astype(jnp.int32),
-        block_fn=block_fn, head_fn=head_fn, tiles=tiles, tile_plan=plan,
-    )
-    if sliced:
-        new_cache = {
-            k: jnp.concatenate([new_cache[k], cache[k][n:]], axis=0)
-            for k in new_cache
-        }
-    return logits, toks, new_cache
-
-
-def _serve_step_whole_tp(params, cache, x, cos, sin, mask, phys, off,
-                         page_table, logits_idx, *, cfg, qmax, mesh,
-                         collective):
-    """The TP whole-step walk: a manual ``model``-axis shard_map whose
-    per-shard body scans the layers through the SAME
-    :func:`_block_paged_xla` block (local head shards) with an explicit
-    :func:`..serve.collectives.tp_allreduce` as the row-parallel
-    reduction — issued per layer inside the walk, where the EQuARX
-    quantized mode shrinks the decode collective's bytes. "exact" mode
-    is lax.psum, bitwise the GSPMD reduction of the unfused step."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..core.mesh import MODEL_AXIS, shard_map_unchecked
-    from ..serve.collectives import tp_allreduce
-
-    n = mesh.shape[MODEL_AXIS]
-    quant = qmax is not None
-    tie = cfg.tie_word_embeddings
-    R = x.shape[0]
-
-    def _model_only(spec):
-        return P(*[MODEL_AXIS if s == MODEL_AXIS else None for s in spec])
-
-    layer_specs = jax.tree.map(
-        _model_only, param_pspecs(cfg)["layers"],
-        is_leaf=lambda s: isinstance(s, P),
-    )
-    cache_specs = {
-        name: _model_only(spec)
-        for name, spec in paged_kv_cache_pspecs(
-            cfg, kv_quant="int8" if quant else None
-        ).items()
-    }
-    cache_names = sorted(cache)
-    head_spec = (
-        P(None, None) if tie else _model_only(param_pspecs(cfg)["lm_head"])
-    )
-
-    def body(layers, final_norm, head_w, x_, cos_, sin_, mask_, phys_,
-             off_, pt_, li_, *cache_ops):
-        cc = dict(zip(cache_names, cache_ops))
-
-        def red(t):
-            return tp_allreduce(t, MODEL_AXIS, collective)
-
-        def scan_body(h, xs):
-            if quant:
-                p_l, kc, vc, ks, vs = xs
-            else:
-                p_l, kc, vc = xs
-                ks = vs = None
-            h, kc, vc, ks, vs = _block_paged_xla(
-                cfg, p_l, h, cos_, sin_, mask_, kc, vc, phys_, off_,
-                pt_, ks, vs, qmax, reduce_fn=red,
-            )
-            out = (kc, vc, ks, vs) if quant else (kc, vc)
-            return h, out
-
-        xs = (layers, cc["k"], cc["v"])
-        if quant:
-            xs = xs + (cc["k_scale"], cc["v_scale"])
-        h, new = jax.lax.scan(scan_body, x_, xs)
-        h = _rms(h, final_norm, cfg.rms_norm_eps)
-        h = jnp.take_along_axis(h, li_[:, None, None], axis=1)
-        if tie:
-            logits = jnp.matmul(
-                h, head_w.T, preferred_element_type=jnp.float32
-            )[:, 0]
-        else:
-            part = jnp.matmul(
-                h, head_w, preferred_element_type=jnp.float32
-            )[:, 0]  # (R, V/n) — vocab columns live on one shard each
-            logits = jax.lax.all_gather(
-                part, MODEL_AXIS, axis=1, tiled=True
-            )
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out_cc = {"k": new[0], "v": new[1]}
-        if quant:
-            out_cc["k_scale"], out_cc["v_scale"] = new[2], new[3]
-        return (logits, toks) + tuple(out_cc[nm] for nm in cache_names)
-
-    rep3 = P(None, None, None)
-    in_specs = [
-        layer_specs,
-        P(None),                                  # final_norm
-        head_spec,                                # embed / lm_head
-        rep3,                                     # x
-        rep3, rep3,                               # cos, sin
-        rep3,                                     # mask
-        P(None, None), P(None, None),             # phys, off
-        P(None, None),                            # page table
-        P(None),                                  # logits_idx
-    ] + [cache_specs[nm] for nm in cache_names]
-    out_specs = tuple(
-        [P(None, None), P(None)] + [cache_specs[nm] for nm in cache_names]
-    )
-    head_w = params["embed"] if tie else params["lm_head"]
-    fn = shard_map_unchecked(
-        body, mesh, tuple(in_specs), out_specs,
-        manual_axes={MODEL_AXIS},
-    )
-    outs = jax.jit(fn)(
-        params["layers"], params["final_norm"], head_w, x, cos, sin,
-        mask, phys.astype(jnp.int32), off.astype(jnp.int32),
-        page_table.astype(jnp.int32), logits_idx.astype(jnp.int32),
-        *[cache[nm] for nm in cache_names],
-    )
-    logits, toks = outs[0], outs[1]
-    new_cache = dict(zip(cache_names, outs[2:]))
-    return logits, toks, new_cache
 
 
 def copy_page_kv(

@@ -305,8 +305,7 @@ def validate_serving(cfg: SalaConfig, serving, mesh, *, specinfer: bool = False)
                "computed from full-precision lines")
     if serving.fused_decode:
         refuse(f"fused_decode={serving.fused_decode!r}",
-               "the fused prologue and the whole-step walk know one kind "
-               "of layer")
+               "the fused prologue knows one kind of layer")
     if serving.kv_shard == "context":
         refuse(f"kv_shard={serving.kv_shard!r}",
                "the block choice reads a row's whole context through one "

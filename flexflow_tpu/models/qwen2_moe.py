@@ -34,9 +34,6 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     serve_debug_activations,
     serve_step,
     serve_step_paged,
-    serve_step_whole,
-    whole_step_tile_roles,
-    whole_step_weight_layout,
 )
 from .hf_utils import layer_stackers, linear_w, stack, to_np
 

@@ -892,7 +892,7 @@ def reorder_slots(
 #: path at run time because the additive bias already excludes the
 #: Pallas kernel. The "sampling" epilogue fusion is model-agnostic —
 #: it lives in the engine's step program — so it is not listed here.
-FUSED_DECODE = ("rope_kv_write", "whole_step")
+FUSED_DECODE = ("rope_kv_write",)
 
 
 def init_paged_kv_cache(
@@ -1058,7 +1058,7 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     R, C, D = x.shape
     if cp_mesh is None and not (kernels == "pallas" and bias is None):
         # the unfused XLA path — the CPU-parity reference every fusion
-        # (and the whole-step megakernel) anchors on; ONE shared body
+        # anchors on
         return _block_paged_xla(
             cfg, p, x, rope, bias, mask, k_pool, v_pool, phys, off,
             page_table, k_scale, v_scale, qmax, layer=layer,
@@ -1148,94 +1148,17 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
 
 
-def _mm_reduced(x, w, reduce_fn):
-    """``_mm`` with a tensor-parallel partial-sum chokepoint (see
-    models/llama.py ``_mm_reduced``): the reduction applies to the f32
-    matmul output BEFORE the model-dtype cast — where GSPMD inserts its
-    all-reduce — so the collective-explicit whole-step walk stays
-    bitwise the GSPMD-scheduled step. None = literally ``_mm``."""
-    if reduce_fn is None:
-        return _mm(x, w)
-    out = jnp.matmul(
-        x, _dense_w(w, x.dtype), preferred_element_type=jnp.float32
-    )
-    return reduce_fn(out).astype(x.dtype)
-
-
-def _project_qkv_local(cfg: DecoderConfig, p, h):
-    """:func:`_project_qkv` with head counts derived from the WEIGHT
-    shapes instead of cfg — op-for-op identical on the single-shard
-    path, and what lets the same body serve TP-local head shards."""
-    B, S, _ = h.shape
-    dk = cfg.head_dim
-    q = _mm(h, p["wq"])
-    k = _mm(h, p["wk"])
-    v = _mm(h, p["wv"])
-    if cfg.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    return (
-        q.reshape(B, S, -1, dk),
-        k.reshape(B, S, -1, dk),
-        v.reshape(B, S, -1, dk),
-    )
-
-
-def _attend_paged_xla(cfg: DecoderConfig, q, k_virt, v_virt, bias, mask):
-    """:func:`_serve_attend` with KV heads derived from the operands
-    (see :func:`_project_qkv_local` for why)."""
-    R, C, H, dk = q.shape
-    KV = k_virt.shape[2]
-    G = H // KV
-    qg = q.reshape(R, C, KV, G, dk)
-    scores = jnp.einsum(
-        "rckgd,rskd->rkgcs", qg, k_virt, preferred_element_type=jnp.float32
-    ) / math.sqrt(cfg.head_dim)
-    if bias is not None:
-        scores = scores + bias.reshape(R, KV, G, *bias.shape[-2:])
-    scores = jnp.where(mask[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("rkgcs,rskd->rckgd", probs, v_virt)
-    return out.reshape(R, C, H * dk)
-
-
-def _ffn_reduced(cfg: DecoderConfig, p, h, reduce_fn):
-    """:func:`_ffn` with the row-parallel down-projection routed
-    through ``reduce_fn`` (None = literally ``_ffn``; MoE FFNs never
-    reach here with a reduce_fn — the whole-step layout hook excludes
-    them)."""
-    if reduce_fn is None:
-        return _ffn(cfg, p, h)
-    up = _mm(h, p["w_up"])
-    if cfg.mlp_bias:
-        up = up + p["b_up"]
-    if cfg.glu:
-        gate = _mm(h, p["w_gate"])
-        if cfg.mlp_bias:
-            gate = gate + p["b_gate"]
-        act = _activation(cfg, gate) * up
-    else:
-        act = _activation(cfg, up)
-    out = _mm_reduced(act, p["w_down"], reduce_fn)
-    if cfg.mlp_bias:
-        out = out + p["b_down"]
-    return out
-
-
 def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
                      k_pool, v_pool, phys, off, page_table,
-                     k_scale=None, v_scale=None, qmax=None,
-                     reduce_fn=None, layer=None):
-    """One block of the UNFUSED XLA paged step on values — the shared
-    body of :func:`serve_block_paged`'s XLA path AND the whole-step
-    decode megakernel / TP walk (:func:`serve_step_whole`); one
-    definition is what makes whole-step decode bitwise the unfused XLA
-    step (see the llama twin for the full rationale). ``layer``: the
-    pools are the stacked ones (see :func:`serve_block_paged`)."""
+                     k_scale=None, v_scale=None, qmax=None, layer=None):
+    """One block of the UNFUSED XLA paged step: the body of
+    :func:`serve_block_paged`'s XLA path. ``layer``: the pools are the
+    stacked ones (see :func:`serve_block_paged`)."""
     from ..serve import kernels as _pk
 
     R, C, D = x.shape
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
-    q, k, v = _project_qkv_local(cfg, p, h)
+    q, k, v = _project_qkv(cfg, p, h)
     if rope is not None:
         cos, sin = rope
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
@@ -1253,8 +1176,8 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
     else:
         k_virt = _pk.gather_pages(k_l, page_table)
         v_virt = _pk.gather_pages(v_l, page_table)
-    attn = _attend_paged_xla(cfg, q, k_virt, v_virt, bias, mask)
-    attn = _mm_reduced(attn, p["wo"], reduce_fn)
+    attn = _serve_attend(cfg, q, k_virt, v_virt, bias, mask)
+    attn = _mm(attn, p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
     if cfg.parallel_block:
@@ -1262,12 +1185,10 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
             h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
         else:
             h2 = h
-        return (x + attn + _ffn_reduced(cfg, p, h2, reduce_fn),
-                k_pool, v_pool, k_scale, v_scale)
+        return x + attn + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
     x = x + attn
     h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-    return (x + _ffn_reduced(cfg, p, h2, reduce_fn),
-            k_pool, v_pool, k_scale, v_scale)
+    return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
 
 
 def _paged_serve_context(cfg, cache, positions, cache_positions, mask,
@@ -1390,421 +1311,6 @@ def serve_step_paged(
     if needs_pos_cache(cfg):
         new_cache["pos"] = pos_pool
     return logits, new_cache
-
-
-# ---------------------------------------------------------------------------
-# Whole-step decode megakernel (see models/llama.py's twin and
-# serve/kernels.whole_step_decode for the program design). The generic
-# decoder supports the walk for the configs whose block math the
-# streamed kernel body can run; the layout hook gates the rest with a
-# construction-time error naming the fix.
-
-
-def whole_step_weight_layout(
-    params: Dict[str, Any], cfg: DecoderConfig
-) -> Tuple[Dict[str, jnp.ndarray], Dict[str, jnp.ndarray]]:
-    """Weight layout for blocked HBM→VMEM streaming (see the llama
-    twin): ``(layer_arrays, head_arrays)``. Raises ValueError for
-    configs the walk cannot serve: MoE FFNs (the routed expert einsums
-    have no streamable per-layer block mapping yet), ALiBi /
-    sliding-window families (attention needs the paged position buffer,
-    which is not layer-streamed), and weight-only quantized params."""
-    if cfg.num_local_experts:
-        raise ValueError(
-            "whole_step is not composed with mixture-of-experts FFNs — "
-            "the routed expert contraction has no streamed per-layer "
-            "weight block yet; drop the whole_step fusion for this "
-            "family"
-        )
-    if needs_pos_cache(cfg):
-        raise ValueError(
-            "whole_step is not composed with ALiBi / sliding-window "
-            "families — their attention reads the per-line position "
-            "buffer, which the layer walk does not stream; drop the "
-            "whole_step fusion for this family"
-        )
-    L = cfg.num_hidden_layers
-    layer_arrays = {}
-    for name, a in params["layers"].items():
-        if isinstance(a, dict):
-            raise ValueError(
-                "whole_step is not composed with weight-only "
-                f"quantization (layer tensor {name!r} is a quantized "
-                "{'q','scale'} pair) — serve full-precision params or "
-                "drop the whole_step fusion"
-            )
-        if a.shape[0] != L:
-            raise ValueError(
-                f"layer tensor {name!r} leading dim {a.shape[0]} != "
-                f"num_hidden_layers {L}"
-            )
-        layer_arrays[name] = a
-    head_arrays = {"final_norm_scale": params["final_norm_scale"]}
-    if "final_norm_bias" in params:
-        head_arrays["final_norm_bias"] = params["final_norm_bias"]
-    if cfg.tie_word_embeddings:
-        head_arrays["embed"] = params["embed"]
-    else:
-        if isinstance(params["lm_head"], dict):
-            raise ValueError(
-                "whole_step is not composed with a weight-only "
-                "quantized lm_head"
-            )
-        head_arrays["lm_head"] = params["lm_head"]
-        if "lm_head_bias" in params:
-            head_arrays["lm_head_bias"] = params["lm_head_bias"]
-    return layer_arrays, head_arrays
-
-
-def _whole_head_fn(cfg: DecoderConfig, head, x, logits_idx):
-    """Epilogue on values — op-for-op :func:`serve_step_paged`'s tail
-    (final norm → logits row select → :func:`_lm_logits`)."""
-    x = _norm(cfg, x, head["final_norm_scale"],
-              head.get("final_norm_bias"))
-    x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-    hm = head["embed"].T if cfg.tie_word_embeddings else head["lm_head"]
-    logits = jnp.matmul(x, hm, preferred_element_type=jnp.float32)
-    if "lm_head_bias" in head:
-        logits = logits + head["lm_head_bias"].astype(jnp.float32)
-    return logits[:, 0]
-
-
-def _whole_head_all_fn(cfg: DecoderConfig, head, x, logits_idx):
-    """ALL-positions epilogue twin — op-for-op
-    :func:`serve_step_paged`'s ``all_logits=True`` tail (final norm →
-    LM head over every chunk column). The spec draft/verify fold's
-    head (see the llama twin)."""
-    del logits_idx
-    x = _norm(cfg, x, head["final_norm_scale"],
-              head.get("final_norm_bias"))
-    hm = head["embed"].T if cfg.tie_word_embeddings else head["lm_head"]
-    logits = jnp.matmul(x, hm, preferred_element_type=jnp.float32)
-    if "lm_head_bias" in head:
-        logits = logits + head["lm_head_bias"].astype(jnp.float32)
-    return logits
-
-
-def whole_step_tile_roles(
-    cfg: DecoderConfig,
-) -> Dict[str, Tuple[str, Optional[str]]]:
-    """Sub-block streaming roles for the generic decoder
-    (serve/kernels._whole_step_decode_tiled): the canonical
-    column-tiled projection roles mapped to this family's weight and
-    bias names — biases ride per cfg flag, "gate" only for GLU MLPs."""
-    roles = {
-        "q": ("wq", "bq" if cfg.qkv_bias else None),
-        "k": ("wk", "bk" if cfg.qkv_bias else None),
-        "v": ("wv", "bv" if cfg.qkv_bias else None),
-        "o": ("wo", "bo" if cfg.out_bias else None),
-        "up": ("w_up", "b_up" if cfg.mlp_bias else None),
-        "down": ("w_down", "b_down" if cfg.mlp_bias else None),
-    }
-    if cfg.glu:
-        roles["gate"] = ("w_gate", "b_gate" if cfg.mlp_bias else None)
-    return roles
-
-
-def _whole_tile_plan(cfg: DecoderConfig, qmax):
-    """Closure bundle for the sub-block streaming walk — the SAME ops
-    :func:`_block_paged_xla` runs, split at the projection boundaries
-    (see the llama twin). ``mid_fn`` carries the parallel-block norm
-    routing: parallel blocks feed the MLP the pre-attention norm (or
-    their second norm), sequential blocks norm the post-attention
-    residual."""
-    from ..serve import kernels as _pk
-
-    def pre_fn(p, x):
-        return _norm(cfg, x, p["attn_norm_scale"],
-                     p.get("attn_norm_bias"))
-
-    def attend_fn(p, q, k, v, cs, sn, mask, kb, vb, ks, vs, ph, of, pt):
-        dk = cfg.head_dim
-        R, C, _ = q.shape
-        q = q.reshape(R, C, -1, dk)
-        k = k.reshape(R, C, -1, dk)
-        v = v.reshape(R, C, -1, dk)
-        if cs is not None:
-            q, k = apply_rope(q, cs, sn), apply_rope(k, cs, sn)
-        if qmax is not None:
-            from ..serve.kv_quant import quant_line_write
-
-            kb, ks = quant_line_write(kb, ks, ph, of, k, qmax)
-            vb, vs = quant_line_write(vb, vs, ph, of, v, qmax)
-        else:
-            kb = kb.at[ph, of].set(k.astype(kb.dtype))
-            vb = vb.at[ph, of].set(v.astype(vb.dtype))
-        if qmax is not None:
-            k_virt = _pk.dequant_pages(kb, ks, pt, q.dtype)
-            v_virt = _pk.dequant_pages(vb, vs, pt, q.dtype)
-        else:
-            k_virt = _pk.gather_pages(kb, pt)
-            v_virt = _pk.gather_pages(vb, pt)
-        attn = _attend_paged_xla(cfg, q, k_virt, v_virt, None, mask)
-        return attn, kb, vb, ks, vs
-
-    def mid_fn(p, x, h, x2):
-        if cfg.parallel_block:
-            if cfg.parallel_two_norms:
-                return _norm(cfg, x, p["mlp_norm_scale"],
-                             p.get("mlp_norm_bias"))
-            return h
-        return _norm(cfg, x2, p["mlp_norm_scale"],
-                     p.get("mlp_norm_bias"))
-
-    def act_fn(g, u):
-        if g is not None:
-            return _activation(cfg, g) * u
-        return _activation(cfg, u)
-
-    return {
-        "roles": whole_step_tile_roles(cfg),
-        "mm_fn": _mm,
-        "pre_fn": pre_fn,
-        "attend_fn": attend_fn,
-        "mid_fn": mid_fn,
-        "act_fn": act_fn,
-    }
-
-
-def serve_step_whole(
-    params: Dict[str, Any],
-    cache: Dict[str, jnp.ndarray],
-    tokens: jnp.ndarray,      # (R, C) int32 — C=1 decode, C>1 mixed
-    positions: jnp.ndarray,   # (R, C) int32
-    logits_idx: jnp.ndarray,  # (R,) int32
-    page_table: jnp.ndarray,  # (R, NP) int32
-    *,
-    cfg: DecoderConfig,
-    cache_len: int,
-    kv_quant: Optional[str] = None,
-    tp_mesh=None,
-    collective: str = "exact",
-    tiles: int = 1,
-    mask: Optional[jnp.ndarray] = None,       # (R, C, cache_len+1) bool
-    cache_positions: Optional[jnp.ndarray] = None,  # (R, C) cache lines
-    all_logits: bool = False,
-    num_layers: Optional[int] = None,
-):
-    """The WHOLE serving step as one program — the generic-decoder twin
-    of models/llama.serve_step_whole (same contract: returns
-    ``(logits, greedy_tokens, new_cache)``, bitwise the unfused
-    kernels="xla" step on the same backend under the "exact"
-    collective). ``C == 1`` is the decode step, ``C > 1`` the
-    whole-step mixed step; ``tiles > 1`` streams each projection
-    weight in output-column sub-tiles (the engine's VMEM gate picks
-    the count — see the llama twin). The SPECULATION FOLD kwargs
-    (explicit tree ``mask``, slack-line ``cache_positions``,
-    ``all_logits``, early-exit ``num_layers``) turn one SpecInfer
-    round's draft and verify passes into two dispatches of this one
-    persistent program — see the llama twin; not composed with
-    ``tiles > 1`` or the TP walk."""
-    from ..serve.kernels import paged_serve_mask
-
-    R, C = tokens.shape
-    ps = cache["k"].shape[2]
-    spec_fold = all_logits or num_layers is not None
-    if spec_fold and tiles > 1:
-        raise ValueError(
-            "the whole-step speculation fold (all_logits/num_layers) is "
-            "not composed with sub-block streaming (tiles > 1) — the "
-            "tiled walk's epilogue emits the single decode logits row"
-        )
-    if cache_positions is None:
-        cache_positions = positions
-    x = _embed_in(cfg, params, tokens, positions)
-    rope = rope_freqs(cfg, positions) if cfg.positions == "rope" else None
-    mask = paged_serve_mask(
-        mask, positions, page_table.shape[1], ps, cache_len
-    )
-    phys, off = _page_lookup(page_table, cache_positions, ps)
-    qmax = None
-    if kv_quant is not None:
-        from ..serve.kv_quant import resolve_spec
-
-        qmax = resolve_spec(kv_quant).qmax
-    from ..core.mesh import MODEL_AXIS
-
-    if tp_mesh is not None and tp_mesh.shape.get(MODEL_AXIS, 1) > 1:
-        if tiles > 1:
-            raise ValueError(
-                "whole-step sub-block streaming (tiles > 1) is not "
-                "composed with the TP walk — the collective-explicit "
-                "path is per-layer XLA, not one kernel"
-            )
-        if spec_fold:
-            raise ValueError(
-                "the whole-step speculation fold (all_logits/num_layers) "
-                "is not composed with the TP walk — the engine routes "
-                "TP spec rounds through the unfused paged step"
-            )
-        return _serve_step_whole_tp(
-            params, cache, x, rope, mask, phys, off, page_table,
-            logits_idx, cfg=cfg, qmax=qmax, mesh=tp_mesh,
-            collective=collective,
-        )
-    layer_arrays, head_arrays = whole_step_weight_layout(params, cfg)
-    from ..serve import kernels as _pk
-
-    cos, sin = rope if rope is not None else (None, None)
-
-    n = cfg.num_hidden_layers
-    if num_layers is not None:
-        n = min(num_layers, n)
-    sliced = n < cfg.num_hidden_layers
-    walk_cache = cache
-    if sliced:
-        # early-exit draft fold: walk only the first n layers; deeper
-        # pool rows are handed back untouched below (serve_step_paged's
-        # num_layers contract)
-        layer_arrays = {k: a[:n] for k, a in layer_arrays.items()}
-        walk_cache = {k: a[:n] for k, a in cache.items()}
-
-    def block_fn(p_l, xv, cs, sn, mk, kb, vb, ks, vs, ph, of, pt):
-        rp = (cs, sn) if cs is not None else None
-        return _block_paged_xla(
-            cfg, p_l, xv, rp, None, mk, kb, vb, ph, of, pt, ks, vs, qmax
-        )
-
-    if all_logits:
-        def head_fn(head, xv, li):
-            return _whole_head_all_fn(cfg, head, xv, li)
-    else:
-        def head_fn(head, xv, li):
-            return _whole_head_fn(cfg, head, xv, li)
-
-    plan = _whole_tile_plan(cfg, qmax) if tiles > 1 else None
-    logits, toks, new_cache = _pk.whole_step_decode(
-        layer_arrays, head_arrays, x, cos, sin, walk_cache, page_table,
-        phys, off, mask, logits_idx.astype(jnp.int32),
-        block_fn=block_fn, head_fn=head_fn, tiles=tiles, tile_plan=plan,
-    )
-    if sliced:
-        new_cache = {
-            k: jnp.concatenate([new_cache[k], cache[k][n:]], axis=0)
-            for k in new_cache
-        }
-    return logits, toks, new_cache
-
-
-def _serve_step_whole_tp(params, cache, x, rope, mask, phys, off,
-                         page_table, logits_idx, *, cfg, qmax, mesh,
-                         collective):
-    """The TP whole-step walk (see the llama twin): manual ``model``-
-    axis shard_map, per-layer :func:`_block_paged_xla` with explicit
-    :func:`..serve.collectives.tp_allreduce` row-parallel reductions."""
-    from ..core.mesh import MODEL_AXIS, shard_map_unchecked
-    from ..serve.collectives import tp_allreduce
-
-    whole_step_weight_layout(params, cfg)  # capability gate, fail fast
-    quant = qmax is not None
-    tie = cfg.tie_word_embeddings
-    has_rope = rope is not None
-
-    def _model_only(spec):
-        return P(*[MODEL_AXIS if s == MODEL_AXIS else None for s in spec])
-
-    pspecs = param_pspecs(cfg)
-    layer_specs = jax.tree.map(
-        _model_only, pspecs["layers"], is_leaf=lambda s: isinstance(s, P)
-    )
-    cache_specs = {
-        name: _model_only(spec)
-        for name, spec in paged_kv_cache_pspecs(
-            cfg, kv_quant="int8" if quant else None
-        ).items()
-    }
-    cache_names = sorted(cache)
-    head_names = ["final_norm_scale"]
-    if "final_norm_bias" in params:
-        head_names.append("final_norm_bias")
-    if tie:
-        head_names.append("embed")
-    else:
-        head_names.append("lm_head")
-        if "lm_head_bias" in params:
-            head_names.append("lm_head_bias")
-    head_specs = [
-        _model_only(pspecs[n]) if n in ("lm_head", "lm_head_bias")
-        else P(*([None] * params[n].ndim))
-        for n in head_names
-    ]
-
-    def body(layers, x_, mask_, phys_, off_, pt_, li_, *rest):
-        nh = len(head_names)
-        heads = dict(zip(head_names, rest[:nh]))
-        i = nh
-        if has_rope:
-            rp = (rest[i], rest[i + 1])
-            i += 2
-        else:
-            rp = None
-        cc = dict(zip(cache_names, rest[i:]))
-
-        def red(t):
-            return tp_allreduce(t, MODEL_AXIS, collective)
-
-        def scan_body(h, xs):
-            if quant:
-                p_l, kc, vc, ks, vs = xs
-            else:
-                p_l, kc, vc = xs
-                ks = vs = None
-            h, kc, vc, ks, vs = _block_paged_xla(
-                cfg, p_l, h, rp, None, mask_, kc, vc, phys_, off_,
-                pt_, ks, vs, qmax, reduce_fn=red,
-            )
-            return h, (kc, vc, ks, vs) if quant else (kc, vc)
-
-        xs = (layers, cc["k"], cc["v"])
-        if quant:
-            xs = xs + (cc["k_scale"], cc["v_scale"])
-        h, new = lax.scan(scan_body, x_, xs)
-        h = _norm(cfg, h, heads["final_norm_scale"],
-                  heads.get("final_norm_bias"))
-        h = jnp.take_along_axis(h, li_[:, None, None], axis=1)
-        if tie:
-            logits = jnp.matmul(
-                h, heads["embed"].T, preferred_element_type=jnp.float32
-            )[:, 0]
-        else:
-            part = jnp.matmul(
-                h, heads["lm_head"], preferred_element_type=jnp.float32
-            )
-            if "lm_head_bias" in heads:
-                part = part + heads["lm_head_bias"].astype(jnp.float32)
-            part = part[:, 0]  # (R, V/n)
-            logits = jax.lax.all_gather(
-                part, MODEL_AXIS, axis=1, tiled=True
-            )
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out_cc = {"k": new[0], "v": new[1]}
-        if quant:
-            out_cc["k_scale"], out_cc["v_scale"] = new[2], new[3]
-        return (logits, toks) + tuple(out_cc[nm] for nm in cache_names)
-
-    rep3 = P(None, None, None)
-    in_specs = [layer_specs, rep3, rep3, P(None, None), P(None, None),
-                P(None, None), P(None)] + head_specs
-    operands = [
-        params["layers"], x, mask, phys.astype(jnp.int32),
-        off.astype(jnp.int32), page_table.astype(jnp.int32),
-        logits_idx.astype(jnp.int32),
-    ] + [params[n] for n in head_names]
-    if has_rope:
-        in_specs += [rep3, rep3]
-        operands += [rope[0], rope[1]]
-    in_specs += [cache_specs[nm] for nm in cache_names]
-    operands += [cache[nm] for nm in cache_names]
-    out_specs = tuple(
-        [P(None, None), P(None)] + [cache_specs[nm] for nm in cache_names]
-    )
-    fn = shard_map_unchecked(
-        body, mesh, tuple(in_specs), out_specs, manual_axes={MODEL_AXIS},
-    )
-    outs = jax.jit(fn)(*operands)
-    logits, toks = outs[0], outs[1]
-    new_cache = dict(zip(cache_names, outs[2:]))
-    return logits, toks, new_cache
 
 
 def copy_page_kv(cache, src, dst):

@@ -110,14 +110,13 @@ SCHED_COUNTERS = frozenset({
     "spills", "readmits", "host_hit_tokens",
     "spec_rounds", "spec_drafted", "spec_accepted", "spec_resizes",
     "verify_skipped_rounds", "spec_reprobes",
-    "ring_steps", "compiles", "retraces", "whole_step_fallbacks",
+    "ring_steps", "compiles", "retraces",
     "real_rows", "state_resets", "sparse_rows",
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({
-    "host_bytes", "cp_shards", "shard_balance", "whole_step_vmem_est",
-    "slot_state_bytes",
+    "host_bytes", "cp_shards", "shard_balance", "slot_state_bytes",
 })
 #: SchedulerStats fields NOT exported verbatim — each maps to the
 #: derived snapshot() gauge that replaces it on the scrape surface.

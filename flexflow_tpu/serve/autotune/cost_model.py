@@ -2,8 +2,7 @@
 
 The training search (``search/unity.py``) already has what the paper
 calls the simulator: per-op rooflines + ring-collective formulas from
-``search/machine_model.py`` with predicted-vs-measured validation in
-bench. This module is the SERVING counterpart: the same chip model,
+``search/machine_model.py``. This module is the SERVING counterpart: the same chip model,
 priced over the serving-specific kernel regimes the repo actually
 ships —
 
@@ -11,18 +10,14 @@ ships —
   step reads the full (TP-sharded) weight set once plus every live
   request's KV context (fp / int8 / int4 pages), so step time is
   ``max(flops, bytes)`` through :func:`~..search.machine_model
-  .compute_time` with bytes dominating at serving batch sizes. The
-  whole-step megakernel (PR 15/16) collapses per-layer dispatch
-  overhead to one program; the unfused path pays a per-layer launch
-  tax.
+  .compute_time` with bytes dominating at serving batch sizes, plus
+  one program launch a step.
 * **prefill** is compute-bound: ``2·params`` FLOPs per uncached prompt
   token (prefix caching removes the cached share), chunked at
   ``prefill_chunk``.
 * **TP collectives** go through :class:`~..search.machine_model
   .CollectiveModel` ring formulas over the topology's link degrees —
-  two all-reduces of the batch's activations per layer, with the
-  EQuARX-style int8 reduce (``quantized_allreduce``) shipping ~27% of
-  the f32 bytes.
+  two all-reduces of the batch's activations per layer.
 * **speculation** multiplies committed tokens per verify step by the
   expected accepted path length (a geometric series in the accept
   rate over the bucket ladder's depth), while the verify step prices
@@ -32,9 +27,7 @@ Queueing is a deterministic M/D/c-flavored approximation over
 Little's-law concurrency — good enough to RANK configurations, which
 is all the offline search and the online autoscaler consume. On this
 CPU box the absolute numbers are fiction (the chip constants describe
-a TPU); predictions are ranked, not absolute, off-chip — the README
-design note and the bench ``serve_autotune`` phase (rank correlation,
-not error bars) both carry that caveat. :func:`~..search.machine_model
+a TPU); predictions are ranked, not absolute, off-chip. :func:`~..search.machine_model
 .calibrate_chip` substitutes host-measured constants where absolute
 numbers matter.
 """
@@ -65,10 +58,8 @@ __all__ = [
 #: serve/kv_quant.py), int4 packs two codes per byte (>=3.8x).
 _KV_QUANT_BYTES = {None: 2.0, "int8": 1.05, "int4": 0.53}
 
-#: Host-side dispatch overhead per launched program (s). The unfused
-#: decode step launches ~2 programs per layer; the whole-step
-#: megakernel launches ONE per step — this constant is what makes the
-#: cost model reproduce the PR-15/16 fusion win.
+#: Host-side dispatch overhead per launched program (s): one a decode
+#: step, one a prefill chunk.
 _DISPATCH_S = 8e-6
 
 #: Dequantization arithmetic per quantized KV byte read (FLOPs): the
@@ -191,8 +182,6 @@ class ServingCandidate:
     #: W×D ladder top rung the speculative arm drafts at
     spec_width: int = 2
     spec_depth: int = 4
-    whole_step: bool = True
-    quantized_allreduce: Optional[str] = None
     max_requests_per_batch: int = 16
     max_sequence_length: int = 2048
     prefill_chunk: int = 128
@@ -211,7 +200,6 @@ class ServingCandidate:
 
         from ..engine import ServingConfig
 
-        fused = ("whole_step",) if self.whole_step else ()
         kw = dict(
             max_requests_per_batch=self.max_requests_per_batch,
             max_sequence_length=self.max_sequence_length,
@@ -222,11 +210,6 @@ class ServingCandidate:
             replicas=self.replicas,
             prefill_replicas=self.prefill_replicas,
             decode_replicas=self.decode_replicas,
-            fused_decode=fused,
-            quantized_allreduce=(
-                self.quantized_allreduce if self.whole_step and self.tp > 1
-                else None
-            ),
         )
         kw.update(overrides)
         if base is not None:
@@ -311,16 +294,10 @@ class ServingCostModel:
         # layer, through the ring model's link degrees
         if cand.tp > 1:
             ar_bytes = rows * g.hidden_size * g.param_bytes
-            if cand.quantized_allreduce == "int8":
-                ar_bytes *= 0.27
             t += (g.num_layers / cand.pp) * 2.0 * self.collectives.all_reduce(
                 ar_bytes, cand.tp, "model"
             )
-        # dispatch overhead: one program per step under whole_step, ~2
-        # per layer unfused (the PR-6 per-layer fusions)
-        launches = 1.0 if cand.whole_step else 2.0 * g.num_layers / cand.pp
-        t += launches * _DISPATCH_S
-        return t
+        return t + _DISPATCH_S
 
     def _scaled_chip(self, oversubscription: float) -> TPUChip:
         if oversubscription <= 1.0:
@@ -375,9 +352,7 @@ class ServingCostModel:
                 ar_bytes, cand.tp, "model"
             )
         chunks = math.ceil(uncached / max(1, cand.prefill_chunk))
-        t += chunks * _DISPATCH_S * (
-            1.0 if cand.whole_step else 2.0 * g.num_layers / cand.pp
-        )
+        t += chunks * _DISPATCH_S
         # pipeline fill: the first token crosses every stage once
         t += (cand.pp - 1) * self.topo.per_hop_latency
         return t

@@ -193,8 +193,6 @@ class Autoscaler:
                 replicas - min(pf, max(0, replicas - 1)) if pf else 0
             ),
             speculation=self.estimator.spec_accept_rate() > 0,
-            whole_step="whole_step" in sc.fused_decode,
-            quantized_allreduce=sc.quantized_allreduce,
             max_requests_per_batch=sc.max_requests_per_batch,
             max_sequence_length=sc.max_sequence_length,
             prefill_chunk=sc.prefill_chunk,

@@ -14,10 +14,8 @@ fail-before-emit discipline the engine applies at construction.
 
 SLOs are CONSTRAINTS, not weights: a candidate whose predicted TTFT/
 TPOT p99 breaches the SLO is infeasible however fast it is, exactly
-like unity's memory-budget λ treatment. Predicted-vs-measured is
-validated in bench (``serve_autotune`` phase) the way
-``unity_searched_train_mfu`` validates the training search — by rank
-correlation on this box, absolute error on a chip.
+like unity's memory-budget λ treatment. Predicted-vs-measured on the
+chip is ROADMAP A12's: no run has checked it yet.
 """
 from __future__ import annotations
 
@@ -170,8 +168,7 @@ def search_serving_config(
     # (the unity backtracking flavor: one axis at a time, keep a move
     # only if it strictly improves the key, loop until a full sweep
     # makes no move)
-    axes = ("tp", "pp", "replicas", "page_size", "kv_quant",
-            "speculation", "whole_step")
+    axes = ("tp", "pp", "replicas", "page_size", "kv_quant", "speculation")
     moved = True
     while moved:
         moved = False
@@ -212,6 +209,4 @@ def _axis_values(axis: str, cur: ServingCandidate, chip_budget: int,
         if traffic.spec_accept_rate <= 0 or cur.prefill_replicas:
             return []
         return [not cur.speculation]
-    if axis == "whole_step":
-        return [not cur.whole_step]
     return []

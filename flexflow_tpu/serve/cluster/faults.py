@@ -6,7 +6,7 @@ reproduces under a real outage is a failover that was never tested. So
 the harness ships with the feature — a :class:`FaultPlan` scripts
 exactly which replica fails, how, and at which replica-local step, and
 the same plan replays the same scenario bit-for-bit (tests/
-test_cluster_faults.py, bench ``serve_faults``).
+test_cluster_faults.py).
 
 Faults are wired at the :class:`~.replica.Replica` surface — the same
 five-method boundary a multi-host deployment would put RPC behind, so

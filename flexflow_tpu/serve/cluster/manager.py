@@ -1505,9 +1505,7 @@ class ClusterManager:
         """The original one-RPC-at-a-time drive loop — kept verbatim as
         the reference arm (``ServingConfig.concurrent_stepping=False``,
         and what the in-process cluster runs): the concurrent loop's
-        contract is to be indistinguishable from THIS, and the
-        ``serve_cluster_async`` bench measures the two against each
-        other."""
+        contract is to be indistinguishable from THIS."""
         progressed = False
         for pos in range(len(self.replicas)):
             rep = self.replicas[pos]

@@ -165,36 +165,9 @@ class ServingConfig:
     #     batches skip the (R, V) sorts entirely, and the sync path
     #     drops from two dispatched programs per step (step + host-side
     #     sample) to one (engine.run_sampled).
-    #   "whole_step" — the WHOLE decode step (embedding, all L layers'
-    #     QKV/attention/MLP, the fused RoPE+KV-write prologue, ragged
-    #     paged attention over fp/int8/int4 pools, final norm, LM head
-    #     and the greedy sampling epilogue) runs as ONE persistent
-    #     Pallas program whose grid walks the layers with
-    #     double-buffered HBM→VMEM weight streaming
-    #     (serve/kernels.whole_step_decode; models/*.serve_step_whole).
-    #     Paged layout only; families advertise support via
-    #     FUSED_DECODE and gate unstreamable layouts (MoE, ALiBi,
-    #     weight-quantized params) in whole_step_weight_layout. On TP
-    #     meshes the walk runs collective-explicit (one
-    #     serve/collectives.tp_allreduce per row-parallel matmul —
-    #     see quantized_allreduce), still one dispatched program. When
-    #     the per-layer working set exceeds the VMEM budget
-    #     (kernels.WHOLE_STEP_VMEM_BUDGET, FF_WHOLE_STEP_VMEM_MB) the
-    #     engine logs and FALLS BACK to the PR-6 per-layer fusions.
-    #     Bitwise the unfused kernels="xla" step on the same backend.
     # Off by default; () compiles exactly the pre-fusion step programs
     # under exactly the pre-fusion step keys.
     fused_decode: Tuple[str, ...] = ()
-    # Quantized TP decode collectives (serve/collectives.py, EQuARX —
-    # PAPERS.md arxiv 2506.17615), whole_step + TP meshes only. None or
-    # "exact": the walk's per-layer allreduce is literally lax.psum —
-    # bitwise the GSPMD reduction of the unfused step. "int8": the
-    # reduce ships int8 codes + per-128-block f32 amax scales (~27% of
-    # the f32 bytes) and accumulates dequantized shards in absolute
-    # shard order — deterministic, greedy-token-stable in practice, but
-    # NOT bitwise (per-element error ≤ n·amax_block/254; an explicit
-    # accuracy/bandwidth trade like kv_quant).
-    quantized_allreduce: Optional[str] = None
     # Cluster serving (serve/cluster/): one process drives this many
     # engine replicas — each its own mesh and KV pool — behind a
     # front-end Router (prefix-cache-aware placement, session affinity,
@@ -654,14 +627,6 @@ class ServingConfig:
                 "not shard-local; drop kv_quant or the fusion (full-"
                 "precision pools compose)"
             )
-        if "whole_step" in (self.fused_decode or ()) and mesh_seq_degree > 1:
-            raise ValueError(
-                "fused_decode='whole_step' is not composed with ring "
-                "context parallelism on a sequence-sharded mesh — the "
-                "layer walk gathers pages through the full table; serve "
-                "whole_step with context_shards on a seq-degree-1 mesh "
-                "(the layout-blind gather), or drop one of the two"
-            )
 
     @property
     def cache_len(self) -> int:
@@ -723,12 +688,6 @@ def program_name(key: Any) -> str:
         chunk, with_mask, mode, cap, with_logits = rest
         return (f"ff_step_sampled_c{chunk}"
                 + flags(logits=with_logits, mask=with_mask) + head(mode, cap))
-    if kind == "whole_step":
-        chunk, tiles, mode, cap, with_logits = rest
-        return (f"ff_step_whole_c{chunk}_t{tiles}"
-                + flags(logits=with_logits) + head(mode, cap))
-    if kind == "whole_step_tree":
-        return f"ff_step_whole_tree_c{rest[0]}"
     if kind == "speculate":
         return "ff_speculate_" + "_".join(str(p) for p in rest)
     chunk, all_logits, with_mask = key  # the sync step (_get_step)
@@ -840,77 +799,11 @@ class InferenceEngine:
             self.serving = dataclasses.replace(self.serving,
                                                fused_decode=fused)
         for name in fused:
-            if name not in ("rope_kv_write", "sampling", "whole_step"):
+            if name not in ("rope_kv_write", "sampling"):
                 raise ValueError(
                     f"unknown fused_decode entry {name!r} (expected "
-                    "'rope_kv_write', 'sampling' and/or 'whole_step')"
+                    "'rope_kv_write' and/or 'sampling')"
                 )
-        self._refuse_uncompilable()
-        # Whole-step megakernel (serve/kernels.whole_step_decode):
-        # capability-gated at construction. The VMEM gate below picks a
-        # sub-block tile count per step shape (1 = untiled walk);
-        # whole_step_on only flips to False when even the finest legal
-        # tiling cannot fit the budget (whole_step_fallbacks counts
-        # those, mirrored into SchedulerStats). whole_step_mixed_on
-        # extends the walk to the C>1 mixed/chunked-prefill step.
-        self.whole_step_on = False
-        self.whole_step_tiles = 1
-        self.whole_step_mixed_on = False
-        self.whole_step_mixed_tiles = 1
-        self.whole_step_fallbacks = 0
-        self.whole_step_vmem_est = 0
-        from .collectives import resolve_mode as _resolve_collective
-
-        self.collective_mode = _resolve_collective(
-            self.serving.quantized_allreduce
-        )
-        if (
-            self.serving.quantized_allreduce is not None
-            and "whole_step" not in fused
-        ):
-            raise ValueError(
-                "quantized_allreduce only applies to the whole-step "
-                "decode walk — set fused_decode=('whole_step',) (TP "
-                "meshes), or drop quantized_allreduce"
-            )
-        if "whole_step" in fused:
-            if not self.paged:
-                raise ValueError(
-                    "fused_decode='whole_step' requires "
-                    "kv_layout='paged' — the layer walk commits and "
-                    "gathers K/V through the page table"
-                )
-            if "whole_step" not in getattr(model, "FUSED_DECODE", ()):
-                raise ValueError(
-                    "fused_decode='whole_step' requested but "
-                    f"{getattr(model, '__name__', repr(model))} does not "
-                    "advertise it (model.FUSED_DECODE)"
-                )
-            if self.pipelined:
-                raise ValueError(
-                    "fused_decode='whole_step' is not composed with "
-                    "pipeline parallelism — the walk owns the whole "
-                    "layer stack"
-                )
-            from ..core.mesh import MODEL_AXIS as _MODEL_AXIS
-
-            tp = self.mesh.shape.get(_MODEL_AXIS, 1)
-            if tp > 1 and (
-                cfg.num_attention_heads % tp
-                or cfg.num_key_value_heads % tp
-            ):
-                raise ValueError(
-                    "fused_decode='whole_step' on a TP mesh needs head "
-                    f"counts divisible by the model degree ({tp}): got "
-                    f"H={cfg.num_attention_heads}, "
-                    f"KV={cfg.num_key_value_heads} (MQA replicated "
-                    "caches are not composed with the manual TP walk)"
-                )
-            # capability gate: the family's weight-layout hook raises a
-            # named error for unstreamable layouts (MoE, ALiBi,
-            # weight-quantized params) — at construction, never mid-serve
-            model.whole_step_weight_layout(params, cfg)
-            self.whole_step_on = True
         if "rope_kv_write" in fused:
             if not self.paged:
                 raise ValueError(
@@ -926,7 +819,7 @@ class InferenceEngine:
                     "advertise it (model.FUSED_DECODE) — the family's "
                     "serve_step_paged has no fused prologue"
                 )
-        # Dispatch telemetry (bench serve_fused): device programs this
+        # Dispatch telemetry: device programs this
         # engine's serving loop issued — every jitted step dispatched
         # here plus host-side decode heads the scheduler counts via
         # count_dispatch. The fused-epilogue claim ("strictly fewer
@@ -986,145 +879,6 @@ class InferenceEngine:
         if validate is not None:
             validate(cfg, self.serving, self.mesh)
         self.cache = self._alloc_cache()
-        if self.whole_step_on:
-            self._whole_step_vmem_gate()
-
-    def _refuse_uncompilable(self) -> None:
-        """On a TPU, an option whose Pallas kernel the chip's compiler
-        refuses (serve/kernels.TPU_REFUSED) is an error naming the
-        option and the compiler's message — never interpret mode, never
-        a quiet switch to another path. The CPU backend runs these in
-        interpret mode, which is what the tests cover."""
-        if (
-            self.mesh.devices.flat[0].platform != "tpu"
-            or "whole_step" not in self.serving.fused_decode
-        ):
-            return
-        from .kernels import TPU_REFUSED
-
-        option = "fused_decode='whole_step'"
-        raise NotImplementedError(
-            f"{option} is not compiled for the TPU: the compiler "
-            f"refuses its kernel — {TPU_REFUSED[option]}. It has "
-            "only run in interpret mode on the CPU; drop the option "
-            "(the per-layer kernels='pallas' path compiles and runs)."
-        )
-
-    @staticmethod
-    def _whole_step_vmem_budget() -> int:
-        """Resolve the whole-step VMEM budget: the kernel default
-        (kernels.WHOLE_STEP_VMEM_BUDGET) unless FF_WHOLE_STEP_VMEM_MB
-        overrides it. A malformed override raises a ValueError NAMING
-        the env var — never an unhandled float() traceback mid-
-        construction."""
-        import os
-
-        from . import kernels as _pk
-
-        env = os.environ.get("FF_WHOLE_STEP_VMEM_MB")
-        if not env:
-            return _pk.WHOLE_STEP_VMEM_BUDGET
-        try:
-            mb = float(env)
-        except ValueError:
-            raise ValueError(
-                f"FF_WHOLE_STEP_VMEM_MB={env!r} is not a number — set "
-                "the whole-step VMEM budget override in megabytes "
-                "(e.g. FF_WHOLE_STEP_VMEM_MB=14), or unset it for the "
-                "kernel default"
-            ) from None
-        if mb <= 0:
-            raise ValueError(
-                f"FF_WHOLE_STEP_VMEM_MB={env!r} must be positive — "
-                "the whole-step VMEM budget is a size in megabytes"
-            )
-        return int(mb * 1024 * 1024)
-
-    def _whole_step_vmem_gate(self):
-        """VMEM gate of the whole-step walk (single-shard meshes — the
-        TP walk is collective-explicit XLA, not one kernel): for each
-        step shape the walk serves (the C=1 decode step; the C=
-        mixed-chunk mixed step) pick the SMALLEST sub-block tile count
-        whose priced working set (serve/kernels.whole_step_vmem_bytes)
-        fits the budget (kernels.WHOLE_STEP_VMEM_BUDGET;
-        FF_WHOLE_STEP_VMEM_MB overrides). Geometries whose layer does
-        not fit untiled get a tile count, NOT a fallback — the walk's
-        projection weights stream in output-column sub-tiles
-        (serve/kernels._whole_step_decode_tiled), so the footprint is
-        bounded by the tile size. The only remaining fallback is a
-        budget below the walk's irreducible floor (pool slices +
-        resident constants + accumulators), which no tiling can shrink;
-        that flips the path off loudly and bumps
-        ``whole_step_fallbacks`` (mirrored into SchedulerStats /
-        ClusterStats). README "Whole-step decode megakernel" carries
-        the budget math."""
-        from ..core.mesh import MODEL_AXIS
-        from . import kernels as _pk
-        from ..logging_utils import get_logger
-
-        if self.mesh.shape.get(MODEL_AXIS, 1) > 1:
-            return  # TP walk: per-layer XLA programs, no VMEM gate
-        budget = self._whole_step_vmem_budget()
-        layer_arrays, head_arrays = self.model.whole_step_weight_layout(
-            self.params, self.cfg
-        )
-        tile_roles = self.model.whole_step_tile_roles(self.cfg)
-        R = self.num_slots
-        D = self.cfg.hidden_size
-        S_virt = self.serving.pages_per_slot * self.serving.page_size
-
-        def pick(C):
-            x0 = np.zeros((R, C, D), jnp.dtype(self.cfg.dtype))
-            mask = np.zeros((R, C, S_virt), np.bool_)
-            return _pk.whole_step_pick_tiles(
-                layer_arrays, head_arrays, self.cache, x0, mask,
-                self.cfg.num_attention_heads,
-                tile_roles=tile_roles, budget=budget,
-            )
-
-        tiles, est = pick(1)
-        self.whole_step_vmem_est = int(est)
-        if tiles is None:
-            self.whole_step_fallbacks += 1
-            get_logger("serve").warning(
-                "whole_step: even the finest sub-block tiling prices "
-                "%.1f MB against the %.1f MB budget (the pool slices + "
-                "resident constants + accumulators floor) — falling "
-                "back to the PR-6 per-layer fused decode path (raise "
-                "FF_WHOLE_STEP_VMEM_MB, or shrink the pool/model; "
-                "README 'Whole-step decode megakernel')",
-                est / 1e6, budget / 1e6,
-            )
-            self.whole_step_on = False
-            return
-        self.whole_step_tiles = int(tiles)
-        if tiles > 1:
-            get_logger("serve").info(
-                "whole_step: layer working set over budget untiled — "
-                "streaming weight sub-blocks at tiles=%d (%.1f MB "
-                "priced vs %.1f MB budget)",
-                tiles, est / 1e6, budget / 1e6,
-            )
-        # the whole-step MIXED step: the same walk over the (R, C)
-        # chunked-prefill step shape, priced at the widest chunk the
-        # scheduler dispatches
-        C = self.serving.prefill_chunk
-        if C <= 1:
-            self.whole_step_mixed_on = True
-            self.whole_step_mixed_tiles = self.whole_step_tiles
-            return
-        mtiles, mest = pick(C)
-        if mtiles is None:
-            self.whole_step_fallbacks += 1
-            get_logger("serve").warning(
-                "whole_step: the C=%d mixed step prices %.1f MB "
-                "against the %.1f MB budget at every tiling — decode "
-                "keeps the walk, mixed steps keep the per-layer path",
-                C, mest / 1e6, budget / 1e6,
-            )
-            return
-        self.whole_step_mixed_on = True
-        self.whole_step_mixed_tiles = int(mtiles)
 
     @property
     def pipelined(self) -> bool:
@@ -1472,128 +1226,6 @@ class InferenceEngine:
             )
         return self._steps[key_id]
 
-    def _serve_whole_fn(self, tiles: int = 1) -> Callable:
-        """model.serve_step_whole bound to this engine's static kwargs
-        (the whole-step layer walk — serve/kernels.whole_step_decode on
-        single-shard meshes, the collective-explicit TP walk
-        otherwise). ``tiles`` is the VMEM gate's sub-block tile count
-        for the step shape being compiled (1 = untiled walk)."""
-        from ..core.mesh import MODEL_AXIS
-
-        tp = self.mesh.shape.get(MODEL_AXIS, 1)
-        return functools.partial(
-            self.model.serve_step_whole,
-            cfg=self.cfg,
-            cache_len=self.serving.cache_len,
-            kv_quant=self.serving.kv_quant,
-            tp_mesh=self.mesh if tp > 1 else None,
-            collective=self.collective_mode,
-            tiles=tiles,
-        )
-
-    @property
-    def whole_step_spec_on(self) -> bool:
-        """Whether SpecInfer rounds fold into the whole-step walk: the
-        draft pass (early-exit ``num_layers`` slice) and the verify
-        pass (tree mask + slack-line ``cache_positions`` +
-        ``all_logits``) dispatch as two programs of the ONE persistent
-        layer walk instead of the per-layer unfused step. Requires the
-        untiled single-shard walk — sub-block streaming, context-ring
-        and TP meshes keep the unfused spec programs (the fold's
-        all-positions epilogue and layer slicing are not composed with
-        those walks)."""
-        from ..core.mesh import MODEL_AXIS
-
-        return (
-            self.whole_step_on
-            and self.whole_step_tiles == 1
-            and not self.cp_ring
-            and self.mesh.shape.get(MODEL_AXIS, 1) == 1
-        )
-
-    def _get_tree_whole_step(self, chunk: int):
-        """The VERIFY half of the speculation fold
-        (:attr:`whole_step_spec_on`): the whole-step walk dispatched
-        with the verify round's tree mask, slack-line cache positions
-        and the all-positions head twin — same signature as the paged
-        :meth:`_get_step`, so :meth:`run` routes verify dispatches here
-        transparently. One program per chunk (the spec manager's
-        padded tree width), bitwise the unfused verify step because
-        the walk runs the same ``_block_paged_xla`` body."""
-        key_id = ("whole_step_tree", chunk)
-        if key_id not in self._steps:
-            wfn = self._serve_whole_fn(1)
-
-            def step(params, cache, tokens, positions, logits_idx,
-                     mask, cpos, page_table):
-                logits, _gtoks, cache = wfn(
-                    params, cache, tokens, positions, logits_idx,
-                    page_table, mask=mask, cache_positions=cpos,
-                    all_logits=True,
-                )
-                return logits, cache
-
-            self._steps[key_id] = self._jit(
-                step, key=key_id, donate_argnums=(1,)
-            )
-        return self._steps[key_id]
-
-    def _get_whole_step(self, with_logits: bool, sample_mode: str,
-                        topk_cap: int, chunk: int = 1):
-        """The whole-step program (fused_decode=("whole_step",)):
-        token select (device feedback vs host) → the ONE-program layer
-        walk (model.serve_step_whole) → the sampling epilogue.
-        ``chunk == 1`` is the decode step; ``chunk > 1`` the whole-step
-        MIXED step (chunked prefill + decode in the same walk — the
-        columns past the token select ride through like the fused
-        mixed step's). Greedy batches take the walk's in-kernel argmax
-        head; other modes sample from the walk's logits inside the
-        same jitted program — either way ONE dispatched program per
-        step, with strictly fewer kernel launches than the per-layer
-        path (:func:`program_launch_count` is the measured proxy). The
-        step key carries the chunk and the gate's tile count, so each
-        (shape, tiling) compiles exactly once."""
-        tiles = (self.whole_step_tiles if chunk == 1
-                 else self.whole_step_mixed_tiles)
-        key_id = ("whole_step", chunk, tiles, sample_mode, topk_cap,
-                  with_logits)
-        if key_id not in self._steps:
-            from .sampling import sample_tokens
-
-            fn = self._serve_whole_fn(tiles)
-            mode = sample_mode or "full"
-
-            def step(params, cache, last_tokens, host_tokens, use_last,
-                     positions, logits_idx, key, greedy, temperature,
-                     topp, topk, page_table=None):
-                first = jnp.where(use_last, last_tokens, host_tokens[:, 0])
-                if chunk == 1:
-                    tokens = first[:, None]
-                else:
-                    tokens = jnp.concatenate(
-                        [first[:, None], host_tokens[:, 1:]], axis=1
-                    )
-                logits, gtoks, cache = fn(
-                    params, cache, tokens, positions, logits_idx,
-                    page_table,
-                )
-                if mode == "greedy":
-                    toks = gtoks  # the walk's fused argmax head
-                else:
-                    toks = sample_tokens(
-                        logits, key,
-                        greedy=greedy, temperature=temperature, topp=topp,
-                        topk_arr=topk, mode=mode, topk_cap=topk_cap,
-                    )
-                if with_logits:
-                    return toks, logits, cache
-                return toks, cache
-
-            self._steps[key_id] = self._jit(
-                step, key=key_id, donate_argnums=(1,)
-            )
-        return self._steps[key_id]
-
     def run_mixed(self, last_tokens, host_tokens, use_last, positions,
                   logits_idx, key, greedy, temperature, topp, topk,
                   with_logits: bool = False):
@@ -1605,18 +1237,6 @@ class InferenceEngine:
         if self.paged:
             kw["page_table"] = self.page_table_device()
         host_tokens = np.asarray(host_tokens)
-        if self.whole_step_on and (
-            host_tokens.shape[1] == 1 or self.whole_step_mixed_on
-        ):
-            # the whole-step megakernel owns the C==1 decode step AND —
-            # when the VMEM gate priced the chunked shape — the C>1
-            # mixed step; the sampling epilogue is part of the walk's
-            # contract
-            return self._run_whole(
-                last_tokens, host_tokens, use_last, positions,
-                logits_idx, key, greedy, temperature, topp, topk,
-                with_logits, kw,
-            )
         mode, cap = None, 0
         if "sampling" in self.serving.fused_decode:
             from .sampling import choose_sample_mode
@@ -1658,50 +1278,6 @@ class InferenceEngine:
         self._poison_donated(
             donated, ("mixed_fused", host_tokens.shape[1], with_logits)
         )
-        return toks
-
-    def _run_whole(self, last_tokens, host_tokens, use_last, positions,
-                   logits_idx, key, greedy, temperature, topp, topk,
-                   with_logits, kw):
-        """Dispatch ONE whole-step program (run_mixed's route with
-        fused_decode=("whole_step",) — the C==1 decode walk, or the
-        C>1 mixed walk when the gate enabled it): same argument
-        contract, same pinned-dtype conversion, same donation — the
-        step key is mode-tagged like the fused sampling head's."""
-        from .sampling import choose_sample_mode
-
-        host_tokens = np.asarray(host_tokens)
-        chunk = int(host_tokens.shape[1])
-        mode, cap = choose_sample_mode(
-            greedy, topp, topk, self.cfg.vocab_size
-        )
-        donated = self.cache
-        self.count_dispatch(
-            "whole_step" if chunk == 1 else "whole_step_mixed"
-        )
-        with _set_mesh(self.mesh):
-            step = self._get_whole_step(with_logits, mode, cap, chunk)
-            out = step(
-                self.params,
-                self.cache,
-                self._carry(last_tokens),
-                jnp.asarray(host_tokens, dtype=jnp.int32),
-                jnp.asarray(use_last, dtype=jnp.bool_),
-                jnp.asarray(positions, dtype=jnp.int32),
-                jnp.asarray(logits_idx, dtype=jnp.int32),
-                key,
-                jnp.asarray(greedy, dtype=jnp.bool_),
-                jnp.asarray(temperature, dtype=jnp.float32),
-                jnp.asarray(topp, dtype=jnp.float32),
-                jnp.asarray(topk, dtype=jnp.int32),
-                **kw,
-            )
-        if with_logits:
-            toks, logits, self.cache = out
-            self._poison_donated(donated, ("whole_step", chunk, mode, cap))
-            return toks, logits
-        toks, self.cache = out
-        self._poison_donated(donated, ("whole_step", chunk, mode, cap))
         return toks
 
     def run_decode(self, last_tokens, host_tokens, use_last, positions,
@@ -1770,26 +1346,6 @@ class InferenceEngine:
         if self.serving.inference_debugging:
             with _set_mesh(self.mesh):
                 self._dump_debug(bc)
-        if (
-            self.whole_step_on
-            and (bc.chunk == 1 or self.whole_step_mixed_on)
-            and bc.mask is None
-            and bc.cache_positions is None
-        ):
-            # sync decode step — or sync chunked-prefill/mixed step
-            # when the gate enabled the mixed walk: same whole-step
-            # program (and step key) the pipelined path compiles —
-            # use_last all-False feeds the host tokens through the
-            # same token select
-            R = self.num_slots
-            kw = {}
-            if self.paged:
-                kw["page_table"] = self.page_table_device()
-            return self._run_whole(
-                jnp.zeros((R,), jnp.int32), np.asarray(bc.tokens),
-                np.zeros((R,), bool), bc.positions, bc.logits_idx,
-                key, greedy, temperature, topp, topk, with_logits, kw,
-            )
         mode, cap = choose_sample_mode(
             greedy, topp, topk, self.cfg.vocab_size
         )
@@ -1846,35 +1402,12 @@ class InferenceEngine:
         SpecConfig.bucket_ladder), so the key set stays bounded by the
         ladder, never free-form. ``num_layers`` is the self-speculation
         early-exit draft: the frontier expands through a layer-sliced
-        step over THIS engine's own params + cache.
-
-        With :attr:`whole_step_spec_on` the per-depth expansion runs
-        the whole-step walk (early-exit slice + all-positions head +
-        tree mask + slack lines) — the DRAFT half of the speculation
-        fold: the draft becomes the first ``num_layers`` grid steps of
-        the same persistent program the verify pass dispatches, bitwise
-        the unfused spec round (shared ``_block_paged_xla`` body)."""
+        step over THIS engine's own params + cache."""
         key_id = ("speculate", W, D)
         if num_layers is not None:
             key_id = key_id + (int(num_layers),)
-        whole = self.whole_step_spec_on
-        if whole:
-            key_id = key_id + ("whole_step",)
         if key_id not in self._steps:
-            if whole:
-                wfn = self._serve_whole_fn(1)
-
-                def fn(params, cache, tokens, positions, logits_idx,
-                       mask, cpos, page_table):
-                    logits, _gtoks, cache = wfn(
-                        params, cache, tokens, positions, logits_idx,
-                        page_table, mask=mask, cache_positions=cpos,
-                        all_logits=True, num_layers=num_layers,
-                    )
-                    return logits, cache
-            else:
-                fn = self._serve_step_fn(all_logits=True,
-                                         num_layers=num_layers)
+            fn = self._serve_step_fn(all_logits=True, num_layers=num_layers)
             from .sampling import log_softmax
 
             R = self.num_slots
@@ -2056,24 +1589,11 @@ class InferenceEngine:
             args = args + (self.page_table_device(),)
         donated = self.cache
         self.count_dispatch("step")
-        # the speculation fold's verify half: tree-masked all-logits
-        # dispatches ride the whole-step walk when the engine runs it
-        # (whole_step_spec_on) — same signature, one persistent program
-        fold_verify = (
-            all_logits and bc.mask is not None
-            and bc.cache_positions is not None and self.whole_step_spec_on
-        )
         with _set_mesh(self.mesh):
-            step = (
-                self._get_tree_whole_step(bc.chunk) if fold_verify
-                else self._get_step(bc.chunk, all_logits,
-                                    bc.mask is not None)
-            )
+            step = self._get_step(bc.chunk, all_logits, bc.mask is not None)
             logits, self.cache = step(self.params, self.cache, *args)
         self._poison_donated(
-            donated,
-            ("whole_step_tree", bc.chunk) if fold_verify
-            else (bc.chunk, all_logits, bc.mask is not None),
+            donated, (bc.chunk, all_logits, bc.mask is not None)
         )
         return logits
 
@@ -2235,49 +1755,3 @@ class InferenceEngine:
         built over the old allocator is invalidated with it — managers
         are expected to be rebuilt alongside an engine reset."""
         self.cache = self._alloc_cache()
-
-
-def program_launch_count(fn, *args, **kwargs) -> int:
-    """Structural kernel-launch proxy of one step program: ``fn`` is
-    traced to a jaxpr and its equations counted recursively — each
-    primitive equation is one launch-site execution, ``scan`` bodies
-    multiply by their trip count, call-like primitives (pjit /
-    shard_map / custom calls / remat) recurse into their subjaxprs,
-    ``cond`` counts its largest branch. Not an HLO kernel count (XLA
-    fuses elementwise chains), but a faithful ORDER comparison: the
-    PR-6 fused decode step executes O(L) launch sites (one scan
-    iteration per layer, each with its projections, Pallas kernel and
-    MLP), the whole-step walk O(1) — ONE pallas_call whose grid walks
-    the layers. bench serve_megakernel and tests/test_whole_step.py
-    assert the strict inequality on this measure."""
-    jaxpr = jax.make_jaxpr(fn)(*args, **kwargs).jaxpr
-
-    def count(jx, mult: int) -> int:
-        total = 0
-        for eqn in jx.eqns:
-            name = eqn.primitive.name
-            if name == "scan":
-                total += count(
-                    eqn.params["jaxpr"].jaxpr,
-                    mult * int(eqn.params["length"]),
-                )
-            elif name == "while":
-                total += count(eqn.params["cond_jaxpr"].jaxpr, mult)
-                total += count(eqn.params["body_jaxpr"].jaxpr, mult)
-            elif name == "cond":
-                total += max(
-                    count(b.jaxpr, mult) for b in eqn.params["branches"]
-                )
-            else:
-                sub = None
-                for k in ("jaxpr", "call_jaxpr"):
-                    if k in eqn.params:
-                        sub = eqn.params[k]
-                        break
-                if sub is not None:
-                    total += count(getattr(sub, "jaxpr", sub), mult)
-                else:
-                    total += mult
-        return total
-
-    return count(jaxpr, 1)

@@ -92,7 +92,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -102,25 +102,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..ops.flash_attention import _block_positions, _interpret
 
 NEG_INF = -1e30
-
-
-# Serving options whose kernel the TPU's compiler refuses today, with
-# the first line of what it says (each compiled for a described v5e at
-# Mistral-7B widths, PR 23; tests/test_chip_compile.py holds the kernels
-# that DO compile). These have only ever run in interpret mode on the
-# CPU. On a TPU the engine raises at construction when one is selected
-# (InferenceEngine._refuse_uncompilable): it neither drops to interpret
-# mode nor to another path. Repair the kernel, add its compile test,
-# then delete its line here.
-TPU_REFUSED = {
-    "fused_decode='whole_step'": (
-        "The Pallas TPU lowering currently requires that the last two "
-        "dimensions of your block shape are divisible by 8 and 128 "
-        "respectively, or be equal to the respective dimensions of the "
-        "overall array — the walk's per-layer (1, D) blocks of (L, D) "
-        "stacks and its weight sub-tiles narrower than 128 lanes"
-    ),
-}
 
 
 def _decode_kernel(
@@ -1531,750 +1512,3 @@ def fused_rope_paged_attention(
                 ks.reshape(k_scale.shape), vs.reshape(v_scale.shape))
     out, k_pool, v_pool = outs
     return out.reshape(R, C, H, dk), k_pool, v_pool, None, None
-
-
-# ---------------------------------------------------------------------------
-# Whole-step decode megakernel (ServingConfig.fused_decode=("whole_step",);
-# MPK "Mega-Kernelizing Tensor Programs", PAPERS.md). PR 6 collapsed the
-# decode step to ONE dispatched program, but inside that program XLA
-# still runs L independent layer kernels, each round-tripping the (R, D)
-# hidden state and re-fetching its weights from HBM per step.
-# :func:`whole_step_decode` is the next multiple: ONE persistent
-# ``pallas_call`` whose GRID WALKS THE LAYERS — grid step l computes
-# layer l's full block (QKV projections, RoPE + KV page commit, ragged
-# paged attention over the table, out-projection, MLP) with the hidden
-# state carried in VMEM scratch, and the final grid step runs the
-# epilogue (final norm, LM head, greedy argmax). Layer l's weights are
-# delivered by BlockSpec index maps over the stacked (L, ...) parameter
-# arrays, which is exactly Pallas's pipelined-grid contract: while grid
-# step l computes, the DMA engines prefetch grid step l+1's blocks into
-# the revolving VMEM buffers — double-buffered HBM→VMEM weight
-# streaming without hand-written semaphores. The KV pool's per-layer
-# slices stream the same way and alias their outputs, so only layer l's
-# pages are resident at a time.
-#
-# Division of labor: THIS builder owns the grid, the streaming
-# BlockSpecs, the aliasing, the hidden-state carry and the epilogue;
-# the model family supplies ``block_fn``/``head_fn`` — closures over
-# the SAME per-layer math its unfused XLA step runs
-# (models/*.serve_step_paged's block body, op for op). That sharing is
-# the bitwise contract: given identical inputs the kernel body executes
-# identical operations, so whole-step decode is BITWISE the unfused XLA
-# step on the same backend (fp and int8 pools; int4 under the PR-7
-# packed-nibble tolerance documented in README) — the same way PR 6's
-# fusions anchor on the XLA step as the CPU-parity reference.
-#
-# VMEM budget and SUB-BLOCK streaming: one grid step must hold 2×
-# (double buffer) each layer's weight blocks + 2× its K/V pool slice
-# (in + aliased out) + the resident constants (lm_head, mask, embed
-# when tied) + the scratch carry + attention intermediates.
-# :func:`whole_step_vmem_bytes` prices this; when the whole layer does
-# not fit the budget (WHOLE_STEP_VMEM_BUDGET, ~a TPU core's usable
-# VMEM, overridable via FF_WHOLE_STEP_VMEM_MB), the engine does NOT
-# fall back — it picks a tile count K (:func:`whole_step_pick_tiles`)
-# and the walk streams each projection weight in K output-column
-# sub-tiles over an inner grid dimension (grid (L, 4·K): QKV tiles →
-# attention → out-proj tiles → MLP up/gate tiles → down tiles), each
-# tile's partial result accumulated into VMEM scratch. Column-tiling
-# the OUTPUT dim only — never the contraction dim — keeps every tile's
-# matmul bit-identical to the corresponding column slice of the full
-# matmul, so the tiled walk stays bitwise the unfused XLA step; the
-# footprint is bounded by the tile size, not the layer, which is what
-# makes the megakernel the default path for 7B-class geometries
-# (ROADMAP item 5a/5b). README "Whole-step decode megakernel" carries
-# the math.
-
-
-#: bytes of VMEM one grid step of the whole-step program may occupy
-#: before the engine picks a sub-block tile count (see
-#: :func:`whole_step_pick_tiles`); ~16 MB is a TPU core's VMEM
-#: (pallas_guide.md), minus headroom.
-WHOLE_STEP_VMEM_BUDGET = 12 * 1024 * 1024
-
-#: canonical sub-block streaming roles (column-tiled projection
-#: weights) in the stage order the inner grid dimension walks them:
-#: stage 0 = QKV projections (→ attention at the last tile), stage 1 =
-#: attention out-projection, stage 2 = MLP up/gate, stage 3 = MLP down.
-_TILE_ROLE_ORDER = ("q", "k", "v", "o", "gate", "up", "down")
-_TILE_ROLE_STAGE = {"q": 0, "k": 0, "v": 0, "o": 1,
-                    "gate": 2, "up": 2, "down": 3}
-_TILE_STAGES = 4
-
-
-def whole_step_vmem_bytes(
-    layer_arrays: Dict[str, jnp.ndarray],
-    head_arrays: Dict[str, jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],
-    x0: jnp.ndarray,
-    mask: jnp.ndarray,
-    num_heads: int,
-    *,
-    tiles: int = 1,
-    tile_roles: Optional[Dict[str, Tuple[str, Optional[str]]]] = None,
-) -> int:
-    """Estimate the per-grid-step VMEM working set of
-    :func:`whole_step_decode` (see the section comment): 2× the layer
-    weight blocks and 2× the per-layer pool slices (stream double
-    buffering + aliased outputs), the resident constants, the f32
-    hidden-state intermediates and the (R, C, H, S_virt) f32 attention
-    score/probability pair.
-
-    ``tiles > 1`` prices the SUB-BLOCK streaming walk instead: each
-    role-tiled projection weight (``tile_roles``, the family's
-    ``whole_step_tile_roles`` map) is resident one 1/tiles output-column
-    slice at a time, and the per-role VMEM accumulators (q/k/v/attn
-    rows, the residual/norm carries, the MLP activation) are added —
-    the footprint the engine's gate compares against the budget when it
-    picks a tile count."""
-    tiled_names = set()
-    if tiles > 1:
-        if tile_roles is None:
-            raise ValueError(
-                "whole_step_vmem_bytes: tiles > 1 needs tile_roles "
-                "(the family's whole_step_tile_roles map)"
-            )
-        tiled_names = {w for (w, _b) in tile_roles.values()}
-    per_layer = 0
-    for name, a in layer_arrays.items():
-        b = int(a.nbytes) // a.shape[0]
-        if name in tiled_names:
-            b //= tiles
-        per_layer += b
-    pool = sum(int(a.nbytes) // a.shape[0] for a in cache.values())
-    const = sum(int(a.nbytes) for a in head_arrays.values())
-    const += int(x0.nbytes) + int(mask.nbytes)
-    R, C, S = mask.shape
-    scores = 2 * 4 * R * C * num_heads * S        # scores + probs, f32
-    hidden = 6 * 4 * R * C * x0.shape[-1]         # f32 block temporaries
-    total = 2 * per_layer + 2 * pool + const + scores + hidden
-    if tiles > 1:
-        # tiled-walk accumulators (model dtype, serve/kernels
-        # _whole_step_decode_tiled scratch): x/h/x2/h2 residual and
-        # norm carries, q + attn rows, k/v rows, the MLP activation
-        item = jnp.dtype(x0.dtype).itemsize
-        D = int(x0.shape[-1])
-        Hdk = int(layer_arrays[tile_roles["q"][0]].shape[-1])
-        KVdk = int(layer_arrays[tile_roles["k"][0]].shape[-1])
-        F = int(layer_arrays[tile_roles["up"][0]].shape[-1])
-        total += item * R * C * (4 * D + 2 * Hdk + 2 * KVdk + F)
-    return total
-
-
-def whole_step_tile_candidates(
-    layer_arrays: Dict[str, jnp.ndarray],
-    tile_roles: Dict[str, Tuple[str, Optional[str]]],
-) -> Tuple[int, ...]:
-    """Legal sub-block tile counts for this weight layout, ascending:
-    every count must divide EVERY tiled weight's output (last) dim so
-    each role splits into equal column tiles — the divisors of the gcd
-    of the tiled last dims."""
-    g = 0
-    for wname, _b in tile_roles.values():
-        g = math.gcd(g, int(layer_arrays[wname].shape[-1]))
-    return tuple(t for t in range(1, g + 1) if g % t == 0)
-
-
-def whole_step_pick_tiles(
-    layer_arrays: Dict[str, jnp.ndarray],
-    head_arrays: Dict[str, jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],
-    x0: jnp.ndarray,
-    mask: jnp.ndarray,
-    num_heads: int,
-    *,
-    tile_roles: Dict[str, Tuple[str, Optional[str]]],
-    budget: int,
-) -> Tuple[Optional[int], int]:
-    """Pick the SMALLEST tile count whose priced working set fits the
-    budget (1 = the untiled walk; larger counts trade grid steps for
-    footprint). Returns ``(tiles, est_bytes)`` — ``(None, best_est)``
-    when even the finest legal tiling cannot fit (the pool + resident
-    constants + accumulators alone exceed the budget), which is the
-    only remaining fallback-to-per-layer-path condition."""
-    best_est = None
-    for t in whole_step_tile_candidates(layer_arrays, tile_roles):
-        est = whole_step_vmem_bytes(
-            layer_arrays, head_arrays, cache, x0, mask, num_heads,
-            tiles=t, tile_roles=tile_roles,
-        )
-        if best_est is None or est < best_est:
-            best_est = est
-        if est <= budget:
-            return t, est
-    return None, int(best_est if best_est is not None else 0)
-
-
-def whole_step_decode(
-    layer_arrays: Dict[str, jnp.ndarray],  # each (L, ...): streamed blocks
-    head_arrays: Dict[str, jnp.ndarray],   # resident epilogue params
-    x0: jnp.ndarray,            # (R, C, D) embedded step input
-    cos: Optional[jnp.ndarray],  # (R, C, rot) f32, or None (no-RoPE family)
-    sin: Optional[jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],  # k/v (L, P+1, ps, KV, dkp) [+ scales]
-    page_table: jnp.ndarray,    # (R, NP) int32
-    phys: jnp.ndarray,          # (R, C) int32 physical page per new line
-    off: jnp.ndarray,           # (R, C) int32 in-page offset per new line
-    mask: jnp.ndarray,          # (R, C, NP*ps) bool
-    logits_idx: jnp.ndarray,    # (R,) int32
-    *,
-    block_fn: Callable,
-    head_fn: Callable,
-    tiles: int = 1,
-    tile_plan: Optional[Dict[str, Any]] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """ONE persistent Pallas program for the FULL step (see the
-    section comment above): grid = (L,), layer weights and KV pool
-    slices streamed per grid step (double-buffered by the Pallas
-    pipeline), hidden state carried in VMEM scratch, epilogue fused
-    into the last grid step. C = 1 is the decode step; C > 1 is the
-    whole-step MIXED step (chunked prefill + decode in the same walk —
-    the per-row ``logits_idx`` head select is already ragged).
-
-    ``block_fn(p_l, x, cos, sin, mask, k, v, ks, vs, phys, off,
-    page_table) -> (x, k, v, ks, vs)`` runs one layer on VALUES —
-    the model family passes the same math its unfused XLA step runs.
-    ``head_fn(head, x, logits_idx) -> (R, V) f32`` is the epilogue.
-
-    ``tiles > 1`` selects the SUB-BLOCK streaming walk
-    (:func:`_whole_step_decode_tiled`): the projection weights named by
-    ``tile_plan["roles"]`` stream in output-column sub-tiles over an
-    inner grid dimension, so the per-grid-step footprint is bounded by
-    the tile size instead of the layer — the path the engine's VMEM
-    gate picks for geometries the untiled walk cannot fit. The tiled
-    walk runs the same ops on column slices (no contraction splits),
-    so both paths are bitwise the unfused XLA step.
-
-    Returns ``(logits (R, V) f32, greedy_tokens (R,) int32,
-    new_cache)`` — the greedy tokens are the fused sampling epilogue's
-    argmax head (``sample_tokens`` mode="greedy", in-kernel); non-greedy
-    batches sample from the returned logits in the same jitted program.
-    """
-    if tiles > 1:
-        if tile_plan is None:
-            raise ValueError(
-                "whole_step_decode: tiles > 1 needs a tile_plan (the "
-                "family's _whole_tile_plan closures)"
-            )
-        return _whole_step_decode_tiled(
-            layer_arrays, head_arrays, x0, cos, sin, cache, page_table,
-            phys, off, mask, logits_idx, tiles=tiles,
-            tile_plan=tile_plan, head_fn=head_fn,
-        )
-    L = cache["k"].shape[0]
-    R, C, D = x0.shape
-    quant = "k_scale" in cache
-    has_rope = cos is not None
-    layer_names = sorted(layer_arrays)
-    head_names = sorted(head_arrays)
-
-    def _const(spec_shape):
-        nd = len(spec_shape)
-        return pl.BlockSpec(
-            spec_shape, lambda l, _nd=nd: (0,) * _nd
-        )
-
-    in_specs = []
-    operands = []
-    # streamed per-layer weight blocks: index map walks the layer dim —
-    # the Pallas pipeline prefetches step l+1's blocks during step l
-    for name in layer_names:
-        a = layer_arrays[name]
-        if a.shape[0] != L:
-            raise ValueError(
-                f"whole_step_decode: layer array {name!r} leading dim "
-                f"{a.shape[0]} != num layers {L}"
-            )
-        nd = a.ndim - 1
-        in_specs.append(pl.BlockSpec(
-            (1,) + a.shape[1:], lambda l, _nd=nd: (l,) + (0,) * _nd
-        ))
-        operands.append(a)
-    # streamed + aliased KV pool slices (and quant scale rows)
-    pool_names = ["k", "v"] + (["k_scale", "v_scale"] if quant else [])
-    pool_in_idx = {}
-    for name in pool_names:
-        a = cache[name]
-        nd = a.ndim - 1
-        pool_in_idx[name] = len(operands)
-        in_specs.append(pl.BlockSpec(
-            (1,) + a.shape[1:], lambda l, _nd=nd: (l,) + (0,) * _nd
-        ))
-        operands.append(a)
-    # resident (constant index map) operands
-    const_ops = [x0]
-    const_specs = [_const((R, C, D))]
-    if has_rope:
-        const_ops += [cos, sin]
-        const_specs += [_const(cos.shape), _const(sin.shape)]
-    const_ops += [
-        page_table.astype(jnp.int32), phys.astype(jnp.int32),
-        off.astype(jnp.int32), logits_idx.astype(jnp.int32), mask,
-    ]
-    const_specs += [
-        _const(page_table.shape), _const(phys.shape), _const(off.shape),
-        _const(logits_idx.shape), _const(mask.shape),
-    ]
-    for name in head_names:
-        const_ops.append(head_arrays[name])
-        const_specs.append(_const(head_arrays[name].shape))
-    in_specs += const_specs
-    operands += const_ops
-
-    # epilogue output shapes: probe the head on abstract values. The
-    # full shape (not just V) so head twins with different ranks
-    # compose — the decode head returns (R, V), the all-positions head
-    # the spec verify fold dispatches returns (R, C, V); the argmax
-    # epilogue below is rank-agnostic either way.
-    head_abs = {n: head_arrays[n] for n in head_names}
-    head_shape = jax.eval_shape(
-        lambda h, x, li: head_fn(h, x, li),
-        head_abs, jnp.zeros((R, C, D), x0.dtype),
-        logits_idx.astype(jnp.int32),
-    ).shape
-
-    out_shapes = [
-        jax.ShapeDtypeStruct(head_shape, jnp.float32),      # logits
-        jax.ShapeDtypeStruct(head_shape[:-1], jnp.int32),   # greedy tokens
-    ]
-    out_specs = [_const(head_shape), _const(head_shape[:-1])]
-    aliases = {}
-    for j, name in enumerate(pool_names):
-        a = cache[name]
-        nd = a.ndim - 1
-        out_shapes.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
-        out_specs.append(pl.BlockSpec(
-            (1,) + a.shape[1:], lambda l, _nd=nd: (l,) + (0,) * _nd
-        ))
-        aliases[pool_in_idx[name]] = 2 + j
-
-    def kernel(*refs):
-        i = 0
-        p_l = {}
-        for name in layer_names:
-            p_l[name] = refs[i][0]
-            i += 1
-        pool_refs = {}
-        for name in pool_names:
-            pool_refs[name] = refs[i]
-            i += 1
-        x0_ref = refs[i]; i += 1
-        if has_rope:
-            cos_ref = refs[i]; i += 1
-            sin_ref = refs[i]; i += 1
-        pt_ref = refs[i]; i += 1
-        ph_ref = refs[i]; i += 1
-        of_ref = refs[i]; i += 1
-        li_ref = refs[i]; i += 1
-        mask_ref = refs[i]; i += 1
-        head_vals = {}
-        for name in head_names:
-            head_vals[name] = refs[i][...]
-            i += 1
-        logits_ref = refs[i]; i += 1
-        tok_ref = refs[i]; i += 1
-        pool_out = {}
-        for name in pool_names:
-            pool_out[name] = refs[i]
-            i += 1
-        x_scr = refs[i]
-
-        l = pl.program_id(0)
-
-        @pl.when(l == 0)
-        def _():
-            x_scr[:] = x0_ref[...]
-
-        x = x_scr[:]
-        cs = cos_ref[...] if has_rope else None
-        sn = sin_ref[...] if has_rope else None
-        kb = pool_refs["k"][0]
-        vb = pool_refs["v"][0]
-        ks = pool_refs["k_scale"][0] if quant else None
-        vs = pool_refs["v_scale"][0] if quant else None
-        x, kb, vb, ks, vs = block_fn(
-            p_l, x, cs, sn, mask_ref[...], kb, vb, ks, vs,
-            ph_ref[...], of_ref[...], pt_ref[...],
-        )
-        pool_out["k"][0] = kb
-        pool_out["v"][0] = vb
-        if quant:
-            pool_out["k_scale"][0] = ks
-            pool_out["v_scale"][0] = vs
-        x_scr[:] = x
-
-        @pl.when(l == L - 1)
-        def _():
-            logits = head_fn(head_vals, x, li_ref[...])
-            logits_ref[...] = logits
-            # fused sampling epilogue, greedy head: op-for-op
-            # serve/sampling.sample_tokens mode="greedy" (logits are
-            # already f32 — the astype there is a no-op)
-            tok_ref[...] = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=out_shapes,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
-            grid=(L,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((R, C, D), x0.dtype)],
-        ),
-        input_output_aliases=aliases,
-        name=f"ff_whole_step_c{C}",
-        interpret=_interpret(),
-    )(*operands)
-    logits, toks = outs[0], outs[1]
-    new_cache = dict(cache)
-    for j, name in enumerate(pool_names):
-        new_cache[name] = outs[2 + j]
-    return logits, toks, new_cache
-
-
-def _whole_step_decode_tiled(
-    layer_arrays: Dict[str, jnp.ndarray],
-    head_arrays: Dict[str, jnp.ndarray],
-    x0: jnp.ndarray,
-    cos: Optional[jnp.ndarray],
-    sin: Optional[jnp.ndarray],
-    cache: Dict[str, jnp.ndarray],
-    page_table: jnp.ndarray,
-    phys: jnp.ndarray,
-    off: jnp.ndarray,
-    mask: jnp.ndarray,
-    logits_idx: jnp.ndarray,
-    *,
-    tiles: int,
-    tile_plan: Dict[str, Any],
-    head_fn: Callable,
-) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """The SUB-BLOCK streaming whole-step walk: grid ``(L, 4·K)`` —
-    the outer dimension walks the layers exactly like
-    :func:`whole_step_decode`, the inner dimension walks
-    ``_TILE_STAGES`` stages of K output-column weight tiles each:
-
-      stage 0  QKV tiles — each grid step matmuls the normed hidden
-               state against one (in, cols/K) column tile of wq/wk/wv
-               and writes the column slice of the q/k/v accumulators;
-               the LAST tile runs RoPE + KV commit + ragged paged
-               attention (``attend_fn``) on the assembled rows
-      stage 1  out-projection tiles — each tile produces D/K columns
-               of the attention output and adds the residual slice
-      stage 2  MLP up (+ gate, GLU families) tiles → activation slices
-      stage 3  down-projection tiles close the layer into the residual
-
-    Tile index maps freeze outside a role's stage (col 0 before, col
-    K-1 after), so each operand's revolving VMEM buffer only refetches
-    while its stage is live — Pallas double-buffers the K tiles across
-    the inner grid steps the same way the layer walk double-buffers
-    layers. Only the OUTPUT dim is split (the contraction dims stay
-    whole), so every tile's matmul is bit-identical to the matching
-    column slice of the full matmul and the tiled walk stays bitwise
-    the unfused XLA step.
-
-    ``tile_plan`` is the family's closure bundle:
-    ``roles`` ({role: (weight_name, bias_name|None)} over
-    ``_TILE_ROLE_ORDER``; "gate" only for GLU MLPs), ``mm_fn`` (the
-    family's ``_mm``), ``pre_fn(p, x) -> h`` (attention norm),
-    ``attend_fn(p, q, k, v, cos, sin, mask, kb, vb, ks, vs, phys, off,
-    pt) -> (attn, kb, vb, ks, vs)`` (RoPE + commit + gather + attend on
-    the assembled flat rows), ``mid_fn(p, x, h, x2) -> h2`` (the MLP
-    norm — parallel-block aware) and ``act_fn(gate|None, up) -> act``.
-    """
-    L = cache["k"].shape[0]
-    R, C, D = x0.shape
-    K = int(tiles)
-    quant = "k_scale" in cache
-    has_rope = cos is not None
-    roles = tile_plan["roles"]
-    glu = "gate" in roles
-    mm_fn = tile_plan["mm_fn"]
-    pre_fn = tile_plan["pre_fn"]
-    attend_fn = tile_plan["attend_fn"]
-    mid_fn = tile_plan["mid_fn"]
-    act_fn = tile_plan["act_fn"]
-
-    role_order = tuple(r for r in _TILE_ROLE_ORDER if r in roles)
-    for r in ("q", "k", "v", "o", "up", "down"):
-        if r not in roles:
-            raise ValueError(
-                f"whole_step tile_plan is missing role {r!r}"
-            )
-    tiled_w = {r: roles[r][0] for r in role_order}
-    tiled_names = set(tiled_w.values())
-    tw = {}
-    for r in role_order:
-        a = layer_arrays[tiled_w[r]]
-        if a.ndim != 3 or a.shape[0] != L:
-            raise ValueError(
-                f"whole_step tiled role {r!r}: weight "
-                f"{tiled_w[r]!r} must be (L, in, out), got {a.shape}"
-            )
-        if a.shape[-1] % K:
-            raise ValueError(
-                f"whole_step tiles={K} does not divide {tiled_w[r]!r} "
-                f"output dim {a.shape[-1]} (see "
-                "whole_step_tile_candidates)"
-            )
-        tw[r] = a.shape[-1] // K
-    Hdk = layer_arrays[tiled_w["q"]].shape[-1]
-    KVdk = layer_arrays[tiled_w["k"]].shape[-1]
-    F = layer_arrays[tiled_w["up"]].shape[-1]
-    layer_names = sorted(n for n in layer_arrays if n not in tiled_names)
-    head_names = sorted(head_arrays)
-    pool_names = ["k", "v"] + (["k_scale", "v_scale"] if quant else [])
-    I = _TILE_STAGES * K
-
-    def _const(spec_shape):
-        nd = len(spec_shape)
-        return pl.BlockSpec(
-            spec_shape, lambda l, i, _nd=nd: (0,) * _nd
-        )
-
-    def _per_layer(shape):
-        nd = len(shape) - 1
-        return pl.BlockSpec(
-            (1,) + tuple(shape[1:]),
-            lambda l, i, _nd=nd: (l,) + (0,) * _nd,
-        )
-
-    in_specs = []
-    operands = []
-    # streamed weight SUB-TILES: the index map walks the columns during
-    # the role's stage and freezes at the stage boundaries (col 0
-    # before, col K-1 after), so the revolving buffer neither refetches
-    # out of stage nor thrashes — Pallas prefetches tile t+1 while tile
-    # t computes, the same pipelined-grid contract as the layer walk
-    for r in role_order:
-        a = layer_arrays[tiled_w[r]]
-
-        def _tile_idx(l, i, _s=_TILE_ROLE_STAGE[r], _K=K):
-            st = i // _K
-            t = i % _K
-            col = jnp.where(
-                st < _s, 0, jnp.where(st == _s, t, _K - 1)
-            )
-            return (l, 0, col)
-
-        in_specs.append(pl.BlockSpec((1, a.shape[1], tw[r]), _tile_idx))
-        operands.append(a)
-    # untiled per-layer params (norm scales, biases): whole blocks,
-    # refetched once per layer
-    for name in layer_names:
-        a = layer_arrays[name]
-        if a.shape[0] != L:
-            raise ValueError(
-                f"whole_step_decode: layer array {name!r} leading dim "
-                f"{a.shape[0]} != num layers {L}"
-            )
-        in_specs.append(_per_layer(a.shape))
-        operands.append(a)
-    # streamed + aliased KV pool slices (and quant scale rows)
-    pool_in_idx = {}
-    for name in pool_names:
-        a = cache[name]
-        pool_in_idx[name] = len(operands)
-        in_specs.append(_per_layer(a.shape))
-        operands.append(a)
-    # resident (constant index map) operands
-    const_ops = [x0]
-    const_specs = [_const((R, C, D))]
-    if has_rope:
-        const_ops += [cos, sin]
-        const_specs += [_const(cos.shape), _const(sin.shape)]
-    const_ops += [
-        page_table.astype(jnp.int32), phys.astype(jnp.int32),
-        off.astype(jnp.int32), logits_idx.astype(jnp.int32), mask,
-    ]
-    const_specs += [
-        _const(page_table.shape), _const(phys.shape), _const(off.shape),
-        _const(logits_idx.shape), _const(mask.shape),
-    ]
-    for name in head_names:
-        const_ops.append(head_arrays[name])
-        const_specs.append(_const(head_arrays[name].shape))
-    in_specs += const_specs
-    operands += const_ops
-
-    head_abs = {n: head_arrays[n] for n in head_names}
-    V = jax.eval_shape(
-        lambda h, x, li: head_fn(h, x, li),
-        head_abs, jnp.zeros((R, C, D), x0.dtype),
-        logits_idx.astype(jnp.int32),
-    ).shape[-1]
-
-    out_shapes = [
-        jax.ShapeDtypeStruct((R, V), jnp.float32),       # logits
-        jax.ShapeDtypeStruct((R,), jnp.int32),           # greedy tokens
-    ]
-    out_specs = [_const((R, V)), _const((R,))]
-    aliases = {}
-    for j, name in enumerate(pool_names):
-        a = cache[name]
-        out_shapes.append(jax.ShapeDtypeStruct(a.shape, a.dtype))
-        out_specs.append(_per_layer(a.shape))
-        aliases[pool_in_idx[name]] = 2 + j
-
-    def kernel(*refs):
-        i = 0
-        wref = {}
-        for r in role_order:
-            wref[r] = refs[i]
-            i += 1
-        p_l = {}
-        for name in layer_names:
-            p_l[name] = refs[i][0]
-            i += 1
-        pool_refs = {}
-        for name in pool_names:
-            pool_refs[name] = refs[i]
-            i += 1
-        x0_ref = refs[i]; i += 1
-        if has_rope:
-            cos_ref = refs[i]; i += 1
-            sin_ref = refs[i]; i += 1
-        pt_ref = refs[i]; i += 1
-        ph_ref = refs[i]; i += 1
-        of_ref = refs[i]; i += 1
-        li_ref = refs[i]; i += 1
-        mask_ref = refs[i]; i += 1
-        head_vals = {}
-        for name in head_names:
-            head_vals[name] = refs[i][...]
-            i += 1
-        logits_ref = refs[i]; i += 1
-        tok_ref = refs[i]; i += 1
-        pool_out = {}
-        for name in pool_names:
-            pool_out[name] = refs[i]
-            i += 1
-        x_scr = refs[i]; i += 1      # residual carry
-        h_scr = refs[i]; i += 1      # attention-norm output
-        x2_scr = refs[i]; i += 1     # post-attention residual
-        h2_scr = refs[i]; i += 1     # MLP-norm output
-        q_scr = refs[i]; i += 1
-        k_scr = refs[i]; i += 1
-        v_scr = refs[i]; i += 1
-        attn_scr = refs[i]; i += 1
-        act_scr = refs[i]
-
-        l = pl.program_id(0)
-        ii = pl.program_id(1)
-        st = ii // K
-        t = ii % K
-        cs = cos_ref[...] if has_rope else None
-        sn = sin_ref[...] if has_rope else None
-
-        def _bias(r):
-            bname = roles[r][1]
-            if bname is None:
-                return None
-            return jax.lax.dynamic_slice_in_dim(
-                p_l[bname], t * tw[r], tw[r], axis=0
-            )
-
-        def _proj(r, h):
-            out_t = mm_fn(h, wref[r][0])
-            b = _bias(r)
-            return out_t if b is None else out_t + b
-
-        @pl.when((l == 0) & (ii == 0))
-        def _():
-            x_scr[:] = x0_ref[...]
-
-        # stage 0: attention norm once, then QKV column tiles; the
-        # last tile runs RoPE + KV commit + attention on the full rows
-        @pl.when((st == 0) & (t == 0))
-        def _():
-            h_scr[:] = pre_fn(p_l, x_scr[:])
-
-        @pl.when(st == 0)
-        def _():
-            h = h_scr[:]
-            q_scr[:, :, pl.ds(t * tw["q"], tw["q"])] = _proj("q", h)
-            k_scr[:, :, pl.ds(t * tw["k"], tw["k"])] = _proj("k", h)
-            v_scr[:, :, pl.ds(t * tw["v"], tw["v"])] = _proj("v", h)
-
-        @pl.when((st == 0) & (t == K - 1))
-        def _():
-            kb = pool_refs["k"][0]
-            vb = pool_refs["v"][0]
-            ks = pool_refs["k_scale"][0] if quant else None
-            vs = pool_refs["v_scale"][0] if quant else None
-            attn, kb, vb, ks, vs = attend_fn(
-                p_l, q_scr[:], k_scr[:], v_scr[:], cs, sn,
-                mask_ref[...], kb, vb, ks, vs,
-                ph_ref[...], of_ref[...], pt_ref[...],
-            )
-            attn_scr[:] = attn
-            pool_out["k"][0] = kb
-            pool_out["v"][0] = vb
-            if quant:
-                pool_out["k_scale"][0] = ks
-                pool_out["v_scale"][0] = vs
-
-        # stage 1: out-projection tiles accumulate the post-attention
-        # residual slice by slice; the last tile runs the MLP norm
-        @pl.when(st == 1)
-        def _():
-            ao = _proj("o", attn_scr[:])
-            sl = pl.ds(t * tw["o"], tw["o"])
-            x2_scr[:, :, sl] = x_scr[:, :, sl] + ao
-
-        @pl.when((st == 1) & (t == K - 1))
-        def _():
-            h2_scr[:] = mid_fn(p_l, x_scr[:], h_scr[:], x2_scr[:])
-
-        # stage 2: MLP up (+ gate) tiles → activation slices
-        @pl.when(st == 2)
-        def _():
-            h2 = h2_scr[:]
-            up_t = _proj("up", h2)
-            g_t = _proj("gate", h2) if glu else None
-            act_scr[:, :, pl.ds(t * tw["up"], tw["up"])] = (
-                act_fn(g_t, up_t)
-            )
-
-        # stage 3: down-projection tiles close the layer's residual
-        @pl.when(st == 3)
-        def _():
-            dn = _proj("down", act_scr[:])
-            sl = pl.ds(t * tw["down"], tw["down"])
-            x_scr[:, :, sl] = x2_scr[:, :, sl] + dn
-
-        @pl.when((l == L - 1) & (ii == I - 1))
-        def _():
-            logits = head_fn(head_vals, x_scr[:], li_ref[...])
-            logits_ref[...] = logits
-            tok_ref[...] = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=out_shapes,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
-            grid=(L, I),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((R, C, D), x0.dtype),
-                pltpu.VMEM((R, C, D), x0.dtype),
-                pltpu.VMEM((R, C, D), x0.dtype),
-                pltpu.VMEM((R, C, D), x0.dtype),
-                pltpu.VMEM((R, C, Hdk), x0.dtype),
-                pltpu.VMEM((R, C, KVdk), x0.dtype),
-                pltpu.VMEM((R, C, KVdk), x0.dtype),
-                pltpu.VMEM((R, C, Hdk), x0.dtype),
-                pltpu.VMEM((R, C, F), x0.dtype),
-            ],
-        ),
-        input_output_aliases=aliases,
-        name=f"ff_whole_step_tiled_c{C}_t{K}",
-        interpret=_interpret(),
-    )(*operands)
-    logits, toks = outs[0], outs[1]
-    new_cache = dict(cache)
-    for j, name in enumerate(pool_names):
-        new_cache[name] = outs[2 + j]
-    return logits, toks, new_cache

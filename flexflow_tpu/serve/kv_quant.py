@@ -54,8 +54,7 @@ bytes of never-written lines decode to the out-of-band code -8, which
 a zero page scale maps to 0.0). The halves-of-dk split (rather than
 even/odd interleave) unpacks as one concatenate — no lane-crossing
 reshuffle in the Pallas kernel. A fixed HBM budget holds ~4x the bf16
-pages (≥3.8x after the scale rows — asserted in the
-``serve_kv_hierarchy`` bench phase); the same write-side contract
+pages (less the scale rows); the same write-side contract
 (running amax, rescale-on-growth, offset-0 reset) applies on the
 unpacked code values, so int4 generation keeps the bitwise
 run-to-run and preemption/recompute guarantees, at a wider

@@ -1014,9 +1014,9 @@ class RequestManager:
                 last, host_tokens, use_last, positions, sub, greedy, temp,
                 topp, topk,
             )
-            # decode_step_ms (bench serve_megakernel; ROADMAP 5b): the
-            # engine call's host wall time — dispatch cost on this
-            # pipelined path (the device runs ahead; no sync is added)
+            # decode_step_ms: the engine call's host wall time —
+            # dispatch cost on this pipelined path (the device runs
+            # ahead; no sync is added)
             self.stats.note_decode_step_ms((time.perf_counter() - t0) * 1e3)
             self._mirror_dispatch(
                 last, host_tokens, use_last, positions,
@@ -1271,15 +1271,6 @@ class RequestManager:
             self.stats.cp_shards = cp
             self.stats.ring_steps += cp - 1
             self.stats.shard_balance = self.engine.pager.shard_balance()
-        # whole-step VMEM gate telemetry (engine._whole_step_vmem_gate)
-        # mirrored the same way, so BENCH_r*.json and the Prometheus
-        # scrape track when the walk is actually taken vs fallen back
-        self.stats.whole_step_fallbacks = getattr(
-            self.engine, "whole_step_fallbacks", 0
-        )
-        self.stats.whole_step_vmem_est = getattr(
-            self.engine, "whole_step_vmem_est", 0
-        )
         if self._step_counter % 200 == 0:
             self._log.debug("%s", self.stats.report())
 
@@ -1356,10 +1347,7 @@ class RequestManager:
         ]
         t0 = time.perf_counter()
         fused = self.engine.serving.fused_decode
-        if (
-            ("sampling" in fused or "whole_step" in fused)
-            and self.supports_fused_sampling
-        ):
+        if "sampling" in fused and self.supports_fused_sampling:
             # fused sampling epilogue: ONE dispatched program per sync
             # step (step + on-device decode head) instead of two — the
             # (R, V) logits never reach the host. Same single key split

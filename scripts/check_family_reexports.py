@@ -70,12 +70,6 @@ SERVE_API = (
     # fused variants themselves ride on serve_step_paged's
     # ``fused_rope=...`` kwarg (carried by reference, like kv_quant)
     "FUSED_DECODE",
-    # whole-step decode megakernel (PR 15): the one-program layer walk
-    # and its blocked-streaming weight-layout hook (the engine calls
-    # the hook at construction to gate capability and price VMEM)
-    "serve_step_whole",
-    "whole_step_weight_layout",
-    "whole_step_tile_roles",
     # triage + params
     "serve_debug_activations",
     "forward",

@@ -42,21 +42,7 @@
 #                           scale_out warm joins / scale_in drains
 #                           leak-free / set_pools under traffic,
 #                           replica-death + manager-death chaos
-#  12. whole-step megakernel — the one-program layer walk bitwise the
-#                           unfused XLA step over fp/int8/int4 pools,
-#                           TP2 exact-collective bitwise + int8
-#                           EQuARX tolerance, strictly-fewer-launches,
-#                           VMEM fallback, ring fused-prologue lift,
-#                           whole-step retrace churn
-#  13. sub-block streaming  — the VMEM-gated sub-block weight walk:
-#                           FF_WHOLE_STEP_VMEM_MB parse hardening,
-#                           tile pricing/selection units, tiled-walk
-#                           bitwise parity over fp/int8/int4, the
-#                           whole-step MIXED walk one-dispatch, gate
-#                           telemetry through SchedulerStats/Cluster-
-#                           Stats, 7B-class over-budget geometry auto-
-#                           picking tiles, tile-count retrace churn
-#  14. concurrent stepping  — multiplexed async RPC transport:
+#  12. concurrent stepping  — multiplexed async RPC transport:
 #                           call-tag demux of out-of-order socket
 #                           responses, the re-dial race (one
 #                           reconnect), the duplicate-seq at-most-
@@ -65,7 +51,7 @@
 #                           (seeded chaos included), the pinned
 #                           one-observation-per-step guard, in-flight
 #                           depth / step+RTT percentile telemetry
-#  15. self-driving serving — serving cost model structural sanities
+#  13. self-driving serving — serving cost model structural sanities
 #                           (capacity monotone in replicas, quantized-
 #                           KV page multiplication), traffic-estimator
 #                           bit-determinism on the step clock, offline
@@ -74,7 +60,7 @@
 #                           dry-run units, journaled burst scale_out→
 #                           scale_in e2e bitwise vs static, mid-scale-
 #                           event SIGKILL recovery
-#  16. concurrency analysis  — the ffcheck concurrency rules
+#  14. concurrency analysis  — the ffcheck concurrency rules
 #                           (FF109 wall-clock-in-step-logic, FF110
 #                           unguarded-shared-state, FF111
 #                           held-lock-blocking-call) over their test
@@ -84,17 +70,16 @@
 #                           raises), and the sanitizer-on == -off
 #                           bitwise suites (transport chaos, SIGKILL
 #                           recovery, autoscaler drive loop)
-#  17. distilled drafts +   — verify-skip state-machine units (skip at
+#  15. distilled drafts +   — verify-skip state-machine units (skip at
 #      verify-skip            cold (1,1), re-probe cadence, warm-up
 #                           exit), skip arm == incremental bitwise
 #                           with SSM cache debt repaid, distillation
 #                           harvest/train determinism on the pinned-
 #                           threefry CPU backend, checkpoint round-
 #                           trip, accept-rate-per-draft-GFLOP ranking
-#                           + measured-rate cost-model feed, the
-#                           megakernel-folded spec round bitwise the
-#                           unfused arm, skip/re-probe flapping
-#                           compiling a bounded step-key set
+#                           + measured-rate cost-model feed,
+#                           skip/re-probe flapping compiling a
+#                           bounded step-key set
 #
 # Exits non-zero at the first failing gate. Full tier-1 (ROADMAP.md
 # "Tier-1 verify") is the merge bar; this is the fast inner loop.
@@ -103,49 +88,49 @@ cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=cpu
 
-echo "== premerge 1/17: ffcheck (static hazard lint)" >&2
+echo "== premerge 1/15: ffcheck (static hazard lint)" >&2
 python scripts/ffcheck.py
 
-echo "== premerge 2/17: family serve-API re-exports" >&2
+echo "== premerge 2/15: family serve-API re-exports" >&2
 python scripts/check_family_reexports.py
 
-echo "== premerge 3/17: fused decode parity + retrace guard" >&2
+echo "== premerge 3/15: fused decode parity + retrace guard" >&2
 # unfiltered: runs the interpret-mode Pallas e2e tests that tier-1
 # slow-marks for time-budget reasons
 python -m pytest tests/test_fused_decode.py tests/test_retrace_guard.py \
     -q -p no:cacheprovider
 
-echo "== premerge 4/17: hierarchical KV cache (int4 + host spill)" >&2
+echo "== premerge 4/15: hierarchical KV cache (int4 + host spill)" >&2
 # Pallas/XLA nibble-unpack parity, bitwise cold/warm/spilled-readmit
 # generation parity over fp+int8+int4 pools, spill-tier bookkeeping
 python -m pytest tests/test_kv_hierarchy.py -q -p no:cacheprovider
 
-echo "== premerge 5/17: cluster serving (router + migration)" >&2
+echo "== premerge 5/15: cluster serving (router + migration)" >&2
 # router units, cluster-vs-bare-engine bitwise parity, disaggregated
 # prefill→decode migration over fp/int8/int4, shed-is-terminal
 python -m pytest tests/test_cluster.py -q -p no:cacheprovider
 
-echo "== premerge 6/17: fault-tolerant cluster serving" >&2
+echo "== premerge 6/15: fault-tolerant cluster serving" >&2
 # health state machine + circuit breaker, deterministic FaultPlan
 # injection, replica-death failover bitwise vs the fault-free run,
 # seeded chaos (every request terminal, zero leaks on survivors),
 # migration queue back-pressure, pool-death fallbacks
 python -m pytest tests/test_cluster_faults.py -q -p no:cacheprovider
 
-echo "== premerge 7/17: adaptive speculation" >&2
+echo "== premerge 7/15: adaptive speculation" >&2
 # tree-shaping controller units, spec==incremental bitwise parity over
 # fp/int8/int4 pools + prefix-cache hits + continuous-batching churn,
 # early-exit self-draft, cluster SSM-mirror smoke
 python -m pytest tests/test_adaptive_spec.py -q -p no:cacheprovider
 
-echo "== premerge 8/17: context-parallel long-context serving" >&2
+echo "== premerge 8/15: context-parallel long-context serving" >&2
 # striped allocator invariants, CP-vs-single-shard bitwise parity
 # (fp/int8; int4 at tolerance), chunked prefill across shards, spill/
 # readmit + preemption under CP, ring shard_map kernel parity on a
 # seq=2 mesh, CP retrace churn (one program per step key)
 python -m pytest tests/test_long_context.py -q -p no:cacheprovider
 
-echo "== premerge 9/17: replica RPC transport + warm standbys" >&2
+echo "== premerge 9/15: replica RPC transport + warm standbys" >&2
 # unfiltered: runs the int8/int4 loopback parity params and the
 # subprocess replica-server tests that tier-1 slow-marks — wire-codec
 # byte-exactness, loopback cluster bitwise the in-process PR-8/9
@@ -154,7 +139,7 @@ echo "== premerge 9/17: replica RPC transport + warm standbys" >&2
 # gaps + the one-observation-per-step guard, warm-standby adoption
 python -m pytest tests/test_transport.py -q -p no:cacheprovider
 
-echo "== premerge 10/17: observability (tracing + export + recorder)" >&2
+echo "== premerge 10/15: observability (tracing + export + recorder)" >&2
 # unfiltered: runs the subprocess-replica envelope-shipping test and
 # the trace-determinism re-run that tier-1 slow-marks — stitched
 # fault-injected loopback timeline (one trace id across both replicas
@@ -166,7 +151,7 @@ echo "== premerge 10/17: observability (tracing + export + recorder)" >&2
 # dispatched-programs-per-step)
 python -m pytest tests/test_observability.py -q -p no:cacheprovider
 
-echo "== premerge 11/17: elastic control plane (journal + reconfigure)" >&2
+echo "== premerge 11/15: elastic control plane (journal + reconfigure)" >&2
 # unfiltered: runs the int8 kill-restart, subprocess reconnect and
 # sigkill-chaos tests that tier-1 slow-marks — journal round-trip +
 # torn-tail truncation + compaction, manager kill-restart bitwise the
@@ -176,26 +161,7 @@ echo "== premerge 11/17: elastic control plane (journal + reconfigure)" >&2
 # death chaos
 python -m pytest tests/test_elastic.py -q -p no:cacheprovider
 
-echo "== premerge 12/17: whole-step decode megakernel" >&2
-# unfiltered: runs the quantized e2e generation-parity params, the
-# TP2 int8-collective generation run and the whole-step retrace churn
-# that tier-1 slow-marks — collectives units (exact == psum bitwise,
-# int8 tolerance), the fp/int8/int4 whole-vs-unfused bitwise matrix,
-# TP2 exact bitwise, launch accounting, VMEM fallback, and the lifted
-# rope_kv_write × kv_shard='context' ring prologue
-python -m pytest tests/test_whole_step.py -q -p no:cacheprovider
-
-echo "== premerge 13/17: whole-step sub-block weight streaming" >&2
-# unfiltered: runs the quantized tiled-walk params, the 7B-class
-# over-budget geometry matrix and the tile-count retrace churn that
-# tier-1 slow-marks — FF_WHOLE_STEP_VMEM_MB parse hardening, tile
-# candidate/pricing units, forced-tiles bitwise parity, the
-# whole-step mixed walk's one-dispatch-per-step accounting, VMEM-gate
-# telemetry mirroring, and the default-budget auto-pick on >12 MB/
-# layer geometry (the shape PR 15 used to fall back on)
-python -m pytest tests/test_whole_step_subblock.py -q -p no:cacheprovider
-
-echo "== premerge 14/17: concurrent cluster stepping (async transport)" >&2
+echo "== premerge 12/15: concurrent cluster stepping (async transport)" >&2
 # unfiltered: runs the subprocess two-server fan-out test that tier-1
 # slow-marks — RpcFuture deadline/issue semantics, socket call-tag
 # demux of out-of-order responses, the serialized re-dial race, the
@@ -208,7 +174,7 @@ echo "== premerge 14/17: concurrent cluster stepping (async transport)" >&2
 # telemetry through the Prometheus exporter
 python -m pytest tests/test_transport_async.py -q -p no:cacheprovider
 
-echo "== premerge 15/17: self-driving serving (autotune + autoscaler)" >&2
+echo "== premerge 13/15: self-driving serving (autotune + autoscaler)" >&2
 # unfiltered: runs the burst scale_out→scale_in e2e, the mid-scale-
 # event SIGKILL recovery and the advise-mode e2e that tier-1 slow-
 # marks — cost-model monotonicity/feasibility units, estimator
@@ -218,7 +184,7 @@ echo "== premerge 15/17: self-driving serving (autotune + autoscaler)" >&2
 # completion-window + per-replica arrival/completion reconciliation
 python -m pytest tests/test_autotune.py -q -p no:cacheprovider
 
-echo "== premerge 16/17: concurrency analysis + lock sanitizer" >&2
+echo "== premerge 14/15: concurrency analysis + lock sanitizer" >&2
 # the three PR-19 AST rules + drift/lock-order whole-program checks
 # over their fixture corpus (must lint clean — the fixtures exercise
 # the suppression/registry syntax premerge depends on), the sanitizer
@@ -232,9 +198,9 @@ python -m pytest tests/test_transport.py tests/test_elastic.py \
     tests/test_autotune.py -q -p no:cacheprovider \
     -k "locks_sanitizer"
 
-echo "== premerge 17/17: distilled drafts + verify-skip" >&2
-# unfiltered: runs the megakernel-fold bitwise e2e and the verify-skip
-# flapping churn variant that tier-1 slow-marks — verify-skip
+echo "== premerge 15/15: distilled drafts + verify-skip" >&2
+# unfiltered: runs the verify-skip flapping churn variant that tier-1
+# slow-marks — verify-skip
 # controller units + skip-arm bitwise parity (SSM lag repaid),
 # distillation determinism / checkpoint round-trip / draft ranking,
 # measured accept rate overriding the cost model's workload prior

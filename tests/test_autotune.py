@@ -5,8 +5,7 @@ The contracts under test:
 
 * **Cost model** — structural sanities the search and policy lean on:
   capacity is monotone in replicas, quantized KV multiplies the page
-  budget, the whole-step fusion never prices slower than the per-layer
-  launch tax, oversubscription only slows a candidate down.
+  budget, oversubscription only slows a candidate down.
 * **Estimator** — bit-identical profiles from identical observation
   sequences (the replayable-decisions property), pre-envelope windows
   never fit garbage (ready() gates), wall clock enters ONLY at
@@ -109,13 +108,6 @@ def test_quantized_kv_multiplies_page_budget():
     # the budget invariant: ~1.9x for int8, ~3.8x for int4
     assert i8.kv_pages_capacity >= 1.8 * fp.kv_pages_capacity
     assert i4.kv_pages_capacity >= 3.5 * fp.kv_pages_capacity
-
-
-def test_whole_step_never_slower():
-    cm = ServingCostModel(GEOM)
-    fused = cm.predict(ServingCandidate(whole_step=True), TRAFFIC)
-    unfused = cm.predict(ServingCandidate(whole_step=False), TRAFFIC)
-    assert fused.decode_step_s <= unfused.decode_step_s
 
 
 def test_oversubscription_slows_decode():
@@ -224,6 +216,32 @@ def test_search_emits_validate_cluster_accepted_config():
     assert sc.kv_layout == "paged"
     assert report.prediction.feasible
     assert report.summary().startswith("serving search:")
+
+
+def test_every_emitted_candidate_builds_an_engine(tiny):
+    """What the search emits, the engine accepts: every candidate of the
+    leaderboard for the tiny geometry lowers to a ServingConfig whose
+    fusions the family advertises, and one replica's InferenceEngine
+    builds from it. (Until PR 31 the DEFAULT candidate lowered to a
+    fusion the engine refused on a TPU, and only there.)"""
+    from flexflow_tpu.serve import InferenceEngine
+
+    cfg, params = tiny
+    best, report = search_serving_config(
+        ModelGeometry.from_model_config(cfg), TRAFFIC, chip_budget=8,
+    )
+    assert best is not None and report.table
+    built = []
+    for cand in [best] + [c for c, _ in report.table]:
+        sc = cand.to_serving_config(
+            max_sequence_length=96, cache_dtype=jnp.float32,
+            replicas=1, prefill_replicas=0, decode_replicas=0,
+        )
+        assert set(sc.fused_decode) <= {"sampling", *llama.FUSED_DECODE}
+        if sc not in built:
+            built.append(sc)
+            InferenceEngine(llama, cfg, params, sc)  # must not raise
+    assert built
 
 
 def test_search_never_emits_spec_x_disagg():
@@ -733,7 +751,7 @@ def test_autoscale_advise_mode_applies_nothing_e2e(tiny, tmp_path):
 # ---------------------------------------------------------------------------
 # PR-19 satellite: the autoscaler drive loop under the lock sanitizer —
 # decisions and outputs BITWISE identical sanitizer-on vs -off, zero
-# findings. Gate 16 selects this by the `locks_sanitizer` fragment.
+# findings. Gate 14 selects this by the `locks_sanitizer` fragment.
 
 
 @pytest.mark.slow

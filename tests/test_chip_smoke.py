@@ -125,31 +125,3 @@ def test_seeded_normal_is_the_eager_draw_bit_for_bit(dtype):
             )
 
 
-@pytest.mark.parametrize("serving_kw, named", [
-    (dict(fused_decode=("whole_step",)), "whole_step"),
-])
-def test_a_refused_kernels_option_raises_on_tpu(serving_kw, named):
-    """An option whose kernel the chip's compiler refuses is an error on
-    a TPU (with the compiler's reason), and still runs — in interpret
-    mode — on the CPU."""
-    from flexflow_tpu.models import llama
-    from flexflow_tpu.serve import InferenceEngine, ServingConfig, kernels
-
-    cfg = llama.LLaMAConfig.tiny(dtype=jnp.float32)
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    serving = ServingConfig(
-        kv_layout="paged", page_size=8, kernels="pallas",
-        max_requests_per_batch=2, max_sequence_length=32, **serving_kw,
-    )
-    on_cpu = InferenceEngine(llama, cfg, params, serving)  # fine
-
-    class OnTpu:
-        platform = "tpu"
-
-    class TpuMesh:
-        devices = np.array([OnTpu()], dtype=object)
-        shape = on_cpu.mesh.shape
-
-    with pytest.raises(NotImplementedError, match=named) as exc:
-        InferenceEngine(llama, cfg, params, serving, TpuMesh())
-    assert any(msg in str(exc.value) for msg in kernels.TPU_REFUSED.values())

@@ -67,6 +67,13 @@ def test_cli_serve_cluster_flags():
     assert "BOTH pools" in r.stderr
 
 
+def test_cli_serve_refuses_a_fusion_that_is_gone():
+    r = _run(["serve", "--kv-layout", "paged", "--fused-decode", "whole_step",
+              "--max-new-tokens", "2"])
+    assert r.returncode != 0
+    assert "unknown fused_decode entry" in r.stderr
+
+
 def test_cli_search_exports(tmp_path):
     dot = str(tmp_path / "strategy.dot")
     strat = str(tmp_path / "strategy.json")

@@ -849,7 +849,7 @@ def test_chaos_sigkill_server_and_manager_crash(tiny, tmp_path):
 # ---------------------------------------------------------------------------
 # PR-19 satellite: the SIGKILL-recovery path under the lock sanitizer —
 # recovery must be BITWISE identical sanitizer-on vs -off, with zero
-# findings over the whole kill/replay/finish sequence. Gate 16 selects
+# findings over the whole kill/replay/finish sequence. Gate 14 selects
 # this by the `locks_sanitizer` name fragment.
 
 

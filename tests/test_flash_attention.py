@@ -1,7 +1,6 @@
 """Flash-attention kernel numerics: fwd + custom-VJP bwd vs the XLA
 attention path (ADVICE r3 medium: the 363-line Pallas kernel had no
-direct test coverage). Runs interpret=True on the CPU mesh; the on-chip
-Mosaic compile is gated separately by bench.py's kernel_parity phase."""
+direct test coverage). Runs interpret=True on the CPU mesh."""
 import functools
 
 import numpy as np

@@ -17,6 +17,7 @@ unfused step on the same backend:
 Covered pools: dense, paged, paged+int8; greedy plus per-row top-k
 batches; the mixed prefill+decode step (continuous batching); TP2.
 """
+import dataclasses
 import functools
 
 import jax
@@ -314,3 +315,19 @@ def test_fused_decode_validation(tiny):
         llama, cfg, params, _sc("rope_kv_write, sampling")
     )
     assert eng.serving.fused_decode == ("rope_kv_write", "sampling")
+
+
+@pytest.mark.parametrize("spelling, error, names", [
+    (dict(fused_decode=("whole_step",)), ValueError,
+     "'rope_kv_write' and/or 'sampling'"),
+    (dict(quantized_allreduce="int8"), TypeError, "quantized_allreduce"),
+])
+def test_a_deleted_spelling_is_refused_as_any_unknown_one(
+        tiny, spelling, error, names):
+    """The whole-step walk and its collectives are gone (PR 31): the
+    old value is an unknown fusion, answered with the two that are left,
+    and the old field is no field."""
+    cfg, params = tiny
+    with pytest.raises(error, match=names):
+        InferenceEngine(llama, cfg, params,
+                        dataclasses.replace(_sc(()), **spelling))

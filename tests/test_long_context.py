@@ -23,17 +23,22 @@ Contracts under test:
     agrees greedily with the single-device run.
   * Retrace guard: CP churn compiles one program per step key, zero
     steady-state recompiles.
+  * The fused RoPE + KV-write prologue (fused_decode="rope_kv_write")
+    joins the ring body bitwise on full-precision pools; the quantized
+    ring commit stays excluded by name.
 
-Wired as premerge gate 8/8 (scripts/premerge.sh).
+Wired as premerge gate 8 (scripts/premerge.sh).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding
 
-from flexflow_tpu.core.mesh import MachineSpec
+from flexflow_tpu.core.mesh import MachineSpec, set_mesh
 from flexflow_tpu.models import llama
 from flexflow_tpu.serve import (
     InferenceEngine,
@@ -466,3 +471,66 @@ class TestCpRetrace:
             f"steady-state CP workload compiled new programs: "
             f"{before} -> {s2['compiles']}"
         )
+
+
+# ---------------------------------------------------------------------------
+# the fused prologue inside the ring body (rope_kv_write × kv_shard)
+
+
+@pytest.mark.slow  # seq=2 shard_map compile x2 (~4s); premerge gate 8
+# unfiltered (the validation check below stays in tier-1)
+def test_ring_fused_rope_kv_write_bitwise(tiny):
+    """seq=2 mesh, kernels='pallas': the fused prologue inside the ring
+    body is bitwise the unfused ring composition — prefill chunk AND
+    decode step, logits and pool bytes."""
+    cfg, params = tiny
+    mesh = MachineSpec(seq=2).make_mesh(jax.devices()[:2])
+    rng = np.random.RandomState(0)
+    ps, NP, Pp = 8, 4, 5  # rows = 6, divisible by the seq degree
+    cache0 = llama.init_paged_kv_cache(cfg, Pp, ps)
+    cspecs = llama.paged_kv_cache_pspecs(cfg, kv_shard="context")
+    cache0 = {
+        n: jax.device_put(a, NamedSharding(mesh, cspecs[n]))
+        for n, a in cache0.items()
+    }
+    R = 2
+    pt = jnp.asarray([[0, 1, Pp, Pp], [2, 3, Pp, Pp]], jnp.int32)
+    ptoks = jnp.asarray(rng.randint(0, cfg.vocab_size, (R, 5)), jnp.int32)
+    ppos = jnp.broadcast_to(jnp.arange(5, dtype=jnp.int32), (R, 5))
+    lidx = jnp.full((R,), 4, jnp.int32)
+    outs = {}
+    for fused in (False, True):
+        c = dict(cache0)
+        step = functools.partial(
+            llama.serve_step_paged, cfg=cfg, cache_len=NP * ps - 1,
+            kernels="pallas", fused_rope=fused, cp_mesh=mesh,
+        )
+        with set_mesh(mesh):
+            l1, c = jax.jit(step)(params, c, ptoks, ppos, lidx,
+                                  None, None, pt)
+            dtok = jnp.asarray([[7], [11]], jnp.int32)
+            dpos = jnp.full((R, 1), 5, jnp.int32)
+            l2, c = jax.jit(step)(params, c, dtok, dpos,
+                                  jnp.zeros((R,), jnp.int32),
+                                  None, None, pt)
+        outs[fused] = (l1, l2, c)
+    a, b = outs[False], outs[True]
+    assert bool(jnp.all(a[0] == b[0])), "prefill logits diverge"
+    assert bool(jnp.all(a[1] == b[1])), "decode logits diverge"
+    for n in a[2]:
+        assert bool(jnp.all(a[2][n][:, :Pp] == b[2][n][:, :Pp])), n
+
+
+def test_ring_fused_validation_lifted_and_quant_still_excluded():
+    """validate_long_context: fp rope_kv_write × seq-sharded passes;
+    the QUANTIZED ring commit stays excluded by name."""
+    sc = ServingConfig(
+        max_requests_per_batch=4, max_sequence_length=48, prefill_chunk=8,
+        max_spec_tree_tokens=8, cache_dtype=jnp.float32, kv_layout="paged",
+        page_size=8, kernels="pallas", fused_decode=("rope_kv_write",),
+        kv_shard="context", context_shards=0,
+    )
+    sc.validate_long_context(mesh_seq_degree=2)  # no raise
+    bad = dataclasses.replace(sc, kv_quant="int8")
+    with pytest.raises(ValueError, match="QUANTIZED"):
+        bad.validate_long_context(mesh_seq_degree=2)

@@ -74,7 +74,7 @@ def test_from_file_v5e16(tmp_path):
     p = tmp_path / "machine.cfg"
     p.write_text(
         """
-# v5e-16 (BASELINE.json north-star shape)
+# v5e-16
 chip = v5e
 num_chips = 16
 torus = 4x4

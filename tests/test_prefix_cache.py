@@ -330,8 +330,7 @@ def test_eviction_under_pressure_regression(tiny):
 
 @pytest.mark.slow
 def test_poisson_shared_system_prompt_parity(tiny):
-    """Poisson-arrival shared-system-prompt workload (the bench.py
-    serve_prefix shape): caching on vs off must produce identical
+    """Poisson-arrival shared-system-prompt workload: caching on vs off must produce identical
     outputs while the cache reports a substantial hit rate."""
     cfg, _ = tiny
     rng = np.random.default_rng(7)

@@ -214,60 +214,6 @@ def test_churn_fused_decode_zero_retraces(tiny):
     assert outs == outs_off
 
 
-@pytest.mark.slow  # the whole-step walk recompiles per head mode under
-# interpret-mode Pallas (~tens of seconds); premerge gate 12 runs it
-# unfiltered
-def test_churn_whole_step_zero_retraces(tiny):
-    """The WHOLE-STEP decode megakernel under the headline churn
-    workload (fused_decode=("whole_step",)): admission waves past 64
-    slots, preemption, prefix splice/COW/eviction, and decode batches
-    oscillating between greedy and bucketed-top-k heads. The bar: ONE
-    compile per step key — the whole-step program compiles once per
-    head mode it actually serves, nothing per churn event — ZERO
-    steady-state retraces, and generations bitwise the unfused
-    engine's."""
-    cfg, _ = tiny
-    eng = churn_engine(
-        tiny, "paged", ("retrace", "donation"), fused=("whole_step",)
-    )
-    assert eng.whole_step_on
-    rm = RequestManager(eng)
-    prompts = churn_prompts(cfg, n=80)
-    outs = run_churn(rm, prompts, mixed_sampling=True)
-    assert all(len(o) == 6 for o in outs)
-
-    s = rm.stats
-    assert s.preemptions > 0, "pool never exhausted — churn too soft"
-    assert s.prefix_hits > 0 and s.prefix_evictions > 0
-    # decode_step_ms telemetry rides the same churn
-    assert s.decode_step_ms_samples and s.decode_step_ms_p50 > 0.0
-
-    # greedy-only tail on the sealed engine: the greedy whole-step key
-    # compiles exactly once more, nothing retraces
-    tail = [rm.submit(p, max_new_tokens=6) for p in churn_prompts(cfg, n=8)]
-    while rm.step():
-        pass
-    rm.drain()
-    assert all(len(rm.requests[r].output_tokens) == 6 for r in tail)
-
-    guard = eng.retrace_guard
-    guard.assert_one_compile_per_key()
-    assert guard.retraces == 0
-    counts = guard.compile_counts()
-    whole_keys = [k for k in counts if k[0] == "whole_step"]
-    assert whole_keys, counts
-    assert {k[3] for k in whole_keys} == {"greedy", "topk"}, counts
-    assert all(counts[k] == 1 for k in whole_keys), counts
-
-    # the guard is a pure observer on the whole-step path too
-    outs_off = run_churn(
-        RequestManager(churn_engine(tiny, "paged", (),
-                                    fused=("whole_step",))),
-        prompts, mixed_sampling=True,
-    )
-    assert outs == outs_off
-
-
 @pytest.mark.parametrize("kv_layout", ["paged", "paged-q"])
 def test_sanitizers_do_not_change_outputs(tiny, kv_layout):
     """Guard + sanitizer are observers: bitwise-identical generations

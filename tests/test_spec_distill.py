@@ -1,15 +1,13 @@
 """Distilled drafts + verify-skip (PR 20, ROADMAP item 4).
 
-Three claims under test. (1) Verify-skip: a request whose controller
+Two claims under test. (1) Verify-skip: a request whose controller
 sits at the (1,1) rung with a cold acceptance EMA rides the incremental
 decode path — bitwise the non-speculative scheduler, with the SSM
 mirrors' cache debt repaid before anything reads them. (2) Distillation
 (`serve/spec_distill.py`): harvest → KL-train → checkpoint is
 deterministic on the pinned-threefry CPU backend, and the emitted
 student loads as an SSM spec whose utility the eval harness prices by
-accept-rate-per-draft-GFLOP. (3) The megakernel fold: early-exit spec
-rounds dispatched through the whole-step walk are bitwise the unfused
-spec rounds (slow-marked e2e).
+accept-rate-per-draft-GFLOP.
 """
 import dataclasses
 
@@ -412,34 +410,3 @@ def test_cost_model_prefers_measured_accept_rate():
     assert commit_hot > commit_prior   # measured-hot beats the prior
 
 
-# ---------------------------------------------------------------------------
-# the megakernel fold (heavy e2e: whole-step walk on CPU)
-
-
-@pytest.mark.slow
-def test_megakernel_fold_bitwise_unfused(tiny):
-    """Early-exit spec rounds dispatched through the whole-step walk
-    (draft = layer-sliced grid, verify = tree-masked all-positions
-    head) produce bitwise the unfused spec arm's outputs — which are
-    themselves bitwise plain incremental greedy."""
-    spec = SpecConfig(2, 3, draft="early_exit", draft_layers=1)
-    ref = incr_ref(tiny, n_new=10)
-
-    mgr_unf = SpecInferManager(make_engine(tiny), None, spec)
-    unf = [
-        o.output_tokens for o in mgr_unf.generate(PROMPTS, max_new_tokens=10)
-    ]
-    assert unf == ref
-    assert not mgr_unf.engine.whole_step_spec_on
-
-    eng = make_engine(tiny, fused_decode=("whole_step",))
-    assert eng.whole_step_spec_on
-    mgr_fold = SpecInferManager(eng, None, spec)
-    fold = [
-        o.output_tokens
-        for o in mgr_fold.generate(PROMPTS, max_new_tokens=10)
-    ]
-    assert fold == unf
-    keys = [str(k) for k in eng._steps]
-    assert any("whole_step_tree" in k for k in keys), keys
-    assert any("speculate" in k and "whole_step" in k for k in keys), keys

@@ -227,15 +227,40 @@ def test_step_programs_are_named_from_their_keys(tiny, sanitizers):
         assert f"module @jit_{name} " in lowered.as_text()
 
 
+@pytest.mark.parametrize("kind, chunk", [("decode", 1), ("mixed", CHUNK)])
+def test_one_step_program_a_scheduler_step(tiny, kind, chunk):
+    """A scheduler step of the pipelined paged Pallas engine dispatches
+    ONE device program, ``ff_step_c<chunk>``, compiled once: the
+    in-suite twin of the benchmark's ``engine.programs_per_step`` (which
+    counts the device's modules over those named ``jit_ff_step_*``)."""
+    rm = make_rm(tiny, kernels="pallas", sanitizers=("retrace",))
+    eng = rm.engine
+    for p in PROMPTS:
+        rm.submit(p, max_new_tokens=6)
+    steps_of_kind = 0
+    more = True
+    while more:
+        before = (eng.dispatch_count, getattr(rm.stats, f"{kind}_steps"))
+        more = rm.step()
+        if getattr(rm.stats, f"{kind}_steps") > before[1]:
+            steps_of_kind += 1
+            assert eng.dispatch_count - before[0] == 1
+    rm.drain()
+    assert steps_of_kind > 0
+    counts = eng.retrace_guard.compile_counts()
+    key = ("mixed_fused", chunk, False)
+    assert counts.get(key) == 1, counts
+    assert program_name(key) == f"ff_step_c{chunk}"
+    assert eng.retrace_guard.retraces == 0
+
+
 def test_program_names_are_distinct_and_stable():
     keys = ["commit", "copy_page", "reorder", (1, False, False),
             (8, True, True), ("mixed_fused", 1, False),
             ("mixed_fused", 1, True), ("mixed_fused", 1, False, "greedy", 0),
             ("mixed_fused", 1, False, "topk", 64),
             ("step_sampled", 1, False, "greedy", 0, False),
-            ("whole_step", 1, 2, "greedy", 0, False),
-            ("whole_step_tree", 8), ("speculate", 2, 3),
-            ("speculate", 2, 3, 1, "whole_step")]
+            ("speculate", 2, 3)]
     names = [program_name(k) for k in keys]
     assert len(set(names)) == len(names)
     assert all(n.startswith("ff_") and n.replace("_", "").isalnum()
@@ -245,7 +270,7 @@ def test_program_names_are_distinct_and_stable():
     assert names[4] == "ff_step_sync_c8_logits_mask"
     assert names[8] == "ff_step_c1_topk64"
     # every per-step program reads as a step
-    assert all(n.startswith("ff_step_") for n in names[3:12])
+    assert all(n.startswith("ff_step_") for n in names[3:10])
 
 
 # ---------------------------------------------------------------------------

@@ -840,7 +840,7 @@ def test_subprocess_server_survives_malformed_frames(tiny):
 # lock sanitizer over the threaded transport (PR-19): the deadlock
 # regression — reader delivering out-of-order completions while the
 # writer re-dials under the writer lock — and the sanitizer-on ==
-# sanitizer-off bitwise chaos run. Gate 16 selects these by the
+# sanitizer-off bitwise chaos run. Gate 14 selects these by the
 # `locks_sanitizer` name fragment.
 
 from flexflow_tpu.analysis.locks import (  # noqa: E402

@@ -9,7 +9,7 @@ reorder completions; outputs/health/failover sequence bitwise the
 serial arm's), the pinned one-observation-per-step guard under the
 concurrent loop, and the new ClusterStats/exporter surface
 (rpc_inflight_peak, cluster_step_ms + per-replica RTT percentiles).
-Premerge gate 14 runs this file unfiltered; the subprocess variant is
+Premerge gate 12 runs this file unfiltered; the subprocess variant is
 slow-marked.
 """
 import json
@@ -573,7 +573,7 @@ def test_cluster_stats_async_fields_and_exporter(tiny):
 
 # ---------------------------------------------------------------------------
 # subprocess replica servers under the concurrent loop (slow: spawns
-# its own JAX runtimes; premerge gate 14 runs this unfiltered)
+# its own JAX runtimes; premerge gate 12 runs this unfiltered)
 
 
 def _spawn_server(serving_dict, index=0, seed=0):
