@@ -176,6 +176,15 @@ class SchedulerStats:
     attn_steps_grid: int = 0
     attn_steps_live: int = 0
     attn_steps_narrow: int = 0
+    # The token axis of the pipelined mixed steps (note_step_tokens):
+    # the real tokens they held, the widths they were dispatched at (a
+    # packed rung of the engine's ladder, serve/engine.pack_widths; a
+    # step that is not packed counts slots x chunk), and the steps by
+    # width. The dict is REPLACED on every count, never updated in
+    # place, so a copy of the stats keeps the counts of its own moment.
+    step_tokens_real: int = 0
+    step_tokens_width: int = 0
+    steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
 
     def record_step(
         self,
@@ -231,6 +240,14 @@ class SchedulerStats:
         self.attn_steps_live += int(live.sum())
         self.attn_steps_narrow += int(live[count <= narrow].sum())
 
+    def note_step_tokens(self, real: int, width: int) -> None:
+        """Count one mixed step's token axis: the ``real`` tokens it
+        held and the ``width`` it ran at."""
+        self.step_tokens_real += int(real)
+        self.step_tokens_width += int(width)
+        by = self.steps_by_width
+        self.steps_by_width = {**by, int(width): by.get(int(width), 0) + 1}
+
     def note_decode_step_ms(self, ms: float) -> None:
         """Record one decode-step wall sample (bounded reservoir)."""
         s = self.decode_step_ms_samples
@@ -263,6 +280,13 @@ class SchedulerStats:
         return (
             self.budget_fill_sum / self.mixed_steps if self.mixed_steps else 0.0
         )
+
+    @property
+    def pack_fill(self) -> float:
+        """Real tokens over dispatched width, over the mixed steps."""
+        if not self.step_tokens_width:
+            return 0.0
+        return self.step_tokens_real / self.step_tokens_width
 
     @property
     def prefix_hit_rate(self) -> float:
@@ -330,6 +354,10 @@ class SchedulerStats:
             "decode_step_ms_p99": round(self.decode_step_ms_p99, 3),
             "compiles": self.compiles,
             "retraces": self.retraces,
+            "step_tokens_real": self.step_tokens_real,
+            "step_tokens_width": self.step_tokens_width,
+            "pack_fill": round(self.pack_fill, 4),
+            "steps_by_width": dict(sorted(self.steps_by_width.items())),
         }
 
     def report(self) -> str:
@@ -355,7 +383,10 @@ class SchedulerStats:
             f"bal={s['shard_balance']:.2f} "
             f"dstep_ms={s['decode_step_ms_p50']:.2f}/"
             f"{s['decode_step_ms_p99']:.2f} "
-            f"compiles={s['compiles']} retraces={s['retraces']}"
+            f"compiles={s['compiles']} retraces={s['retraces']} "
+            f"pack={s['step_tokens_real']}/{s['step_tokens_width']} by width "
+            + (",".join(f"{w}:{n}" for w, n in s["steps_by_width"].items())
+               or "-")
         )
 
 

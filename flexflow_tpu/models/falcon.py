@@ -14,6 +14,7 @@ from . import transformer
 from .transformer import (  # noqa: F401  (engine serving protocol)
     DecoderConfig,
     FUSED_DECODE,
+    PACKED_STEP,
     commit_kv,
     commit_kv_paged,
     copy_page_kv,

@@ -894,6 +894,14 @@ def reorder_slots(
 #: it lives in the engine's step program — so it is not listed here.
 FUSED_DECODE = ("rope_kv_write",)
 
+#: serve_step_paged takes a PACKED token axis (its ``pack``): the engine
+#: may run a mixed step's norms, projections, FFN and residual stream
+#: over the tokens that exist, at one of a few compiled widths
+#: (serve/engine.pack_widths), and keep (slots, chunk) for attention
+#: alone. A family whose step has no such axis leaves this unset and is
+#: served the padded program.
+PACKED_STEP = True
+
 
 def init_paged_kv_cache(
     cfg: DecoderConfig, num_pages: int, page_size: int, dtype=None,
@@ -1015,11 +1023,56 @@ def _pallas_pools(k_pool, v_pool, k_scale, v_scale, layer):
                       row_offset=layer * k_pool.shape[1])
 
 
+def _pack_tokens(tokens, positions, q_len, page_table, page_size, cache_len,
+                 width):
+    """The packed token axis of a (R, C) step at the static ``width``:
+    the real tokens (a row's leading ``q_len`` columns,
+    serve/kernels.real_query_lengths) in row-major order, derived on the
+    device. Returns ``(tokens, positions, phys, off)``, each (1, width)
+    — spare places hold token 0 at the scratch position, whose K/V line
+    is the scratch line of row 0 — and ``(place, flat)``: ``place``
+    (R, C) the packed place of every column (padding columns point at
+    some real place: attention never reads them as queries that count),
+    ``flat`` (width,) the r*C+c each packed place came from. The caller
+    has chosen ``width`` to hold the real tokens
+    (serve/engine.run_mixed)."""
+    R, C = positions.shape
+    real = (jnp.arange(C, dtype=jnp.int32)[None] < q_len[:, None]).reshape(-1)
+    (flat,) = jnp.nonzero(real, size=width, fill_value=R * C)
+    spare = flat >= R * C
+    flat = jnp.where(spare, 0, flat).astype(jnp.int32)
+    place = jnp.clip(jnp.cumsum(real.astype(jnp.int32)) - 1, 0, width - 1)
+    tok = jnp.where(spare, 0, tokens.reshape(-1)[flat])
+    pos = jnp.where(spare, cache_len, positions.reshape(-1)[flat])
+    phys = page_table[flat // C, pos // page_size]
+    return ((tok[None], pos[None], phys[None], (pos % page_size)[None]),
+            (place.reshape(R, C), flat))
+
+
+def _spread_queries(q, pack):
+    """Packed queries (1, T, H, dk) to the (R, C, H, dk) block the
+    attention of a paged step takes; ``pack`` None: ``q`` is that
+    block already."""
+    if pack is None:
+        return q
+    return jnp.take(q[0], pack[0], axis=0, mode="clip")
+
+
+def _gather_attended(attn, pack):
+    """The attention result (R, C, ...) as (B, S, H*dk) on the token
+    axis of the block's residual stream: (R, C) itself, or with
+    ``pack`` the packed axis (1, T)."""
+    R, C = attn.shape[:2]
+    if pack is None:
+        return attn.reshape(R, C, -1)
+    return jnp.take(attn.reshape(R * C, -1), pack[1], axis=0, mode="clip")[None]
+
+
 def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                       phys, off, page_table, kernels: str = "xla",
                       k_scale=None, v_scale=None, qmax=None,
                       *, fused_rope: bool = False, logical=None,
-                      cp_mesh=None, layer=None, q_len=None):
+                      cp_mesh=None, layer=None, q_len=None, pack=None):
     """Paged twin of :func:`serve_block`: scatter new K/V at the
     table-resolved (page, offset); attend over the virtual cache read
     through the table (``jnp.take`` gather, or the fused ragged paged
@@ -1052,16 +1105,22 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     ``q_len`` (R,): the real queries of each row
     (serve/kernels.real_query_lengths), for the plain Pallas kernel,
     which then computes for those alone; the other paths take every
-    column."""
+    column.
+
+    ``pack`` (:func:`_pack_tokens`; the unfused paths without a ring):
+    ``x``, ``rope``, ``phys`` and ``off`` are on the packed token axis
+    (1, T) and so is everything here that is not attention; the queries
+    are spread to (R, C) for the attention call alone — its mask, bias,
+    ``q_len`` and result shape are the padded step's — and its result
+    is gathered back."""
     from ..serve import kernels as _pk
 
-    R, C, D = x.shape
     if cp_mesh is None and not (kernels == "pallas" and bias is None):
         # the unfused XLA path — the CPU-parity reference every fusion
         # anchors on
         return _block_paged_xla(
             cfg, p, x, rope, bias, mask, k_pool, v_pool, phys, off,
-            page_table, k_scale, v_scale, qmax, layer=layer,
+            page_table, k_scale, v_scale, qmax, layer=layer, pack=pack,
         )
     if cp_mesh is not None and layer is not None:
         # the ring's pool rows are sharded over ``seq``, which the rows
@@ -1131,9 +1190,10 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale,
                                                v_scale, layer)
             attn = _pk.ragged_paged_attention(
-                q, k_rows, v_rows, page_table, mask, q_len=q_len, **kw
+                _spread_queries(q, pack), k_rows, v_rows, page_table, mask,
+                q_len=q_len, **kw
             )
-    attn = attn.reshape(R, C, -1)
+    attn = _gather_attended(attn, pack)
     attn = _mm(attn, p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
@@ -1150,13 +1210,14 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
 
 def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
                      k_pool, v_pool, phys, off, page_table,
-                     k_scale=None, v_scale=None, qmax=None, layer=None):
+                     k_scale=None, v_scale=None, qmax=None, layer=None,
+                     pack=None):
     """One block of the UNFUSED XLA paged step: the body of
     :func:`serve_block_paged`'s XLA path. ``layer``: the pools are the
-    stacked ones (see :func:`serve_block_paged`)."""
+    stacked ones, ``pack``: the token axis is packed (see
+    :func:`serve_block_paged`)."""
     from ..serve import kernels as _pk
 
-    R, C, D = x.shape
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
     q, k, v = _project_qkv(cfg, p, h)
     if rope is not None:
@@ -1176,8 +1237,9 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
     else:
         k_virt = _pk.gather_pages(k_l, page_table)
         v_virt = _pk.gather_pages(v_l, page_table)
-    attn = _serve_attend(cfg, q, k_virt, v_virt, bias, mask)
-    attn = _mm(attn, p["wo"])
+    attn = _serve_attend(cfg, _spread_queries(q, pack), k_virt, v_virt, bias,
+                         mask)
+    attn = _mm(_gather_attended(attn, pack), p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
     if cfg.parallel_block:
@@ -1241,6 +1303,7 @@ def serve_step_paged(
     num_layers: Optional[int] = None,
     mesh=None,
     cp_mesh=None,
+    pack: Optional[int] = None,
 ):
     """Paged twin of :func:`serve_step` — same contract plus the page
     table (see models/llama.py serve_step_paged; ``kv_quant`` selects
@@ -1248,11 +1311,30 @@ def serve_step_paged(
     step's in-kernel RoPE + KV-write prologue on the Pallas path,
     ``num_layers`` the layer-sliced early-exit draft step, ``cp_mesh``
     the ring context-parallel attention over a sequence-sharded pool —
-    ALiBi-bias families reject it, see serve_block_paged)."""
+    ALiBi-bias families reject it, see serve_block_paged).
+
+    ``pack`` (a static width T that holds the step's real tokens; the
+    engine picks it from the positions, serve/engine.run_mixed): embed,
+    norms, projections, FFN, residual stream, K/V line writes and the
+    final norm run over a packed (1, T) token axis, attention alone at
+    (R, C) (:func:`_pack_tokens`, :func:`serve_block_paged`), and each
+    row's logits are taken at the packed place of its ``logits_idx``.
+    Every real token goes through the operations of the padded step;
+    positions that held none stop being computed. None: the padded
+    step, operation for operation."""
     if mesh is not None and mesh.shape.get(PIPE_AXIS, 1) > 1:
         raise NotImplementedError(
             "paged KV serving is not composed with pipeline parallelism "
             "yet — use kv_layout='dense' with pipe>1"
+        )
+    if pack is not None and (
+        mask is not None or cache_positions is not None or all_logits
+        or fused_rope or cp_mesh is not None
+    ):
+        raise ValueError(
+            "a packed token axis serves the causal mixed step alone: no "
+            "explicit mask or cache positions, no all_logits, no fused "
+            "RoPE prologue, no ring"
         )
     if cache_positions is None:
         cache_positions = positions
@@ -1264,11 +1346,22 @@ def serve_step_paged(
         from ..serve.kernels import real_query_lengths
 
         q_len = real_query_lengths(positions, cache_len)
-    x = _embed_in(cfg, params, tokens, positions)
-    rope = rope_freqs(cfg, positions) if cfg.positions == "rope" else None
+    pack_idx, token_axis = None, (tokens, positions)
+    if pack is not None:
+        packed, pack_idx = _pack_tokens(
+            tokens, positions, q_len, page_table, cache["k"].shape[2],
+            cache_len, pack,
+        )
+        token_axis, lines = packed[:2], packed[2:]
+    x = _embed_in(cfg, params, *token_axis)
+    rope = rope_freqs(cfg, token_axis[1]) if cfg.positions == "rope" else None
+    # the mask, the bias and the position pool follow from the (R, C)
+    # positions whatever the token axis: attention stays (R, C)
     phys, off, mask, bias, pos_pool = _paged_serve_context(
         cfg, cache, positions, cache_positions, mask, page_table, cache_len
     )
+    if pack is not None:  # the K/V lines written are the packed tokens'
+        phys, off = lines
     logical = cache_positions // cache["k"].shape[2]
 
     n = cfg.num_hidden_layers
@@ -1293,7 +1386,7 @@ def serve_step_paged(
             cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
             page_table, kernels, ks, vs, qmax,
             fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
-            layer=l, q_len=q_len,
+            layer=l, q_len=q_len, pack=pack_idx,
         ), None
 
     (x, k_new, v_new, *scales), _ = lax.scan(
@@ -1303,7 +1396,12 @@ def serve_step_paged(
     if qmax is not None:
         new_cache["k_scale"], new_cache["v_scale"] = scales
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    if not all_logits:
+    if pack_idx is not None:
+        # row r samples from the packed place of its column logits_idx[r]
+        at = jnp.take_along_axis(pack_idx[0], logits_idx[:, None], axis=1)
+        x = jnp.take(x[0], at, axis=0, mode="clip")
+        logits = _lm_logits(cfg, params, x)[:, 0]
+    elif not all_logits:
         x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
         logits = _lm_logits(cfg, params, x)[:, 0]
     else:

@@ -113,6 +113,7 @@ SCHED_COUNTERS = frozenset({
     "ring_steps", "compiles", "retraces",
     "real_rows", "state_resets", "sparse_rows",
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
+    "step_tokens_real", "step_tokens_width",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({
@@ -126,12 +127,15 @@ SCHED_EXCLUDED = {
     # the raw reservoir is host-side sample storage; the scrape surface
     # carries its derived percentiles
     "decode_step_ms_samples": "decode_step_ms_p50",
+    # a by-width dict; the scrape surface carries the two counters it
+    # sums to and their ratio
+    "steps_by_width": "pack_fill",
 }
 #: Derived snapshot() rates exported as gauges alongside the counters.
 SCHED_DERIVED = (
     "mean_occupancy", "mean_budget_fill", "prefix_hit_rate",
     "host_hit_rate", "spec_accept_rate",
-    "decode_step_ms_p50", "decode_step_ms_p99",
+    "decode_step_ms_p50", "decode_step_ms_p99", "pack_fill",
 )
 
 CLUSTER_COUNTERS = frozenset({

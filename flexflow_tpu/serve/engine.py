@@ -663,13 +663,33 @@ class ServingConfig:
         )
 
 
+@functools.lru_cache(maxsize=None)
+def pack_widths(slots: int, chunk: int) -> Tuple[int, ...]:
+    """The widths a (slots, chunk) mixed step's token axis is compiled
+    at, ascending: a quarter, a half and the whole of ``slots * chunk``
+    (the whole is the padded step: what fits there fits today), less
+    any rung narrower than one chunk. From the two extents alone; a
+    step takes the narrowest rung that holds its real tokens
+    (:meth:`InferenceEngine.run_mixed`). Three rungs because each is a
+    program traced and lowered in set-up (0.75-1 s of host time a rung
+    with a warm compile cache, PERF.md section 6), by halves from the
+    top because a closed loop fills about a quarter of a mixed step.
+    One rung is no ladder: ``chunk == 1``, a single slot."""
+    top = slots * chunk
+    if chunk == 1:
+        return (top,)
+    return tuple(w for w in (-(-top // 4), -(-top // 2), top) if w >= chunk)
+
+
 def program_name(key: Any) -> str:
     """The stable name of the program compiled under the step key
     ``key`` (:meth:`InferenceEngine._jit`): what JAX calls its module
     (``jit_<name>``) in lowered HLO and on a profile's ``XLA Modules``
     line. Every per-step program starts ``ff_step_``; ``c<n>`` is the
     chunk — ``ff_step_c1`` the pipelined decode step, ``ff_step_c128``
-    the pipelined mixed step at ``mixed_chunk=128``."""
+    the pipelined mixed step at ``mixed_chunk=128``, ``ff_step_c128_t512``
+    that step with its token axis packed at 512 places
+    (:func:`pack_widths`)."""
     if isinstance(key, str):  # commit, copy_page, reorder, ...
         return f"ff_{key}"
 
@@ -680,6 +700,10 @@ def program_name(key: Any) -> str:
         return f"_{mode}{cap or ''}" if mode else ""
 
     kind, *rest = key
+    if kind == "mixed_packed":  # returns its logits to every caller
+        chunk, width, *mode_cap = rest
+        return (f"ff_step_c{chunk}_t{width}"
+                + (head(*mode_cap) if mode_cap else ""))
     if kind == "mixed_fused":
         chunk, with_logits, *mode_cap = rest
         return (f"ff_step_c{chunk}" + flags(logits=with_logits)
@@ -727,8 +751,12 @@ class InferenceEngine:
         self.mesh = mesh or MachineSpec().make_mesh(jax.devices()[:1])
         self.params = params
         # Key: (chunk, all_logits, with_mask) for plain steps, or a
-        # tagged tuple for fused variants (("mixed_fused", chunk, ...)).
+        # tagged tuple for fused variants (("mixed_fused", chunk, ...);
+        # ("mixed_packed", chunk, width, ...) a rung of its ladder).
         self._steps: Dict[Any, Callable] = {}
+        # serving ladders (chunk, sampling head) whose every rung is
+        # compiled
+        self._ladders_compiled: set = set()
         self._commit: Optional[Callable] = None
         # Hazard sanitizers (flexflow_tpu/analysis — see
         # ServingConfig.sanitizers): every step program is created
@@ -1108,17 +1136,20 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     def _serve_step_fn(self, all_logits: bool,
-                       num_layers: Optional[int] = None) -> Callable:
+                       num_layers: Optional[int] = None,
+                       pack: Optional[int] = None) -> Callable:
         """model.serve_step (or serve_step_paged) bound to this engine's
         static kwargs. The paged variant takes the page table as a
         trailing positional and needs cache_len for its scratch-line
         mask cutoff. ``num_layers`` binds the LAYER-SLICED early-exit
         draft step (SpecConfig.draft="early_exit"): the model runs only
         its first ``num_layers`` blocks and leaves the deeper cache
-        rows untouched."""
+        rows untouched. ``pack`` binds a rung of :meth:`pack_ladder`."""
         kw = dict(cfg=self.cfg, all_logits=all_logits)
         if num_layers is not None:
             kw["num_layers"] = int(num_layers)
+        if pack is not None:
+            kw["pack"] = int(pack)
         if self.serving.kernels != "xla":
             kw["kernels"] = self.serving.kernels
         if self.pipelined:
@@ -1166,9 +1197,33 @@ class InferenceEngine:
             self._steps[key] = self._jit(step, key=key, donate_argnums=(1,))
         return self._steps[key]
 
+    def pack_ladder(self, chunk: int) -> Tuple[int, ...]:
+        """The packed rungs of this engine's mixed step at ``chunk``,
+        ascending (:func:`pack_widths` less its widest, which is the
+        padded program). Empty where the step takes no packed token
+        axis: the decode step, a family that declares no
+        ``PACKED_STEP``, the dense layout, the ring, the fused RoPE
+        prologue (it commits K/V inside the kernel, at (R, C))."""
+        if (
+            not self.paged or self.cp_ring
+            or not getattr(self.model, "PACKED_STEP", False)
+            or "rope_kv_write" in self.serving.fused_decode
+        ):
+            return ()
+        return pack_widths(self.num_slots, chunk)[:-1]
+
+    def pack_width(self, real: int, chunk: int) -> int:
+        """The width a mixed step at ``chunk`` that holds ``real`` real
+        tokens runs at: the narrowest rung of :meth:`pack_ladder` that
+        holds them, or ``num_slots * chunk``, the padded program, where
+        none does. From its arguments alone: :meth:`run_mixed` picks
+        its program by it, and the scheduler counts its steps by it."""
+        return next((w for w in self.pack_ladder(chunk) if w >= real),
+                    self.num_slots * chunk)
+
     def _get_mixed_step(self, chunk: int, with_logits: bool = False,
                         sample_mode: Optional[str] = None,
-                        topk_cap: int = 0):
+                        topk_cap: int = 0, pack: Optional[int] = None):
         """Fused MIXED step — the continuous-batching workhorse: token
         select (device feedback vs host) for column 0 → serve_step over
         (R, chunk) ragged rows (decode rows use one column, prefill rows
@@ -1189,14 +1244,23 @@ class InferenceEngine:
         the full-sort reference head — greedy-only decode batches skip
         the (R, V) sorts entirely. None keeps the pre-fusion program
         AND its pre-fusion step key; a set mode tags the key, so each
-        head the workload actually needs compiles exactly once."""
+        head the workload actually needs compiles exactly once.
+
+        ``pack`` (a rung of :meth:`pack_ladder`): the same step with
+        the model's token axis packed at that width, under a key and a
+        name of its own (``ff_step_c<chunk>_t<pack>``). A rung returns
+        its logits to every caller (they exist on the device anyway,
+        for the sampling head), so a caller that wants them runs the
+        very program the server runs, and a ladder is compiled once."""
         key_id = ("mixed_fused", chunk, with_logits)
+        if pack is not None:
+            key_id, with_logits = ("mixed_packed", chunk, pack), True
         if sample_mode is not None:
             key_id = key_id + (sample_mode, topk_cap)
         if key_id not in self._steps:
             from .sampling import sample_tokens
 
-            fn = self._serve_step_fn(all_logits=False)
+            fn = self._serve_step_fn(all_logits=False, pack=pack)
             paged = self.paged
             mode = sample_mode or "full"
 
@@ -1232,11 +1296,24 @@ class InferenceEngine:
         """Dispatch one fused mixed step over (R, C) host data; returns
         the sampled tokens as a DEVICE array (R,) — the caller fetches
         them up to ``dispatch_ahead`` steps later. ``with_logits``
-        additionally returns the (R, V) logits (device array)."""
+        additionally returns the (R, V) logits (device array).
+
+        The step runs at :meth:`pack_width` of the real tokens the
+        ``positions`` show. Every serving rung of a ladder is lowered
+        and compiled when its first is asked for, so a later step that
+        lands on another rung compiles nothing."""
         kw = {}
         if self.paged:
             kw["page_table"] = self.page_table_device()
         host_tokens = np.asarray(host_tokens)
+        chunk = host_tokens.shape[1]
+        pack, ladder = None, self.pack_ladder(chunk)
+        if ladder:
+            from .kernels import real_query_lengths  # Pallas: not at import
+
+            width = self.pack_width(int(real_query_lengths(
+                np.asarray(positions), self.scratch_pos).sum()), chunk)
+            pack = width if width <= ladder[-1] else None
         mode, cap = None, 0
         if "sampling" in self.serving.fused_decode:
             from .sampling import choose_sample_mode
@@ -1251,9 +1328,8 @@ class InferenceEngine:
         donated = self.cache
         self.count_dispatch("mixed")
         with _set_mesh(self.mesh):
-            step = self._get_mixed_step(host_tokens.shape[1], with_logits,
-                                        mode, cap)
-            out = step(
+            step = self._get_mixed_step(chunk, with_logits, mode, cap, pack)
+            args = (
                 self.params,
                 self.cache,
                 self._carry(last_tokens),
@@ -1266,19 +1342,24 @@ class InferenceEngine:
                 jnp.asarray(temperature, dtype=jnp.float32),
                 jnp.asarray(topp, dtype=jnp.float32),
                 jnp.asarray(topk, dtype=jnp.int32),
-                **kw,
             )
-        if with_logits:
-            toks, logits, self.cache = out
-            self._poison_donated(
-                donated, ("mixed_fused", host_tokens.shape[1], with_logits)
-            )
-            return toks, logits
-        toks, self.cache = out
+            if (ladder and not with_logits
+                    and (chunk, mode, cap) not in self._ladders_compiled):
+                # the other rungs take these same arguments: lowering
+                # and compiling them here fills the caches the jitted
+                # call reads, so their first dispatch compiles nothing
+                self._ladders_compiled.add((chunk, mode, cap))
+                for width in ladder + (None,):
+                    if width != pack:
+                        self._get_mixed_step(
+                            chunk, False, mode, cap, width
+                        ).lower(*args, **kw).compile()
+            out = step(*args, **kw)
+        toks, *logits, self.cache = out
         self._poison_donated(
-            donated, ("mixed_fused", host_tokens.shape[1], with_logits)
-        )
-        return toks
+            donated, ("mixed_packed", chunk, pack) if pack
+            else ("mixed_fused", chunk, with_logits))
+        return (toks, *logits) if with_logits else toks
 
     def run_decode(self, last_tokens, host_tokens, use_last, positions,
                    key, greedy, temperature, topp, topk=None):

@@ -96,6 +96,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -380,9 +381,12 @@ def real_query_lengths(positions: jnp.ndarray, cache_len: int) -> jnp.ndarray:
     this is their count; were one ever to leave a gap, the columns up
     to its last real one count, and no real query is dropped. The
     ``q_len`` operand of :func:`ragged_paged_attention`, derived here
-    and nowhere else."""
-    cols = jnp.arange(1, positions.shape[1] + 1, dtype=jnp.int32)
-    return jnp.max(jnp.where(positions < cache_len, cols, 0), axis=1)
+    and nowhere else — on the device inside a step, and by the engine
+    on the host's own (numpy) positions, whose sum is the step's real
+    tokens (serve/engine.run_mixed)."""
+    xp = np if isinstance(positions, np.ndarray) else jnp
+    cols = xp.arange(1, positions.shape[1] + 1, dtype=xp.int32)
+    return xp.max(xp.where(positions < cache_len, cols, 0), axis=1)
 
 
 def narrow_query_extent(C: int) -> int:

@@ -1149,6 +1149,8 @@ class RequestManager:
             self.stats.note_rows(bc.positions[:, 0], bc.qlens,
                                  eng.cfg.dense_len)
         self._note_attn_steps(bc.positions[:, 0], bc.qlens, C)
+        real = int(bc.qlens.sum())
+        self.stats.note_step_tokens(real, eng.pack_width(real, C))
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,

@@ -198,6 +198,28 @@ def _assert_pool_in_place(compiled, text, pool):
     assert temp < pool.size * pool.dtype.itemsize
 
 
+def _assert_pool_carried(text, pool):
+    """The same, read off the program itself and not off its
+    temporaries (a sparse model's expert einsums hold more than a pool):
+    both stacked pools are parameters the program aliases to its
+    outputs, the layer loop's ``while`` carries them whole, nothing
+    yields ONE layer of a pool (a per-layer slice or write-back), and a
+    whole pool comes only from the line write's scatter, in place."""
+    whole = rf"\w+\[{','.join(map(str, pool.shape))}\]"
+    layer = rf"\w+\[(1,)?{','.join(map(str, pool.shape[1:]))}\]"
+    params = {int(n) for n in re.findall(
+        rf"= {whole}\S* parameter\((\d+)\), sharding", text)}
+    alias, = re.findall(r"input_output_alias={(.*?) }, entry", text)
+    aliased = {int(n) for n in re.findall(r"\((\d+), {}, \S+?\)", alias)}
+    assert len(params) == 2 and params <= aliased
+    loop, = re.findall(r"= \((.*?)\) while\(", text)
+    assert len(re.findall(whole, loop)) == 2
+    assert not re.findall(rf"= {layer}\S* [\w-]+\(", text)
+    makers = set(re.findall(rf"= {whole}\S* ([\w-]+)\(", text))
+    assert makers <= {"parameter", "get-tuple-element", "fusion", "scatter",
+                      "bitcast"}, makers
+
+
 @pytest.mark.parametrize("C", [1, 128])
 def test_mistral_paged_pallas_step_compiles(chip, C):
     """The step program chip_smoke.py runs: published widths, 2 layers,
@@ -215,6 +237,7 @@ def test_mistral_paged_pallas_step_compiles(chip, C):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
     _assert_pool_in_place(compiled, text, args[1]["k"])
+    _assert_pool_carried(text, args[1]["k"])
 
 
 @pytest.mark.parametrize("C", [1, 128])
@@ -231,6 +254,90 @@ def test_mistral_paged_step_arms_keep_pool_in_place(chip, C, arm):
         _step(cfg, kernels="pallas", **arm), *args, donate=(1,)
     )
     _assert_pool_in_place(compiled, text, args[1]["k"])
+
+
+def _need(compiled):
+    """Bytes the program holds on the device, as benchmarks/tools/fit.py
+    counts them."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("family", ["mistral", "mixtral"])
+def test_packed_rungs_compile_with_the_pool_in_place(chip, family):
+    """Every rung of the (16, 128) ladder (ISSUE 32) at published
+    widths, two layers: the kernel's call keeps its (slots, chunk) shape
+    and its name (the benchmark finds the step program by them), the
+    pool stays the loop's carry updated in place, the matmuls run at the
+    rung's width, and no rung needs more of the device than the padded
+    step, which is what ``benchmarks/tools/fit.py`` sizes a depth by."""
+    from flexflow_tpu.models import mixtral
+    from flexflow_tpu.serve.engine import pack_widths
+
+    mod = mistral if family == "mistral" else mixtral
+    cfg = (mistral.mistral_7b if family == "mistral" else
+           mixtral.mixtral_8x7b)(dtype=jnp.bfloat16, num_hidden_layers=2)
+    args = _step_args(chip, cfg, 128)
+    if family == "mixtral":
+        args = (_on(jax.eval_shape(functools.partial(
+            mixtral.init_params, cfg=cfg), jax.random.PRNGKey(0)), chip),
+        ) + args[1:]
+    *rungs, top = pack_widths(R, 128)
+    assert (rungs, top) == ([512, 1024], 2048)
+
+    def step(pack):
+        def fn(params, cache, tokens, positions, logits_idx, page_table):
+            return mod.serve_step_paged(
+                params, cache, tokens, positions, logits_idx, None, None,
+                page_table, cfg=cfg, cache_len=CACHE_LEN, kernels="pallas",
+                pack=pack)
+        return fn
+
+    padded, _ = _compile(step(None), *args, donate=(1,))
+    for width in rungs:
+        compiled, text = _compile(step(width), *args, donate=(1,))
+        assert text.count("tpu_custom_call") == 1
+        kernel, = re.findall(r"%ff_ragged_paged_c128\S* = (\S+) custom-call",
+                             text)
+        assert kernel.startswith(f"bf16[{R},128,")   # reduce.kernel_chunk
+        _assert_pool_carried(text, args[1]["k"])
+        if family == "mistral":  # the expert einsums hold more than a pool
+            _assert_pool_in_place(compiled, text, args[1]["k"])
+        # the FFN runs over the rung, not over slots x chunk
+        assert re.search(rf"bf16\[(1,)?{width},(8,)?14336\]", text)
+        assert not re.search(r"bf16\[(16,128|2048),(8,)?14336\]", text)
+        assert _need(compiled) <= _need(padded)
+
+
+@pytest.mark.parametrize("family, layers, gigabytes", [
+    ("mistral", 20, 10.66), ("mixtral", 4, 12.90)])
+def test_widest_rung_needs_what_the_padded_step_did(chip, family, layers,
+                                                    gigabytes):
+    """The widest rung is the padded step itself: at the benchmark's
+    depths and pool (128 pages, 17 a slot) it needs what
+    ``benchmarks/tools/fit.py`` counted before the ladder (PERF.md
+    section 4), so a depth that fitted still fits."""
+    from flexflow_tpu.models import mixtral
+
+    mod = mistral if family == "mistral" else mixtral
+    cfg = (mistral.mistral_7b if family == "mistral" else
+           mixtral.mixtral_8x7b)(dtype=jnp.bfloat16, num_hidden_layers=layers)
+    params = _on(jax.eval_shape(functools.partial(
+        mod.init_params, cfg=cfg), jax.random.PRNGKey(0)), chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        mod.init_paged_kv_cache, cfg, 128, PAGE, jnp.bfloat16)), chip)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return mod.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=CACHE_LEN, kernels="pallas")
+
+    compiled, _ = _compile(
+        step, params, cache, chip((R, 128), jnp.int32),
+        chip((R, 128), jnp.int32), chip((R,), jnp.int32),
+        chip((R, 17), jnp.int32), donate=(1,))
+    assert _need(compiled) / 1e9 == pytest.approx(gigabytes, abs=0.02)
 
 
 # --- kernels repaired in PR 23 (refused by the chip's compiler before) ---
