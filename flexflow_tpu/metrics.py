@@ -10,7 +10,7 @@ and aggregated on host with :class:`PerfMetrics`.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -184,6 +184,16 @@ class SchedulerStats:
     # place, so a copy of the stats keeps the counts of its own moment.
     step_tokens_real: int = 0
     step_tokens_width: int = 0
+    # The routed expert layers of a sparse family that returns its
+    # tokens per expert with each pipelined step (``step_counts``;
+    # note_expert_counts, at the flush that fetches them), summed over
+    # steps and sparse layers: (token, expert) pairs of real tokens
+    # computed, experts that were given a token, experts held (a
+    # layer's count a layer and step), and the fullest expert's tokens.
+    moe_pairs: int = 0
+    moe_experts_hit: int = 0
+    moe_experts_held: int = 0
+    moe_load_max: int = 0
     steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
 
     def record_step(
@@ -210,14 +220,25 @@ class SchedulerStats:
         if num_slots > 0:
             self.occupancy_sum += active_slots / num_slots
 
-    def note_rows(self, first, count, dense_len: int) -> None:
+    def note_rows(self, first, count, dense_len: Optional[int]) -> None:
         """Count one step's rows for a family with per-slot state:
         ``first`` (R,) each row's first position, ``count`` (R,) its
-        real positions (0: the row is padding)."""
+        real positions (0: the row is padding); ``dense_len`` None: the
+        family has no block choice."""
         rows = count > 0
         self.real_rows += int(rows.sum())
         self.state_resets += int((rows & (first == 0)).sum())
-        self.sparse_rows += int((rows & (first + count > dense_len)).sum())
+        if dense_len is not None:
+            self.sparse_rows += int((rows & (first + count > dense_len)).sum())
+
+    def note_expert_counts(self, counts) -> None:
+        """Count one step's routed expert layers: ``counts`` (sparse
+        layers, experts held) the real tokens each expert was given."""
+        counts = np.asarray(counts)
+        self.moe_pairs += int(counts.sum())
+        self.moe_experts_hit += int((counts > 0).sum())
+        self.moe_experts_held += int(counts.size)
+        self.moe_load_max += int(counts.max(axis=-1).sum())
 
     def note_attn_steps(self, first, count, page_size: int, num_pages: int,
                         narrow: int, window: int = 0) -> None:

@@ -449,8 +449,13 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
     ``expert`` mesh axis so each device computes only its expert range
     and GSPMD inserts the combine reduction (the serving-time analog of
     ops/moe.py's ExpertsOp range sharding). For E=8,K=2 this spends E/K
-    = 4x the FLOPs of perfect dispatch at prefill — acceptable until
-    a capacity-dispatch Pallas path is warranted."""
+    = 4x the FLOPs of perfect dispatch at prefill, and reads every
+    expert's weights every step. The grouped dispatch is
+    :func:`routed_experts_ffn` (tokens sorted by expert, a ragged matmul
+    over the routed pairs of real tokens), which ``models/lfm2_moe.py``
+    takes; ``mixtral`` and ``qwen2_moe`` (and the twin's MoE) still take
+    this all-expert einsum: moving them is ROADMAP A4, under the Mixtral
+    cell."""
     E, K = cfg.num_local_experts, cfg.num_experts_per_tok
     router = jnp.matmul(
         h.astype(jnp.float32), _dense_w(p["w_router"], jnp.float32),
@@ -504,6 +509,120 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
         ).astype(h.dtype)  # (B,S,1)
         out = out + s_gate * s_out
     return out
+
+
+def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
+                       norm_topk: bool = True, scaling: float = 1.0):
+    """A sigmoid router with a selection offset (HF ``Lfm2MoeSparseMoeBlock``,
+    DeepSeek-V3's rule): scores ``s = sigmoid(h W_r)`` in float32; the
+    ``k`` largest of ``s + select_offset`` are CHOSEN (the offset
+    chooses, it does not weigh; among equals the lower index first);
+    the weights are the chosen experts' own ``s``, with ``norm_topk``
+    divided by their sum plus 1e-6, times ``scaling``. h (T, D) ->
+    (experts (T, k) int32, weights (T, k) float32)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), _dense_w(w_router, jnp.float32),
+        preferred_element_type=jnp.float32))
+    choose = s if select_offset is None else s + select_offset.astype(jnp.float32)
+    _, experts = lax.top_k(choose, k)
+    weights = jnp.take_along_axis(s, experts, axis=-1)
+    if norm_topk:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+    return experts.astype(jnp.int32), weights * scaling
+
+
+def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
+                       experts_held: Tuple[int, int], layer=None,
+                       kernels: str = "xla"):
+    """The routed half of a sparse FFN as a GROUPED matmul: the (token,
+    expert) pairs of real tokens sorted by expert, one grouped matmul a
+    projection over the groups, the pairs' results weighted and summed
+    back by token. Its FLOPs follow the rows, not rows x experts, and it
+    reads the weights of the experts that have rows. Beside
+    :func:`_moe_ffn`, which computes every expert for every position.
+
+    ``kernels="xla"``: ``lax.ragged_dot`` over the sorted rows (on the
+    chip the compiler's own grouped-matmul kernel). ``"pallas"``:
+    serve/kernels ``grouped_glu`` / ``grouped_down``
+    (``ff_moe_grouped_*``), for which every expert's rows start at a
+    multiple of the row tile, so a tile has one expert, named in the
+    weight blocks' index map; tiles past the last row are skipped.
+
+    h (T, D); ``real`` (T,) bool: padding places route nowhere;
+    ``experts`` / ``weights`` (T, k) the router's choice
+    (:func:`route_sigmoid_topk`, over ALL its outputs). ``experts_held``
+    (lo, hi): the range of the router's outputs whose weights are here
+    (``w_gate`` / ``w_up`` (hi - lo, D, F), ``w_down`` (hi - lo, F, D));
+    the result is that range's part of the layer's, so the parts of
+    ranges that cover the router add up to the whole layer (the
+    model-configs guide's usual cut: a chip holds some experts of each
+    layer and computes its own part). ``layer``: the weights are every
+    layer's, stacked (L, hi - lo, ...), and this call addresses its own
+    experts inside the free (L * (hi - lo), ...) view (the Pallas path
+    by an offset on the tiles' expert index, the XLA path by giving
+    every other layer's experts an empty group): a layer sliced out of
+    the stack would be a copy of its weights a step, since no slice
+    fuses into a kernel call.
+
+    Returns (out (T, D) in h's dtype, counts (hi - lo,) int32: the real
+    tokens each held expert was given)."""
+    T, k = experts.shape
+    P = T * k
+    lo, hi = experts_held
+    n = hi - lo
+    held = real[:, None] & (experts >= lo) & (experts < hi)
+    group = jnp.where(held, experts - lo, n).reshape(-1)   # n: no group, sorts last
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
+    first = 0
+    if layer is not None:
+        first = layer * n
+        w_gate, w_up, w_down = (
+            w.reshape((-1,) + w.shape[2:]) for w in (w_gate, w_up, w_down))
+    w_gate, w_up, w_down = (_dense_w(w, h.dtype) for w in (w_gate, w_up, w_down))
+    if kernels == "pallas":
+        from ..serve import kernels as _pk
+
+        tm = _pk.grouped_tile(P)
+        tiles = -(-(P + n * (tm - 1)) // tm)
+        aligned = -(-counts // tm) * tm
+        ends = jnp.cumsum(aligned)
+        by_group = group[order]                               # sorted; n at the tail
+        g = jnp.minimum(by_group, n - 1)
+        rank = jnp.arange(P, dtype=jnp.int32) - (jnp.cumsum(counts) - counts)[g]
+        at = jnp.where(by_group < n, (ends - aligned)[g] + rank, tiles * tm)
+        source = jnp.full((tiles * tm,), T, jnp.int32).at[at].set(
+            (order // k).astype(jnp.int32), mode="drop")
+        rows = jnp.take(h, source, axis=0, mode="fill", fill_value=0)
+        n_active = ends[-1] // tm
+        tile = jnp.arange(tiles, dtype=jnp.int32)
+        tile_group = jnp.searchsorted(ends, tile * tm, side="right")
+        last = tile_group[jnp.maximum(n_active - 1, 0)]
+        tile_group = first + jnp.minimum(
+            jnp.where(tile < n_active, tile_group, last), n - 1)
+        act = _pk.grouped_glu(rows, w_gate, w_up, tile_group, n_active, tm=tm)
+        out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm)
+        place = jnp.zeros((P,), jnp.int32).at[order].set(at.astype(jnp.int32))
+        out = jnp.take(out, place, axis=0, mode="clip")
+    else:
+        sizes = counts
+        if layer is not None:
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0],), jnp.int32), counts, (first,))
+        rows = jnp.take(h, order // k, axis=0)               # (P, D), by expert
+        dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                                preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(h.dtype)
+        out = dot(act, w_down)                               # (P, D) float32
+        place = jnp.zeros((P,), jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32))
+        out = jnp.take(out, place, axis=0)
+    # a pair in no group has no result: say so here and not by what a
+    # grouped matmul leaves in rows it never wrote
+    out = out.reshape(T, k, -1)
+    out = jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
+                     jnp.where(held[..., None], out, 0.0))
+    return out.astype(h.dtype), counts
 
 
 def _ffn(cfg: DecoderConfig, p, h):
@@ -583,8 +702,15 @@ def _embed_in(cfg: DecoderConfig, params, tokens, positions):
 
 
 def _lm_logits(cfg: DecoderConfig, params, x):
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    logits = jnp.matmul(x, head, preferred_element_type=jnp.float32)
+    if cfg.tie_word_embeddings:
+        # x embed^T as a contraction over the embedding's own minor
+        # axis: a transposed (V, D) table would be written out a step
+        logits = lax.dot_general(
+            x, params["embed"], (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.matmul(x, params["lm_head"],
+                            preferred_element_type=jnp.float32)
     if "lm_head_bias" in params:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
     return logits
@@ -1581,6 +1707,67 @@ def serve_debug_activations(
         )
         acts.append(x)
     return acts
+
+
+# ---------------------------------------------------------------------------
+# Layers of several kinds in one model: runs of kinds
+
+
+def layer_runs(kinds):
+    """A static layer order as RUNS of one kind. ``kinds``: one entry a
+    layer, each a tuple of the names of the parameter groups the layer
+    takes its weights from, in the order its blocks run (("conv",
+    "sparse"): a conv mixer, then a sparse FFN). Every group's weights
+    are stacked over the layers that use the group, in layer order.
+    Returns [(kind, {group: index of the run's first layer within the
+    group's stack}, number of layers)]."""
+    runs, seen = [], {}
+    for kind in kinds:
+        kind = tuple(kind)
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, {g: seen.get(g, 0) for g in kind}, 1])
+        for g in kind:
+            seen[g] = seen.get(g, 0) + 1
+    return [tuple(r) for r in runs]
+
+
+def layer_weights(stack, index, *, whole=()):
+    """Layer ``index`` of a group's stacked weights; the leaves named in
+    ``whole`` stay the stack (what a kernel call addresses by index: a
+    slice of them would be a copy)."""
+    return {name: w if name in whole else _layer_of(w, index)
+            for name, w in stack.items()}
+
+
+def run_layers(kinds, blocks, params, x, carried):
+    """The generic layer loop of a model whose layers are of several
+    kinds (ROADMAP B8): walks :func:`layer_runs` of ``kinds``, each run
+    one ``fori_loop`` over its groups' stacked weights. ``blocks``: group name -> ``fn(stack,
+    index, x, carried) -> (x, carried)``, where ``stack`` is
+    ``params[group]`` and ``index`` the layer's place in it: the block
+    takes its own weights out (:func:`layer_weights`; a read-only slice
+    fuses into the matmul that reads it) and addresses its own part of
+    ``carried`` by the same index. ``carried`` is
+    whatever the blocks keep IN PLACE from layer to layer (a K/V pool
+    for attention layers, a per-slot state for recurrent or convolution
+    layers, counters): a pytree that is the loop's carry through every
+    run, never scanned in and out, so a block updates its own layer
+    inside the whole stack and nothing is copied
+    (tests/test_chip_compile.py)."""
+    for kind, first, n in layer_runs(kinds):
+        def body(i, carry, kind=kind, first=first):
+            x, carried = carry
+            for g in kind:
+                x, carried = blocks[g](params[g], first[g] + i, x, carried)
+            return x, carried
+
+        # a run of one layer is a loop too: with a traced index a block
+        # addresses its layer inside the carried stacks in place, with
+        # a static one the compiler copies the stacks it writes to
+        x, carried = lax.fori_loop(0, n, body, (x, carried))
+    return x, carried
 
 
 def num_params(cfg: DecoderConfig) -> int:

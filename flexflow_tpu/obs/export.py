@@ -114,6 +114,7 @@ SCHED_COUNTERS = frozenset({
     "real_rows", "state_resets", "sparse_rows",
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
     "step_tokens_real", "step_tokens_width",
+    "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -853,6 +854,15 @@ class InferenceEngine:
         # count_dispatch. The fused-epilogue claim ("strictly fewer
         # programs per step") is measured against this counter.
         self.dispatch_count = 0
+        # A family whose step returns counters of its own beside its
+        # cache (``step_counts(cfg)``: name -> shape of int32 entries
+        # of the cache a step returns that are no state, a sparse
+        # model's tokens per expert) has them packed behind the sampled
+        # tokens in ONE int32 array, ``step_fetch`` (the newest mixed
+        # step's, a device array): the scheduler's flush fetches that
+        # one array where it would fetch the tokens (split_fetch)
+        self._step_counts = getattr(model, "step_counts", lambda cfg: {})(cfg)
+        self.step_fetch = None
         # Observability (flexflow_tpu/obs): count_dispatch doubles as
         # the tracing chokepoint — with a tracer attached (shared with
         # the owning scheduler's lane by obs.attach_observability),
@@ -1137,14 +1147,23 @@ class InferenceEngine:
 
     def _serve_step_fn(self, all_logits: bool,
                        num_layers: Optional[int] = None,
-                       pack: Optional[int] = None) -> Callable:
+                       pack: Optional[int] = None,
+                       counts: bool = False) -> Callable:
         """model.serve_step (or serve_step_paged) bound to this engine's
         static kwargs. The paged variant takes the page table as a
         trailing positional and needs cache_len for its scratch-line
         mask cutoff. ``num_layers`` binds the LAYER-SLICED early-exit
         draft step (SpecConfig.draft="early_exit"): the model runs only
         its first ``num_layers`` blocks and leaves the deeper cache
-        rows untouched. ``pack`` binds a rung of :meth:`pack_ladder`."""
+        rows untouched. ``pack`` binds a rung of :meth:`pack_ladder`.
+
+        A family that declares ``step_counts`` returns, in the cache of
+        its step, entries that are this step's own counters and no
+        state: they are taken out of the cache here, so the cache a
+        step returns is the cache it was given, and returned third
+        where ``counts`` asks for them (a family that declares none
+        returns two values either way)."""
+        names = tuple(self._step_counts)
         kw = dict(cfg=self.cfg, all_logits=all_logits)
         if num_layers is not None:
             kw["num_layers"] = int(num_layers)
@@ -1165,7 +1184,17 @@ class InferenceEngine:
                 # ring ragged paged program (partial shard_map over the
                 # seq axis; serve/kernels.ring_ragged_paged_attention)
                 kw["cp_mesh"] = self.mesh
-            return functools.partial(self.model.serve_step_paged, **kw)
+            fn = functools.partial(self.model.serve_step_paged, **kw)
+            if not names:
+                return fn
+
+            def step(*args):
+                logits, cache = fn(*args)
+                cache = dict(cache)
+                taken = {name: cache.pop(name) for name in names}
+                return (logits, cache, taken) if counts else (logits, cache)
+
+            return step
         return functools.partial(self.model.serve_step, **kw)
 
     def count_dispatch(self, kind: str = "step") -> None:
@@ -1260,7 +1289,7 @@ class InferenceEngine:
         if key_id not in self._steps:
             from .sampling import sample_tokens
 
-            fn = self._serve_step_fn(all_logits=False, pack=pack)
+            fn = self._serve_step_fn(all_logits=False, pack=pack, counts=True)
             paged = self.paged
             mode = sample_mode or "full"
 
@@ -1275,15 +1304,17 @@ class InferenceEngine:
                         None, None)
                 if paged:
                     args = args + (page_table,)
-                logits, cache = fn(*args)
+                logits, cache, *counts = fn(*args)
                 toks = sample_tokens(
                     logits, key,
                     greedy=greedy, temperature=temperature, topp=topp,
                     topk_arr=topk, mode=mode, topk_cap=topk_cap,
                 )
-                if with_logits:
-                    return toks, logits, cache
-                return toks, cache
+                out = (toks, logits) if with_logits else (toks,)
+                if counts:  # one array to fetch: the tokens, then the counters
+                    out += (jnp.concatenate(
+                        [toks] + [c.reshape(-1) for c in counts[0].values()]),)
+                return (*out, cache)
 
             self._steps[key_id] = self._jit(
                 step, key=key_id, donate_argnums=(1,)
@@ -1355,11 +1386,23 @@ class InferenceEngine:
                             chunk, False, mode, cap, width
                         ).lower(*args, **kw).compile()
             out = step(*args, **kw)
-        toks, *logits, self.cache = out
+        toks, *rest, self.cache = out
+        if self._step_counts:
+            self.step_fetch = rest.pop()
         self._poison_donated(
             donated, ("mixed_packed", chunk, pack) if pack
             else ("mixed_fused", chunk, with_logits))
-        return (toks, *logits) if with_logits else toks
+        return (toks, *rest) if with_logits else toks
+
+    def split_fetch(self, fetched: np.ndarray):
+        """A fetched ``step_fetch`` as (sampled tokens (R,), {name:
+        counters}) by the family's ``step_counts`` shapes."""
+        toks, rest = fetched[:self.num_slots], fetched[self.num_slots:]
+        counts = {}
+        for name, shape in self._step_counts.items():
+            n = math.prod(shape)
+            counts[name], rest = rest[:n].reshape(shape), rest[n:]
+        return toks, counts
 
     def run_decode(self, last_tokens, host_tokens, use_last, positions,
                    key, greedy, temperature, topp, topk=None):

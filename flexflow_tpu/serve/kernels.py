@@ -552,6 +552,7 @@ def _build_ragged_paged_kernel(
     has_rope: bool = True,
     pack: int = 1,
     group_mask: bool = False,
+    merged_heads: int = 0,
 ):
     """ONE builder for every Pallas variant of the ragged paged kernel
     (see the module-docstring matrix): ``quant`` folds the per-page
@@ -572,8 +573,21 @@ def _build_ragged_paged_kernel(
     per-row count of real queries) works for those alone: columns from
     ``q_len[r]`` on keep no page alive and come out zero, and a row of
     at most :func:`narrow_query_extent` real queries runs the same step
-    at that query extent."""
+    at that query extent. ``merged_heads`` (the plain kernel): the page
+    blocks are (ps, KV*dk), a line's heads side by side on the minor
+    axis (a pool of head size under a lane tile, see
+    :func:`_ragged_paged_attention`), and the body takes each head's dk
+    lanes out after the load."""
     narrow = narrow_query_extent(C)
+
+    def _heads_major(block):
+        # a loaded page block as (KV, ps, dk) float32
+        x = _unpack_codes(block, pack)
+        if not merged_heads:
+            return x.transpose(1, 0, 2)
+        dk = x.shape[-1] // merged_heads
+        return jnp.stack([x[:, h * dk:(h + 1) * dk]
+                          for h in range(merged_heads)], axis=0)
 
     def _masked(mask, x, fill):
         # x (C, KV, G, ps). One mask: (C, ps), every head alike. A mask
@@ -715,8 +729,8 @@ def _build_ragged_paged_kernel(
             @pl.when(some)
             def _():
                 q = q_ref[0, :n].astype(jnp.float32)
-                k = _unpack_codes(k_ref[0], pack).transpose(1, 0, 2)
-                v = _unpack_codes(v_ref[0], pack).transpose(1, 0, 2)
+                k = _heads_major(k_ref[0])
+                v = _heads_major(v_ref[0])
                 ks = ks_ref[0] if quant else None
                 vs = vs_ref[0] if quant else None
                 _attend(q, k, v, ks, vs, mask if group_mask else mask[0],
@@ -925,7 +939,9 @@ def ragged_paged_attention(
         in_specs.append(P())
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else mesh.shape.get(MODEL_AXIS, 1)
-    if tp == 1 or k_pool.shape[2] % tp:
+    # (a pool with merged heads, rank 3, is served on one shard: its
+    # family refuses ``model > 1``)
+    if tp == 1 or k_pool.ndim == 3 or k_pool.shape[2] % tp:
         return body(*operands)
     # every mesh axis manual (Mosaic refuses a partial-manual context);
     # the specs name only ``model``, so the others see replicas
@@ -964,6 +980,15 @@ def _ragged_paged_attention(
     ``l*(P+1)`` on — how the serving step's layer loop reads its carried
     pool without slicing a layer out (models/transformer.py).
 
+    MERGED pools (``k_pool`` / ``v_pool`` of rank 3, (P+1, ps, KV*dk)):
+    a line's heads side by side on the minor axis. For a head size under
+    a lane tile (dk = 64) the device's own layout of a (..., ps, KV, dk)
+    array puts ``ps`` on the lanes, and a step would re-lay the whole
+    pool into the kernel's and back out, four pool copies a step; a
+    minor axis of KV*dk (a multiple of 128) is laid out as it is
+    written, and the body takes each head's lanes out of the loaded
+    block (models/lfm2_moe.py; full-precision pools, one mask a row).
+
     ``q_len`` (:func:`real_query_lengths`) is a third: row r's columns
     from ``q_len[r]`` on are padding. A page is then computed only if a
     real query of the row may see a key of it (a padding query sits at
@@ -974,7 +999,15 @@ def _ragged_paged_attention(
     same, to the bit at the chunk's extent. ``None``: every column
     counts, the kernel as it was."""
     R, C, H, dk = q.shape
-    _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
+    merged = k_pool.ndim == 3  # (P+1, ps, KV*dk): heads on the minor axis
+    if merged:
+        if k_scale is not None or group_mask:
+            raise NotImplementedError(
+                "a pool with merged heads is neither quantized nor read "
+                "under a mask a KV group")
+        ps, KV, dkp = k_pool.shape[1], k_pool.shape[2] // dk, dk
+    else:
+        _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
     NP = page_table.shape[1]
     G = H // KV
     pack = dk // dkp if k_scale is not None else 1
@@ -990,17 +1023,18 @@ def _ragged_paged_attention(
     def page(r, p, pt, *base):
         # the paged gather: block row = page_table[r, p] (+ row_offset)
         row = pt[r, p] + base[0][0] if row_offset is not None else pt[r, p]
-        return (row, 0, 0, 0)
+        return (row,) + (0,) * (k_pool.ndim - 1)
 
+    lines = (1, ps, KV * dk) if merged else (1, ps, KV, dkp)
     in_specs = [
         pl.BlockSpec((1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)),
-        pl.BlockSpec((1, ps, KV, dkp), page),
-        pl.BlockSpec((1, ps, KV, dkp), page),
+        pl.BlockSpec(lines, page),
+        pl.BlockSpec(lines, page),
     ]
     operands = [qg, k_pool, v_pool]
     body = _build_ragged_paged_kernel(
         quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack,
-        group_mask=group_mask,
+        group_mask=group_mask, merged_heads=KV if merged else 0,
     )
 
     def kernel(*refs):  # the body knows the table and the query lengths
@@ -1516,3 +1550,95 @@ def fused_rope_paged_attention(
                 ks.reshape(k_scale.shape), vs.reshape(v_scale.shape))
     out, k_pool, v_pool = outs
     return out.reshape(R, C, H, dk), k_pool, v_pool, None, None
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmuls of a routed expert layer (models/transformer.py
+# ``routed_experts_ffn``): rows sorted by expert, every expert's rows
+# starting at a multiple of the row tile, so a tile belongs to ONE expert
+# and its weights are named by a prefetched scalar in the block index map.
+
+
+def grouped_tile(pairs: int) -> int:
+    """Rows a tile of the grouped expert matmuls at ``pairs`` (token,
+    expert) pairs: 16 (a bf16 sublane tile) while a step's pairs are a
+    few an expert (the decode step: the kernel is then a read of the
+    experts' weights and alignment costs rows, not time), 128 from a
+    thousand pairs on (a grid step's overhead would count)."""
+    return 16 if pairs <= 1024 else 128
+
+
+def _grouped_call(kernel, name, tile_group, n_active, operands, in_specs,
+                  out_shape, out_spec, grid):
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_spec),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=48 << 20),
+        name=name,
+        interpret=_interpret(),
+    )(tile_group.astype(jnp.int32), n_active.astype(jnp.int32).reshape(1),
+      *operands)
+
+
+def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
+                tf: int = 512):
+    """``silu(rows W_gate[g]) * (rows W_up[g])`` tile by tile, ``g`` the
+    expert of the tile. rows (P, D), P a multiple of ``tm``;
+    ``w_gate`` / ``w_up`` (G, D, F); ``tile_group`` (P / tm,) the index
+    into G of each tile's expert, ``n_active`` the tiles that hold a
+    row: the others are skipped (their output rows are left as they
+    are; ``tile_group`` repeats the last active tile's expert there, so
+    they fetch no weights). The grid runs the F blocks outermost and the
+    tiles innermost: an expert's (D, tf) block is fetched once a run of
+    its tiles, so the weights read are those of the experts that have
+    rows, once. -> (P, F) in rows' dtype."""
+    P, D = rows.shape
+    F = w_gate.shape[-1]
+    tf = min(tf, F)
+    assert P % tm == 0 and F % tf == 0, (P, tm, F, tf)
+
+    def kernel(tg_ref, na_ref, x_ref, wg_ref, wu_ref, o_ref):
+        @pl.when(pl.program_id(1) < na_ref[0])
+        def _():
+            x = x_ref[...]
+            g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+            o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
+
+    weights = pl.BlockSpec((1, D, tf), lambda j, t, tg, na: (tg[t], 0, j))
+    return _grouped_call(
+        kernel, f"ff_moe_grouped_glu_t{tm}", tile_group, n_active,
+        (rows, w_gate, w_up),
+        [pl.BlockSpec((tm, D), lambda j, t, tg, na: (t, 0)), weights, weights],
+        jax.ShapeDtypeStruct((P, F), rows.dtype),
+        pl.BlockSpec((tm, tf), lambda j, t, tg, na: (t, j)),
+        (F // tf, P // tm))
+
+
+def grouped_down(act, w_down, tile_group, n_active, *, tm: int, td: int = 512):
+    """``act W_down[g]`` tile by tile (see :func:`grouped_glu`): act
+    (P, F), ``w_down`` (G, F, D) -> (P, D) float32."""
+    P, F = act.shape
+    D = w_down.shape[-1]
+    td = min(td, D)
+    assert P % tm == 0 and D % td == 0, (P, tm, D, td)
+
+    def kernel(tg_ref, na_ref, a_ref, w_ref, o_ref):
+        @pl.when(pl.program_id(1) < na_ref[0])
+        def _():
+            o_ref[...] = jnp.dot(a_ref[...], w_ref[0],
+                                 preferred_element_type=jnp.float32)
+
+    return _grouped_call(
+        kernel, f"ff_moe_grouped_down_t{tm}", tile_group, n_active,
+        (act, w_down),
+        [pl.BlockSpec((tm, F), lambda j, t, tg, na: (t, 0)),
+         pl.BlockSpec((1, F, td), lambda j, t, tg, na: (tg[t], 0, j))],
+        jax.ShapeDtypeStruct((P, D), jnp.float32),
+        pl.BlockSpec((tm, td), lambda j, t, tg, na: (t, j)),
+        (D // td, P // tm))

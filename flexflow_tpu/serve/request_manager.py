@@ -1022,7 +1022,7 @@ class RequestManager:
                 last, host_tokens, use_last, positions,
                 np.zeros((R,), np.int32), sub, greedy, temp, topp, topk,
             )
-        self._inflight.append((toks, snapshot))
+        self._inflight.append((toks, snapshot, self.engine.step_fetch))
         self._prev_dispatch_slots = {s for _, s, _, _ in snapshot}
         self._step_counter += 1
         self.stats.record_step(
@@ -1032,7 +1032,7 @@ class RequestManager:
         real = positions[:, 0] != scratch
         if self._slot_state:
             self.stats.note_rows(positions[:, 0], real,
-                                 self.engine.cfg.dense_len)
+                                 getattr(self.engine.cfg, "dense_len", None))
         self._note_attn_steps(positions[:, 0], real, 1)
         tr = self.tracer
         if tr.enabled:
@@ -1137,7 +1137,7 @@ class RequestManager:
                 sub, greedy, temp, topp, topk,
             )
         self._stamp_prefill_dispatched(finals)
-        self._inflight.append((toks, snapshot))
+        self._inflight.append((toks, snapshot, self.engine.step_fetch))
         self._prev_dispatch_slots = sampled_slots
         self._step_counter += 1
         self.stats.record_step(
@@ -1147,7 +1147,7 @@ class RequestManager:
         )
         if self._slot_state:
             self.stats.note_rows(bc.positions[:, 0], bc.qlens,
-                                 eng.cfg.dense_len)
+                                 getattr(eng.cfg, "dense_len", None))
         self._note_attn_steps(bc.positions[:, 0], bc.qlens, C)
         real = int(bc.qlens.sum())
         self.stats.note_step_tokens(real, eng.pack_width(real, C))
@@ -1166,11 +1166,14 @@ class RequestManager:
         still drains its pipeline refs — its slot/pages are released at
         the flush that drains the last reference."""
         with self.tracer.span("step.flush"):
-            toks, snapshot = self._inflight.pop(0)
+            toks, snapshot, fetch = self._inflight.pop(0)
             with self.tracer.span("step.flush_wait"):
                 # ffcheck: disable=FF107 -- the pipeline flush IS the designed sync point: it drains steps the device already finished, dispatch_ahead steps behind
-                toks = np.asarray(jax.device_get(toks))
+                toks = np.asarray(jax.device_get(toks if fetch is None else fetch))
             self.stats.flushes += 1
+            if fetch is not None:  # the step's counters ride behind its tokens
+                toks, counts = self.engine.split_fetch(toks)
+                self.stats.note_expert_counts(counts["moe_counts"])
             tr = self.tracer
             if tr.enabled:
                 tr.event("flush", entries=len(snapshot))

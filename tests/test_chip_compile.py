@@ -472,3 +472,67 @@ def test_minicpm_sala_hybrid_step_compiles_in_place(chip, C):
     pool = cache["k"]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool.size * pool.dtype.itemsize
+
+
+# --- conv layers beside attention layers, routed experts (LFM2-MoE) ---------
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, None), (128, 2048)])
+def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
+    """models/lfm2_moe.py at published widths (head size 64, 64 experts
+    of 1536, the whole vocabulary), five layers (a dense conv layer,
+    then attention, two conv, attention: every kind of run), the
+    benchmark cell's 64 slots of 8 pages, padded and on a packed rung:
+    the ragged paged kernel is in the program by name at head size 64
+    and is its FIRST kernel call (the trace reduction finds the step by
+    it), the grouped expert matmuls (``ff_moe_grouped_*``) follow, there
+    is no all-expert product, and the loop's carry is updated in place: no copy of a K/V
+    pool, of the conv states or of a layer's expert weights, temporaries
+    under one layer's experts (a relayout of the pool or a layer's
+    experts sliced out of their stack would each be more)."""
+    from flexflow_tpu.models import lfm2_moe as fam
+
+    A, V = fam.ATTENTION, fam.CONV
+    cfg = fam.config(num_hidden_layers=5, num_dense_layers=1,
+                     layer_types=(V, A, V, V, A), dtype=jnp.bfloat16)
+    slots, pages, cache_len = 64, 8, 1024
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        num_slots=slots, cache_len=cache_len)), chip)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    # the scheduled ENTRY computation holds layer 1 (the first attention
+    # layer and its sparse FFN: a run of one, unrolled) in program order
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", entry)
+    kernel = f"[{slots},{C},8,4,64]"
+    assert f"%ff_ragged_paged_c{C}" in text and kernel in calls[0], calls[:2]
+    tokens = pack or slots * C
+    assert not re.findall(rf"\[{tokens},64,1536\]", text)   # no all-expert product
+    # the grouped expert matmuls, by name, over the routed pairs' rows
+    # (each expert's rows aligned to the row tile)
+    tm = kernels.grouped_tile(4 * tokens)
+    rows = -(-(4 * tokens + 64 * (tm - 1)) // tm) * tm
+    assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},1536\]", text)
+    assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},2048\]", text)
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["k"], cache["v"], cache["conv"], experts,
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    # activations only (the padded step's 32768 pair rows of float32 are
+    # 0.65 GB): under one sparse layer's experts, 1.2 GB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3 * experts.size // experts.shape[0] * experts.dtype.itemsize
