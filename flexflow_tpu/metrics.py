@@ -233,8 +233,11 @@ class SchedulerStats:
 
     def note_expert_counts(self, counts) -> None:
         """Count one step's routed expert layers: ``counts`` (sparse
-        layers, experts held) the real tokens each expert was given."""
+        layers, experts held) the real tokens each expert was given. A
+        layer that was given none did not route (a step that took the
+        all-expert einsum returns zeros) and is not counted."""
         counts = np.asarray(counts)
+        counts = counts[counts.sum(axis=-1) > 0]
         self.moe_pairs += int(counts.sum())
         self.moe_experts_hit += int((counts > 0).sum())
         self.moe_experts_held += int(counts.size)

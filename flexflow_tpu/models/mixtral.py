@@ -3,8 +3,23 @@
 its MoE support is the training-side expert ops). Runs on the generic
 decoder (:mod:`.transformer`) with ``num_local_experts`` > 0: a linear
 router takes the top-k experts per token (softmax over the selected k,
-HF ``MixtralSparseMoeBlock`` semantics), expert weights shard over the
-``expert`` mesh axis with Megatron TP inside each expert.
+HF ``MixtralSparseMoeBlock`` semantics, ``transformer.route_softmax_topk``).
+
+Which step computes the experts how (``transformer.routes_tokens``):
+the PAGED serving step on one device, at a width whose (token, expert)
+pairs give every expert a row tile (every rung of the mixed step),
+routes its tokens (``transformer.routed_experts_ffn``: the pairs of the
+real tokens sorted by expert, grouped matmuls over them, serve/kernels
+``ff_moe_grouped_*`` on the Pallas path; the FLOPs and the weights read
+follow the tokens, and each step returns its tokens per expert,
+``step_counts``). The all-expert einsum (``transformer._moe_ffn``)
+stays for the narrow C=1 step (a few rows read every expert either
+way, and the einsum is the faster step by 3%: PERF.md, PR 36), for
+training (``forward``), the dense-layout ``serve_step`` and a mesh of
+more than one device, where the expert weights shard over the
+``expert`` mesh axis with Megatron TP inside each expert: the grouped
+Pallas calls under GSPMD need a ``shard_map`` over the experts held
+(ROADMAP B0/B1).
 
 Architecture = LLaMA attention (RoPE, GQA, RMSNorm, no biases) + the
 MoE FFN; weight conversion from HF ``MixtralForCausalLM``.
@@ -39,6 +54,7 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     serve_debug_activations,
     serve_step,
     serve_step_paged,
+    step_counts,
 )
 from .hf_utils import layer_stackers, linear_w, stack, to_np
 

@@ -4,7 +4,11 @@ decoder's MoE path plus its Qwen2-MoE extensions: routed experts with
 their own FFN width, softmax-over-all top-k WITHOUT renormalization
 (``norm_topk_prob=False`` default), and an always-on sigmoid-gated
 shared expert. Attention is Qwen2-style (RoPE, GQA, RMSNorm, QKV
-biases)."""
+biases). As for ``models/mixtral.py``, a paged serving step on one
+device wide enough to give every expert a row tile routes its tokens
+through the grouped expert matmuls (``transformer.routed_experts_ffn``;
+the shared expert stays dense matmuls over every token) and every
+other step takes the all-expert einsum (``transformer._moe_ffn``)."""
 from __future__ import annotations
 
 from typing import Any, Dict
@@ -35,6 +39,7 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     serve_debug_activations,
     serve_step,
     serve_step_paged,
+    step_counts,
 )
 from .hf_utils import layer_stackers, linear_w, stack, to_np
 

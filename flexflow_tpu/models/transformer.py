@@ -437,41 +437,69 @@ def _project_qkv(cfg: DecoderConfig, p, h):
     )
 
 
-def _moe_ffn(cfg: DecoderConfig, p, h):
-    """Mixtral-style sparse-MoE FFN (HF ``MixtralSparseMoeBlock``):
-    linear router → top-k per token → softmax over the SELECTED k →
-    weighted sum of expert outputs.
-
-    TPU shape: experts are computed as one batched einsum over the
-    expert dim rather than gather/scatter per expert — at decode (a few
-    tokens per step) the all-expert compute is cheap and keeps the MXU
-    busy with one big contraction; the expert dim shards over the
-    ``expert`` mesh axis so each device computes only its expert range
-    and GSPMD inserts the combine reduction (the serving-time analog of
-    ops/moe.py's ExpertsOp range sharding). For E=8,K=2 this spends E/K
-    = 4x the FLOPs of perfect dispatch at prefill, and reads every
-    expert's weights every step. The grouped dispatch is
-    :func:`routed_experts_ffn` (tokens sorted by expert, a ragged matmul
-    over the routed pairs of real tokens), which ``models/lfm2_moe.py``
-    takes; ``mixtral`` and ``qwen2_moe`` (and the twin's MoE) still take
-    this all-expert einsum: moving them is ROADMAP A4, under the Mixtral
-    cell."""
-    E, K = cfg.num_local_experts, cfg.num_experts_per_tok
+def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
+    """A linear router with a softmax (HF ``MixtralSparseMoeBlock``,
+    ``Qwen2MoeSparseMoeBlock``), in float32: ``lax.top_k`` of the
+    logits ``h W_r`` CHOOSES (among equals the lower index first); the
+    weights are the softmax over the chosen k (``norm_topk``, Mixtral),
+    or the chosen entries of the softmax over all experts, verbatim
+    (Qwen2-MoE's ``norm_topk_prob=False``). h (..., D) -> (experts
+    (..., k) int32, weights (..., k) float32). Beside
+    :func:`route_sigmoid_topk`."""
     router = jnp.matmul(
-        h.astype(jnp.float32), _dense_w(p["w_router"], jnp.float32),
+        h.astype(jnp.float32), _dense_w(w_router, jnp.float32),
         preferred_element_type=jnp.float32,
-    )  # (B,S,E)
-    topv, topi = lax.top_k(router, K)
-    if cfg.moe_norm_topk:
-        # renormalize over the selected k (Mixtral; equals softmax over
-        # the selected logits)
-        gate = jax.nn.softmax(topv, axis=-1)  # (B,S,K)
+    )  # (..., E)
+    topv, topi = lax.top_k(router, k)
+    if norm_topk:
+        gate = jax.nn.softmax(topv, axis=-1)
     else:
-        # softmax over ALL experts, keep the selected weights verbatim
-        # (Qwen2-MoE norm_topk_prob=False default)
         gate = jnp.take_along_axis(
             jax.nn.softmax(router, axis=-1), topi, axis=-1
         )
+    return topi, gate
+
+
+def _shared_expert(cfg: DecoderConfig, p, h):
+    """Qwen2-MoE's always-on shared expert, scaled by a sigmoid token
+    gate (HF ``Qwen2MoeSparseMoeBlock`` shared_expert +
+    shared_expert_gate): dense matmuls over every token."""
+    s_up = _mm(h, p["w_shared_up"])
+    s_act = _activation(cfg, _mm(h, p["w_shared_gate"])) * s_up
+    s_out = _mm(s_act, p["w_shared_down"])
+    s_gate = jax.nn.sigmoid(
+        jnp.matmul(
+            h.astype(jnp.float32),
+            _dense_w(p["shared_expert_gate"], jnp.float32),
+            preferred_element_type=jnp.float32,
+        )
+    ).astype(h.dtype)  # (..., 1)
+    return s_gate * s_out
+
+
+def _moe_ffn(cfg: DecoderConfig, p, h):
+    """Mixtral-style sparse-MoE FFN (HF ``MixtralSparseMoeBlock``):
+    linear router → top-k per token → softmax over the SELECTED k
+    (:func:`route_softmax_topk`) → weighted sum of expert outputs, every
+    expert computed for every position as one batched einsum over the
+    expert dim. For E=8, K=2 that is E/K = 4x the routed FLOPs, and
+    every expert's weights read every step.
+
+    Still taken by :func:`forward` (training: the expert dim shards over
+    the ``expert`` mesh axis, each device computes its expert range and
+    GSPMD inserts the combine reduction, the serving-time analog of
+    ops/moe.py's ExpertsOp range sharding), by the dense-layout
+    :func:`serve_step`, by the paged step on a mesh of more than one
+    device (no benchmark cell runs these, and a Mosaic kernel under
+    GSPMD needs the ``shard_map`` that ``experts_held`` was written for,
+    ROADMAP B0/B1) and by a paged step too narrow to give every expert
+    a row tile (the C=1 step: both forms read every expert there, and
+    the einsum is 3% the faster step). Every other paged serving step
+    on one device routes its tokens instead (:func:`routes_tokens`,
+    :func:`_routed_ffn`, :func:`routed_experts_ffn`)."""
+    E, K = cfg.num_local_experts, cfg.num_experts_per_tok
+    topi, gate = route_softmax_topk(
+        h, p["w_router"], K, norm_topk=cfg.moe_norm_topk)  # (B,S,K)
     combine = jnp.einsum(
         "bsk,bske->bse", gate, jax.nn.one_hot(topi, E, dtype=jnp.float32)
     )  # (B,S,E)
@@ -495,19 +523,7 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
         preferred_element_type=jnp.float32,
     ).astype(h.dtype)
     if cfg.moe_shared_expert_intermediate_size:
-        # always-on shared expert, scaled by a sigmoid token gate
-        # (HF Qwen2MoeSparseMoeBlock shared_expert + shared_expert_gate)
-        s_up = _mm(h, p["w_shared_up"])
-        s_act = _activation(cfg, _mm(h, p["w_shared_gate"])) * s_up
-        s_out = _mm(s_act, p["w_shared_down"])
-        s_gate = jax.nn.sigmoid(
-            jnp.matmul(
-                h.astype(jnp.float32),
-                _dense_w(p["shared_expert_gate"], jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
-        ).astype(h.dtype)  # (B,S,1)
-        out = out + s_gate * s_out
+        out = out + _shared_expert(cfg, p, h)
     return out
 
 
@@ -540,6 +556,10 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     back by token. Its FLOPs follow the rows, not rows x experts, and it
     reads the weights of the experts that have rows. Beside
     :func:`_moe_ffn`, which computes every expert for every position.
+    Taken by the paged serving steps on one device: ``models/lfm2_moe.py``
+    at every width and, for ``mixtral`` and ``qwen2_moe``,
+    :func:`serve_step_paged` from a row tile an expert on
+    (:func:`routes_tokens`, :func:`_routed_ffn`).
 
     ``kernels="xla"``: ``lax.ragged_dot`` over the sorted rows (on the
     chip the compiler's own grouped-matmul kernel). ``"pallas"``:
@@ -550,7 +570,8 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
 
     h (T, D); ``real`` (T,) bool: padding places route nowhere;
     ``experts`` / ``weights`` (T, k) the router's choice
-    (:func:`route_sigmoid_topk`, over ALL its outputs). ``experts_held``
+    (:func:`route_sigmoid_topk` or :func:`route_softmax_topk`, over ALL
+    its outputs). ``experts_held``
     (lo, hi): the range of the router's outputs whose weights are here
     (``w_gate`` / ``w_up`` (hi - lo, D, F), ``w_down`` (hi - lo, F, D));
     the result is that range's part of the layer's, so the parts of
@@ -583,7 +604,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     if kernels == "pallas":
         from ..serve import kernels as _pk
 
-        tm = _pk.grouped_tile(P)
+        tm = _pk.grouped_tile(P, n)
         tiles = -(-(P + n * (tm - 1)) // tm)
         aligned = -(-counts // tm) * tm
         ends = jnp.cumsum(aligned)
@@ -642,6 +663,69 @@ def _ffn(cfg: DecoderConfig, p, h):
     if cfg.mlp_bias:
         out = out + p["b_down"]
     return out
+
+
+#: the experts' leaves of a layer's weights: what a routed step keeps
+#: stacked over the layers (``layer_weights``' ``whole``)
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def routes_tokens(cfg: DecoderConfig, layers, tokens: int) -> bool:
+    """Whether a paged serving step over ``tokens`` places sends its
+    real tokens through :func:`routed_experts_ffn` rather than
+    :func:`_moe_ffn`, from what the step can see: a sparse layer of the
+    form the grouped matmuls compute (a SiLU GLU over plain,
+    unquantized expert stacks); an ambient mesh of one device (under
+    GSPMD the grouped Pallas calls need a ``shard_map`` over the
+    experts held, ROADMAP B0/B1; the einsum shards as it is); and
+    static pairs that give every expert a row tile. Under that (the
+    C=1 step of 16 slots: 32 pairs, 4 an expert) both forms read every
+    expert's weights for a handful of rows, and routing, sorting and
+    gathering the pairs only add to the step: 17.40 ms against the
+    einsum's 16.91 on a v5e at Mixtral's widths (PERF.md, PR 36)."""
+    from ..serve.kernels import grouped_tile
+
+    mesh = jax.sharding.get_abstract_mesh()
+    experts, pairs = cfg.num_local_experts, tokens * cfg.num_experts_per_tok
+    return bool(
+        experts and cfg.glu and cfg.activation == "silu"
+        and not any(isinstance(layers[name], dict) for name in EXPERT_STACKS)
+        and (mesh.empty or mesh.size == 1)
+        and pairs >= grouped_tile(pairs, experts) * experts
+    )
+
+
+def step_counts(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
+    """What :func:`serve_step_paged` returns in its cache that is no
+    state (name -> shape, int32; the engine takes these out and hands
+    them to the scheduler behind the sampled tokens, as for
+    ``models/lfm2_moe.py``). ``moe_counts``: each layer's real tokens
+    per expert where the step routed its tokens (:func:`routes_tokens`),
+    zeros where it took the all-expert einsum. A dense model: nothing."""
+    if not cfg.num_local_experts:
+        return {}
+    return {"moe_counts": (cfg.num_hidden_layers, cfg.num_local_experts)}
+
+
+def _routed_ffn(cfg: DecoderConfig, p, h, real, layer, kernels: str):
+    """The sparse FFN of the paged serving step with its tokens ROUTED:
+    :func:`_moe_ffn`'s router, then the (token, expert) pairs of the
+    real tokens through the grouped matmuls; the shared expert stays
+    dense. h (B, S, D); ``real`` (B*S,); ``p``'s ``EXPERT_STACKS`` are
+    every layer's, addressed by ``layer``. -> (out (B, S, D), counts
+    (E,) int32 the real tokens each expert was given)."""
+    B, S, D = h.shape
+    flat = h.reshape(B * S, D)
+    experts, weights = route_softmax_topk(
+        flat, p["w_router"], cfg.num_experts_per_tok,
+        norm_topk=cfg.moe_norm_topk)
+    out, counts = routed_experts_ffn(
+        flat, real, experts, weights, *(p[name] for name in EXPERT_STACKS),
+        experts_held=(0, cfg.num_local_experts), layer=layer, kernels=kernels)
+    out = out.reshape(B, S, D)
+    if cfg.moe_shared_expert_intermediate_size:
+        out = out + _shared_expert(cfg, p, h)
+    return out, counts
 
 
 def block(
@@ -1194,11 +1278,37 @@ def _gather_attended(attn, pack):
     return jnp.take(attn.reshape(R * C, -1), pack[1], axis=0, mode="clip")[None]
 
 
+def _add_attn_ffn(cfg: DecoderConfig, p, x, h, attn, routed, layer,
+                  kernels: str):
+    """The second half of a paged block: the attention result and the
+    FFN added to the residual stream ``x`` (``h``: the block's first
+    norm, which a parallel block feeds to both). ``routed`` None: the
+    FFN is :func:`_ffn`, -> (x,). Else ``(real, counts)``
+    (:func:`serve_step_paged`): a sparse layer routes its real tokens
+    (:func:`_routed_ffn`) and writes its tokens per expert into row
+    ``layer`` of ``counts``, -> (x, counts)."""
+    if cfg.parallel_block:
+        if cfg.parallel_two_norms:
+            h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
+        else:
+            h2 = h
+        x = x + attn
+    else:
+        x = x + attn
+        h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
+    if routed is None:
+        return (x + _ffn(cfg, p, h2),)
+    real, counts = routed
+    out, given = _routed_ffn(cfg, p, h2, real, layer, kernels)
+    return x + out, lax.dynamic_update_index_in_dim(counts, given, layer, 0)
+
+
 def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                       phys, off, page_table, kernels: str = "xla",
                       k_scale=None, v_scale=None, qmax=None,
                       *, fused_rope: bool = False, logical=None,
-                      cp_mesh=None, layer=None, q_len=None, pack=None):
+                      cp_mesh=None, layer=None, q_len=None, pack=None,
+                      routed=None):
     """Paged twin of :func:`serve_block`: scatter new K/V at the
     table-resolved (page, offset); attend over the virtual cache read
     through the table (``jnp.take`` gather, or the fused ragged paged
@@ -1238,7 +1348,12 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     (1, T) and so is everything here that is not attention; the queries
     are spread to (R, C) for the attention call alone — its mask, bias,
     ``q_len`` and result shape are the padded step's — and its result
-    is gathered back."""
+    is gathered back.
+
+    ``routed`` (:func:`_add_attn_ffn`; with ``layer``, off the ring): a
+    sparse layer routes its real tokens, ``p``'s ``EXPERT_STACKS`` are
+    every layer's, and the step's tokens per expert are returned as a
+    sixth value."""
     from ..serve import kernels as _pk
 
     if cp_mesh is None and not (kernels == "pallas" and bias is None):
@@ -1247,6 +1362,7 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
         return _block_paged_xla(
             cfg, p, x, rope, bias, mask, k_pool, v_pool, phys, off,
             page_table, k_scale, v_scale, qmax, layer=layer, pack=pack,
+            routed=routed,
         )
     if cp_mesh is not None and layer is not None:
         # the ring's pool rows are sharded over ``seq``, which the rows
@@ -1323,24 +1439,18 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     attn = _mm(attn, p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
-    if cfg.parallel_block:
-        if cfg.parallel_two_norms:
-            h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-        else:
-            h2 = h
-        return x + attn + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
-    x = x + attn
-    h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-    return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
+    x, *counts = _add_attn_ffn(cfg, p, x, h, attn, routed, layer, kernels)
+    return (x, k_pool, v_pool, k_scale, v_scale, *counts)
 
 
 def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
                      k_pool, v_pool, phys, off, page_table,
                      k_scale=None, v_scale=None, qmax=None, layer=None,
-                     pack=None):
+                     pack=None, routed=None):
     """One block of the UNFUSED XLA paged step: the body of
     :func:`serve_block_paged`'s XLA path. ``layer``: the pools are the
-    stacked ones, ``pack``: the token axis is packed (see
+    stacked ones, ``pack``: the token axis is packed, ``routed``: a
+    sparse layer routes its tokens, through ``lax.ragged_dot`` (see
     :func:`serve_block_paged`)."""
     from ..serve import kernels as _pk
 
@@ -1368,15 +1478,8 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
     attn = _mm(_gather_attended(attn, pack), p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
-    if cfg.parallel_block:
-        if cfg.parallel_two_norms:
-            h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-        else:
-            h2 = h
-        return x + attn + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
-    x = x + attn
-    h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
-    return x + _ffn(cfg, p, h2), k_pool, v_pool, k_scale, v_scale
+    x, *counts = _add_attn_ffn(cfg, p, x, h, attn, routed, layer, "xla")
+    return (x, k_pool, v_pool, k_scale, v_scale, *counts)
 
 
 def _paged_serve_context(cfg, cache, positions, cache_positions, mask,
@@ -1447,7 +1550,16 @@ def serve_step_paged(
     row's logits are taken at the packed place of its ``logits_idx``.
     Every real token goes through the operations of the padded step;
     positions that held none stop being computed. None: the padded
-    step, operation for operation."""
+    step, operation for operation.
+
+    A sparse model on one device, at a width that gives every expert a
+    row tile (:func:`routes_tokens`: the mixed step's rungs, not the
+    C=1 step of a few slots), sends the (token, expert) pairs of its
+    REAL tokens through the grouped expert matmuls
+    (:func:`routed_experts_ffn`; its experts' weights stay stacked over
+    the layers, each layer addressing its own). The returned cache of a
+    sparse model also holds ``moe_counts`` (:func:`step_counts`: an
+    output, not an input)."""
     if mesh is not None and mesh.shape.get(PIPE_AXIS, 1) > 1:
         raise NotImplementedError(
             "paged KV serving is not composed with pipeline parallelism "
@@ -1500,25 +1612,52 @@ def serve_step_paged(
         qmax = resolve_spec(kv_quant).qmax
         scales = (cache["k_scale"], cache["v_scale"])
 
+    # a sparse layer's real tokens, on the block's token axis: a packed
+    # place below the step's real count, a row's leading ``q_len``
+    # columns, every column under an explicit mask
+    stacks, real = (), None
+    if routes_tokens(cfg, params["layers"], pack or tokens.size):
+        stacks = EXPERT_STACKS
+        if pack is not None:
+            real = jnp.arange(pack, dtype=jnp.int32) < q_len.sum()
+        elif q_len is not None:
+            real = (jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
+                    < q_len[:, None]).reshape(-1)
+        else:
+            real = jnp.ones((tokens.size,), jnp.bool_)
+    counts = {name: jnp.zeros(shape, jnp.int32)
+              for name, shape in step_counts(cfg).items()}
+    layers = params["layers"]
+
     # The stacked pools are the loop's CARRY, updated in place: a layer
     # addresses its own pages inside them (serve_block_paged, ``layer``).
     # Scanned in as xs and out as ys they are two buffers: a copy of the
     # whole pool a step, a slice out and a write-back of every layer
-    # (tests/test_chip_compile.py holds the compiled step to this).
+    # (tests/test_chip_compile.py holds the compiled step to this). A
+    # routed layer addresses its experts inside their stacks likewise (a
+    # layer's experts sliced out to feed a kernel call would be a copy
+    # of them a step) and its row of the tokens per expert in the carry.
     def scan_body(carry, l):
-        h, kc, vc, ks, vs = carry
-        p_l = jax.tree.map(lambda a: _layer_of(a, l), params["layers"])
+        h, kc, vc, ks, vs, *given = carry
+        p_l = jax.tree.map(
+            lambda a: _layer_of(a, l),
+            {k: w for k, w in layers.items() if k not in stacks})
+        p_l.update((k, layers[k]) for k in stacks)
         return serve_block_paged(
             cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
             page_table, kernels, ks, vs, qmax,
             fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
             layer=l, q_len=q_len, pack=pack_idx,
+            routed=(real, *given) if given else None,
         ), None
 
-    (x, k_new, v_new, *scales), _ = lax.scan(
-        scan_body, (x, cache["k"], cache["v"], *scales), jnp.arange(n)
-    )
-    new_cache = {"k": k_new, "v": v_new}
+    carry = (x, cache["k"], cache["v"], *scales)
+    if real is not None:
+        carry += (counts["moe_counts"],)
+    (x, k_new, v_new, *scales), _ = lax.scan(scan_body, carry, jnp.arange(n))
+    if real is not None:
+        counts["moe_counts"] = scales.pop()
+    new_cache = {"k": k_new, "v": v_new, **counts}
     if qmax is not None:
         new_cache["k_scale"], new_cache["v_scale"] = scales
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))

@@ -861,7 +861,10 @@ class InferenceEngine:
         # tokens in ONE int32 array, ``step_fetch`` (the newest mixed
         # step's, a device array): the scheduler's flush fetches that
         # one array where it would fetch the tokens (split_fetch)
-        self._step_counts = getattr(model, "step_counts", lambda cfg: {})(cfg)
+        # (the paged step's alone: the dense layout returns none)
+        self._step_counts = (
+            getattr(model, "step_counts", lambda cfg: {})(cfg)
+            if self.paged else {})
         self.step_fetch = None
         # Observability (flexflow_tpu/obs): count_dispatch doubles as
         # the tracing chokepoint — with a tracer attached (shared with
@@ -1188,8 +1191,8 @@ class InferenceEngine:
             if not names:
                 return fn
 
-            def step(*args):
-                logits, cache = fn(*args)
+            def step(*args, **kw):
+                logits, cache = fn(*args, **kw)
                 cache = dict(cache)
                 taken = {name: cache.pop(name) for name in names}
                 return (logits, cache, taken) if counts else (logits, cache)

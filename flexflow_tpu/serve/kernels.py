@@ -1559,13 +1559,33 @@ def fused_rope_paged_attention(
 # and its weights are named by a prefetched scalar in the block index map.
 
 
-def grouped_tile(pairs: int) -> int:
+def grouped_tile(pairs: int, experts: int) -> int:
     """Rows a tile of the grouped expert matmuls at ``pairs`` (token,
-    expert) pairs: 16 (a bf16 sublane tile) while a step's pairs are a
-    few an expert (the decode step: the kernel is then a read of the
-    experts' weights and alignment costs rows, not time), 128 from a
-    thousand pairs on (a grid step's overhead would count)."""
-    return 16 if pairs <= 1024 else 128
+    expert) pairs over ``experts`` experts held, both static: 128 (the
+    MXU's rows) where the pairs are a tile's worth an expert, 16 (a bf16
+    sublane tile) while they are a few an expert (the decode step: the
+    kernel is then a read of the experts' weights and alignment costs
+    rows, not time). 1024 pairs are 128 rows each of Mixtral's 8
+    experts, whose matmuls in tiles of 16 would use an eighth of the
+    MXU, and 16 rows each of LFM2's 64."""
+    return 128 if pairs >= 128 * experts else 16
+
+
+def grouped_block(width: int, depth: int, weights: int, itemsize: int) -> int:
+    """Columns of a weight block of the grouped expert matmuls, from the
+    static widths: ``weights`` stacks of (depth, width) an expert, read
+    as (depth, block) blocks. The rows' block is read again for every
+    column block, so 512 columns are doubled while there would be more
+    than 16 column blocks, the wider block divides ``width`` and the
+    weight blocks, double-buffered, stay within 32 MB of the 48 MB the
+    calls state (:func:`_grouped_call`; the rows' and the result's
+    blocks take the rest). LFM2's (2048, 1536) stays at 512; Mixtral's
+    up-projections (4096 -> 14336) take 1024, 14 column blocks."""
+    block = min(512, width)
+    while (width // block > 16 and width % (2 * block) == 0
+           and 2 * weights * depth * 2 * block * itemsize <= 32 << 20):
+        block *= 2
+    return block
 
 
 def _grouped_call(kernel, name, tile_group, n_active, operands, in_specs,
@@ -1585,8 +1605,7 @@ def _grouped_call(kernel, name, tile_group, n_active, operands, in_specs,
       *operands)
 
 
-def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
-                tf: int = 512):
+def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int):
     """``silu(rows W_gate[g]) * (rows W_up[g])`` tile by tile, ``g`` the
     expert of the tile. rows (P, D), P a multiple of ``tm``;
     ``w_gate`` / ``w_up`` (G, D, F); ``tile_group`` (P / tm,) the index
@@ -1596,10 +1615,11 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
     they fetch no weights). The grid runs the F blocks outermost and the
     tiles innermost: an expert's (D, tf) block is fetched once a run of
     its tiles, so the weights read are those of the experts that have
-    rows, once. -> (P, F) in rows' dtype."""
+    rows, once (``tf``: :func:`grouped_block`). -> (P, F) in rows'
+    dtype."""
     P, D = rows.shape
     F = w_gate.shape[-1]
-    tf = min(tf, F)
+    tf = grouped_block(F, D, 2, w_gate.dtype.itemsize)
     assert P % tm == 0 and F % tf == 0, (P, tm, F, tf)
 
     def kernel(tg_ref, na_ref, x_ref, wg_ref, wu_ref, o_ref):
@@ -1620,12 +1640,12 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
         (F // tf, P // tm))
 
 
-def grouped_down(act, w_down, tile_group, n_active, *, tm: int, td: int = 512):
+def grouped_down(act, w_down, tile_group, n_active, *, tm: int):
     """``act W_down[g]`` tile by tile (see :func:`grouped_glu`): act
     (P, F), ``w_down`` (G, F, D) -> (P, D) float32."""
     P, F = act.shape
     D = w_down.shape[-1]
-    td = min(td, D)
+    td = grouped_block(D, F, 1, w_down.dtype.itemsize)
     assert P % tm == 0 and D % td == 0, (P, tm, D, td)
 
     def kernel(tg_ref, na_ref, a_ref, w_ref, o_ref):

@@ -212,7 +212,9 @@ def _assert_pool_carried(text, pool):
     alias, = re.findall(r"input_output_alias={(.*?) }, entry", text)
     aliased = {int(n) for n in re.findall(r"\((\d+), {}, \S+?\)", alias)}
     assert len(params) == 2 and params <= aliased
-    loop, = re.findall(r"= \((.*?)\) while\(", text)
+    # (a routed expert layer's grouping has small loops of its own)
+    loop, = (carry for carry in re.findall(r"= \((.*?)\) while\(", text)
+             if re.search(whole, carry))
     assert len(re.findall(whole, loop)) == 2
     assert not re.findall(rf"= {layer}\S* [\w-]+\(", text)
     makers = set(re.findall(rf"= {whole}\S* ([\w-]+)\(", text))
@@ -264,6 +266,13 @@ def _need(compiled):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
 
 
+def _pair_rows(pairs, experts):
+    """Rows of the grouped expert matmuls at ``pairs`` static (token,
+    expert) pairs: every expert's rows aligned to the row tile."""
+    tm = kernels.grouped_tile(pairs, experts)
+    return -(-(pairs + experts * (tm - 1)) // tm) * tm
+
+
 @pytest.mark.parametrize("family", ["mistral", "mixtral"])
 def test_packed_rungs_compile_with_the_pool_in_place(chip, family):
     """Every rung of the (16, 128) ladder (ISSUE 32) at published
@@ -297,27 +306,34 @@ def test_packed_rungs_compile_with_the_pool_in_place(chip, family):
     padded, _ = _compile(step(None), *args, donate=(1,))
     for width in rungs:
         compiled, text = _compile(step(width), *args, donate=(1,))
-        assert text.count("tpu_custom_call") == 1
+        # the attention call, and a sparse model's two grouped matmuls
+        assert text.count("tpu_custom_call") == (
+            1 if family == "mistral" else 3)
         kernel, = re.findall(r"%ff_ragged_paged_c128\S* = (\S+) custom-call",
                              text)
         assert kernel.startswith(f"bf16[{R},128,")   # reduce.kernel_chunk
         _assert_pool_carried(text, args[1]["k"])
-        if family == "mistral":  # the expert einsums hold more than a pool
+        if family == "mistral":  # the pairs' rows hold more than a pool
             _assert_pool_in_place(compiled, text, args[1]["k"])
-        # the FFN runs over the rung, not over slots x chunk
-        assert re.search(rf"bf16\[(1,)?{width},(8,)?14336\]", text)
-        assert not re.search(r"bf16\[(16,128|2048),(8,)?14336\]", text)
+        # the FFN runs over the rung, not over slots x chunk: a dense one
+        # at the rung's width, a sparse one over the rung's routed pairs
+        rows = width if family == "mistral" else _pair_rows(2 * width, 8)
+        assert re.search(rf"bf16\[(1,)?{rows},14336\]", text)
+        assert not re.search(r"bf16\[(16,128|\d+,8),14336\]", text)
+        assert family == "mixtral" or "bf16[2048,14336]" not in text
         assert _need(compiled) <= _need(padded)
 
 
 @pytest.mark.parametrize("family, layers, gigabytes", [
-    ("mistral", 20, 10.66), ("mixtral", 4, 12.90)])
+    ("mistral", 20, 10.66), ("mixtral", 4, 12.57)])
 def test_widest_rung_needs_what_the_padded_step_did(chip, family, layers,
                                                     gigabytes):
     """The widest rung is the padded step itself: at the benchmark's
     depths and pool (128 pages, 17 a slot) it needs what
     ``benchmarks/tools/fit.py`` counted before the ladder (PERF.md
-    section 4), so a depth that fitted still fits."""
+    section 4), so a depth that fitted still fits. (Mixtral: 12.90 GB
+    with the all-expert einsums' 0.49 GB of temporaries, 12.57 since
+    its tokens are routed, ISSUE 36.)"""
     from flexflow_tpu.models import mixtral
 
     mod = mistral if family == "mistral" else mixtral
@@ -523,8 +539,7 @@ def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
     assert not re.findall(rf"\[{tokens},64,1536\]", text)   # no all-expert product
     # the grouped expert matmuls, by name, over the routed pairs' rows
     # (each expert's rows aligned to the row tile)
-    tm = kernels.grouped_tile(4 * tokens)
-    rows = -(-(4 * tokens + 64 * (tm - 1)) // tm) * tm
+    tm, rows = kernels.grouped_tile(4 * tokens, 64), _pair_rows(4 * tokens, 64)
     assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},1536\]", text)
     assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},2048\]", text)
     experts = params["sparse"]["w_gate"]
@@ -536,3 +551,99 @@ def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
     # 0.65 GB): under one sparse layer's experts, 1.2 GB
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 3 * experts.size // experts.shape[0] * experts.dtype.itemsize
+
+
+# --- the generic decoder's sparse layer with its tokens routed (Mixtral) ----
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 512), (128, 1024),
+                                     (128, None)])
+def test_mixtral_routed_step_compiles_in_place(chip, C, pack):
+    """models/mixtral.py at published widths (4096 / 14336, 8 experts,
+    top-2), two layers, the benchmark cell's 16 slots. Both packed
+    rungs and the padded step send their real tokens' pairs through the
+    grouped expert matmuls (``ff_moe_grouped_*_t128``: from the 512
+    rung on the static pairs are a 128-row tile an expert), the
+    attention call stays the program's FIRST kernel call (the trace
+    reduction finds the step by it) and there is no all-expert product;
+    the C=1 step (32 pairs: under a tile an expert) keeps the einsum.
+    In all four the pool is the loop's carry in place and the scheduled
+    program copies no expert stack and no layer of one (a layer sliced
+    out to feed a kernel call would be 0.94 GB a projection a layer a
+    step)."""
+    from flexflow_tpu.models import mixtral
+
+    cfg = mixtral.mixtral_8x7b(dtype=jnp.bfloat16, num_hidden_layers=2)
+    args = (_on(jax.eval_shape(functools.partial(
+        mixtral.init_params, cfg=cfg), jax.random.PRNGKey(0)), chip),
+    ) + _step_args(chip, cfg, C)[1:]
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return mixtral.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=CACHE_LEN, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(step, *args, donate=(1,))
+    calls = re.findall(
+        r"%(\w+)(?:\.\d+)* = \S+ custom-call\(.*tpu_custom_call", text)
+    tokens = pack or R * C
+    if C == 1:
+        assert calls == ["ff_ragged_paged_c1"], calls
+        assert re.findall(rf"\[{tokens},8,14336\]", text)
+    else:
+        assert kernels.grouped_tile(2 * tokens, 8) == 128
+        assert calls == [f"ff_ragged_paged_c{C}", "ff_moe_grouped_glu_t128",
+                         "ff_moe_grouped_down_t128"], calls
+        rows = _pair_rows(2 * tokens, 8)
+        assert re.findall(
+            rf"%ff_moe_grouped_glu_t128\S* = bf16\[{rows},14336\]", text)
+        assert re.findall(
+            rf"%ff_moe_grouped_down_t128\S* = f32\[{rows},4096\]", text)
+        assert not re.findall(r"\[\d+,8,14336\]", text)  # no all-expert product
+    _assert_pool_carried(text, args[1]["k"])
+    for name in ("w_gate", "w_up", "w_down"):
+        stack = args[0]["layers"][name]
+        for shape in (stack.shape, stack.shape[1:]):
+            dims = ",".join(map(str, shape))
+            assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    # activations only: under one projection of one layer's experts
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    stack = args[0]["layers"]["w_up"]
+    assert temp < stack.size // stack.shape[0] * stack.dtype.itemsize
+    assert set(compiled.output_shardings[1]) >= {"k", "v", "moe_counts"}
+
+
+@pytest.mark.parametrize("tm", [16, 128])
+def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
+                                                          monkeypatch):
+    """``grouped_glu`` / ``grouped_down`` alone at one row tile an
+    expert of Mixtral's widths, (8 tm, 4096) x (8, 4096, 14336), under
+    the VMEM limit the calls state: the up-projections in 14 column
+    blocks of 1024 (two (4096, 1024) weight blocks, double-buffered,
+    are 32 MB), the down-projection in 8 of 512 (a (14336, 512) block
+    is 14.7 MB). LFM2's widths keep 512 for both."""
+    assert kernels.grouped_block(14336, 4096, 2, 2) == 1024
+    assert kernels.grouped_block(4096, 14336, 1, 2) == 512
+    assert kernels.grouped_block(1536, 2048, 2, 2) == 512
+    assert kernels.grouped_block(2048, 1536, 1, 2) == 512
+    limits = []
+    params = kernels.pltpu.CompilerParams
+    monkeypatch.setattr(
+        kernels.pltpu, "CompilerParams",
+        lambda **kw: limits.append(kw["vmem_limit_bytes"]) or params(**kw))
+    E, D, F = 8, 4096, 14336
+    up, down = chip((E, D, F), jnp.bfloat16), chip((E, F, D), jnp.bfloat16)
+    tiles = chip((E,), jnp.int32)
+
+    def fn(rows, w_gate, w_up, w_down, tile_group, n_active):
+        act = kernels.grouped_glu(rows, w_gate, w_up, tile_group, n_active,
+                                  tm=tm)
+        return kernels.grouped_down(act, w_down, tile_group, n_active, tm=tm)
+
+    _, text = _compile(fn, chip((E * tm, D), jnp.bfloat16), up, up, down,
+                       tiles, chip((), jnp.int32))
+    assert text.count("tpu_custom_call") == 2
+    assert f"%ff_moe_grouped_glu_t{tm}" in text
+    assert f"%ff_moe_grouped_down_t{tm}" in text
+    assert limits == [48 << 20, 48 << 20]

@@ -200,3 +200,165 @@ def test_cache_op_serves_cached_value_at_inference():
     out_cached = _np.asarray(m.forward(x2))   # should use x1's cached enc
     out_ref = _np.asarray(m.forward(x1))
     _np.testing.assert_allclose(out_cached, out_ref, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The generic decoder's sparse layer with its tokens ROUTED (ISSUE 36): the
+# paged serving step of ``mixtral`` / ``qwen2_moe`` against the all-expert
+# einsum (``transformer._moe_ffn``) on the same weights.
+
+from flexflow_tpu.models import mixtral, qwen2_moe, transformer  # noqa: E402
+from flexflow_tpu.serve import kernels as serve_kernels  # noqa: E402
+
+SLOTS, CHUNK, PAGE, PAGES = 4, 16, 8, 4
+CACHE_LEN = PAGE * PAGES - 1     # the scratch position: a slot's last line
+
+
+def _sparse_family(name):
+    """mixtral: softmax over the chosen k; qwen2_moe: softmax over all
+    experts, the chosen weights verbatim, and a shared expert."""
+    mod = {"mixtral": mixtral, "qwen2_moe": qwen2_moe}[name]
+    cfg = mod.tiny(dtype=jnp.float32)
+    assert cfg.moe_norm_topk == (name == "mixtral")
+    assert bool(cfg.moe_shared_expert_intermediate_size) == (name == "qwen2_moe")
+    return mod, cfg, mod.init_params(jax.random.PRNGKey(3), cfg)
+
+
+def _paged_step(mod, cfg, params, cache, tokens, q_len, first, **kw):
+    """One paged step over rows whose real tokens are their leading
+    ``q_len`` columns from position ``first``; the rest is padding at
+    the scratch position."""
+    C = tokens.shape[1]
+    cols = np.arange(C)[None]
+    positions = np.where(cols < q_len[:, None], first[:, None] + cols,
+                         CACHE_LEN).astype(np.int32)
+    table = jnp.arange(SLOTS * PAGES, dtype=jnp.int32).reshape(SLOTS, PAGES)
+    return mod.serve_step_paged(
+        params, cache, jnp.asarray(tokens), jnp.asarray(positions),
+        jnp.asarray(np.maximum(q_len - 1, 0), jnp.int32), None, None, table,
+        cfg=cfg, cache_len=CACHE_LEN, **kw)
+
+
+@pytest.mark.parametrize("program", ["padded", "rung", "decode"])
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+@pytest.mark.parametrize("family", ["mixtral", "qwen2_moe"])
+def test_routed_paged_step_matches_the_all_expert_einsum(
+        family, kernels, program, monkeypatch):
+    """The padded step and a packed rung, each with padding places and
+    a row that holds no real token: the logits of the rows that sample
+    and the K/V lines written are the einsum step's within float32
+    accumulation, and each layer's tokens per expert sum to real tokens
+    x k. The C=1 step (8 pairs over 4 experts: under a row tile each)
+    takes the einsum itself and returns zeros."""
+    mod, cfg, params = _sparse_family(family)
+    rng = np.random.default_rng(7)
+    cache = mod.init_paged_kv_cache(cfg, SLOTS * PAGES, PAGE, jnp.float32)
+    q_len, first = np.array([16, 3, 0, 9]), np.zeros(SLOTS, np.int64)
+    tokens = rng.integers(0, cfg.vocab_size, (SLOTS, CHUNK)).astype(np.int32)
+    kw = dict(kernels=kernels)
+    if program == "rung":
+        kw["pack"] = 32
+    if program == "decode":   # from a cache that holds the prompts
+        _, cache = _paged_step(mod, cfg, params, cache, tokens, q_len, first,
+                               **kw)
+        cache.pop("moe_counts")
+        first, q_len = q_len, np.array([1, 1, 0, 1])
+        tokens = tokens[:, :1]
+    routed = transformer.routes_tokens(
+        cfg, params["layers"], kw.get("pack") or tokens.size)
+    assert routed == (program != "decode")
+    got, new = _paged_step(mod, cfg, params, cache, tokens, q_len, first, **kw)
+    with monkeypatch.context() as m:
+        m.setattr(transformer, "routes_tokens", lambda *a: False)
+        want, old = _paged_step(mod, cfg, params, cache, tokens, q_len, first,
+                                **kw)
+    rows = q_len > 0
+    np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(want)[rows],
+                               rtol=1e-5, atol=1e-5)
+    for name in ("k", "v"):   # the lines of the tokens that exist
+        lines = [np.asarray(c[name])[:, :SLOTS * PAGES].reshape(
+            cfg.num_hidden_layers, SLOTS, PAGES * PAGE, -1) for c in (new, old)]
+        for r in range(SLOTS):
+            held = first[r] + q_len[r]
+            np.testing.assert_allclose(lines[0][:, r, :held],
+                                       lines[1][:, r, :held],
+                                       rtol=1e-5, atol=1e-6)
+    counts = np.asarray(new["moe_counts"])
+    assert counts.shape == (cfg.num_hidden_layers, cfg.num_local_experts)
+    assert (counts.sum(axis=1)
+            == routed * q_len.sum() * cfg.num_experts_per_tok).all()
+    assert not np.asarray(old["moe_counts"]).any()
+    assert mod.step_counts(cfg) == {"moe_counts": counts.shape}
+
+
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+@pytest.mark.parametrize("family", ["mixtral", "qwen2_moe"])
+def test_routed_ffn_chooses_the_einsums_experts(family, kernels):
+    """One sparse layer over a flat token axis with padding places: the
+    experts the routed layer counted are the ones ``_moe_ffn``'s rule
+    chooses for the real tokens (``lax.top_k`` of the float32 router
+    logits), and its result on them is the einsum's."""
+    mod, cfg, params = _sparse_family(family)
+    layer, T = 1, 24
+    rng = np.random.default_rng(11)
+    h = jnp.asarray(rng.normal(size=(1, T, cfg.hidden_size)), jnp.float32)
+    real = np.arange(T) % 5 != 3
+    p_l = jax.tree.map(lambda a: a[layer], params["layers"])
+    want = transformer._moe_ffn(cfg, p_l, h)
+    stacked = dict(p_l, **{name: params["layers"][name]
+                           for name in transformer.EXPERT_STACKS})
+    got, counts = transformer._routed_ffn(cfg, stacked, h, jnp.asarray(real),
+                                          layer, kernels)
+    np.testing.assert_allclose(np.asarray(got)[0, real],
+                               np.asarray(want)[0, real], rtol=1e-5, atol=1e-6)
+    logits = np.asarray(h[0], np.float32) @ np.asarray(p_l["w_router"])
+    chosen = np.argsort(-logits, axis=-1, kind="stable")[
+        :, :cfg.num_experts_per_tok]
+    np.testing.assert_array_equal(
+        np.asarray(counts),
+        np.bincount(chosen[real].reshape(-1), minlength=cfg.num_local_experts))
+
+
+@pytest.mark.parametrize("norm_topk", [True, False])
+def test_route_softmax_topk_is_the_einsums_rule(norm_topk):
+    """``route_softmax_topk`` against ``_moe_ffn``'s arithmetic written
+    out: top-k of the float32 logits, among equals the lower index
+    first (experts 1 and 3 share a router column, so every token ties
+    them), then the softmax over the chosen k, or the chosen entries of
+    the softmax over all."""
+    rng = np.random.default_rng(5)
+    T, D, E, K = 32, 16, 6, 2
+    w = rng.normal(size=(D, E)).astype(np.float32)
+    w[:, 3] = w[:, 1]
+    h = rng.normal(size=(T, D)).astype(np.float32)
+    experts, gate = transformer.route_softmax_topk(
+        jnp.asarray(h), jnp.asarray(w), K, norm_topk=norm_topk)
+    logits = np.asarray(jnp.matmul(jnp.asarray(h), jnp.asarray(w),
+                                   preferred_element_type=jnp.float32))
+    topv, topi = jax.lax.top_k(logits, K)
+    np.testing.assert_array_equal(np.asarray(experts), np.asarray(topi))
+    order = np.argsort(-logits, axis=-1, kind="stable")[:, :K]
+    np.testing.assert_array_equal(np.asarray(experts), order)
+    tied = (order == 1).any(axis=1) & (order == 3).any(axis=1)
+    assert tied.any()
+    assert (np.argmax(order[tied] == 1, axis=1)
+            < np.argmax(order[tied] == 3, axis=1)).all()
+    if norm_topk:
+        want = jax.nn.softmax(topv, axis=-1)
+    else:
+        want = jnp.take_along_axis(jax.nn.softmax(jnp.asarray(logits), axis=-1),
+                                   topi, axis=-1)
+    np.testing.assert_array_equal(np.asarray(gate), np.asarray(want))
+    assert experts.dtype == jnp.int32 and gate.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("pairs, experts, tile", [
+    # LFM2's programs (64 experts, top-4): the C=1 step at 64 rows, the
+    # 2048 and 4096 rungs and the padded step: what they had before
+    (256, 64, 16), (8192, 64, 128), (16384, 64, 128), (32768, 64, 128),
+    # Mixtral's (8 experts, top-2): the C=1 step at 16 rows, the 512 rung
+    # (128 rows an expert: a whole tile), the 1024 rung, the padded step
+    (32, 8, 16), (1024, 8, 128), (2048, 8, 128), (4096, 8, 128),
+])
+def test_grouped_tile_follows_the_rows_an_expert_gets(pairs, experts, tile):
+    assert serve_kernels.grouped_tile(pairs, experts) == tile

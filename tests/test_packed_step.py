@@ -305,7 +305,8 @@ def test_unpacked_callers_trace_the_padded_step(model, kernels, monkeypatch):
             m.setattr(transformer, "_pack_tokens", None)
             parents = _jaxpr_of_step(eng, chunk, False)
         assert ours == parents
-        assert "cumsum" not in ours
+        # (a routed expert layer's grouping has a cumsum of its own)
+        assert "cumsum" not in ours or model[1].num_local_experts
     # and the decode step, the dense layout and the llama twin have no ladder
     assert eng.pack_ladder(1) == ()
     mod, cfg, params = model
