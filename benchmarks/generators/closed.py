@@ -3,15 +3,30 @@ moment its last one completes — callers that wait for a reply. A slow
 server therefore receives less load; the rate is an outcome.
 
 Lengths: every seed draws the same multiset, round by round, in another
-order (``harness/lengths.py``). Each client's FIRST request is cut to a seeded share of
+order (``harness/lengths.py``). Each client's FIRST request is cut to a drawn share of
 its lengths (the shares evenly spaced over the clients), so that the
 clients are out of phase from the first step and the window opens on a
 steady state after seconds, not after a round of full requests. Those
 first requests are warm-up and never samples.
 
+``order`` (a key of the traffic file, a whole number; PR 38). In a
+closed loop with no think time the lengths' order IS the arrivals: a
+client's next request starts a fixed number of steps after its last, so
+which prompts' chunks share a step, and with that how wide every step
+is, follows from the order alone. Two seeds' windows then hold two
+samples of some thousand steps and their percentiles read 2% and more
+apart where two runs of one seed agree to 0.1% (PERF.md section 2).
+With the key, the rounds and the first cuts are drawn from ``order``
+and the seed seats the clients on them (which client holds which
+sequence of lengths) and draws every token: every seed sends the same
+requests at the same steps, from other clients, with other tokens.
+Without it the seed draws the order too, as before PR 38.
+
 Samples: the full requests that COMPLETE inside the window. A request's
 due time is the moment its client saw the previous one complete.
 """
+import numpy as np
+
 from benchmarks.harness import lengths
 from benchmarks.harness.loop import Sent
 
@@ -23,15 +38,21 @@ class Generator:
         self.warmup_s = float(traffic["warmup_s"])
         self.vocab = vocab
         n = int(traffic["clients"])
+        # ``order`` (a whole number): the lengths and the first cuts are
+        # drawn from IT and the seed only seats the clients on them
+        # (below); absent, the seed draws them
+        order = (np.random.default_rng(int(traffic["order"]))
+                 if "order" in traffic else rng)
         # round by round the clients hold one length from each of n
-        # strata, in a seeded order: whatever part of the rounds a
+        # strata, in a drawn order: whatever part of the rounds a
         # window sees, it sees the same work under every seed
-        rounds = [list(zip(lengths.block(traffic["prompt_tokens"], n, r, rng),
-                           lengths.block(traffic["answer_tokens"], n, r, rng)))
+        rounds = [list(zip(lengths.block(traffic["prompt_tokens"], n, r, order),
+                           lengths.block(traffic["answer_tokens"], n, r, order)))
                   for r in range(ROUNDS)]
-        shares = lengths.unit_block(n, 0, rng)
+        shares = lengths.unit_block(n, 0, order)
+        seat = rng.permutation(n) if order is not rng else range(n)
         self.queues = []
-        for c in range(n):
+        for c in seat:
             queue = [rounds[r][c] for r in range(ROUNDS)]
             p, a = queue[0]
             queue[0] = (max(8, round(p * shares[c])), max(2, round(a * shares[c])))

@@ -1,13 +1,26 @@
 """Token-length distributions of the traffic files. Every seed gets
 the SAME multiset of lengths — the distribution's evenly spaced
-quantiles — in another order: runs with different seeds then do the
-same work, and differ as two runs of one seed do. The balance holds
-block by block (a closed loop's round of clients): a window uses only part of
-what is drawn, and two seeds whose windows held different parts read 3%
-apart on the chip while two runs of one seed agreed to 1% (PR 26).
-This is for lengths in a closed loop, where the order of the work is
-all a seed should change; an open loop's arrival gaps are not to be
-balanced so (its bursts are what it measures; PERF.md section 7)."""
+quantiles — in another order: runs with different seeds then offer the
+same requests. The balance holds block by block (a closed loop's round
+of clients): a window uses only part of what is drawn, and two seeds
+whose windows held different parts read 3% apart on the chip while two
+runs of one seed agreed to 1% (PR 26).
+
+What a seed changes, then, is the ORDER, and what it cannot change is
+the work offered. It can still change the work DONE: where a step's
+cost depends on which requests meet in it (since PR 32 a mixed step
+runs at the narrowest of a few widths that holds its tokens), the
+order decides how many steps run wide, and a window of some thousand
+steps is one sample of that. The two ``prefill-closed`` cells read 2%
+to 5% apart from seed to seed for this reason alone (PR 38, PERF.md
+section 2); their traffic file therefore fixes the order too
+(``generators/closed.py``, the ``order`` key) and leaves the seed the
+seats and the tokens. The decode cells' and the hybrid cell's steps
+cost the same whoever meets in them, and their seeds draw the order.
+
+This is for lengths in a closed loop; an open loop's arrival gaps are
+not to be balanced so (its bursts are what it measures; PERF.md
+section 7)."""
 import numpy as np
 
 

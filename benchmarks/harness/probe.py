@@ -178,14 +178,28 @@ def verdict(config, readings, log):
     ``limit``); at most ``rows_over_allowed`` of them (none, unless the
     file says otherwise and why) may read over the limit."""
     tol = config["tolerance"]
-    which = {"max_share": 2, "rms_share": 3}[tol["metric"]]
-    allowed = tol.get("rows_over_allowed", 0)
     for r in readings:
         log(f"[probe] row {r[0]} pos {r[1]}: max_share {r[2]:.5f} "
             f"rms_share {r[3]:.5f} router_margin {r[4]:.4f} routing {r[5]} "
             f"(limit on {tol['metric']}: {tol['limit']})")
-    over = sum(r[which] > tol["limit"] for r in readings)
+    c = compared(config, readings)
+    worst, limit = c["worst_row_" + tol["metric"]]
+    over, allowed = c["rows_over_limit"]
     log(f"[probe] {len(readings)} rows judged; worst {tol['metric']} "
-        f"{max(r[which] for r in readings):.5f} against the limit "
-        f"{tol['limit']}; rows over it {over} against {allowed} allowed")
+        f"{worst:.5f} against the limit {limit}; rows over it {over} "
+        f"against {allowed} allowed")
     return bool(over <= allowed)
+
+
+def compared(config, readings):
+    """The numbers ``verdict`` compares, each beside its limit, under
+    short names: what a run prints last and keeps in its result."""
+    tol = config["tolerance"]
+    which = {"max_share": 2, "rms_share": 3}[tol["metric"]]
+    return {
+        "rows_judged": len(readings),
+        "worst_row_" + tol["metric"]: [float(max(r[which] for r in readings)),
+                                       tol["limit"]],
+        "rows_over_limit": [int(sum(r[which] > tol["limit"] for r in readings)),
+                            tol.get("rows_over_allowed", 0)],
+    }

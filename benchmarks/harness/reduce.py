@@ -293,3 +293,13 @@ class Context:
         a, b = ((self.tracer.stats_start, self.tracer.stats_stop) if sub
                 else (self.window.stats_open, self.window.stats_close))
         return getattr(b, field) - getattr(a, field)
+
+    def steps_by_width(self):
+        """The window's pipelined mixed steps by the width the engine
+        dispatched them at; None where the server keeps no such count
+        (a program before PR 32)."""
+        a = getattr(self.window.stats_open, "steps_by_width", None)
+        if a is None:
+            return None
+        b = self.window.stats_close.steps_by_width
+        return {w: n - a.get(w, 0) for w, n in sorted(b.items())}

@@ -5,7 +5,8 @@ runs a mixed step's matmuls at the narrowest of a few compiled widths
 that holds its real tokens (``serve/engine.pack_widths``); a step that
 is not packed (a family with its own step, the dense layout) counts
 slots x chunk as its width, so there this reads ``sched.mixed_fill_pct``
-with the decoding rows added. Logs the window's steps by width. None
+with the decoding rows added (``run.py`` logs the window's steps by
+width beside it, in every run). None
 where the server keeps no such counters (a program before PR 32) or
 the window held no mixed step."""
 
@@ -16,8 +17,4 @@ def read(ctx):
     width = ctx.stats_delta("step_tokens_width")
     if not width:
         return None
-    a = ctx.window.stats_open.steps_by_width
-    b = ctx.window.stats_close.steps_by_width
-    ctx.log(f"[pack] mixed steps by width over the window: "
-            f"{ {w: n - a.get(w, 0) for w, n in sorted(b.items())} }")
     return 100.0 * ctx.stats_delta("step_tokens_real") / width

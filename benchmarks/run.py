@@ -166,6 +166,7 @@ def main(argv=None):
     t = phase("probe through the served path", t)
     readings = probe.compare(cell.config, params, seqs, judged)
     correct = probe.verdict(cell.config, readings, log)
+    compared = probe.compared(cell.config, readings)
     t = phase("reference", t)
 
     kind = spec.load_module("generators", cell.traffic["kind"])
@@ -203,6 +204,7 @@ def main(argv=None):
                          peaks=peaks.get(device["kind"]),
                          pool_pages=engine.pager.num_pages, log=log,
                          tracer=tracer)
+    log(f"[window] mixed steps by width: {ctx.steps_by_width()}")
     out = {"correct": bool(correct), "attempted": win.attempted,
            "failed": win.failed, "metrics": {}, "device": device}
     if tracer is not None:
@@ -223,7 +225,10 @@ def main(argv=None):
         if value is not None:
             out["metrics"][m["name"]] = {"value": float(value),
                                          "unit": m["unit"]}
+    out["compared"] = compared  # last: each number beside its limit
     log(f"[run] {time.perf_counter() - T_PROCESS:.1f}s in all")
+    print(f"[compared] correct {bool(correct)}: {json.dumps(compared)} "
+          "(number, limit)", file=sys.stderr, flush=True)
     if args.rehearse:
         log(json.dumps(out)[:2000])
         print("benchmark: rehearsal complete — not a chip run, no result",

@@ -12,13 +12,16 @@ from benchmarks.harness.loop import Window
 from benchmarks.tools import phases
 
 
-def _read(stats, log=lambda _msg: None):
+def _ctx(stats):
     win = Window()
     win.stats_open, win.stats_close = stats
-    ctx = reduce.Context(
+    return reduce.Context(
         window=win, setup_s=0.0, cfg={}, peaks=None, trace=reduce.NoTrace(),
-        engine_serving=types.SimpleNamespace(mixed_chunk=128), log=log)
-    return spec.load_module("per_layer", "step.pack_fill_pct").read(ctx)
+        engine_serving=types.SimpleNamespace(mixed_chunk=128))
+
+
+def _read(stats):
+    return spec.load_module("per_layer", "step.pack_fill_pct").read(_ctx(stats))
 
 
 def _stats(real, width, by):
@@ -27,18 +30,18 @@ def _stats(real, width, by):
 
 
 def test_real_tokens_over_dispatched_width():
-    lines = []
     stats = (_stats(143, 512, {512: 1}),
              _stats(143 + 460 + 300 + 700, 512 + 512 + 512 + 1024,
                     {512: 3, 1024: 1}))
-    assert _read(stats, lines.append) == pytest.approx(100.0 * 1460 / 2048)
-    assert "{512: 2, 1024: 1}" in lines[0]
+    assert _read(stats) == pytest.approx(100.0 * 1460 / 2048)
+    assert _ctx(stats).steps_by_width() == {512: 2, 1024: 1}  # what run.py logs
     assert _read((stats[0], stats[0])) is None    # no mixed step in the window
 
 
 def test_a_program_without_the_counters_reads_nothing():
     old = (types.SimpleNamespace(steps=1), types.SimpleNamespace(steps=2))
     assert _read(old) is None
+    assert _ctx(old).steps_by_width() is None
 
 
 def test_the_metric_is_declared_for_every_cell():
@@ -50,7 +53,8 @@ def test_the_metric_is_declared_for_every_cell():
         source="program_counter", layer="model step", moves="out_tokens_per_s")
     mean = dict(name="step.mixed_mean_ms", unit="ms", better="lower",
                 source="device_trace", layer="model step", moves="ttft_p50_ms")
-    assert bench["per_layer"][-2:] == [entry, mean]  # appended, nothing moved
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert by_name["step.mixed_mean_ms"] == mean  # looked up by name: later PRs append
     for cell in bench["workloads"]:
         cell = spec.Cell(cell["name"])
         assert entry in cell.per_layer
