@@ -194,6 +194,10 @@ class SchedulerStats:
     moe_experts_hit: int = 0
     moe_experts_held: int = 0
     moe_load_max: int = 0
+    # A family whose page pool holds one compressed line a token and
+    # layer (a latent pool, models/deepseek_v3.py): the lines the
+    # pipelined steps wrote, real tokens x layers.
+    latent_lines: int = 0
     steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
 
     def record_step(

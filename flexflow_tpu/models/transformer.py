@@ -528,22 +528,40 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
 
 
 def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
-                       norm_topk: bool = True, scaling: float = 1.0):
+                       norm_topk: bool = True, scaling: float = 1.0,
+                       groups: Tuple[int, int] = (1, 1), eps: float = 1e-6):
     """A sigmoid router with a selection offset (HF ``Lfm2MoeSparseMoeBlock``,
     DeepSeek-V3's rule): scores ``s = sigmoid(h W_r)`` in float32; the
-    ``k`` largest of ``s + select_offset`` are CHOSEN (the offset
+    ``k`` largest of ``t = s + select_offset`` are CHOSEN (the offset
     chooses, it does not weigh; among equals the lower index first);
     the weights are the chosen experts' own ``s``, with ``norm_topk``
-    divided by their sum plus 1e-6, times ``scaling``. h (T, D) ->
-    (experts (T, k) int32, weights (T, k) float32)."""
+    divided by their sum plus ``eps`` (LFM2's 1e-6; DeepSeek-V3's is
+    1e-20), times ``scaling``.
+
+    ``groups`` (n_group, topk_group), DeepSeek-V3's choice by groups:
+    the router's outputs are ``n_group`` equal runs; a group's score is
+    the sum of its two largest ``t``; the ``topk_group`` best groups
+    stay (among equals the lower index first) and the ``k`` experts are
+    chosen among theirs alone. (1, 1): one group, every expert stays,
+    and the function traces as it did before it had the argument.
+
+    h (T, D) -> (experts (T, k) int32, weights (T, k) float32)."""
     s = jax.nn.sigmoid(jnp.matmul(
         h.astype(jnp.float32), _dense_w(w_router, jnp.float32),
         preferred_element_type=jnp.float32))
     choose = s if select_offset is None else s + select_offset.astype(jnp.float32)
+    n_group, topk_group = groups
+    if n_group > 1:
+        T, E = choose.shape
+        grouped = choose.reshape(T, n_group, E // n_group)
+        score = lax.top_k(grouped, 2)[0].sum(axis=-1)            # (T, n_group)
+        _, kept = lax.top_k(score, topk_group)
+        stays = jnp.sum(jax.nn.one_hot(kept, n_group, dtype=jnp.int32), axis=1) > 0
+        choose = jnp.where(stays[:, :, None], grouped, -jnp.inf).reshape(T, E)
     _, experts = lax.top_k(choose, k)
     weights = jnp.take_along_axis(s, experts, axis=-1)
     if norm_topk:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scaling
 
 

@@ -1054,16 +1054,18 @@ class InferenceEngine:
         return sum(int(leaf.nbytes) for leaf in jax.tree.leaves(self.cache))
 
     def kv_bytes_per_line(self) -> float:
-        """K+V bytes one cached token line costs across all layers —
-        quantized pools amortize their per-page f32 scale rows into the
-        per-line figure, so the metric stays an honest HBM cost."""
-        k, v = self.cache["k"], self.cache["v"]
-        lines = k.shape[1] * k.shape[2]  # slots×(len+1) or pages×page_size
-        total = int(k.nbytes) + int(v.nbytes)
-        for name in ("k_scale", "v_scale"):
-            if name in self.cache:
-                total += int(self.cache[name].nbytes)
-        return total / lines
+        """Bytes one cached token line costs across all layers, counted
+        from the family's own pool arrays (its ``PAGE_POOLS``; K and V
+        and their scale rows where it names none: a latent pool holds
+        one compressed line and no K/V heads) — quantized pools
+        amortize their per-page f32 scale rows into the per-line
+        figure, so the metric stays an honest HBM cost."""
+        names = getattr(self.model, "PAGE_POOLS",
+                        ("k", "v", "k_scale", "v_scale"))
+        pools = [self.cache[name] for name in names if name in self.cache]
+        # slots×(len+1) or pages×page_size
+        lines = pools[0].shape[1] * pools[0].shape[2]
+        return sum(int(a.nbytes) for a in pools) / lines
 
     def slot_state_bytes(self) -> int:
         """Bytes of the cache held per SLOT and not per page (a family's

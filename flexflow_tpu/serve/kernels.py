@@ -1662,3 +1662,250 @@ def grouped_down(act, w_down, tile_group, n_active, *, tm: int):
         jax.ShapeDtypeStruct((P, D), jnp.float32),
         pl.BlockSpec((tm, td), lambda j, t, tg, na: (t, j)),
         (D // td, P // tm))
+
+
+# ---------------------------------------------------------------------------
+# Latent paged attention (multi-head latent attention, absorbed form)
+#
+# The pool holds ONE line a token and layer (models/deepseek_v3.py): the
+# normed compressed key/value ``c`` and the roped key ``kr`` every head
+# shares. With the key and value up-projections absorbed into the query
+# and the output, attention over the pool has H query heads on one key
+# ``[c | kr]`` and one value ``c`` a token. The mask is causal by
+# position and nothing else, so the kernels take each row's first
+# position and its count of real queries (consecutive positions, leading
+# columns: what every dispatch builds) and no mask array.
+#
+# The line lies in TWO arrays, both with a minor axis of whole lane
+# tiles: ``c`` (P+1, ps, c) and ``kr`` PAIRED, (P+1, ps/2, 2*r): row j
+# of a page holds the rope keys of its tokens j and j + ps/2 side by
+# side. One array of c + r = 576 values, or a ``kr`` of 64, has a minor
+# axis that is no multiple of 128, and the device then lays the array
+# out with the page on its lanes for the step's line write and back for
+# the kernel: two copies of the pool a layer (tests/test_chip_compile).
+
+#: query columns of one grid step of :func:`mla_paged_attention`: with
+#: H = 128 heads 16 columns are 2048 rows of the matmuls, a 4 MiB
+#: float32 accumulator
+MLA_QUERY_TILE = 16
+
+
+def pair_rope_place(off: jnp.ndarray, page_size: int, width: int):
+    """Where the rope key of the token at offset ``off`` of its page
+    lies in the paired ``kr`` page (ps/2, 2*width): (row (...,), lanes
+    (..., width))."""
+    half = page_size // 2
+    lanes = (off // half)[..., None] * width + jnp.arange(width, dtype=off.dtype)
+    return off % half, lanes
+
+
+def unpair_rope_lines(kr: jnp.ndarray) -> jnp.ndarray:
+    """Paired pages (..., ps/2, 2*r) as lines in token order
+    (..., ps, r)."""
+    r = kr.shape[-1] // 2
+    return jnp.concatenate([kr[..., :r], kr[..., r:]], axis=-2)
+
+
+def mla_paged_attention_xla(
+    q_abs: jnp.ndarray,       # (R, C, H, c) absorbed queries q'
+    q_rope: jnp.ndarray,      # (R, C, H, r) roped queries
+    c_pool: jnp.ndarray,      # (P+1, ps, c) compressed lines
+    kr_pool: jnp.ndarray,     # (P+1, ps/2, 2r) rope keys, paired
+    page_table: jnp.ndarray,  # (R, NP) int32
+    q_start: jnp.ndarray,     # (R,) int32 position of each row's column 0
+    q_len: jnp.ndarray,       # (R,) int32 real queries a row
+    *,
+    scale: float,
+) -> jnp.ndarray:
+    """The XLA twin of :func:`mla_paged_attention` (the CPU path and the
+    kernel's correctness reference): gather each row's lines through
+    the page table, one masked softmax over them. -> (R, C, H, c) in
+    q's dtype; padding columns come out zero."""
+    R, C = q_abs.shape[:2]
+    c = gather_pages(c_pool, page_table)                       # (R, S, c)
+    kr = unpair_rope_lines(jnp.take(kr_pool, page_table, axis=0))
+    kr = kr.reshape(R, -1, kr.shape[-1])                       # (R, S, r)
+    cols = jnp.arange(C, dtype=jnp.int32)
+    real = cols[None] < q_len[:, None]                         # (R, C)
+    q_pos = q_start[:, None] + cols[None]
+    seen = (jnp.arange(c.shape[1], dtype=jnp.int32)[None, None]
+            <= q_pos[..., None]) & real[..., None]             # (R, C, S)
+    scores = (jnp.einsum("rchw,rsw->rhcs", q_abs, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("rchw,rsw->rhcs", q_rope, kr,
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(seen[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q_abs.dtype)
+    out = jnp.einsum("rhcs,rsv->rchv", probs, c,
+                     preferred_element_type=jnp.float32)
+    return jnp.where(real[..., None, None], out, 0.0).astype(q_abs.dtype)
+
+
+def mla_paged_attention(
+    q_abs: jnp.ndarray,       # (R, C, H, c) absorbed queries q'
+    q_rope: jnp.ndarray,      # (R, C, H, r) roped queries
+    c_pool: jnp.ndarray,      # (P+1, ps, c) compressed lines
+    kr_pool: jnp.ndarray,     # (P+1, ps/2, 2r) rope keys, paired
+    page_table: jnp.ndarray,  # (R, NP) int32
+    q_start: jnp.ndarray,     # (R,) int32 position of each row's column 0
+    q_len: jnp.ndarray,       # (R,) int32 real queries a row
+    *,
+    scale: float,
+    row_offset=None,          # int32 scalar: pool row of table entry 0
+) -> jnp.ndarray:
+    """Latent paged attention, ``ff_mla_paged_c<C>``: grid (row, query
+    tile, logical page); a step loads ONE page of lines through the
+    page table (``row_offset``: the pools are every layer's pages, see
+    :func:`_ragged_paged_attention`) and attends a tile's queries, all
+    H heads of :data:`MLA_QUERY_TILE` columns as the rows of its
+    matmuls in the pools' dtype: scores ``q' c^T + q_rope kr^T``,
+    values over ``c``, online softmax in float32 between them.
+
+    Work follows the real queries: a page past a tile's last real
+    query is neither fetched (the index map repeats the last page the
+    tile needs, and a block whose index repeats is not fetched again)
+    nor computed; a tile with no real query fetches nothing and writes
+    zeros; a tile whose one real query is its first column (a decode
+    row of a mixed step) runs at one column's rows; the causal mask is
+    computed from the positions and only on the pages it cuts.
+    -> (R, C, H, c) in q's dtype, padding columns zero."""
+    R, C, H, V = q_abs.shape
+    dr = q_rope.shape[-1]
+    ps = c_pool.shape[1]
+    NP = page_table.shape[1]
+    TC = min(C, MLA_QUERY_TILE)
+    if C % TC:
+        raise ValueError(f"chunk {C} is no multiple of the query tile {TC}")
+    prefetch = [page_table.astype(jnp.int32), q_start.astype(jnp.int32),
+                q_len.astype(jnp.int32)]
+    if row_offset is not None:
+        prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
+
+    def live_tile(r, t, count):
+        # the last tile of row r that holds a real query, or 0
+        return jnp.minimum(t, jnp.maximum(count[r] - 1, 0) // TC)
+
+    def q_block(r, t, p, pt, start, count, *base):
+        return (r, live_tile(r, t, count), 0, 0)
+
+    def page_block(r, t, p, pt, start, count, *base):
+        # the page of the tile's last real query, once p has passed it
+        t = live_tile(r, t, count)
+        last = start[r] + jnp.minimum(count[r], (t + 1) * TC) - 1
+        row = pt[r, jnp.minimum(p, jnp.clip(last // ps, 0, NP - 1))]
+        if base:
+            row = row + base[0][0]
+        return (row, 0, 0)
+
+    def kernel(pt_ref, start_ref, count_ref, *refs):
+        qa_ref, qr_ref, c_ref, kr_ref, out_ref, acc, m_scr, l_scr = refs[-8:]
+        r, t, p = (pl.program_id(i) for i in range(3))
+        start, count = start_ref[r], count_ref[r]
+        cols = jnp.clip(count - t * TC, 0, TC)     # real queries of the tile
+        first = start + t * TC                      # position of its column 0
+        last = first + cols - 1
+        nt = (((1,), (1,)), ((), ()))               # x y^T
+
+        def step(n):
+            # the tile's first n columns: n * H leading rows of the
+            # accumulators
+            M = n * H
+
+            @pl.when(p == 0)
+            def _():
+                acc[:M] = jnp.zeros((M, V), jnp.float32)
+                m_scr[:M] = jnp.full((M, 1), NEG_INF, jnp.float32)
+                l_scr[:M] = jnp.zeros((M, 1), jnp.float32)
+
+            def attend(cut):
+                c = c_ref[0]                                      # (ps, V)
+                kr = unpair_rope_lines(kr_ref[0])                 # (ps, dr)
+                s = (jax.lax.dot_general(
+                        qa_ref[0, :n].reshape(M, V), c, nt,
+                        preferred_element_type=jnp.float32)
+                     + jax.lax.dot_general(
+                        qr_ref[0, :n].reshape(M, dr), kr, nt,
+                        preferred_element_type=jnp.float32)) * scale
+                if cut:
+                    # at the scores' own shape: Mosaic broadcasts no
+                    # boolean along sublanes
+                    key = p * ps + jax.lax.broadcasted_iota(
+                        jnp.int32, (n, H, ps), 2)
+                    col = jax.lax.broadcasted_iota(jnp.int32, (n, H, ps), 0)
+                    seen = (key <= first + col) & (col < cols)
+                    s = jnp.where(seen, s.reshape(n, H, ps),
+                                  NEG_INF).reshape(M, ps)
+                m_old = m_scr[:M]
+                m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+                prob = jnp.exp(s - m_new)
+                if cut:  # a row that sees no key of this page: exp(0)
+                    prob = jnp.where(seen, prob.reshape(n, H, ps),
+                                     0.0).reshape(M, ps)
+                corr = jnp.exp(m_old - m_new)
+                l_scr[:M] = l_scr[:M] * corr + prob.sum(axis=1, keepdims=True)
+                acc[:M] = acc[:M] * corr + jnp.dot(
+                    prob.astype(c.dtype), c, preferred_element_type=jnp.float32)
+                m_scr[:M] = m_new
+
+            # every query of the n sees every key of the page, or the
+            # mask cuts it
+            whole = (p * ps + ps - 1 <= first) & (cols >= n)
+            pl.when((p * ps <= last) & whole)(lambda: attend(False))
+            pl.when((p * ps <= last) & ~whole)(lambda: attend(True))
+
+            @pl.when(p == NP - 1)
+            def _():
+                o = acc[:M] / jnp.maximum(l_scr[:M], 1e-20)
+                out_ref[0, :n] = o.reshape(n, H, V).astype(out_ref.dtype)
+                if n < TC:
+                    out_ref[0, n:] = jnp.zeros((TC - n, H, V), out_ref.dtype)
+
+        if TC > 1:
+            pl.when(cols > 1)(lambda: step(TC))
+        pl.when(cols == 1)(lambda: step(1))
+
+        @pl.when((cols == 0) & (p == NP - 1))
+        def _():
+            out_ref[0] = jnp.zeros((TC, H, V), out_ref.dtype)
+
+    in_specs = [pl.BlockSpec((1, TC, H, V), q_block),
+                pl.BlockSpec((1, TC, H, dr), q_block),
+                pl.BlockSpec((1, ps, V), page_block),
+                pl.BlockSpec((1, ps // 2, 2 * dr), page_block)]
+    out_spec = pl.BlockSpec((1, TC, H, V), lambda r, t, p, *_: (r, t, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((R, C, H, V), q_abs.dtype)
+    M = TC * H
+    scratch = [pltpu.VMEM((M, V), jnp.float32),
+               pltpu.VMEM((M, 1), jnp.float32),
+               pltpu.VMEM((M, 1), jnp.float32)]
+    # blocks double-buffered, the scratch, and the body's float32
+    # intermediates: the scores and their exponentials (M, ps), the
+    # values' product (M, V) and the rescaled accumulator
+    operands = (q_abs, q_rope, c_pool, kr_pool)
+    need = 2 * sum(_vmem_bytes(spec.block_shape, a.dtype) for spec, a in
+                   zip(in_specs + [out_spec], operands + (out_shape,)))
+    need += sum(_vmem_bytes(s.shape, s.dtype) for s in scratch)
+    need += 6 * _vmem_bytes((M, ps), jnp.float32)
+    need += 2 * _vmem_bytes((M, V), jnp.float32)
+    if need > _VMEM_SCOPE_CEILING:
+        raise ValueError(
+            f"latent paged attention at {TC} columns x {H} heads needs "
+            f"{need >> 20} MiB of VMEM a grid step (ceiling "
+            f"{_VMEM_SCOPE_CEILING >> 20} MiB)")
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(R, C // TC, NP),
+            in_specs=in_specs,
+            out_specs=out_spec,
+            scratch_shapes=scratch,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=max(need, _VMEM_SCOPE_DEFAULT),
+        ),
+        name=f"ff_mla_paged_c{C}",
+        interpret=_interpret(),
+    )(*prefetch, *operands)

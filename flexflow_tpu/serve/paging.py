@@ -10,10 +10,17 @@ tokens rounded up to the page size — not to the worst-case sequence
 length, which is what lets serving run the reference's 64 request slots
 on one chip (VERDICT.md round 5, missing #3).
 
-HBM accounting: one page costs ``2 · page_size · KV · ceil(dk / pack)
-· itemsize(cache_dtype)`` bytes per layer (K and V; ``pack`` is the
+HBM accounting: a page costs what the family's own pool arrays hold of
+it (``init_paged_kv_cache``'s shapes; ``InferenceEngine.
+kv_bytes_per_line`` reads them, never heads x head size). For a K/V
+pool that is ``2 · page_size · KV · ceil(dk / pack) ·
+itemsize(cache_dtype)`` bytes per layer (K and V; ``pack`` is the
 codes-per-element factor of the storage layout — 1 for fp and int8,
-2 for int4's packed nibbles), and ``ServingConfig.max_cached_tokens``
+2 for int4's packed nibbles); for a LATENT pool (models/deepseek_v3.py:
+one compressed line a token instead of K and V a head) ``page_size ·
+(kv_lora_rank + qk_rope_head_dim) · itemsize``, 1152 B a token and
+layer in bf16 at the published widths where 8 K/V heads of 128 are
+4096. ``ServingConfig.max_cached_tokens``
 prices the pool in the pack=1 full-precision units — it is an HBM
 budget expressed as full-precision tokens. With
 ``ServingConfig.kv_quant`` (serve/kv_quant.py) pages store quantized

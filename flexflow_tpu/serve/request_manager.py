@@ -165,6 +165,10 @@ class RequestManager:
         self._slot_state = bool(getattr(engine.model, "SLOT_STATE", ()))
         if self._slot_state:
             self.stats.slot_state_bytes = engine.slot_state_bytes()
+        # layers of a latent page pool (SchedulerStats.latent_lines)
+        self._latent_layers = (
+            engine.cache["latent"].shape[0]
+            if "latent" in getattr(engine.model, "PAGE_POOLS", ()) else 0)
         self._log = get_logger("serve")
         # Observability (flexflow_tpu/obs): request-lifecycle tracing +
         # failure flight recorder. Disabled by default — every EVENT
@@ -1034,6 +1038,8 @@ class RequestManager:
             self.stats.note_rows(positions[:, 0], real,
                                  getattr(self.engine.cfg, "dense_len", None))
         self._note_attn_steps(positions[:, 0], real, 1)
+        if self._latent_layers:
+            self.stats.latent_lines += int(real.sum()) * self._latent_layers
         tr = self.tracer
         if tr.enabled:
             tr.event("decode_step", rows=len(decoding))
@@ -1151,6 +1157,7 @@ class RequestManager:
         self._note_attn_steps(bc.positions[:, 0], bc.qlens, C)
         real = int(bc.qlens.sum())
         self.stats.note_step_tokens(real, eng.pack_width(real, C))
+        self.stats.latent_lines += real * self._latent_layers
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,

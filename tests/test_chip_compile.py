@@ -647,3 +647,79 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
     assert f"%ff_moe_grouped_glu_t{tm}" in text
     assert f"%ff_moe_grouped_down_t{tm}" in text
     assert limits == [48 << 20, 48 << 20]
+
+
+# --- latent attention over a compressed paged line (DeepSeek-V3) ------------
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_mla_paged_kernel_compiles(chip, C):
+    """serve/kernels.mla_paged_attention at the published widths (128
+    heads on one line of 512 + 64 a token) and the benchmark cell's
+    shapes (4 slots of 82 logical pages of 128, five layers' pool as
+    one view with a row offset): Mosaic takes the paired rope keys'
+    lane halves, the 2048-row tile's accumulators and the page index
+    map that stops at a tile's last real query."""
+    slots, pages, layers = 4, 82, 5
+    rows = layers * (slots * 81 + 1)
+    fn = functools.partial(kernels.mla_paged_attention, scale=0.1,
+                           row_offset=jnp.int32(325))
+    _, text = _compile(
+        fn, chip((slots, C, 128, 512), jnp.bfloat16),
+        chip((slots, C, 128, 64), jnp.bfloat16),
+        chip((rows, PAGE, 512), jnp.bfloat16),
+        chip((rows, PAGE // 2, 128), jnp.bfloat16),
+        chip((slots, pages), jnp.int32),
+        chip((slots,), jnp.int32), chip((slots,), jnp.int32))
+    assert f"%ff_mla_paged_c{C}" in text
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 128), (128, None)])
+def test_deepseek_v3_step_compiles_in_place(chip, C, pack):
+    """models/deepseek_v3.py at published widths, the benchmark
+    configuration's cut (a dense layer and two of its sparse layers, 16
+    of 256 experts held, an eighth of the vocabulary), the cell's 4
+    slots: the latent kernel is in the program by name at the chunk's
+    width and is its FIRST kernel call, the grouped expert matmuls
+    follow, and the latent pool is the loop's carry in place: no copy
+    of either of its arrays (one array of 576 values a line is re-laid
+    with the page on its lanes for the line write and back for the
+    kernel), of an expert stack or of a layer of one."""
+    from flexflow_tpu.models import deepseek_v3 as fam
+
+    cfg = fam.config(num_hidden_layers=3, first_k_dense_replace=1,
+                     experts_held=(0, 16), vocab_size=16160,
+                     dtype=jnp.bfloat16)
+    slots, pages, cache_len = 4, 82, 10432
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * 81, PAGE, jnp.bfloat16)), chip)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", entry)
+    assert f"%ff_mla_paged_c{C}" in text
+    assert f"[{slots},{C},128,512]" in calls[0], calls[:2]
+    tokens = pack or slots * C
+    tm, rows = kernels.grouped_tile(8 * tokens, 16), _pair_rows(8 * tokens, 16)
+    assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},2048\]", text)
+    assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},7168\]", text)
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["latent"], cache["latent_rope"], experts,
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.5e9, temp
