@@ -502,14 +502,12 @@ def main(argv=None):
                         "request completion incl. generated tokens "
                         "(complete) or as soon as prefill ends (prefill)")
     s.add_argument("--fused-decode", default=None,
-                   help="megakernel decode-step fusions, comma-separated "
-                        "(rope_kv_write,sampling): fold RoPE + the KV "
-                        "page write into the ragged paged Pallas "
-                        "kernel (requires --kv-layout paged; active "
-                        "with --pallas) and/or the greedy/top-k "
-                        "sampling epilogue into the step program; "
-                        "each fusion is bitwise-identical to the "
-                        "unfused step")
+                   help="decode-step fusions, comma-separated "
+                        "(rope_kv_write): fold RoPE + the KV page "
+                        "write into the ragged paged Pallas kernel "
+                        "(requires --kv-layout paged; active with "
+                        "--pallas); bitwise-identical to the unfused "
+                        "step")
     s.add_argument("--replicas", type=int, default=1,
                    help="cluster serving (serve/cluster/): drive this "
                         "many engine replicas — each its own mesh and "

@@ -198,6 +198,11 @@ class SchedulerStats:
     # layer (a latent pool, models/deepseek_v3.py): the lines the
     # pipelined steps wrote, real tokens x layers.
     latent_lines: int = 0
+    # The sampling head of the pipelined steps (note_head): the mixed
+    # and decode dispatches, and those whose batch was all greedy and
+    # so took the argmax head (serve/sampling.choose_sample_mode).
+    head_steps: int = 0
+    head_greedy_steps: int = 0
     steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
 
     def record_step(
@@ -223,6 +228,12 @@ class SchedulerStats:
         self.decode_tokens += int(decode_tokens)
         if num_slots > 0:
             self.occupancy_sum += active_slots / num_slots
+
+    def note_head(self, mode: str) -> None:
+        """Count one pipelined step by the sampling head it was
+        dispatched with (``InferenceEngine.step_head``)."""
+        self.head_steps += 1
+        self.head_greedy_steps += mode == "greedy"
 
     def note_rows(self, first, count, dense_len: Optional[int]) -> None:
         """Count one step's rows for a family with per-slot state:
@@ -385,6 +396,8 @@ class SchedulerStats:
             "step_tokens_real": self.step_tokens_real,
             "step_tokens_width": self.step_tokens_width,
             "pack_fill": round(self.pack_fill, 4),
+            "head_steps": self.head_steps,
+            "head_greedy_steps": self.head_greedy_steps,
             "steps_by_width": dict(sorted(self.steps_by_width.items())),
         }
 
@@ -412,6 +425,7 @@ class SchedulerStats:
             f"dstep_ms={s['decode_step_ms_p50']:.2f}/"
             f"{s['decode_step_ms_p99']:.2f} "
             f"compiles={s['compiles']} retraces={s['retraces']} "
+            f"greedy_head={s['head_greedy_steps']}/{s['head_steps']} "
             f"pack={s['step_tokens_real']}/{s['step_tokens_width']} by width "
             + (",".join(f"{w}:{n}" for w, n in s["steps_by_width"].items())
                or "-")

@@ -785,9 +785,8 @@ def serve_debug_activations(
 #: decode-step fusions this family's serving step supports
 #: (ServingConfig.fused_decode; the engine validates requests against
 #: this). "rope_kv_write": serve_step_paged folds RoPE + the KV page
-#: write into the ragged paged Pallas kernel (the megakernel decode
-#: step). The "sampling" epilogue fusion is model-agnostic — it lives
-#: in the engine's step program — so it is not listed here.
+#: write into the ragged paged Pallas kernel. (The sampling head is no
+#: fusion: the engine's step program holds the one its batch needs.)
 FUSED_DECODE = ("rope_kv_write",)
 
 

@@ -1118,8 +1118,8 @@ def reorder_slots(
 #: learned-position families, just the quantizing KV page write) into
 #: the ragged paged Pallas kernel; ALiBi batches keep the unfused
 #: path at run time because the additive bias already excludes the
-#: Pallas kernel. The "sampling" epilogue fusion is model-agnostic —
-#: it lives in the engine's step program — so it is not listed here.
+#: Pallas kernel. (The sampling head is no fusion: the engine's step
+#: program holds the one its batch needs.)
 FUSED_DECODE = ("rope_kv_write",)
 
 #: serve_step_paged takes a PACKED token axis (its ``pack``): the engine

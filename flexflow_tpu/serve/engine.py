@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.mesh import DATA_AXIS, MachineSpec, set_mesh as _set_mesh
 from ..obs.tracer import NULL_TRACER
 from .batch_config import BatchConfig
+from .sampling import choose_sample_mode, sample_tokens
 
 
 @dataclasses.dataclass
@@ -148,10 +149,8 @@ class ServingConfig:
     # transcript); "prefill" — the prompt alone, as soon as its last
     # chunk is dispatched (concurrent same-prompt requests hit sooner).
     cache_policy: str = "complete"
-    # Megakernel decode step (ROADMAP item 2, MPK-style): which
-    # decode-step fusions to enable, each independently toggleable and
-    # bitwise-identical to its unfused counterpart
-    # (tests/test_fused_decode.py).
+    # Decode-step fusions to enable, each bitwise-identical to its
+    # unfused counterpart (tests/test_fused_decode.py). One is left:
     #   "rope_kv_write" — RoPE on Q/K and the (optionally
     #     int8-quantizing) KV page write fold INSIDE the ragged paged
     #     Pallas kernel (serve/kernels.fused_rope_paged_attention), so
@@ -160,14 +159,9 @@ class ServingConfig:
     #     advertise support via their FUSED_DECODE tuple. With
     #     kernels="xla" the flag is a no-op — the unfused XLA step IS
     #     the CPU-parity fallback.
-    #   "sampling" — the greedy/top-k sampling epilogue fuses into the
-    #     step program with a mode-specialized head
-    #     (serve/sampling.choose_sample_mode): greedy-only decode
-    #     batches skip the (R, V) sorts entirely, and the sync path
-    #     drops from two dispatched programs per step (step + host-side
-    #     sample) to one (engine.run_sampled).
-    # Off by default; () compiles exactly the pre-fusion step programs
-    # under exactly the pre-fusion step keys.
+    # Off by default. (The sampling head is no fusion to ask for: every
+    # step samples on the device with the head its batch's decode-head
+    # arrays need, serve/sampling.choose_sample_mode.)
     fused_decode: Tuple[str, ...] = ()
     # Cluster serving (serve/cluster/): one process drives this many
     # engine replicas — each its own mesh and KV pool — behind a
@@ -690,32 +684,35 @@ def program_name(key: Any) -> str:
     chunk — ``ff_step_c1`` the pipelined decode step, ``ff_step_c128``
     the pipelined mixed step at ``mixed_chunk=128``, ``ff_step_c128_t512``
     that step with its token axis packed at 512 places
-    (:func:`pack_widths`)."""
+    (:func:`pack_widths`). Those are the programs with the argmax head,
+    the one an all-greedy batch takes (serve/sampling.py): the greedy
+    head is the unmarked one, and a program whose head samples carries
+    the suffix that names its extra work, ``_sample``, ``_topk<cap>``
+    or ``_full`` (``ff_step_c1_topk8``)."""
     if isinstance(key, str):  # commit, copy_page, reorder, ...
         return f"ff_{key}"
 
     def flags(**on):
         return "".join(f"_{word}" for word, yes in on.items() if yes)
 
-    def head(mode, cap):  # the sampling head a fused step compiled in
-        return f"_{mode}{cap or ''}" if mode else ""
+    def head(mode, cap):  # the sampling head the step compiled in
+        return "" if mode == "greedy" else f"_{mode}{cap or ''}"
 
     kind, *rest = key
     if kind == "mixed_packed":  # returns its logits to every caller
-        chunk, width, *mode_cap = rest
-        return (f"ff_step_c{chunk}_t{width}"
-                + (head(*mode_cap) if mode_cap else ""))
+        chunk, width, mode, cap = rest
+        return f"ff_step_c{chunk}_t{width}" + head(mode, cap)
     if kind == "mixed_fused":
-        chunk, with_logits, *mode_cap = rest
+        chunk, with_logits, mode, cap = rest
         return (f"ff_step_c{chunk}" + flags(logits=with_logits)
-                + (head(*mode_cap) if mode_cap else ""))
+                + head(mode, cap))
     if kind == "step_sampled":
         chunk, with_mask, mode, cap, with_logits = rest
         return (f"ff_step_sampled_c{chunk}"
                 + flags(logits=with_logits, mask=with_mask) + head(mode, cap))
     if kind == "speculate":
         return "ff_speculate_" + "_".join(str(p) for p in rest)
-    chunk, all_logits, with_mask = key  # the sync step (_get_step)
+    chunk, all_logits, with_mask = key  # the two-dispatch sync step (_get_step)
     return f"ff_step_sync_c{chunk}" + flags(logits=all_logits, mask=with_mask)
 
 
@@ -752,8 +749,10 @@ class InferenceEngine:
         self.mesh = mesh or MachineSpec().make_mesh(jax.devices()[:1])
         self.params = params
         # Key: (chunk, all_logits, with_mask) for plain steps, or a
-        # tagged tuple for fused variants (("mixed_fused", chunk, ...);
-        # ("mixed_packed", chunk, width, ...) a rung of its ladder).
+        # tagged tuple for the steps that sample on the device
+        # (("mixed_fused", chunk, with_logits, mode, cap);
+        # ("mixed_packed", chunk, width, mode, cap) a rung of its
+        # ladder; mode and cap name the head, serve/sampling.py).
         self._steps: Dict[Any, Callable] = {}
         # serving ladders (chunk, sampling head) whose every rung is
         # compiled
@@ -820,18 +819,18 @@ class InferenceEngine:
         # is locally addressable and the plain table gather IS the ring
         # result (bitwise the CP-off step — serve/kernels.py)
         self.cp_ring = self.cp_shards > 1 and seq_deg > 1
-        # Megakernel decode step: validate the fusion set up front so a
-        # bad toggle fails at engine construction, not mid-serve.
+        # Decode-step fusions: validate the set up front so a bad
+        # toggle fails at engine construction, not mid-serve.
         fused = self.serving.fused_decode
         if isinstance(fused, str):
             fused = tuple(s.strip() for s in fused.split(",") if s.strip())
             self.serving = dataclasses.replace(self.serving,
                                                fused_decode=fused)
         for name in fused:
-            if name not in ("rope_kv_write", "sampling"):
+            if name != "rope_kv_write":
                 raise ValueError(
                     f"unknown fused_decode entry {name!r} (expected "
-                    "'rope_kv_write' and/or 'sampling')"
+                    "'rope_kv_write')"
                 )
         if "rope_kv_write" in fused:
             if not self.paged:
@@ -851,9 +850,13 @@ class InferenceEngine:
         # Dispatch telemetry: device programs this
         # engine's serving loop issued — every jitted step dispatched
         # here plus host-side decode heads the scheduler counts via
-        # count_dispatch. The fused-epilogue claim ("strictly fewer
-        # programs per step") is measured against this counter.
+        # count_dispatch (a step that samples on the device is one
+        # program, the two-dispatch sync step two).
         self.dispatch_count = 0
+        # the sampling head (mode, topk_cap) the newest mixed / decode
+        # step was dispatched with: what its batch's decode-head arrays
+        # chose (run_mixed); the scheduler counts its steps by it
+        self.step_head: Tuple[str, int] = ("greedy", 0)
         # A family whose step returns counters of its own beside its
         # cache (``step_counts(cfg)``: name -> shape of int32 entries
         # of the cache a step returns that are no state, a sparse
@@ -1255,9 +1258,9 @@ class InferenceEngine:
         return next((w for w in self.pack_ladder(chunk) if w >= real),
                     self.num_slots * chunk)
 
-    def _get_mixed_step(self, chunk: int, with_logits: bool = False,
-                        sample_mode: Optional[str] = None,
-                        topk_cap: int = 0, pack: Optional[int] = None):
+    def _get_mixed_step(self, chunk: int, with_logits: bool,
+                        sample_mode: str, topk_cap: int,
+                        pack: Optional[int] = None):
         """Fused MIXED step — the continuous-batching workhorse: token
         select (device feedback vs host) for column 0 → serve_step over
         (R, chunk) ragged rows (decode rows use one column, prefill rows
@@ -1273,12 +1276,11 @@ class InferenceEngine:
         (parity tests/debug only — the serving path skips the extra
         output).
 
-        ``sample_mode``/``topk_cap`` (the "sampling" decode fusion,
-        serve/sampling.py): a mode-specialized sampling head replaces
-        the full-sort reference head — greedy-only decode batches skip
-        the (R, V) sorts entirely. None keeps the pre-fusion program
-        AND its pre-fusion step key; a set mode tags the key, so each
-        head the workload actually needs compiles exactly once.
+        ``sample_mode``/``topk_cap`` (serve/sampling.py): the head the
+        program samples with, the one its caller's batch chose
+        (:meth:`run_mixed`) — an all-greedy batch's program holds an
+        argmax and no (R, V) sort. They tag the key, so each head the
+        traffic actually needs compiles exactly once.
 
         ``pack`` (a rung of :meth:`pack_ladder`): the same step with
         the model's token axis packed at that width, under a key and a
@@ -1286,17 +1288,13 @@ class InferenceEngine:
         its logits to every caller (they exist on the device anyway,
         for the sampling head), so a caller that wants them runs the
         very program the server runs, and a ladder is compiled once."""
-        key_id = ("mixed_fused", chunk, with_logits)
+        key_id = ("mixed_fused", chunk, with_logits, sample_mode, topk_cap)
         if pack is not None:
-            key_id, with_logits = ("mixed_packed", chunk, pack), True
-        if sample_mode is not None:
-            key_id = key_id + (sample_mode, topk_cap)
+            key_id = ("mixed_packed", chunk, pack, sample_mode, topk_cap)
+            with_logits = True
         if key_id not in self._steps:
-            from .sampling import sample_tokens
-
             fn = self._serve_step_fn(all_logits=False, pack=pack, counts=True)
             paged = self.paged
-            mode = sample_mode or "full"
 
             def step(params, cache, last_tokens, host_tokens, use_last,
                      positions, logits_idx, key, greedy, temperature,
@@ -1313,7 +1311,7 @@ class InferenceEngine:
                 toks = sample_tokens(
                     logits, key,
                     greedy=greedy, temperature=temperature, topp=topp,
-                    topk_arr=topk, mode=mode, topk_cap=topk_cap,
+                    topk_arr=topk, mode=sample_mode, topk_cap=topk_cap,
                 )
                 out = (toks, logits) if with_logits else (toks,)
                 if counts:  # one array to fetch: the tokens, then the counters
@@ -1334,10 +1332,14 @@ class InferenceEngine:
         them up to ``dispatch_ahead`` steps later. ``with_logits``
         additionally returns the (R, V) logits (device array).
 
-        The step runs at :meth:`pack_width` of the real tokens the
-        ``positions`` show. Every serving rung of a ladder is lowered
-        and compiled when its first is asked for, so a later step that
-        lands on another rung compiles nothing."""
+        The step samples with the head its batch asks for
+        (``choose_sample_mode`` over the host ``greedy`` / ``topp`` /
+        ``topk`` arrays, on every dispatch; :attr:`step_head` keeps the
+        choice) and runs at :meth:`pack_width` of the real tokens the
+        ``positions`` show. Every serving rung of a (chunk, head)
+        ladder is lowered and compiled when its first is asked for, so
+        a later step that lands on another rung compiles nothing; a
+        head no batch has asked for is never compiled."""
         kw = {}
         if self.paged:
             kw["page_table"] = self.page_table_device()
@@ -1350,13 +1352,9 @@ class InferenceEngine:
             width = self.pack_width(int(real_query_lengths(
                 np.asarray(positions), self.scratch_pos).sum()), chunk)
             pack = width if width <= ladder[-1] else None
-        mode, cap = None, 0
-        if "sampling" in self.serving.fused_decode:
-            from .sampling import choose_sample_mode
-
-            mode, cap = choose_sample_mode(
-                greedy, topp, topk, self.cfg.vocab_size
-            )
+        mode, cap = self.step_head = choose_sample_mode(
+            greedy, topp, topk, self.cfg.vocab_size
+        )
         # every jit-call argument converts with a PINNED dtype: the
         # abstract signature — and so the compile-cache key — must not
         # follow whatever host types the scheduler happened to produce
@@ -1395,8 +1393,8 @@ class InferenceEngine:
         if self._step_counts:
             self.step_fetch = rest.pop()
         self._poison_donated(
-            donated, ("mixed_packed", chunk, pack) if pack
-            else ("mixed_fused", chunk, with_logits))
+            donated, ("mixed_packed", chunk, pack, mode, cap) if pack
+            else ("mixed_fused", chunk, with_logits, mode, cap))
         return (toks, *rest) if with_logits else toks
 
     def split_fetch(self, fetched: np.ndarray):
@@ -1425,18 +1423,16 @@ class InferenceEngine:
     def _get_step_sampled(self, chunk: int, with_mask: bool,
                           sample_mode: str, topk_cap: int,
                           with_logits: bool = False):
-        """The "sampling"-fused SYNC step (megakernel decode epilogue):
-        serve_step plus the mode-specialized decode head in ONE
-        compiled program, cache donated — where the unfused sync path
-        dispatches two programs per step (the step, then the host-side
-        ``sample_tokens``), this dispatches one and keeps the logits on
+        """The SYNC step that samples on the device: serve_step plus
+        the decode head its batch chose in ONE compiled program, cache
+        donated — where the two-dispatch sync step (:meth:`run`, then
+        the scheduler's host-side ``sample_tokens``) dispatches two
+        programs per step, this dispatches one and keeps the logits on
         device. ``with_logits`` additionally returns them (parity
         tests; the serving path skips the extra output)."""
         key_id = ("step_sampled", chunk, with_mask, sample_mode, topk_cap,
                   with_logits)
         if key_id not in self._steps:
-            from .sampling import sample_tokens
-
             fn = self._serve_step_fn(all_logits=False)
             paged = self.paged
 
@@ -1464,14 +1460,13 @@ class InferenceEngine:
 
     def run_sampled(self, bc: BatchConfig, key, greedy, temperature, topp,
                     topk, with_logits: bool = False):
-        """Dispatch one step WITH the fused sampling epilogue (the
-        ``fused_decode=("sampling",)`` sync path): one program computes
-        the step's logits at each row's ``logits_idx`` AND samples
-        them, so the (R, V) logits never reach the host. Returns the
-        sampled tokens as a device array (R,) — plus the logits when
-        ``with_logits``."""
-        from .sampling import choose_sample_mode
-
+        """Dispatch one SYNC step that samples on the device (the sync
+        scheduler's step wherever the manager's
+        ``supports_fused_sampling`` holds): one program computes the
+        step's logits at each row's ``logits_idx`` AND samples them
+        with the head the batch chose, so the (R, V) logits never reach
+        the host. Returns the sampled tokens as a device array (R,) —
+        plus the logits when ``with_logits``."""
         if self.serving.inference_debugging:
             with _set_mesh(self.mesh):
                 self._dump_debug(bc)

@@ -102,7 +102,7 @@ class RequestManager:
     # the _cache_attach/_cache_insert hooks — the SSM pools page
     # independently but share the token offset math.
     supports_prefix_cache = True
-    # The "sampling" decode fusion's sync path (engine.run_sampled)
+    # The sync step that samples on the device (engine.run_sampled)
     # bypasses the _run_batch hook; managers that override _run_batch
     # to keep a second engine in sync (SpecInfer) opt out and keep the
     # two-dispatch step + host sample.
@@ -905,7 +905,7 @@ class RequestManager:
             topk_cap=cap,
         )
         # the host-side decode head is its own dispatched program — the
-        # figure the fused sampling epilogue's one-program step beats
+        # figure the one-program step that samples on the device beats
         self.engine.count_dispatch("host_sample")
         with self.tracer.span("step.flush_wait"):
             # ffcheck: disable=FF107 -- blocking sync-scheduler decode head: this path trades latency for simplicity by design (the pipelined path samples on device)
@@ -1033,6 +1033,7 @@ class RequestManager:
             "decode", active_slots=len(decoding), num_slots=R,
             decode_tokens=len(decoding),
         )
+        self.stats.note_head(self.engine.step_head[0])
         real = positions[:, 0] != scratch
         if self._slot_state:
             self.stats.note_rows(positions[:, 0], real,
@@ -1151,6 +1152,7 @@ class RequestManager:
             prefill_tokens=spent, decode_tokens=len(decoding),
             budget=C * max(1, len(prefilling)),
         )
+        self.stats.note_head(eng.step_head[0])
         if self._slot_state:
             self.stats.note_rows(bc.positions[:, 0], bc.qlens,
                                  getattr(eng.cfg, "dense_len", None))
@@ -1358,12 +1360,11 @@ class RequestManager:
             and not r.profile.first_token_time
         ]
         t0 = time.perf_counter()
-        fused = self.engine.serving.fused_decode
-        if "sampling" in fused and self.supports_fused_sampling:
-            # fused sampling epilogue: ONE dispatched program per sync
-            # step (step + on-device decode head) instead of two — the
-            # (R, V) logits never reach the host. Same single key split
-            # per step as the unfused path, so generations are bitwise
+        if self.supports_fused_sampling:
+            # ONE dispatched program per sync step (step + on-device
+            # decode head) instead of two — the (R, V) logits never
+            # reach the host. Same single key split per step as the
+            # two-dispatch path below, so generations are bitwise
             # identical.
             with self.tracer.span("step.build"):
                 greedy, temp, topp, topk = self._decode_head_params(

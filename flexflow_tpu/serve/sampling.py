@@ -7,12 +7,14 @@ TPU-native equivalents of the reference decode operators ``argmax``,
 per-request parameters as arrays, so mixed greedy/sampling batches run in
 a single program (the reference dispatches per-model decode-head ops).
 
-Mode-specialized heads (the megakernel decode step's sampling
-epilogue): the general path pays one full ``(R, V)`` descending sort —
-shared by the top-k and top-p filters — every step, even when every
-row is greedy (today's common decode batch). ``mode`` specializes the
-compiled head to what the batch actually needs, chosen host-side by
-:func:`choose_sample_mode` from the step's decode-head arrays:
+Mode-specialized heads (how every step samples: the engine's step
+programs, pipelined and sync, and the scheduler's host-side head): the
+general path pays one full ``(R, V)`` descending sort — shared by the
+top-k and top-p filters — whatever the rows ask for, and every row of
+the common decode batch is greedy. ``mode`` specializes the compiled
+head to what the batch actually needs, chosen host-side on every
+dispatch by :func:`choose_sample_mode` from the step's decode-head
+arrays:
 
 ``"greedy"``
     every row argmaxes — no scaling, no filters, no sort, no RNG.
@@ -44,8 +46,8 @@ import numpy as np
 
 NEG_INF = -1e30
 
-#: sampling-epilogue modes a compiled head can specialize to; also the
-#: vocabulary of ``ServingConfig.fused_decode``-tagged step keys
+#: the modes a compiled head can specialize to: what tags every step
+#: key of the engine that samples on the device (with its top-k cap)
 SAMPLE_MODES = ("full", "greedy", "sample", "topk")
 
 #: largest per-row top-k the bucketed "topk" mode serves; bigger ks

@@ -237,7 +237,7 @@ def test_every_emitted_candidate_builds_an_engine(tiny):
             max_sequence_length=96, cache_dtype=jnp.float32,
             replicas=1, prefill_replicas=0, decode_replicas=0,
         )
-        assert set(sc.fused_decode) <= {"sampling", *llama.FUSED_DECODE}
+        assert set(sc.fused_decode) <= set(llama.FUSED_DECODE)
         if sc not in built:
             built.append(sc)
             InferenceEngine(llama, cfg, params, sc)  # must not raise

@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -723,3 +724,80 @@ def test_deepseek_v3_step_compiles_in_place(chip, C, pack):
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1.5e9, temp
+
+
+# --- the engine's own decode program: the head its batch chose (PR 41) ------
+
+
+def _engine_decode_program(fam, cfg, slots, max_seq, head):
+    """``InferenceEngine._get_mixed_step(1, ...)`` of an engine that
+    holds no array: the program ``run_decode`` dispatches for a batch
+    whose decode-head arrays chose ``head``, from the engine's own
+    code (an engine that is built allocates its pool)."""
+    from flexflow_tpu.core.mesh import MachineSpec
+    from flexflow_tpu.serve.engine import InferenceEngine, ServingConfig
+
+    eng = object.__new__(InferenceEngine)
+    eng.model, eng.cfg = fam, cfg
+    eng.serving = ServingConfig(
+        max_requests_per_batch=slots, max_sequence_length=max_seq,
+        max_spec_tree_tokens=0, kv_layout="paged", page_size=PAGE,
+        kernels="pallas")
+    eng.mesh = MachineSpec().make_mesh(jax.devices()[:1])
+    eng.paged, eng.cp_ring, eng.retrace_guard = True, False, None
+    eng._step_counts = getattr(fam, "step_counts", lambda cfg: {})(cfg)
+    eng._steps = {}
+    return eng._get_mixed_step(1, False, *head)
+
+
+@pytest.mark.parametrize("family", ["mistral", "lfm2_moe"])
+def test_greedy_decode_program_has_no_sort(chip, family):
+    """``ff_step_c1`` as the engine compiles it for an all-greedy batch,
+    at published widths (Mistral: 16 rows of 32000 logits; LFM2: the
+    benchmark cell's 64 rows of 65536): no ``sort`` over a vocabulary
+    in the program, while the full head's program of the same engine
+    sorts its (rows, vocabulary) logits (4 ms of a 19 ms LFM2 step on
+    the chip; ledger, PR 40)."""
+    from flexflow_tpu.serve.sampling import choose_sample_mode
+
+    if family == "mistral":
+        fam, slots, pages = mistral, R, PAGES_PER_SLOT
+        cfg = mistral.mistral_7b(dtype=jnp.bfloat16, num_hidden_layers=2)
+        params, cache = _step_args(chip, cfg, 1)[:2]
+    else:
+        from flexflow_tpu.models import lfm2_moe as fam
+
+        A, V = fam.ATTENTION, fam.CONV
+        cfg = fam.config(num_hidden_layers=3, num_dense_layers=1,
+                         layer_types=(V, A, V), dtype=jnp.bfloat16)
+        slots, pages = 64, 8
+        params = _on(jax.eval_shape(functools.partial(
+            fam.init_params, cfg=cfg), jax.random.PRNGKey(0)), chip)
+        cache = _on(jax.eval_shape(functools.partial(
+            fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+            num_slots=slots, cache_len=pages * PAGE)), chip)
+    rows = lambda dtype: chip((slots,), dtype)
+    args = (params, cache, rows(jnp.int32), chip((slots, 1), jnp.int32),
+            rows(jnp.bool_), chip((slots, 1), jnp.int32), rows(jnp.int32),
+            chip((2,), jnp.uint32), rows(jnp.bool_), rows(jnp.float32),
+            rows(jnp.float32), rows(jnp.int32))
+    table = chip((slots, pages), jnp.int32)
+    logits = rf"f32\[{slots},{cfg.vocab_size}\]"
+    greedy = choose_sample_mode(
+        np.ones(slots, bool), np.full(slots, 2.0), np.zeros(slots), cfg.vocab_size)
+    assert greedy == ("greedy", 0)
+    for head, sorts in ((greedy, False), (("full", 0), True)):
+        step = _engine_decode_program(fam, cfg, slots, pages * PAGE - 1, head)
+        text = step.lower(*args, page_table=table).compile().as_text()
+        name = "jit_ff_step_c1" + ("_full" if sorts else "")
+        assert f"HloModule {name}," in text
+        assert "%ff_ragged_paged_c1" in text
+        # (the chip sorts values and places as a pair; a routed layer
+        # sorts its pairs by expert: no such sort holds a vocabulary)
+        vocab_sorts = [line for line in re.findall(r"= (.*) sort\(", text)
+                       if f",{cfg.vocab_size}]" in line]
+        assert bool(vocab_sorts) == sorts
+        assert all(re.match(rf"\({logits}", s) for s in vocab_sorts)
+        # nor is the temperature's divide over the logits there
+        assert sorts == bool(re.findall(
+            rf"%\S*divide\S* = \(?{logits}", text))

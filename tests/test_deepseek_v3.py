@@ -486,7 +486,7 @@ def test_from_hf_reads_the_catalog_row_and_the_benchmark_configuration():
     (dict(prefix_caching=True), "prefix_caching"),
     (dict(kv_quant="int8"), "kv_quant"),
     (dict(fused_decode=("rope_kv_write",)), "rope_kv_write"),
-    (dict(fused_decode=("sampling",)), "fused_decode"),
+    (dict(fused_decode=("sampling",)), "unknown fused_decode entry 'sampling'"),
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
     (dict(kv_layout="dense"), "kv_layout"),
 ], ids=lambda v: v if isinstance(v, str) else "")

@@ -1,7 +1,8 @@
-"""Megakernel decode step — fused-vs-unfused BITWISE parity.
+"""Fused decode step — fused-vs-unfused BITWISE parity.
 
-Every ``ServingConfig.fused_decode`` fusion must be bit-for-bit the
-unfused step on the same backend:
+The ``ServingConfig.fused_decode`` fusion must be bit-for-bit the
+unfused step on the same backend, and so must the head every step
+samples with on the device be the host-side head:
 
 * "rope_kv_write" (serve/kernels.fused_rope_paged_attention): in-kernel
   RoPE + (optionally int8-quantizing) KV page write vs the unfused
@@ -9,10 +10,11 @@ unfused step on the same backend:
   composition — identical logits AND identical non-scratch pool bytes
   (the shared scratch page is written with padding garbage by both
   paths and read by neither);
-* "sampling" (serve/sampling.py mode-specialized heads): greedy-only /
-  temperature-only / bucketed-top-k heads vs the full-sort reference
-  head, and the one-dispatch ``engine.run_sampled`` sync step vs
-  step-then-host-sample.
+* the mode-specialized heads (serve/sampling.py; no flag: the batch
+  chooses): greedy-only / temperature-only / bucketed-top-k heads vs
+  the full-sort reference head, and the steps that sample on the
+  device (pipelined, and the one-dispatch ``engine.run_sampled`` sync
+  step) vs step-then-host-sample (``RequestManager._sample``).
 
 Covered pools: dense, paged, paged+int8; greedy plus per-row top-k
 batches; the mixed prefill+decode step (continuous batching); TP2.
@@ -79,9 +81,9 @@ GENS_TOPP = [
 ]
 
 
-def _generate(rm, n_new=6):
+def _generate(rm, n_new=6, gens=GENS):
     rids = [rm.submit(p, g, max_new_tokens=n_new)
-            for p, g in zip(PROMPTS, GENS)]
+            for p, g in zip(PROMPTS, gens)]
     while rm.step():
         pass
     rm.drain()
@@ -89,7 +91,7 @@ def _generate(rm, n_new=6):
 
 
 # ---------------------------------------------------------------------------
-# sampling epilogue: mode-specialized heads vs the full reference head
+# mode-specialized heads vs the full reference head
 
 
 def test_sample_mode_heads_bitwise_match_full():
@@ -222,83 +224,99 @@ def test_step_fused_rope_parity_generic_decoder():
 
 
 # ---------------------------------------------------------------------------
-# engine/scheduler parity: every fusion combination generates the same
-# tokens through the continuous-batching scheduler (mixed prefill+decode
-# steps, greedy + per-row top-k rows) with zero steady-state recompiles
+# engine/scheduler parity: every step that samples on the device (the
+# pipelined steps, the one-dispatch sync step), with and without the
+# fusion, generates the tokens of the HOST-SIDE head (the two-dispatch
+# sync step: engine.run, then RequestManager._sample) through the
+# continuous-batching scheduler (mixed prefill+decode steps, greedy +
+# per-row top-k rows) with zero steady-state recompiles
+
+
+class HostHeadManager(RequestManager):
+    """The reference: every step goes through the blocking sync path
+    and samples with the host-side head (what a manager that keeps a
+    second engine in step, SpecInfer, runs)."""
+
+    supports_fast_decode = False
+    supports_fused_sampling = False
+
+
+def _host_head(cfg, params, sc, **kw):
+    rm = HostHeadManager(InferenceEngine(llama, cfg, params, sc))
+    return _generate(rm, **kw), rm
 
 
 def test_generation_parity_paged_fusions(tiny):
     cfg, params = tiny
-    outs = {}
-    for fused in ((), ("sampling",), ("rope_kv_write", "sampling")):
+    want, ref = _host_head(cfg, params, _sc(()))
+    assert ref.stats.sync_steps == ref.stats.steps  # host-side head only
+    for fused in ((), ("rope_kv_write",)):
         rm = RequestManager(
             InferenceEngine(llama, cfg, params, _sc(fused))
         )
-        outs[fused] = _generate(rm)
+        assert _generate(rm) == want, fused
+        assert rm.stats.sync_steps == 0 and rm.stats.head_steps > 0
         assert rm.engine.retrace_guard.retraces == 0, fused
-    assert outs[()] == outs[("sampling",)]
-    assert outs[()] == outs[("rope_kv_write", "sampling")]
 
 
 @pytest.mark.slow  # interpret-mode Pallas e2e (~8s); the step-level
 # int8 fused parity stays in tier-1 (test_step_fused_rope_parity_llama)
 # and scripts/premerge.sh runs this file unfiltered
 def test_generation_parity_paged_int8_pallas(tiny):
-    """Both fusions on the quantized pool through the interpret-mode
+    """The fusion on the quantized pool through the interpret-mode
     Pallas kernels — the in-kernel quantizing commit vs
-    quant_line_write, end to end."""
+    quant_line_write, end to end (a nucleus row: the full-sort head)."""
     cfg, params = tiny
     outs = []
-    for fused in ((), ("rope_kv_write", "sampling")):
+    for fused in ((), ("rope_kv_write",)):
         rm = RequestManager(InferenceEngine(
             llama, cfg, params,
             _sc(fused, kernels="pallas", kv_quant="int8", slots=2),
         ))
-        rids = [rm.submit(p, g, max_new_tokens=4)
-                for p, g in zip(PROMPTS[:2], GENS_TOPP)]
-        while rm.step():
-            pass
-        rm.drain()
-        outs.append([list(rm.requests[r].output_tokens) for r in rids])
+        outs.append(_generate(rm, n_new=4, gens=GENS_TOPP))
         assert rm.engine.retrace_guard.retraces == 0
     assert outs[0] == outs[1]
 
 
 def test_dense_sync_sampling_fusion(tiny):
-    """Dense pool + the sync scheduler: the fused sampling epilogue
-    must generate identical tokens while dispatching STRICTLY fewer
-    programs per step (one fused program vs step + host-side head)."""
+    """Dense pool + the sync scheduler: the step that samples on the
+    device must generate the host-side head's tokens while dispatching
+    STRICTLY fewer programs per step (one program vs step + host-side
+    head). Which of the two a manager runs is its class's
+    ``supports_fused_sampling``, no flag."""
     cfg, params = tiny
-    results = {}
-    for fused in ((), ("sampling",)):
-        rm = RequestManager(InferenceEngine(
-            llama, cfg, params, _sc(fused, layout="dense")
-        ))
-        rm.supports_fast_decode = False  # force the blocking sync path
-        toks = _generate(rm)
-        results[fused] = (toks, rm.engine.dispatch_count)
-        assert rm.engine.retrace_guard.retraces == 0
-    assert results[()][0] == results[("sampling",)][0]
-    assert results[("sampling",)][1] < results[()][1], (
-        "fused step must issue strictly fewer programs than the "
-        f"unfused baseline: {results}"
+    want, ref = _host_head(cfg, params, _sc((), layout="dense"))
+    rm = RequestManager(InferenceEngine(
+        llama, cfg, params, _sc((), layout="dense")
+    ))
+    rm.supports_fast_decode = False  # force the blocking sync path
+    assert _generate(rm) == want
+    for m in (rm, ref):
+        assert m.engine.retrace_guard.retraces == 0
+        assert m.stats.sync_steps == m.stats.steps
+    kinds = {k[0] for k in rm.engine._steps if isinstance(k, tuple)}
+    assert kinds == {"step_sampled"}, kinds
+    assert rm.engine.dispatch_count < ref.engine.dispatch_count, (
+        "the step that samples on the device must issue strictly fewer "
+        "programs than the two-dispatch one: "
+        f"{rm.engine.dispatch_count} vs {ref.engine.dispatch_count}"
     )
 
 
 def test_tp2_fused_parity(tiny):
-    """TP2 mesh: both fusions on vs off must match the single-device
-    greedy+top-k generations bit for bit (the reference's TP output
-    equality bar, python_inference_tests.sh:128)."""
+    """TP2 mesh: the pipelined steps, fusion off and on, must match the
+    single-device host-side head's greedy+top-k generations bit for bit
+    (the reference's TP output equality bar,
+    python_inference_tests.sh:128)."""
     cfg, params = tiny
+    want, _ = _host_head(cfg, params, _sc(()), n_new=4)
     mesh = MachineSpec(model=2).make_mesh(jax.devices()[:2])
-    outs = []
-    for fused in ((), ("rope_kv_write", "sampling")):
+    for fused in ((), ("rope_kv_write",)):
         rm = RequestManager(InferenceEngine(
             llama, cfg, params, _sc(fused), mesh=mesh
         ))
-        outs.append(_generate(rm, n_new=4))
+        assert _generate(rm, n_new=4) == want, fused
         assert rm.engine.retrace_guard.retraces == 0
-    assert outs[0] == outs[1]
 
 
 def test_fused_decode_validation(tiny):
@@ -311,22 +329,25 @@ def test_fused_decode_validation(tiny):
     with pytest.raises(ValueError, match="unknown fused_decode"):
         InferenceEngine(llama, cfg, params, _sc(("bogus",)))
     # string form normalizes like sanitizers
-    eng = InferenceEngine(
-        llama, cfg, params, _sc("rope_kv_write, sampling")
-    )
-    assert eng.serving.fused_decode == ("rope_kv_write", "sampling")
+    eng = InferenceEngine(llama, cfg, params, _sc(" rope_kv_write, "))
+    assert eng.serving.fused_decode == ("rope_kv_write",)
 
 
 @pytest.mark.parametrize("spelling, error, names", [
     (dict(fused_decode=("whole_step",)), ValueError,
-     "'rope_kv_write' and/or 'sampling'"),
+     r"'whole_step' \(expected 'rope_kv_write'\)"),
+    (dict(fused_decode=("sampling",)), ValueError,
+     r"unknown fused_decode entry 'sampling' \(expected 'rope_kv_write'\)"),
+    (dict(fused_decode="rope_kv_write,sampling"), ValueError,
+     r"unknown fused_decode entry 'sampling'"),
     (dict(quantized_allreduce="int8"), TypeError, "quantized_allreduce"),
 ])
 def test_a_deleted_spelling_is_refused_as_any_unknown_one(
         tiny, spelling, error, names):
-    """The whole-step walk and its collectives are gone (PR 31): the
-    old value is an unknown fusion, answered with the two that are left,
-    and the old field is no field."""
+    """The whole-step walk and its collectives are gone (PR 31), and so
+    is the flag over the sampling head (PR 41: the batch chooses it):
+    an old value is an unknown fusion, answered with the one that is
+    left, and the old field is no field."""
     cfg, params = tiny
     with pytest.raises(error, match=names):
         InferenceEngine(llama, cfg, params,

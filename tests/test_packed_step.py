@@ -205,8 +205,12 @@ def test_scheduler_generations_equal_the_padded_engine(model, kernels):
     assert guard.retraces == 0
     counts = guard.compile_counts()
     # every rung of the serving ladder, asked for or not, compiled once
-    assert counts == {("mixed_packed", C, 12): 1, ("mixed_packed", C, 24): 1,
-                      ("mixed_fused", C, False): 1, ("mixed_fused", 1, False): 1,
+    # (of the argmax head's ladder: every request is greedy)
+    g = ("greedy", 0)
+    assert counts == {("mixed_packed", C, 12, *g): 1,
+                      ("mixed_packed", C, 24, *g): 1,
+                      ("mixed_fused", C, False, *g): 1,
+                      ("mixed_fused", 1, False, *g): 1,
                       "copy_page": 1}, counts
 
 
@@ -369,11 +373,22 @@ def test_step_token_counters_against_a_hand_counted_schedule():
     assert "by width -" in SchedulerStats().report()
 
 
-def test_program_names_of_the_rungs():
-    assert program_name(("mixed_packed", 128, 512)) == "ff_step_c128_t512"
-    assert program_name(("mixed_packed", 128, 1024, "greedy", 0)) == (
-        "ff_step_c128_t1024_greedy")
-    assert program_name(("mixed_fused", 128, False)) == "ff_step_c128"
+@pytest.mark.parametrize("key, name", [
+    (("mixed_packed", 128, 512, "greedy", 0), "ff_step_c128_t512"),
+    (("mixed_packed", 128, 1024, "greedy", 0), "ff_step_c128_t1024"),
+    (("mixed_fused", 128, False, "greedy", 0), "ff_step_c128"),
+    (("mixed_fused", 1, False, "greedy", 0), "ff_step_c1"),
+    (("mixed_fused", 128, True, "greedy", 0), "ff_step_c128_logits"),
+    (("mixed_packed", 128, 512, "topk", 8), "ff_step_c128_t512_topk8"),
+    (("mixed_packed", 128, 512, "sample", 0), "ff_step_c128_t512_sample"),
+    (("mixed_fused", 128, False, "full", 0), "ff_step_c128_full"),
+    (("mixed_fused", 1, False, "topk", 64), "ff_step_c1_topk64"),
+])
+def test_program_names_of_the_rungs(key, name):
+    """The greedy head is the unmarked one (the names the benchmark's
+    readers match are the argmax programs'); a head that samples names
+    its extra work."""
+    assert program_name(key) == name
 
 
 @pytest.mark.parametrize("family", [

@@ -90,8 +90,8 @@ def churn_prompts(cfg, n=96):
 def run_churn(rm, prompts, mixed_sampling=False):
     """``mixed_sampling`` gives every 4th request a per-row top-k head
     (the rest stay greedy) so batches oscillate between decode-head
-    modes — exactly the churn the mode-tagged fused-sampling step keys
-    must absorb without a single retrace."""
+    modes — exactly the churn the mode-tagged step keys must absorb
+    without a single retrace."""
     from flexflow_tpu.serve import GenerationConfig
 
     gens = [
@@ -137,16 +137,17 @@ def test_churn_one_compile_per_step_key(tiny, kv_layout):
     assert guard.retraces == 0
     counts = guard.compile_counts()
     C = eng.serving.mixed_chunk
-    assert counts.get(("mixed_fused", C, False)) == 1, counts
-    assert counts.get(("mixed_fused", 1, False)) == 1, counts
+    # every request is greedy: the argmax head's programs and no other
+    assert counts.get(("mixed_fused", C, False, "greedy", 0)) == 1, counts
+    assert counts.get(("mixed_fused", 1, False, "greedy", 0)) == 1, counts
     if kv_layout != "dense":
         assert counts.get("copy_page") == 1, counts
         # quantizing the pool adds NO step programs: the quant write and
         # in-kernel dequant live inside the same jitted steps, so the
         # step-key set is identical with kv_quant on and off
         assert set(counts) == {
-            ("mixed_fused", C, False), ("mixed_fused", 1, False),
-            "copy_page",
+            ("mixed_fused", C, False, "greedy", 0),
+            ("mixed_fused", 1, False, "greedy", 0), "copy_page",
         }, counts
     # compile telemetry mirrored into the scheduler stats
     assert s.compiles == guard.total_compiles
@@ -156,16 +157,17 @@ def test_churn_one_compile_per_step_key(tiny, kv_layout):
 
 
 def test_churn_fused_decode_zero_retraces(tiny):
-    """The megakernel decode step under the headline churn workload:
-    both fusions on (fused_decode=("rope_kv_write", "sampling")) over
-    the tight paged pool with prefix caching — preemption, splice/COW
-    and eviction all exercised, with every 4th request on a top-k
-    decode head so the mode-specialized sampling step keys churn too.
-    The bar is the same as unfused: one compile per step key (the
-    mode-tagged keys each count once), ZERO steady-state retraces, and
+    """The fused decode step under the headline churn workload: the
+    fusion on (fused_decode=("rope_kv_write",)) over the tight paged
+    pool with prefix caching — preemption, splice/COW and eviction all
+    exercised, with every 4th request on a top-k decode head so the
+    mode-tagged step keys churn too (the batch chooses its head: no
+    flag). The bar is the same as unfused: one compile per step key
+    (the mode-tagged keys each count once), ZERO steady-state
+    retraces, a return to a head seen before compiles nothing, and
     sanitizers-on == sanitizers-off generations bitwise."""
     cfg, _ = tiny
-    fused = ("rope_kv_write", "sampling")
+    fused = ("rope_kv_write",)
     eng = churn_engine(
         tiny, "paged", ("retrace", "donation"), fused=fused
     )
@@ -192,12 +194,20 @@ def test_churn_fused_decode_zero_retraces(tiny):
     assert all(len(rm.requests[r].output_tokens) == 6 for r in tail)
 
     guard = eng.retrace_guard
+    compiled = guard.total_compiles
+    # ... and a return to a head seen before (top-k rows again, then
+    # the greedy ones that outlive them) compiles nothing
+    outs_back = run_churn(rm, churn_prompts(cfg, n=8), mixed_sampling=True)
+    assert all(len(o) == 6 for o in outs_back)
+    assert guard.total_compiles == compiled
+    assert s.head_steps == s.mixed_steps + s.decode_steps
+    assert 0 < s.head_greedy_steps < s.head_steps
     guard.assert_one_compile_per_key()
     assert guard.retraces == 0
     counts = guard.compile_counts()
-    # the fused engine's mixed-step keys are sampling-mode-tagged; the
-    # workload uses exactly two head modes (bucketed top-k batches,
-    # then the greedy-only tail), each compiled once per chunk width
+    # the mixed-step keys are sampling-mode-tagged; the workload uses
+    # exactly two head modes (bucketed top-k batches, then the
+    # greedy-only tail), each compiled once per chunk width
     C = eng.serving.mixed_chunk
     modes = {k[3] for k in counts if k[0] == "mixed_fused"}
     assert modes == {"greedy", "topk"}, counts

@@ -207,8 +207,8 @@ def test_step_programs_are_named_from_their_keys(tiny, sanitizers):
     rm = make_rm(tiny, sanitizers=sanitizers)
     rm.generate(PROMPTS[:1], max_new_tokens=3)
     eng = rm.engine
-    keys = {("mixed_fused", 1, False): "ff_step_c1",
-            ("mixed_fused", CHUNK, False): f"ff_step_c{CHUNK}"}
+    keys = {("mixed_fused", 1, False, "greedy", 0): "ff_step_c1",
+            ("mixed_fused", CHUNK, False, "greedy", 0): f"ff_step_c{CHUNK}"}
     assert set(keys) <= set(eng._steps)
     if eng.retrace_guard is not None:
         eng.retrace_guard.strict = False  # lowering again traces again
@@ -248,7 +248,7 @@ def test_one_step_program_a_scheduler_step(tiny, kind, chunk):
     rm.drain()
     assert steps_of_kind > 0
     counts = eng.retrace_guard.compile_counts()
-    key = ("mixed_fused", chunk, False)
+    key = ("mixed_fused", chunk, False, "greedy", 0)
     assert counts.get(key) == 1, counts
     assert program_name(key) == f"ff_step_c{chunk}"
     assert eng.retrace_guard.retraces == 0
@@ -256,8 +256,9 @@ def test_one_step_program_a_scheduler_step(tiny, kind, chunk):
 
 def test_program_names_are_distinct_and_stable():
     keys = ["commit", "copy_page", "reorder", (1, False, False),
-            (8, True, True), ("mixed_fused", 1, False),
-            ("mixed_fused", 1, True), ("mixed_fused", 1, False, "greedy", 0),
+            (8, True, True), ("mixed_fused", 1, False, "greedy", 0),
+            ("mixed_fused", 1, True, "greedy", 0),
+            ("mixed_fused", 1, False, "sample", 0),
             ("mixed_fused", 1, False, "topk", 64),
             ("step_sampled", 1, False, "greedy", 0, False),
             ("speculate", 2, 3)]
@@ -268,7 +269,8 @@ def test_program_names_are_distinct_and_stable():
     assert names[:4] == ["ff_commit", "ff_copy_page", "ff_reorder",
                          "ff_step_sync_c1"]
     assert names[4] == "ff_step_sync_c8_logits_mask"
-    assert names[8] == "ff_step_c1_topk64"
+    assert names[5:9] == ["ff_step_c1", "ff_step_c1_logits",
+                          "ff_step_c1_sample", "ff_step_c1_topk64"]
     # every per-step program reads as a step
     assert all(n.startswith("ff_step_") for n in names[3:10])
 
