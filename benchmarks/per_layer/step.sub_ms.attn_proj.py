@@ -1,0 +1,13 @@
+"""Device milliseconds a step under the scope ``ff.attn.proj``: the Q/K/V
+(or latent, absorbed-query) projections, the per-head q/k norms, RoPE
+and the output projection. The summed durations of the traced window's
+``XLA Ops`` events (container opcodes left out) inside ``jit_ff_step_*``
+modules whose instruction the program's scope map puts under
+``ff.attn.proj``, over the number of those modules
+(``harness/sublayers.py``). None where the cell has no such operation,
+without a trace, and on a program that gives no map."""
+from benchmarks.harness import sublayers
+
+
+def read(ctx):
+    return sublayers.read(ctx, "attn_proj")

@@ -22,8 +22,8 @@ SPARSE_CATEGORICAL_CROSSENTROPY = "sparse_categorical_crossentropy"
 MEAN_SQUARED_ERROR = "mean_squared_error"
 MEAN_ABSOLUTE_ERROR = "mean_absolute_error"
 
-#: decode_step_ms reservoir bound (SchedulerStats.note_decode_step_ms)
-_DECODE_MS_CAP = 4096
+#: bound of a ClusterStats wall-time reservoir (note_cluster_step_ms, ...)
+_STEP_MS_CAP = 4096
 
 
 def compute_metrics(
@@ -135,19 +135,6 @@ class SchedulerStats:
     cp_shards: int = 0
     ring_steps: int = 0
     shard_balance: float = 1.0
-    # Whole-step decode telemetry (ROADMAP 5b: decode_step_ms is THE
-    # metric the megakernel trajectory tracks): wall-clock samples of
-    # the scheduler's decode-step engine call — on the pipelined path
-    # this is the host-side dispatch cost (the device runs up to
-    # dispatch_ahead steps ahead, so it is NOT device latency; no
-    # device sync is ever added for the measurement — FF107/FF108);
-    # on the blocking sync path it is the full step wall time. A
-    # bounded reservoir (newest _DECODE_MS_CAP samples kept) so steady
-    # traffic cannot grow host memory; snapshot() derives p50/p99 by
-    # nearest-rank.
-    decode_step_ms_samples: List[float] = dataclasses.field(
-        default_factory=list
-    )
     # Retrace sentinel (analysis/retrace.py, wired when the engine runs
     # with ServingConfig.sanitizers=("retrace",)): XLA compiles of step
     # programs observed at the engine's jit chokepoint, and how many of
@@ -287,29 +274,6 @@ class SchedulerStats:
         by = self.steps_by_width
         self.steps_by_width = {**by, int(width): by.get(int(width), 0) + 1}
 
-    def note_decode_step_ms(self, ms: float) -> None:
-        """Record one decode-step wall sample (bounded reservoir)."""
-        s = self.decode_step_ms_samples
-        s.append(float(ms))
-        if len(s) > _DECODE_MS_CAP:
-            del s[: len(s) - _DECODE_MS_CAP]
-
-    def _decode_ms_pct(self, q: float) -> float:
-        s = self.decode_step_ms_samples
-        if not s:
-            return 0.0
-        ordered = sorted(s)
-        idx = min(len(ordered) - 1, int(round(q * (len(ordered) - 1))))
-        return ordered[idx]
-
-    @property
-    def decode_step_ms_p50(self) -> float:
-        return self._decode_ms_pct(0.50)
-
-    @property
-    def decode_step_ms_p99(self) -> float:
-        return self._decode_ms_pct(0.99)
-
     @property
     def mean_occupancy(self) -> float:
         return self.occupancy_sum / self.steps if self.steps else 0.0
@@ -389,8 +353,6 @@ class SchedulerStats:
             "cp_shards": self.cp_shards,
             "ring_steps": self.ring_steps,
             "shard_balance": round(self.shard_balance, 4),
-            "decode_step_ms_p50": round(self.decode_step_ms_p50, 3),
-            "decode_step_ms_p99": round(self.decode_step_ms_p99, 3),
             "compiles": self.compiles,
             "retraces": self.retraces,
             "step_tokens_real": self.step_tokens_real,
@@ -422,8 +384,6 @@ class SchedulerStats:
             f"reprobe={s['spec_reprobes']} "
             f"cp={s['cp_shards']} ring={s['ring_steps']} "
             f"bal={s['shard_balance']:.2f} "
-            f"dstep_ms={s['decode_step_ms_p50']:.2f}/"
-            f"{s['decode_step_ms_p99']:.2f} "
             f"compiles={s['compiles']} retraces={s['retraces']} "
             f"greedy_head={s['head_greedy_steps']}/{s['head_steps']} "
             f"pack={s['step_tokens_real']}/{s['step_tokens_width']} by width "
@@ -553,11 +513,11 @@ class ClusterStats:
 
     def note_cluster_step_ms(self, ms: float) -> None:
         """Record one whole-cluster-step wall sample (bounded
-        reservoir, same trim discipline as decode_step_ms)."""
+        reservoir: the newest ``_STEP_MS_CAP`` samples are kept)."""
         s = self.cluster_step_ms_samples
         s.append(float(ms))
-        if len(s) > _DECODE_MS_CAP:
-            del s[: len(s) - _DECODE_MS_CAP]
+        if len(s) > _STEP_MS_CAP:
+            del s[: len(s) - _STEP_MS_CAP]
 
     def note_arrival(self, replica: int) -> None:
         """Count one first-time placement onto ``replica`` (failover
@@ -581,16 +541,16 @@ class ClusterStats:
         percentiles must reflect what admission actually saw."""
         s = self.queue_delay_s_samples
         s.append(max(0.0, float(delay_s)))
-        if len(s) > _DECODE_MS_CAP:
-            del s[: len(s) - _DECODE_MS_CAP]
+        if len(s) > _STEP_MS_CAP:
+            del s[: len(s) - _STEP_MS_CAP]
 
     def note_rpc_rtt_ms(self, replica: int, ms: float) -> None:
         """Record one step-RPC round-trip sample for ``replica``
         (bounded per-replica reservoir)."""
         s = self.rpc_rtt_ms_samples.setdefault(int(replica), [])
         s.append(float(ms))
-        if len(s) > _DECODE_MS_CAP:
-            del s[: len(s) - _DECODE_MS_CAP]
+        if len(s) > _STEP_MS_CAP:
+            del s[: len(s) - _STEP_MS_CAP]
 
     @staticmethod
     def _pct(samples: Sequence[float], q: float) -> float:
@@ -688,11 +648,6 @@ class ClusterStats:
                 sum(s.get("mean_budget_fill", 0.0) for s in per) / len(per),
                 4,
             )
-            # percentiles do not sum either — report the replica mean
-            for k in ("decode_step_ms_p50", "decode_step_ms_p99"):
-                agg[k] = round(
-                    sum(s.get(k, 0.0) for s in per) / len(per), 3
-                )
         return {
             "submitted": self.submitted,
             "placements": dict(self.placements),

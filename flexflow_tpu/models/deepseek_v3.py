@@ -74,13 +74,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..obs.sublayers import sublayer
 from .transformer import (
     DecoderConfig,
     _embed_in,
     _ffn,
     _gather_attended,
+    _head_logits,
     _layer_of,
-    _lm_logits,
     _mm,
     _norm,
     _pack_tokens,
@@ -525,18 +526,20 @@ def _mla_block(cfg, ctx, stack, index, x, carried):
     B, T, _ = x.shape
     H = cfg.num_attention_heads
     h = _norm(cfg, x, p["attn_norm_scale"], None)
-    with jax.named_scope("mla.project"):
+    with sublayer("attn.proj"):
         c, kr = latent_line(cfg, p, h, ctx["rope"])
+    with sublayer("attn.write"):
         cp, krp = carried["latent"], carried["latent_rope"]
         phys, off = ctx["phys"], ctx["off"]
         cp = cp.at[index, phys, off].set(c.astype(cp.dtype))
         row, lanes = _pk.pair_rope_place(off, cp.shape[2], cfg.qk_rope_head_dim)
         krp = krp.at[index, phys[..., None], row[..., None], lanes].set(
             kr.astype(krp.dtype))
-        q = [_spread_queries(q, ctx["pack"])                # (R, C, H, .)
-             for q in absorbed_queries(cfg, p, h, ctx["rope"])]
+    with sublayer("attn.proj"):
+        q = absorbed_queries(cfg, p, h, ctx["rope"])
     rows = (ctx["page_table"], ctx["q_start"], ctx["q_len"])
-    with jax.named_scope("mla.attend"):
+    with sublayer("attn.core"):
+        q = [_spread_queries(q, ctx["pack"]) for q in q]    # (R, C, H, .)
         if ctx["kernels"] == "pallas":
             o = _pk.mla_paged_attention(
                 *q, *(a.reshape((-1,) + a.shape[2:]) for a in (cp, krp)),
@@ -546,8 +549,8 @@ def _mla_block(cfg, ctx, stack, index, x, carried):
             o = _pk.mla_paged_attention_xla(
                 *q, _layer_of(cp, index), _layer_of(krp, index), *rows,
                 scale=softmax_scale(cfg))
-    with jax.named_scope("mla.project"):
         o = _gather_attended(o, ctx["pack"]).reshape(B, T, H, cfg.kv_lora_rank)
+    with sublayer("attn.proj"):
         _, w_uv = kv_up_halves(cfg, p["w_kvb"])
         o = jnp.einsum("bthc,chd->bthd", o, w_uv,
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -576,8 +579,7 @@ def sparse_ffn(cfg, p, h, real, layer=None, kernels="xla"):
     of every layer, stacked. The experts held compute their part, the
     shared expert the whole of its own.
     -> (out (N, D), counts (experts held,))."""
-    with jax.named_scope("moe.route"):
-        experts, weights = route(cfg, p, h)
+    experts, weights = route(cfg, p, h)
     out, counts = routed_experts_ffn(
         h, real, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
         experts_held=cfg.held, layer=layer, kernels=kernels)
@@ -604,6 +606,7 @@ def _sparse_block(cfg, ctx, stack, index, x, carried):
 # The step
 
 
+@sublayer("glue")
 def serve_step_paged(
     params: Dict[str, Any],
     cache: Dict[str, jnp.ndarray],
@@ -646,8 +649,10 @@ def serve_step_paged(
         (*token_axis, phys, off), pack_idx = _pack_tokens(
             tokens, positions, q_len, page_table, ps, cache_len, pack)
         real = token_axis[1][0] < cache_len
+    with sublayer("attn.proj"):
+        rope = rope_cos_sin(cfg, token_axis[1])
     ctx = dict(
-        rope=rope_cos_sin(cfg, token_axis[1]), phys=phys, off=off,
+        rope=rope, phys=phys, off=off,
         page_table=page_table, kernels=kernels, q_len=q_len, pack=pack_idx,
         q_start=positions[:, 0], real=real,
     )
@@ -659,13 +664,5 @@ def serve_step_paged(
         for name, fn in (("mla", _mla_block), ("dense", _dense_block),
                          ("sparse", _sparse_block))}
     x, new_cache = run_layers(cfg.kinds, blocks, params, x, carried)
-    x = _norm(cfg, x, params["final_norm_scale"], None)
-    if pack_idx is not None:
-        # row r samples from the packed place of its column logits_idx[r]
-        at = jnp.take_along_axis(pack_idx[0], logits_idx[:, None], axis=1)
-        x = jnp.take(x[0], at, axis=0, mode="clip")
-        return _lm_logits(cfg, params, x)[:, 0], new_cache
-    if not all_logits:
-        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-        return _lm_logits(cfg, params, x)[:, 0], new_cache
-    return _lm_logits(cfg, params, x), new_cache
+    return _head_logits(cfg, params, x, logits_idx, pack_idx,
+                        all_logits), new_cache

@@ -74,13 +74,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..obs.sublayers import sublayer
 from .transformer import (
     DecoderConfig,
     _embed_in,
     _ffn,
     _gather_attended,
+    _head_logits,
     _layer_of,
-    _lm_logits,
     _mm,
     _norm,
     _pack_tokens,
@@ -436,15 +437,17 @@ def _conv_block(cfg, ctx, stack, index, x, carried):
     p = layer_weights(stack, index)
     B, T, D = x.shape
     h = _norm(cfg, x, p["attn_norm_scale"], None)
-    b, c_gate, z = jnp.split(_mm(h, p["w_in"]), 3, axis=-1)
-    u = (b * z).reshape(B * T, D)
-    c, state = short_conv(
-        u, p["conv_w"], _layer_of(carried["conv"], index), ctx["row"],
-        ctx["col"], ctx["q_len"], ctx["fresh"], ctx["place"])
-    y = c_gate * c.astype(x.dtype).reshape(B, T, D)
-    carried = dict(carried, conv=jax.lax.dynamic_update_index_in_dim(
-        carried["conv"], state, index, 0))
-    return x + _mm(y, p["wo"]), carried
+    with sublayer("mixer"):
+        b, c_gate, z = jnp.split(_mm(h, p["w_in"]), 3, axis=-1)
+        u = (b * z).reshape(B * T, D)
+        c, state = short_conv(
+            u, p["conv_w"], _layer_of(carried["conv"], index), ctx["row"],
+            ctx["col"], ctx["q_len"], ctx["fresh"], ctx["place"])
+        y = c_gate * c.astype(x.dtype).reshape(B, T, D)
+        carried = dict(carried, conv=jax.lax.dynamic_update_index_in_dim(
+            carried["conv"], state, index, 0))
+        out = _mm(y, p["wo"])
+    return x + out, carried
 
 
 def _attn_block(cfg, ctx, stack, index, x, carried):
@@ -454,28 +457,32 @@ def _attn_block(cfg, ctx, stack, index, x, carried):
     B, T, _ = x.shape
     H, KV, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     h = _norm(cfg, x, p["attn_norm_scale"], None)
-    q = _norm(cfg, _mm(h, p["wq"]).reshape(B, T, H, d), p["q_norm_scale"], None)
-    k = _norm(cfg, _mm(h, p["wk"]).reshape(B, T, KV, d), p["k_norm_scale"], None)
-    v = _mm(h, p["wv"]).reshape(B, T, KV, d)
-    q, k = apply_rope(q, *ctx["rope"]), apply_rope(k, *ctx["rope"])
+    with sublayer("attn.proj"):
+        q = _norm(cfg, _mm(h, p["wq"]).reshape(B, T, H, d), p["q_norm_scale"], None)
+        k = _norm(cfg, _mm(h, p["wk"]).reshape(B, T, KV, d), p["k_norm_scale"], None)
+        v = _mm(h, p["wv"]).reshape(B, T, KV, d)
+        q, k = apply_rope(q, *ctx["rope"]), apply_rope(k, *ctx["rope"])
     kp, vp, _, _ = _write_kv_lines(
         carried["k"], carried["v"], None, None, index, ctx["phys"], ctx["off"],
         k.reshape(B, T, KV * d), v.reshape(B, T, KV * d), None)
-    q = _spread_queries(q, ctx["pack"])                       # (R, C, H, d)
-    if ctx["kernels"] == "pallas":
-        k_rows, v_rows, kw = _pallas_pools(kp, vp, None, None, index)
-        o = _pk.ragged_paged_attention(
-            q, k_rows, v_rows, ctx["page_table"], ctx["mask"],
-            row_offset=kw["row_offset"], q_len=ctx["q_len"])
-    else:
-        k_virt, v_virt = (
-            _pk.gather_pages(_layer_of(pool, index), ctx["page_table"])
-            for pool in (kp, vp))
-        split = k_virt.shape[:2] + (KV, d)
-        o = _serve_attend(cfg, q, k_virt.reshape(split), v_virt.reshape(split),
-                          None, ctx["mask"])
-    o = _gather_attended(o, ctx["pack"])
-    return x + _mm(o, p["wo"]), dict(carried, k=kp, v=vp)
+    with sublayer("attn.core"):
+        q = _spread_queries(q, ctx["pack"])                   # (R, C, H, d)
+        if ctx["kernels"] == "pallas":
+            k_rows, v_rows, kw = _pallas_pools(kp, vp, None, None, index)
+            o = _pk.ragged_paged_attention(
+                q, k_rows, v_rows, ctx["page_table"], ctx["mask"],
+                row_offset=kw["row_offset"], q_len=ctx["q_len"])
+        else:
+            k_virt, v_virt = (
+                _pk.gather_pages(_layer_of(pool, index), ctx["page_table"])
+                for pool in (kp, vp))
+            split = k_virt.shape[:2] + (KV, d)
+            o = _serve_attend(cfg, q, k_virt.reshape(split),
+                              v_virt.reshape(split), None, ctx["mask"])
+        o = _gather_attended(o, ctx["pack"])
+    with sublayer("attn.proj"):
+        out = _mm(o, p["wo"])
+    return x + out, dict(carried, k=kp, v=vp)
 
 
 def _dense_block(cfg, ctx, stack, index, x, carried):
@@ -511,6 +518,7 @@ def _sparse_block(cfg, ctx, stack, index, x, carried):
 # The step
 
 
+@sublayer("glue")
 def serve_step_paged(
     params: Dict[str, Any],
     cache: Dict[str, jnp.ndarray],
@@ -559,8 +567,10 @@ def serve_step_paged(
         place, flat = pack_idx
         row, col = flat // C, flat % C
         real = token_axis[1][0] < cache_len
+    with sublayer("attn.proj"):
+        rope = rope_freqs(cfg, token_axis[1])
     ctx = dict(
-        rope=rope_freqs(cfg, token_axis[1]), phys=phys, off=off,
+        rope=rope, phys=phys, off=off,
         page_table=page_table, kernels=kernels, q_len=q_len, pack=pack_idx,
         mask=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
         row=row, col=col, real=real, place=place,
@@ -574,13 +584,5 @@ def serve_step_paged(
         for name, fn in (("conv", _conv_block), ("attn", _attn_block),
                          ("dense", _dense_block), ("sparse", _sparse_block))}
     x, new_cache = run_layers(cfg.kinds, blocks, params, x, carried)
-    x = _norm(cfg, x, params["final_norm_scale"], None)
-    if pack_idx is not None:
-        # row r samples from the packed place of its column logits_idx[r]
-        at = jnp.take_along_axis(place, logits_idx[:, None], axis=1)
-        x = jnp.take(x[0], at, axis=0, mode="clip")
-        return _lm_logits(cfg, params, x)[:, 0], new_cache
-    if not all_logits:
-        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-        return _lm_logits(cfg, params, x)[:, 0], new_cache
-    return _lm_logits(cfg, params, x), new_cache
+    return _head_logits(cfg, params, x, logits_idx, pack_idx,
+                        all_logits), new_cache

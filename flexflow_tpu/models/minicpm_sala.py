@@ -79,6 +79,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from ..obs.sublayers import sublayer
 from .transformer import (
     DecoderConfig,
     _embed_in,
@@ -432,6 +433,7 @@ def lightning_attend(q, k, v, state, real, fresh):
     return o, jnp.where(active[:, None, None, None], s1, state)
 
 
+@sublayer("mixer")
 def _lightning_mixer(cfg, p, u, rope, state, real, fresh):
     R, C, _ = u.shape
     H, d = cfg.lightning_heads, cfg.lightning_head_dim
@@ -445,6 +447,7 @@ def _lightning_mixer(cfg, p, u, rope, state, real, fresh):
     return _mm(o, p["wo"]), state
 
 
+@sublayer("attn.select")
 def _update_kbar(cfg, kbar, k_pool, layer, page_table, first, last, active, C):
     """Bring one sparse layer's compressed keys up to date with the
     lines a step of chunk ``C`` has just written: entry j is the mean of
@@ -473,6 +476,7 @@ def _update_kbar(cfg, kbar, k_pool, layer, page_table, first, last, active, C):
     return kbar.at[layer, rows, js].set(means, mode="drop")
 
 
+@sublayer("attn.select")
 def choose_blocks(cfg, q, kbar, positions, real):
     """InfLLM-v2's choice for every query: (R, C, KV, blocks) bool, the
     ``topk`` blocks a query at position t (n = t + 1 keys visible)
@@ -525,15 +529,17 @@ def _sparse_mixer(cfg, p, u, k_pool, v_pool, kbar, chosen_last, layer, ctx):
 
     R, C, _ = u.shape
     H, KV, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    q = _head_norm(cfg, _mm(u, p["wq"]).reshape(R, C, H, d), p["q_norm_scale"])
-    k = _head_norm(cfg, _mm(u, p["wk"]).reshape(R, C, KV, d), p["k_norm_scale"])
-    v = _mm(u, p["wv"]).reshape(R, C, KV, d)
+    with sublayer("attn.proj"):
+        q = _head_norm(cfg, _mm(u, p["wq"]).reshape(R, C, H, d), p["q_norm_scale"])
+        k = _head_norm(cfg, _mm(u, p["wk"]).reshape(R, C, KV, d), p["k_norm_scale"])
+        v = _mm(u, p["wv"]).reshape(R, C, KV, d)
     k_pool, v_pool, _, _ = _write_kv_lines(
         k_pool, v_pool, None, None, layer, ctx["phys"], ctx["off"], k, v, None)
     kbar = _update_kbar(cfg, kbar, k_pool, layer, ctx["page_table"],
                         ctx["first"], ctx["last"], ctx["active"], C)
     blk = cfg.sparse_block
 
+    @sublayer("attn.core")
     def attend(mask, group_mask):
         if ctx["kernels"] == "pallas":
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, None, None, layer)
@@ -561,20 +567,28 @@ def _sparse_mixer(cfg, p, u, k_pool, v_pool, kbar, chosen_last, layer, ctx):
     def sparse(_):
         chosen = choose_blocks(cfg, q, kbar_l, ctx["positions"],
                                ctx["real"])                          # (R, C, KV, NB)
-        lines = jnp.repeat(chosen.transpose(0, 2, 1, 3), blk, axis=-1)
-        mask = lines[..., :ctx["causal"].shape[-1]] & ctx["causal"][:, None]  # (R, KV, C, S)
-        at_last = jnp.take_along_axis(
-            chosen, ctx["last_col"][:, None, None, None], axis=1)[:, 0]
-        at_last = at_last & ctx["sparse_row"][:, None, None]
-        return attend(mask, True), jnp.pad(at_last, ((0, 0), (0, 0), (0, wider)))
+        with sublayer("attn.select"):
+            lines = jnp.repeat(chosen.transpose(0, 2, 1, 3), blk, axis=-1)
+            mask = lines[..., :ctx["causal"].shape[-1]] & ctx["causal"][:, None]  # (R, KV, C, S)
+            at_last = jnp.take_along_axis(
+                chosen, ctx["last_col"][:, None, None, None], axis=1)[:, 0]
+            at_last = at_last & ctx["sparse_row"][:, None, None]
+        o = attend(mask, True)
+        with sublayer("attn.select"):
+            kept = jnp.pad(at_last, ((0, 0), (0, 0), (0, wider)))
+        return o, kept
 
     def dense(_):
         return attend(ctx["causal"], False), jnp.zeros(blocks, bool)
 
     o, at_last = lax.cond(ctx["any_sparse"], sparse, dense, None)
-    chosen_last = lax.dynamic_update_index_in_dim(chosen_last, at_last, layer, 0)
-    o = o.reshape(R, C, H * d) * jax.nn.sigmoid(_mm(u, p["w_ogate"]))
-    return _mm(o, p["wo"]), k_pool, v_pool, kbar, chosen_last
+    with sublayer("attn.select"):
+        chosen_last = lax.dynamic_update_index_in_dim(
+            chosen_last, at_last, layer, 0)
+    with sublayer("attn.proj"):
+        o = o.reshape(R, C, H * d) * jax.nn.sigmoid(_mm(u, p["w_ogate"]))
+        out = _mm(o, p["wo"])
+    return out, k_pool, v_pool, kbar, chosen_last
 
 
 # ---------------------------------------------------------------------------
@@ -594,6 +608,7 @@ def _runs(kinds):
     return [tuple(r) for r in runs]
 
 
+@sublayer("glue")
 def serve_step_paged(
     params: Dict[str, Any],
     cache: Dict[str, jnp.ndarray],
@@ -639,7 +654,8 @@ def serve_step_paged(
         causal=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
     )
     fresh = real[:, 0] & (first == 0)
-    rope = rope_freqs(cfg, positions)
+    with sublayer("mixer"):  # the lightning layers alone take RoPE
+        rope = rope_freqs(cfg, positions)
 
     def ffn(p, x):
         n = _norm(cfg, x, p["mlp_norm_scale"], None)
@@ -667,9 +683,10 @@ def serve_step_paged(
         carry = lax.fori_loop(start, start + n, body, carry)
     x, (kp, vp, kbar, chosen), state = carry
     new_cache = {"k": kp, "v": vp, "kbar": kbar, "chosen": chosen, "state": state}
-    x = _norm(cfg, x, params["final_norm_scale"], None)
-    x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
-    if not all_logits:
-        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-        return _lm_logits(cfg, params, x)[:, 0], new_cache
-    return _lm_logits(cfg, params, x), new_cache
+    with sublayer("head"):
+        x = _norm(cfg, x, params["final_norm_scale"], None)
+        x = x / jnp.asarray(cfg.hidden_size / cfg.dim_model_base, x.dtype)
+        if not all_logits:
+            x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
+            return _lm_logits(cfg, params, x)[:, 0], new_cache
+        return _lm_logits(cfg, params, x), new_cache

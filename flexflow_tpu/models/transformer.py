@@ -43,6 +43,7 @@ from ..core.mesh import (
     PIPE_AXIS,
     SEQ_AXIS,
 )
+from ..obs.sublayers import sublayer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -437,6 +438,32 @@ def _project_qkv(cfg: DecoderConfig, p, h):
     )
 
 
+@sublayer("attn.proj")
+def _project_rope(cfg: DecoderConfig, p, h, rope):
+    """:func:`_project_qkv`, with RoPE on the queries and keys where
+    the family has it (``rope`` (cos, sin) or None)."""
+    q, k, v = _project_qkv(cfg, p, h)
+    if rope is not None:
+        cos, sin = rope
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    return q, k, v
+
+
+@sublayer("attn.proj")
+def _rope_of(cfg: DecoderConfig, positions):
+    """A step's (cos, sin) where the family has RoPE, else None."""
+    return rope_freqs(cfg, positions) if cfg.positions == "rope" else None
+
+
+@sublayer("attn.proj")
+def _project_out(cfg: DecoderConfig, p, attn):
+    attn = _mm(attn, p["wo"])
+    if cfg.out_bias:
+        attn = attn + p["bo"]
+    return attn
+
+
+@sublayer("moe.route")
 def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
     """A linear router with a softmax (HF ``MixtralSparseMoeBlock``,
     ``Qwen2MoeSparseMoeBlock``), in float32: ``lax.top_k`` of the
@@ -460,6 +487,7 @@ def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
     return topi, gate
 
 
+@sublayer("ffn")
 def _shared_expert(cfg: DecoderConfig, p, h):
     """Qwen2-MoE's always-on shared expert, scaled by a sigmoid token
     gate (HF ``Qwen2MoeSparseMoeBlock`` shared_expert +
@@ -500,9 +528,10 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
     E, K = cfg.num_local_experts, cfg.num_experts_per_tok
     topi, gate = route_softmax_topk(
         h, p["w_router"], K, norm_topk=cfg.moe_norm_topk)  # (B,S,K)
-    combine = jnp.einsum(
-        "bsk,bske->bse", gate, jax.nn.one_hot(topi, E, dtype=jnp.float32)
-    )  # (B,S,E)
+    with sublayer("moe.route"):
+        combine = jnp.einsum(
+            "bsk,bske->bse", gate, jax.nn.one_hot(topi, E, dtype=jnp.float32)
+        )  # (B,S,E)
     w_up = _dense_w(p["w_up"], h.dtype)
     w_down = _dense_w(p["w_down"], h.dtype)
     up = jnp.einsum(
@@ -527,6 +556,7 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
     return out
 
 
+@sublayer("moe.route")
 def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
                        norm_topk: bool = True, scaling: float = 1.0,
                        groups: Tuple[int, int] = (1, 1), eps: float = 1e-6):
@@ -565,6 +595,7 @@ def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
     return experts.astype(jnp.int32), weights * scaling
 
 
+@sublayer("moe.route")
 def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
                        experts_held: Tuple[int, int], layer=None,
                        kernels: str = "xla"):
@@ -639,8 +670,10 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
         last = tile_group[jnp.maximum(n_active - 1, 0)]
         tile_group = first + jnp.minimum(
             jnp.where(tile < n_active, tile_group, last), n - 1)
-        act = _pk.grouped_glu(rows, w_gate, w_up, tile_group, n_active, tm=tm)
-        out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm)
+        with sublayer("ffn"):
+            act = _pk.grouped_glu(rows, w_gate, w_up, tile_group, n_active,
+                                  tm=tm)
+            out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm)
         place = jnp.zeros((P,), jnp.int32).at[order].set(at.astype(jnp.int32))
         out = jnp.take(out, place, axis=0, mode="clip")
     else:
@@ -651,8 +684,10 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
         rows = jnp.take(h, order // k, axis=0)               # (P, D), by expert
         dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
                                 preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up)).astype(h.dtype)
-        out = dot(act, w_down)                               # (P, D) float32
+        with sublayer("ffn"):
+            act = (jax.nn.silu(dot(rows, w_gate))
+                   * dot(rows, w_up)).astype(h.dtype)
+            out = dot(act, w_down)                           # (P, D) float32
         place = jnp.zeros((P,), jnp.int32).at[order].set(
             jnp.arange(P, dtype=jnp.int32))
         out = jnp.take(out, place, axis=0)
@@ -664,6 +699,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     return out.astype(h.dtype), counts
 
 
+@sublayer("ffn")
 def _ffn(cfg: DecoderConfig, p, h):
     if cfg.num_local_experts:
         return _moe_ffn(cfg, p, h)
@@ -803,6 +839,7 @@ def _embed_in(cfg: DecoderConfig, params, tokens, positions):
     return x
 
 
+@sublayer("head")
 def _lm_logits(cfg: DecoderConfig, params, x):
     if cfg.tie_word_embeddings:
         # x embed^T as a contraction over the embedding's own minor
@@ -816,6 +853,25 @@ def _lm_logits(cfg: DecoderConfig, params, x):
     if "lm_head_bias" in params:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
     return logits
+
+
+@sublayer("head")
+def _head_logits(cfg: DecoderConfig, params, x, logits_idx, pack, all_logits):
+    """The end of a step: the final norm over the token axis, each
+    row's hidden state at its ``logits_idx`` (with ``pack``,
+    :func:`_pack_tokens`' second result, at that column's packed place)
+    unless ``all_logits``, and the LM head. -> (R, V), or (R, C, V)
+    with ``all_logits``."""
+    x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
+    if pack is None and all_logits:
+        return _lm_logits(cfg, params, x)
+    if pack is None:
+        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
+    else:
+        # row r samples from the packed place of its column logits_idx[r]
+        at = jnp.take_along_axis(pack[0], logits_idx[:, None], axis=1)
+        x = jnp.take(x[0], at, axis=0, mode="clip")
+    return _lm_logits(cfg, params, x)[:, 0]
 
 
 def forward(
@@ -918,20 +974,18 @@ def _serve_attend(cfg: DecoderConfig, q, k_cache, v_cache, bias, mask):
     return out.reshape(R, C, H * dk)
 
 
+@sublayer("glue")
 def serve_block(cfg, p, x, rope, bias, mask, k_cache, v_cache, cache_positions):
     R, C, D = x.shape
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
-    q, k, v = _project_qkv(cfg, p, h)
-    if rope is not None:
-        cos, sin = rope
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    bidx = jnp.arange(R)[:, None]
-    k_cache = k_cache.at[bidx, cache_positions].set(k.astype(k_cache.dtype))
-    v_cache = v_cache.at[bidx, cache_positions].set(v.astype(v_cache.dtype))
-    attn = _serve_attend(cfg, q, k_cache, v_cache, bias, mask)
-    attn = _mm(attn, p["wo"])
-    if cfg.out_bias:
-        attn = attn + p["bo"]
+    q, k, v = _project_rope(cfg, p, h, rope)
+    with sublayer("attn.write"):
+        bidx = jnp.arange(R)[:, None]
+        k_cache = k_cache.at[bidx, cache_positions].set(k.astype(k_cache.dtype))
+        v_cache = v_cache.at[bidx, cache_positions].set(v.astype(v_cache.dtype))
+    with sublayer("attn.core"):
+        attn = _serve_attend(cfg, q, k_cache, v_cache, bias, mask)
+    attn = _project_out(cfg, p, attn)
     if cfg.parallel_block:
         if cfg.parallel_two_norms:
             h2 = _norm(cfg, x, p["mlp_norm_scale"], p.get("mlp_norm_bias"))
@@ -943,6 +997,7 @@ def serve_block(cfg, p, x, rope, bias, mask, k_cache, v_cache, cache_positions):
     return x + _ffn(cfg, p, h2), k_cache, v_cache
 
 
+@sublayer("glue")
 def serve_step(
     params: Dict[str, Any],
     cache: Dict[str, jnp.ndarray],
@@ -970,7 +1025,7 @@ def serve_step(
     if cache_positions is None:
         cache_positions = positions
     x = _embed_in(cfg, params, tokens, positions)
-    rope = rope_freqs(cfg, positions) if cfg.positions == "rope" else None
+    rope = _rope_of(cfg, positions)
     if mask is None:
         from ..serve.kernels import causal_serve_mask
 
@@ -1067,12 +1122,7 @@ def serve_step(
         x, (k_new, v_new) = lax.scan(
             scan_body, x, (params["layers"], cache["k"], cache["v"])
         )
-    x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    if not all_logits:
-        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-        logits = _lm_logits(cfg, params, x)[:, 0]
-    else:
-        logits = _lm_logits(cfg, params, x)
+    logits = _head_logits(cfg, params, x, logits_idx, None, all_logits)
     new_cache = {"k": k_new, "v": v_new}
     if needs_pos_cache(cfg):
         new_cache["pos"] = pos_cache
@@ -1214,6 +1264,7 @@ def _layer_of(a, layer):
     return lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
 
 
+@sublayer("attn.write")
 def _write_kv_lines(k_pool, v_pool, k_scale, v_scale, layer, phys, off,
                     k, v, qmax):
     """Commit the step's new K/V lines at their table-resolved (page,
@@ -1399,64 +1450,63 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
             for a, b in zip(pools, new)
         ))
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
-    q, k, v = _project_qkv(cfg, p, h)
     cos, sin = rope if rope is not None else (None, None)
     fused = fused_rope and kernels == "pallas" and bias is None
-    if fused and cp_mesh is not None:
-        # ring fused prologue: RoPE + the resident-line commit move
-        # inside the per-shard shard_map body (full-precision pools;
-        # quantized raises loudly in the kernel and is excluded at
-        # ServingConfig validation)
-        attn, k_pool, v_pool = _pk.ring_ragged_paged_attention(
-            q, k_pool, v_pool, page_table, mask, cp_mesh,
-            fused=dict(k_new=k, v_new=v, cos=cos, sin=sin,
-                       phys=phys, off=off),
-        )
-    elif fused:
-        k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale, v_scale,
-                                           layer)
-        attn, *new = _pk.fused_rope_paged_attention(
-            q, k, v, cos, sin, k_rows, v_rows, page_table, logical, off,
-            mask, qmax=qmax, **kw,
-        )
-        k_pool, v_pool, k_scale, v_scale = (
-            b if b is None else b.reshape(a.shape)
-            for a, b in zip((k_pool, v_pool, k_scale, v_scale), new)
-        )
-    else:
-        if rope is not None:
-            q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        k_pool, v_pool, k_scale, v_scale = _write_kv_lines(
-            k_pool, v_pool, k_scale, v_scale, layer, phys, off, k, v, qmax
-        )
-        if cp_mesh is not None:
-            if bias is not None:
-                # ALiBi's additive bias needs per-key-position terms the
-                # ring program does not carry yet (same exclusion as the
-                # Pallas kernel); sliding-window masks are fine — they
-                # are mask refinements, already folded in before this
-                # call.
-                raise NotImplementedError(
-                    "ring context parallelism is not composed with ALiBi "
-                    "position bias — serve this family with "
-                    "kv_shard='context' on a seq-degree-1 mesh (the table-"
-                    "gather layout), or use a RoPE/learned-position family"
-                )
-            attn = _pk.ring_ragged_paged_attention(
+    # a fused prologue does RoPE and the line commit inside the kernel
+    q, k, v = _project_rope(cfg, p, h, None if fused else rope)
+    # the attention call; the line write inside it keeps its own scope
+    with sublayer("attn.core"):
+        if fused and cp_mesh is not None:
+            # ring fused prologue: RoPE + the resident-line commit move
+            # inside the per-shard shard_map body (full-precision pools;
+            # quantized raises loudly in the kernel and is excluded at
+            # ServingConfig validation)
+            attn, k_pool, v_pool = _pk.ring_ragged_paged_attention(
                 q, k_pool, v_pool, page_table, mask, cp_mesh,
-                k_scale=k_scale, v_scale=v_scale,
+                fused=dict(k_new=k, v_new=v, cos=cos, sin=sin,
+                           phys=phys, off=off),
             )
-        else:  # kernels == "pallas", bias None (the xla path returned above)
+        elif fused:
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale,
                                                v_scale, layer)
-            attn = _pk.ragged_paged_attention(
-                _spread_queries(q, pack), k_rows, v_rows, page_table, mask,
-                q_len=q_len, **kw
+            attn, *new = _pk.fused_rope_paged_attention(
+                q, k, v, cos, sin, k_rows, v_rows, page_table, logical, off,
+                mask, qmax=qmax, **kw,
             )
-    attn = _gather_attended(attn, pack)
-    attn = _mm(attn, p["wo"])
-    if cfg.out_bias:
-        attn = attn + p["bo"]
+            k_pool, v_pool, k_scale, v_scale = (
+                b if b is None else b.reshape(a.shape)
+                for a, b in zip((k_pool, v_pool, k_scale, v_scale), new)
+            )
+        else:
+            k_pool, v_pool, k_scale, v_scale = _write_kv_lines(
+                k_pool, v_pool, k_scale, v_scale, layer, phys, off, k, v, qmax
+            )
+            if cp_mesh is not None:
+                if bias is not None:
+                    # ALiBi's additive bias needs per-key-position terms the
+                    # ring program does not carry yet (same exclusion as the
+                    # Pallas kernel); sliding-window masks are fine — they
+                    # are mask refinements, already folded in before this
+                    # call.
+                    raise NotImplementedError(
+                        "ring context parallelism is not composed with ALiBi "
+                        "position bias — serve this family with "
+                        "kv_shard='context' on a seq-degree-1 mesh (the table-"
+                        "gather layout), or use a RoPE/learned-position family"
+                    )
+                attn = _pk.ring_ragged_paged_attention(
+                    q, k_pool, v_pool, page_table, mask, cp_mesh,
+                    k_scale=k_scale, v_scale=v_scale,
+                )
+            else:  # kernels == "pallas", bias None (xla returned above)
+                k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, k_scale,
+                                                   v_scale, layer)
+                attn = _pk.ragged_paged_attention(
+                    _spread_queries(q, pack), k_rows, v_rows, page_table,
+                    mask, q_len=q_len, **kw
+                )
+        attn = _gather_attended(attn, pack)
+    attn = _project_out(cfg, p, attn)
     x, *counts = _add_attn_ffn(cfg, p, x, h, attn, routed, layer, kernels)
     return (x, k_pool, v_pool, k_scale, v_scale, *counts)
 
@@ -1473,29 +1523,26 @@ def _block_paged_xla(cfg: DecoderConfig, p, x, rope, bias, mask,
     from ..serve import kernels as _pk
 
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
-    q, k, v = _project_qkv(cfg, p, h)
-    if rope is not None:
-        cos, sin = rope
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    q, k, v = _project_rope(cfg, p, h, rope)
     k_pool, v_pool, k_scale, v_scale = _write_kv_lines(
         k_pool, v_pool, k_scale, v_scale, layer, phys, off, k, v, qmax
     )
-    k_l, v_l = _layer_of(k_pool, layer), _layer_of(v_pool, layer)
-    if qmax is not None:
-        k_virt = _pk.dequant_pages(
-            k_l, _layer_of(k_scale, layer), page_table, q.dtype
-        )
-        v_virt = _pk.dequant_pages(
-            v_l, _layer_of(v_scale, layer), page_table, q.dtype
-        )
-    else:
-        k_virt = _pk.gather_pages(k_l, page_table)
-        v_virt = _pk.gather_pages(v_l, page_table)
-    attn = _serve_attend(cfg, _spread_queries(q, pack), k_virt, v_virt, bias,
-                         mask)
-    attn = _mm(_gather_attended(attn, pack), p["wo"])
-    if cfg.out_bias:
-        attn = attn + p["bo"]
+    with sublayer("attn.core"):
+        k_l, v_l = _layer_of(k_pool, layer), _layer_of(v_pool, layer)
+        if qmax is not None:
+            k_virt = _pk.dequant_pages(
+                k_l, _layer_of(k_scale, layer), page_table, q.dtype
+            )
+            v_virt = _pk.dequant_pages(
+                v_l, _layer_of(v_scale, layer), page_table, q.dtype
+            )
+        else:
+            k_virt = _pk.gather_pages(k_l, page_table)
+            v_virt = _pk.gather_pages(v_l, page_table)
+        attn = _serve_attend(cfg, _spread_queries(q, pack), k_virt, v_virt,
+                             bias, mask)
+        attn = _gather_attended(attn, pack)
+    attn = _project_out(cfg, p, attn)
     x, *counts = _add_attn_ffn(cfg, p, x, h, attn, routed, layer, "xla")
     return (x, k_pool, v_pool, k_scale, v_scale, *counts)
 
@@ -1531,6 +1578,7 @@ def _paged_serve_context(cfg, cache, positions, cache_positions, mask,
     return phys, off, mask, bias, pos_pool
 
 
+@sublayer("glue")
 def serve_step_paged(
     params: Dict[str, Any],
     cache: Dict[str, jnp.ndarray],
@@ -1610,7 +1658,7 @@ def serve_step_paged(
         )
         token_axis, lines = packed[:2], packed[2:]
     x = _embed_in(cfg, params, *token_axis)
-    rope = rope_freqs(cfg, token_axis[1]) if cfg.positions == "rope" else None
+    rope = _rope_of(cfg, token_axis[1])
     # the mask, the bias and the position pool follow from the (R, C)
     # positions whatever the token axis: attention stays (R, C)
     phys, off, mask, bias, pos_pool = _paged_serve_context(
@@ -1678,17 +1726,7 @@ def serve_step_paged(
     new_cache = {"k": k_new, "v": v_new, **counts}
     if qmax is not None:
         new_cache["k_scale"], new_cache["v_scale"] = scales
-    x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    if pack_idx is not None:
-        # row r samples from the packed place of its column logits_idx[r]
-        at = jnp.take_along_axis(pack_idx[0], logits_idx[:, None], axis=1)
-        x = jnp.take(x[0], at, axis=0, mode="clip")
-        logits = _lm_logits(cfg, params, x)[:, 0]
-    elif not all_logits:
-        x = jnp.take_along_axis(x, logits_idx[:, None, None], axis=1)
-        logits = _lm_logits(cfg, params, x)[:, 0]
-    else:
-        logits = _lm_logits(cfg, params, x)
+    logits = _head_logits(cfg, params, x, logits_idx, pack_idx, all_logits)
     if needs_pos_cache(cfg):
         new_cache["pos"] = pos_pool
     return logits, new_cache

@@ -24,6 +24,13 @@ timeline captures — rebuilt TPU-native over the serve stack:
   wall stamps are ``time.perf_counter()``, the clock ``ProfileInfo``
   uses, so a request's events and stamps compare; the profiler's
   clock is the session's own and only annotations can be put on it.
+* :mod:`.sublayers` — beneath the jit boundary: the one vocabulary of
+  ``ff.*`` named scopes that every step program carries
+  (``sublayer(name)``; metadata, nothing at dispatch), so an xprof
+  capture groups the device's operations by sublayer, and
+  ``scope_maps()``, which reads ``{program: {HLO instruction:
+  sublayer}}`` off the compiled executables for a reader that has only
+  instruction names (the benchmark's ``step.sub_ms.*``).
 * :mod:`.export` — Chrome/Perfetto ``trace_event`` JSON (one lane per
   replica; a migrated request is ONE trace id hopping lanes) and a
   Prometheus text snapshot mechanically derived from
@@ -58,6 +65,7 @@ from .export import (
     write_prometheus,
 )
 from .flight_recorder import REDACTED_ATTRS, FlightRecorder
+from .sublayers import SUBLAYERS, scope_maps, sublayer
 from .tracer import NULL_TRACER, NullTracer, TraceBuffer, Tracer
 
 __all__ = [
@@ -74,6 +82,9 @@ __all__ = [
     "check_export_coverage",
     "ExportDriftError",
     "attach_observability",
+    "SUBLAYERS",
+    "sublayer",
+    "scope_maps",
 ]
 
 

@@ -126,9 +126,6 @@ SCHED_GAUGES = frozenset({
 SCHED_EXCLUDED = {
     "occupancy_sum": "mean_occupancy",
     "budget_fill_sum": "mean_budget_fill",
-    # the raw reservoir is host-side sample storage; the scrape surface
-    # carries its derived percentiles
-    "decode_step_ms_samples": "decode_step_ms_p50",
     # a by-width dict; the scrape surface carries the two counters it
     # sums to and their ratio
     "steps_by_width": "pack_fill",
@@ -137,7 +134,7 @@ SCHED_EXCLUDED = {
 SCHED_DERIVED = (
     "mean_occupancy", "mean_budget_fill", "prefix_hit_rate",
     "host_hit_rate", "spec_accept_rate",
-    "decode_step_ms_p50", "decode_step_ms_p99", "pack_fill",
+    "pack_fill",
 )
 
 CLUSTER_COUNTERS = frozenset({

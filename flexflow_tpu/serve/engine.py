@@ -21,6 +21,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.mesh import DATA_AXIS, MachineSpec, set_mesh as _set_mesh
+from ..obs import sublayers
+from ..obs.sublayers import sublayer
 from ..obs.tracer import NULL_TRACER
 from .batch_config import BatchConfig
 from .sampling import choose_sample_mode, sample_tokens
@@ -676,6 +678,19 @@ def pack_widths(slots: int, chunk: int) -> Tuple[int, ...]:
     return tuple(w for w in (-(-top // 4), -(-top // 2), top) if w >= chunk)
 
 
+def _abstract(tree):
+    """The abstract twin of a tree of tracers (or arrays): what
+    ``jit(...).lower`` takes in their place. Shape, dtype, weak type
+    and the sharding the type carries are the whole of the tracing
+    cache's key, so lowering with it traces nothing again."""
+    def leaf(x):
+        aval = jax.typeof(x)
+        return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                    sharding=aval.sharding,
+                                    weak_type=aval.weak_type)
+    return jax.tree.map(leaf, tree)
+
+
 def program_name(key: Any) -> str:
     """The stable name of the program compiled under the step key
     ``key`` (:meth:`InferenceEngine._jit`): what JAX calls its module
@@ -754,6 +769,10 @@ class InferenceEngine:
         # ("mixed_packed", chunk, width, mode, cap) a rung of its
         # ladder; mode and cap name the head, serve/sampling.py).
         self._steps: Dict[Any, Callable] = {}
+        # program name -> (jitted, abstract arguments), kept by _jit's
+        # wrapper as it is traced (step_program_texts)
+        self._traced: Dict[str, Tuple[Callable, Any]] = {}
+        sublayers.register(self)
         # serving ladders (chunk, sampling head) whose every rung is
         # compiled
         self._ladders_compiled: set = set()
@@ -1113,14 +1132,37 @@ class InferenceEngine:
         under ``key`` and, in strict mode, raises on any recompile of a
         known key (analysis/retrace.py)."""
 
+        name = program_name(key)
+
         @functools.wraps(fn)
         def program(*args, **kwargs):
+            # trace time only: what step_program_texts lowers again
+            self._traced[name] = (jitted, _abstract((args, kwargs)))
             return fn(*args, **kwargs)
 
-        program.__name__ = program.__qualname__ = program_name(key)
+        program.__name__ = program.__qualname__ = name
         if self.retrace_guard is not None:
             program = self.retrace_guard.instrument(program, key=key)
-        return jax.jit(program, donate_argnums=donate_argnums)
+        jitted = jax.jit(program, donate_argnums=donate_argnums)
+        return jitted
+
+    def step_program_texts(self, names=None) -> Dict[str, str]:
+        """``{program name: the compiled executable's HLO text}`` of
+        the programs this engine has traced through :meth:`_jit` (all,
+        or those of ``names``): each is lowered and compiled again with
+        the abstract arguments it was traced with, under the engine's
+        mesh. The tracing cache answers the first (no trace, so the
+        retrace sentinel sees nothing) and the compilation cache the
+        second; nothing is dispatched. Seconds a program all the same
+        (my chip runs, PR 42: 1-4 s): for ``obs.sublayers.scope_maps``,
+        after the measured work, never on the serving path."""
+        texts = {}
+        with _set_mesh(self.mesh):
+            for name, (jitted, (args, kwargs)) in list(self._traced.items()):
+                if names is None or name in names:
+                    texts[name] = jitted.lower(
+                        *args, **kwargs).compile().as_text()
+        return texts
 
     def _carry(self, last_tokens):
         """The sampled-token carry as a step's own output would present
@@ -1299,24 +1341,28 @@ class InferenceEngine:
             def step(params, cache, last_tokens, host_tokens, use_last,
                      positions, logits_idx, key, greedy, temperature,
                      topp, topk, page_table=None):
-                first = jnp.where(use_last, last_tokens, host_tokens[:, 0])
-                tokens = jnp.concatenate(
-                    [first[:, None], host_tokens[:, 1:]], axis=1
-                )
+                with sublayer("glue"):
+                    first = jnp.where(use_last, last_tokens, host_tokens[:, 0])
+                    tokens = jnp.concatenate(
+                        [first[:, None], host_tokens[:, 1:]], axis=1
+                    )
                 args = (params, cache, tokens, positions, logits_idx,
                         None, None)
                 if paged:
                     args = args + (page_table,)
                 logits, cache, *counts = fn(*args)
-                toks = sample_tokens(
-                    logits, key,
-                    greedy=greedy, temperature=temperature, topp=topp,
-                    topk_arr=topk, mode=sample_mode, topk_cap=topk_cap,
-                )
+                with sublayer("head"):
+                    toks = sample_tokens(
+                        logits, key,
+                        greedy=greedy, temperature=temperature, topp=topp,
+                        topk_arr=topk, mode=sample_mode, topk_cap=topk_cap,
+                    )
                 out = (toks, logits) if with_logits else (toks,)
                 if counts:  # one array to fetch: the tokens, then the counters
-                    out += (jnp.concatenate(
-                        [toks] + [c.reshape(-1) for c in counts[0].values()]),)
+                    with sublayer("glue"):
+                        out += (jnp.concatenate(
+                            [toks]
+                            + [c.reshape(-1) for c in counts[0].values()]),)
                 return (*out, cache)
 
             self._steps[key_id] = self._jit(
@@ -1444,11 +1490,12 @@ class InferenceEngine:
                 if paged:
                     args = args + (page_table,)
                 logits, cache = fn(*args)
-                toks = sample_tokens(
-                    logits, key,
-                    greedy=greedy, temperature=temperature, topp=topp,
-                    topk_arr=topk, mode=sample_mode, topk_cap=topk_cap,
-                )
+                with sublayer("head"):
+                    toks = sample_tokens(
+                        logits, key,
+                        greedy=greedy, temperature=temperature, topp=topp,
+                        topk_arr=topk, mode=sample_mode, topk_cap=topk_cap,
+                    )
                 if with_logits:
                     return toks, logits, cache
                 return toks, cache

@@ -1013,15 +1013,10 @@ class RequestManager:
                 last = jnp.zeros((R,), jnp.int32)
             self._key, sub = jax.random.split(self._key)
         with self.tracer.span("step.dispatch"):
-            t0 = time.perf_counter()
             toks = self.engine.run_decode(
                 last, host_tokens, use_last, positions, sub, greedy, temp,
                 topp, topk,
             )
-            # decode_step_ms: the engine call's host wall time —
-            # dispatch cost on this pipelined path (the device runs
-            # ahead; no sync is added)
-            self.stats.note_decode_step_ms((time.perf_counter() - t0) * 1e3)
             self._mirror_dispatch(
                 last, host_tokens, use_last, positions,
                 np.zeros((R,), np.int32), sub, greedy, temp, topp, topk,
@@ -1351,7 +1346,6 @@ class RequestManager:
             return bool(self.pending)
         prefilling = self._active(RequestStatus.PREFILLING)
         decoding = self._active(RequestStatus.DECODING)
-        decode_only = bool(decoding) and not prefilling
         # rows whose final prompt chunk rides this batch: its sample is
         # their first token
         finals = [
@@ -1359,7 +1353,6 @@ class RequestManager:
             if r.n_cached + int(bc.qlens[r.slot]) >= len(r.tokens)
             and not r.profile.first_token_time
         ]
-        t0 = time.perf_counter()
         if self.supports_fused_sampling:
             # ONE dispatched program per sync step (step + on-device
             # decode head) instead of two — the (R, V) logits never
@@ -1386,12 +1379,6 @@ class RequestManager:
                 logits = self._run_batch(bc)
             self._stamp_prefill_dispatched(finals)
             sampled = self._sample(logits)
-        if decode_only:
-            # decode_step_ms, sync path: the full blocking step wall
-            # time (dispatch + fetch — this path syncs by design)
-            self.stats.note_decode_step_ms(
-                (time.perf_counter() - t0) * 1e3
-            )
         for req in decoding:
             req.n_cached += 1
             req.n_sched = req.n_cached

@@ -746,7 +746,7 @@ def _engine_decode_program(fam, cfg, slots, max_seq, head):
     eng.mesh = MachineSpec().make_mesh(jax.devices()[:1])
     eng.paged, eng.cp_ring, eng.retrace_guard = True, False, None
     eng._step_counts = getattr(fam, "step_counts", lambda cfg: {})(cfg)
-    eng._steps = {}
+    eng._steps, eng._traced = {}, {}
     return eng._get_mixed_step(1, False, *head)
 
 
