@@ -1683,11 +1683,57 @@ def grouped_down(act, w_down, tile_group, n_active, *, tm: int):
 # axis that is no multiple of 128, and the device then lays the array
 # out with the page on its lanes for the step's line write and back for
 # the kernel: two copies of the pool a layer (tests/test_chip_compile).
+#
+# A grid step of the kernel is a tile of query columns against a BLOCK
+# of KB logical pages, KB x ps lines side by side: the running maximum
+# and sum and the (rows, c) float32 accumulator are rescaled once a
+# block (their cost does not grow with the keys), and the state lies
+# replicated along a lane tile. The tile and KB come from the static
+# shapes (mla_block): the widest tile, then the most pages whose
+# float32 scores Mosaic still keeps out of its spill slots.
 
 #: query columns of one grid step of :func:`mla_paged_attention`: with
-#: H = 128 heads 16 columns are 2048 rows of the matmuls, a 4 MiB
-#: float32 accumulator
-MLA_QUERY_TILE = 16
+#: H = 128 heads 32 columns are 4096 rows of the matmuls, an 8 MiB
+#: float32 accumulator (a 16-column tile reads 10-13% slower on the
+#: kernel alone at any block of pages: PERF.md section 6, PR 43)
+MLA_QUERY_TILE = 32
+
+#: bytes of the float32 scores of one grid step of that kernel, rows x
+#: the block's lines: at 8 MiB (16 columns x 8 pages of 128 lines, or 32
+#: x 4) Mosaic spills 120 MB of registers and the kernel runs three
+#: times slower than at 4 MiB, which 16 x 4 and 32 x 2 both are
+MLA_SCORES_BYTES = 4 << 20
+
+#: lanes of that kernel's softmax state: the running maximum and sum of
+#: a row are kept replicated along one whole lane tile, so that reading
+#: them against the scores and the accumulator moves no data (held as
+#: (rows, 1) arrays they cost as much as the rest of the step)
+MLA_STATE_LANES = 128
+
+
+def mla_block(C: int, NP: int, H: int, ps: int) -> tuple[int, int]:
+    """(query columns, pages) of one grid step of
+    :func:`mla_paged_attention`, from the static shapes: the tile is
+    :data:`MLA_QUERY_TILE` columns (the chunk's own under it); the block
+    is the largest of 8, 4, 2, 1 pages that the table holds at least
+    once and whose scores stay within :data:`MLA_SCORES_BYTES`. The
+    DeepSeek cell's mixed step (128 heads, pages of 128 lines) takes 32
+    columns x 2 pages, its decode step 1 x 8."""
+    TC = min(C, MLA_QUERY_TILE)
+    for KB in (8, 4, 2):
+        if KB <= NP and TC * H * KB * ps * 4 <= MLA_SCORES_BYTES:
+            return TC, KB
+    return TC, 1
+
+
+def _along_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """A state replicated along its lanes, (M, MLA_STATE_LANES), at
+    ``width`` lanes: whole lane tiles side by side, which moves no
+    data, or a broadcast (the tests' few lines a page)."""
+    lanes = x.shape[1]
+    if width % lanes == 0:
+        return jnp.tile(x, (1, width // lanes))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
 def pair_rope_place(off: jnp.ndarray, page_size: int, width: int):
@@ -1754,28 +1800,39 @@ def mla_paged_attention(
     row_offset=None,          # int32 scalar: pool row of table entry 0
 ) -> jnp.ndarray:
     """Latent paged attention, ``ff_mla_paged_c<C>``: grid (row, query
-    tile, logical page); a step loads ONE page of lines through the
-    page table (``row_offset``: the pools are every layer's pages, see
-    :func:`_ragged_paged_attention`) and attends a tile's queries, all
-    H heads of :data:`MLA_QUERY_TILE` columns as the rows of its
-    matmuls in the pools' dtype: scores ``q' c^T + q_rope kr^T``,
-    values over ``c``, online softmax in float32 between them.
+    tile, block of logical pages). A step is handed the block's KB
+    pages of lines through the page table (each pool is passed KB
+    times, one page block an operand; ``row_offset``: the pools are
+    every layer's pages, see :func:`_ragged_paged_attention`) and
+    attends a tile's queries, all H heads of the tile's columns as the
+    rows of its matmuls in the pools' dtype, against the KB x ps lines
+    side by side: scores ``q' c^T + q_rope kr^T``, values over ``c``,
+    and between them ONE online-softmax update in float32 a block: one
+    row maximum, one exponent pass, one rescale of the (rows, c)
+    accumulator. The running maximum and sum lie replicated along a
+    lane tile (:data:`MLA_STATE_LANES`). The tile and the block come
+    from the shapes (:func:`mla_block`).
 
     Work follows the real queries: a page past a tile's last real
-    query is neither fetched (the index map repeats the last page the
-    tile needs, and a block whose index repeats is not fetched again)
-    nor computed; a tile with no real query fetches nothing and writes
-    zeros; a tile whose one real query is its first column (a decode
-    row of a mixed step) runs at one column's rows; the causal mask is
-    computed from the positions and only on the pages it cuts.
+    query is neither fetched (its index map repeats the page of that
+    query, and a block whose index repeats is not fetched again; so
+    does a page past the table's end, where ``NP`` is no multiple of
+    KB) nor, by whole blocks, computed; a tile with no real query
+    fetches nothing and writes zeros; a tile whose one real query is
+    its first column (a decode row of a mixed step) runs at one
+    column's rows; the causal mask is computed from the positions and
+    only on the blocks it cuts, where it also hides the block's pages
+    past the last query.
     -> (R, C, H, c) in q's dtype, padding columns zero."""
     R, C, H, V = q_abs.shape
     dr = q_rope.shape[-1]
     ps = c_pool.shape[1]
     NP = page_table.shape[1]
-    TC = min(C, MLA_QUERY_TILE)
+    TC, KB = mla_block(C, NP, H, ps)
     if C % TC:
         raise ValueError(f"chunk {C} is no multiple of the query tile {TC}")
+    NB = pl.cdiv(NP, KB)        # blocks of a row's table
+    W = KB * ps                 # keys of a block
     prefetch = [page_table.astype(jnp.int32), q_start.astype(jnp.int32),
                 q_len.astype(jnp.int32)]
     if row_offset is not None:
@@ -1785,21 +1842,28 @@ def mla_paged_attention(
         # the last tile of row r that holds a real query, or 0
         return jnp.minimum(t, jnp.maximum(count[r] - 1, 0) // TC)
 
-    def q_block(r, t, p, pt, start, count, *base):
+    def q_block(r, t, b, pt, start, count, *base):
         return (r, live_tile(r, t, count), 0, 0)
 
-    def page_block(r, t, p, pt, start, count, *base):
-        # the page of the tile's last real query, once p has passed it
-        t = live_tile(r, t, count)
-        last = start[r] + jnp.minimum(count[r], (t + 1) * TC) - 1
-        row = pt[r, jnp.minimum(p, jnp.clip(last // ps, 0, NP - 1))]
-        if base:
-            row = row + base[0][0]
-        return (row, 0, 0)
+    def page_block(j):
+        # page j of block b, or the page of the tile's last real query
+        # once the block has passed it (a page past the table's end
+        # lies past every query)
+        def index(r, t, b, pt, start, count, *base):
+            t = live_tile(r, t, count)
+            last = start[r] + jnp.minimum(count[r], (t + 1) * TC) - 1
+            row = pt[r, jnp.minimum(b * KB + j,
+                                    jnp.clip(last // ps, 0, NP - 1))]
+            if base:
+                row = row + base[0][0]
+            return (row, 0, 0)
+        return index
 
     def kernel(pt_ref, start_ref, count_ref, *refs):
-        qa_ref, qr_ref, c_ref, kr_ref, out_ref, acc, m_scr, l_scr = refs[-8:]
-        r, t, p = (pl.program_id(i) for i in range(3))
+        qa_ref, qr_ref, *pages, out_ref, acc, m_scr, l_scr = \
+            refs[-(2 * KB + 6):]
+        c_refs, kr_refs = pages[:KB], pages[KB:]
+        r, t, b = (pl.program_id(i) for i in range(3))
         start, count = start_ref[r], count_ref[r]
         cols = jnp.clip(count - t * TC, 0, TC)     # real queries of the tile
         first = start + t * TC                      # position of its column 0
@@ -1811,15 +1875,17 @@ def mla_paged_attention(
             # accumulators
             M = n * H
 
-            @pl.when(p == 0)
+            @pl.when(b == 0)
             def _():
                 acc[:M] = jnp.zeros((M, V), jnp.float32)
-                m_scr[:M] = jnp.full((M, 1), NEG_INF, jnp.float32)
-                l_scr[:M] = jnp.zeros((M, 1), jnp.float32)
+                m_scr[:M] = jnp.full((M, MLA_STATE_LANES), NEG_INF, jnp.float32)
+                l_scr[:M] = jnp.zeros((M, MLA_STATE_LANES), jnp.float32)
 
             def attend(cut):
-                c = c_ref[0]                                      # (ps, V)
-                kr = unpair_rope_lines(kr_ref[0])                 # (ps, dr)
+                # the block's lines side by side, one softmax update
+                c = jnp.concatenate([ref[0] for ref in c_refs])        # (W, V)
+                kr = jnp.concatenate(
+                    [unpair_rope_lines(ref[0]) for ref in kr_refs])    # (W, dr)
                 s = (jax.lax.dot_general(
                         qa_ref[0, :n].reshape(M, V), c, nt,
                         preferred_element_type=jnp.float32)
@@ -1828,35 +1894,36 @@ def mla_paged_attention(
                         preferred_element_type=jnp.float32)) * scale
                 if cut:
                     # at the scores' own shape: Mosaic broadcasts no
-                    # boolean along sublanes
-                    key = p * ps + jax.lax.broadcasted_iota(
-                        jnp.int32, (n, H, ps), 2)
-                    col = jax.lax.broadcasted_iota(jnp.int32, (n, H, ps), 0)
-                    seen = (key <= first + col) & (col < cols)
-                    s = jnp.where(seen, s.reshape(n, H, ps),
-                                  NEG_INF).reshape(M, ps)
+                    # boolean along sublanes. A padding column sees
+                    # nothing in any block and is zeroed at the end
+                    key = b * W + jax.lax.broadcasted_iota(
+                        jnp.int32, (n, H, W), 2)
+                    col = jax.lax.broadcasted_iota(jnp.int32, (n, H, W), 0)
+                    s = jnp.where(key <= first + col, s.reshape(n, H, W),
+                                  NEG_INF).reshape(M, W)
                 m_old = m_scr[:M]
                 m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
-                prob = jnp.exp(s - m_new)
-                if cut:  # a row that sees no key of this page: exp(0)
-                    prob = jnp.where(seen, prob.reshape(n, H, ps),
-                                     0.0).reshape(M, ps)
+                prob = jnp.exp(s - _along_lanes(m_new, W))
                 corr = jnp.exp(m_old - m_new)
                 l_scr[:M] = l_scr[:M] * corr + prob.sum(axis=1, keepdims=True)
-                acc[:M] = acc[:M] * corr + jnp.dot(
+                acc[:M] = acc[:M] * _along_lanes(corr, V) + jnp.dot(
                     prob.astype(c.dtype), c, preferred_element_type=jnp.float32)
                 m_scr[:M] = m_new
 
-            # every query of the n sees every key of the page, or the
+            # every query of the n sees every key of the block, or the
             # mask cuts it
-            whole = (p * ps + ps - 1 <= first) & (cols >= n)
-            pl.when((p * ps <= last) & whole)(lambda: attend(False))
-            pl.when((p * ps <= last) & ~whole)(lambda: attend(True))
+            whole = (b * W + W - 1 <= first) & (cols >= n)
+            pl.when((b * W <= last) & whole)(lambda: attend(False))
+            pl.when((b * W <= last) & ~whole)(lambda: attend(True))
 
-            @pl.when(p == NP - 1)
+            @pl.when(b == NB - 1)
             def _():
-                o = acc[:M] / jnp.maximum(l_scr[:M], 1e-20)
-                out_ref[0, :n] = o.reshape(n, H, V).astype(out_ref.dtype)
+                o = acc[:M] / _along_lanes(jnp.maximum(l_scr[:M], 1e-20), V)
+                o = o.reshape(n, H, V)
+                if n > 1:
+                    col = jax.lax.broadcasted_iota(jnp.int32, (n, H, V), 0)
+                    o = jnp.where(col < cols, o, 0.0)
+                out_ref[0, :n] = o.astype(out_ref.dtype)
                 if n < TC:
                     out_ref[0, n:] = jnp.zeros((TC - n, H, V), out_ref.dtype)
 
@@ -1864,28 +1931,31 @@ def mla_paged_attention(
             pl.when(cols > 1)(lambda: step(TC))
         pl.when(cols == 1)(lambda: step(1))
 
-        @pl.when((cols == 0) & (p == NP - 1))
+        @pl.when((cols == 0) & (b == NB - 1))
         def _():
             out_ref[0] = jnp.zeros((TC, H, V), out_ref.dtype)
 
-    in_specs = [pl.BlockSpec((1, TC, H, V), q_block),
-                pl.BlockSpec((1, TC, H, dr), q_block),
-                pl.BlockSpec((1, ps, V), page_block),
-                pl.BlockSpec((1, ps // 2, 2 * dr), page_block)]
-    out_spec = pl.BlockSpec((1, TC, H, V), lambda r, t, p, *_: (r, t, 0, 0))
+    in_specs = ([pl.BlockSpec((1, TC, H, V), q_block),
+                 pl.BlockSpec((1, TC, H, dr), q_block)]
+                + [pl.BlockSpec((1, ps, V), page_block(j)) for j in range(KB)]
+                + [pl.BlockSpec((1, ps // 2, 2 * dr), page_block(j))
+                   for j in range(KB)])
+    out_spec = pl.BlockSpec((1, TC, H, V), lambda r, t, b, *_: (r, t, 0, 0))
     out_shape = jax.ShapeDtypeStruct((R, C, H, V), q_abs.dtype)
     M = TC * H
     scratch = [pltpu.VMEM((M, V), jnp.float32),
-               pltpu.VMEM((M, 1), jnp.float32),
-               pltpu.VMEM((M, 1), jnp.float32)]
-    # blocks double-buffered, the scratch, and the body's float32
-    # intermediates: the scores and their exponentials (M, ps), the
-    # values' product (M, V) and the rescaled accumulator
-    operands = (q_abs, q_rope, c_pool, kr_pool)
+               pltpu.VMEM((M, MLA_STATE_LANES), jnp.float32),
+               pltpu.VMEM((M, MLA_STATE_LANES), jnp.float32)]
+    # blocks double-buffered, the scratch, and the body's intermediates:
+    # the block's lines side by side, the float32 scores (M, W) with
+    # their masked and exponentiated forms (about six live at the widest
+    # point), the values' product (M, V) and the rescaled accumulator
+    operands = (q_abs, q_rope, *[c_pool] * KB, *[kr_pool] * KB)
     need = 2 * sum(_vmem_bytes(spec.block_shape, a.dtype) for spec, a in
                    zip(in_specs + [out_spec], operands + (out_shape,)))
     need += sum(_vmem_bytes(s.shape, s.dtype) for s in scratch)
-    need += 6 * _vmem_bytes((M, ps), jnp.float32)
+    need += _vmem_bytes((W, V + dr), c_pool.dtype)
+    need += 6 * _vmem_bytes((M, W), jnp.float32)
     need += 2 * _vmem_bytes((M, V), jnp.float32)
     if need > _VMEM_SCOPE_CEILING:
         raise ValueError(
@@ -1897,7 +1967,7 @@ def mla_paged_attention(
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(R, C // TC, NP),
+            grid=(R, C // TC, NB),
             in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=scratch,

@@ -654,15 +654,27 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
 
 
 @pytest.mark.parametrize("C", [1, 128])
-def test_mla_paged_kernel_compiles(chip, C):
+def test_mla_paged_kernel_compiles(chip, C, monkeypatch):
     """serve/kernels.mla_paged_attention at the published widths (128
     heads on one line of 512 + 64 a token) and the benchmark cell's
     shapes (4 slots of 82 logical pages of 128, five layers' pool as
     one view with a row offset): Mosaic takes the paired rope keys'
     lane halves, the 2048-row tile's accumulators and the page index
-    map that stops at a tile's last real query."""
+    maps that stop at a tile's last real query. ONE call, whose grid is
+    as deep as the table's blocks of pages (``kernels.mla_block``) and
+    whose stated VMEM is under the scope's ceiling."""
     slots, pages, layers = 4, 82, 5
     rows = layers * (slots * 81 + 1)
+    tc, kb = kernels.mla_block(C, pages, 128, PAGE)
+    assert (tc, kb) == {1: (1, 8), 128: (32, 2)}[C]
+    stated = {}
+    params, spec = kernels.pltpu.CompilerParams, kernels.pltpu.PrefetchScalarGridSpec
+    monkeypatch.setattr(
+        kernels.pltpu, "CompilerParams",
+        lambda **kw: stated.update(vmem=kw["vmem_limit_bytes"]) or params(**kw))
+    monkeypatch.setattr(
+        kernels.pltpu, "PrefetchScalarGridSpec",
+        lambda **kw: stated.update(grid=kw["grid"]) or spec(**kw))
     fn = functools.partial(kernels.mla_paged_attention, scale=0.1,
                            row_offset=jnp.int32(325))
     _, text = _compile(
@@ -674,6 +686,8 @@ def test_mla_paged_kernel_compiles(chip, C):
         chip((slots,), jnp.int32), chip((slots,), jnp.int32))
     assert f"%ff_mla_paged_c{C}" in text
     assert text.count("tpu_custom_call") == 1
+    assert stated["grid"] == (slots, C // tc, -(-pages // kb))
+    assert stated["vmem"] <= kernels._VMEM_SCOPE_CEILING
 
 
 @pytest.mark.parametrize("C, pack", [(1, None), (128, 128), (128, None)])

@@ -257,6 +257,66 @@ def test_absorbed_attention_over_the_pool_is_expanded_attention(twin):
         assert not np.asarray(o[r, n:]).any()      # padding columns come out zero
 
 
+# the block of pages a grid step attends (kernels.mla_block): each case
+# gives rows as (last position, real queries as a share of the chunk),
+# in units of the block's KB pages of LINES lines
+LINES, HEADS, LINE, ROPE = 16, 2, 256, 8
+KERNEL_CASES = {
+    # the table's last block holds 3 pages of KB; a row ends in it
+    "pages_no_multiple_of_the_block": (lambda kb: 2 * kb + 3, [
+        (lambda kb: (2 * kb + 2) * LINES + 5, 1.0), (lambda kb: 2 * kb * LINES, 1.0),
+        (lambda kb: kb * LINES + 3, 0.5), (lambda kb: 7, 0.0)]),
+    "last_query_in_the_first_page_of_a_block": (lambda kb: 3 * kb, [
+        (lambda kb: kb * LINES + 2, 1.0), (lambda kb: 2 * kb * LINES, 1.0),
+        (lambda kb: kb * LINES + LINES - 1, 0.5), (lambda kb: kb * LINES, 0.5)]),
+    "last_query_in_the_last_page_of_a_block": (lambda kb: 3 * kb, [
+        (lambda kb: 2 * kb * LINES - 1, 1.0), (lambda kb: 3 * kb * LINES - 2, 1.0),
+        (lambda kb: 2 * kb * LINES - LINES, 0.5), (lambda kb: kb * LINES - 3, 1.0)]),
+    "a_context_of_exactly_one_block": (lambda kb: 2 * kb, [
+        (lambda kb: kb * LINES - 1, 1.0), (lambda kb: kb * LINES - 1, 0.5),
+        (lambda kb: kb * LINES, 1.0), (lambda kb: kb * LINES - 1, 0.0)]),
+    "a_decode_row_beside_prefilling_rows": (lambda kb: 2 * kb + 1, [
+        (lambda kb: kb * LINES + 40, 1.0), (lambda kb: 2 * kb * LINES + 9, 0.01),
+        (lambda kb: 31, 1.0), (lambda kb: kb * LINES - 1, 0.01)]),
+    "padding_rows": (lambda kb: kb + 2, [
+        (lambda kb: 0, 0.0), (lambda kb: kb * LINES + 20, 1.0),
+        (lambda kb: 0, 0.0), (lambda kb: 0, 0.0)]),
+}
+
+
+@pytest.mark.parametrize("C", [1, 8, 32])
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_latent_kernel_by_blocks_is_its_twin(case, C):
+    """``mla_paged_attention`` (interpret mode) against
+    ``mla_paged_attention_xla`` in float32 where the block of pages a
+    grid step attends matters: at C = 32 a row's two query tiles walk
+    different block counts; the pools are three layers' pages and the
+    table's entry 0 is the second layer's first row."""
+    pages, rows = KERNEL_CASES[case]
+    _, kb = kernels.mla_block(C, 64, HEADS, LINES)
+    NP, layers, R = pages(kb), 3, len(rows)
+    assert kb > 1 and NP >= kb
+    lens = [min(C, max(1, math.ceil(share * C))) if share else 0 for _, share in rows]
+    starts = [max(last(kb) - n + 1, 0) for (last, _), n in zip(rows, lens)]
+    assert all(s + n <= NP * LINES for s, n in zip(starts, lens))
+    per = R * NP + 1                                # a layer's rows, its scratch page last
+    k = jax.random.split(jax.random.PRNGKey(5), 4)
+    q_abs = jax.random.normal(k[0], (R, C, HEADS, LINE))
+    q_rope = jax.random.normal(k[1], (R, C, HEADS, ROPE))
+    c_pool = jax.random.normal(k[2], (layers * per, LINES, LINE))
+    kr_pool = jax.random.normal(k[3], (layers * per, LINES // 2, 2 * ROPE))
+    table = jnp.asarray(np.random.default_rng(9).permutation(R * NP).reshape(R, NP), jnp.int32)
+    args = (table, jnp.asarray(starts, jnp.int32), jnp.asarray(lens, jnp.int32))
+    got = kernels.mla_paged_attention(q_abs, q_rope, c_pool, kr_pool, *args, scale=0.07,
+                                      row_offset=jnp.int32(per))
+    want = kernels.mla_paged_attention_xla(q_abs, q_rope, c_pool[per:2 * per],
+                                           kr_pool[per:2 * per], *args, scale=0.07)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=2e-5)
+    for r, n in enumerate(lens):
+        assert not np.asarray(got[r, n:]).any()     # padding columns come out zero
+        assert n == 0 or np.asarray(got[r, :n]).any()
+
+
 # --- (c) the choice by groups -------------------------------------------------
 
 
