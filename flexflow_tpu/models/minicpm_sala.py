@@ -100,6 +100,9 @@ LIGHTNING, SPARSE = "lightning-attn", "minicpm4"
 # the cache entries that are per SLOT, not per page: the engine passes
 # its slot count to ``init_paged_kv_cache`` and counts their bytes apart
 SLOT_STATE = ("state", "kbar", "chosen")
+# the one of them a real token updates by a recurrence
+# (SchedulerStats.recurrent_updates)
+RECURRENT_STATE = "state"
 FUSED_DECODE = ()
 HIGHEST = lax.Precision.HIGHEST
 

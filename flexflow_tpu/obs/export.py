@@ -115,7 +115,7 @@ SCHED_COUNTERS = frozenset({
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
     "step_tokens_real", "step_tokens_width",
     "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max",
-    "latent_lines", "head_steps", "head_greedy_steps",
+    "latent_lines", "recurrent_updates", "head_steps", "head_greedy_steps",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({

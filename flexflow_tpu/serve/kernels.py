@@ -553,6 +553,7 @@ def _build_ragged_paged_kernel(
     pack: int = 1,
     group_mask: bool = False,
     merged_heads: int = 0,
+    head_blocks: bool = False,
 ):
     """ONE builder for every Pallas variant of the ragged paged kernel
     (see the module-docstring matrix): ``quant`` folds the per-page
@@ -577,8 +578,11 @@ def _build_ragged_paged_kernel(
     blocks are (ps, KV*dk), a line's heads side by side on the minor
     axis (a pool of head size under a lane tile, see
     :func:`_ragged_paged_attention`), and the body takes each head's dk
-    lanes out after the load."""
+    lanes out after the load. ``head_blocks`` (the plain kernel): the
+    grid has a leading axis over blocks of KV heads, so rows and pages
+    are its axes 1 and 2 (:func:`_ragged_paged_attention`)."""
     narrow = narrow_query_extent(C)
+    row_axis, page_axis = (1, 2) if head_blocks else (0, 1)
 
     def _heads_major(block):
         # a loaded page block as (KV, ps, dk) float32
@@ -648,7 +652,7 @@ def _build_ragged_paged_kernel(
     def _finalize(p, out_ref, o_scr, l_scr, n=C):
         # the rows past a narrow body's n never attended and are what
         # _init and this divide would give them: zero
-        @pl.when(p == pl.num_programs(1) - 1)
+        @pl.when(p == pl.num_programs(page_axis) - 1)
         def _():
             l = jnp.maximum(l_scr[:n], 1e-20)
             out_ref[0, :n] = (o_scr[:n] / l[..., None]).astype(out_ref.dtype)
@@ -706,7 +710,7 @@ def _build_ragged_paged_kernel(
         out_ref = refs[i]; i += 1       # (1, C, KV, G, dk)
         o_scr, m_scr, l_scr = refs[i:i + 3]
 
-        p = pl.program_id(1)
+        p = pl.program_id(page_axis)
 
         def step(n, q_len=None):
             # this grid step over the row's first n queries: n is a
@@ -741,7 +745,7 @@ def _build_ragged_paged_kernel(
         if q_len_ref is None:
             step(C)
         else:
-            q_len = q_len_ref[pl.program_id(0)]
+            q_len = q_len_ref[pl.program_id(row_axis)]
             if not narrow:
                 step(C, q_len)
             else:
@@ -869,9 +873,9 @@ def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
     return scale.astype(jnp.float32)[:, None, :]
 
 
-def _ragged_vmem_limit(specs, arrays, scratch, C, H, width) -> int:
-    """``vmem_limit_bytes`` of a ragged paged kernel: its blocks double-
-    buffered, its scratch, and the attention body's float32
+def _ragged_vmem_need(specs, arrays, scratch, C, H, width) -> int:
+    """VMEM bytes a grid step of a ragged paged kernel: its blocks
+    double-buffered, its scratch, and the attention body's float32
     intermediates — each one (C, H, max(dk, ps)) tile, about a dozen
     live at the widest point (q and its grouped transpose, the scores,
     their masked/exponentiated/transposed forms, pv)."""
@@ -880,7 +884,13 @@ def _ragged_vmem_limit(specs, arrays, scratch, C, H, width) -> int:
         for spec, a in zip(specs, arrays)
     )
     need += sum(_vmem_bytes(s.shape, s.dtype) for s in scratch)
-    need += 12 * _vmem_bytes((C * H, width), jnp.float32)
+    return need + 12 * _vmem_bytes((C * H, width), jnp.float32)
+
+
+def _ragged_vmem_limit(specs, arrays, scratch, C, H, width) -> int:
+    """``vmem_limit_bytes`` of a ragged paged kernel
+    (:func:`_ragged_vmem_need`), refused over the ceiling."""
+    need = _ragged_vmem_need(specs, arrays, scratch, C, H, width)
     if need > _VMEM_SCOPE_CEILING:
         raise ValueError(
             f"ragged paged attention at C={C} rows x H={H} heads needs "
@@ -1013,57 +1023,86 @@ def _ragged_paged_attention(
     pack = dk // dkp if k_scale is not None else 1
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
     qg = q.reshape(R, C, KV, G, dk)
-    grid = (R, NP)
     prefetch = [page_table.astype(jnp.int32)]
     if row_offset is not None:
         prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
     if q_len is not None:  # last, so the index maps' ``base`` stays put
         prefetch.append(q_len.astype(jnp.int32))
-
-    def page(r, p, pt, *base):
-        # the paged gather: block row = page_table[r, p] (+ row_offset)
-        row = pt[r, p] + base[0][0] if row_offset is not None else pt[r, p]
-        return (row,) + (0,) * (k_pool.ndim - 1)
-
-    lines = (1, ps, KV * dk) if merged else (1, ps, KV, dkp)
-    in_specs = [
-        pl.BlockSpec((1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)),
-        pl.BlockSpec(lines, page),
-        pl.BlockSpec(lines, page),
-    ]
     operands = [qg, k_pool, v_pool]
-    body = _build_ragged_paged_kernel(
-        quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack,
-        group_mask=group_mask, merged_heads=KV if merged else 0,
-    )
-
-    def kernel(*refs):  # the body knows the table and the query lengths
-        body(refs[0], *refs[len(prefetch):],
-             q_len_ref=None if q_len is None else refs[len(prefetch) - 1])
-
     if k_scale is not None:
         # per-page scales as (P+1, KV, 1, 1): the block hands the body a
         # (KV, 1, 1) value that broadcasts over the (KV, C*G, ps) scores
         # as it is — Mosaic has no relayout from a (1, KV) lane vector
         # to that leading dim ("unsupported shape cast")
-        scale_spec = pl.BlockSpec((1, KV, 1, 1), page)
-        in_specs += [scale_spec, scale_spec]
         operands += [
             k_scale.astype(jnp.float32)[:, :, None, None],
             v_scale.astype(jnp.float32)[:, :, None, None],
         ]
     rows = KV * C if group_mask else C  # (R, KV, C, S) as (R, KV*C, S)
-    in_specs.append(pl.BlockSpec((1, rows, ps), lambda r, p, *_: (r, 0, p)))
     operands.append(mask.reshape(R, rows, -1))
     out_shape = jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype)
-    out_spec = pl.BlockSpec(
-        (1, C, KV, G, dk), lambda r, p, *_: (r, 0, 0, 0, 0)
+
+    def blocks_of(KVb):
+        """(grid, in_specs, out_spec, scratch) with ``KVb`` KV heads a
+        grid step: all of them on the (row, page) grid, or a block of
+        them under a leading grid axis over the blocks (merged pools:
+        a block's lanes of a line are a block of the minor axis)."""
+        split = KVb < KV
+
+        def at(index_map):  # (head block, row, page, *prefetch) -> block
+            if split:
+                return index_map
+            return lambda r, p, *pre: index_map(0, r, p, *pre)
+
+        def page(b, r, p, pt, *base):
+            # the paged gather: block row = page_table[r, p] (+ row_offset)
+            row = pt[r, p] + base[0][0] if row_offset is not None else pt[r, p]
+            return (row, 0, b) if merged else (row,) + (0,) * (k_pool.ndim - 1)
+
+        heads = at(lambda b, r, p, *_: (r, 0, b, 0, 0))
+        lines = (1, ps, KVb * dk) if merged else (1, ps, KV, dkp)
+        in_specs = [
+            pl.BlockSpec((1, C, KVb, G, dk), heads),
+            pl.BlockSpec(lines, at(page)),
+            pl.BlockSpec(lines, at(page)),
+        ]
+        if k_scale is not None:
+            in_specs += [pl.BlockSpec((1, KV, 1, 1), at(page))] * 2
+        in_specs.append(pl.BlockSpec(
+            (1, rows, ps), at(lambda b, r, p, *_: (r, 0, p))))
+        out_spec = pl.BlockSpec((1, C, KVb, G, dk), heads)
+        scratch = [
+            pltpu.VMEM((C, KVb, G, dk), jnp.float32),
+            pltpu.VMEM((C, KVb, G), jnp.float32),
+            pltpu.VMEM((C, KVb, G), jnp.float32),
+        ]
+        grid = (KV // KVb, R, NP) if split else (R, NP)
+        return grid, in_specs, out_spec, scratch
+
+    def vmem(KVb, need=_ragged_vmem_limit):
+        _, in_specs, out_spec, scratch = blocks_of(KVb)
+        return need(in_specs + [out_spec], operands + [out_shape], scratch,
+                    C, KVb * G, max(dk, ps))
+
+    # every KV head a grid step; merged pools whose heads do not fit the
+    # fast memory at once (30 K/V heads of 128 at C=128, a query block
+    # of one head a group: models/olmo_hybrid.py) the largest block of
+    # them that does, the same kernel name and result
+    KVb = KV
+    if merged:
+        KVb = next((KV // n for n in range(1, KV + 1) if KV % n == 0
+                    and vmem(KV // n, _ragged_vmem_need) <= _VMEM_SCOPE_CEILING),
+                   1)
+    grid, in_specs, out_spec, scratch = blocks_of(KVb)
+    body = _build_ragged_paged_kernel(
+        quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack,
+        group_mask=group_mask, merged_heads=KVb if merged else 0,
+        head_blocks=KVb < KV,
     )
-    scratch = [
-        pltpu.VMEM((C, KV, G, dk), jnp.float32),
-        pltpu.VMEM((C, KV, G), jnp.float32),
-        pltpu.VMEM((C, KV, G), jnp.float32),
-    ]
+
+    def kernel(*refs):  # the body knows the table and the query lengths
+        body(refs[0], *refs[len(prefetch):],
+             q_len_ref=None if q_len is None else refs[len(prefetch) - 1])
 
     out = pl.pallas_call(
         kernel,
@@ -1076,10 +1115,7 @@ def _ragged_paged_attention(
             scratch_shapes=scratch,
         ),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_ragged_vmem_limit(
-                in_specs + [out_spec], operands + [out_shape], scratch,
-                C, H, max(dk, ps),
-            ),
+            vmem_limit_bytes=vmem(KVb),
         ),
         name=("ff_sparse_paged" if group_mask else "ff_ragged_paged")
              + f"_c{C}" + _quant_suffix(k_scale is not None, pack),

@@ -169,6 +169,10 @@ class RequestManager:
         self._latent_layers = (
             engine.cache["latent"].shape[0]
             if "latent" in getattr(engine.model, "PAGE_POOLS", ()) else 0)
+        # layers that step a recurrent state (SchedulerStats.recurrent_updates)
+        recurrent = getattr(engine.model, "RECURRENT_STATE", None)
+        self._recurrent_layers = (
+            engine.cache[recurrent].shape[0] if recurrent else 0)
         self._log = get_logger("serve")
         # Observability (flexflow_tpu/obs): request-lifecycle tracing +
         # failure flight recorder. Disabled by default — every EVENT
@@ -1036,6 +1040,9 @@ class RequestManager:
         self._note_attn_steps(positions[:, 0], real, 1)
         if self._latent_layers:
             self.stats.latent_lines += int(real.sum()) * self._latent_layers
+        if self._recurrent_layers:
+            self.stats.recurrent_updates += (
+                int(real.sum()) * self._recurrent_layers)
         tr = self.tracer
         if tr.enabled:
             tr.event("decode_step", rows=len(decoding))
@@ -1155,6 +1162,7 @@ class RequestManager:
         real = int(bc.qlens.sum())
         self.stats.note_step_tokens(real, eng.pack_width(real, C))
         self.stats.latent_lines += real * self._latent_layers
+        self.stats.recurrent_updates += real * self._recurrent_layers
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,

@@ -554,6 +554,63 @@ def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
     assert temp < 3 * experts.size // experts.shape[0] * experts.dtype.itemsize
 
 
+# --- Gated DeltaNet layers beside full attention (Olmo-Hybrid) ---------------
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 2048)])
+def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
+    """models/olmo_hybrid.py at published widths (30 heads of 128 with
+    as many K/V heads, 30 recurrent heads of 96 x 192, the whole
+    vocabulary), five layers (three recurrent, attention, one
+    recurrent: both kinds of run), the benchmark cell's 64 slots of 8
+    pages, the decode step and a packed rung: the ragged paged kernel is
+    the program's ONLY kind of kernel call and its result is [slots,
+    chunk, ...] (the trace reduction keys the step by it; at C = 128
+    thirty heads of one query a group pass the fast memory at once, and
+    the call takes them in blocks under one name), and the loop's carry
+    is updated in place: no copy of the K/V pools, of the recurrent
+    state stack (0.57 GB here, 1.27 GB at the cell's nine layers) or of
+    the convolution states, no relayout of a pool, temporaries (a packed
+    rung's activations: 2048 tokens' q, k and v in float32 are 94 MB)
+    under two layers' states, where a second state stack would be four."""
+    from flexflow_tpu.models import olmo_hybrid as fam
+
+    L, A = fam.LINEAR, fam.ATTENTION
+    cfg = fam.config(num_hidden_layers=5, layer_types=(L, L, L, A, L),
+                     dtype=jnp.bfloat16)
+    slots, pages, cache_len = 64, 8, 1024
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        num_slots=slots, cache_len=cache_len)), chip)
+    assert cache["state"].shape == (4, 64, 30, 96, 192)
+    assert cache["state"].dtype == jnp.float32
+    assert cache["conv"].shape == (4, 3, 64, 11520)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", text)
+    assert f"%ff_ragged_paged_c{C}" in text and len(calls) == 1, calls
+    assert f"[{slots},{C},30,1,128]" in calls[0]
+    layer = cache["state"].shape[1:]
+    for a in (cache["k"], cache["v"], cache["state"], cache["conv"],
+              jax.ShapeDtypeStruct(layer, jnp.float32)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * np.prod(layer) * 4, temp
+
+
 # --- the generic decoder's sparse layer with its tokens routed (Mixtral) ----
 
 

@@ -19,6 +19,7 @@ from flexflow_tpu.models import (
     minicpm_sala,
     mistral,
     mixtral,
+    olmo_hybrid,
 )
 from flexflow_tpu.obs import sublayers
 from flexflow_tpu.obs.sublayers import (
@@ -40,6 +41,7 @@ FAMILIES = {
     "minicpm_sala": (minicpm_sala, ALWAYS | {"ff.mixer", "ff.attn.select"}),
     "lfm2_moe": (lfm2_moe, ALWAYS | {"ff.mixer", "ff.moe.route"}),
     "deepseek_v3": (deepseek_v3, ALWAYS | {"ff.moe.route"}),
+    "olmo_hybrid": (olmo_hybrid, ALWAYS | {"ff.mixer"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
