@@ -665,17 +665,27 @@ def pack_widths(slots: int, chunk: int) -> Tuple[int, ...]:
     """The widths a (slots, chunk) mixed step's token axis is compiled
     at, ascending: a quarter, a half and the whole of ``slots * chunk``
     (the whole is the padded step: what fits there fits today), less
-    any rung narrower than one chunk. From the two extents alone; a
-    step takes the narrowest rung that holds its real tokens
-    (:meth:`InferenceEngine.run_mixed`). Three rungs because each is a
-    program traced and lowered in set-up (0.75-1 s of host time a rung
-    with a warm compile cache, PERF.md section 6), by halves from the
-    top because a closed loop fills about a quarter of a mixed step.
-    One rung is no ladder: ``chunk == 1``, a single slot."""
+    any rung narrower than one chunk, and under them the ADMISSION
+    rung: the places of a step in which every slot decodes but the one
+    whose prompt's chunk rides along, ``slots + chunk`` rounded up to a
+    power of two, where that is narrower than the quarter rung (64 x
+    128: 256 under 2048, 4096, 8192; 16 x 128: 256 under 512; 4 x 128:
+    none, its quarter is 128). From the two extents alone; a step
+    takes the narrowest rung that holds its real tokens
+    (:meth:`InferenceEngine.run_mixed`). Few rungs because each is a
+    program traced and lowered in set-up (with a warm compile cache
+    0.4-1.2 s of host time a rung at 16 slots, 2.5-4.6 s for the
+    64-slot families' longer programs, PERF.md section 6), by halves from the
+    top because a closed loop of prompts fills about a quarter of a
+    mixed step, one at the bottom because a closed loop of decoding
+    rows fills slots + chunk whatever the slots: a fiftieth of 64 x
+    128. One rung is no ladder: ``chunk == 1``, a single slot."""
     top = slots * chunk
     if chunk == 1:
         return (top,)
-    return tuple(w for w in (-(-top // 4), -(-top // 2), top) if w >= chunk)
+    rungs = tuple(w for w in (-(-top // 4), -(-top // 2), top) if w >= chunk)
+    admission = 1 << (slots + chunk - 1).bit_length()
+    return (admission,) + rungs if admission < rungs[0] else rungs
 
 
 def _abstract(tree):
@@ -698,7 +708,8 @@ def program_name(key: Any) -> str:
     line. Every per-step program starts ``ff_step_``; ``c<n>`` is the
     chunk — ``ff_step_c1`` the pipelined decode step, ``ff_step_c128``
     the pipelined mixed step at ``mixed_chunk=128``, ``ff_step_c128_t512``
-    that step with its token axis packed at 512 places
+    that step with its token axis packed at 512 places and
+    ``ff_step_c128_t256`` at the admission rung's 256
     (:func:`pack_widths`). Those are the programs with the argmax head,
     the one an all-greedy batch takes (serve/sampling.py): the greedy
     head is the unmarked one, and a program whose head samples carries

@@ -276,12 +276,15 @@ def _pair_rows(pairs, experts):
 
 @pytest.mark.parametrize("family", ["mistral", "mixtral"])
 def test_packed_rungs_compile_with_the_pool_in_place(chip, family):
-    """Every rung of the (16, 128) ladder (ISSUE 32) at published
-    widths, two layers: the kernel's call keeps its (slots, chunk) shape
-    and its name (the benchmark finds the step program by them), the
-    pool stays the loop's carry updated in place, the matmuls run at the
-    rung's width, and no rung needs more of the device than the padded
-    step, which is what ``benchmarks/tools/fit.py`` sizes a depth by."""
+    """Every rung of the (16, 128) ladder (ISSUE 32; the admission
+    rung's 256 places under them, ISSUE 45) at published widths, two
+    layers: the kernel's call keeps its (slots, chunk) shape and its
+    name (the benchmark finds the step program by them), the pool stays
+    the loop's carry updated in place, the matmuls run at the rung's
+    width (a sparse model's over the rung's routed pairs: at 256 places
+    they are 64 an expert, so the 16-row tile), and no rung needs more
+    of the device than the padded step, which is what
+    ``benchmarks/tools/fit.py`` sizes a depth by."""
     from flexflow_tpu.models import mixtral
     from flexflow_tpu.serve.engine import pack_widths
 
@@ -294,7 +297,7 @@ def test_packed_rungs_compile_with_the_pool_in_place(chip, family):
             mixtral.init_params, cfg=cfg), jax.random.PRNGKey(0)), chip),
         ) + args[1:]
     *rungs, top = pack_widths(R, 128)
-    assert (rungs, top) == ([512, 1024], 2048)
+    assert (rungs, top) == ([256, 512, 1024], 2048)
 
     def step(pack):
         def fn(params, cache, tokens, positions, logits_idx, page_table):
@@ -494,12 +497,15 @@ def test_minicpm_sala_hybrid_step_compiles_in_place(chip, C):
 # --- conv layers beside attention layers, routed experts (LFM2-MoE) ---------
 
 
-@pytest.mark.parametrize("C, pack", [(1, None), (128, None), (128, 2048)])
+@pytest.mark.parametrize("C, pack", [(1, None), (128, None), (128, 2048),
+                                     (128, 256)])
 def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
     """models/lfm2_moe.py at published widths (head size 64, 64 experts
     of 1536, the whole vocabulary), five layers (a dense conv layer,
     then attention, two conv, attention: every kind of run), the
-    benchmark cell's 64 slots of 8 pages, padded and on a packed rung:
+    benchmark cell's 64 slots of 8 pages, padded, on a packed rung and
+    on the admission rung (ISSUE 45: 256 places, whose 1024 pairs are
+    16 an expert, so the grouped calls are the decode step's ``_t16``):
     the ragged paged kernel is in the program by name at head size 64
     and is its FIRST kernel call (the trace reduction finds the step by
     it), the grouped expert matmuls (``ff_moe_grouped_*``) follow, there
@@ -557,13 +563,14 @@ def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
 # --- Gated DeltaNet layers beside full attention (Olmo-Hybrid) ---------------
 
 
-@pytest.mark.parametrize("C, pack", [(1, None), (128, 2048)])
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 2048), (128, 256)])
 def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
     """models/olmo_hybrid.py at published widths (30 heads of 128 with
     as many K/V heads, 30 recurrent heads of 96 x 192, the whole
     vocabulary), five layers (three recurrent, attention, one
     recurrent: both kinds of run), the benchmark cell's 64 slots of 8
-    pages, the decode step and a packed rung: the ragged paged kernel is
+    pages, the decode step, a packed rung and the admission rung (ISSUE
+    45: 256 places): the ragged paged kernel is
     the program's ONLY kind of kernel call and its result is [slots,
     chunk, ...] (the trace reduction keys the step by it; at C = 128
     thirty heads of one query a group pass the fast memory at once, and
@@ -572,7 +579,8 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
     state stack (0.57 GB here, 1.27 GB at the cell's nine layers) or of
     the convolution states, no relayout of a pool, temporaries (a packed
     rung's activations: 2048 tokens' q, k and v in float32 are 94 MB)
-    under two layers' states, where a second state stack would be four."""
+    under two layers' states, where a second state stack would be four
+    (the admission rung: no more than the padded step's)."""
     from flexflow_tpu.models import olmo_hybrid as fam
 
     L, A = fam.LINEAR, fam.ATTENTION
@@ -589,16 +597,19 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
     assert cache["state"].dtype == jnp.float32
     assert cache["conv"].shape == (4, 3, 64, 11520)
 
-    def step(params, cache, tokens, positions, logits_idx, page_table):
-        return fam.serve_step_paged(
-            params, cache, tokens, positions, logits_idx, None, None,
-            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
-            pack=pack)
+    def compile_at(pack):
+        def step(params, cache, tokens, positions, logits_idx, page_table):
+            return fam.serve_step_paged(
+                params, cache, tokens, positions, logits_idx, None, None,
+                page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
+                pack=pack)
 
-    compiled, text = _compile(
-        step, params, cache, chip((slots, C), jnp.int32),
-        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
-        chip((slots, pages), jnp.int32), donate=(1,))
+        return _compile(
+            step, params, cache, chip((slots, C), jnp.int32),
+            chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+            chip((slots, pages), jnp.int32), donate=(1,))
+
+    compiled, text = compile_at(pack)
     calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", text)
     assert f"%ff_ragged_paged_c{C}" in text and len(calls) == 1, calls
     assert f"[{slots},{C},30,1,128]" in calls[0]
@@ -608,20 +619,29 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
         dims = ",".join(map(str, a.shape))
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 2 * np.prod(layer) * 4, temp
+    if pack == 256:
+        # the admission rung is held to what its issue asks: no more
+        # than the padded step's (292 MB against 1.37 GB, compiled here,
+        # PR 45; two layers' states are 283 MB)
+        padded, _ = compile_at(None)
+        assert temp <= padded.memory_analysis().temp_size_in_bytes, temp
+    else:
+        assert temp < 2 * np.prod(layer) * 4, temp
 
 
 # --- the generic decoder's sparse layer with its tokens routed (Mixtral) ----
 
 
-@pytest.mark.parametrize("C, pack", [(1, None), (128, 512), (128, 1024),
-                                     (128, None)])
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 256), (128, 512),
+                                     (128, 1024), (128, None)])
 def test_mixtral_routed_step_compiles_in_place(chip, C, pack):
     """models/mixtral.py at published widths (4096 / 14336, 8 experts,
-    top-2), two layers, the benchmark cell's 16 slots. Both packed
-    rungs and the padded step send their real tokens' pairs through the
+    top-2), two layers, the benchmark cell's 16 slots. Every packed
+    rung and the padded step send their real tokens' pairs through the
     grouped expert matmuls (``ff_moe_grouped_*_t128``: from the 512
-    rung on the static pairs are a 128-row tile an expert), the
+    rung on the static pairs are a 128-row tile an expert; the
+    admission rung's 512 pairs are 64 an expert and take ``_t16``, a
+    read of the experts' weights as LFM2's decode step is), the
     attention call stays the program's FIRST kernel call (the trace
     reduction finds the step by it) and there is no all-expert product;
     the C=1 step (32 pairs: under a tile an expert) keeps the einsum.
@@ -650,14 +670,15 @@ def test_mixtral_routed_step_compiles_in_place(chip, C, pack):
         assert calls == ["ff_ragged_paged_c1"], calls
         assert re.findall(rf"\[{tokens},8,14336\]", text)
     else:
-        assert kernels.grouped_tile(2 * tokens, 8) == 128
-        assert calls == [f"ff_ragged_paged_c{C}", "ff_moe_grouped_glu_t128",
-                         "ff_moe_grouped_down_t128"], calls
+        tm = kernels.grouped_tile(2 * tokens, 8)
+        assert tm == (16 if tokens == 256 else 128)
+        assert calls == [f"ff_ragged_paged_c{C}", f"ff_moe_grouped_glu_t{tm}",
+                         f"ff_moe_grouped_down_t{tm}"], calls
         rows = _pair_rows(2 * tokens, 8)
         assert re.findall(
-            rf"%ff_moe_grouped_glu_t128\S* = bf16\[{rows},14336\]", text)
+            rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},14336\]", text)
         assert re.findall(
-            rf"%ff_moe_grouped_down_t128\S* = f32\[{rows},4096\]", text)
+            rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},4096\]", text)
         assert not re.findall(r"\[\d+,8,14336\]", text)  # no all-expert product
     _assert_pool_carried(text, args[1]["k"])
     for name in ("w_gate", "w_up", "w_down"):

@@ -38,11 +38,12 @@ def model(request):
     return mod, cfg, mod.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _engine(model, kernels="xla", pack=True, **kw):
+def _engine(model, kernels="xla", pack=True, slots=R, chunk=C, **kw):
     mod, cfg, params = model
     sc = ServingConfig(
-        max_requests_per_batch=R, max_sequence_length=56, prefill_chunk=C,
-        max_spec_tree_tokens=8, cache_dtype=jnp.float32, kv_layout="paged",
+        max_requests_per_batch=slots, max_sequence_length=56,
+        prefill_chunk=chunk, max_spec_tree_tokens=8,
+        cache_dtype=jnp.float32, kv_layout="paged",
         page_size=PS, kernels=kernels, **kw,
     )
     eng = InferenceEngine(mod, cfg, params, sc)
@@ -64,7 +65,9 @@ def _engine(model, kernels="xla", pack=True, **kw):
 
 
 def _run(eng, feed, done, seqs):
-    """One (R, C) mixed step with logits: ``feed`` row -> new tokens."""
+    """One (slots, chunk) mixed step with logits: ``feed`` row -> new
+    tokens."""
+    R, C = eng.num_slots, eng.serving.mixed_chunk
     toks = np.zeros((R, C), np.int32)
     pos = np.full((R, C), eng.scratch_pos, np.int32)
     idx = np.zeros((R,), np.int32)
@@ -111,16 +114,37 @@ FEEDS = [
     ({0: 1, 3: 1, 5: 7}, 12),
 ]
 
+# 12 slots x chunk 16, whose ladder (32, 48, 96, 192) has the ADMISSION
+# rung (ISSUE 45: slots + chunk = 28, rounded up, under the quarter's
+# 48): feeds that fill it exactly, sit well inside it, go one token
+# over it, and the step it is named for (every other slot on one token
+# beside one prompt's chunk); then one that fills a rung above
+WIDE = (12, 16)
+FEEDS_WIDE = [
+    ({0: 16, 1: 16}, 32),                     # exactly fills the admission rung
+    ({0: 1, 1: 1, 2: 5}, 32),                 # well inside it
+    ({0: 1, 1: 1, 2: 1, 3: 16, 4: 14}, 48),   # one token over it
+    ({**{r: 1 for r in range(11)}, 11: 16}, 32),
+    ({r: 8 for r in range(12)}, 96),
+]
+
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_every_rung_matches_the_padded_program(model, kernels):
-    assert pack_widths(R, C) == (12, 24, 48)
-    packed, padded = _engine(model, kernels), _engine(model, kernels, False)
-    assert packed.pack_ladder(C) == (12, 24) and packed.pack_ladder(1) == ()
+@pytest.mark.parametrize("geometry, feeds, ladder", [
+    ((R, C), FEEDS, (12, 24, 48)), (WIDE, FEEDS_WIDE, (32, 48, 96, 192))],
+    ids=["6x8", "12x16-admission"])
+def test_every_rung_matches_the_padded_program(model, kernels, geometry,
+                                               feeds, ladder):
+    R, C = geometry
+    assert pack_widths(R, C) == ladder
+    packed = _engine(model, kernels, slots=R, chunk=C)
+    padded = _engine(model, kernels, False, slots=R, chunk=C)
+    assert packed.pack_ladder(C) == ladder[:-1]
+    assert packed.pack_ladder(1) == ()
     rng = np.random.default_rng(3)
     seqs = [list(rng.integers(1, 250, 56)) for _ in range(R)]
     done = {r: 0 for r in range(R)}
-    for feed, width in FEEDS:
+    for feed, width in feeds:
         a = _run(packed, feed, done, seqs)
         b = _run(padded, feed, done, seqs)
         real = sum(feed.values())
@@ -135,8 +159,9 @@ def test_every_rung_matches_the_padded_program(model, kernels):
     names = {program_name(k) for k in packed._steps}
     # a rung is ONE program for the probe and the server; the padded
     # step keeps its sibling with the logits returned
-    assert names == {f"ff_step_c{C}_t12", f"ff_step_c{C}_t24",
-                     f"ff_step_c{C}_logits"}
+    ran = {w for _, w in feeds}
+    padded_ran = {f"ff_step_c{C}_logits"} if R * C in ran else set()
+    assert names == {f"ff_step_c{C}_t{w}" for w in ran - {R * C}} | padded_ran
     assert all(n.startswith(f"ff_step_c{C}") for n in names)
 
 
@@ -212,6 +237,34 @@ def test_scheduler_generations_equal_the_padded_engine(model, kernels):
                       ("mixed_fused", C, False, *g): 1,
                       ("mixed_fused", 1, False, *g): 1,
                       "copy_page": 1}, counts
+
+
+def test_a_house_of_decoding_rows_admits_on_the_admission_rung(model):
+    """A closed loop of decoding rows (ISSUE 45): once the first wave's
+    prompts are in, a mixed step holds one admitted prompt's chunk
+    beside the other slots' single tokens, slots + chunk places at
+    most, and runs at the admission rung; the generations are the
+    padded engine's, every rung compiled once and none after the first
+    mixed dispatch."""
+    R, C = WIDE
+    new = lambda i: 9 + (i * 7) % 11    # answers end one at a time
+    rm, outs = _serve(model, True, n=30, new=new, slots=R, chunk=C)
+    rm0, outs0 = _serve(model, False, n=30, new=new, slots=R, chunk=C)
+    assert outs == outs0
+    s = rm.stats
+    assert set(rm0.stats.steps_by_width) == {R * C}
+    assert s.step_tokens_real == rm0.stats.step_tokens_real
+    assert set(s.steps_by_width) <= set(pack_widths(R, C))
+    assert s.steps_by_width[32] > s.mixed_steps / 2
+    assert s.steps_by_width == collections.Counter(
+        w for w in rm.engine.ran if w != R)           # R: a decode step
+    guard = rm.engine.retrace_guard
+    guard.assert_one_compile_per_key()
+    assert guard.retraces == 0
+    g = ("greedy", 0)
+    assert guard.compile_counts() == {
+        **{("mixed_packed", C, w, *g): 1 for w in (32, 48, 96)},
+        ("mixed_fused", C, False, *g): 1, ("mixed_fused", 1, False, *g): 1}
 
 
 @pytest.mark.parametrize("kw", [
@@ -339,9 +392,10 @@ def test_packed_step_refuses_what_it_cannot_serve(model):
 
 
 @pytest.mark.parametrize("slots, chunk, want", [
-    (16, 128, (512, 1024, 2048)),
-    (4, 128, (128, 256, 512)),
-    (64, 128, (2048, 4096, 8192)),
+    (16, 128, (256, 512, 1024, 2048)),       # the admission rung (ISSUE 45)
+    (4, 128, (128, 256, 512)),               # 256 is not under its quarter
+    (64, 128, (256, 2048, 4096, 8192)),
+    (12, 16, (32, 48, 96, 192)),
     (6, 8, (12, 24, 48)),
     (3, 8, (12, 24)),
     (2, 8, (8, 16)),
@@ -351,8 +405,16 @@ def test_packed_step_refuses_what_it_cannot_serve(model):
 def test_ladder_follows_from_slots_and_chunk(slots, chunk, want):
     got = pack_widths(slots, chunk)
     assert got == want
-    assert got[-1] == slots * chunk and len(got) <= 3
+    assert got[-1] == slots * chunk and list(got) == sorted(set(got))
     assert all(w >= chunk for w in got)
+    # by halves from the top, and under the quarter rung at most ONE
+    # rung, which holds every slot decoding beside one prompt's chunk
+    quarter = -(-slots * chunk // 4)
+    below = [w for w in got if w < quarter]
+    assert set(got) - set(below) <= {quarter, -(-slots * chunk // 2),
+                                     slots * chunk}
+    assert len(below) <= 1 and all(
+        slots + chunk <= w < 2 * (slots + chunk) for w in below)
 
 
 def test_step_token_counters_against_a_hand_counted_schedule():

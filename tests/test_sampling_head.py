@@ -51,11 +51,12 @@ def model(request):
     return mod, cfg, mod.init_params(jax.random.PRNGKey(0), cfg)
 
 
-def _manager(model, **kw):
+def _manager(model, slots=R, chunk=C, **kw):
     mod, cfg, params = model
     return RequestManager(InferenceEngine(mod, cfg, params, ServingConfig(
-        max_requests_per_batch=R, max_sequence_length=48, prefill_chunk=C,
-        max_spec_tree_tokens=8, cache_dtype=jnp.float32, kv_layout="paged",
+        max_requests_per_batch=slots, max_sequence_length=48,
+        prefill_chunk=chunk, max_spec_tree_tokens=8,
+        cache_dtype=jnp.float32, kv_layout="paged",
         page_size=PS, kernels="xla", **kw)))
 
 
@@ -191,10 +192,10 @@ def test_generations_are_the_full_heads(model, kind, head, others,
     assert outs == want
 
 
-def _mixed_run(model):
+def _mixed_run(model, slots=R, chunk=C):
     """Greedy requests decode; a top-k request is admitted among them,
     finishes, and the greedy ones decode on; then a second one."""
-    rm = _manager(model, sanitizers=("retrace",))
+    rm = _manager(model, slots, chunk, sanitizers=("retrace",))
     guard = rm.engine.retrace_guard
     prompts = _prompts(5)
     rids = [rm.submit(p, max_new_tokens=20) for p in prompts[:3]]
@@ -218,8 +219,13 @@ def _mixed_run(model):
     return rm, _finish(rm, rids), log
 
 
-def test_a_mix_that_changes_mid_run(model, monkeypatch):
-    rm, outs, log = _mixed_run(model)
+@pytest.mark.parametrize("R, C", [
+    (R, C),
+    # (32, 48, 96, 192): each head's ladder holds the admission rung
+    # (ISSUE 45), and the admitted top-k row's steps run on it
+    (12, 16)], ids=["4x8", "12x16-admission"])
+def test_a_mix_that_changes_mid_run(model, monkeypatch, R, C):
+    rm, outs, log = _mixed_run(model, R, C)
     assert [len(o) for o in outs] == [20, 20, 20, 3, 3]
     heads = [h for h, _ in log]
     # greedy, then the top-k head while the sampling row is there, then
@@ -241,6 +247,8 @@ def test_a_mix_that_changes_mid_run(model, monkeypatch):
     ladder = eng.pack_ladder(C)
     if ladder:
         assert eng._ladders_compiled == {(C, "greedy", 0), (C, "topk", 8)}
+        assert ladder == {8: (8, 16), 16: (32, 48, 96)}[C]
+        assert C == 8 or set(rm.stats.steps_by_width) == {32}
     counts = guard.compile_counts()
     for head in (("greedy", 0), ("topk", 8)):
         for width in ladder:
@@ -259,7 +267,7 @@ def test_a_mix_that_changes_mid_run(model, monkeypatch):
 
     monkeypatch.setattr(engine_mod, "choose_sample_mode",
                         lambda *a: ("full", 0))
-    ref, want, _ = _mixed_run(model)
+    ref, want, _ = _mixed_run(model, R, C)
     assert _heads(ref.engine) == {("full", 0)}
     assert outs == want
 
