@@ -187,8 +187,9 @@ class SchedulerStats:
     latent_lines: int = 0
     # A family that steps a per-slot state by a recurrence a token (it
     # declares ``RECURRENT_STATE``, the cache entry's name:
-    # models/olmo_hybrid.py, models/minicpm_sala.py): the updates the
-    # pipelined steps made, real tokens x recurrent layers.
+    # models/olmo_hybrid.py, models/minicpm_sala.py,
+    # models/granite_hybrid.py): the updates the pipelined steps made,
+    # real tokens x recurrent layers.
     recurrent_updates: int = 0
     # The sampling head of the pipelined steps (note_head): the mixed
     # and decode dispatches, and those whose batch was all greedy and

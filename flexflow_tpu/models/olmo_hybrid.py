@@ -472,46 +472,47 @@ def gated_delta(q, k, v, g, b, state, count, fresh):
     return o, jnp.where((count > 0)[:, None, None, None], s1, state)
 
 
-def _delta_step(q, k, v, g, b, states, index, ctx):
-    """The delta rule of a step on its flat token axis: q, k, v, g, b
-    (N, H, ...) a token; ``states`` the recurrent layers' stacked
-    states, of which this layer is ``index``. -> (o (N, H, dv) float32,
-    ``states`` with the layer's rows updated in place).
+def step_rows(rule, token, states, index, ctx):
+    """A per-row recurrence of a step on its flat token axis.
+    ``token``: arrays (N, ...) a token; ``rule(*arrays (R, C, ...),
+    state (R, ...), count, fresh) -> (o (R, C, ...) float32, state)``
+    is :func:`gated_delta`'s contract (a row's real tokens lead, a row
+    with none keeps its state bitwise); ``states`` the recurrent
+    layers' stacked states, of which this layer is ``index``. -> (o (N,
+    ...) float32, ``states`` with the layer's rows updated in place).
 
-    Rows that hold one token take the recurrence, all of them at once;
-    each row that holds more takes the chunk form, in a loop over those
-    rows alone (module docstring)."""
+    Rows that hold one token take the rule at C = 1, all of them at
+    once; each row that holds more takes it at the step's chunk, in a
+    loop over those rows alone (module docstring)."""
     count, fresh, place = ctx["q_len"], ctx["fresh"], ctx["place"]
     R, C = place.shape
-    N = q.shape[0]
+    N = token[0].shape[0]
     s_l = _layer_of(states, index)
     if C == 1:  # the token axis is the rows
-        o, s_l = gated_delta(*(x[:, None] for x in (q, k, v, g, b)), s_l,
-                             count, fresh)
+        o, s_l = rule(*(x[:, None] for x in token), s_l, count, fresh)
         return o[:, 0], lax.dynamic_update_index_in_dim(states, s_l, index, 0)
     first = place[:, 0]
     single = count == 1
-    o1, s_l = gated_delta(*(x[first][:, None] for x in (q, k, v, g, b)), s_l,
-                          single.astype(count.dtype), fresh)
+    o1, s_l = rule(*(x[first][:, None] for x in token), s_l,
+                   single.astype(count.dtype), fresh)
     states = lax.dynamic_update_index_in_dim(states, s_l, index, 0)
     o = jnp.zeros((N,) + o1.shape[2:], jnp.float32)
     o = o.at[jnp.where(single, first, N)].set(o1[:, 0], mode="drop")
     (rows,) = jnp.nonzero(count > 1, size=R, fill_value=0)
     cols = jnp.arange(C)
+    block = (1, 1) + states.shape[2:]
 
     def one_row(i, carry):
         o, states = carry
         r = rows[i]
         at = lax.dynamic_index_in_dim(place, r, 0, keepdims=False)   # (C,)
         n = lax.dynamic_slice(count, (r,), (1,))
-        s_r = lax.dynamic_slice(
-            states, (index, r, 0, 0, 0), (1, 1) + states.shape[2:])
-        o_r, s_r = gated_delta(
-            *(x[at][None] for x in (q, k, v, g, b)), s_r[0],
-            n, lax.dynamic_slice(fresh, (r,), (1,)))
+        where = (index, r) + (0,) * (states.ndim - 2)
+        s_r = lax.dynamic_slice(states, where, block)
+        o_r, s_r = rule(*(x[at][None] for x in token), s_r[0],
+                        n, lax.dynamic_slice(fresh, (r,), (1,)))
         o = o.at[jnp.where(cols < n, at, N)].set(o_r[0], mode="drop")
-        return o, lax.dynamic_update_slice(
-            states, s_r[None], (index, r, 0, 0, 0))
+        return o, lax.dynamic_update_slice(states, s_r[None], where)
 
     return lax.fori_loop(0, jnp.sum(count > 1), one_row, (o, states))
 
@@ -544,8 +545,8 @@ def _gdn_block(cfg, ctx, stack, index, x, carried):
         b = jax.nn.sigmoid(gates[:, :H]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             gates[:, H:] + p["dt_bias"].astype(f32))
-        o, state = _delta_step(q, k, v.reshape(-1, H, dv), g, b,
-                               carried["state"], index, ctx)
+        o, state = step_rows(gated_delta, (q, k, v.reshape(-1, H, dv), g, b),
+                             carried["state"], index, ctx)
         carried = dict(carried, state=state)
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * p["o_norm_scale"].astype(f32)).astype(x.dtype)
@@ -610,6 +611,43 @@ def _ffn_block(cfg, ctx, stack, index, x, carried):
 # The step
 
 
+def step_context(tokens, positions, page_table, page_size, cache_len,
+                 kernels, pack):
+    """What the blocks of a paged step with per-slot state read beside
+    their weights: ``((tokens, positions) on the step's token axis,
+    ctx)``. The token axis is (R, C) itself or, with ``pack``, the
+    packed one (1, pack); ``ctx``: each token's page and offset, the
+    table and the mask for the attention call, each row's real tokens
+    ``q_len`` and whether it starts ``fresh`` (its first position is
+    0), and the token axis's geometry (``row`` / ``col`` of each token,
+    ``place`` of each (row, column)) for :func:`step_rows` and
+    ``lfm2_moe.short_conv``."""
+    from ..serve.kernels import paged_serve_mask, real_query_lengths
+
+    R, C = tokens.shape
+    q_len = real_query_lengths(positions, cache_len)  # real columns lead
+    if pack is None:
+        token_axis = (tokens, positions)
+        phys, off = _page_lookup(page_table, positions, page_size)
+        place = jnp.arange(R * C, dtype=jnp.int32).reshape(R, C)
+        row = jnp.repeat(jnp.arange(R, dtype=jnp.int32), C)
+        col = jnp.tile(jnp.arange(C, dtype=jnp.int32), R)
+        pack_idx = None
+    else:
+        (*token_axis, phys, off), pack_idx = _pack_tokens(
+            tokens, positions, q_len, page_table, page_size, cache_len, pack)
+        place, flat = pack_idx
+        row, col = flat // C, flat % C
+    return token_axis, dict(
+        phys=phys, off=off, page_table=page_table, kernels=kernels,
+        q_len=q_len, pack=pack_idx,
+        mask=paged_serve_mask(None, positions, page_table.shape[1],
+                              page_size, cache_len),
+        row=row, col=col, place=place,
+        fresh=(q_len > 0) & (positions[:, 0] == 0),
+    )
+
+
 @sublayer("glue")
 def serve_step_paged(
     params: Dict[str, Any],
@@ -637,35 +675,14 @@ def serve_step_paged(
         _no_state_rollback()
     if pack is not None and all_logits:
         raise ValueError("a packed token axis returns one logits row a row")
-    from ..serve.kernels import paged_serve_mask, real_query_lengths
-
-    R, C = tokens.shape
-    ps = cache["k"].shape[2]
-    q_len = real_query_lengths(positions, cache_len)  # real columns lead
-    if pack is None:
-        token_axis = (tokens, positions)
-        phys, off = _page_lookup(page_table, positions, ps)
-        place = jnp.arange(R * C, dtype=jnp.int32).reshape(R, C)
-        row = jnp.repeat(jnp.arange(R, dtype=jnp.int32), C)
-        col = jnp.tile(jnp.arange(C, dtype=jnp.int32), R)
-        pack_idx = None
-    else:
-        (*token_axis, phys, off), pack_idx = _pack_tokens(
-            tokens, positions, q_len, page_table, ps, cache_len, pack)
-        place, flat = pack_idx
-        row, col = flat // C, flat % C
-    ctx = dict(
-        phys=phys, off=off, page_table=page_table, kernels=kernels,
-        q_len=q_len, pack=pack_idx,
-        mask=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
-        row=row, col=col, place=place,
-        fresh=(q_len > 0) & (positions[:, 0] == 0),
-    )
+    token_axis, ctx = step_context(
+        tokens, positions, page_table, cache["k"].shape[2], cache_len,
+        kernels, pack)
     x = _embed_in(cfg, params, *token_axis)
     blocks = {
         name: functools.partial(fn, cfg, ctx)
         for name, fn in (("gdn", _gdn_block), ("attn", _attn_block),
                          ("ffn", _ffn_block))}
     x, new_cache = run_layers(cfg.kinds, blocks, params, x, cache)
-    return _head_logits(cfg, params, x, logits_idx, pack_idx,
+    return _head_logits(cfg, params, x, logits_idx, ctx["pack"],
                         all_logits), new_cache

@@ -957,15 +957,22 @@ def kv_cache_pspecs(cfg: DecoderConfig = None, *, pipeline: bool = False):
     return specs
 
 
-def _serve_attend(cfg: DecoderConfig, q, k_cache, v_cache, bias, mask):
-    """q (R,C,H,dk) against cache (R,S1,KV,dk)."""
+def _serve_attend(cfg: DecoderConfig, q, k_cache, v_cache, bias, mask,
+                  scale: Optional[float] = None):
+    """q (R,C,H,dk) against cache (R,S1,KV,dk). ``scale``: the softmax
+    scale of a family that states its own (Granite's
+    ``attention_multiplier``); None is 1/sqrt(head_dim)."""
     R, C, H, dk = q.shape
     KV = k_cache.shape[2]
     G = H // KV
     qg = q.reshape(R, C, KV, G, dk)
     scores = jnp.einsum(
         "rckgd,rskd->rkgcs", qg, k_cache, preferred_element_type=jnp.float32
-    ) / math.sqrt(cfg.head_dim)
+    )
+    if scale is None:
+        scores = scores / math.sqrt(cfg.head_dim)
+    else:
+        scores = scores * scale
     if bias is not None:  # (R,H,C,S1)
         scores = scores + bias.reshape(R, KV, G, *bias.shape[-2:])
     scores = jnp.where(mask[:, None, None], scores, -1e30)

@@ -629,6 +629,64 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
         assert temp < 2 * np.prod(layer) * 4, temp
 
 
+# --- Mamba-2 layers beside attention (Granite 4.0-H) --------------------------
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 2048), (128, 256)])
+def test_granite_hybrid_step_compiles_in_place(chip, C, pack):
+    """models/granite_hybrid.py at published widths (64 state-space
+    heads of 64 over a state of 128, GQA 32/8 at head size 64, the
+    whole vocabulary, tied), five layers (two mamba, attention, two
+    mamba: both kinds of run), the benchmark cell's 64 slots of 8
+    pages, the decode step, a packed rung and the admission rung: the
+    ragged paged kernel is the program's ONLY kind of kernel call and
+    its result is [slots, chunk, ...] (the trace reduction keys the
+    step by it), and the loop's carry is updated in place: no copy of
+    the state stack (0.54 GB here, 4.83 GB at the cell's 36 layers,
+    where a second one does not fit the chip beside 6.38 GB of
+    weights), of a layer's states, of the convolution states or of the
+    K/V pools, temporaries (a packed rung's activations: 2048 tokens'
+    convolved channels in float32 are 36 MB) under two layers' states,
+    where a second state stack would be four."""
+    from flexflow_tpu.models import granite_hybrid as fam
+
+    M, A = fam.MAMBA, fam.ATTENTION
+    cfg = fam.config(num_hidden_layers=5, layer_types=(M, M, A, M, M),
+                     dtype=jnp.bfloat16)
+    slots, pages, cache_len = 64, 8, 1024
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        num_slots=slots, cache_len=cache_len)), chip)
+    assert cache["state"].shape == (4, 64, 64, 64, 128)
+    assert cache["state"].dtype == jnp.float32
+    assert cache["conv"].shape == (4, 3, 64, 4352)
+    assert cache["k"].shape == (1, slots * pages + 1, PAGE, 512)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", text)
+    assert f"%ff_ragged_paged_c{C}" in text and len(calls) == 1, calls
+    assert f"[{slots},{C},8,4,64]" in calls[0]
+    layer = cache["state"].shape[1:]
+    for a in (cache["k"], cache["v"], cache["state"], cache["conv"],
+              jax.ShapeDtypeStruct(layer, jnp.float32)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * np.prod(layer) * 4, temp
+
+
 # --- the generic decoder's sparse layer with its tokens routed (Mixtral) ----
 
 

@@ -15,6 +15,7 @@ import pytest
 
 from flexflow_tpu.models import (
     deepseek_v3,
+    granite_hybrid,
     lfm2_moe,
     minicpm_sala,
     mistral,
@@ -42,6 +43,7 @@ FAMILIES = {
     "lfm2_moe": (lfm2_moe, ALWAYS | {"ff.mixer", "ff.moe.route"}),
     "deepseek_v3": (deepseek_v3, ALWAYS | {"ff.moe.route"}),
     "olmo_hybrid": (olmo_hybrid, ALWAYS | {"ff.mixer"}),
+    "granite_hybrid": (granite_hybrid, ALWAYS | {"ff.mixer"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
