@@ -33,10 +33,14 @@ engine's ordinary step programs, as ``models/lfm2_moe.py``:
 * the cache is the paged K/V pool of the ATTENTION layers only
   (``k``/``v``: (attention layers, pages+1, page, KV * d), a line's
   heads merged on the minor axis as ``models/lfm2_moe.py``'s) plus per-SLOT
-  state (``SLOT_STATE``): ``state`` (recurrent layers, slots, H, dk,
-  dv) float32 and ``conv`` (recurrent layers, taps - 1, slots, 2 H dk +
-  H dv): the q, k and v convolutions' newest inputs side by side, in
-  the cache's dtype.
+  state (``SLOT_STATE``): ``state`` (recurrent layers, slots, H / p, dk,
+  p dv) float32, p = :func:`lane_pack` heads side by side on the minor
+  axis so that it is whole lane tiles (published: two heads of 192 a
+  row of 384; the device pads a minor extent of 192 to 256, a third
+  more to hold and to move: ISSUE 48), and ``conv`` (recurrent layers,
+  taps - 1, slots, 2 H dk + H dv): the q, k and v convolutions' newest
+  inputs side by side, in the cache's dtype. The rule for any per-slot
+  float32 state: its minor extent is a multiple of 128 or it is packed.
 * what a step is handed decides everything: a row whose chunk starts at
   position 0 starts from zero states, the scratch position and padded
   rows update nothing (a row with no real token keeps its states
@@ -44,9 +48,15 @@ engine's ordinary step programs, as ``models/lfm2_moe.py``:
 * the step takes the engine's PACKED token axis (``PACKED_STEP``). The
   delta rule runs a ROW at a time: :func:`gated_delta` is the
   recurrence itself for one token a row (the C = 1 step, and the rows
-  of a mixed step that hold one token) and the chunk form for more
-  (each row of a mixed step that prefills, in a loop over those rows
-  alone: a mixed step holds one or two of them beside 60 that decode).
+  of a mixed step that hold one token), written on the state as the
+  cache holds it, and the chunk form for more (each row of a mixed
+  step that prefills, in a loop over those rows alone: a mixed step
+  holds one or two of them beside 60 that decode; that loop alone
+  views a row's 2.2 MB with the heads apart). No program transposes a
+  layer's state. The C = 1 program under ``kernels="pallas"`` runs the
+  recurrence in ONE pass over the state (:func:`recurrence_c1`,
+  ``serve/kernels.gdn_recur_c1``, ``ff_gdn_recur_c1``: XLA makes two
+  fusions of the rule, each over the layer).
   The chunk form solves ``(I + A) U = rhs`` a sub-chunk of
   :data:`SUB_CHUNK` positions (a unit lower-triangular solve); every
   exponent in it is a difference ``G_i - G_j`` with j <= i taken on the
@@ -349,6 +359,38 @@ commit_kv = reorder_slots = _no_state_rollback
 # Cache: the attention layers' paged pool, the recurrent layers' per-slot state
 
 
+#: a lane tile (``ops/flash_attention.LANES``; the kernels are imported
+#: where they are called, so the constant is written again)
+LANES = 128
+
+
+def lane_pack(H: int, dv: int) -> int:
+    """Heads kept side by side on the minor axis of the recurrent
+    state: the least divisor p of H that makes ``p dv`` whole lane
+    tiles, 1 where dv is or no divisor does (the tests' tiny widths).
+    The device tiles a float32 array's two minor axes at (8, 128), so a
+    per-slot state whose minor extent is no multiple of 128 is held,
+    read and written with its padding."""
+    return next((p for p in range(1, H + 1)
+                 if H % p == 0 and p * dv % LANES == 0), 1)
+
+
+def pack_heads(s, p: int):
+    """(..., H, dk, dv) -> (..., H / p, dk, p dv): head ``h`` on lanes
+    ``(h % p) dv ..`` of row ``h // p``. A transpose: for the tests and
+    one prefilling row's state, never a layer's."""
+    *lead, H, dk, dv = s.shape
+    s = s.reshape(*lead, H // p, p, dk, dv)
+    return jnp.moveaxis(s, -3, -2).reshape(*lead, H // p, dk, p * dv)
+
+
+def unpack_heads(s, p: int):
+    """:func:`pack_heads` undone."""
+    *lead, Hp, dk, W = s.shape
+    s = s.reshape(*lead, Hp, dk, p, W // p)
+    return jnp.moveaxis(s, -2, -3).reshape(*lead, Hp * p, dk, W // p)
+
+
 def init_paged_kv_cache(
     cfg: OlmoHybridConfig, num_pages: int, page_size: int, dtype=None,
     kv_quant: Optional[str] = None, extra_rows: int = 0, *,
@@ -360,9 +402,13 @@ def init_paged_kv_cache(
     with its heads padded to 32 for the line write and with the page
     inside the heads for the kernel, three pool copies a step), row
     ``num_pages`` the scratch page; ``state``: (recurrent layers,
-    slots, H, dk, dv) float32 whatever the cache's dtype; ``conv``:
-    (recurrent layers, taps - 1, slots, conv_dim), each slot's newest
-    convolution inputs, oldest first."""
+    slots, H / p, dk, p dv) float32 whatever the cache's dtype, p =
+    :func:`lane_pack` heads side by side on the minor axis (published:
+    two heads of 192, (.., 15, 96, 384): at (.., 30, 96, 192) the device
+    pads every row of 192 to 256 lanes, 1.70 GB for nine layers' 1.27,
+    and a step moves the padding too; tests/test_chip_compile.py);
+    ``conv``: (recurrent layers, taps - 1, slots, conv_dim), each
+    slot's newest convolution inputs, oldest first."""
     if kv_quant is not None or extra_rows:
         raise NotImplementedError(
             "olmo_hybrid's pool is neither quantized nor row-sharded "
@@ -376,11 +422,12 @@ def init_paged_kv_cache(
     pool = (cfg.count("attn"), num_pages + 1, page_size,
             cfg.num_key_value_heads * cfg.head_dim)
     n = cfg.count("gdn")
+    H, dv = cfg.linear_num_heads, cfg.linear_value_head_dim
+    p = lane_pack(H, dv)
     return {
         "k": jnp.zeros(pool, dt), "v": jnp.zeros(pool, dt),
-        "state": jnp.zeros((n, slots, cfg.linear_num_heads,
-                            cfg.linear_key_head_dim,
-                            cfg.linear_value_head_dim), jnp.float32),
+        "state": jnp.zeros((n, slots, H // p, cfg.linear_key_head_dim,
+                            p * dv), jnp.float32),
         "conv": jnp.zeros((n, cfg.linear_conv_kernel_dim - 1, slots,
                            cfg.conv_dim), dt),
     }
@@ -424,22 +471,40 @@ def _chunk(q, k, v, g, b, s):
     return o, s
 
 
+def _lanes_of(x, p: int, dv: int):
+    """(R, H / p, p, ...) -> (R, H / p, ..., p dv): each of a row's p
+    heads' values on that head's dv lanes, by p broadcasts and p - 1
+    selects on the lane index (p = 1: the broadcast alone). Elementwise,
+    so a consumer's fusion makes it where it reads it."""
+    out = jnp.broadcast_to(x[:, :, 0, ..., None], x.shape[:2] + x.shape[3:] + (p * dv,))
+    lane = jnp.arange(p * dv)
+    for j in range(1, p):
+        out = jnp.where(lane >= j * dv, x[:, :, j, ..., None], out)
+    return out
+
+
 def gated_delta(q, k, v, g, b, state, count, fresh):
     """The gated delta rule of one step over the carried state.
 
     q, k (R, C, H, dk): L2-normalised, q scaled; v (R, C, H, dv); g
     (R, C, H): the log of each token's decay; b (R, C, H): its write
-    strength; ``state`` (R, H, dk, dv) float32; ``count`` (R,): the
+    strength; ``state`` (R, H, dk, dv) float32 or, as the cache holds
+    it, (R, H / p, dk, p dv) with p heads side by side on the lanes
+    (:func:`pack_heads`; p is read off the shapes); ``count`` (R,): the
     row's real tokens, its first columns; ``fresh`` (R,): rows that
     start from a zero state. Returns (o (R, C, H, dv) float32, the
-    state after each row's last real token). A row with no real token
-    keeps its state bitwise.
+    state after each row's last real token, laid out as it came). A row
+    with no real token keeps its state bitwise.
 
-    C == 1 is the recurrence itself, with the state read once for both
-    ``S^T k`` and ``S^T q`` (``o = a S^T q + (k . q) u`` is ``(a S + k
-    u^T)^T q``); C > 1 the chunk form at sub-chunks of
-    :data:`SUB_CHUNK` (module docstring)."""
-    C = q.shape[1]
+    C == 1 is the recurrence itself ON THE PACKED FORM (no transpose of
+    a state), with the state read once for both ``S^T k`` and ``S^T q``
+    (``o = a S^T q + (k . q) u`` is ``(a S + k u^T)^T q``); C > 1 the
+    chunk form at sub-chunks of :data:`SUB_CHUNK` (module docstring) on
+    the heads apart: one prefilling row's state is unpacked and packed
+    again around it."""
+    R, C, H, dk = q.shape
+    dv = v.shape[-1]
+    p = H // state.shape[1]
     f32 = jnp.float32
     real = jnp.arange(C)[None, :] < count[:, None]           # (R, C)
     q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
@@ -447,29 +512,47 @@ def gated_delta(q, k, v, g, b, state, count, fresh):
     b = jnp.where(real[..., None], b.astype(f32), 0.0)
     s0 = jnp.where(fresh[:, None, None, None], 0.0, state)
     if C == 1:
-        q, k, v, g, b = q[:, 0], k[:, 0], v[:, 0], g[:, 0], b[:, 0]
-        a = jnp.exp(g)[..., None]                            # (R, H, 1)
+        rows = lambda x: x[:, 0].reshape((R, H // p, p) + x.shape[3:])
+        a, b, kq = (_lanes_of(rows(x), p, dv)                # (R, H/p, p dv)
+                    for x in (jnp.exp(g), b, jnp.sum(k * q, axis=-1)))
+        q, k = (_lanes_of(rows(x), p, dv) for x in (q, k))   # (R, H/p, dk, p dv)
         # S^T k and S^T q as products summed over dk, not as 1920
         # matrix-vector products of two rows each: the state streams
         # through once and the matrix unit would load it as weights
-        sk = a * jnp.sum(s0 * k[..., None], axis=-2)         # (R, H, dv)
-        sq = a * jnp.sum(s0 * q[..., None], axis=-2)
-        u = b[..., None] * (v - sk)
-        s1 = a[..., None] * s0 + k[..., None] * u[:, :, None, :]
-        o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * u
-        o = o[:, None]
+        sk = a * jnp.sum(s0 * k, axis=-2)                    # (R, H/p, p dv)
+        sq = a * jnp.sum(s0 * q, axis=-2)
+        u = b * (v[:, 0].reshape(sk.shape) - sk)
+        s1 = a[:, :, None] * s0 + k * u[:, :, None]
+        o = (sq + kq * u).reshape(R, 1, H, dv)
     else:
         c = min(C, SUB_CHUNK)
         if C % c:
             raise ValueError(f"a chunk of {C} is no multiple of {c}")
         heads = lambda x: jnp.moveaxis(x, 2, 1)              # (R, H, C, ...)
         q, k, v, g, b = map(heads, (q, k, v, g, b))
-        s1, outs = s0, []
+        s1, outs = unpack_heads(s0, p), []
         for lo in range(0, C, c):
             o, s1 = _chunk(*(x[:, :, lo:lo + c] for x in (q, k, v, g, b)), s1)
             outs.append(o)
         o = jnp.moveaxis(jnp.concatenate(outs, axis=2), 1, 2)
+        s1 = pack_heads(s1, p)
     return o, jnp.where((count > 0)[:, None, None, None], s1, state)
+
+
+def recurrence_c1(q, k, v, g, b, states, index, count, fresh):
+    """:func:`gated_delta` at one column as ONE pass over the state
+    (``serve/kernels.gdn_recur_c1``): a token's arrays (R, ...) with no
+    column axis, ``states`` the recurrent layers' whole stack, of which
+    this layer is ``index``. -> (o (R, H, dv) float32, ``states`` with
+    the layer's rows updated in place)."""
+    from ..serve.kernels import gdn_recur_c1
+
+    live = (count > 0)[:, None]
+    o, states = gdn_recur_c1(
+        states, index, q, k, v,
+        jnp.exp(jnp.where(live, g.astype(jnp.float32), 0.0)),
+        jnp.where(live, b, 0.0), count, fresh)
+    return o.reshape(v.shape), states
 
 
 def step_rows(rule, token, states, index, ctx, kernel=None):
@@ -485,11 +568,15 @@ def step_rows(rule, token, states, index, ctx, kernel=None):
     once; each row that holds more takes it at the step's chunk, in a
     loop over those rows alone (module docstring). ``kernel(*arrays (R,
     ...), states, index, count, fresh) -> (o (R, ...), states)``, where
-    a family hands one over, is the rule of the C = 1 PROGRAM under
-    ``kernels="pallas"``, on the whole stack in place; a mixed
+    a family hands one over (both do: :func:`recurrence_c1` here,
+    ``granite_hybrid.recurrence_c1``), is the rule of the C = 1 PROGRAM
+    under ``kernels="pallas"``, on the whole stack in place; a mixed
     program's rows of one token keep ``rule`` (a kernel result of
     [slots, 1, ...] ahead of its ragged call would key it as a decode
-    step: ``benchmarks/harness/reduce.py::kernel_chunk``)."""
+    step: ``benchmarks/harness/reduce.py::kernel_chunk``). A state
+    comes and goes as the family's cache lays it out (Olmo's with its
+    heads packed on the lanes): ``rule`` and ``kernel`` read the layout
+    off the shapes, nothing here re-lays a layer."""
     count, fresh, place = ctx["q_len"], ctx["fresh"], ctx["place"]
     R, C = place.shape
     N = token[0].shape[0]
@@ -554,7 +641,7 @@ def _gdn_block(cfg, ctx, stack, index, x, carried):
         g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
             gates[:, H:] + p["dt_bias"].astype(f32))
         o, state = step_rows(gated_delta, (q, k, v.reshape(-1, H, dv), g, b),
-                             carried["state"], index, ctx)
+                             carried["state"], index, ctx, kernel=recurrence_c1)
         carried = dict(carried, state=state)
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * p["o_norm_scale"].astype(f32)).astype(x.dtype)

@@ -49,7 +49,11 @@ their dispatch latency) disappear from the step program.
 a block at a time, is updated there, read against ``C`` and written
 back where it came from, the layers' whole stack aliased through the
 call; :func:`granite_hybrid.selective_scan` at one column is its XLA
-twin and correctness reference.
+twin and correctness reference. :func:`gdn_recur_c1` is the same for
+the gated delta rule (``models/olmo_hybrid.py``), over a state kept
+with its heads side by side on the lanes (``olmo_hybrid.lane_pack``):
+both sums over dk and the rank-one write while a head's tile is held;
+:func:`olmo_hybrid.gated_delta` at one column is its XLA twin.
 
 Kernel-variant matrix — every Pallas variant of the ragged paged
 kernel is emitted by ONE parameterized builder
@@ -2122,3 +2126,126 @@ def ssm_recur_c1(
       fresh.astype(jnp.int32), a.astype(f32)[..., None],
       dx.astype(f32)[:, None], B.astype(f32)[:, None], C.astype(f32)[:, None],
       states)
+
+
+# ---------------------------------------------------------------------------
+# The gated delta rule of a decode step (models/olmo_hybrid.py): one token
+# a row, ``u = b (v - a S^T k)``, ``S <- a S + k u^T``, ``o = a S^T q + (k .
+# q) u``, in ONE pass over the row's float32 state, the Mamba-2 kernel's
+# way. The rule reads the state (both sums over dk) before it writes it, so
+# both happen while a head's (dk, lanes) tile is held. XLA makes two
+# fusions of it, each over the whole layer: two reads and a write.
+#
+# The state comes lane-dense, ``p`` heads side by side on the minor axis
+# ((rows of p heads, dk, p dv): ``olmo_hybrid.lane_pack``), so every
+# vector a head, ``v``, ``u``, ``o``, ``S^T k``, is a (1, p dv) row of the
+# same lanes and the sums over dk run down the sublanes. k and q come
+# TRANSPOSED (dk on the sublanes, a head a column), so a head's column
+# spreads along the lanes by a broadcast and, where p > 1, a select on the
+# lane index between the p heads of the row.
+
+
+def _gdn_recur_kernel(lay, cnt, fr, cols_ref, vec_ref, s_ref, o_ref, out_ref,
+                      *, p: int):
+    """One (row, block of packed heads) grid step. ``cols_ref`` (1, 1,
+    dk, 2 p heads): the block's k columns, then its q columns;
+    ``vec_ref`` (1, 4, heads, p dv): v, and the decay, the write
+    strength and ``k . q`` on their head's lanes; ``o_ref`` (1, 1,
+    heads, p dv); ``s_ref`` / ``out_ref`` (1, 1, heads, dk, p dv): the
+    SAME block of the stacked state."""
+    r = pl.program_id(0)
+    Hb, dk, W = s_ref.shape[2:]
+    dv = W // p
+    fresh = fr[r] > 0
+    lane = jax.lax.broadcasted_iota(jnp.int32, (dk, W), 1)
+    of_head = [lane >= j * dv for j in range(1, p)]
+
+    def along_lanes(first):
+        """Columns ``first .. first + p`` of ``cols_ref``, each on the
+        dv lanes of its head: (dk, p dv)."""
+        x = jnp.broadcast_to(cols_ref[0, 0, :, first:first + 1], (dk, W))
+        for j, mask in enumerate(of_head, 1):
+            x = jnp.where(mask, cols_ref[0, 0, :, first + j:first + j + 1], x)
+        return x
+
+    for h in range(Hb):  # unrolled: a head's tile is what the registers see
+        s0 = jnp.where(fresh, 0.0, s_ref[0, 0, h])
+        k, q = along_lanes(h * p), along_lanes((Hb + h) * p)
+        v, a, b, kq = (vec_ref[0, i, h:h + 1, :] for i in range(4))
+        u = b * (v - a * jnp.sum(s0 * k, axis=0, keepdims=True))
+        o_ref[0, 0, h:h + 1, :] = (
+            a * jnp.sum(s0 * q, axis=0, keepdims=True) + kq * u)
+        out_ref[0, 0, h] = a * s0 + k * u
+
+    @pl.when(cnt[r] == 0)
+    def _():  # a row with no real token keeps its state bitwise
+        out_ref[0, 0] = s_ref[0, 0]
+
+
+def gdn_recur_c1(
+    states: jnp.ndarray,   # (L, R, H / p, dk, p dv) float32: the layers' stacked states
+    layer,                 # int32 scalar (traced): the layer to step
+    q: jnp.ndarray,        # (R, H, dk) float32
+    k: jnp.ndarray,        # (R, H, dk) float32
+    v: jnp.ndarray,        # (R, H, dv) float32
+    a: jnp.ndarray,        # (R, H) float32: each head's decay exp(g)
+    b: jnp.ndarray,        # (R, H) float32: its write strength
+    count: jnp.ndarray,    # (R,) int32: a row's real tokens (0 or 1)
+    fresh: jnp.ndarray,    # (R,) bool: rows that start from a zero state
+):
+    """``models/olmo_hybrid.gated_delta`` at one column over the layer
+    ``layer`` of the stack, in place: -> (o (R, 1, H / p, p dv)
+    float32, ``states``); p, the heads side by side on the state's
+    lanes, is read off the shapes. The stack is aliased to the second
+    result and the layer named by a prefetched scalar in the block
+    index, as :func:`ssm_recur_c1` does; ``o`` is the FIRST result and
+    [slots, 1, ...] for the same reason. What is a token's and small
+    (the decay and the write strength a head, ``k . q``, their spread
+    over a head's lanes, the transpose of k and q) is the caller's XLA:
+    a few KB a row."""
+    L, R, Hp, dk, W = states.shape
+    H = q.shape[1]
+    p, dv = H // Hp, v.shape[-1]
+    if Hp * p != H or p * dv != W or q.shape[-1] != dk:
+        raise ValueError(f"a state {states.shape} does not hold {H} heads of "
+                         f"{dk} x {dv}, {p} to a row of lanes")
+    Hb = ssm_recur_block(Hp, dk, W)
+    nb = Hp // Hb
+    f32 = jnp.float32
+    q, k, v, a, b = (x.astype(f32) for x in (q, k, v, a, b))
+
+    def columns(x):  # (R, H, dk) -> (R, blocks, dk, the block's heads)
+        return x.reshape(R, nb, Hb * p, dk).swapaxes(2, 3)
+
+    def on_lanes(x):  # (R, H) -> (R, H / p, p dv): a head's value on its lanes
+        return jnp.repeat(x.reshape(R, Hp, p), dv, axis=-1)
+
+    cols = jnp.concatenate([columns(k), columns(q)], axis=-1)
+    vec = jnp.stack([v.reshape(R, Hp, W), on_lanes(a), on_lanes(b),
+                     on_lanes(jnp.sum(k * q, axis=-1))], axis=1)
+
+    def of_state(r, h, lay, cnt, fr):
+        return (lay[0], r, h, 0, 0)
+
+    block = pl.BlockSpec((1, 1, Hb, dk, W), of_state)
+    rows = pl.BlockSpec((1, 1, Hb, W), lambda r, h, *_: (r, 0, h, 0))
+    return pl.pallas_call(
+        functools.partial(_gdn_recur_kernel, p=p),
+        out_shape=[jax.ShapeDtypeStruct((R, 1, Hp, W), f32),
+                   jax.ShapeDtypeStruct(states.shape, states.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(R, nb),
+            in_specs=[pl.BlockSpec((1, 1, dk, 2 * Hb * p),
+                                   lambda r, h, *_: (r, h, 0, 0)),
+                      pl.BlockSpec((1, 4, Hb, W), lambda r, h, *_: (r, 0, h, 0)),
+                      block],
+            out_specs=[rows, block]),
+        # operand 5 of the flattened list (the prefetched scalars first)
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(_VMEM_SCOPE_DEFAULT,
+                                 6 * Hb * dk * W * 4 + (4 << 20))),
+        name="ff_gdn_recur_c1",
+        interpret=_interpret(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), count.astype(jnp.int32),
+      fresh.astype(jnp.int32), cols, vec, states)

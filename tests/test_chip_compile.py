@@ -560,6 +560,19 @@ def test_lfm2_moe_step_compiles_in_place(chip, C, pack):
     assert temp < 3 * experts.size // experts.shape[0] * experts.dtype.itemsize
 
 
+def _assert_kernel_calls(text, want, slots, C):
+    """The program's Pallas calls are ``want`` ((name, what its result
+    starts with) pairs, sorted by name), each result [slots, C, ...]:
+    the trace reduction keys the step by its FIRST kernel's result,
+    whichever call that is."""
+    calls = re.findall(
+        r"%(\w+?)(?:\.\d+)* = (.+?) custom-call\(.*tpu_custom_call", text)
+    assert sorted(name for name, _ in calls) == [name for name, _ in want], calls
+    for name, shape in calls:
+        assert dict(want)[name] in shape, calls
+        assert re.search(r"\[(\d+),(\d+),", shape).groups() == (str(slots), str(C))
+
+
 # --- Gated DeltaNet layers beside full attention (Olmo-Hybrid) ---------------
 
 
@@ -570,17 +583,24 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
     vocabulary), five layers (three recurrent, attention, one
     recurrent: both kinds of run), the benchmark cell's 64 slots of 8
     pages, the decode step, a packed rung and the admission rung (ISSUE
-    45: 256 places): the ragged paged kernel is
-    the program's ONLY kind of kernel call and its result is [slots,
-    chunk, ...] (the trace reduction keys the step by it; at C = 128
-    thirty heads of one query a group pass the fast memory at once, and
-    the call takes them in blocks under one name), and the loop's carry
-    is updated in place: no copy of the K/V pools, of the recurrent
-    state stack (0.57 GB here, 1.27 GB at the cell's nine layers) or of
-    the convolution states, no relayout of a pool, temporaries (a packed
-    rung's activations: 2048 tokens' q, k and v in float32 are 94 MB)
-    under two layers' states, where a second state stack would be four
-    (the admission rung: no more than the padded step's)."""
+    45: 256 places): in the two mixed programs the ragged paged kernel
+    is the ONLY kind of kernel call (at C = 128 thirty heads of one
+    query a group pass the fast memory at once, and the call takes them
+    in blocks under one name); the decode step also calls the delta
+    rule's kernel, once a run of recurrent layers (``ff_gdn_recur_c1``,
+    on the state stack in place, ``o`` its first result); either way
+    the program's FIRST kernel result is [slots, chunk, ...] (the trace
+    reduction keys the step by it), and the loop's carry is updated in
+    place: no copy of the K/V pools, of the recurrent state stack (0.57
+    GB here, 1.27 GB at the cell's nine layers) or of the convolution
+    states, no relayout of a pool or of a layer's states (the state is
+    kept two heads to a row of 384 lanes and every program reads and
+    writes it so), temporaries (a packed rung's activations: 2048
+    tokens' q, k and v in float32 are 94 MB) under two layers' states,
+    where a second state stack would be four (the admission rung: no
+    more than the padded step's). And the state's bytes on the device
+    are its arithmetic: at (.., 30, 96, 192) the device pads each row
+    of 192 to 256 lanes, a third more to hold and to move (ISSUE 48)."""
     from flexflow_tpu.models import olmo_hybrid as fam
 
     L, A = fam.LINEAR, fam.ATTENTION
@@ -593,7 +613,7 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
     cache = _on(jax.eval_shape(functools.partial(
         fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
         num_slots=slots, cache_len=cache_len)), chip)
-    assert cache["state"].shape == (4, 64, 30, 96, 192)
+    assert cache["state"].shape == (4, 64, 15, 96, 384)
     assert cache["state"].dtype == jnp.float32
     assert cache["conv"].shape == (4, 3, 64, 11520)
 
@@ -610,14 +630,23 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
             chip((slots, pages), jnp.int32), donate=(1,))
 
     compiled, text = compile_at(pack)
-    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", text)
-    assert f"%ff_ragged_paged_c{C}" in text and len(calls) == 1, calls
-    assert f"[{slots},{C},30,1,128]" in calls[0]
+    attn = (f"ff_ragged_paged_c{C}", f"[{slots},{C},30,1,128]")
+    # the C=1 program: a recurrence call a run of recurrent layers (two
+    # runs here: each run's loop body is one computation of the text), o
+    # ahead of the stack in its result, so the step is keyed 1 though
+    # its first call is a recurrent layer's (as in the cell's program)
+    recur = ("ff_gdn_recur_c1", f"(f32[{slots},1,15,384]")
+    _assert_kernel_calls(text, [attn] if C > 1 else [recur, recur, attn], slots, C)
     layer = cache["state"].shape[1:]
+    apart = (slots, 30, 96, 192)
     for a in (cache["k"], cache["v"], cache["state"], cache["conv"],
-              jax.ShapeDtypeStruct(layer, jnp.float32)):
+              jax.ShapeDtypeStruct(layer, jnp.float32),
+              jax.ShapeDtypeStruct(apart, jnp.float32),
+              jax.ShapeDtypeStruct((1,) + apart, jnp.float32)):
         dims = ",".join(map(str, a.shape))
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    # no layer's states with the heads apart anywhere: nothing re-lays a layer
+    assert f"[{','.join(map(str, apart))}]" not in text
     temp = compiled.memory_analysis().temp_size_in_bytes
     if pack == 256:
         # the admission rung is held to what its issue asks: no more
@@ -627,6 +656,53 @@ def test_olmo_hybrid_step_compiles_in_place(chip, C, pack):
         assert temp <= padded.memory_analysis().temp_size_in_bytes, temp
     else:
         assert temp < 2 * np.prod(layer) * 4, temp
+
+
+@pytest.mark.parametrize("layout, padded", [
+    ((64, 15, 96, 384), False), ((64, 30, 96, 192), True)],
+    ids=["lane-packed", "heads-apart"])
+def test_olmo_recurrent_state_takes_its_arithmetic_on_the_device(chip, layout, padded):
+    """The finding of ISSUE 48, pinned: the device tiles a float32
+    array's two minor axes at (8, 128), so one layer's states with the
+    heads apart (rows of 192) take 188.7 MB as an argument where their
+    values are 141.6, and two heads to a row of 384 lanes take their
+    arithmetic. ``init_paged_kv_cache`` lays the state out the second
+    way; a layout that pads its lanes fails here, before any chip
+    call."""
+    from flexflow_tpu.models import olmo_hybrid as fam
+
+    cfg = fam.config(num_hidden_layers=4, dtype=jnp.bfloat16)
+    state = jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, 64, PAGE, jnp.bfloat16, num_slots=64))["state"]
+    assert state.shape == (3, 64, 15, 96, 384)
+    assert fam.lane_pack(30, 192) == 2
+    compiled, _ = _compile(lambda s: s + 1.0, chip((1,) + layout, jnp.float32),
+                           donate=(0,))
+    held = compiled.memory_analysis().argument_size_in_bytes
+    arithmetic = int(np.prod(layout)) * 4
+    assert arithmetic == 141_557_760
+    assert held == (arithmetic * 4 // 3 if padded else arithmetic), held
+
+
+def test_gdn_recurrence_kernel_compiles_at_the_cells_shapes(chip):
+    """Mosaic takes ``ff_gdn_recur_c1`` at the Olmo cell's shapes: 64
+    rows, a row's 15 pairs of heads a block (96 x 384 float32 a pair,
+    2.2 MB a row in and out), the nine-layer stack aliased through the
+    call, nothing copied beside it."""
+    from flexflow_tpu.models import olmo_hybrid as fam
+
+    R, H, dk, dv = 64, 30, 96, 192
+    f32 = lambda *shape: chip(shape, jnp.float32)
+    stack = f32(9, R, 15, dk, 384)
+    compiled, text = _compile(
+        fam.recurrence_c1, f32(R, H, dk), f32(R, H, dk), f32(R, H, dv),
+        f32(R, H), f32(R, H), stack, chip((), jnp.int32),
+        chip((R,), jnp.int32), chip((R,), jnp.bool_), donate=(5,))
+    call, = re.findall(r"= (\S+ \S+) custom-call\(.*tpu_custom_call", text)
+    assert call.startswith("(f32[64,1,15,384]") and "f32[9,64,15,96,384]" in call
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9 * 141_557_760
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
 
 
 # --- Mamba-2 layers beside attention (Granite 4.0-H) --------------------------
@@ -678,19 +754,13 @@ def test_granite_hybrid_step_compiles_in_place(chip, C, pack):
         step, params, cache, chip((slots, C), jnp.int32),
         chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
         chip((slots, pages), jnp.int32), donate=(1,))
-    calls = re.findall(
-        r"%(\w+?)(?:\.\d+)* = (.+?) custom-call\(.*tpu_custom_call", text)
     attn = (f"ff_ragged_paged_c{C}", f"[{slots},{C},8,4,64]")
     # the C=1 program: a recurrence call a run of mamba layers (two runs
     # here: each run's loop body is one computation of the text), y ahead
     # of the stack in its result, so whichever call runs first (a mamba
     # layer's here, as in the cell's program) the step is keyed 1
     recur = ("ff_ssm_recur_c1", f"(f32[{slots},1,64,64]")
-    want = [attn] if C > 1 else [attn, recur, recur]
-    assert sorted(name for name, _ in calls) == [name for name, _ in want], calls
-    for name, shape in calls:
-        assert dict(want)[name] in shape, calls
-        assert re.search(r"\[(\d+),(\d+),", shape).groups() == (str(slots), str(C))
+    _assert_kernel_calls(text, [attn] if C > 1 else [attn, recur, recur], slots, C)
     layer = cache["state"].shape[1:]
     for a in (cache["k"], cache["v"], cache["state"], cache["conv"],
               jax.ShapeDtypeStruct(layer, jnp.float32)):

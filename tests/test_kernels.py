@@ -340,3 +340,96 @@ def test_ssm_recur_c1_result_leads_with_y_and_aliases_the_stack():
     assert y.aval.shape[:2] == (R, 1) and stack.aval.shape == (3, R, H, P, N)
     assert tuple(call.params["input_output_aliases"]) == ((7, 1),)
     assert call.invars[7].aval.shape == stack.aval.shape
+
+
+# --- the gated delta rule of the decode step (ff_gdn_recur_c1) ---------------
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("R, H, dk, dv", [
+    (4, 3, 8, 16), (4, 2, 8, 64), (4, 4, 16, 64), (4, 30, 96, 192)],
+    ids=["tiny-p1", "pair-p2", "two-pairs-p2", "published-p2"])
+def test_gdn_recur_c1_is_the_delta_rule_at_one_column(R, H, dk, dv, layer):
+    """``olmo_hybrid.recurrence_c1`` (the kernel on the whole stack as
+    the cache holds it, ``lane_pack`` heads side by side on the lanes,
+    the layer a traced index) against ``gated_delta`` at one column on
+    that layer's states with the heads apart, at the tests' tiny shape
+    (no packing), at widths that pack in pairs and at a row of the
+    published one (15 pairs of 96 x 384, 2.2 MB): the addressed layer's
+    rows to float32 rounding (the sums over dk take another order), the
+    other layers bitwise untouched, a row with no real token bitwise
+    untouched, a fresh row as from a zero state over a stale one."""
+    from flexflow_tpu.models import olmo_hybrid as fam
+    from flexflow_tpu.serve import kernels as K
+
+    p = fam.lane_pack(H, dv)
+    assert p == (1 if dv == 16 else 2)
+    assert K.ssm_recur_block(H // p, dk, p * dv) == H // p
+    rng = np.random.default_rng(13)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+    plain = draw(3, R, H, dk, dv)
+    states = fam.pack_heads(plain, p)
+    assert states.shape == (3, R, H // p, dk, p * dv)
+    np.testing.assert_array_equal(fam.unpack_heads(states, p), plain)
+    q, k = fam._l2norm(draw(R, H, dk)) * dk ** -0.5, fam._l2norm(draw(R, H, dk))
+    v = draw(R, H, dv)
+    g = jnp.log(jnp.asarray(rng.uniform(0.5, 1.0, (R, H)).astype(np.float32)))
+    b = jnp.asarray(rng.uniform(0.0, 2.0, (R, H)).astype(np.float32))
+    count = jnp.asarray([1, 0, 1, 1], jnp.int32)
+    fresh = jnp.asarray([False, False, True, False])
+    column = lambda *xs: tuple(x[:, None] for x in xs)
+    want_o, want_s = fam.gated_delta(
+        *column(q, k, v, g, b), plain[layer], count, fresh)
+    o, got = jax.jit(fam.recurrence_c1)(
+        q, k, v, g, b, states, jnp.int32(layer), count, fresh)
+    assert o.shape == (R, H, dv) and o.dtype == got.dtype == jnp.float32
+    assert got.shape == states.shape
+    np.testing.assert_allclose(o, want_o[:, 0], rtol=0,
+                               atol=2e-6 * float(jnp.abs(want_o).max()))
+    tol = dict(rtol=0, atol=2e-6 * float(jnp.abs(want_s).max()))
+    np.testing.assert_allclose(fam.unpack_heads(got[layer], p), want_s, **tol)
+    for other in {0, 1, 2} - {layer}:
+        np.testing.assert_array_equal(got[other], states[other])
+    np.testing.assert_array_equal(got[layer, 1], states[layer, 1])
+    # the fresh row: what a zero state gives, whatever the slot held
+    _, zero = fam.gated_delta(
+        *column(q[2:3], k[2:3], v[2:3], g[2:3], b[2:3]),
+        jnp.zeros((1, H, dk, dv)), count[2:3], fresh[2:3])
+    np.testing.assert_allclose(fam.unpack_heads(got[layer, 2], p), zero[0], **tol)
+    # XLA's rule on the packed form is the rule on the heads apart
+    packed_o, packed_s = fam.gated_delta(
+        *column(q, k, v, g, b), states[layer], count, fresh)
+    np.testing.assert_allclose(packed_o, want_o, rtol=0, atol=tol["atol"])
+    np.testing.assert_allclose(fam.unpack_heads(packed_s, p), want_s, **tol)
+
+
+def test_gdn_recur_c1_result_leads_with_o_and_aliases_the_stack():
+    """What the callers and the benchmark's trace reduction lean on: o
+    is the call's FIRST result and [slots, 1, ...] ([slots, 1, H, dv]
+    where no heads share a row of lanes), the stack its second, aliased
+    to the stack it was handed."""
+    from flexflow_tpu.serve import kernels as K
+
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    for R, H, dk, dv, p in ((4, 3, 8, 16, 1), (4, 4, 8, 64, 2)):
+        jaxpr = jax.make_jaxpr(K.gdn_recur_c1)(
+            z(3, R, H // p, dk, p * dv), jnp.int32(1), z(R, H, dk), z(R, H, dk),
+            z(R, H, dv), z(R, H), z(R, H), jnp.ones((R,), jnp.int32),
+            jnp.zeros((R,), bool))
+        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert call.params["name"] == "ff_gdn_recur_c1"
+        o, stack = call.outvars
+        assert o.aval.shape == (R, 1, H // p, p * dv)
+        assert stack.aval.shape == (3, R, H // p, dk, p * dv)
+        assert tuple(call.params["input_output_aliases"]) == ((5, 1),)
+        assert call.invars[5].aval.shape == stack.aval.shape
+
+
+def test_gdn_recur_c1_refuses_a_state_that_does_not_hold_the_heads():
+    from flexflow_tpu.serve import kernels as K
+
+    z = lambda *shape: jnp.zeros(shape, jnp.float32)
+    with pytest.raises(ValueError, match="does not hold 4 heads"):
+        K.gdn_recur_c1(z(3, 2, 2, 8, 64), 0, z(2, 4, 8), z(2, 4, 8), z(2, 4, 64),
+                       z(2, 4), z(2, 4), jnp.ones((2,), jnp.int32),
+                       jnp.zeros((2,), bool))

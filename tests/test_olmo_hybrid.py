@@ -387,6 +387,109 @@ def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(tiny, sh
     np.testing.assert_allclose(logits[0], logits[1], rtol=0, atol=2e-6)
 
 
+def _step_jaxpr(cfg, chunk, kernels, pack=None, family=fam):
+    params = jax.eval_shape(lambda: family.init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: family.init_paged_kv_cache(
+        cfg, SLOTS * 4, PAGE, jnp.float32, num_slots=SLOTS, cache_len=MAX_SEQ))
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return family.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None, page_table,
+            cfg=cfg, cache_len=MAX_SEQ, kernels=kernels, pack=pack)
+
+    return str(jax.make_jaxpr(step)(
+        params, cache, i32(SLOTS, chunk), i32(SLOTS, chunk), i32(SLOTS),
+        i32(SLOTS, MAX_SEQ // PAGE)))
+
+
+def test_only_the_pallas_decode_program_holds_the_recurrence_kernel(tiny):
+    """The kernel is chosen by the program's chunk of 1 and
+    ``kernels="pallas"``: a call site a run of recurrent layers in that
+    program (a run is one loop: three layers and one here), none in the
+    mixed programs (whose rows of one token keep XLA's rule on the same
+    packed state), none on the XLA path; and it is this family's: the
+    Mamba-2 kernel is in none of them."""
+    cfg, _ = tiny
+    calls = lambda *a, **k: _step_jaxpr(cfg, *a, **k).count("name=ff_gdn_recur_c1")
+    assert cfg.layer_types == (fam.LINEAR,) * 3 + (fam.ATTENTION, fam.LINEAR)
+    assert calls(1, "pallas") == 2
+    assert calls(CHUNK, "pallas") == calls(CHUNK, "pallas", pack=32) == 0
+    assert calls(1, "xla") == calls(CHUNK, "xla") == 0
+    text = _step_jaxpr(cfg, CHUNK, "pallas")
+    assert "pallas_call" in text and "ff_ssm_recur" not in _step_jaxpr(cfg, 1, "pallas")
+
+
+@pytest.mark.parametrize("kernels, chunk, pack, digest", [
+    ("xla", 1, None, "1a3a0c54ccc87a58"), ("xla", CHUNK, None, "6d931809afad25a2"),
+    ("xla", CHUNK, 32, "3959f50f4f5213cd"), ("pallas", 1, None, "65b2b9f643e3d2ad"),
+    ("pallas", CHUNK, None, "5e3de41aa961407a"), ("pallas", CHUNK, 32, "017f205cee363c31")])
+def test_the_shared_seam_leaves_granites_programs_alone(kernels, chunk, pack, digest):
+    """``step_rows`` is shared with ``granite_hybrid``, whose state is
+    lane-dense as it is (64 x 128) and whose kernel is its own: its six
+    step jaxprs at the tiny preset are PR 47's, by the first 16 hex
+    digits of their SHA-256 (taken on the parent commit, PR 48). A PR
+    that means to change Granite's programs takes the digests anew; one
+    that means to change Olmo's alone does not get here."""
+    import hashlib
+
+    from flexflow_tpu.models import granite_hybrid
+
+    text = _step_jaxpr(granite_hybrid.tiny(dtype=jnp.float32), chunk, kernels,
+                       pack, family=granite_hybrid)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+@pytest.mark.parametrize("H, dv, p", [(3, 16, 1), (30, 192, 2), (64, 128, 1),
+                                      (4, 64, 2), (3, 64, 1), (6, 32, 1), (8, 32, 4)])
+def test_lane_pack_is_the_least_divisor_that_fills_the_lanes(H, dv, p):
+    """Read off the geometry: the tests' tiny widths and a width that
+    is whole lane tiles already keep the heads apart, the published
+    pair of 192 shares 384 lanes, an odd count of heads of 64 has no
+    divisor that would."""
+    assert fam.lane_pack(H, dv) == p
+    s = jnp.arange(2 * H * 5 * dv, dtype=jnp.float32).reshape(2, H, 5, dv)
+    packed = fam.pack_heads(s, p)
+    assert packed.shape == (2, H // p, 5, p * dv)
+    # head h sits on lanes (h % p) dv .. of row h // p
+    h = H - 1
+    np.testing.assert_array_equal(
+        packed[:, h // p, :, (h % p) * dv:(h % p + 1) * dv], s[:, h])
+    np.testing.assert_array_equal(fam.unpack_heads(packed, p), s)
+
+
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+def test_a_lane_packed_state_serves_what_the_heads_apart_serve(kernels, monkeypatch):
+    """Four recurrent heads of 8 x 64 pack in pairs (``lane_pack``: 128
+    lanes a row). The same prefill chunk, mixed step (a decoding row
+    beside a prefilling one) and four decode steps with the state
+    packed and with the heads kept apart: the logits of every step and
+    the state agree to float32 rounding (the C = 1 rule sums over dk on
+    the packed form, the chunk form unpacks one row around itself)."""
+    cfg = fam.tiny(dtype=jnp.float32, linear_num_heads=4, linear_value_head_dim=64)
+    tiny = cfg, fam.init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(5)
+    seq = {r: rng.integers(0, cfg.vocab_size, 40).tolist() for r in (1, 3)}
+    out = []
+    for packed in (True, False):
+        if not packed:
+            monkeypatch.setattr(fam, "lane_pack", lambda H, dv: 1)
+        eng = _server(tiny, kernels=kernels).engine
+        p = 2 if packed else 1
+        assert eng.cache["state"].shape == (4, SLOTS, 4 // p, 8, p * 64)
+        logits = [_feed(eng, {1: (seq[1][:CHUNK], 0)}, CHUNK)[[1]],
+                  _feed(eng, {1: (seq[1][CHUNK:CHUNK + 1], CHUNK),
+                              3: (seq[3][:11], 0)}, CHUNK)[[1, 3]]]
+        for t in range(4):
+            logits.append(_feed(eng, {1: (seq[1][CHUNK + 1 + t:][:1], CHUNK + 1 + t),
+                                      3: (seq[3][11 + t:][:1], 11 + t)}, 1)[[1, 3]])
+        state = fam.unpack_heads(eng.cache["state"], p)
+        assert float(jnp.abs(state[:, 1]).max()) > 0 == float(jnp.abs(state[:, 0]).max())
+        out.append((np.concatenate(logits), np.asarray(state)))
+    for a, b in zip(*out):
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-6 * np.abs(b).max())
+
+
 # --- (e) what is refused, by name -------------------------------------------
 
 
