@@ -447,7 +447,9 @@ def main(argv=None):
     s.add_argument("--num-beams", type=int, default=1)
     s.add_argument("--quantization", choices=["int8", "int4"], default=None)
     s.add_argument("--offload", action="store_true")
-    s.add_argument("--pallas", action="store_true")
+    s.add_argument("--pallas", action="store_true",
+                   help="the paged step's Pallas kernels (requires "
+                        "--kv-layout paged)")
     s.add_argument("--kv-layout", choices=["dense", "paged"], default="dense",
                    help="paged = block-paged KV cache (HBM scales with "
                         "live tokens; enables high request concurrency)")
@@ -724,6 +726,8 @@ def main(argv=None):
     sdp.set_defaults(fn=cmd_spec_distill)
 
     args = p.parse_args(argv)
+    if args.fn is cmd_serve and args.pallas and args.kv_layout == "dense":
+        s.error("--pallas requires --kv-layout paged")
     # this process owns its compiles
     from .config import enable_compile_cache
 

@@ -6,7 +6,7 @@ run the Unity-style search, and times the resulting compiled step. With
 the fused :class:`~flexflow_tpu.ops.fused_transformer
 .TransformerDecoderStackOp` the searched strategy executes the same
 scan + remat + flash-attention program as the hand-sharded
-``models/llama.make_train_step`` — the search reaches the fast path
+``models/transformer.make_train_step`` — the search reaches the fast path
 instead of the interpreted per-op graph (reference: the searched PCG is
 lowered back to real operators via ``convert_graph_to_operators``,
 src/runtime/graph.cc:2108 + model.cc:3347).

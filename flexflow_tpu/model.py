@@ -213,7 +213,7 @@ class FFModel:
         ONE graph node (ops/fused_transformer.py): scan-over-layers +
         remat + optional Pallas flash attention — the fast-path bridge
         that lets ``compile(auto_parallel=True)`` reach the same program
-        quality as the hand-sharded ``models/llama.make_train_step``
+        quality as the hand-sharded ``models/transformer.make_train_step``
         (the reference's FusedOp + transformer substitutions,
         src/ops/fused.cc)."""
         return self._add(

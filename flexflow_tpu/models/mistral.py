@@ -8,8 +8,6 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
-import jax.numpy as jnp
-
 from . import transformer
 from .transformer import (  # noqa: F401  (engine serving protocol)
     DecoderConfig,
@@ -34,7 +32,7 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     serve_step,
     serve_step_paged,
 )
-from .hf_utils import layer_stackers, linear_w, stack, to_np
+from .llama import convert_hf_state_dict  # noqa: F401  (HF llama layout)
 
 
 def config(**kw) -> DecoderConfig:
@@ -101,35 +99,3 @@ def from_hf(hf: Dict[str, Any], **kw) -> DecoderConfig:
     )
     d.update(kw)
     return config(**d)
-
-
-def convert_hf_state_dict(
-    sd: Dict[str, Any], cfg: DecoderConfig
-) -> Dict[str, Any]:
-    """HF ``MistralForCausalLM`` state dict → framework pytree (same
-    tensor names as LLaMA's HF layout)."""
-    dt = cfg.dtype
-    L = cfg.num_hidden_layers
-    pre = "model."
-
-    mats, vecs = layer_stackers(sd, pre, L, dt)
-
-    layers = {
-        "attn_norm_scale": vecs("layers.{}.input_layernorm.weight"),
-        "mlp_norm_scale": vecs("layers.{}.post_attention_layernorm.weight"),
-        "wq": mats("layers.{}.self_attn.q_proj.weight"),
-        "wk": mats("layers.{}.self_attn.k_proj.weight"),
-        "wv": mats("layers.{}.self_attn.v_proj.weight"),
-        "wo": mats("layers.{}.self_attn.o_proj.weight"),
-        "w_gate": mats("layers.{}.mlp.gate_proj.weight"),
-        "w_up": mats("layers.{}.mlp.up_proj.weight"),
-        "w_down": mats("layers.{}.mlp.down_proj.weight"),
-    }
-    out: Dict[str, Any] = {
-        "embed": jnp.asarray(to_np(sd[pre + "embed_tokens.weight"]), dt),
-        "layers": layers,
-        "final_norm_scale": jnp.asarray(to_np(sd[pre + "norm.weight"]), dt),
-    }
-    if not cfg.tie_word_embeddings:
-        out["lm_head"] = jnp.asarray(to_np(sd["lm_head.weight"]).T, dt)
-    return out

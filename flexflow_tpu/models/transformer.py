@@ -1,9 +1,10 @@
-"""Generic decoder-only transformer — one engine for the whole model zoo.
+"""The decoder: one configurable decoder-only transformer that every
+family of the zoo trains on, serves on and is tested on.
 
 The reference builds each serving architecture as a separate C++ graph
-builder (reference ``inference/models/{opt,falcon,mpt,starcoder}.cc`` and
-Python twins ``python/flexflow/serve/models/*.py``), each wiring the same
-operator set with per-family choices (norm type, positional scheme,
+builder (reference ``inference/models/{llama,opt,falcon,mpt,starcoder}.cc``
+and Python twins ``python/flexflow/serve/models/*.py``), each wiring the
+same operator set with per-family choices (norm type, positional scheme,
 MQA/GQA widths, FFN activation, parallel vs sequential block). The
 TPU-native design factors that variation into one configurable decoder:
 a single `lax.scan`-over-stacked-layers program whose config selects
@@ -16,13 +17,23 @@ a single `lax.scan`-over-stacked-layers program whose config selects
     (x + attn + ffn, Falcon-style, with one or two input norms),
   * biases and tied embeddings.
 
-Each family module (opt.py, falcon.py, mpt.py, starcoder.py) is then just
-a config mapping + HF weight converter. LLaMA keeps its tuned standalone
-implementation (models/llama.py) as the flagship.
+Each family module (llama.py, opt.py, falcon.py, mpt.py, ...) is a
+config mapping + HF weight converter that re-exports this module's
+protocol. Three parts: parameters; the full-sequence pass and the train
+step built on it (:func:`forward`, :func:`make_train_step`); the
+serving protocol the engine calls (:func:`serve_step`,
+:func:`serve_step_paged` and the cache helpers).
 
-Sharding follows the same Megatron scheme as llama.py: QKV/up
-column-parallel and O/down row-parallel on the ``model`` mesh axis, layer
-stack sharded on ``pipe``, KV cache slots on ``data``.
+Design choices, made for the TPU:
+  * **Stacked layers**: all N layers' weights live in one pytree with a
+    leading layer dim. One compiled block serves every layer (fast
+    compile), the layer dim shards over the ``pipe`` axis for pipeline
+    parallelism, and ``jax.checkpoint`` remats per block.
+  * **bf16 compute / f32 accumulate** on the MXU via
+    ``preferred_element_type``.
+  * **Megatron sharding**: QKV/up column-parallel and O/down
+    row-parallel on the ``model`` mesh axis, layer stack sharded on
+    ``pipe``, KV cache slots on ``data``.
 """
 from __future__ import annotations
 
@@ -789,14 +800,25 @@ def block(
     rope: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
     bias: Optional[jnp.ndarray],  # additive attention bias (ALiBi)
     mask: Optional[jnp.ndarray],
+    attn_fn=None,
 ):
-    """One decoder block, full-sequence (training) attention."""
+    """One decoder block, full-sequence (training) attention; the
+    serving blocks with a KV cache are :func:`serve_block` and
+    :func:`serve_block_paged`. ``attn_fn(cfg, q, k, v, mask)`` ->
+    (B, S, H, dk) computes the attention in place of
+    :func:`_gqa_attend` (:func:`make_flash_attention`,
+    :func:`make_sp_attention`): it is given K/V compact (KV heads, not
+    H) and derives causality itself. Returns (x_out, None): the None
+    keeps the scan-body signature of the serving blocks."""
     h = _norm(cfg, x, p["attn_norm_scale"], p.get("attn_norm_bias"))
     q, k, v = _project_qkv(cfg, p, h)
     if rope is not None:
         cos, sin = rope
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    attn = _gqa_attend(cfg, q, k, v, bias, mask)
+    if attn_fn is None:
+        attn = _gqa_attend(cfg, q, k, v, bias, mask)
+    else:
+        attn = attn_fn(cfg, q, k, v, mask).reshape(*x.shape[:2], -1)
     attn = _mm(attn, p["wo"])
     if cfg.out_bias:
         attn = attn + p["bo"]
@@ -874,26 +896,59 @@ def _head_logits(cfg: DecoderConfig, params, x, logits_idx, pack, all_logits):
     return _lm_logits(cfg, params, x)[:, 0]
 
 
-def forward(
-    params: Dict[str, Any],
-    tokens: jnp.ndarray,
-    cfg: DecoderConfig,
-    *,
-    positions: Optional[jnp.ndarray] = None,
-    remat: bool = False,
-    shard_activations: bool = False,
-) -> jnp.ndarray:
-    """Training/eval forward → logits (B, S, V)."""
-    B, S = tokens.shape
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = _embed_in(cfg, params, tokens, positions)
+def _full_sequence_context(cfg: DecoderConfig, positions, attn_fn):
+    """(rope, bias, mask) of a full-sequence pass over ``positions``
+    (..., S). With an ``attn_fn`` there is no (S, S) mask to build: the
+    override derives causality from the positions and never
+    materialises it (the long-context path), and knows neither an
+    additive bias nor a window."""
     rope = rope_freqs(cfg, positions) if cfg.positions == "rope" else None
-    bias = _train_bias(cfg, positions)
+    if attn_fn is not None:
+        if cfg.positions == "alibi" or cfg.sliding_window:
+            raise ValueError(
+                "an attention override (flash / sequence-parallel) is "
+                "plain causal attention: it carries no ALiBi bias and no "
+                "sliding window"
+            )
+        return rope, None, None
+    S = positions.shape[-1]
     mask = jnp.tril(jnp.ones((S, S), bool))
     if cfg.sliding_window:
         idx = jnp.arange(S)
         mask &= idx[None, :] > idx[:, None] - cfg.sliding_window
+    return rope, _train_bias(cfg, positions), mask
+
+
+def _block_fn(cfg: DecoderConfig, attn_fn, remat: bool,
+              remat_policy: Optional[str]):
+    """:func:`block` bound to its config, under ``jax.checkpoint`` with
+    ``remat`` (``remat_policy``: core/remat.py)."""
+    blk = functools.partial(block, cfg, attn_fn=attn_fn)
+    if remat:
+        from ..core.remat import resolve_remat_policy
+
+        blk = jax.checkpoint(blk, policy=resolve_remat_policy(remat_policy))
+    return blk
+
+
+def forward(
+    params: Dict[str, Any],
+    tokens: jnp.ndarray,  # (B, S) int32
+    cfg: DecoderConfig,
+    *,
+    positions: Optional[jnp.ndarray] = None,
+    remat: bool = False,
+    remat_policy: Optional[str] = None,
+    shard_activations: bool = False,
+    attn_fn=None,
+) -> jnp.ndarray:
+    """Training/eval forward: full causal attention → logits (B, S, V).
+    ``attn_fn`` overrides the attention computation (:func:`block`)."""
+    B, S = tokens.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    x = _embed_in(cfg, params, tokens, positions)
+    rope, bias, mask = _full_sequence_context(cfg, positions, attn_fn)
 
     def constrain(t):
         if shard_activations:
@@ -901,9 +956,7 @@ def forward(
         return t
 
     x = constrain(x)
-    blk = functools.partial(block, cfg)
-    if remat:
-        blk = jax.checkpoint(blk)
+    blk = _block_fn(cfg, attn_fn, remat, remat_policy)
 
     def scan_body(carry, p_l):
         y, _ = blk(p_l, carry, rope, bias, mask)
@@ -914,11 +967,187 @@ def forward(
     return _lm_logits(cfg, params, x)
 
 
+def make_flash_attention(block_q: int = 128, block_k: int = 128):
+    """Causal flash-attention ``attn_fn`` (Pallas kernel with custom VJP,
+    ops/flash_attention.py): scores stream through VMEM instead of
+    materialising the (B, H, S, S) tensor the XLA path writes to HBM."""
+    from ..ops.flash_attention import flash_attention
+
+    def attn_fn(cfg, q, k, v, mask):
+        # the kernel takes one K/V head a query head
+        rep = cfg.num_attention_heads // cfg.num_key_value_heads
+        if rep > 1:
+            k = jnp.repeat(k, rep, axis=2)
+            v = jnp.repeat(v, rep, axis=2)
+        return flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k
+        )
+
+    return attn_fn
+
+
+def make_sp_attention(mesh, impl: str = "ring"):
+    """A sequence-parallel ``attn_fn`` (ring ppermute or Ulysses
+    all-to-all over the ``seq`` axis — the long-context capability the
+    reference lacks, SURVEY.md §7 step 7)."""
+    from ..parallel.sequence import ring_attention, ulysses_attention
+
+    fn = ring_attention if impl == "ring" else ulysses_attention
+
+    def attn_fn(cfg, q, k, v, mask):
+        # K/V stay compact (GQA/MQA); the SP primitives expand per block
+        # so ring ppermute traffic is KV-sized, not H-sized.
+        return fn(
+            q, k, v, mesh, causal=True,
+            shard_heads=mesh.shape[MODEL_AXIS] > 1,
+        )
+
+    return attn_fn
+
+
+def _next_token_nll(logits, targets) -> jnp.ndarray:
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    return nll.mean()
+
+
+def next_token_loss(params, tokens, cfg: DecoderConfig, **kw) -> jnp.ndarray:
+    """Causal LM loss: predict tokens[:, 1:] from tokens[:, :-1]."""
+    logits = forward(params, tokens[:, :-1], cfg, **kw)
+    return _next_token_nll(logits, tokens[:, 1:].astype(jnp.int32))
+
+
+def make_train_step(
+    cfg: DecoderConfig,
+    mesh,
+    optimizer,
+    *,
+    num_microbatches: int = 1,
+    remat: bool = True,
+    remat_policy: Optional[str] = None,  # None (full) | "dots"
+    shard_activations: bool = True,
+    attention: str = "xla",  # "xla" | "flash" (Pallas, ops/flash_attention)
+):
+    """Build (init_fn, step_fn, data_sharding) jitted over ``mesh`` with
+    the full dp/tp/pp/sp sharding stack, for any :class:`DecoderConfig`.
+
+    * dp: batch dim sharded on ``data`` (GSPMD all-reduces grads).
+    * tp: Megatron weight shardings from :func:`param_pspecs` (GSPMD
+      inserts the QKV/FFN all-reduces over ICI).
+    * sp: activation sequence dim constrained to the ``seq`` axis.
+    * pp (when mesh has pipe>1): GPipe microbatching via
+      ``parallel.pipeline`` — the stacked layer dim is sharded over
+      ``pipe`` and only that axis runs manually under shard_map.
+    """
+    from jax.sharding import NamedSharding
+
+    pipeline = mesh.shape[PIPE_AXIS] > 1
+    pspecs = param_pspecs(cfg, pipeline=pipeline)
+    shardings = jax.tree.map(
+        lambda p: NamedSharding(mesh, p), pspecs, is_leaf=lambda x: isinstance(x, P)
+    )
+
+    def init_fn(key):
+        params = jax.jit(
+            functools.partial(init_params, cfg=cfg), out_shardings=shardings
+        )(key)
+        opt_state = optimizer.init(params)
+        return params, opt_state
+
+    if not pipeline:
+        sp = mesh.shape[SEQ_AXIS] > 1
+        if sp:
+            if attention == "flash":
+                # explicit kernel choices must not be silently ignored
+                from ..logging_utils import get_logger
+
+                get_logger("model").warning(
+                    "attention='flash' requested but the mesh has seq=%d: "
+                    "sequence parallelism uses ring attention instead "
+                    "(flash+SP composition is not implemented)",
+                    mesh.shape[SEQ_AXIS],
+                )
+            attn_fn = make_sp_attention(mesh, "ring")
+        elif attention == "flash":
+            attn_fn = make_flash_attention()
+        else:
+            attn_fn = None
+
+        def loss_fn(params, tokens):
+            return next_token_loss(
+                params,
+                tokens,
+                cfg,
+                remat=remat,
+                remat_policy=remat_policy,
+                shard_activations=shard_activations and sp,
+                attn_fn=attn_fn,
+            )
+
+    else:
+        assert mesh.shape[SEQ_AXIS] == 1, (
+            "sequence parallelism is not composed with the pipeline path "
+            "yet: pipe>1 with seq>1 would fall back to dense attention "
+            "over the gathered sequence (O(S^2) memory)"
+        )
+        from ..parallel.pipeline import make_pipelined_apply
+
+        attn_fn = make_flash_attention() if attention == "flash" else None
+        blk = _block_fn(cfg, attn_fn, remat, remat_policy)
+
+        def loss_fn(params, tokens):
+            B, S = tokens.shape
+            mb = B // num_microbatches
+            inp, targets = tokens[:, :-1], tokens[:, 1:].astype(jnp.int32)
+            positions = jnp.broadcast_to(
+                jnp.arange(S - 1, dtype=jnp.int32), (B, S - 1))
+            x = _embed_in(cfg, params, inp, positions)
+            # every microbatch holds the same positions
+            rope, bias, mask = _full_sequence_context(
+                cfg, positions[:mb], attn_fn)
+
+            def block_stack(stage_layers, x_mb):
+                def body(carry, p_l):
+                    y, _ = blk(p_l, carry, rope, bias, mask)
+                    return y, None
+
+                y, _ = lax.scan(body, x_mb, stage_layers)
+                return y
+
+            piped = make_pipelined_apply(
+                mesh,
+                block_stack,
+                num_microbatches=num_microbatches,
+                params_spec=jax.tree.map(
+                    lambda _: P(PIPE_AXIS), params["layers"]
+                ),
+            )
+            y = piped(
+                params["layers"],
+                x.reshape(num_microbatches, mb, S - 1, cfg.hidden_size),
+            ).reshape(B, S - 1, cfg.hidden_size)
+            y = _norm(cfg, y, params["final_norm_scale"],
+                      params.get("final_norm_bias"))
+            return _next_token_nll(_lm_logits(cfg, params, y), targets)
+
+    def step_fn(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(loss_fn)(params, tokens)
+        params, opt_state = optimizer.update(grads, opt_state, params)
+        return params, opt_state, loss
+
+    data_sharding = NamedSharding(mesh, P(DATA_AXIS, None))
+    step = jax.jit(step_fn, donate_argnums=(0, 1))
+    return init_fn, step, data_sharding
+
+
 # ---------------------------------------------------------------------------
-# Serving path — the same engine protocol as models/llama.py: request-slot
-# paged KV cache with a scratch row, one compiled program per static
-# (chunk, all_logits, mask-mode) signature (reference's three attention
-# operators inc/spec/tree_inc_multihead_self_attention collapse into one).
+# Serving path (KV cache) — the engine's protocol (serve/engine.py). One
+# step function serves prefill (chunk C>1), incremental decode (C=1) and
+# SpecInfer tree-verify (explicit mask): the TPU-native counterpart of the
+# reference's three attention operators
+# (inc/spec/tree_inc_multihead_self_attention, SURVEY.md §2.1). Instead of
+# three CUDA kernels there is one compiled XLA program per static
+# (C, all_logits, mask-mode) signature, all sharing the same KV buffers.
 
 
 def needs_pos_cache(cfg: DecoderConfig) -> bool:
@@ -932,6 +1161,13 @@ def needs_pos_cache(cfg: DecoderConfig) -> bool:
 
 
 def init_kv_cache(cfg: DecoderConfig, num_slots: int, max_len: int, dtype=None):
+    """KV cache pytree: (L, slots, max_len+1, KV, dk). The last position
+    is a scratch row — padding tokens scatter there so real cache lines
+    are never corrupted (replaces the reference's per-request contiguous
+    cache with request-slot paging,
+    inc_multihead_self_attention.cu:1338). ALiBi / sliding-window
+    configs add the (slots, max_len+1) position buffer
+    (:func:`needs_pos_cache`)."""
     L, KV, dk = cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim
     dt = dtype or cfg.dtype
     shape = (L, num_slots, max_len + 1, KV, dk)
@@ -1019,14 +1255,32 @@ def serve_step(
     num_layers: Optional[int] = None,
     mesh=None,
 ):
-    """One serving step over R request slots × C tokens; same contract as
-    ``models.llama.serve_step`` (see engine protocol in serve/engine.py),
-    including the stage-sharded pipeline path when ``mesh`` has pipe>1.
-    ``num_layers`` is the layer-sliced early-exit draft step (see
-    models/llama.serve_step): only the first ``num_layers`` blocks run
-    and commit K/V; the deeper layers' cache buffers pass through for
-    the verify pass to own (the position buffer, written once per step
-    rather than per layer, updates in full either way)."""
+    """One serving step over R request slots × C tokens each (the engine
+    protocol, serve/engine.py). Padding tokens sit at the scratch
+    position.
+
+    ``cache_positions`` (cache line indices) defaults to ``positions``;
+    SpecInfer passes them separately because sibling tree tokens share a
+    sequence position (prefix + depth) but need distinct cache lines
+    (prefix + node index).
+
+    With a ``mesh`` whose pipe axis is >1, the layer stack (and the
+    layer-major KV cache) is stage-sharded and activations flow through
+    the pipeline (reference inference_manager.cc:91-133 stage mapping).
+
+    ``num_layers`` runs a LAYER-SLICED step: only the first
+    ``num_layers`` blocks execute (their K/V commit into the cache; the
+    deeper layers' cache buffers pass through untouched; the position
+    buffer, written once per step rather than per layer, updates in
+    full either way) before the full model's final norm + head read the
+    truncated hidden state — the self-speculation "early-exit" draft
+    (LayerSkip-style, SpecConfig.draft="early_exit"): the target's own
+    shallow prefix drafts tokens the full-depth verify pass then
+    re-checks. None (default) = the full stack.
+
+    Returns (logits, new_cache): logits (R, V) at ``logits_idx`` or
+    (R, C, V) when ``all_logits`` (tree verification needs every token's
+    logits, reference tree_inc_multihead_self_attention.cu)."""
     R, C = tokens.shape
     S1 = cache["k"].shape[2]
     if cache_positions is None:
@@ -1137,9 +1391,14 @@ def serve_step(
 
 
 def commit_kv(cache, src, dst):
-    """Move accepted speculative cache lines into committed positions (see
-    ``models.llama.commit_kv``; reference ``request_manager.cu`` token
-    commit). Handles the extra (R, S1) position buffer for ALiBi caches."""
+    """Move accepted speculative K/V lines ``src`` (R, K) into their
+    committed positions ``dst`` (R, K) — the TPU-native version of the
+    reference's token-commit copy kernels (reference
+    ``request_manager.cu`` commit_tokens + the KV-cache commit in
+    ``tree_inc_multihead_self_attention.cu``). Unused slots should map
+    scratch→scratch. Functional gather-then-scatter, so overlapping
+    src/dst ranges are safe. The (R, S1) position buffer of ALiBi /
+    sliding-window caches moves with the lines."""
     R = src.shape[0]
     bidx = jnp.arange(R)[:, None]
     out = {}
@@ -1154,8 +1413,11 @@ def commit_kv(cache, src, dst):
 def reorder_slots(
     cache: Dict[str, jnp.ndarray], src: jnp.ndarray  # (R,) int32
 ) -> Dict[str, jnp.ndarray]:
-    """Gather cache slots (see models.llama.reorder_slots); the ALiBi
-    position buffer's slot dim leads instead of following the layer dim."""
+    """Gather cache slots: new slot r takes slot src[r]'s lines — beam
+    search reorders hypotheses across request slots this way (the
+    reference's beam attention forks sub-request KV instead,
+    spec_inc_multihead_self_attention.cu). The position buffer's slot
+    dim leads instead of following the layer dim."""
     return {
         name: (buf[src] if name == "pos" else buf[:, src])
         for name, buf in cache.items()
@@ -1163,11 +1425,18 @@ def reorder_slots(
 
 
 # ---------------------------------------------------------------------------
-# Paged serving path (Ragged Paged Attention layout — see the twin
-# implementation in models/llama.py for the design rationale): the pool
-# replaces the per-slot line dim with (pages+1, page_size); page tables
-# resolve logical cache lines to physical pages. The extra per-line
-# position buffer (ALiBi/sliding-window families) pages the same way.
+# Paged serving path (Ragged Paged Attention layout, PAPERS.md arxiv
+# 2604.15464): K/V live in a pool of fixed-size token pages shared by all
+# request slots; each slot's page table maps logical cache lines
+# (line // page_size) to physical pages. HBM is proportional to pages
+# allocated — live tokens — instead of slots × max_len, which is what
+# lets one chip serve the reference's 64 request slots. The XLA path
+# gathers the virtual cache through the table with ``jnp.take`` and runs
+# the dense _serve_attend math (bit-for-bit parity with the dense
+# layout); ``kernels="pallas"`` routes through the fused ragged paged
+# kernel (serve/kernels.py), which DMAs pages directly. The extra
+# per-line position buffer (ALiBi/sliding-window families) pages the
+# same way.
 
 #: decode-step fusions the generic decoder's serving step supports
 #: (ServingConfig.fused_decode; the engine validates requests against
@@ -1193,15 +1462,22 @@ def init_paged_kv_cache(
     kv_quant: Optional[str] = None, extra_rows: int = 0,
 ):
     """Pool (L, num_pages+1, page_size, KV, dk); pool row ``num_pages``
-    is the shared scratch page. ALiBi/sliding-window configs also page
-    the per-line position buffer. With ``kv_quant`` the pools store
+    is the shared scratch page — unallocated page-table entries point
+    there, so padding writes and gathers through unallocated entries
+    never touch live pages (the paged analog of the dense layout's
+    per-slot scratch row). ALiBi/sliding-window configs also page the
+    per-line position buffer. With ``kv_quant`` the pools store
     quantized codes — int8, or packed int4 nibbles (two codes per byte
     along dk, trailing dim ``head_dim // 2``) — plus per-page-per-KV-
-    head f32 ``k_scale``/``v_scale`` rows (serve/kv_quant.py; the
+    head f32 ``k_scale``/``v_scale`` rows, zero-initialised: a zero
+    scale marks a page with no committed lines (serve/kv_quant.py; the
     position buffer stays int32 — it is exact metadata, not tensor
-    payload). ``extra_rows`` appends never-referenced pad rows after
-    the scratch row (context-parallel row-shard alignment — see
-    models/llama.py init_paged_kv_cache)."""
+    payload). ``extra_rows`` appends never-referenced pad rows AFTER
+    the scratch row: context-parallel serving
+    (ServingConfig.kv_shard="context") shards pool rows over the mesh
+    ``seq`` axis and pads the row count to a multiple of the shard
+    degree; no table entry ever points past the scratch row, so the
+    pads are pure alignment."""
     L, KV, dk = cfg.num_hidden_layers, cfg.num_key_value_heads, cfg.head_dim
     dt = dtype or cfg.dtype
     spec = None
@@ -1235,9 +1511,10 @@ def paged_kv_cache_pspecs(cfg: DecoderConfig = None, *, pipeline: bool = False,
     """Pages shard over DP, KV heads over TP (MQA replicates, as in the
     dense layout); quantized scale rows shard like their pools (pages
     on data, KV heads on model). ``kv_shard="context"`` shards pool
-    rows (and the position buffer's) over the SEQ axis instead — the
-    sequence-sharded layout of context-parallel serving (see
-    models/llama.py paged_kv_cache_pspecs)."""
+    rows (and the position buffer's) over the SEQ axis instead: each
+    sequence shard holds its own slice of one request's pages, which
+    ring ragged paged attention reads locally
+    (serve/kernels.ring_ragged_paged_attention)."""
     kv_axis = (
         None if (cfg is not None and cfg.num_key_value_heads == 1)
         else MODEL_AXIS
@@ -1607,13 +1884,21 @@ def serve_step_paged(
     cp_mesh=None,
     pack: Optional[int] = None,
 ):
-    """Paged twin of :func:`serve_step` — same contract plus the page
-    table (see models/llama.py serve_step_paged; ``kv_quant`` selects
-    the quantized pool layout, ``fused_rope`` the megakernel decode
-    step's in-kernel RoPE + KV-write prologue on the Pallas path,
-    ``num_layers`` the layer-sliced early-exit draft step, ``cp_mesh``
-    the ring context-parallel attention over a sequence-sharded pool —
-    ALiBi-bias families reject it, see serve_block_paged).
+    """Paged twin of :func:`serve_step` — same contract plus the
+    per-slot page table; prefill chunks, single-token decode and
+    tree-verify all read/write K/V through the table. ``kv_quant``
+    selects the quantized pool layout (serve/kv_quant.py): the KV commit
+    quantizes in-step and attention dequantizes at read time.
+    ``fused_rope`` (megakernel decode step) folds RoPE and the KV page
+    write into the Pallas kernel per block — a no-op on the XLA path,
+    which already is the fused variants' CPU-parity reference.
+    ``num_layers`` is the layer-sliced early-exit draft step
+    (:func:`serve_step`): deeper pool rows (and their quant scale rows)
+    pass through untouched for the verify pass to own. ``cp_mesh``
+    (context parallelism, ServingConfig.kv_shard="context" on a
+    sequence-sharded mesh) routes every block's attention through ring
+    ragged paged attention over the seq-sharded pool — ALiBi-bias
+    families reject it, see serve_block_paged.
 
     ``pack`` (a static width T that holds the step's real tokens; the
     engine picks it from the positions, serve/engine.run_mixed): embed,
@@ -1740,9 +2025,11 @@ def serve_step_paged(
 
 
 def copy_page_kv(cache, src, dst):
-    """Copy one physical page's lines to another page (prefix-cache
-    copy-on-write; see models.llama.copy_page_kv) — the position pool
-    pages like K/V but without the layer dim. Dtype-agnostic: quantized
+    """Copy one physical page's lines (all layers) to another page — the
+    device half of prefix-cache copy-on-write (serve/prefix_cache.py): a
+    request appending into a shared cached tail page writes into a
+    private copy, never the cached original. The position pool pages
+    like K/V but without the layer dim. Dtype-agnostic: quantized
     pools' int8 codes and their (L, P+1, KV) scale rows copy through
     the same pool-row scatter, so a COW'd page dequantizes identically
     to its original."""
@@ -1756,9 +2043,13 @@ def copy_page_kv(cache, src, dst):
 
 
 def gather_page_kv(cache, page):
-    """Slice one physical page out of every cache buffer (hierarchical-
-    KV spill read; see models.llama.gather_page_kv) — the position pool
-    pages like K/V but without the layer dim."""
+    """Slice one physical page's content out of every cache buffer — the
+    device half of a hierarchical-KV SPILL (serve/prefix_cache.py host
+    tier): the engine starts an async device→host copy on the returned
+    pytree and the page returns to the free list. Covers K/V pools AND
+    the quantized layout's per-page scale rows, so a spilled page
+    re-admits byte-for-byte; the position pool pages like K/V but
+    without the layer dim."""
     out = {}
     for name, buf in cache.items():
         if name == "pos":  # (P+1, ps)
@@ -1769,8 +2060,12 @@ def gather_page_kv(cache, page):
 
 
 def scatter_page_kv(cache, page, values):
-    """Write a spilled page's content back into pool row ``page``
-    (hierarchical-KV re-admit; see models.llama.scatter_page_kv)."""
+    """Write a previously spilled page's content (the pytree
+    :func:`gather_page_kv` produced) into pool row ``page`` — the device
+    half of a host-tier RE-ADMIT. Exact inverse of the gather: codes and
+    scales land byte-for-byte, which is what keeps
+    spilled-then-readmitted generation bitwise identical to the
+    never-evicted warm path."""
     out = {}
     for name, buf in cache.items():
         if name == "pos":
@@ -1781,11 +2076,19 @@ def scatter_page_kv(cache, page, values):
 
 
 def commit_kv_paged(cache, page_table, src, dst, *, kv_quant=None):
-    """:func:`commit_kv` through the page table (see
-    models.llama.commit_kv_paged); the position pool pages like K/V but
-    without the layer dim. Quantized pools dequant-then-requant the
-    moved lines so destination page scales stay exact (the position
-    buffer still moves verbatim — it is exact int32 metadata)."""
+    """:func:`commit_kv` through the page table: accepted speculative
+    lines move between table-resolved (page, offset) pairs. Functional
+    gather-then-scatter, so overlapping ranges stay safe; scratch→
+    scratch no-ops are harmless duplicates (identical values). The
+    position pool pages like K/V but without the layer dim.
+
+    On a quantized pool the codes cannot move verbatim (source and
+    destination pages carry different scales): the lines dequantize at
+    their source page's scale and re-commit through the standard
+    quantized write (serve/kv_quant.quant_commit_lines), updating the
+    destination pages' amax scales exactly as a fresh write would (the
+    position buffer still moves verbatim — it is exact int32
+    metadata)."""
     ps = cache["k"].shape[2]
     s_phys, s_off = _page_lookup(page_table, src, ps)
     d_phys, d_off = _page_lookup(page_table, dst, ps)
@@ -1814,8 +2117,12 @@ def commit_kv_paged(cache, page_table, src, dst, *, kv_quant=None):
 
 
 def reorder_slots_paged(cache, page_table, src):
-    """Page-content copy between slots' own pages (see
-    models.llama.reorder_slots_paged)."""
+    """:func:`reorder_slots` for the paged layout: page OWNERSHIP stays
+    with each slot (the host table is untouched) and page CONTENT is
+    copied — new slot r's pages receive slot src[r]'s lines. Requires
+    the destination slots to have (at least) the source slots' pages
+    allocated, which beam search guarantees by construction
+    (equal-length hypotheses)."""
     src_pages = page_table[src].reshape(-1)
     dst_pages = page_table.reshape(-1)
     out = {}
@@ -1841,14 +2148,14 @@ def serve_debug_activations(
     cache_len: Optional[int] = None,
     kv_quant: Optional[str] = None,
 ):
-    """Per-layer hidden-state capture for ``inference_debugging`` on the
-    generic decoder — previously the hook only existed for LLaMA, making
-    the switch a silent no-op for every other family (ADVICE.md round
-    5). Eager Python loop so each layer's output survives as its own
-    array; cache writes are computed and DISCARDED (the engine's
-    donating step does the real commit). ``kernels`` is accepted for
-    signature parity with the engine's call and ignored — the triage
-    path is deliberately the plain XLA one."""
+    """Per-layer hidden-state capture for ``inference_debugging``
+    (reference's per-op tensor dump mode, serve/__init__.py:48 — saving
+    all inputs/outputs to file for serving triage). Eager Python loop so
+    each layer's output survives as its own array; cache writes are
+    computed and DISCARDED (the engine's donating step does the real
+    commit). Deliberately slow — a triage tool, not a serving path.
+    ``kernels`` is accepted for signature parity with the engine's call
+    and ignored — the triage path is deliberately the plain XLA one."""
     del kernels  # triage runs the reference XLA math
     if cache_positions is None:
         cache_positions = positions
@@ -1977,3 +2284,9 @@ def num_params(cfg: DecoderConfig) -> int:
     return sum(
         int(math.prod(s.shape)) for s in jax.tree.leaves(shapes)
     )
+
+
+def flops_per_token(cfg: DecoderConfig, seq_len: int) -> int:
+    """Forward FLOPs/token ≈ 2*n_params + attention quadratic term."""
+    return (2 * num_params(cfg)
+            + 4 * cfg.num_hidden_layers * cfg.hidden_size * seq_len)

@@ -11,8 +11,7 @@ FlashAttention-2 schedule laid out for the MXU (128-aligned blocks,
 f32 accumulators).
 
 Layout: ``(B, S, H, dk)`` queries / ``(B, T, H, dk)`` keys+values (GQA
-heads repeated by the caller, as models/llama.py already does for the
-XLA path). Non-TPU backends run ``interpret=True`` so the CPU-mesh
+heads repeated by the caller, models/transformer.make_flash_attention). Non-TPU backends run ``interpret=True`` so the CPU-mesh
 tests exercise the same code path numerically.
 """
 from __future__ import annotations
@@ -388,7 +387,7 @@ def flash_attention(
     block_k: int = 128,
 ) -> jnp.ndarray:
     """Fused multi-head attention, differentiable. Heads must already be
-    repeated for GQA (matches the XLA path in models/llama.py)."""
+    repeated for GQA (models/transformer.make_flash_attention does)."""
     B, S, H, dk = q.shape
     T = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)

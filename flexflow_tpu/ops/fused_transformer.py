@@ -1,13 +1,13 @@
 """Fused transformer decoder stack as ONE graph-IR operator.
 
 This op is the bridge between the graph-IR training stack (FFModel +
-Unity search) and the fast hand-sharded path (models/llama.py): the
+Unity search) and the fast hand-sharded path (models/transformer.py): the
 whole N-layer decoder — RMSNorm → QKV+RoPE → attention → residual →
 SwiGLU FFN, scanned over stacked layer weights with per-block remat and
 optionally the Pallas flash-attention kernel — executes as a single op
 inside ``FFModel.run_graph``. The Unity search prices and shards it like
 any other node, so ``compile(auto_parallel=True)`` now reaches the same
-compiled program quality as ``llama.make_train_step`` instead of the
+compiled program quality as ``transformer.make_train_step`` instead of the
 interpreted per-op graph.
 
 The reference gets the equivalent effect from its FusedOp + the
@@ -26,10 +26,8 @@ search's resharding point of view the op behaves like a DP node.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, List
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -50,7 +48,7 @@ def _cfg_from_attrs(attrs: Dict, D: int, S: int, dtype):
         num_hidden_layers=attrs["num_layers"],
         num_attention_heads=H,
         num_key_value_heads=attrs.get("num_kv_heads") or H,
-        rms_norm_eps=attrs.get("eps", 1e-6),
+        norm_eps=attrs.get("eps", 1e-6),
         rope_theta=attrs.get("rope_theta", 10000.0),
         max_position_embeddings=max(S, 1),
         dtype=dtype,
@@ -78,36 +76,30 @@ class TransformerDecoderStackOp(OpDef):
         return [x]
 
     def init(self, key, in_specs: List[TensorSpec], attrs: Dict) -> Dict:
-        from ..models import llama
+        from ..models import transformer
 
         (x,) = in_specs
         cfg = _cfg_from_attrs(attrs, x.shape[-1], x.shape[1], x.jnp_dtype)
         # init_params builds embed/head too (tiny at vocab_size=1);
         # keep only the stacked layer weights this op owns.
-        full = llama.init_params(key, cfg)
+        full = transformer.init_params(key, cfg)
         return full["layers"]
 
     def forward(self, weights, inputs, attrs, ctx):
-        from ..models import llama
+        from ..models import transformer
 
         (x,) = inputs
         B, S, D = x.shape
         cfg = _cfg_from_attrs(attrs, D, S, x.dtype)
-        positions = jnp.arange(S, dtype=jnp.int32)
-        cos, sin = llama.rope_freqs(cfg, positions)
-        attn_impl = attrs.get("attention", "xla")
-        attn_fn = llama.make_flash_attention() if attn_impl == "flash" else None
-        mask = None if attn_fn is not None else llama.causal_mask(S)
-        blk = functools.partial(llama.block, cfg, attn_fn=attn_fn)
-        if attrs.get("remat", True):
-            from ..core.remat import resolve_remat_policy
-
-            blk = jax.checkpoint(
-                blk, policy=resolve_remat_policy(attrs.get("remat_policy"))
-            )
+        attn_fn = (transformer.make_flash_attention()
+                   if attrs.get("attention", "xla") == "flash" else None)
+        rope, bias, mask = transformer._full_sequence_context(
+            cfg, jnp.arange(S, dtype=jnp.int32), attn_fn)
+        blk = transformer._block_fn(
+            cfg, attn_fn, attrs.get("remat", True), attrs.get("remat_policy"))
 
         def body(carry, p_l):
-            y, _ = blk(p_l, carry, cos, sin, mask)
+            y, _ = blk(p_l, carry, rope, bias, mask)
             return y, None
 
         y, _ = lax.scan(body, x, weights)
@@ -118,15 +110,15 @@ class TransformerDecoderStackOp(OpDef):
     def weight_pspecs(self, in_specs, attrs, model_axis):
         if attrs.get("tp_shard") == "megatron":
             return {
-                "attn_norm": P(None, None),
+                "attn_norm_scale": P(None, None),
                 "wq": P(None, None, model_axis),
                 "wk": P(None, None, model_axis),
                 "wv": P(None, None, model_axis),
                 "wo": P(None, model_axis, None),
-                "ffn_norm": P(None, None),
-                "w1": P(None, None, model_axis),
-                "w2": P(None, model_axis, None),
-                "w3": P(None, None, model_axis),
+                "mlp_norm_scale": P(None, None),
+                "w_gate": P(None, None, model_axis),
+                "w_down": P(None, model_axis, None),
+                "w_up": P(None, None, model_axis),
             }
         return super().weight_pspecs(in_specs, attrs, model_axis)
 

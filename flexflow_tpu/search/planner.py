@@ -10,7 +10,7 @@ MXU compute, Megatron all-reduces per layer, GPipe bubble + stage
 hand-offs, ring-attention K/V rotation, DP gradient all-reduce — under
 an HBM-fit constraint (params + optimizer moments + rematerialized
 activations). The winner plugs straight into
-``llama.make_train_step``'s MachineSpec.
+``transformer.make_train_step``'s MachineSpec.
 
 The reference explores its analogous dims inside one search because
 Legion tasks make pipelining just another placement; under XLA the

@@ -30,7 +30,7 @@ class BatchConfig:
     """One step's device inputs, padded to (num_slots, chunk).
 
     ``positions`` of padding tokens point at the cache's scratch row so
-    their K/V writes are harmless (models/llama.py init_kv_cache).
+    their K/V writes are harmless (models/transformer.py init_kv_cache).
     """
 
     tokens: np.ndarray        # (R, C) int32

@@ -61,12 +61,8 @@ def init(cfg_json: str) -> int:
     family = cfg.get("family", "llama")
     mod = importlib.import_module(f"flexflow_tpu.models.{family}")
     model_kw = _dtypes(cfg.get("model", {}), "dtype")
-    if hasattr(mod, "LLaMAConfig"):
-        mcfg = mod.LLaMAConfig(**model_kw)
-    else:
-        # generic-decoder families (opt/falcon/mpt/starcoder/qwen2)
-        # expose a config() factory over DecoderConfig
-        mcfg = mod.config(**model_kw)
+    # every family module exposes a config() factory over DecoderConfig
+    mcfg = mod.config(**model_kw)
     from .engine import InferenceEngine, ServingConfig
     from .request_manager import RequestManager
 

@@ -38,8 +38,10 @@ class ServingConfig:
     prefill_chunk: int = 128
     max_spec_tree_tokens: int = 64
     cache_dtype: Any = jnp.bfloat16
-    # "xla" (default) or "pallas" — fused decode/tree-verify attention
-    # kernels (serve/kernels.py) for models that support the kwarg.
+    # "xla" (default) or "pallas": what the PAGED step runs on — the
+    # Pallas kernels of serve/kernels.py (ragged paged attention, the
+    # grouped expert matmuls, a family's own), or their XLA twins. The
+    # dense layout is the XLA reference layout and takes "xla" alone.
     kernels: str = "xla"
     # Steady-state decode keeps up to this many steps in flight: sampled
     # tokens feed the next step on-device, the host fetches results one
@@ -746,7 +748,7 @@ class InferenceEngine:
     """Owns device-resident params + KV cache and the jitted step fns.
 
     ``model`` is a model-family module exposing the serving protocol
-    (see models/llama.py): ``init_kv_cache(cfg, slots, max_len, dtype)``,
+    (see models/transformer.py): ``init_kv_cache(cfg, slots, max_len, dtype)``,
     ``commit_kv(cache, src, dst)`` and
     ``serve_step(params, cache, tokens, positions, logits_idx, mask,
     cache_positions, *, cfg, all_logits)``.
@@ -832,6 +834,12 @@ class InferenceEngine:
             raise ValueError(
                 f"unknown kv_layout {self.serving.kv_layout!r} "
                 "(expected 'dense' or 'paged')"
+            )
+        if not self.paged and self.serving.kernels != "xla":
+            raise ValueError(
+                f"kernels={self.serving.kernels!r} requires "
+                "kv_layout='paged': the dense layout is the XLA reference "
+                "layout and has no Pallas kernels"
             )
         # Context-parallel long-context serving (kv_shard="context"):
         # resolve the shard degree against this engine's mesh and fail
@@ -1230,12 +1238,12 @@ class InferenceEngine:
             kw["num_layers"] = int(num_layers)
         if pack is not None:
             kw["pack"] = int(pack)
-        if self.serving.kernels != "xla":
-            kw["kernels"] = self.serving.kernels
         if self.pipelined:
             kw["mesh"] = self.mesh
         if self.paged:
             kw["cache_len"] = self.serving.cache_len
+            if self.serving.kernels != "xla":
+                kw["kernels"] = self.serving.kernels
             if self.serving.kv_quant is not None:
                 kw["kv_quant"] = self.serving.kv_quant
             if "rope_kv_write" in self.serving.fused_decode:

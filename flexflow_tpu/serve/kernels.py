@@ -7,17 +7,8 @@ prefill path is MXU-shaped already (big GEMMs — XLA does it well), but
 **decode** attention (one query token against a long KV cache) is
 bandwidth-bound and benefits from a fused flash-style kernel: QK^T →
 online softmax → PV in VMEM, one pass over the cache, no (R, H, S)
-score tensor ever hitting HBM.
-
-:func:`decode_attention` — grid (request, cache-chunk); per-request
-online-softmax accumulators persist in VMEM scratch across the chunk
-dimension. Per-request ``seq_lens`` mask invalid cache lines, so one
-static-shape program serves every request length (the reference pads to
-MAX_NUM_TOKENS the same way, batch_config.h:58-60).
-
-:func:`verify_attention` — the tree-verify variant: C query tokens per
-request with an explicit (C, S) boolean mask (the reference's causal
-``BitMask``), same online-softmax core.
+score tensor ever hitting HBM. The kernels here serve the PAGED layout;
+the dense layout is the XLA reference layout and has none.
 
 :func:`ragged_paged_attention` — the paged-KV variant (PAPERS.md,
 arxiv 2604.15464 Ragged Paged Attention): K/V live in a pool of
@@ -111,234 +102,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.flash_attention import _block_positions, _interpret
+from ..ops.flash_attention import _interpret
 
 NEG_INF = -1e30
-
-
-def _decode_kernel(
-    seq_ref,      # scalar-prefetch: (R,) int32 valid cache length per slot
-    q_ref,        # (1, KV, G, dk)
-    k_ref,        # (1, CS, KV, dk)
-    v_ref,        # (1, CS, KV, dk)
-    out_ref,      # (1, KV, G, dk)
-    o_scr,        # VMEM (KV, G, dk) f32
-    m_scr,        # VMEM (KV, G) f32
-    l_scr,        # VMEM (KV, G) f32
-    *,
-    block_s: int,
-    scale: float,
-):
-    r = pl.program_id(0)
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _():
-        o_scr[:] = jnp.zeros_like(o_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    def valid(shape, axis):
-        return _block_positions(s, block_s, shape, axis) < seq_ref[r]
-
-    @pl.when(s * block_s < seq_ref[r])  # any line of this block valid
-    def _():
-        q = q_ref[0].astype(jnp.float32)                    # (KV, G, dk)
-        # Mosaic batched matmul needs both batch dims leading: lay K/V
-        # out as (KV, CS, dk) for the chunk
-        k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (KV, CS, dk)
-        v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        # zero out-of-bounds/invalid rows: p is 0 there, but 0·NaN from
-        # block padding would still poison the PV product
-        v = jnp.where(valid(v.shape, 1), v, 0.0)
-        # scores (KV, G, CS): batch over KV heads, contract dk
-        scores = jax.lax.dot_general(
-            q, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        ok = valid(scores.shape, 2)
-        scores = jnp.where(ok, scores, NEG_INF)
-        m_new = jnp.maximum(m_scr[:], scores.max(axis=-1))
-        p = jnp.exp(scores - m_new[..., None])
-        p = jnp.where(ok, p, 0.0)
-        corr = jnp.exp(m_scr[:] - m_new)
-        l_scr[:] = l_scr[:] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (KV, G, dk)
-        o_scr[:] = o_scr[:] * corr[..., None] + pv
-        m_scr[:] = m_new
-
-    @pl.when(s == pl.num_programs(1) - 1)
-    def _():
-        l = jnp.maximum(l_scr[:], 1e-20)
-        out_ref[0] = (o_scr[:] / l[..., None]).astype(out_ref.dtype)
-
-
-def decode_attention(
-    q: jnp.ndarray,        # (R, H, dk)
-    k_cache: jnp.ndarray,  # (R, S1, KV, dk)
-    v_cache: jnp.ndarray,  # (R, S1, KV, dk)
-    seq_lens: jnp.ndarray, # (R,) int32 — lines [0, seq_len) are attended
-    *,
-    block_s: int = 256,
-    scale: Optional[float] = None,
-) -> jnp.ndarray:
-    """Fused decode attention: one query token per request slot against
-    its cache prefix. Returns (R, H, dk)."""
-    R, H, dk = q.shape
-    _, S1, KV, _ = k_cache.shape
-    G = H // KV
-    scale = scale if scale is not None else 1.0 / math.sqrt(dk)
-    # keep blocks lane-aligned: a non-multiple-of-128 block (e.g. the
-    # cache's odd S1 = max_len+1) tiles catastrophically in Mosaic
-    block_s = 128 * pl.cdiv(min(block_s, S1), 128)
-    qg = q.reshape(R, KV, G, dk)
-    grid = (R, pl.cdiv(S1, block_s))
-
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_s=block_s, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((R, KV, G, dk), q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                # index maps receive the scalar-prefetch ref as a trailing arg
-                pl.BlockSpec((1, KV, G, dk), lambda r, s, seq: (r, 0, 0, 0)),
-                pl.BlockSpec((1, block_s, KV, dk), lambda r, s, seq: (r, s, 0, 0)),
-                pl.BlockSpec((1, block_s, KV, dk), lambda r, s, seq: (r, s, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, KV, G, dk), lambda r, s, seq: (r, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((KV, G, dk), jnp.float32),
-                pltpu.VMEM((KV, G), jnp.float32),
-                pltpu.VMEM((KV, G), jnp.float32),
-            ],
-        ),
-        name="ff_decode_attention",
-        interpret=_interpret(),
-    )(seq_lens.astype(jnp.int32), qg, k_cache, v_cache)
-    return out.reshape(R, H, dk)
-
-
-def _verify_kernel(
-    q_ref,        # (1, C, KV, G, dk)
-    k_ref,        # (1, CS, KV, dk)
-    v_ref,        # (1, CS, KV, dk)
-    mask_ref,     # (1, C, CS) bool
-    out_ref,      # (1, C, KV, G, dk)
-    o_scr,        # VMEM (C, KV, G, dk) f32
-    m_scr,        # VMEM (C, KV, G) f32
-    l_scr,        # VMEM (C, KV, G) f32
-    *,
-    block_s: int,
-    total_s: int,
-    scale: float,
-):
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _():
-        o_scr[:] = jnp.zeros_like(o_scr)
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-
-    # When S1 % block_s != 0 the mask block's tail is out-of-bounds
-    # padding with unspecified contents on TPU — bound it explicitly.
-    def inbounds(shape, axis):
-        return _block_positions(s, block_s, shape, axis) < total_s
-
-    mask = mask_ref[0]
-    mask = mask & inbounds(mask.shape, 1)  # (C, CS)
-
-    @pl.when(jnp.any(mask))
-    def _():
-        q = q_ref[0].astype(jnp.float32)           # (C, KV, G, dk)
-        k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # (KV, CS, dk)
-        v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-        v = jnp.where(inbounds(v.shape, 1), v, 0.0)
-        C = q.shape[0]
-        # (KV, C*G, dk) grouped layout so one batched dot serves all KV heads
-        qkv = q.transpose(1, 0, 2, 3).reshape(q.shape[1], -1, q.shape[-1])
-        # (KV, C*G, dk) × (KV, CS, dk) -> (KV, C*G, CS)
-        scores = jax.lax.dot_general(
-            qkv, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        KV = q.shape[1]
-        G = q.shape[2]
-        scores = scores.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)  # (C,KV,G,CS)
-        scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-        m_new = jnp.maximum(m_scr[:], scores.max(axis=-1))
-        p = jnp.exp(scores - m_new[..., None])
-        p = jnp.where(mask[:, None, None, :], p, 0.0)
-        corr = jnp.exp(m_scr[:] - m_new)
-        l_scr[:] = l_scr[:] * corr + p.sum(axis=-1)
-        pk = p.transpose(1, 0, 2, 3).reshape(KV, C * G, -1)   # (KV, C*G, CS)
-        pv = jax.lax.dot_general(
-            pk, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (KV, C*G, dk)
-        pv = pv.reshape(KV, C, G, -1).transpose(1, 0, 2, 3)
-        o_scr[:] = o_scr[:] * corr[..., None] + pv
-        m_scr[:] = m_new
-
-    @pl.when(s == pl.num_programs(1) - 1)
-    def _():
-        l = jnp.maximum(l_scr[:], 1e-20)
-        out_ref[0] = (o_scr[:] / l[..., None]).astype(out_ref.dtype)
-
-
-def verify_attention(
-    q: jnp.ndarray,        # (R, C, H, dk) — C tree tokens per request
-    k_cache: jnp.ndarray,  # (R, S1, KV, dk)
-    v_cache: jnp.ndarray,  # (R, S1, KV, dk)
-    mask: jnp.ndarray,     # (R, C, S1) bool — the spec-tree BitMask
-    *,
-    block_s: int = 256,
-    scale: Optional[float] = None,
-) -> jnp.ndarray:
-    """Fused tree-verify attention: every speculative tree token attends
-    its causal-bitmask cache subset in one pass (reference
-    ``tree_inc_multihead_self_attention.cu``). Returns (R, C, H, dk)."""
-    R, C, H, dk = q.shape
-    _, S1, KV, _ = k_cache.shape
-    G = H // KV
-    scale = scale if scale is not None else 1.0 / math.sqrt(dk)
-    block_s = 128 * pl.cdiv(min(block_s, S1), 128)  # lane-aligned blocks
-    qg = q.reshape(R, C, KV, G, dk)
-    grid = (R, pl.cdiv(S1, block_s))
-
-    out = pl.pallas_call(
-        functools.partial(_verify_kernel, block_s=block_s, total_s=S1,
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype),
-        grid_spec=pl.GridSpec(
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, C, KV, G, dk), lambda r, s: (r, 0, 0, 0, 0)),
-                pl.BlockSpec((1, block_s, KV, dk), lambda r, s: (r, s, 0, 0)),
-                pl.BlockSpec((1, block_s, KV, dk), lambda r, s: (r, s, 0, 0)),
-                pl.BlockSpec((1, C, block_s), lambda r, s: (r, 0, s)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, C, KV, G, dk), lambda r, s: (r, 0, 0, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((C, KV, G, dk), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-                pltpu.VMEM((C, KV, G), jnp.float32),
-            ],
-        ),
-        name=f"ff_verify_attention_c{C}",
-        interpret=_interpret(),
-    )(qg, k_cache, v_cache, mask)
-    return out.reshape(R, C, H, dk)
 
 
 # ---------------------------------------------------------------------------
@@ -347,8 +113,7 @@ def verify_attention(
 # the same causal-by-position contract: a query token attends every
 # cache line whose position is <= its own, never the scratch line, so
 # one static-shape program serves ragged rows (padding columns sit at
-# the scratch position and are masked out of nothing real). These were
-# previously duplicated across the model-family modules.
+# the scratch position and are masked out of nothing real).
 
 
 def causal_serve_mask(positions: jnp.ndarray, S1: int) -> jnp.ndarray:
@@ -525,7 +290,7 @@ def ragged_paged_attention_xla(
 
 def _rope_rotate(x, cos, sin):
     """Rotate-half RoPE on the trailing head dim, op-for-op the XLA
-    ``apply_rope`` (models/llama.py, models/transformer.py) so the
+    ``apply_rope`` (models/transformer.py) so the
     in-kernel prologue stays bitwise-identical to the unfused path.
     ``cos``/``sin`` arrive pre-broadcast against ``x``; partial rotary
     widths (``cos.shape[-1] < head_dim``, Phi-style) pass the tail of

@@ -59,7 +59,7 @@ Physical page ``num_pages`` (one past the pool) is the shared
 **scratch page**: unallocated table entries point at it, so padding
 tokens' K/V writes and gathers through unallocated entries land on a
 real buffer that no mask ever exposes (the paged analog of the dense
-layout's per-slot scratch row, models/llama.py init_kv_cache).
+layout's per-slot scratch row, models/transformer.py init_kv_cache).
 
 Context parallelism (``ServingConfig.kv_shard="context"``): with
 ``cp_shards`` > 1 the pool is partitioned into per-shard slices —

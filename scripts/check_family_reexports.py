@@ -1,17 +1,15 @@
 #!/usr/bin/env python
 """Guard: every model-family module re-exports the full serve API.
 
-The generic-decoder families (falcon, gemma, gpt2, mistral, mixtral,
-mpt, opt, phi, qwen2, qwen2_moe, starcoder) implement nothing serving-
-specific themselves — they re-export ``models/transformer.py``'s
+The generic-decoder families (falcon, gemma, gpt2, llama, mistral,
+mixtral, mpt, opt, phi, qwen2, qwen2_moe, starcoder) implement nothing
+serving-specific themselves — they re-export ``models/transformer.py``'s
 serving protocol so the InferenceEngine can treat any family module
-uniformly (``engine.model.serve_step_paged`` etc.), and ``models/
-llama.py`` implements the same surface natively. That re-export list is
-copy-pasted per family and silently rots: a new serve symbol (e.g.
+uniformly (``engine.model.serve_step_paged`` etc.). That re-export list
+is copy-pasted per family and silently rots: a new serve symbol (e.g.
 ``copy_page_kv``, added for prefix-cache copy-on-write) lands in
-transformer.py and llama.py, and any family module that misses it keeps
-importing fine until an engine feature hits the missing attribute at
-runtime.
+transformer.py, and any family module that misses it keeps importing
+fine until an engine feature hits the missing attribute at runtime.
 
 This script asserts the full surface on every family module. It is
 importable (``check()`` returns {module: [missing symbols]}) and wired
@@ -78,8 +76,8 @@ SERVE_API = (
     "param_pspecs",
 )
 
-# Every family module the zoo serves (llama implements the surface
-# natively; the rest re-export models/transformer.py).
+# Every family module the zoo serves by re-exporting
+# models/transformer.py.
 FAMILIES = (
     "falcon",
     "gemma",

@@ -3,6 +3,7 @@ paths (DP/TP/PP/SP meshes) compile and run without TPU hardware — the
 analog of the reference's single-box multinode emulation
 (reference ``tests/multinode_helpers/mpi_wrapper2.sh`` slices
 CUDA_VISIBLE_DEVICES per MPI rank)."""
+import math
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -13,6 +14,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 # The test suite runs on the CPU (backends are not initialised yet at
 # conftest import time).
@@ -32,6 +34,49 @@ def pytest_addoption(parser):
         "--shard", default=None,
         help="deterministic test sharding as i/n (1-based)",
     )
+
+
+def _llama_recorded_params(key, cfg):
+    """The weights ``models/llama.init_params`` drew until ISSUE 49 (its
+    own 8-way key split, ``lm_head`` from ``fold_in(key, 99)``), under the
+    decoder's parameter names. Two assertions are exact on these weights
+    and not on the decoder's own draw from the same key, at the parent as
+    here (``CHANGES.md``, PR 49 has the witnesses): SpecInfer == incremental on an int4 pool
+    (tests/test_adaptive_spec.py) and the fused-RoPE step's bitwise
+    logits (tests/test_fused_decode.py). Those files keep the weights
+    their assertions were recorded on."""
+    from flexflow_tpu.models.transformer import seeded_normal
+
+    L, D, F = cfg.num_hidden_layers, cfg.hidden_size, cfg.intermediate_size
+    H, KV, dk = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    ks = jax.random.split(key, 8)
+    out = 0.02 / math.sqrt(2 * L)
+
+    def w(k, shape, std=0.02):
+        return seeded_normal(k, std, shape=shape, dtype=cfg.dtype)
+
+    ones = lambda shape: jax.numpy.ones(shape, cfg.dtype)
+    return {
+        "embed": w(ks[0], (cfg.vocab_size, D)),
+        "layers": {
+            "attn_norm_scale": ones((L, D)),
+            "wq": w(ks[1], (L, D, H * dk)),
+            "wk": w(ks[2], (L, D, KV * dk)),
+            "wv": w(ks[3], (L, D, KV * dk)),
+            "wo": w(ks[4], (L, H * dk, D), out),
+            "mlp_norm_scale": ones((L, D)),
+            "w_gate": w(ks[5], (L, D, F)),
+            "w_down": w(ks[6], (L, F, D), out),
+            "w_up": w(ks[7], (L, D, F)),
+        },
+        "final_norm_scale": ones((D,)),
+        "lm_head": w(jax.random.fold_in(key, 99), (D, cfg.vocab_size)),
+    }
+
+
+@pytest.fixture(scope="session")
+def llama_recorded_params():
+    return _llama_recorded_params
 
 
 def pytest_configure(config):

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from flexflow_tpu.models import mistral
+from flexflow_tpu.models import llama, mistral
 from flexflow_tpu.serve import kernels
 
 R, PAGE, PAGES_PER_SLOT = 16, 128, 16          # slots, tokens/page, NP
@@ -153,10 +153,10 @@ def test_sparse_paged_attention_with_query_lengths_compiles(chip):
     assert "%ff_sparse_paged_c128" in text
 
 
-def _step_args(sds, cfg, C, kv_quant=None):
+def _step_args(sds, cfg, C, kv_quant=None, family=mistral):
     params = _on(
         jax.eval_shape(
-            functools.partial(mistral.init_params, cfg=cfg),
+            functools.partial(family.init_params, cfg=cfg),
             jax.random.PRNGKey(0),
         ),
         sds,
@@ -164,7 +164,7 @@ def _step_args(sds, cfg, C, kv_quant=None):
     cache = _on(
         jax.eval_shape(
             functools.partial(
-                mistral.init_paged_kv_cache, cfg, NUM_PAGES, PAGE,
+                family.init_paged_kv_cache, cfg, NUM_PAGES, PAGE,
                 jnp.bfloat16, kv_quant=kv_quant,
             )
         ),
@@ -177,9 +177,9 @@ def _step_args(sds, cfg, C, kv_quant=None):
     )
 
 
-def _step(cfg, **kw):
+def _step(cfg, family=mistral, **kw):
     def step(params, cache, tokens, positions, logits_idx, page_table):
-        return mistral.serve_step_paged(
+        return family.serve_step_paged(
             params, cache, tokens, positions, logits_idx, None, None,
             page_table, cfg=cfg, cache_len=CACHE_LEN, **kw,
         )
@@ -239,6 +239,19 @@ def test_mistral_paged_pallas_step_compiles(chip, C):
     # weights + pool + temporaries of this cut fit one 16 GB chip
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    _assert_pool_in_place(compiled, text, args[1]["k"])
+    _assert_pool_carried(text, args[1]["k"])
+
+
+def test_llama_paged_pallas_decode_step_keeps_pool_in_place(chip):
+    """The llama family's step is the decoder's (ISSUE 49): at
+    ``llama_7b`` widths (32 K/V heads: four times Mistral's pool a
+    token) its compiled C=1 step holds the donated pool once."""
+    cfg = llama.LLaMAConfig.llama_7b(dtype=jnp.bfloat16, num_hidden_layers=2)
+    args = _step_args(chip, cfg, 1, family=llama)
+    compiled, text = _compile(
+        _step(cfg, family=llama, kernels="pallas"), *args, donate=(1,))
+    assert text.count("tpu_custom_call") == 1 and "%ff_ragged_paged_c1" in text
     _assert_pool_in_place(compiled, text, args[1]["k"])
     _assert_pool_carried(text, args[1]["k"])
 
@@ -399,37 +412,6 @@ def test_mistral_fused_rope_step_compiles(chip, C, kv_quant):
     if kv_quant:
         kw["kv_quant"] = kv_quant
     _, text = _compile(_step(cfg, **kw), *_step_args(chip, cfg, C, kv_quant))
-    assert "tpu_custom_call" in text
-
-
-def _dense_args(sds, cfg, S1=2049):
-    H, KV, dk = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                 cfg.head_dim)
-    cache = sds((R, S1, KV, dk), jnp.bfloat16)
-    return H, dk, cache
-
-
-def test_dense_decode_attention_compiles(chip):
-    """kernels="pallas" on the dense layout: the in-bounds mask is built
-    from an integer iota in its final shape (the i1 reshape was refused)."""
-    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
-    H, dk, cache = _dense_args(chip, cfg)
-    _, text = _compile(
-        kernels.decode_attention,
-        chip((R, H, dk), jnp.bfloat16), cache, cache, chip((R,), jnp.int32),
-    )
-    assert "tpu_custom_call" in text
-
-
-def test_dense_verify_attention_compiles(chip):
-    cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
-    H, dk, cache = _dense_args(chip, cfg)
-    C = 64  # max_spec_tree_tokens
-    _, text = _compile(
-        kernels.verify_attention,
-        chip((R, C, H, dk), jnp.bfloat16), cache, cache,
-        chip((R, C, cache.shape[1]), jnp.bool_),
-    )
     assert "tpu_custom_call" in text
 
 

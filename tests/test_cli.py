@@ -74,6 +74,12 @@ def test_cli_serve_refuses_a_fusion_that_is_gone():
     assert "unknown fused_decode entry" in r.stderr
 
 
+def test_cli_serve_refuses_pallas_on_the_dense_layout():
+    r = _run(["serve", "--pallas", "--max-new-tokens", "2"])
+    assert r.returncode == 2 and "Traceback" not in r.stderr
+    assert "--pallas requires --kv-layout paged" in r.stderr
+
+
 def test_cli_search_exports(tmp_path):
     dot = str(tmp_path / "strategy.dot")
     strat = str(tmp_path / "strategy.json")

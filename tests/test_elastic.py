@@ -106,6 +106,20 @@ def _outputs(cm, n_new=8, prompts=PROMPTS):
     ]
 
 
+_REFERENCE = {}
+
+
+def _reference(tiny, **kw):
+    """``_outputs`` of an undisturbed cluster at ``sc_kwargs(**kw)``,
+    computed once a configuration: greedy decoding is deterministic (what
+    the tests here assert bitwise), and every cluster built compiles its
+    replicas' step programs again (ROADMAP A13)."""
+    key = tuple(sorted((k, v) for k, v in kw.items() if v is not None))
+    if key not in _REFERENCE:
+        _REFERENCE[key] = _outputs(_cluster(tiny, **kw))
+    return _REFERENCE[key]
+
+
 def _finish(cm, cids, max_steps=4000):
     steps = 0
     while any(not cm._terminal(c) for c in cids):
@@ -237,8 +251,8 @@ def test_kill_restart_bitwise(tiny, tmp_path, kv_quant):
     cfg, params = tiny
     kw = sc_kwargs(replicas=2, router_policy="round_robin",
                    kv_quant=kv_quant)
-    ref = _outputs(ClusterManager.build(
-        llama, cfg, params, ServingConfig(**kw)))
+    ref = _reference(tiny, replicas=2, router_policy="round_robin",
+                     kv_quant=kv_quant)
 
     sc = ServingConfig(journal_dir=str(tmp_path), **kw)
     cm = ClusterManager.build(llama, cfg, params, sc)
@@ -279,8 +293,7 @@ def test_kill_restart_with_torn_tail(tiny, tmp_path):
     bitwise through recompute."""
     cfg, params = tiny
     kw = sc_kwargs(replicas=2, router_policy="round_robin")
-    ref = _outputs(ClusterManager.build(
-        llama, cfg, params, ServingConfig(**kw)))
+    ref = _reference(tiny, replicas=2, router_policy="round_robin")
     sc = ServingConfig(journal_dir=str(tmp_path), **kw)
     cm = ClusterManager.build(llama, cfg, params, sc)
     cids = [cm.submit(p, max_new_tokens=8) for p in PROMPTS]
@@ -336,8 +349,7 @@ def test_manager_crash_fault_kind(tiny, tmp_path):
     state survives) finishes the run bitwise."""
     cfg, params = tiny
     kw = sc_kwargs(replicas=2, router_policy="round_robin")
-    ref = _outputs(ClusterManager.build(
-        llama, cfg, params, ServingConfig(**kw)))
+    ref = _reference(tiny, replicas=2, router_policy="round_robin")
 
     sc = ServingConfig(journal_dir=str(tmp_path), **kw)
     cm = ClusterManager.build(llama, cfg, params, sc)
@@ -439,10 +451,7 @@ def test_scale_in_drains_clean(tiny, tmp_path):
                        **sc_kwargs(replicas=2,
                                    router_policy="round_robin"))
     cm = ClusterManager.build(llama, cfg, params, sc)
-    ref = _outputs(ClusterManager.build(
-        llama, cfg, params,
-        ServingConfig(**sc_kwargs(replicas=2,
-                                  router_policy="round_robin"))))
+    ref = _reference(tiny, replicas=2, router_policy="round_robin")
     cids = [cm.submit(p, max_new_tokens=8) for p in PROMPTS]
     on_one = [c for c in cids if cm.requests[c].replica == 1]
     assert on_one, "round robin should have placed work on replica 1"
@@ -523,12 +532,9 @@ def test_set_pools_under_traffic_bitwise(tiny, tmp_path):
     the new pools) — migrations prove the split went live."""
     cfg, params = tiny
     kw = sc_kwargs(replicas=2, router_policy="round_robin")
-    ref_mixed = _outputs(ClusterManager.build(
-        llama, cfg, params, ServingConfig(**kw)))
-    ref_disagg = _outputs(ClusterManager.build(
-        llama, cfg, params,
-        ServingConfig(**sc_kwargs(replicas=2, prefill_replicas=1,
-                                  decode_replicas=1))))
+    ref_mixed = _reference(tiny, replicas=2, router_policy="round_robin")
+    ref_disagg = _reference(tiny, replicas=2, prefill_replicas=1,
+                            decode_replicas=1)
 
     sc = ServingConfig(journal_dir=str(tmp_path), **kw)
     cm = ClusterManager.build(llama, cfg, params, sc)
@@ -574,8 +580,7 @@ def test_reconfigured_membership_survives_recovery(tiny, tmp_path):
     the in-flight requests finish bitwise."""
     cfg, params = tiny
     kw = sc_kwargs(replicas=1)
-    ref = _outputs(ClusterManager.build(
-        llama, cfg, params, ServingConfig(**kw)))
+    ref = _reference(tiny, replicas=1)
     sc = ServingConfig(journal_dir=str(tmp_path), **kw)
     cm = ClusterManager.build(llama, cfg, params, sc)
     cm.scale_out(warm=False)

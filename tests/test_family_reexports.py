@@ -1,7 +1,7 @@
 """Tier-1 wiring of scripts/check_family_reexports.py: the PR-1
 re-export pattern (family modules re-exporting models/transformer.py's
 serving protocol) has no compile-time guard — a serve symbol added to
-transformer.py/llama.py but missed in a family module only explodes
+transformer.py but missed in a family module only explodes
 when an engine feature touches it at runtime. This test rots loudly
 instead."""
 import importlib.util

@@ -382,7 +382,7 @@ def test_engine_nested_steps_are_traced():
 
 
 def test_model_serve_protocol_is_traced():
-    path = os.path.join(REPO, "flexflow_tpu", "models", "llama.py")
+    path = os.path.join(REPO, "flexflow_tpu", "models", "transformer.py")
     ctx = FileContext(path, open(path).read())
     traced_names = {fn.name for fn in ctx.traced}
     for name in ("serve_step", "serve_step_paged", "commit_kv_paged",

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from flexflow_tpu.core.mesh import set_mesh as _set_mesh
-from flexflow_tpu.models import llama
+from flexflow_tpu.models import llama, transformer
 from flexflow_tpu.ops.flash_attention import flash_attention
 
 
@@ -69,7 +69,7 @@ def test_flash_backward_matches_xla(S, causal):
 
 def test_flash_gqa_via_model_attn_fn():
     """make_flash_attention repeats the compact KV heads before the
-    kernel — must equal the XLA GQA path in llama.attention."""
+    kernel — must equal the decoder's grouped XLA attention."""
     cfg = llama.LLaMAConfig(
         vocab_size=64, hidden_size=64, intermediate_size=128,
         num_hidden_layers=1, num_attention_heads=4, num_key_value_heads=2,
@@ -82,7 +82,9 @@ def test_flash_gqa_via_model_attn_fn():
     v = jax.random.normal(ks[2], (B, S, 2, 16), jnp.float32)
     attn_fn = llama.make_flash_attention(block_q=16, block_k=16)
     got = attn_fn(cfg, q, k, v, None)
-    want = llama.attention(cfg, q, k, v, llama.causal_mask(S))
+    want = transformer._gqa_attend(
+        cfg, q, k, v, None, jnp.tril(jnp.ones((S, S), bool))
+    ).reshape(got.shape)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )

@@ -39,9 +39,9 @@ from flexflow_tpu.serve.sampling import choose_sample_mode, sample_tokens
 
 
 @pytest.fixture(scope="module")
-def tiny():
+def tiny(llama_recorded_params):
     cfg = llama.LLaMAConfig.tiny(dtype=jnp.float32)
-    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    params = llama_recorded_params(jax.random.PRNGKey(0), cfg)
     return cfg, params
 
 

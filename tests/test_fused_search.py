@@ -12,7 +12,7 @@ import pytest
 import flexflow_tpu as ff
 from flexflow_tpu.bench_search import build_searched_lm
 from flexflow_tpu.core.mesh import MachineSpec, set_mesh as _set_mesh
-from flexflow_tpu.models import llama
+from flexflow_tpu.models import llama, transformer
 from flexflow_tpu.optimizers import AdamOptimizer, SGDOptimizer
 from flexflow_tpu.search import CostModel, TPUChip, TPUTopology, optimize
 from flexflow_tpu.search.unity import memory_search
@@ -33,7 +33,7 @@ def _lm(num_devices=1, batch=B):
 
 
 def test_fused_stack_matches_llama_forward():
-    """The op must compute exactly what models/llama.py's scanned blocks
+    """The op must compute exactly what the decoder's scanned blocks
     compute (same weight layout, same RoPE/mask conventions)."""
     cfg = llama.LLaMAConfig(
         vocab_size=V, hidden_size=D, intermediate_size=F,
@@ -45,17 +45,17 @@ def test_fused_stack_matches_llama_forward():
 
     op = get_op("transformer_decoder_stack")
     attrs = dict(num_layers=L, num_heads=H, num_kv_heads=H,
-                 intermediate_size=F, eps=cfg.rms_norm_eps,
+                 intermediate_size=F, eps=cfg.norm_eps,
                  rope_theta=cfg.rope_theta, remat=False, attention="xla")
     from flexflow_tpu.ops.registry import OpContext
 
     (got,) = op.forward(params["layers"], [x], attrs, OpContext(training=False))
 
-    cos, sin = llama.rope_freqs(cfg, jnp.arange(S, dtype=jnp.int32))
-    mask = llama.causal_mask(S)
+    rope = transformer.rope_freqs(cfg, jnp.arange(S, dtype=jnp.int32))
+    mask = jnp.tril(jnp.ones((S, S), bool))
 
     def body(carry, p_l):
-        y, _ = llama.block(cfg, p_l, carry, cos, sin, mask)
+        y, _ = transformer.block(cfg, p_l, carry, rope, None, mask)
         return y, None
 
     want, _ = jax.lax.scan(body, x, params["layers"])

@@ -1,79 +1,14 @@
-"""Pallas serving-kernel tests (interpret mode on the CPU backend):
-decode and tree-verify attention must match the dense XLA reference —
-the TPU analog of the reference's op kernel tests (tests/ops/,
-SURVEY.md §4)."""
+"""Pallas serving-kernel tests (interpret mode on the CPU backend): the
+ragged paged kernel must match its XLA reference — the TPU analog of
+the reference's op kernel tests (tests/ops/, SURVEY.md §4)."""
 import functools
-import math
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from flexflow_tpu.serve.kernels import decode_attention, verify_attention
-
-R, S1, H, KV, dk = 4, 96, 8, 4, 16
-
-
-def _dense_decode(q, k, v, seq_lens):
-    G = H // KV
-    qg = q.reshape(R, KV, G, dk)
-    scores = jnp.einsum("rkgd,rskd->rkgs", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(dk)
-    valid = jnp.arange(S1)[None, :] < seq_lens[:, None]
-    scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("rkgs,rskd->rkgd", p, v.astype(jnp.float32))
-    return out.reshape(R, H, dk).astype(q.dtype)
-
-
-def test_decode_attention_matches_dense():
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.normal(size=(R, H, dk)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    seq_lens = jnp.asarray([1, 17, 64, 96], jnp.int32)
-    out = decode_attention(q, k, v, seq_lens, block_s=32)
-    ref = _dense_decode(q, k, v, seq_lens)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_decode_attention_zero_len_slot_is_finite():
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(R, H, dk)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    seq_lens = jnp.asarray([0, 5, 0, 10], jnp.int32)
-    out = decode_attention(q, k, v, seq_lens, block_s=32)
-    assert np.isfinite(np.asarray(out)).all()
-
-
-def test_verify_attention_matches_dense():
-    rng = np.random.default_rng(2)
-    C = 8
-    q = jnp.asarray(rng.normal(size=(R, C, H, dk)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(R, S1, KV, dk)), jnp.float32)
-    # random spec-tree-ish mask: committed prefix + random tree edges
-    mask = np.zeros((R, C, S1), bool)
-    for r in range(R):
-        pref = rng.integers(1, 40)
-        mask[r, :, :pref] = True
-        for c in range(C):
-            mask[r, c, pref + rng.integers(0, C)] = True
-    mask = jnp.asarray(mask)
-    out = verify_attention(q, k, v, mask, block_s=32)
-
-    G = H // KV
-    qg = q.reshape(R, C, KV, G, dk)
-    scores = jnp.einsum("rckgd,rskd->rckgs", qg, k).astype(jnp.float32)
-    scores = scores / math.sqrt(dk)
-    scores = jnp.where(mask[:, :, None, None, :], scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1)
-    ref = jnp.einsum("rckgs,rskd->rckgd", p, v.astype(jnp.float32)).reshape(
-        R, C, H, dk
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+H, KV, dk = 8, 4, 16
 
 
 def test_llama_generation_pallas_equals_xla():
@@ -92,7 +27,8 @@ def test_llama_generation_pallas_equals_xla():
         m = LLM(llama, cfg, params, tokenizer=None)
         m.compile(ServingConfig(max_requests_per_batch=2,
                                 max_sequence_length=64, prefill_chunk=4,
-                                cache_dtype=jnp.float32, kernels=kern))
+                                cache_dtype=jnp.float32, kv_layout="paged",
+                                page_size=8, kernels=kern))
         outs[kern] = [r.output_tokens for r in m.generate(prompts, max_new_tokens=6)]
     assert outs["xla"] == outs["pallas"], outs
 

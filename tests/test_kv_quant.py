@@ -119,12 +119,13 @@ class TestPoolAccounting:
         # int4 is live (PR 7): packed nibbles, two codes per byte
         spec4 = resolve_spec("int4")
         assert spec4.qmax == 7.0 and spec4.pack == 2
-        # packing needs an even head_dim — loud, at construction
+        # packing needs an even head_dim — loud, at construction (a
+        # head that rotates is even by DecoderConfig's own check)
         import dataclasses
 
         odd = dataclasses.replace(
             tiny[0], hidden_size=60, num_attention_heads=4,
-            num_key_value_heads=2,
+            num_key_value_heads=2, positions="learned",
         )
         assert odd.head_dim % 2 == 1
         with pytest.raises(ValueError, match="head_dim"):
@@ -319,7 +320,7 @@ def test_prefix_cache_hit_over_quantized_pages_is_bitwise(tiny):
 def test_specinfer_commit_over_quantized_pool(tiny):
     """Tree-verify writes quantize at slack lines; commit dequantizes
     the accepted lines at their source page scales and re-commits them
-    (models/llama.commit_kv_paged kv_quant path). Speculative decoding
+    (models/transformer.commit_kv_paged kv_quant path). Speculative decoding
     stays lossless against the SAME quantized engine's incremental
     decode on this model/seed, and both pools drain clean."""
     cfg, params = tiny
@@ -327,7 +328,7 @@ def test_specinfer_commit_over_quantized_pool(tiny):
     dparams = {
         "embed": params["embed"],
         "layers": {k: v[:1] for k, v in params["layers"].items()},
-        "final_norm": params["final_norm"],
+        "final_norm_scale": params["final_norm_scale"],
         "lm_head": params["lm_head"],
     }
     prompts = [[3, 17, 91, 42, 7], [9, 8, 7, 6, 5], [42] * 9]

@@ -38,7 +38,7 @@ def test_vs_hf_transformers():
         num_hidden_layers=CFG.num_hidden_layers,
         num_attention_heads=CFG.num_attention_heads,
         num_key_value_heads=CFG.num_key_value_heads,
-        rms_norm_eps=CFG.rms_norm_eps,
+        rms_norm_eps=CFG.norm_eps,
         rope_theta=CFG.rope_theta,
         max_position_embeddings=CFG.max_position_embeddings,
         attn_implementation="eager",
@@ -55,10 +55,10 @@ def test_vs_hf_transformers():
     L = CFG.num_hidden_layers
     params = {
         "embed": t2j("model.embed_tokens.weight"),
-        "final_norm": t2j("model.norm.weight"),
+        "final_norm_scale": t2j("model.norm.weight"),
         "lm_head": t2j("lm_head.weight").T,
         "layers": {
-            "attn_norm": jnp.stack(
+            "attn_norm_scale": jnp.stack(
                 [t2j(f"model.layers.{i}.input_layernorm.weight") for i in range(L)]
             ),
             "wq": jnp.stack(
@@ -73,19 +73,19 @@ def test_vs_hf_transformers():
             "wo": jnp.stack(
                 [t2j(f"model.layers.{i}.self_attn.o_proj.weight").T for i in range(L)]
             ),
-            "ffn_norm": jnp.stack(
+            "mlp_norm_scale": jnp.stack(
                 [
                     t2j(f"model.layers.{i}.post_attention_layernorm.weight")
                     for i in range(L)
                 ]
             ),
-            "w1": jnp.stack(
+            "w_gate": jnp.stack(
                 [t2j(f"model.layers.{i}.mlp.gate_proj.weight").T for i in range(L)]
             ),
-            "w2": jnp.stack(
+            "w_down": jnp.stack(
                 [t2j(f"model.layers.{i}.mlp.down_proj.weight").T for i in range(L)]
             ),
-            "w3": jnp.stack(
+            "w_up": jnp.stack(
                 [t2j(f"model.layers.{i}.mlp.up_proj.weight").T for i in range(L)]
             ),
         },

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.metrics import SchedulerStats
-from flexflow_tpu.models import llama, mistral, mixtral, transformer
+from flexflow_tpu.models import mistral, mixtral, transformer
 from flexflow_tpu.serve import InferenceEngine, RequestManager, ServingConfig
 from flexflow_tpu.serve.engine import pack_widths, program_name
 
@@ -364,14 +364,13 @@ def test_unpacked_callers_trace_the_padded_step(model, kernels, monkeypatch):
         assert ours == parents
         # (a routed expert layer's grouping has a cumsum of its own)
         assert "cumsum" not in ours or model[1].num_local_experts
-    # and the decode step, the dense layout and the llama twin have no ladder
+    # and the decode step, the dense layout and the fused prologue have no ladder
     assert eng.pack_ladder(1) == ()
     mod, cfg, params = model
     dense = InferenceEngine(mod, cfg, params, ServingConfig(
         max_requests_per_batch=R, max_sequence_length=56, prefill_chunk=C,
         max_spec_tree_tokens=8, cache_dtype=jnp.float32))
     assert dense.pack_ladder(C) == ()
-    assert not getattr(llama, "PACKED_STEP", False)
     fused = _engine(model, "pallas", fused_decode=("rope_kv_write",))
     assert fused.pack_ladder(C) == ()
 
@@ -454,13 +453,13 @@ def test_program_names_of_the_rungs(key, name):
 
 
 @pytest.mark.parametrize("family", [
-    "falcon", "gemma", "gpt2", "mistral", "mixtral", "mpt", "opt", "phi",
-    "qwen2", "qwen2_moe", "starcoder"])
+    "falcon", "gemma", "gpt2", "llama", "mistral", "mixtral", "mpt", "opt",
+    "phi", "qwen2", "qwen2_moe", "starcoder"])
 def test_generic_decoder_families_declare_the_packed_step(family):
     """A family that re-exports the generic decoder's step re-exports
     its declaration too, or it would be served the padded program in
-    silence; the llama twin and MiniCPM-SALA have steps of their own
-    that take no packed axis and declare nothing."""
+    silence; MiniCPM-SALA has a step of its own that takes no packed
+    axis and declares nothing."""
     import importlib
 
     from flexflow_tpu.models import minicpm_sala

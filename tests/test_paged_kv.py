@@ -342,7 +342,7 @@ class TestPagedDenseParity:
         dparams = {
             "embed": params["embed"],
             "layers": {k: v[:1] for k, v in params["layers"].items()},
-            "final_norm": params["final_norm"],
+            "final_norm_scale": params["final_norm_scale"],
             "lm_head": params["lm_head"],
         }
         prompts = [[3, 17, 91, 42, 7], [9, 8, 7, 6, 5], [42] * 9]

@@ -144,9 +144,13 @@ def test_churn_one_compile_per_step_key(tiny, kv_layout):
         assert counts.get("copy_page") == 1, counts
         # quantizing the pool adds NO step programs: the quant write and
         # in-kernel dequant live inside the same jitted steps, so the
-        # step-key set is identical with kv_quant on and off
+        # step-key set is identical with kv_quant on and off: the padded
+        # mixed step, every packed rung of its ladder, the decode step
+        ladder = eng.pack_ladder(C)
+        assert ladder, "the paged mixed step has packed rungs"
         assert set(counts) == {
             ("mixed_fused", C, False, "greedy", 0),
+            *(("mixed_packed", C, w, "greedy", 0) for w in ladder),
             ("mixed_fused", 1, False, "greedy", 0), "copy_page",
         }, counts
     # compile telemetry mirrored into the scheduler stats

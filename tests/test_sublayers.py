@@ -17,6 +17,7 @@ from flexflow_tpu.models import (
     deepseek_v3,
     granite_hybrid,
     lfm2_moe,
+    llama,
     minicpm_sala,
     mistral,
     mixtral,
@@ -38,6 +39,7 @@ ALWAYS = ATTENTION | {"ff.ffn", "ff.head", "ff.glue"}
 # family -> (module, the sublayers its paged step has)
 FAMILIES = {
     "dense": (mistral, ALWAYS),
+    "llama": (llama, ALWAYS),
     "routed": (mixtral, ALWAYS | {"ff.moe.route"}),
     "minicpm_sala": (minicpm_sala, ALWAYS | {"ff.mixer", "ff.attn.select"}),
     "lfm2_moe": (lfm2_moe, ALWAYS | {"ff.mixer", "ff.moe.route"}),

@@ -333,6 +333,21 @@ def _cluster(tiny, transport, **kw):
     return ClusterManager.build(llama, cfg, params, sc)
 
 
+_REFERENCE = {}
+
+
+def _reference(tiny, transport, **kw):
+    """``_outputs`` of a fault-free cluster, computed once a
+    configuration: greedy decoding is deterministic (what the tests here
+    assert bitwise), and every cluster built compiles its replicas' step
+    programs again (ROADMAP A13)."""
+    key = (transport,) + tuple(
+        sorted((k, v) for k, v in kw.items() if v is not None))
+    if key not in _REFERENCE:
+        _REFERENCE[key] = _outputs(_cluster(tiny, transport, **kw))
+    return _REFERENCE[key]
+
+
 @pytest.mark.parametrize("kv_quant", [
     None,
     pytest.param("int8", marks=pytest.mark.slow),
@@ -340,7 +355,7 @@ def _cluster(tiny, transport, **kw):
 ])
 def test_loopback_cluster_bitwise_inproc(tiny, kv_quant):
     kw = dict(replicas=2, router_policy="round_robin", kv_quant=kv_quant)
-    ref = _outputs(_cluster(tiny, "inproc", **kw))
+    ref = _reference(tiny, "inproc", **kw)
     cm = _cluster(tiny, "loopback", **kw)
     got = _outputs(cm)
     assert got == ref, "loopback-transported cluster diverged bitwise"
@@ -372,7 +387,7 @@ def test_loopback_disaggregated_migration_bitwise(tiny, kv_quant):
     PR-8 proved bitwise the single replica)."""
     kw = dict(replicas=2, prefill_replicas=1, decode_replicas=1,
               kv_quant=kv_quant)
-    ref = _outputs(_cluster(tiny, "inproc", **kw))
+    ref = _reference(tiny, "inproc", **kw)
     cm = _cluster(tiny, "loopback", **kw)
     got = _outputs(cm)
     assert got == ref
@@ -403,8 +418,8 @@ def test_drop_fault_absorbed_by_retries(tiny):
     the retry machinery: zero health observations, zero rpc_errors,
     outputs bitwise — the retries are visible in ClusterStats and
     mirrored per-request into ProfileInfo.transport_retries."""
-    ref = _outputs(_cluster(tiny, "loopback", replicas=2,
-                            router_policy="round_robin"))
+    ref = _reference(tiny, "loopback", replicas=2,
+                     router_policy="round_robin")
     cm = _cluster(tiny, "loopback", replicas=2,
                   router_policy="round_robin")
     cm.attach_faults(FaultPlan([
@@ -433,8 +448,8 @@ def test_partition_trips_breaker_failover_bitwise(tiny):
     machine circuit-breaks it, and its requests fail over through
     recompute — greedy outputs bitwise the fault-free run (the PR-9
     contract, now over the wire)."""
-    ref = _outputs(_cluster(tiny, "loopback", replicas=2,
-                            router_policy="round_robin"))
+    ref = _reference(tiny, "loopback", replicas=2,
+                     router_policy="round_robin")
     cm = _cluster(tiny, "loopback", replicas=2,
                   router_policy="round_robin")
     cm.attach_faults(FaultPlan([
@@ -453,8 +468,8 @@ def test_delay_fault_over_deadline_degrades_like_a_stall(tiny):
     """An injected link delay at/over rpc_deadline_s fails every
     attempt (DeadlineExceeded) — the replica degrades exactly like a
     stalled one: breaker trips, requests fail over, outputs bitwise."""
-    ref = _outputs(_cluster(tiny, "loopback", replicas=2,
-                            router_policy="round_robin"))
+    ref = _reference(tiny, "loopback", replicas=2,
+                     router_policy="round_robin")
     cm = _cluster(tiny, "loopback", replicas=2,
                   router_policy="round_robin", rpc_deadline_s=1.0)
     cm.attach_faults(FaultPlan([
@@ -467,8 +482,8 @@ def test_delay_fault_over_deadline_degrades_like_a_stall(tiny):
 
 
 def test_disconnect_reconnects_without_health_impact(tiny):
-    ref = _outputs(_cluster(tiny, "loopback", replicas=2,
-                            router_policy="round_robin"))
+    ref = _reference(tiny, "loopback", replicas=2,
+                     router_policy="round_robin")
     cm = _cluster(tiny, "loopback", replicas=2,
                   router_policy="round_robin")
     cm.attach_faults(FaultPlan([Fault("disconnect", replica=0, step=3)]))
@@ -544,7 +559,7 @@ def test_transport_chaos_seeded_terminal_bitwise(tiny):
     the fault-free run, and the same plan fires the same sequence."""
     kw = dict(replicas=3, router_policy="round_robin",
               failover_retries=3)
-    ref = _outputs(_cluster(tiny, "loopback", **kw))
+    ref = _reference(tiny, "loopback", **kw)
     plan_json = FaultPlan([
         Fault("partition", replica=1, step=2, count=1000),
         Fault("delay", replica=0, step=3, count=3, seconds=0.25),
