@@ -196,6 +196,17 @@ class SchedulerStats:
     # so took the argmax head (serve/sampling.choose_sample_mode).
     head_steps: int = 0
     head_greedy_steps: int = 0
+    # An engine whose family declares classes of page
+    # (serve/paging.PageClasses), read where a step's pages are
+    # reserved: the pages the window classes freed behind their windows
+    # (a counter), the most pages each class held at such a boundary,
+    # by class and over the window classes together, and the most the
+    # window classes WOULD have held there with nothing freed. The
+    # dict is replaced, never updated in place.
+    window_pages_freed: int = 0
+    window_pages_live_peak: int = 0
+    window_pages_unfreed_peak: int = 0
+    pages_live_peak: Dict[str, int] = dataclasses.field(default_factory=dict)
     steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
 
     def record_step(
@@ -271,6 +282,21 @@ class SchedulerStats:
         self.attn_steps_grid += count.size * num_pages
         self.attn_steps_live += int(live.sum())
         self.attn_steps_narrow += int(live[count <= narrow].sum())
+
+    def note_page_classes(self, classes) -> None:
+        """Read the allocators of an engine's page classes (name ->
+        ``PageAllocator``) at a step boundary, after the step's pages
+        were reserved."""
+        peak = self.pages_live_peak
+        self.pages_live_peak = {
+            name: max(peak.get(name, 0), a.used_pages)
+            for name, a in classes.items()}
+        windowed = [a for a in classes.values() if a.window is not None]
+        self.window_pages_live_peak = max(
+            self.window_pages_live_peak, sum(a.used_pages for a in windowed))
+        self.window_pages_unfreed_peak = max(
+            self.window_pages_unfreed_peak,
+            sum(a.untrimmed_pages for a in windowed))
 
     def note_step_tokens(self, real: int, width: int) -> None:
         """Count one mixed step's token axis: the ``real`` tokens it
@@ -367,6 +393,10 @@ class SchedulerStats:
             "head_steps": self.head_steps,
             "head_greedy_steps": self.head_greedy_steps,
             "steps_by_width": dict(sorted(self.steps_by_width.items())),
+            "window_pages_freed": self.window_pages_freed,
+            "window_pages_live_peak": self.window_pages_live_peak,
+            "window_pages_unfreed_peak": self.window_pages_unfreed_peak,
+            "pages_live_peak": dict(self.pages_live_peak),
         }
 
     def report(self) -> str:

@@ -96,9 +96,12 @@ class DecoderConfig:
     moe_norm_topk: bool = True
     # Sliding-window attention (Mistral-style): w > 0 lets a query at
     # position q attend only keys in (q-w, q]. 0 = full causal. The
-    # serving KV cache keeps its full-length layout (lines beyond the
-    # window are masked, not evicted) — correctness first; a rolling
-    # cache is a memory optimization the reference also lacks.
+    # generic decoder's serving cache keeps its full-length layout: it
+    # MASKS the lines beyond the window and does not free them, one
+    # window for every layer. A family whose layers differ declares a
+    # class of page with a window (``page_classes``,
+    # models/smallthinker.py; serve/paging.PageClasses) and has the
+    # pages behind it freed.
     sliding_window: int = 0
     # Gemma-style knobs: a head_dim decoupled from hidden/heads (0 =
     # derived — kept as an OVERRIDE field, not resolved at construction,
@@ -609,7 +612,7 @@ def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
 @sublayer("moe.route")
 def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
                        experts_held: Tuple[int, int], layer=None,
-                       kernels: str = "xla"):
+                       kernels: str = "xla", activation: str = "silu"):
     """The routed half of a sparse FFN as a GROUPED matmul: the (token,
     expert) pairs of real tokens sorted by expert, one grouped matmul a
     projection over the groups, the pairs' results weighted and summed
@@ -643,7 +646,9 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     by an offset on the tiles' expert index, the XLA path by giving
     every other layer's experts an empty group): a layer sliced out of
     the stack would be a copy of its weights a step, since no slice
-    fuses into a kernel call.
+    fuses into a kernel call. ``activation`` (static): the gate's, a
+    name of ``jax.nn``: ``silu`` where nothing is said, ``relu`` for
+    ReGLU experts.
 
     Returns (out (T, D) in h's dtype, counts (hi - lo,) int32: the real
     tokens each held expert was given)."""
@@ -683,7 +688,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
             jnp.where(tile < n_active, tile_group, last), n - 1)
         with sublayer("ffn"):
             act = _pk.grouped_glu(rows, w_gate, w_up, tile_group, n_active,
-                                  tm=tm)
+                                  tm=tm, activation=activation)
             out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm)
         place = jnp.zeros((P,), jnp.int32).at[order].set(at.astype(jnp.int32))
         out = jnp.take(out, place, axis=0, mode="clip")
@@ -696,7 +701,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
         dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
                                 preferred_element_type=jnp.float32)
         with sublayer("ffn"):
-            act = (jax.nn.silu(dot(rows, w_gate))
+            act = (getattr(jax.nn, activation)(dot(rows, w_gate))
                    * dot(rows, w_up)).astype(h.dtype)
             out = dot(act, w_down)                           # (P, D) float32
         place = jnp.zeros((P,), jnp.int32).at[order].set(

@@ -116,10 +116,12 @@ SCHED_COUNTERS = frozenset({
     "step_tokens_real", "step_tokens_width",
     "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max",
     "latent_lines", "recurrent_updates", "head_steps", "head_greedy_steps",
+    "window_pages_freed",
 })
 #: SchedulerStats fields exported verbatim as gauges.
 SCHED_GAUGES = frozenset({
     "host_bytes", "cp_shards", "shard_balance", "slot_state_bytes",
+    "window_pages_live_peak", "window_pages_unfreed_peak",
 })
 #: SchedulerStats fields NOT exported verbatim — each maps to the
 #: derived snapshot() gauge that replaces it on the scrape surface.
@@ -129,6 +131,9 @@ SCHED_EXCLUDED = {
     # a by-width dict; the scrape surface carries the two counters it
     # sums to and their ratio
     "steps_by_width": "pack_fill",
+    # a by-class dict; the scrape surface carries the window classes'
+    # peak, which is what a window frees against
+    "pages_live_peak": "window_pages_live_peak",
 }
 #: Derived snapshot() rates exported as gauges alongside the counters.
 SCHED_DERIVED = (

@@ -69,6 +69,9 @@ STEP_SPANS = (
 )
 # profiler names, built once: a span site builds no string per call
 _ANNOTATION_NAMES = {name: "ff." + name for name in STEP_SPANS}
+# inside ``step.reserve``, and only where the engine's pager has a class
+# of page with a window: the host's freeing behind it
+_ANNOTATION_NAMES["step.trim"] = "ff.step.trim"
 
 
 def _annotation(name: str) -> jax.profiler.TraceAnnotation:

@@ -1028,12 +1028,22 @@ class InferenceEngine:
                     # pool rows (num_pages + scratch) shard over data —
                     # round up so the leading dim divides evenly
                     num_pages += (-(num_pages + 1)) % data
-            self.pager = PageAllocator(
-                num_pages, sc.pages_per_slot, sc.max_requests_per_batch,
-                sc.page_size, cp_shards=self.cp_shards,
-            )
-            self._table_cache = None  # fresh pager → stale device copy
             init_kw = dict(kv_quant=sc.kv_quant)
+            classes = self._page_classes()
+            if classes is None:
+                self.pager = PageAllocator(
+                    num_pages, sc.pages_per_slot, sc.max_requests_per_batch,
+                    sc.page_size, cp_shards=self.cp_shards,
+                )
+            else:
+                self.pager = self._class_pagers(classes, num_pages)
+                # a pool a class: the family sizes each by ``class_pages``
+                # (the positional count is its first class's)
+                num_pages = next(iter(self.pager.classes.values())).num_pages
+                init_kw["class_pages"] = {
+                    name: a.num_pages
+                    for name, a in self.pager.classes.items()}
+            self._table_cache = None  # fresh pager → stale device copy
             if extra_rows:
                 init_kw["extra_rows"] = extra_rows
             if getattr(self.model, "SLOT_STATE", ()):
@@ -1074,18 +1084,63 @@ class InferenceEngine:
                 return jax.jit(init, out_shardings=shardings)()
             return init()
 
+    def _page_classes(self):
+        """The family's classes of page (``page_classes(cfg)`` beside
+        its ``PAGE_POOLS``): name -> (the class's pools, its window or
+        None); None where it declares none, and everything is as it
+        was: one allocator, one table, one class of page."""
+        declared = getattr(self.model, "page_classes", None)
+        return None if declared is None else declared(self.cfg)
+
+    def _class_pagers(self, classes, num_pages: int):
+        """One allocator a class (serve/paging.PageClasses). A class
+        with a window takes its worst case, a rolling table a slot
+        (``window_table_pages``: no request can hold more), and the
+        others share what is left of ``num_pages``, the whole budget
+        counted in pages of any class, never under one slot's whole
+        context. A class whose window is no shorter than the context
+        keeps every page like the others."""
+        from .paging import PageAllocator, PageClasses, window_table_pages
+
+        sc = self.serving
+        slots, ps = sc.max_requests_per_batch, sc.page_size
+        step_lines = max(sc.mixed_chunk, sc.prefill_chunk)
+        rolling = {}
+        for name, (_, window) in classes.items():
+            if window is not None:
+                per = window_table_pages(window, step_lines, ps)
+                if per < sc.pages_per_slot:
+                    rolling[name] = per
+        whole = [name for name in classes if name not in rolling]
+        left = num_pages - slots * sum(rolling.values())
+        if sc.max_cached_tokens is None:
+            left = slots * sc.pages_per_slot * len(whole)
+        pagers = {}
+        for name, (_, window) in classes.items():
+            if name in rolling:
+                pagers[name] = PageAllocator(
+                    slots * rolling[name], rolling[name], slots, ps,
+                    window=window, step_lines=step_lines)
+            else:
+                pagers[name] = PageAllocator(
+                    max(left // len(whole), sc.pages_per_slot),
+                    sc.pages_per_slot, slots, ps)
+        return PageClasses(pagers)
+
     # ------------------------------------------------------------------
     # paged-layout accounting (bench + tests)
 
-    def page_table_device(self) -> jnp.ndarray:
+    def page_table_device(self):
         """The engine's own page table as a device array — every step's
-        read-only gather/scatter indices. Cached against the allocator's
-        version counter: steady-state decode (no admissions, no page
-        growth) re-ships nothing."""
+        read-only gather/scatter indices (with several classes of page
+        a dict of them, ``PageClasses.tables``). Cached against the
+        allocator's version counter: steady-state decode (no
+        admissions, no page growth) re-ships nothing."""
         cached = getattr(self, "_table_cache", None)
         if cached is not None and cached[0] == self.pager.version:
             return cached[1]
-        dev = jnp.asarray(self.pager.table, dtype=jnp.int32)
+        dev = jax.tree.map(lambda t: jnp.asarray(t, dtype=jnp.int32),
+                           self.pager.tables())
         self._table_cache = (self.pager.version, dev)
         return dev
 
@@ -1101,8 +1156,14 @@ class InferenceEngine:
         one compressed line and no K/V heads) — quantized pools
         amortize their per-page f32 scale rows into the per-line
         figure, so the metric stays an honest HBM cost."""
-        names = getattr(self.model, "PAGE_POOLS",
-                        ("k", "v", "k_scale", "v_scale"))
+        classes = self._page_classes() if self.paged else None
+        if classes is not None:  # a line is held once in every class
+            return sum(self._class_bytes_per_line(pools)
+                       for pools, _ in classes.values())
+        return self._class_bytes_per_line(getattr(
+            self.model, "PAGE_POOLS", ("k", "v", "k_scale", "v_scale")))
+
+    def _class_bytes_per_line(self, names) -> float:
         pools = [self.cache[name] for name in names if name in self.cache]
         # slots×(len+1) or pages×page_size
         lines = pools[0].shape[1] * pools[0].shape[2]
@@ -1122,10 +1183,15 @@ class InferenceEngine:
         state counts whole: it is held at any length."""
         if not self.paged:
             return self.kv_cache_bytes()
-        return int(
-            self.pager.used_pages * self.serving.page_size
-            * self.kv_bytes_per_line()
-        ) + self.slot_state_bytes()
+        classes = self._page_classes()
+        if classes is not None:
+            used = sum(
+                self.pager.classes[name].used_pages
+                * self._class_bytes_per_line(pools)
+                for name, (pools, _) in classes.items())
+        else:
+            used = self.pager.used_pages * self.kv_bytes_per_line()
+        return int(used * self.serving.page_size) + self.slot_state_bytes()
 
     @property
     def scratch_pos(self) -> int:

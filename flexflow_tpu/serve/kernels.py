@@ -689,6 +689,7 @@ def ragged_paged_attention(
     v_scale: Optional[jnp.ndarray] = None,
     row_offset=None,          # int32 scalar: pool row of table entry 0
     q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
+    tag: str = "",            # a suffix of the kernel's name in a trace
 ) -> jnp.ndarray:
     """:func:`_ragged_paged_attention` placed on the ambient mesh. The
     compiler cannot partition a Mosaic kernel ("wrap the call in a
@@ -705,7 +706,7 @@ def ragged_paged_attention(
     def body(q, k_pool, v_pool, page_table, mask, *rest):
         return _ragged_paged_attention(
             q, k_pool, v_pool, page_table, mask,
-            scale=scale, **dict(zip(optional, rest)),
+            scale=scale, tag=tag, **dict(zip(optional, rest)),
         )
 
     heads = P(None, None, MODEL_AXIS, None)
@@ -747,6 +748,7 @@ def _ragged_paged_attention(
     row_offset=None,          # int32 scalar: pool row of table entry 0
     group_mask: bool = False,  # mask is (R, KV, C, NP*ps): one a KV group
     q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
+    tag: str = "",
 ) -> jnp.ndarray:
     """Fused ragged paged attention: grid (request, logical page); the
     K/V BlockSpec index maps read the scalar-prefetched page table so
@@ -783,7 +785,14 @@ def _ragged_paged_attention(
     :func:`narrow_query_extent` real queries is computed at that extent,
     and padding columns come out zero. A real query's result is the
     same, to the bit at the chunk's extent. ``None``: every column
-    counts, the kernel as it was."""
+    counts, the kernel as it was.
+
+    The table need not start at a request's first line: a layer with a
+    sliding window (models/smallthinker.py) hands in the table of its
+    own class of page, the live pages in order, and a ``mask`` made
+    from those pages' true positions, and names its calls apart with
+    ``tag`` (``ff_ragged_paged_c<C>_win``): the same body over the 34
+    pages that a query may see, not over the context's 128."""
     R, C, H, dk = q.shape
     merged = k_pool.ndim == 3  # (P+1, ps, KV*dk): heads on the minor axis
     if merged:
@@ -894,7 +903,7 @@ def _ragged_paged_attention(
             vmem_limit_bytes=vmem(KVb),
         ),
         name=("ff_sparse_paged" if group_mask else "ff_ragged_paged")
-             + f"_c{C}" + _quant_suffix(k_scale is not None, pack),
+             + f"_c{C}" + _quant_suffix(k_scale is not None, pack) + tag,
         interpret=_interpret(),
     )(*prefetch, *operands)
     return out.reshape(R, C, H, dk)
@@ -1392,8 +1401,12 @@ def grouped_block(width: int, depth: int, weights: int, itemsize: int) -> int:
     weight blocks, double-buffered, stay within 32 MB of the 48 MB the
     calls state (:func:`_grouped_call`; the rows' and the result's
     blocks take the rest). LFM2's (2048, 1536) stays at 512; Mixtral's
-    up-projections (4096 -> 14336) take 1024, 14 column blocks."""
+    up-projections (4096 -> 14336) take 1024, 14 column blocks; a width
+    that 512 does not divide takes the widest multiple of a lane tile
+    under it that does (SmallThinker's 768: 384, two column blocks)."""
     block = min(512, width)
+    while width % block and block > 128:
+        block -= 128
     while (width // block > 16 and width % (2 * block) == 0
            and 2 * weights * depth * 2 * block * itemsize <= 32 << 20):
         block *= 2
@@ -1417,9 +1430,13 @@ def _grouped_call(kernel, name, tile_group, n_active, operands, in_specs,
       *operands)
 
 
-def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int):
-    """``silu(rows W_gate[g]) * (rows W_up[g])`` tile by tile, ``g`` the
-    expert of the tile. rows (P, D), P a multiple of ``tm``;
+def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
+                activation: str = "silu"):
+    """``act(rows W_gate[g]) * (rows W_up[g])`` tile by tile, ``g`` the
+    expert of the tile and ``act`` the static ``activation``, a name of
+    ``jax.nn`` (``silu`` where nothing is said; ``relu``: ReGLU,
+    models/smallthinker.py).
+    rows (P, D), P a multiple of ``tm``;
     ``w_gate`` / ``w_up`` (G, D, F); ``tile_group`` (P / tm,) the index
     into G of each tile's expert, ``n_active`` the tiles that hold a
     row: the others are skipped (their output rows are left as they
@@ -1433,6 +1450,7 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int):
     F = w_gate.shape[-1]
     tf = grouped_block(F, D, 2, w_gate.dtype.itemsize)
     assert P % tm == 0 and F % tf == 0, (P, tm, F, tf)
+    act = getattr(jax.nn, activation)
 
     def kernel(tg_ref, na_ref, x_ref, wg_ref, wu_ref, o_ref):
         @pl.when(pl.program_id(1) < na_ref[0])
@@ -1440,7 +1458,7 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int):
             x = x_ref[...]
             g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
             u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-            o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
+            o_ref[...] = (act(g) * u).astype(o_ref.dtype)
 
     weights = pl.BlockSpec((1, D, tf), lambda j, t, tg, na: (tg[t], 0, j))
     return _grouped_call(

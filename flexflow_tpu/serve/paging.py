@@ -75,12 +75,37 @@ page's owning shard, and ``check_no_leaks`` additionally audits the
 striping invariant (every mapped logical page lives on its owning
 shard) and the per-shard free-list partition. ``cp_shards=1``
 (default) is byte-for-byte the single-pool allocator.
+
+Page CLASSES (``PageClasses``): a family whose layers do not all keep
+the same lines declares more than one class of page (``page_classes``
+beside its ``PAGE_POOLS``; models/smallthinker.py: full-attention
+layers keep a request's every line, sliding-window layers the newest
+``window`` of them). The engine then keeps, a class, a pool over that
+class's layers only, an allocator and a table. The allocator of a class
+with a ``window`` has one operation more, :meth:`PageAllocator.trim`: it
+frees a slot's pages whose every line lies behind the window of the
+slot's next query, and its table ROLLS: entry 0 is the slot's first
+live logical page (``first_page``), so a table of ``ceil((window +
+step_lines) / page_size) + 1`` entries serves any context length. A
+page is freed on the host for steps NOT YET DISPATCHED: a step in
+flight was handed its own copy of the table and the device runs steps
+in order, so a freed page is written again only after every step that
+may read it. One class is the allocator as it was.
 """
 from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
+
+
+def window_table_pages(window: int, step_lines: int, page_size: int) -> int:
+    """Entries of a slot's table in a class of page with a ``window``,
+    where a step covers at most ``step_lines`` new lines: the pages
+    from the one that holds the first query's oldest visible line to
+    the one that holds the step's last line, at their worst alignment
+    (4096 + 128 lines in pages of 128: 34)."""
+    return -(-(int(window) + max(int(step_lines), 2)) // int(page_size)) + 1
 
 
 class PageAllocator:
@@ -101,8 +126,24 @@ class PageAllocator:
         is a no-op.
     """
 
+    #: the allocators by class of an engine's pager: None, this is the
+    #: only one (:class:`PageClasses` has the dict)
+    classes = None
+
     def __init__(self, num_pages: int, pages_per_slot: int, num_slots: int,
-                 page_size: int, cp_shards: int = 1):
+                 page_size: int, cp_shards: int = 1,
+                 window: Optional[int] = None, step_lines: int = 0):
+        if window is not None:
+            if cp_shards != 1:
+                raise ValueError(
+                    "a class of page with a window is not striped over "
+                    "context shards")
+            if pages_per_slot < window_table_pages(window, step_lines,
+                                                   page_size):
+                raise ValueError(
+                    f"a window of {window} lines and steps of {step_lines} "
+                    f"need a table of {window_table_pages(window, step_lines, page_size)}"
+                    f" pages a slot (got {pages_per_slot})")
         if num_pages < pages_per_slot and cp_shards == 1:
             raise ValueError(
                 f"page pool ({num_pages} pages) smaller than one request's "
@@ -122,6 +163,13 @@ class PageAllocator:
         self.pages_per_slot = int(pages_per_slot)
         self.cp_shards = int(cp_shards)
         self.pages_per_shard = self.num_pages // self.cp_shards
+        # a class with a window (module docstring): lines a query sees,
+        # the most new lines a step covers, each slot's first live
+        # logical page (its table's entry 0), pages trimmed so far
+        self.window = None if window is None else int(window)
+        self.step_lines = int(step_lines)
+        self.first_page = np.zeros((num_slots,), np.int64)
+        self.trimmed = 0
         if cp_shards > 1 and -(-int(pages_per_slot) // cp_shards) > (
             self.pages_per_shard
         ):
@@ -214,6 +262,19 @@ class PageAllocator:
     def used_pages(self) -> int:
         return self.num_pages - self.free_pages
 
+    @property
+    def lines_capacity(self) -> int:
+        """The longest context an empty pool could hold in one slot: a
+        class with a window holds any length in its rolling table."""
+        if self.window is not None:
+            return np.iinfo(np.int32).max
+        return self.num_pages * self.page_size
+
+    def tables(self) -> np.ndarray:
+        """What a step is handed: the table (:meth:`PageClasses.tables`
+        hands a dict of them)."""
+        return self.table
+
     def slot_pages(self, slot: int) -> int:
         """Physical pages currently mapped by ``slot``'s table."""
         return int((self.table[slot] != self.scratch_page).sum())
@@ -284,6 +345,39 @@ class PageAllocator:
 
     # ------------------------------------------------------------------
 
+    def trim(self, slot: int, num_lines: int) -> int:
+        """A class with a window: release the logical pages of ``slot``
+        that no query of its next step sees, the step that covers the
+        lines up to ``num_lines``, and roll the table so that entry 0
+        is the first page kept. A step covers at most ``step_lines`` new
+        lines, so its first query is at or after ``num_lines -
+        step_lines``, and a query at ``i`` sees lines ``i - window + 1
+        .. i``: every page wholly before that goes. THE one rule:
+        :meth:`ensure` trims by it before it covers the lines, so the
+        scheduler (which trims all its slots first, under one span) and
+        a caller that only ensures free the same pages. Returns the
+        pages freed; never moves backwards, and a class without a
+        window frees nothing."""
+        if self.window is None:
+            return 0
+        first_query = int(num_lines) - self.step_lines
+        keep_from = max(0, first_query - self.window + 1) // self.page_size
+        drop = min(int(keep_from - self.first_page[slot]), self.pages_per_slot)
+        if drop <= 0:
+            return 0
+        row = self.table[slot]
+        freed = 0
+        for page in row[:drop]:
+            if int(page) != self.scratch_page:
+                freed += int(self.release_ref(int(page)))
+        row[:-drop] = row[drop:]
+        row[-drop:] = self.scratch_page
+        # pages behind the window that were never mapped roll by too
+        self.first_page[slot] = keep_from
+        self.trimmed += freed
+        self.version += 1
+        return freed
+
     def ensure(self, slot: int, num_lines: int) -> bool:
         """Grow ``slot``'s table to cover cache lines [0, num_lines).
 
@@ -294,8 +388,19 @@ class PageAllocator:
         (striped, ``shard_of_logical``). When the free lists cannot
         cover the growth even after ``reclaim_cb`` eviction, returns
         False with NOTHING allocated — the caller preempts a victim and
-        retries. Returns True once the lines are covered."""
+        retries. Returns True once the lines are covered.
+
+        A class with a window first trims (:meth:`trim`) behind the
+        window of the step these lines are for, and covers the lines
+        from its first live page on: a caller asks for ONE step's lines
+        at a time."""
         need = min(self.pages_for(num_lines), self.pages_per_slot)
+        if self.window is not None:
+            # what the trim leaves is the table's width at most
+            # (``window_table_pages``)
+            self.trim(slot, num_lines)
+            need = max(
+                self.pages_for(num_lines) - int(self.first_page[slot]), 0)
         row = self.table[slot]
         have = int((row[:need] != self.scratch_page).sum())
         if need - have <= 0:
@@ -392,9 +497,19 @@ class PageAllocator:
             freed += int(self.release_ref(page))
             row[j] = self.scratch_page
             changed = True
+        if self.first_page[slot]:
+            self.first_page[slot] = 0
+            changed = True
         if changed:
             self.version += 1
         return freed
+
+    @property
+    def untrimmed_pages(self) -> int:
+        """What the slots would hold had :meth:`trim` freed nothing:
+        the pages in use and, a slot, those its table has rolled past
+        (pages of this class are private to a slot)."""
+        return self.used_pages + int(self.first_page.sum())
 
     def check_no_leaks(
         self, external: Optional[Dict[int, int]] = None
@@ -443,3 +558,93 @@ class PageAllocator:
                 f"page {page}: refcount {rc} but "
                 f"{'on' if page in free else 'off'} the free list"
             )
+
+
+class PageClasses:
+    """The pager of an engine whose family declares several classes of
+    page (module docstring): one :class:`PageAllocator` a class, each
+    over its own pool, behind the operations the scheduler and the
+    benchmark's probe drive a single allocator with. A grant covers the
+    lines in EVERY class or the caller preempts and retries
+    (``ensure`` is idempotent on the classes that granted); a release
+    gives back every class's pages. Pages are counted over all classes
+    (``num_pages``, ``used_pages``): they differ in bytes by the layers
+    their pool holds (``InferenceEngine.kv_bytes_per_line``)."""
+
+    cp_shards = 1
+    reclaim_cb = None  # no prefix cache over several classes yet
+
+    def __init__(self, classes: Dict[str, PageAllocator]):
+        self.classes = dict(classes)
+        sizes = {a.page_size for a in self.classes.values()}
+        assert len(sizes) == 1, f"classes differ in page size: {sizes}"
+        (self.page_size,) = sizes
+
+    def _all(self):
+        return self.classes.values()
+
+    @property
+    def num_pages(self) -> int:
+        return sum(a.num_pages for a in self._all())
+
+    @property
+    def free_pages(self) -> int:
+        return sum(a.free_pages for a in self._all())
+
+    @property
+    def used_pages(self) -> int:
+        return sum(a.used_pages for a in self._all())
+
+    @property
+    def version(self) -> int:
+        return sum(a.version for a in self._all())
+
+    @property
+    def table(self) -> np.ndarray:
+        """The first class's table (telemetry: ``BatchConfig``)."""
+        return next(iter(self._all())).table
+
+    @property
+    def lines_capacity(self) -> int:
+        """The longest context an empty pool could hold: a class with
+        a window holds any length in one slot's table."""
+        return min(a.lines_capacity for a in self._all())
+
+    def tables(self) -> Dict[str, np.ndarray]:
+        """What a step is handed: each class's table under its name
+        and, for a class with a window, ``<name>_start`` (slots,): the
+        position of the first line of each slot's entry 0. COPIES: a
+        rolling table is shifted in place by the next trim, and a step
+        in flight keeps the table it was handed (a host-to-device
+        transfer may read, or on the CPU alias, the array it was given
+        after the call returns)."""
+        out = {}
+        for name, a in self.classes.items():
+            out[name] = a.table.copy()
+            if a.window is not None:
+                out[name + "_start"] = (
+                    a.first_page * a.page_size).astype(np.int32)
+        return out
+
+    def pages_for(self, num_lines: int) -> int:
+        return -(-int(num_lines) // self.page_size)
+
+    def slot_pages(self, slot: int) -> int:
+        return sum(a.slot_pages(slot) for a in self._all())
+
+    def shard_balance(self) -> float:
+        return 1.0
+
+    def ensure(self, slot: int, num_lines: int) -> bool:
+        return all(a.ensure(slot, num_lines) for a in self._all())
+
+    def trim(self, slot: int, num_lines: int) -> int:
+        return sum(a.trim(slot, num_lines) for a in self._all())
+
+    def release(self, slot: int) -> int:
+        return sum(a.release(slot) for a in self._all())
+
+    def check_no_leaks(self, external=None) -> None:
+        assert not external, "pages of several classes are never shared"
+        for a in self._all():
+            a.check_no_leaks()

@@ -514,6 +514,7 @@ class RequestManager:
         if not self._paged:
             return
         lines_fn = lines_fn or self._lines_needed
+        classes = self.engine.pager.classes
         with self.tracer.span("step.reserve"):
             while True:
                 active = sorted(
@@ -526,6 +527,18 @@ class RequestManager:
                     ),
                     key=lambda r: r.admit_seq,
                 )
+                if classes is not None:
+                    # give back, in every class of page with a window,
+                    # what this step's queries no longer see
+                    # (``PageAllocator.trim``: the rule ``ensure`` frees
+                    # by too), all slots first and under one span. Steps
+                    # in flight keep the tables they were handed, and
+                    # the device runs them before any step that writes
+                    # a freed page again.
+                    with self.tracer.span("step.trim"):
+                        self.stats.window_pages_freed += sum(
+                            self.engine.pager.trim(r.slot, lines_fn(r))
+                            for r in active)
                 for req in active:
                     if self._ensure_pages(req, lines_fn(req)):
                         continue
@@ -553,6 +566,8 @@ class RequestManager:
                     self._preempt(victims[-1])
                     break  # active set changed; re-derive
                 else:
+                    if classes is not None:
+                        self.stats.note_page_classes(classes)
                     return
 
     def _attach_paging_metadata(self, bc: BatchConfig):
@@ -611,7 +626,7 @@ class RequestManager:
                     f"{sc.max_cached_tokens})"
                 )
             for eng in self._engines():
-                cap = eng.pager.num_pages * eng.pager.page_size
+                cap = eng.pager.lines_capacity
                 if need > cap:
                     return (
                         f"prompt ({len(req.tokens)} tokens) exceeds the "
@@ -982,9 +997,16 @@ class RequestManager:
             from .kernels import narrow_query_extent  # Pallas: not at import
 
             sc = eng.serving
+            narrow = narrow_query_extent(chunk)
+            classes = eng.pager.classes
+            if classes is not None:  # one call of each class's layers
+                for a in classes.values():
+                    self.stats.note_attn_steps(
+                        first, count, sc.page_size, a.pages_per_slot,
+                        narrow, a.window or 0)
+                return
             self.stats.note_attn_steps(
-                first, count, sc.page_size, sc.pages_per_slot,
-                narrow_query_extent(chunk),
+                first, count, sc.page_size, sc.pages_per_slot, narrow,
                 getattr(eng.cfg, "sliding_window", None) or 0,
             )
 

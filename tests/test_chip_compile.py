@@ -1016,3 +1016,75 @@ def test_greedy_decode_program_has_no_sort(chip, family):
         # nor is the temperature's divide over the logits there
         assert sorts == bool(re.findall(
             rf"%\S*divide\S* = \(?{logits}", text))
+
+
+# --- full and window layers over two classes of page (SmallThinker) ----------
+
+
+def _smallthinker_step(chip, layers, C, pack, slots=8, max_seq=16384):
+    """models/smallthinker.py's step at published widths and the
+    benchmark cell's serving sizes, lowered with a table a class of
+    page as the engine hands them: (compiled, text, params, cache,
+    window table pages)."""
+    from flexflow_tpu.models import smallthinker as fam
+    from flexflow_tpu.serve.paging import window_table_pages
+
+    cfg = fam.config(num_hidden_layers=layers, dtype=jnp.bfloat16)
+    pages = -(-(max_seq + 65) // PAGE)
+    win = window_table_pages(cfg.sliding_window, 128, PAGE)
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        class_pages={"full": slots * pages, "window": slots * win})), chip)
+    table = {"full": chip((slots, pages), jnp.int32),
+             "window": chip((slots, win), jnp.int32),
+             "window_start": chip((slots,), jnp.int32)}
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=max_seq + 64, kernels="pallas",
+            pack=pack)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32), table,
+        donate=(1,))
+    return compiled, text, params, cache, win
+
+
+@pytest.mark.parametrize("C, pack", [(1, None), (128, 256), (128, None)])
+def test_smallthinker_step_compiles_in_place(chip, C, pack):
+    """models/smallthinker.py at published widths (28 query heads to 4
+    K/V heads of 128: a group of SEVEN, handed to the merged-head body
+    padded to eight, which costs it a fifth of the time on the chip
+    (``smallthinker._pad_groups``); 64 ReGLU experts of 768; the whole
+    vocabulary), four layers (a full
+    layer, then a run of three window layers), 8 slots of a 16 384
+    context: the full layers' call walks the context's 129 pages under
+    the accepted name, the window layers' the window class's 34 under
+    a name of its own, the full layer's is the program's FIRST kernel
+    call (the trace reduction keys the step by its result), the grouped
+    expert matmuls follow, and both classes' pools are the loop's
+    carry: no copy of a pool or of a layer's experts."""
+    compiled, text, params, cache, win = _smallthinker_step(chip, 4, C, pack)
+    slots = 8
+    assert win == 34
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", entry)
+    assert f"[{slots},{C},4,8,128]" in calls[0], calls[:2]
+    names = set(re.findall(r"%(ff_ragged_paged_c\d+\w*?)(?:\.\d+)* = ", text))
+    assert names == {f"ff_ragged_paged_c{C}", f"ff_ragged_paged_c{C}_win"}, names
+    tokens = pack or slots * C
+    tm, rows = kernels.grouped_tile(6 * tokens, 64), _pair_rows(6 * tokens, 64)
+    assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},768\]", text)
+    assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},2560\]", text)
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["k"], cache["k_win"], experts,
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3 * experts.size // experts.shape[0] * experts.dtype.itemsize

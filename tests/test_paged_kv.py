@@ -219,6 +219,99 @@ class TestAllocatorProperty:
         pa.check_no_leaks()
         assert pa.free_pages == pa.num_pages
 
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5])
+    def test_window_class_interleavings_keep_invariants(self, seed):
+        """The same sweep for an engine's pager with two classes of page
+        (serve/paging.PageClasses), one of them with a window: admit,
+        grow by a chunk or a decode step, trim, preempt and complete in
+        random order over 12 slots. After EVERY operation: no leak and
+        no double free in either class; a freed page is in no live
+        table; a slot holds no more than its window table's entries
+        there and ceil(lines / page) in the whole class; every line a
+        not-yet-dispatched query may see (from its first query's window
+        on) is mapped, in order, from the table's start; a grant is in
+        both classes or (the whole class ran out) changes no covered
+        line. A caller names the lines its next step covers and nothing
+        else: ``trim`` (the scheduler's pass) and ``ensure`` (all the
+        benchmark's probe calls) free by one rule."""
+        from flexflow_tpu.serve.paging import PageClasses, window_table_pages
+
+        rng = np.random.default_rng(seed)
+        slots, ps, W, step, max_lines = 12, 4, 10, 6, 96
+        per = window_table_pages(W, step, ps)
+        assert per == 5
+        win = PageAllocator(slots * per, per, slots, ps, window=W, step_lines=step)
+        whole = PageAllocator(150, max_lines // ps, slots, ps)
+        pager = PageClasses({"whole": whole, "window": win})
+        done = {}  # slot -> lines covered by dispatched steps
+
+        def check():
+            pager.check_no_leaks()
+            live = {int(p) for row in win.table for p in row if p != win.scratch_page}
+            assert not live & {p for f in win._free_by_shard for p in f}
+            assert pager.used_pages == whole.used_pages + win.used_pages
+            for s in range(slots):
+                assert win.slot_pages(s) <= per
+                if s not in done:
+                    assert pager.slot_pages(s) == 0 and win.first_page[s] == 0
+                    continue
+                assert whole.slot_pages(s) == -(-done[s] // ps)
+                # every line from the next query's window on, up to the
+                # lines covered, is mapped at its place from the start
+                first = int(win.first_page[s])
+                assert first * ps <= max(0, done[s] - W + 1)
+                held = -(-done[s] // ps) - first
+                row = win.table[s]
+                assert (row[:held] != win.scratch_page).all()
+                assert (row[held:] == win.scratch_page).all()
+
+        for _ in range(800):
+            op = rng.choice(["admit", "grow", "grow", "trim", "preempt", "complete"])
+            idle = [s for s in range(slots) if s not in done]
+            if op == "admit" and idle:
+                s = int(rng.choice(idle))
+                n = int(rng.integers(1, step + 1))
+                if pager.ensure(s, n):
+                    done[s] = n
+                else:
+                    pager.release(s)
+            elif op == "grow" and done:
+                s = int(rng.choice(list(done)))
+                n = int(rng.integers(1, step + 1))
+                lines = min(done[s] + n, max_lines)
+                if pager.ensure(s, lines):
+                    done[s] = lines
+                else:  # the whole class ran out: nothing covered is lost
+                    assert whole.free_pages < -(-lines // ps) - whole.slot_pages(s)
+                    pager.release(s)   # the caller preempts
+                    del done[s]
+            elif op == "trim" and done:
+                s = int(rng.choice(list(done)))
+                before = win.used_pages
+                freed = pager.trim(s, done[s] + 1)   # a decode step's lines
+                assert before - win.used_pages == freed
+                assert pager.trim(s, done[s] - 3) == 0   # never backwards
+            elif op in ("preempt", "complete") and done:
+                s = int(rng.choice(list(done)))
+                pager.release(s)
+                del done[s]
+            check()
+        assert win.trimmed > 0
+        for s in list(done):
+            pager.release(s)
+        pager.check_no_leaks()
+        assert pager.free_pages == pager.num_pages and not win.first_page.any()
+
+    def test_a_window_table_too_short_for_its_steps_is_refused(self):
+        with pytest.raises(ValueError, match="table of 5"):
+            PageAllocator(40, 4, 2, 4, window=10, step_lines=6)
+        with pytest.raises(ValueError, match="context shards"):
+            PageAllocator(40, 6, 2, 4, cp_shards=2, window=10, step_lines=6)
+        pa = PageAllocator(40, 5, 2, 4, window=10, step_lines=6)
+        for lines in range(6, 97, 6):   # a request's steps, at their widest
+            assert pa.ensure(0, lines) and pa.slot_pages(0) <= 5
+        assert pa.first_page[0] == (96 - 6 - 10 + 1) // 4
+
 
 # ---------------------------------------------------------------------------
 # paged vs dense parity

@@ -22,6 +22,7 @@ from flexflow_tpu.models import (
     mistral,
     mixtral,
     olmo_hybrid,
+    smallthinker,
 )
 from flexflow_tpu.obs import sublayers
 from flexflow_tpu.obs.sublayers import (
@@ -46,6 +47,8 @@ FAMILIES = {
     "deepseek_v3": (deepseek_v3, ALWAYS | {"ff.moe.route"}),
     "olmo_hybrid": (olmo_hybrid, ALWAYS | {"ff.mixer"}),
     "granite_hybrid": (granite_hybrid, ALWAYS | {"ff.mixer"}),
+    # full and window layers alike, the router at the top of the block
+    "smallthinker": (smallthinker, ALWAYS | {"ff.moe.route"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
