@@ -176,11 +176,16 @@ class SchedulerStats:
     # note_expert_counts, at the flush that fetches them), summed over
     # steps and sparse layers: (token, expert) pairs of real tokens
     # computed, experts that were given a token, experts held (a
-    # layer's count a layer and step), and the fullest expert's tokens.
+    # layer's count a layer and step), the fullest expert's tokens, and
+    # the row tiles the experts' tokens fill under the step's own tile
+    # (serve/kernels ``grouped_tile``: the grouped matmuls' grid steps
+    # that hold a row; over ``moe_experts_hit``, the steps that share
+    # one fetch of an expert's weights).
     moe_pairs: int = 0
     moe_experts_hit: int = 0
     moe_experts_held: int = 0
     moe_load_max: int = 0
+    moe_tiles: int = 0
     # A family whose page pool holds one compressed line a token and
     # layer (a latent pool, models/deepseek_v3.py): the lines the
     # pipelined steps wrote, real tokens x layers.
@@ -250,17 +255,20 @@ class SchedulerStats:
         if dense_len is not None:
             self.sparse_rows += int((rows & (first + count > dense_len)).sum())
 
-    def note_expert_counts(self, counts) -> None:
+    def note_expert_counts(self, counts, tile: int) -> None:
         """Count one step's routed expert layers: ``counts`` (sparse
-        layers, experts held) the real tokens each expert was given. A
-        layer that was given none did not route (a step that took the
-        all-expert einsum returns zeros) and is not counted."""
+        layers, experts held) the real tokens each expert was given,
+        ``tile`` the row tile of the step's grouped matmuls
+        (``InferenceEngine.step_tile``). A layer that was given none did not
+        route (a step that took the all-expert einsum returns zeros)
+        and is not counted."""
         counts = np.asarray(counts)
         counts = counts[counts.sum(axis=-1) > 0]
         self.moe_pairs += int(counts.sum())
         self.moe_experts_hit += int((counts > 0).sum())
         self.moe_experts_held += int(counts.size)
         self.moe_load_max += int(counts.max(axis=-1).sum())
+        self.moe_tiles += int((-(-counts // tile)).sum())
 
     def note_attn_steps(self, first, count, page_size: int, num_pages: int,
                         narrow: int, window: int = 0) -> None:

@@ -396,6 +396,13 @@ def step_counts(cfg: DeepseekV3Config) -> Dict[str, Tuple[int, ...]]:
     return {"moe_counts": (cfg.count("sparse"), cfg.held[1] - cfg.held[0])}
 
 
+def expert_routing(cfg: DeepseekV3Config) -> Tuple[int, Tuple[int, int], int]:
+    """(The experts a token chooses, the range of experts held, the
+    router's outputs): what the grouped expert matmuls' row tile is
+    reckoned from (models/transformer.py ``expert_routing``)."""
+    return cfg.num_experts_per_tok, cfg.held, cfg.n_routed_experts
+
+
 def validate_serving(cfg: DeepseekV3Config, serving, mesh, *,
                      specinfer: bool = False) -> None:
     """The combinations this family's latent pool cannot serve yet,
@@ -580,9 +587,10 @@ def sparse_ffn(cfg, p, h, real, layer=None, kernels="xla"):
     shared expert the whole of its own.
     -> (out (N, D), counts (experts held,))."""
     experts, weights = route(cfg, p, h)
+    _, held, routed = expert_routing(cfg)
     out, counts = routed_experts_ffn(
         h, real, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
-        experts_held=cfg.held, layer=layer, kernels=kernels)
+        experts_held=held, routed=routed, layer=layer, kernels=kernels)
     if cfg.n_shared_experts:
         out = out + _ffn(cfg, p["shared"], h)
     return out, counts

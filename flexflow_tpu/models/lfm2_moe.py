@@ -297,6 +297,13 @@ def step_counts(cfg: Lfm2MoeConfig) -> Dict[str, Tuple[int, ...]]:
     return {"moe_counts": (cfg.count("sparse"), cfg.held[1] - cfg.held[0])}
 
 
+def expert_routing(cfg: Lfm2MoeConfig) -> Tuple[int, Tuple[int, int], int]:
+    """(The experts a token chooses, the range of experts held, the
+    router's outputs): what the grouped expert matmuls' row tile is
+    reckoned from (models/transformer.py ``expert_routing``)."""
+    return cfg.num_experts_per_tok, cfg.held, cfg.num_experts
+
+
 def validate_serving(cfg: Lfm2MoeConfig, serving, mesh, *, specinfer: bool = False) -> None:
     """The combinations this family's per-slot state cannot serve yet,
     refused at engine construction, each naming what is missing."""
@@ -498,9 +505,10 @@ def sparse_ffn(cfg, p, h, real, layer=None, kernels="xla"):
     experts, weights = route_sigmoid_topk(
         h, p["w_router"], p.get("router_offset"), cfg.num_experts_per_tok,
         norm_topk=cfg.moe_norm_topk, scaling=cfg.routed_scaling_factor)
+    _, held, routed = expert_routing(cfg)
     return routed_experts_ffn(
         h, real, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
-        experts_held=cfg.held, layer=layer, kernels=kernels)
+        experts_held=held, routed=routed, layer=layer, kernels=kernels)
 
 
 def _sparse_block(cfg, ctx, stack, index, x, carried):

@@ -7,14 +7,17 @@ HF ``MixtralSparseMoeBlock`` semantics, ``transformer.route_softmax_topk``).
 
 Which step computes the experts how (``transformer.routes_tokens``):
 the PAGED serving step on one device, at a width whose (token, expert)
-pairs give every expert a row tile (every rung of the mixed step),
+pairs are 16 an expert or more (every rung of the mixed step),
 routes its tokens (``transformer.routed_experts_ffn``: the pairs of the
 real tokens sorted by expert, grouped matmuls over them, serve/kernels
-``ff_moe_grouped_*`` on the Pallas path; the FLOPs and the weights read
-follow the tokens, and each step returns its tokens per expert,
-``step_counts``). The all-expert einsum (``transformer._moe_ffn``)
-stays for the narrow C=1 step (a few rows read every expert either
-way, and the einsum is the faster step by 3%: PERF.md, PR 36), for
+``ff_moe_grouped_*`` on the Pallas path, at the row tile the static
+pairs give, serve/kernels ``grouped_tile``: 32 rows at the admission
+rung's 64 pairs an expert, 128 from the 512 rung's 128 on; the FLOPs
+and the weights read follow the tokens, and each step returns its
+tokens per expert, ``step_counts``). The all-expert einsum
+(``transformer._moe_ffn``) stays for the narrow C=1 step (a few rows
+read every expert either way, and the einsum is the faster step by 3%:
+PERF.md, PR 36), for
 training (``forward``), the dense-layout ``serve_step`` and a mesh of
 more than one device, where the expert weights shard over the
 ``expert`` mesh axis with Megatron TP inside each expert: the grouped
@@ -39,6 +42,7 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     commit_kv,
     commit_kv_paged,
     copy_page_kv,
+    expert_routing,
     forward,
     gather_page_kv,
     init_kv_cache,

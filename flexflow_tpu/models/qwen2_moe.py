@@ -5,7 +5,7 @@ their own FFN width, softmax-over-all top-k WITHOUT renormalization
 (``norm_topk_prob=False`` default), and an always-on sigmoid-gated
 shared expert. Attention is Qwen2-style (RoPE, GQA, RMSNorm, QKV
 biases). As for ``models/mixtral.py``, a paged serving step on one
-device wide enough to give every expert a row tile routes its tokens
+device whose (token, expert) pairs are 16 an expert routes its tokens
 through the grouped expert matmuls (``transformer.routed_experts_ffn``;
 the shared expert stays dense matmuls over every token) and every
 other step takes the all-expert einsum (``transformer._moe_ffn``)."""
@@ -24,6 +24,7 @@ from .transformer import (  # noqa: F401  (engine serving protocol)
     commit_kv,
     commit_kv_paged,
     copy_page_kv,
+    expert_routing,
     forward,
     gather_page_kv,
     init_kv_cache,

@@ -275,6 +275,13 @@ def step_counts(cfg: SmallThinkerConfig) -> Dict[str, Tuple[int, ...]]:
     return {"moe_counts": (cfg.count("sparse"), cfg.held[1] - cfg.held[0])}
 
 
+def expert_routing(cfg: SmallThinkerConfig) -> Tuple[int, Tuple[int, int], int]:
+    """(The experts a token chooses, the range of experts held, the
+    router's outputs): what the grouped expert matmuls' row tile is
+    reckoned from (models/transformer.py ``expert_routing``)."""
+    return cfg.num_experts_per_tok, cfg.held, cfg.num_experts
+
+
 def page_classes(cfg: SmallThinkerConfig):
     """The classes of page this family's cache is made of, beside
     ``PAGE_POOLS``: name -> (the class's pools, its window in lines or
@@ -467,10 +474,12 @@ def _sparse_block(cfg, ctx, stack, index, x, carried):
     p = layer_weights(stack, index, whole=("w_gate", "w_up", "w_down"))
     B, T, D = x.shape
     h = _norm(cfg, x, p["mlp_norm_scale"], None).reshape(B * T, D)
+    _, held, routed = expert_routing(cfg)
     out, counts = routed_experts_ffn(
         h, ctx["real"], carried["route_experts"], carried["route_weights"],
-        p["w_gate"], p["w_up"], p["w_down"], experts_held=cfg.held,
-        layer=index, kernels=ctx["kernels"], activation=cfg.activation)
+        p["w_gate"], p["w_up"], p["w_down"], experts_held=held,
+        routed=routed, layer=index, kernels=ctx["kernels"],
+        activation=cfg.activation)
     carried = dict(carried, moe_counts=jax.lax.dynamic_update_index_in_dim(
         carried["moe_counts"], counts, index, 0))
     return x + out.reshape(B, T, D), carried

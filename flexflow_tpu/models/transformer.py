@@ -534,8 +534,8 @@ def _moe_ffn(cfg: DecoderConfig, p, h):
     :func:`serve_step`, by the paged step on a mesh of more than one
     device (no benchmark cell runs these, and a Mosaic kernel under
     GSPMD needs the ``shard_map`` that ``experts_held`` was written for,
-    ROADMAP B0/B1) and by a paged step too narrow to give every expert
-    a row tile (the C=1 step: both forms read every expert there, and
+    ROADMAP B0/B1) and by a paged step whose pairs are under 16 an
+    expert (the C=1 step: both forms read every expert there, and
     the einsum is 3% the faster step). Every other paged serving step
     on one device routes its tokens instead (:func:`routes_tokens`,
     :func:`_routed_ffn`, :func:`routed_experts_ffn`)."""
@@ -609,10 +609,29 @@ def route_sigmoid_topk(h, w_router, select_offset, k: int, *,
     return experts.astype(jnp.int32), weights * scaling
 
 
+def routed_tile(tokens: int, k: int, experts_held: Tuple[int, int],
+                routed=None) -> int:
+    """The row tile of the grouped expert matmuls where ``tokens``
+    places each choose ``k`` of the router's ``routed`` outputs (None:
+    the experts held are all of them) and the range ``experts_held`` of
+    them is here: serve/kernels ``grouped_tile`` at the static pairs.
+    Asked by :func:`routed_experts_ffn` as it traces and by the engine
+    for the step it dispatches (``InferenceEngine.step_tile``), both
+    with what the family's ``expert_routing(cfg)`` declares, so that
+    the host counts the tiles a step's tokens per expert fill
+    (``SchedulerStats.note_expert_counts``) under the tile the step
+    ran."""
+    from ..serve.kernels import grouped_tile
+
+    lo, hi = experts_held
+    return grouped_tile(tokens * k, hi - lo, routed)
+
+
 @sublayer("moe.route")
 def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
-                       experts_held: Tuple[int, int], layer=None,
-                       kernels: str = "xla", activation: str = "silu"):
+                       experts_held: Tuple[int, int], routed=None,
+                       layer=None, kernels: str = "xla",
+                       activation: str = "silu"):
     """The routed half of a sparse FFN as a GROUPED matmul: the (token,
     expert) pairs of real tokens sorted by expert, one grouped matmul a
     projection over the groups, the pairs' results weighted and summed
@@ -621,7 +640,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     :func:`_moe_ffn`, which computes every expert for every position.
     Taken by the paged serving steps on one device: ``models/lfm2_moe.py``
     at every width and, for ``mixtral`` and ``qwen2_moe``,
-    :func:`serve_step_paged` from a row tile an expert on
+    :func:`serve_step_paged` from 16 pairs an expert on
     (:func:`routes_tokens`, :func:`_routed_ffn`).
 
     ``kernels="xla"``: ``lax.ragged_dot`` over the sorted rows (on the
@@ -629,7 +648,9 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     serve/kernels ``grouped_glu`` / ``grouped_down``
     (``ff_moe_grouped_*``), for which every expert's rows start at a
     multiple of the row tile, so a tile has one expert, named in the
-    weight blocks' index map; tiles past the last row are skipped.
+    weight blocks' index map; tiles past the last row are skipped. The
+    tile follows from the static pairs, the experts held and the
+    router's outputs (:func:`routed_tile`).
 
     h (T, D); ``real`` (T,) bool: padding places route nowhere;
     ``experts`` / ``weights`` (T, k) the router's choice
@@ -640,7 +661,10 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     the result is that range's part of the layer's, so the parts of
     ranges that cover the router add up to the whole layer (the
     model-configs guide's usual cut: a chip holds some experts of each
-    layer and computes its own part). ``layer``: the weights are every
+    layer and computes its own part); ``routed`` the router's outputs
+    where the range is a part of them (static; None: the range is all
+    of them), from which the row tile reckons the rows an expert is
+    given. ``layer``: the weights are every
     layer's, stacked (L, hi - lo, ...), and this call addresses its own
     experts inside the free (L * (hi - lo), ...) view (the Pallas path
     by an offset on the tiles' expert index, the XLA path by giving
@@ -669,7 +693,7 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     if kernels == "pallas":
         from ..serve import kernels as _pk
 
-        tm = _pk.grouped_tile(P, n)
+        tm = routed_tile(T, k, experts_held, routed)
         tiles = -(-(P + n * (tm - 1)) // tm)
         aligned = -(-counts // tm) * tm
         ends = jnp.cumsum(aligned)
@@ -748,21 +772,33 @@ def routes_tokens(cfg: DecoderConfig, layers, tokens: int) -> bool:
     unquantized expert stacks); an ambient mesh of one device (under
     GSPMD the grouped Pallas calls need a ``shard_map`` over the
     experts held, ROADMAP B0/B1; the einsum shards as it is); and
-    static pairs that give every expert a row tile. Under that (the
+    static pairs that are 16 an expert, a bf16 sublane tile of rows
+    each, whatever row tile the grouped matmuls then take
+    (serve/kernels ``grouped_tile``: 32 at Mixtral's admission rung of
+    512 pairs). Under that (the
     C=1 step of 16 slots: 32 pairs, 4 an expert) both forms read every
     expert's weights for a handful of rows, and routing, sorting and
     gathering the pairs only add to the step: 17.40 ms against the
     einsum's 16.91 on a v5e at Mixtral's widths (PERF.md, PR 36)."""
-    from ..serve.kernels import grouped_tile
-
     mesh = jax.sharding.get_abstract_mesh()
     experts, pairs = cfg.num_local_experts, tokens * cfg.num_experts_per_tok
     return bool(
         experts and cfg.glu and cfg.activation == "silu"
         and not any(isinstance(layers[name], dict) for name in EXPERT_STACKS)
         and (mesh.empty or mesh.size == 1)
-        and pairs >= grouped_tile(pairs, experts) * experts
+        and pairs >= 16 * experts
     )
+
+
+def expert_routing(cfg: DecoderConfig) -> Tuple[int, Tuple[int, int], int]:
+    """What the row tile of the grouped expert matmuls is reckoned from
+    beside a step's places (:func:`routed_tile`), declared once a
+    family beside :func:`step_counts`: (the experts a token chooses,
+    the range of experts held, the router's outputs). Read by the
+    family's own call of :func:`routed_experts_ffn` and by the engine
+    (``InferenceEngine.step_tile``)."""
+    return (cfg.num_experts_per_tok, (0, cfg.num_local_experts),
+            cfg.num_local_experts)
 
 
 def step_counts(cfg: DecoderConfig) -> Dict[str, Tuple[int, ...]]:
@@ -789,9 +825,10 @@ def _routed_ffn(cfg: DecoderConfig, p, h, real, layer, kernels: str):
     experts, weights = route_softmax_topk(
         flat, p["w_router"], cfg.num_experts_per_tok,
         norm_topk=cfg.moe_norm_topk)
+    _, held, routed = expert_routing(cfg)
     out, counts = routed_experts_ffn(
         flat, real, experts, weights, *(p[name] for name in EXPERT_STACKS),
-        experts_held=(0, cfg.num_local_experts), layer=layer, kernels=kernels)
+        experts_held=held, routed=routed, layer=layer, kernels=kernels)
     out = out.reshape(B, S, D)
     if cfg.moe_shared_expert_intermediate_size:
         out = out + _shared_expert(cfg, p, h)
@@ -1915,8 +1952,8 @@ def serve_step_paged(
     positions that held none stop being computed. None: the padded
     step, operation for operation.
 
-    A sparse model on one device, at a width that gives every expert a
-    row tile (:func:`routes_tokens`: the mixed step's rungs, not the
+    A sparse model on one device, at a width whose pairs are 16 an
+    expert (:func:`routes_tokens`: the mixed step's rungs, not the
     C=1 step of a few slots), sends the (token, expert) pairs of its
     REAL tokens through the grouped expert matmuls
     (:func:`routed_experts_ffn`; its experts' weights stay stacked over

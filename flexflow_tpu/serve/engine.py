@@ -907,6 +907,14 @@ class InferenceEngine:
             getattr(model, "step_counts", lambda cfg: {})(cfg)
             if self.paged else {})
         self.step_fetch = None
+        # ... and the row tile of that step's grouped expert matmuls
+        # (models/transformer.py ``routed_tile`` at the width the step
+        # ran and what the family's ``expert_routing(cfg)`` declares
+        # beside its ``step_counts``), under which the scheduler counts
+        # the tiles its tokens per expert fill
+        self.step_tile = 0
+        self._expert_routing = (
+            getattr(model, "expert_routing")(cfg) if self._step_counts else ())
         # Observability (flexflow_tpu/obs): count_dispatch doubles as
         # the tracing chokepoint — with a tracer attached (shared with
         # the owning scheduler's lane by obs.attach_observability),
@@ -1522,7 +1530,11 @@ class InferenceEngine:
             out = step(*args, **kw)
         toks, *rest, self.cache = out
         if self._step_counts:
+            from ..models.transformer import routed_tile
+
             self.step_fetch = rest.pop()
+            self.step_tile = routed_tile(
+                pack or host_tokens.size, *self._expert_routing)
         self._poison_donated(
             donated, ("mixed_packed", chunk, pack, mode, cap) if pack
             else ("mixed_fused", chunk, with_logits, mode, cap))

@@ -1380,35 +1380,88 @@ def fused_rope_paged_attention(
 # and its weights are named by a prefetched scalar in the block index map.
 
 
-def grouped_tile(pairs: int, experts: int) -> int:
+#: rows of the grouped matmuls' tile where an expert is given more rows
+#: than a bf16 sublane tile and holds fewer than the MXU's 128: chosen on
+#: the chip (PERF.md section 6, PR 51). A grid step's matmuls hide under
+#: the arrival of the NEXT expert's weight block only in an expert's last
+#: tile, so an expert's further tiles cost their compute, which 32, 64
+#: and 128 rows do at the same 0.08 us a row where 16 rows take 0.12;
+#: and every expert's rows are aligned to the tile, so the rows that
+#: ``routed_experts_ffn`` gathers, and takes its results from, grow by
+#: the experts held times the tile: at SmallThinker's padded step the
+#: 64-row tile's 2048 more rows than the 32-row tile's cost the step's
+#: routing 3.4 ms where the matmuls gained nothing
+GROUPED_MIDDLE_TILE = 32
+
+
+def grouped_tile(pairs: int, experts: int,
+                 routed: Optional[int] = None) -> int:
     """Rows a tile of the grouped expert matmuls at ``pairs`` (token,
-    expert) pairs over ``experts`` experts held, both static: 128 (the
-    MXU's rows) where the pairs are a tile's worth an expert, 16 (a bf16
-    sublane tile) while they are a few an expert (the decode step: the
-    kernel is then a read of the experts' weights and alignment costs
-    rows, not time). 1024 pairs are 128 rows each of Mixtral's 8
-    experts, whose matmuls in tiles of 16 would use an eighth of the
-    MXU, and 16 rows each of LFM2's 64."""
-    return 128 if pairs >= 128 * experts else 16
+    expert) pairs over ``experts`` experts held of the ``routed``
+    outputs the router chooses among (None: the experts held are all
+    of them), all static. Every expert's rows start at a multiple of
+    the tile, so a tile costs ``experts * (tile - 1)`` rows of
+    alignment, and an expert's weight block is fetched once however
+    many tiles its rows fill: the tile decides how many grid steps
+    share one weight block and how much of the MXU's 128 rows a step
+    uses.
+
+    * 128 where the pairs are 128 to an expert HELD: the alignment is
+      then under the pairs themselves, and a skewed expert walks few
+      tiles whatever the router's width (DeepSeek-V3's 16 held of 256
+      at 2048 pairs: 8 rows arrive on average, a block of weights is
+      72 us and a tile of any height hides under it).
+    * :data:`GROUPED_MIDDLE_TILE` (32) where an expert is GIVEN more
+      than 16 rows on average (pairs over the router's outputs) and
+      holds under 128: in 16-row tiles those are two to eight grid
+      steps at an eighth of the MXU's rows, which compute for longer
+      than the weight block takes to arrive (SmallThinker's padded
+      step, 6144 pairs over 64: seven tiles, 8.1 us against 4.8 us;
+      Mixtral's admission rung, 512 over 8).
+    * 16 (a bf16 sublane tile) while an expert is given a few rows (the
+      decode step: the kernel is then a read of the experts' weights
+      and alignment costs rows, not time): LFM2's 64 experts at 256 and
+      at 1024 pairs, DeepSeek's 16 of 256 at 1024.
+
+    A 256-row tile where an expert is given 256 rows (Mixtral's 1024
+    rung) was timed on the kernel alone and not taken: the
+    up-projections read 4.70 ms for 4.92, and the down-projection,
+    whose (256, 14336) rows' block leaves a weight block 256 columns,
+    3.77 for 2.72 (PERF.md section 6, PR 51)."""
+    if pairs >= 128 * experts:
+        return 128
+    if pairs > 16 * (routed or experts):
+        return GROUPED_MIDDLE_TILE
+    return 16
 
 
 def grouped_block(width: int, depth: int, weights: int, itemsize: int) -> int:
     """Columns of a weight block of the grouped expert matmuls, from the
     static widths: ``weights`` stacks of (depth, width) an expert, read
-    as (depth, block) blocks. The rows' block is read again for every
-    column block, so 512 columns are doubled while there would be more
-    than 16 column blocks, the wider block divides ``width`` and the
-    weight blocks, double-buffered, stay within 32 MB of the 48 MB the
-    calls state (:func:`_grouped_call`; the rows' and the result's
-    blocks take the rest). LFM2's (2048, 1536) stays at 512; Mixtral's
-    up-projections (4096 -> 14336) take 1024, 14 column blocks; a width
-    that 512 does not divide takes the widest multiple of a lane tile
-    under it that does (SmallThinker's 768: 384, two column blocks)."""
+    as (depth, block) blocks which, double-buffered, stay within 32 MB
+    of the 48 MB the calls state (:func:`_grouped_call`; the rows' and
+    the result's blocks take the rest). The rows' block is read again,
+    and the grid walks the tiles again, for every column block. So
+    where an expert's whole matrices fit those 32 MB they are one block
+    (SmallThinker's (2560, 768) gate and up, 15.7 MB double-buffered,
+    and its (768, 2560) down-projection, 7.9: one column block where
+    512 gave two of 384 and five; LFM2's (2048, 1536) gate and up, 25.2,
+    and its (1536, 2048) down-projection, 12.6: one where there were
+    three and four). Where they do not: 512 columns, doubled while
+    there would be more than 16 column blocks, the wider block divides
+    ``width`` and the blocks stay within the 32 MB (Mixtral's
+    up-projections (4096 -> 14336) take 1024, 14 column blocks; its
+    down-projection and DeepSeek-V3's both stay at 512); a width that
+    512 does not divide takes the widest multiple of a lane tile under
+    it that does."""
+    fits = lambda block: 2 * weights * depth * block * itemsize <= 32 << 20
+    if fits(width):
+        return width
     block = min(512, width)
     while width % block and block > 128:
         block -= 128
     while (width // block > 16 and width % (2 * block) == 0
-           and 2 * weights * depth * 2 * block * itemsize <= 32 << 20):
+           and fits(2 * block)):
         block *= 2
     return block
 

@@ -1047,7 +1047,8 @@ class RequestManager:
                 last, host_tokens, use_last, positions,
                 np.zeros((R,), np.int32), sub, greedy, temp, topp, topk,
             )
-        self._inflight.append((toks, snapshot, self.engine.step_fetch))
+        self._inflight.append((toks, snapshot, self.engine.step_fetch,
+                               self.engine.step_tile))
         self._prev_dispatch_slots = {s for _, s, _, _ in snapshot}
         self._step_counter += 1
         self.stats.record_step(
@@ -1168,7 +1169,7 @@ class RequestManager:
                 sub, greedy, temp, topp, topk,
             )
         self._stamp_prefill_dispatched(finals)
-        self._inflight.append((toks, snapshot, self.engine.step_fetch))
+        self._inflight.append((toks, snapshot, eng.step_fetch, eng.step_tile))
         self._prev_dispatch_slots = sampled_slots
         self._step_counter += 1
         self.stats.record_step(
@@ -1200,14 +1201,14 @@ class RequestManager:
         still drains its pipeline refs — its slot/pages are released at
         the flush that drains the last reference."""
         with self.tracer.span("step.flush"):
-            toks, snapshot, fetch = self._inflight.pop(0)
+            toks, snapshot, fetch, tile = self._inflight.pop(0)
             with self.tracer.span("step.flush_wait"):
                 # ffcheck: disable=FF107 -- the pipeline flush IS the designed sync point: it drains steps the device already finished, dispatch_ahead steps behind
                 toks = np.asarray(jax.device_get(toks if fetch is None else fetch))
             self.stats.flushes += 1
             if fetch is not None:  # the step's counters ride behind its tokens
                 toks, counts = self.engine.split_fetch(toks)
-                self.stats.note_expert_counts(counts["moe_counts"])
+                self.stats.note_expert_counts(counts["moe_counts"], tile)
             tr = self.tracer
             if tr.enabled:
                 tr.event("flush", entries=len(snapshot))

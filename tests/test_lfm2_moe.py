@@ -139,6 +139,7 @@ def test_served_logits_match_the_reference(tiny, shared, kernels):
         rows = {r: (seqs[r][done[r]:done[r] + n], done[r]) for r, n in feed.items()}
         logits = _feed(eng, rows, chunk)
         counts = eng.split_fetch(np.asarray(eng.step_fetch))[1]["moe_counts"]
+        assert eng.step_tile == 16             # a few rows an expert at every width here
         assert counts.shape == (cfg.count("sparse"), cfg.num_experts)
         assert (counts.sum(-1) == sum(feed.values()) * cfg.num_experts_per_tok).all()
         for r, n in feed.items():
@@ -193,7 +194,8 @@ def test_greedy_tokens_through_generate_are_the_references(tiny, shared):
         assert _is_the_references_greedy(tiny, prompt, out.output_tokens)
     stats = shared.rm.stats
     grew = {f: getattr(stats, f) - getattr(before, f) for f in (
-        "state_resets", "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max")}
+        "state_resets", "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max",
+        "moe_tiles")}
     assert grew["state_resets"] == 3
     assert stats.slot_state_bytes == shared.engine.slot_state_bytes() > 0
     # every flushed step's sparse layers: pairs of real tokens only
@@ -201,6 +203,9 @@ def test_greedy_tokens_through_generate_are_the_references(tiny, shared):
     assert 0 < grew["moe_experts_hit"] <= grew["moe_experts_held"]
     assert grew["moe_experts_held"] % (cfg.count("sparse") * cfg.num_experts) == 0
     assert grew["moe_load_max"] >= grew["moe_pairs"] / cfg.num_experts
+    # each flushed step's tiles under its own width's row tile: an expert
+    # that was given a token fills at least one, and no more than its tokens
+    assert grew["moe_experts_hit"] <= grew["moe_tiles"] <= grew["moe_pairs"]
 
 
 # --- (b) the conv state across chunk boundaries ------------------------------

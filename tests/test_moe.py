@@ -352,13 +352,177 @@ def test_route_softmax_topk_is_the_einsums_rule(norm_topk):
     assert experts.dtype == jnp.int32 and gate.dtype == jnp.float32
 
 
-@pytest.mark.parametrize("pairs, experts, tile", [
+@pytest.mark.parametrize("pairs, experts, routed, tile", [
     # LFM2's programs (64 experts, top-4): the C=1 step at 64 rows, the
     # 2048 and 4096 rungs and the padded step: what they had before
-    (256, 64, 16), (8192, 64, 128), (16384, 64, 128), (32768, 64, 128),
+    (256, 64, 64, 16), (8192, 64, 64, 128), (16384, 64, 64, 128),
+    (32768, 64, 64, 128),
+    # and its admission rung (256 places): 16 rows an expert fill one
+    # 16-row tile each, so the middle case starts above them
+    (1024, 64, 64, 16),
     # Mixtral's (8 experts, top-2): the C=1 step at 16 rows, the 512 rung
     # (128 rows an expert: a whole tile), the 1024 rung, the padded step
-    (32, 8, 16), (1024, 8, 128), (2048, 8, 128), (4096, 8, 128),
+    # (256 and 512 rows an expert: a 256-row tile read no faster on the
+    # kernel alone, PERF.md section 6, PR 51)
+    (32, 8, 8, 16), (1024, 8, 8, 128), (2048, 8, 8, 128), (4096, 8, 8, 128),
+    # Mixtral's admission rung (256 places): 64 rows an expert were four
+    # 16-row tiles that computed for longer than their weights took to
+    # arrive; the middle tile (ISSUE 51)
+    (512, 8, 8, 32),
+    # SmallThinker's (64 experts, top-6): the padded step (96 rows an
+    # expert: seven 16-row tiles before), its 512 and 256 rungs (48, 24)
+    # and the C=1 step at 8 rows
+    (6144, 64, 64, 32), (3072, 64, 64, 32), (1536, 64, 64, 32),
+    (48, 64, 64, 16),
+    # DeepSeek-V3's (16 experts held of 256, top-8; 4 slots): its programs
+    # come out as they were. 128 pairs to an expert HELD keep the 128-row
+    # tile though 8 and 16 arrive on average (a skewed expert then walks
+    # few tiles, and any tile hides under 59 MB of weights); under that
+    # the rows that ARRIVE decide, 4 an expert at the 128 rung, and not
+    # the 64 a held expert has room for; the C=1 step
+    (4096, 16, 256, 128), (2048, 16, 256, 128), (1024, 16, 256, 16),
+    (32, 16, 256, 16),
+    # the same held share of a router under load: 32 rows arrive
+    (8192, 16, 256, 128), (8192, 128, 256, 32),
 ])
-def test_grouped_tile_follows_the_rows_an_expert_gets(pairs, experts, tile):
-    assert serve_kernels.grouped_tile(pairs, experts) == tile
+def test_grouped_tile_follows_the_rows_an_expert_gets(pairs, experts, routed,
+                                                      tile):
+    assert serve_kernels.grouped_tile(pairs, experts, routed) == tile
+    if routed == experts:   # the router's width is the experts held unless told
+        assert serve_kernels.grouped_tile(pairs, experts) == tile
+
+
+@pytest.mark.parametrize("width, depth, weights, block", [
+    # pinned since PR 36 (tests/test_chip_compile.py compiles them):
+    # Mixtral's up-projections and down-projection (235 MB of gate and
+    # up an expert, 117 MB of down)
+    (14336, 4096, 2, 1024), (4096, 14336, 1, 512),
+    # LFM2's (gate and up 25.2 MB double-buffered, down 12.6): whole,
+    # where 512 gave three and four column blocks (on the kernel alone
+    # the whole matrices read no slower, PERF.md section 6, PR 51)
+    (1536, 2048, 2, 1536), (2048, 1536, 1, 2048),
+    # SmallThinker's (15.7 and 7.9 MB): one column block each, where 512
+    # gave 768 two blocks of 384 and 2560 five of 512
+    (768, 2560, 2, 768), (2560, 768, 1, 2560),
+    # DeepSeek-V3's (117 and 59 MB)
+    (2048, 7168, 2, 512), (7168, 2048, 1, 512),
+    # a width 512 does not divide, over the 32 MB: the widest multiple
+    # of a lane tile under 512 that divides it
+    (1920, 4096, 2, 384),
+    # over the 32 MB (48) and at them exactly
+    (1536, 4096, 2, 512), (1024, 4096, 2, 1024),
+])
+def test_grouped_block_follows_the_widths(width, depth, weights, block):
+    assert serve_kernels.grouped_block(width, depth, weights, 2) == block
+    assert width % block == 0
+    # what the calls hold of their 48 MB for weights, double-buffered
+    assert 2 * weights * depth * block * 2 <= 32 << 20
+
+
+def _pairs_by_count(tm, T, held, unheld):
+    """(experts (T, 2), real (T,)) whose real tokens give the four
+    experts ``held`` no row, one row, exactly a tile and a tile and a
+    row: column 0 holds the expert with one row, column 1 the two
+    with a tile, every other place an expert of ``unheld`` (not held
+    here), and the last three tokens are padding."""
+    empty, one, whole, over = held
+    experts = np.empty((T, 2), np.int32)
+    experts[:, 0], experts[:, 1] = unheld
+    experts[3, 0] = one
+    experts[:tm, 1] = whole
+    experts[tm:2 * tm + 1, 1] = over
+    real = np.arange(T) < T - 3
+    experts[~real] = (one, whole)      # padding routes nowhere
+    return experts, real
+
+
+@pytest.mark.parametrize("tm, T", [(16, 64), (32, 160), (128, 288)])
+def test_grouped_kernels_match_ragged_dot_at_every_tile(tm, T):
+    """``routed_experts_ffn(kernels="pallas")`` (interpret mode) against
+    ``kernels="xla"`` at each tile ``grouped_tile`` returns, the experts
+    held (2 to 5) a part of the router's 8 outputs, the weights every
+    layer's and addressed by ``layer``, on counts that hold an empty
+    expert, an expert with one row, one with exactly a tile and one with
+    a tile and a row."""
+    k, routed, (lo, hi), L, D, F = 2, 8, (2, 6), 2, 32, 48
+    assert serve_kernels.grouped_tile(T * k, hi - lo, routed) == tm
+    experts, real = _pairs_by_count(tm, T, range(lo, hi), (0, 7))
+    rng = np.random.default_rng(tm)
+    h = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(L, hi - lo, D, F)) * 0.2,
+                                jnp.float32) for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(L, hi - lo, F, D)) * 0.2, jnp.float32)
+    out = {}
+    for kernels in ("xla", "pallas"):
+        out[kernels], counts = transformer.routed_experts_ffn(
+            h, jnp.asarray(real), jnp.asarray(experts), weights, w_gate, w_up,
+            w_down, experts_held=(lo, hi), routed=routed, layer=jnp.int32(1),
+            kernels=kernels)
+        np.testing.assert_array_equal(np.asarray(counts), [0, 1, tm, tm + 1])
+    np.testing.assert_allclose(out["pallas"], out["xla"], rtol=1e-5, atol=1e-5)
+    # the layer addressed, not its neighbour: the same call on layer 1 alone
+    alone, _ = transformer.routed_experts_ffn(
+        h, jnp.asarray(real), jnp.asarray(experts), weights, w_gate[1],
+        w_up[1], w_down[1], experts_held=(lo, hi), routed=routed,
+        kernels="pallas")
+    np.testing.assert_allclose(out["pallas"], alone, rtol=1e-6, atol=1e-6)
+    assert np.abs(np.asarray(alone)[real]).max() > 0
+    assert not np.asarray(alone)[~real].any()
+
+
+@pytest.mark.parametrize("tokens, routed", [
+    (16, False),     # the C=1 step at 16 slots: 4 pairs an expert, the einsum
+    (256, True),     # the admission rung: 64 an expert, whatever its tile
+    (512, True), (1024, True), (2048, True),
+])
+def test_routes_tokens_does_not_move_with_the_tile(tokens, routed):
+    """Mixtral's programs at published widths take the grouped form
+    from 16 pairs an expert on, as they did when the tile was 16 there
+    (the condition is stated apart from ``grouped_tile``, ISSUE 51)."""
+    cfg = mixtral.mixtral_8x7b(num_hidden_layers=1)
+    layers = dict.fromkeys(transformer.EXPERT_STACKS)
+    assert transformer.routes_tokens(cfg, layers, tokens) == routed
+    assert transformer.routed_tile(
+        tokens, *transformer.expert_routing(cfg)
+    ) == serve_kernels.grouped_tile(2 * tokens, 8)
+
+
+@pytest.mark.parametrize("family, kw, tokens, tile", [
+    # each cell's programs at published widths: the C=1 step, the
+    # narrowest rung, the padded step
+    ("lfm2_moe", {}, 64, 16), ("lfm2_moe", {}, 256, 16),
+    ("lfm2_moe", {}, 2048, 128), ("lfm2_moe", {}, 8192, 128),
+    ("deepseek_v3", {"experts_held": (0, 16)}, 4, 16),
+    ("deepseek_v3", {"experts_held": (0, 16)}, 128, 16),
+    ("deepseek_v3", {"experts_held": (0, 16)}, 256, 128),
+    ("deepseek_v3", {"experts_held": (0, 16)}, 512, 128),
+    ("smallthinker", {}, 8, 16), ("smallthinker", {}, 256, 32),
+    ("smallthinker", {}, 1024, 32),
+    ("mixtral", {}, 256, 32), ("mixtral", {}, 2048, 128),
+])
+def test_a_familys_expert_routing_gives_its_steps_tile(family, kw, tokens, tile):
+    """``expert_routing(cfg)``, which the family's step hands
+    ``routed_experts_ffn`` and the engine reads for the host's count of
+    tiles (``InferenceEngine.step_tile``), gives ``routed_tile`` the
+    pairs, the experts held and the router's outputs of the cell's
+    programs."""
+    import importlib
+
+    fam = importlib.import_module(f"flexflow_tpu.models.{family}")
+    cfg = (fam.mixtral_8x7b if family == "mixtral" else fam.config)(**kw)
+    assert transformer.routed_tile(tokens, *fam.expert_routing(cfg)) == tile
+
+
+@pytest.mark.parametrize("tile, tiles", [(16, 10), (32, 6), (64, 5), (128, 4)])
+def test_scheduler_counts_the_tiles_the_tokens_fill(tile, tiles):
+    """``SchedulerStats.note_expert_counts`` under a step's tile: an
+    expert with no token fills none, the others their tokens rounded up
+    to tiles; a layer that did not route is not counted."""
+    from flexflow_tpu.metrics import SchedulerStats
+
+    stats = SchedulerStats()
+    stats.note_expert_counts(
+        np.array([[0, 1, 16, 17, 96], [0, 0, 0, 0, 0]]), tile)
+    assert (stats.moe_pairs, stats.moe_experts_hit, stats.moe_experts_held,
+            stats.moe_tiles) == (130, 4, 5, tiles)
