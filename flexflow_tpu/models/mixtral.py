@@ -12,9 +12,11 @@ routes its tokens (``transformer.routed_experts_ffn``: the pairs of the
 real tokens sorted by expert, grouped matmuls over them, serve/kernels
 ``ff_moe_grouped_*`` on the Pallas path, at the row tile the static
 pairs give, serve/kernels ``grouped_tile``: 32 rows at the admission
-rung's 64 pairs an expert, 128 from the 512 rung's 128 on; the FLOPs
-and the weights read follow the tokens, and each step returns its
-tokens per expert, ``step_counts``). The all-expert einsum
+rung's 64 pairs an expert, 128 from the 512 rung's 128 on, each
+expert's weight block copied in while the expert before it computes,
+so a call costs the larger of its weights' read and its matmuls; the
+FLOPs and the weights read follow the tokens, and each step returns
+its tokens per expert, ``step_counts``). The all-expert einsum
 (``transformer._moe_ffn``) stays for the narrow C=1 step (a few rows
 read every expert either way, and the einsum is the faster step by 3%:
 PERF.md, PR 36), for

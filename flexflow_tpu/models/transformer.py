@@ -775,7 +775,8 @@ def routes_tokens(cfg: DecoderConfig, layers, tokens: int) -> bool:
     static pairs that are 16 an expert, a bf16 sublane tile of rows
     each, whatever row tile the grouped matmuls then take
     (serve/kernels ``grouped_tile``: 32 at Mixtral's admission rung of
-    512 pairs). Under that (the
+    512 pairs) and however they fetch their weights (a run of tiles
+    ahead since PR 52, ``grouped_fetches``). Under that (the
     C=1 step of 16 slots: 32 pairs, 4 an expert) both forms read every
     expert's weights for a handful of rows, and routing, sorting and
     gathering the pairs only add to the step: 17.40 ms against the

@@ -1382,15 +1382,19 @@ def fused_rope_paged_attention(
 
 #: rows of the grouped matmuls' tile where an expert is given more rows
 #: than a bf16 sublane tile and holds fewer than the MXU's 128: chosen on
-#: the chip (PERF.md section 6, PR 51). A grid step's matmuls hide under
-#: the arrival of the NEXT expert's weight block only in an expert's last
-#: tile, so an expert's further tiles cost their compute, which 32, 64
-#: and 128 rows do at the same 0.08 us a row where 16 rows take 0.12;
-#: and every expert's rows are aligned to the tile, so the rows that
-#: ``routed_experts_ffn`` gathers, and takes its results from, grow by
-#: the experts held times the tile: at SmallThinker's padded step the
-#: 64-row tile's 2048 more rows than the 32-row tile's cost the step's
-#: routing 3.4 ms where the matmuls gained nothing
+#: the chip under the FETCH OF PR 51, the pipeline's, where a grid step's
+#: matmuls hid under the arrival of the next expert's weight block only
+#: in an expert's last tile and every further tile cost its compute
+#: (PERF.md section 6, PR 51): 32, 64 and 128 rows computed at the same
+#: 0.08 us a row where 16 rows take 0.12; and every expert's rows are
+#: aligned to the tile, so the rows that ``routed_experts_ffn`` gathers,
+#: and takes its results from, grow by the experts held times the tile:
+#: at SmallThinker's padded step the 64-row tile's 2048 more rows than
+#: the 32-row tile's cost the step's routing 3.4 ms where the matmuls
+#: gained nothing. Since PR 52 the next block is fetched a whole run
+#: ahead (:func:`grouped_fetches`) and a run's tiles hide under it
+#: together; the tile has not been read again with the fetch out of its
+#: way (PERF.md section 7)
 GROUPED_MIDDLE_TILE = 32
 
 
@@ -1402,9 +1406,11 @@ def grouped_tile(pairs: int, experts: int,
     of them), all static. Every expert's rows start at a multiple of
     the tile, so a tile costs ``experts * (tile - 1)`` rows of
     alignment, and an expert's weight block is fetched once however
-    many tiles its rows fill: the tile decides how many grid steps
-    share one weight block and how much of the MXU's 128 rows a step
-    uses.
+    many tiles its rows fill, while the tiles of the expert before it
+    compute (:func:`grouped_fetches`): the tile decides how many grid
+    steps share one weight block and how much of the MXU's 128 rows a
+    step uses. The three cases were timed under the pipeline's fetch
+    (PR 51), which hid one tile an expert under a block's arrival.
 
     * 128 where the pairs are 128 to an expert HELD: the alignment is
       then under the pairs themselves, and a skewed expert walks few
@@ -1415,7 +1421,7 @@ def grouped_tile(pairs: int, experts: int,
       than 16 rows on average (pairs over the router's outputs) and
       holds under 128: in 16-row tiles those are two to eight grid
       steps at an eighth of the MXU's rows, which compute for longer
-      than the weight block takes to arrive (SmallThinker's padded
+      than the next weight block takes to arrive (SmallThinker's padded
       step, 6144 pairs over 64: seven tiles, 8.1 us against 4.8 us;
       Mixtral's admission rung, 512 over 8).
     * 16 (a bf16 sublane tile) while an expert is given a few rows (the
@@ -1466,21 +1472,170 @@ def grouped_block(width: int, depth: int, weights: int, itemsize: int) -> int:
     return block
 
 
-def _grouped_call(kernel, name, tile_group, n_active, operands, in_specs,
-                  out_shape, out_spec, grid):
+def grouped_fetches(tile_group, n_active):
+    """When the grouped expert matmuls fetch their weight blocks, as
+    scalars of the tiles, from ``tile_group`` (tiles,) and ``n_active``
+    alone (plain ``jnp``; :func:`_grouped_call` hands them to the kernel
+    as prefetched scalars). A RUN is an expert's consecutive active
+    tiles; a column block walks the runs in order, and the calls walk
+    the column blocks outermost, so the weight blocks a call reads are
+    one chain: run 0, 1, ... of column block 0, then of column block 1.
+    Block ``c`` of that chain (:func:`fetch_of_step`) lies in slot
+    ``c % 2`` of a two-slot buffer, is waited for at its run's first
+    tile and, there, block ``c + 1`` is started into the other slot,
+    whose run has just ended: a whole run ahead of its first use,
+    however many tiles the run has.
+
+    -> (``first`` (tiles,) 1 at the first tile of a run, ``run``
+    (tiles,) a tile's run, ``following`` (tiles,) the expert of the run
+    after a tile's own, after the last run the first run's (the next
+    column block begins with it), ``runs`` (1,) the runs), all int32.
+    Tiles at or past ``n_active`` are in no run (``first`` 0) and
+    ``n_active == 0`` leaves no run at all."""
+    tile_group = tile_group.astype(jnp.int32)
+    tile = jnp.arange(tile_group.shape[0], dtype=jnp.int32)
+    first = (tile < n_active) & (
+        (tile == 0) | (tile_group != jnp.roll(tile_group, 1)))
+    run = jnp.cumsum(first, dtype=jnp.int32) - 1
+    runs = jnp.sum(first, dtype=jnp.int32)
+    # the first tile of the run after each tile's own: the nearest first
+    # tile to its right, tile 0 (the first run's) where there is none
+    tiles = tile.shape[0]
+    right = jnp.append(jnp.where(first, tile, tiles)[1:], tiles)
+    after = jax.lax.cummin(right, reverse=True)
+    following = tile_group[jnp.where(after < tiles, after, 0)]
+    return first.astype(jnp.int32), run, following, runs.reshape(1)
+
+
+def fetch_of_step(j, run, runs, blocks):
+    """What the first tile of run ``run`` does in column block ``j`` of
+    ``blocks``, ``runs`` runs a column block (:func:`grouped_fetches`):
+    (the slot its own weight block lies in, whether it starts the
+    chain's very first block itself, whether a block follows it, that
+    block's column block: its expert is ``following``). Python ints or
+    traced scalars."""
+    last = run == runs - 1
+    return ((j * runs + run) % 2, (j == 0) & (run == 0),
+            jnp.logical_not(last & (j == blocks - 1)), jnp.where(last, j + 1, j))
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_call(name, compute, tm: int, tn: int, dtype, interpret: bool):
+    """-> the jitted ``call(rows, weights, tile_group, n_active)``:
+    ``compute(rows' tile, the tile's expert's block of each of
+    weights)`` tile by tile: rows (P, K), ``weights`` stacks (G, K, N)
+    read as (K, tn) blocks, ``tile_group`` (P / tm,) the index into G
+    of each tile's expert, ``n_active`` the tiles that hold a row ->
+    (P, N) ``dtype``. The grid runs the column blocks outermost and the
+    tiles innermost. The rows' and the result's blocks change every
+    step and are the pipeline's; the weights stay in HBM and the kernel
+    copies their blocks itself into two slots a stack, a run ahead
+    (:func:`grouped_fetches`): under the pipeline, which asks for the
+    next step's block and for nothing while the index stands still, an
+    expert's block began to arrive in the LAST tile of the expert before
+    it and every further tile of a run was paid on top of the fetch. The
+    two slots are the pipeline's double buffer byte for byte
+    (:func:`grouped_block`'s 32 MB); one block a call, the first, is
+    fetched with nothing to hide it. The copies a run ahead are started
+    at the LOW priority: the device takes copies of one priority in the
+    order they were started, so at the pipeline's own a run's third
+    tile waited for its rows behind a whole weight block (SmallThinker's
+    served step: 0.70 ms the up-projections where the low priority reads
+    0.60 and the pipeline's fetch 0.72; PERF.md section 6, PR 52).
+
+    One jitted function a (name, ``compute``, tile, block, dtype), so a
+    step program traces and lowers the kernel once a shape and not once
+    a layer's call site: the body, with its copies and conditions, and
+    the fetches' scalars cost a call site twice what the pipeline's
+    kernel did (LFM2's warm ``[setup] step programs`` 16.5 -> 20.5 s
+    before this, PERF.md section 6, PR 52). ``compute`` is therefore a
+    module-level function (:func:`_glu`, :func:`_dot`)."""
+    return jax.jit(functools.partial(
+        _grouped, name, compute, tm, tn, dtype, interpret))
+
+
+def _grouped(name, compute, tm, tn, dtype, interpret, rows, weights,
+             tile_group, n_active):
+    P, K = rows.shape
+    N = weights[0].shape[-1]
+    assert P % tm == 0 and N % tn == 0, (P, tm, N, tn)
+    blocks, W = N // tn, len(weights)
+
+    def kernel(tg_ref, na_ref, first_ref, run_ref, following_ref, runs_ref,
+               x_ref, *refs):
+        w_hbm, o_ref, bufs, sem = refs[:W], refs[W], refs[W + 1:-1], refs[-1]
+        j, t = pl.program_id(0), pl.program_id(1)
+        slot, opens, followed, column = fetch_of_step(
+            j, run_ref[t], runs_ref[0], blocks)
+
+        def copies(expert, column, slot):
+            # a column block of one expert's matrices (all of their
+            # columns where the block is the whole width)
+            cols = (slice(None) if blocks == 1
+                    else pl.ds(pl.multiple_of(column * tn, tn), tn))
+            return [pltpu.make_async_copy(w.at[expert, :, cols], buf.at[slot],
+                                          sem.at[i, slot])
+                    for i, (w, buf) in enumerate(zip(w_hbm, bufs))]
+
+        @pl.when(first_ref[t] == 1)
+        def _():
+            own = copies(tg_ref[t], j, slot)
+
+            @pl.when(opens)
+            def _():
+                for copy in own:
+                    copy.start()
+
+            @pl.when(followed)
+            def _():
+                # behind the pipeline's own copies: at the pipeline's
+                # priority the rows' block of the tile after next queues
+                # behind this one and the run stalls until it is whole
+                for copy in copies(following_ref[t], column, 1 - slot):
+                    copy.start(priority=1)
+
+            for copy in own:
+                copy.wait()
+
+        @pl.when(t < na_ref[0])
+        def _():
+            o_ref[...] = compute(
+                x_ref[...], *(buf[slot] for buf in bufs)).astype(o_ref.dtype)
+
+    tile = lambda j, t, *_: (t, 0)
     return pl.pallas_call(
         kernel,
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((P, N), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
-            out_specs=out_spec),
+            num_scalar_prefetch=6, grid=(blocks, P // tm),
+            in_specs=[pl.BlockSpec((tm, K), tile)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * W,
+            out_specs=pl.BlockSpec((tm, tn), lambda j, t, *_: (t, j)),
+            scratch_shapes=[pltpu.VMEM((2, K, tn), w.dtype) for w in weights]
+            + [pltpu.SemaphoreType.DMA((W, 2))]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=48 << 20),
         name=name,
-        interpret=_interpret(),
+        interpret=interpret,
     )(tile_group.astype(jnp.int32), n_active.astype(jnp.int32).reshape(1),
-      *operands)
+      *grouped_fetches(tile_group, n_active), rows, *weights)
+
+
+@functools.lru_cache(maxsize=None)
+def _glu(activation: str):
+    act = getattr(jax.nn, activation)
+
+    def glu(x, w_gate, w_up):
+        g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+        u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+        return act(g) * u
+
+    return glu
+
+
+def _dot(a, w):
+    return jnp.dot(a, w, preferred_element_type=jnp.float32)
 
 
 def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
@@ -1493,58 +1648,28 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
     ``w_gate`` / ``w_up`` (G, D, F); ``tile_group`` (P / tm,) the index
     into G of each tile's expert, ``n_active`` the tiles that hold a
     row: the others are skipped (their output rows are left as they
-    are; ``tile_group`` repeats the last active tile's expert there, so
-    they fetch no weights). The grid runs the F blocks outermost and the
-    tiles innermost: an expert's (D, tf) block is fetched once a run of
-    its tiles, so the weights read are those of the experts that have
-    rows, once (``tf``: :func:`grouped_block`). -> (P, F) in rows'
-    dtype."""
-    P, D = rows.shape
-    F = w_gate.shape[-1]
-    tf = grouped_block(F, D, 2, w_gate.dtype.itemsize)
-    assert P % tm == 0 and F % tf == 0, (P, tm, F, tf)
-    act = getattr(jax.nn, activation)
-
-    def kernel(tg_ref, na_ref, x_ref, wg_ref, wu_ref, o_ref):
-        @pl.when(pl.program_id(1) < na_ref[0])
-        def _():
-            x = x_ref[...]
-            g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-            o_ref[...] = (act(g) * u).astype(o_ref.dtype)
-
-    weights = pl.BlockSpec((1, D, tf), lambda j, t, tg, na: (tg[t], 0, j))
+    are, and they wait for, fetch and compute nothing). The grid runs
+    the F blocks outermost and the tiles innermost: an expert's (D, tf)
+    block is copied once a run of its tiles, a run ahead of its first
+    use (:func:`_grouped_call`), so the weights read are those of the
+    experts that have rows, once, and a call costs the larger of their
+    read and its matmuls (``tf``: :func:`grouped_block`). -> (P, F) in
+    rows' dtype."""
+    D, F = w_gate.shape[-2:]
     return _grouped_call(
-        kernel, f"ff_moe_grouped_glu_t{tm}", tile_group, n_active,
-        (rows, w_gate, w_up),
-        [pl.BlockSpec((tm, D), lambda j, t, tg, na: (t, 0)), weights, weights],
-        jax.ShapeDtypeStruct((P, F), rows.dtype),
-        pl.BlockSpec((tm, tf), lambda j, t, tg, na: (t, j)),
-        (F // tf, P // tm))
+        f"ff_moe_grouped_glu_t{tm}", _glu(activation), tm,
+        grouped_block(F, D, 2, w_gate.dtype.itemsize), rows.dtype,
+        _interpret())(rows, (w_gate, w_up), tile_group, n_active)
 
 
 def grouped_down(act, w_down, tile_group, n_active, *, tm: int):
     """``act W_down[g]`` tile by tile (see :func:`grouped_glu`): act
     (P, F), ``w_down`` (G, F, D) -> (P, D) float32."""
-    P, F = act.shape
-    D = w_down.shape[-1]
-    td = grouped_block(D, F, 1, w_down.dtype.itemsize)
-    assert P % tm == 0 and D % td == 0, (P, tm, D, td)
-
-    def kernel(tg_ref, na_ref, a_ref, w_ref, o_ref):
-        @pl.when(pl.program_id(1) < na_ref[0])
-        def _():
-            o_ref[...] = jnp.dot(a_ref[...], w_ref[0],
-                                 preferred_element_type=jnp.float32)
-
+    F, D = w_down.shape[-2:]
     return _grouped_call(
-        kernel, f"ff_moe_grouped_down_t{tm}", tile_group, n_active,
-        (act, w_down),
-        [pl.BlockSpec((tm, F), lambda j, t, tg, na: (t, 0)),
-         pl.BlockSpec((1, F, td), lambda j, t, tg, na: (tg[t], 0, j))],
-        jax.ShapeDtypeStruct((P, D), jnp.float32),
-        pl.BlockSpec((tm, td), lambda j, t, tg, na: (t, j)),
-        (D // td, P // tm))
+        f"ff_moe_grouped_down_t{tm}", _dot, tm,
+        grouped_block(D, F, 1, w_down.dtype.itemsize), jnp.float32,
+        _interpret())(act, (w_down,), tile_group, n_active)
 
 
 # ---------------------------------------------------------------------------

@@ -14,7 +14,14 @@ expert are a multinomial draw of ``real`` pairs (even routing with its
 natural skew), the same draw for every candidate of a case.
 
     chiprun -- python scripts/grouped_bench.py            # every case
+    chiprun -- python scripts/grouped_bench.py --rule     # the rule's own
     python scripts/grouped_bench.py --tiny                 # CPU rehearsal
+
+Beside each case it prints the least times of its weights' read and of
+its FLOPs, their larger (where a call that hides one under the other
+stands) and their sum (where one that hides nothing does), and each
+line carries a ``digest`` of the layer's result, so that two trees'
+runs of one case can be held to each other bit for bit (PR 52).
 
 Off a TPU whose ``device_kind`` is in ``benchmarks/peaks.json`` it
 exits without a reading unless ``--tiny`` is given (on the CPU the
@@ -28,6 +35,7 @@ here is a benchmark cell: it is not under ``benchmarks/``.
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import os
 import sys
@@ -79,6 +87,9 @@ CASES = {
         (16, 512, 512), (16, 1536, 2048)]),
     "lfm2.2048": (64, 2048, 1536, 8192, 8000, 6, [
         (128, 512, 512), (128, 1536, 2048)]),
+    # DeepSeek-V3's held share: 16 experts of 256 at the 256 rung's 2048
+    # pairs, a sixteenth of them for the experts here (one tile each)
+    "deepseek.256": (16, 7168, 2048, 2048, 128, 2, [(128, None, None)]),
 }
 TINY = {"tiny": (4, 128, 256, 64, 40, 2, [(16, None, None), (64, 128, 128)])}
 
@@ -128,12 +139,23 @@ def run_case(name, case, reps, out, device, peaks, counts=None):
     print(f"# {name}: {E} experts, D {D}, F {F}, {pairs} static pairs, "
           f"{real} real (max {counts.max()} an expert, "
           f"{int((counts > 0).sum())} hit), {L} layers", flush=True)
-    # the least times of the experts hit and of the real pairs (None:
-    # a rehearsal on a device without peaks)
-    weights_ms = peaks and round(3 * D * F * 2 * int((counts > 0).sum())
-                                 / peaks["hbm_bytes_per_s"] * 1e3, 4)
-    flops_ms = peaks and round(6 * real * D * F
-                               / peaks["bf16_flops_per_s"] * 1e3, 4)
+    # the least times of the experts hit and of the real pairs, a layer
+    # (the up-projections' are two thirds of each), with their larger,
+    # which a call that hides one under the other stands at, and their
+    # sum, which one that hides nothing does ({}: a rehearsal on a
+    # device without peaks)
+    least = {}
+    if peaks:
+        weights_ms = (3 * D * F * 2 * int((counts > 0).sum())
+                      / peaks["hbm_bytes_per_s"] * 1e3)
+        flops_ms = 6 * real * D * F / peaks["bf16_flops_per_s"] * 1e3
+        least = {k: round(v, 4) for k, v in dict(
+            weights_ms=weights_ms, flops_ms=flops_ms,
+            larger_ms=max(weights_ms, flops_ms),
+            sum_ms=weights_ms + flops_ms).items()}
+        print("#   a layer's " + ", ".join(
+            f"{k[:-3]} {v} ({round(v * 2 / 3, 4)} the up-projections')"
+            for k, v in least.items()) + " ms", flush=True)
     rule = (kernels.grouped_tile(pairs, E),
             kernels.grouped_block(F, D, 2, 2), kernels.grouped_block(D, F, 1, 2))
     reference = None
@@ -185,8 +207,7 @@ def run_case(name, case, reps, out, device, peaks, counts=None):
                 device, case=name, tile=tile,
                 up_block=kernels.grouped_block(F, D, 2, 2),
                 down_block=kernels.grouped_block(D, F, 1, 2),
-                rows=P, tiles_active=n_active,
-                weights_ms=weights_ms, flops_ms=flops_ms)
+                rows=P, tiles_active=n_active, **least)
             line["rule"] = (tile, line["up_block"], line["down_block"]) == rule
             glu = timed(glu_layers, x, w_gate, w_up, jnp.asarray(group))
             line["glu_ms"] = round(glu, 4)
@@ -200,6 +221,8 @@ def run_case(name, case, reps, out, device, peaks, counts=None):
                 tm=tile))[at]
             if reference is None:
                 reference = y
+            # to hold one tree's result to another's bit for bit
+            line["digest"] = hashlib.sha256(y.tobytes()).hexdigest()[:16]
             line["off_first"] = float(
                 np.abs(y - reference).max() / np.abs(reference).max())
         except Exception as e:  # a candidate the compiler refuses is a reading too
@@ -217,6 +240,8 @@ def main():
     ap.add_argument("--cases", nargs="*", default=None)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--rule", action="store_true", help=(
+        "time each case under the rule's own tile and blocks alone"))
     ap.add_argument("--counts", default=None, help=(
         "a .npy of served tokens per expert, (steps, layers, experts): the "
         "first case named runs on the step of median load, its middle layer"))
@@ -231,6 +256,10 @@ def main():
         step = steps[np.argsort(steps.sum(axis=(1, 2)))[len(steps) // 2]]
         served = step[len(step) // 2].astype(np.int64)
     for name in args.cases or cases:
+        if args.rule:
+            E, _, _, pairs = cases[name][:4]
+            cases[name] = cases[name][:-1] + (
+                [(kernels.grouped_tile(pairs, E), None, None)],)
         run_case(name, cases[name], 2 if args.tiny else args.reps, out,
                  device, peaks, served)
         served = None

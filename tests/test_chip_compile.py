@@ -825,7 +825,9 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
     the VMEM limit the calls state: the up-projections in 14 column
     blocks of 1024 (two (4096, 1024) weight blocks, double-buffered,
     are 32 MB), the down-projection in 8 of 512 (a (14336, 512) block
-    is 14.7 MB). LFM2's matrices fit the 32 MB whole (PR 51)."""
+    is 14.7 MB). LFM2's matrices fit the 32 MB whole (PR 51). The two
+    weight blocks' slots are the kernel's own scratch since PR 52
+    (``_grouped_call``), the same bytes."""
     assert kernels.grouped_block(14336, 4096, 2, 2) == 1024
     assert kernels.grouped_block(4096, 14336, 1, 2) == 512
     assert kernels.grouped_block(1536, 2048, 2, 2) == 1536
@@ -835,6 +837,7 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
     monkeypatch.setattr(
         kernels.pltpu, "CompilerParams",
         lambda **kw: limits.append(kw["vmem_limit_bytes"]) or params(**kw))
+    kernels._grouped_call.cache_clear()   # a trace kept reads no patch
     E, D, F = 8, 4096, 14336
     up, down = chip((E, D, F), jnp.bfloat16), chip((E, F, D), jnp.bfloat16)
     tiles = chip((E,), jnp.int32)
@@ -850,6 +853,18 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
     assert f"%ff_moe_grouped_glu_t{tm}" in text
     assert f"%ff_moe_grouped_down_t{tm}" in text
     assert limits == [48 << 20, 48 << 20]
+    # the weights reach the calls as they are held: the stacks stay in
+    # HBM whole and the kernels copy the blocks they read themselves (a
+    # copy, a relayout or a slice of a stack on the way would be the
+    # experts' bytes once more a call)
+    assert not re.findall(
+        r"= \w+\[(?:\d+,)?(?:4096,14336|14336,4096)\]\S* "
+        r"(?:copy|slice|dynamic-slice|bitcast-convert|transpose)\(", text)
+    # and are the program's own parameters, by name, at the calls
+    calls = re.findall(r"custom-call\(([^)]*)\), custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert [re.findall(r"%(w_[a-z]+)(?:\.\d+)?(?=,|$)", call)
+            for call in calls] == [["w_gate", "w_up"], ["w_down"]], calls
 
 
 # --- latent attention over a compressed paged line (DeepSeek-V3) ------------

@@ -2,6 +2,8 @@
 composition vs the fused op, load-balance loss, expert-parallel compile,
 and end-to-end training (the reference's MoE example,
 examples/cpp/mixture_of_experts/moe.cc, as a blob-classification fit)."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -436,10 +438,12 @@ def _pairs_by_count(tm, T, held, unheld):
     return experts, real
 
 
+@pytest.mark.parametrize("activation", ["silu", "relu"])
 @pytest.mark.parametrize("tm, T", [(16, 64), (32, 160), (128, 288)])
-def test_grouped_kernels_match_ragged_dot_at_every_tile(tm, T):
+def test_grouped_kernels_match_ragged_dot_at_every_tile(tm, T, activation):
     """``routed_experts_ffn(kernels="pallas")`` (interpret mode) against
-    ``kernels="xla"`` at each tile ``grouped_tile`` returns, the experts
+    ``kernels="xla"`` at each tile ``grouped_tile`` returns and under
+    either gate, the experts
     held (2 to 5) a part of the router's 8 outputs, the weights every
     layer's and addressed by ``layer``, on counts that hold an empty
     expert, an expert with one row, one with exactly a tile and one with
@@ -458,17 +462,163 @@ def test_grouped_kernels_match_ragged_dot_at_every_tile(tm, T):
         out[kernels], counts = transformer.routed_experts_ffn(
             h, jnp.asarray(real), jnp.asarray(experts), weights, w_gate, w_up,
             w_down, experts_held=(lo, hi), routed=routed, layer=jnp.int32(1),
-            kernels=kernels)
+            kernels=kernels, activation=activation)
         np.testing.assert_array_equal(np.asarray(counts), [0, 1, tm, tm + 1])
     np.testing.assert_allclose(out["pallas"], out["xla"], rtol=1e-5, atol=1e-5)
     # the layer addressed, not its neighbour: the same call on layer 1 alone
     alone, _ = transformer.routed_experts_ffn(
         h, jnp.asarray(real), jnp.asarray(experts), weights, w_gate[1],
         w_up[1], w_down[1], experts_held=(lo, hi), routed=routed,
-        kernels="pallas")
+        kernels="pallas", activation=activation)
     np.testing.assert_allclose(out["pallas"], alone, rtol=1e-6, atol=1e-6)
     assert np.abs(np.asarray(alone)[real]).max() > 0
     assert not np.asarray(alone)[~real].any()
+
+
+#: tokens per expert by the row tile, and the tiles laid out past the
+#: last that holds a row: what the grouped matmuls' weight fetches
+#: (serve/kernels ``grouped_fetches``) are walked over
+_RUNS = {
+    "empty_experts": (lambda tm: [0, 3, 0, tm + 1, 0], 2),
+    "one_tile_beside_eleven": (lambda tm: [1, 11 * tm], 1),
+    "no_row": (lambda tm: [0, 0, 0], 3),
+    "one_tile": (lambda tm: [0, tm, 0], 2),
+    "every_tile": (lambda tm: [2 * tm, 1, tm + 1], 0),
+}
+
+
+def _tiles_of(counts, tm, spare, first=0):
+    """(``tile_group``, ``n_active``, each real row's place) as
+    ``routed_experts_ffn`` lays ``counts`` tokens per expert out in
+    ``tm``-row tiles, ``spare`` tiles past the last that holds a row
+    (they repeat its expert), the experts' indices from ``first``."""
+    counts = np.asarray(counts)
+    aligned = -(-counts // tm) * tm
+    ends = np.cumsum(aligned)
+    n_active = int(ends[-1]) // tm
+    tile = np.arange(n_active + spare)
+    group = np.searchsorted(ends, tile * tm, side="right")
+    group = np.where(tile < n_active, group, group[max(n_active - 1, 0)])
+    at = np.concatenate([s + np.arange(c) for s, c in
+                         zip(ends - aligned, counts)]).astype(np.int64)
+    return (first + np.minimum(group, len(counts) - 1)).astype(np.int32), \
+        n_active, at
+
+
+@pytest.mark.parametrize("first", [0, 7])
+@pytest.mark.parametrize("blocks", [1, 3])
+@pytest.mark.parametrize("case", list(_RUNS))
+def test_grouped_fetches_take_every_block_once_a_run_ahead(case, blocks, first):
+    """The weight fetches of a grouped call as a function of
+    ``tile_group`` and ``n_active`` alone, walked as the kernel walks
+    its grid (column blocks outermost, ``fetch_of_step`` at a run's
+    first tile): every run's block of every column block is fetched
+    once, in the grid's order, into alternating slots; a copy is
+    started into a slot only when none is in flight there and is waited
+    for exactly once; every tile that holds a row reads its own
+    expert's block of its own column block; a tile past ``n_active``
+    does nothing, nor does a call no token chose."""
+    counts, spare = _RUNS[case]
+    tile_group, n_active, _ = _tiles_of(counts(16), 16, spare, first)
+    sched = serve_kernels.grouped_fetches(jnp.asarray(tile_group),
+                                          jnp.int32(n_active))
+    is_first, run, following, runs = (np.asarray(a) for a in sched)
+    assert all(a.dtype == np.int32 for a in (is_first, run, following, runs))
+    assert not is_first[n_active:].any()
+    flying, held, started = {}, {}, []
+    for j in range(blocks):
+        for t in range(len(tile_group)):
+            slot, opens, followed, column = (
+                int(x) for x in serve_kernels.fetch_of_step(
+                    j, int(run[t]), int(runs[0]), blocks))
+            if is_first[t]:
+                own = (int(tile_group[t]), j)
+                fetches = [(slot, own)] * opens + [
+                    (1 - slot, (int(following[t]), column))] * followed
+                for into, block in fetches:
+                    assert into not in flying, (j, t, into)
+                    flying[into] = block
+                    started.append((into, block))
+                assert flying[slot] == own, (j, t)
+                held[slot] = flying.pop(slot)
+            if t < n_active:
+                assert held[slot] == (int(tile_group[t]), j), (j, t)
+    assert not flying                                  # starts equal waits
+    hit = [first + e for e, c in enumerate(counts(16)) if c]
+    assert [block for _, block in started] == [
+        (e, j) for j in range(blocks) for e in hit]
+    assert [slot for slot, _ in started] == [
+        i % 2 for i in range(len(started))]
+    assert int(runs[0]) == len(hit)
+
+
+@pytest.mark.parametrize("tm, activation, D, F", [
+    # the up-projections in two column blocks and the down-projection
+    # in one; then one and three
+    (16, "silu", 128, 256), (32, "relu", 384, 128),
+    (128, "silu", 128, 256), (128, "relu", 384, 128)])
+@pytest.mark.parametrize("case", list(_RUNS))
+def test_grouped_kernels_read_the_blocks_they_fetched(case, tm, activation,
+                                                      D, F, monkeypatch):
+    """``grouped_glu`` / ``grouped_down`` (interpret mode: the copies,
+    the slots and the semaphores run as written) against
+    ``lax.ragged_dot`` on the counts the fetches are walked over, at
+    every tile ``grouped_tile`` returns, with weight blocks of 128
+    columns so that a call walks its runs once a column block, the
+    experts addressed from an offset into a longer stack."""
+    monkeypatch.setattr(serve_kernels, "grouped_block", lambda *a: 128)
+    counts, spare = _RUNS[case]
+    counts, first = np.asarray(counts(tm)), 2
+    tile_group, n_active, at = _tiles_of(counts, tm, spare, first)
+    G = first + len(counts) + 1
+    rng = np.random.default_rng(tm)
+    x = jnp.asarray(rng.normal(size=(int(counts.sum()), D)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(G, D, F)) * 0.2, jnp.float32)
+                    for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(G, F, D)) * 0.2, jnp.float32)
+    rows = jnp.zeros((len(tile_group) * tm, D), jnp.float32).at[at].set(x)
+    act = serve_kernels.grouped_glu(
+        rows, w_gate, w_up, jnp.asarray(tile_group), jnp.int32(n_active),
+        tm=tm, activation=activation)
+    out = serve_kernels.grouped_down(
+        act, w_down, jnp.asarray(tile_group), jnp.int32(n_active), tm=tm)
+    assert act.shape == (len(rows), F) and out.shape == (len(rows), D)
+    sizes = np.zeros(G, np.int32)
+    sizes[first:first + len(counts)] = counts
+    dot = lambda a, w: jax.lax.ragged_dot(
+        a, w, group_sizes=jnp.asarray(sizes),
+        preferred_element_type=jnp.float32)
+    want = getattr(jax.nn, activation)(dot(x, w_gate)) * dot(x, w_up)
+    np.testing.assert_allclose(np.asarray(act)[at], want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(out)[at], dot(want, w_down),
+                               rtol=1e-4, atol=1e-3)
+
+
+def test_grouped_calls_are_traced_once_a_shape():
+    """A step program calls the grouped matmuls once a sparse layer:
+    every call site of one shape shares ONE traced function (the
+    kernel's body is traced and lowered once a program, not once a
+    layer), and another tile or gate is another function."""
+    tile_group, n_active, _ = _tiles_of([3, 20], 16, 1)
+    rows = jnp.ones((len(tile_group) * 16, 32), jnp.float32)
+    w = jnp.ones((2, 32, 48), jnp.float32)
+
+    def layers(rows, w, activation="silu"):
+        acts = [serve_kernels.grouped_glu(
+            rows + l, w, w, jnp.asarray(tile_group), jnp.int32(n_active),
+            tm=16, activation=activation) for l in range(3)]
+        return sum(acts)
+
+    calls = [e for e in jax.make_jaxpr(layers)(rows, w).eqns
+             if e.primitive.name in ("pjit", "jit")
+             and "pallas_call" in str(e.params["jaxpr"])]
+    assert len(calls) == 3
+    assert len({id(e.params["jaxpr"]) for e in calls}) == 1
+    relu = [e for e in jax.make_jaxpr(
+        functools.partial(layers, activation="relu"))(rows, w).eqns
+        if e.primitive.name in ("pjit", "jit")
+        and "pallas_call" in str(e.params["jaxpr"])]
+    assert id(relu[0].params["jaxpr"]) != id(calls[0].params["jaxpr"])
 
 
 @pytest.mark.parametrize("tokens, routed", [
