@@ -126,7 +126,10 @@ L2_EPS = 1e-6
 @dataclasses.dataclass(frozen=True)
 class OlmoHybridConfig(DecoderConfig):
     layer_types: Tuple[str, ...] = ()
-    linear_num_heads: int = 30
+    linear_num_heads: int = 30           # value heads: a state each
+    # key heads, each serving linear_num_heads / linear_num_key_heads
+    # value heads' states (0: a key head a value head, as published)
+    linear_num_key_heads: int = 0
     linear_key_head_dim: int = 96
     linear_value_head_dim: int = 192
     linear_conv_kernel_dim: int = 4
@@ -142,6 +145,10 @@ class OlmoHybridConfig(DecoderConfig):
             raise ValueError(
                 f"layer_types must name {self.num_hidden_layers} layers, "
                 f"each {LINEAR!r} or {ATTENTION!r}: got {kinds}")
+        if self.linear_num_heads % self.key_heads:
+            raise ValueError(
+                f"{self.linear_num_heads} value heads are no whole groups "
+                f"of {self.key_heads} key heads")
 
     @property
     def kinds(self) -> Tuple[Tuple[str, str], ...]:
@@ -149,14 +156,18 @@ class OlmoHybridConfig(DecoderConfig):
         return tuple(("gdn" if t == LINEAR else "attn", "ffn")
                      for t in self.layer_types)
 
+    @property
+    def key_heads(self) -> int:
+        return self.linear_num_key_heads or self.linear_num_heads
+
     def count(self, group: str) -> int:
         return sum(group in kind for kind in self.kinds)
 
     @property
     def conv_dim(self) -> int:
         """Channels of a recurrent layer's convolutions: q, k and v."""
-        return self.linear_num_heads * (
-            2 * self.linear_key_head_dim + self.linear_value_head_dim)
+        return (2 * self.key_heads * self.linear_key_head_dim
+                + self.linear_num_heads * self.linear_value_head_dim)
 
 
 def config(**kw) -> OlmoHybridConfig:
@@ -191,7 +202,10 @@ def tiny(**kw) -> OlmoHybridConfig:
 def from_hf(hf: Dict[str, Any], **kw) -> OlmoHybridConfig:
     """From the published ``config.json`` keys, as they are spelled.
     ``num_hidden_layers`` under ``len(layer_types)`` takes the first
-    entries. ``head_dim`` is read where a configuration states it."""
+    entries. ``head_dim`` is read where a configuration states it.
+    ``linear_num_key_heads`` under ``linear_num_value_heads`` (the
+    published file has them equal) gives every key head a group of
+    value heads (:func:`gated_delta`)."""
     n = kw.get("num_hidden_layers", hf["num_hidden_layers"])
     if hf.get("attention_bias"):
         raise NotImplementedError("attention_bias: the published model has none")
@@ -199,10 +213,6 @@ def from_hf(hf: Dict[str, Any], **kw) -> OlmoHybridConfig:
         raise NotImplementedError(
             "rope_theta: the published file's is null and the attention "
             "layers rotate nothing")
-    if hf["linear_num_key_heads"] != hf["linear_num_value_heads"]:
-        raise NotImplementedError(
-            "linear_num_key_heads != linear_num_value_heads: the published "
-            "model has a key head a value head")
     if hf.get("hidden_act", "silu") != "silu":
         raise NotImplementedError(f"hidden_act {hf['hidden_act']!r}")
     d = dict(
@@ -216,6 +226,7 @@ def from_hf(hf: Dict[str, Any], **kw) -> OlmoHybridConfig:
         tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
         layer_types=tuple(hf["layer_types"])[:n],
         linear_num_heads=hf["linear_num_value_heads"],
+        linear_num_key_heads=hf["linear_num_key_heads"],
         linear_key_head_dim=hf["linear_key_head_dim"],
         linear_value_head_dim=hf["linear_value_head_dim"],
         linear_conv_kernel_dim=hf["linear_conv_kernel_dim"],
@@ -444,11 +455,17 @@ def paged_kv_cache_pspecs(cfg: OlmoHybridConfig = None, *, pipeline: bool = Fals
 
 
 def _chunk(q, k, v, g, b, s):
-    """One sub-chunk of the chunk form. q, k (R, H, c, dk), v (R, H, c,
+    """One sub-chunk of the chunk form. q, k (R, Hk, c, dk), v (R, H, c,
     dv), g, b (R, H, c), all float32, a position that is not real at
-    g = 0 and b = 0; ``s`` (R, H, dk, dv) the incoming state.
+    g = 0 and b = 0; ``s`` (R, H, dk, dv) the incoming state; key head
+    j is that of the value heads j H / Hk on, and ``k k^T`` and
+    ``q k^T`` are taken once a key head.
     -> (o (R, H, c, dv), the state after the sub-chunk)."""
     c = q.shape[2]
+    group = v.shape[1] // q.shape[1]
+    # a key head's array for each of its value heads (one a head: itself)
+    per_value = (lambda x: x) if group == 1 else (
+        lambda x: jnp.repeat(x, group, axis=1))
     G = jnp.cumsum(g, axis=-1)                               # (R, H, c)
     i = jnp.arange(c)
     upto = i[:, None] >= i[None, :]                          # j <= i
@@ -457,17 +474,19 @@ def _chunk(q, k, v, g, b, s):
     decay = jnp.where(upto, jnp.exp(jnp.where(
         upto, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
     ein = functools.partial(jnp.einsum, precision=HIGHEST)
-    a = b[..., None] * decay * ein("rhid,rhjd->rhij", k, k)
+    a = b[..., None] * decay * per_value(ein("rhid,rhjd->rhij", k, k))
     a = jnp.where(i[:, None] > i[None, :], a, 0.0)           # strictly below
     into = jnp.exp(G)[..., None]                             # exp(G_i)
-    rhs = b[..., None] * (v - into * ein("rhid,rhde->rhie", k, s))
+    kv, qv = per_value(k), per_value(q)
+    rhs = b[..., None] * (v - into * ein("rhid,rhde->rhie", kv, s))
     u = jax.scipy.linalg.solve_triangular(
         a + jnp.eye(c, dtype=a.dtype), rhs, lower=True, unit_diagonal=True)
-    o = into * ein("rhid,rhde->rhie", q, s) + ein(
-        "rhij,rhje->rhie", decay * ein("rhid,rhjd->rhij", q, k), u)
+    o = into * ein("rhid,rhde->rhie", qv, s) + ein(
+        "rhij,rhje->rhie",
+        decay * per_value(ein("rhid,rhjd->rhij", q, k)), u)
     left = jnp.exp(G[..., -1:] - G)[..., None]               # exp(G_c - G_j)
     s = jnp.exp(G[..., -1])[..., None, None] * s + ein(
-        "rhjd,rhje->rhde", k * left, u)
+        "rhjd,rhje->rhde", kv * left, u)
     return o, s
 
 
@@ -486,7 +505,7 @@ def _lanes_of(x, p: int, dv: int):
 def gated_delta(q, k, v, g, b, state, count, fresh):
     """The gated delta rule of one step over the carried state.
 
-    q, k (R, C, H, dk): L2-normalised, q scaled; v (R, C, H, dv); g
+    q, k (R, C, Hk, dk): L2-normalised, q scaled; v (R, C, H, dv); g
     (R, C, H): the log of each token's decay; b (R, C, H): its write
     strength; ``state`` (R, H, dk, dv) float32 or, as the cache holds
     it, (R, H / p, dk, p dv) with p heads side by side on the lanes
@@ -496,14 +515,18 @@ def gated_delta(q, k, v, g, b, state, count, fresh):
     state after each row's last real token, laid out as it came). A row
     with no real token keeps its state bitwise.
 
+    Hk key heads serve H value heads: key head j is the q and k of the
+    value heads j H / Hk on, each with a state of its own (Olmo-Hybrid:
+    Hk = H; Qwen3-Next: 16 for 32). Both are read off the shapes.
+
     C == 1 is the recurrence itself ON THE PACKED FORM (no transpose of
     a state), with the state read once for both ``S^T k`` and ``S^T q``
     (``o = a S^T q + (k . q) u`` is ``(a S + k u^T)^T q``); C > 1 the
     chunk form at sub-chunks of :data:`SUB_CHUNK` (module docstring) on
     the heads apart: one prefilling row's state is unpacked and packed
     again around it."""
-    R, C, H, dk = q.shape
-    dv = v.shape[-1]
+    R, C, Hk, dk = q.shape
+    H, dv = v.shape[2:]
     p = H // state.shape[1]
     f32 = jnp.float32
     real = jnp.arange(C)[None, :] < count[:, None]           # (R, C)
@@ -512,9 +535,12 @@ def gated_delta(q, k, v, g, b, state, count, fresh):
     b = jnp.where(real[..., None], b.astype(f32), 0.0)
     s0 = jnp.where(fresh[:, None, None, None], 0.0, state)
     if C == 1:
+        a, kq = jnp.exp(g), jnp.sum(k * q, axis=-1)
+        if Hk < H:  # a broadcast, made where the state's fusion reads it
+            q, k, kq = (jnp.repeat(x, H // Hk, axis=2) for x in (q, k, kq))
         rows = lambda x: x[:, 0].reshape((R, H // p, p) + x.shape[3:])
         a, b, kq = (_lanes_of(rows(x), p, dv)                # (R, H/p, p dv)
-                    for x in (jnp.exp(g), b, jnp.sum(k * q, axis=-1)))
+                    for x in (a, b, kq))
         q, k = (_lanes_of(rows(x), p, dv) for x in (q, k))   # (R, H/p, dk, p dv)
         # S^T k and S^T q as products summed over dk, not as 1920
         # matrix-vector products of two rows each: the state streams
@@ -542,8 +568,8 @@ def gated_delta(q, k, v, g, b, state, count, fresh):
 def recurrence_c1(q, k, v, g, b, states, index, count, fresh):
     """:func:`gated_delta` at one column as ONE pass over the state
     (``serve/kernels.gdn_recur_c1``): a token's arrays (R, ...) with no
-    column axis, ``states`` the recurrent layers' whole stack, of which
-    this layer is ``index``. -> (o (R, H, dv) float32, ``states`` with
+    column axis (q and k a KEY head), ``states`` the recurrent layers'
+    whole stack, of which this layer is ``index``. -> (o (R, H, dv) float32, ``states`` with
     the layer's rows updated in place)."""
     from ..serve.kernels import gdn_recur_c1
 
@@ -620,29 +646,46 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
+def delta_mixer(ctx, carried, index, h, qkv, p, *, heads, beta_scale):
+    """A Gated DeltaNet layer between its input projection and its
+    output norm, on the step's flat token axis: ``qkv`` (N, channels)
+    = ``[q' | k' | v']`` through the layer's convolution (its state in
+    ``carried["conv"]``) and SiLU, q and k L2-normalised a head, the
+    gates ``[b | a] = h w_gates`` (``b = beta_scale sigmoid(.)``), the
+    rule over ``carried["state"]`` (:func:`step_rows`). ``heads``:
+    (key heads, value heads, dk, dv); ``p``: the layer's ``conv_w``,
+    ``w_gates``, ``A_log``, ``dt_bias``. -> (o (N, value heads, dv)
+    float32, ``carried``). Shared with ``models/qwen3_next.py``."""
+    Hk, H, dk, dv = heads
+    f32 = jnp.float32
+    c, conv = short_conv(
+        qkv, p["conv_w"], _layer_of(carried["conv"], index),
+        ctx["row"], ctx["col"], ctx["q_len"], ctx["fresh"], ctx["place"])
+    carried = dict(carried, conv=lax.dynamic_update_index_in_dim(
+        carried["conv"], conv, index, 0))
+    q, k, v = jnp.split(jax.nn.silu(c), (Hk * dk, 2 * Hk * dk), axis=-1)
+    q = _l2norm(q.reshape(-1, Hk, dk)) * dk ** -0.5
+    k = _l2norm(k.reshape(-1, Hk, dk))
+    gates = _mm(h, p["w_gates"]).astype(f32)
+    b = jax.nn.sigmoid(gates[:, :H]) * beta_scale
+    g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
+        gates[:, H:] + p["dt_bias"].astype(f32))
+    o, state = step_rows(gated_delta, (q, k, v.reshape(-1, H, dv), g, b),
+                         carried["state"], index, ctx, kernel=recurrence_c1)
+    return o, dict(carried, state=state)
+
+
 def _gdn_block(cfg, ctx, stack, index, x, carried):
     p = layer_weights(stack, index)
     B, T, D = x.shape
-    H, dk, dv = (cfg.linear_num_heads, cfg.linear_key_head_dim,
-                 cfg.linear_value_head_dim)
+    H, dv = cfg.linear_num_heads, cfg.linear_value_head_dim
     f32 = jnp.float32
     with sublayer("mixer"):
         h = x.reshape(B * T, D)
-        c, conv = short_conv(
-            _mm(h, p["w_qkv"]), p["conv_w"], _layer_of(carried["conv"], index),
-            ctx["row"], ctx["col"], ctx["q_len"], ctx["fresh"], ctx["place"])
-        carried = dict(carried, conv=lax.dynamic_update_index_in_dim(
-            carried["conv"], conv, index, 0))
-        q, k, v = jnp.split(jax.nn.silu(c), (H * dk, 2 * H * dk), axis=-1)
-        q = _l2norm(q.reshape(-1, H, dk)) * dk ** -0.5
-        k = _l2norm(k.reshape(-1, H, dk))
-        gates = _mm(h, p["w_gates"]).astype(f32)
-        b = jax.nn.sigmoid(gates[:, :H]) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
-        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
-            gates[:, H:] + p["dt_bias"].astype(f32))
-        o, state = step_rows(gated_delta, (q, k, v.reshape(-1, H, dv), g, b),
-                             carried["state"], index, ctx, kernel=recurrence_c1)
-        carried = dict(carried, state=state)
+        o, carried = delta_mixer(
+            ctx, carried, index, h, _mm(h, p["w_qkv"]), p,
+            heads=(cfg.key_heads, H, cfg.linear_key_head_dim, dv),
+            beta_scale=2.0 if cfg.linear_allow_neg_eigval else 1.0)
         o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * p["o_norm_scale"].astype(f32)).astype(x.dtype)
         o = o * jax.nn.silu(_mm(h, p["w_ogate"])).reshape(-1, H, dv)

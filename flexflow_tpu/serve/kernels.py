@@ -2107,9 +2107,10 @@ def ssm_recur_c1(
 
 
 def _gdn_recur_kernel(lay, cnt, fr, cols_ref, vec_ref, s_ref, o_ref, out_ref,
-                      *, p: int):
+                      *, p: int, group: int):
     """One (row, block of packed heads) grid step. ``cols_ref`` (1, 1,
-    dk, 2 p heads): the block's k columns, then its q columns;
+    dk, 2 key heads of the block): the block's k columns, then its q
+    columns, one a KEY head, which ``group`` value heads in a row share;
     ``vec_ref`` (1, 4, heads, p dv): v, and the decay, the write
     strength and ``k . q`` on their head's lanes; ``o_ref`` (1, 1,
     heads, p dv); ``s_ref`` / ``out_ref`` (1, 1, heads, dk, p dv): the
@@ -2121,17 +2122,19 @@ def _gdn_recur_kernel(lay, cnt, fr, cols_ref, vec_ref, s_ref, o_ref, out_ref,
     lane = jax.lax.broadcasted_iota(jnp.int32, (dk, W), 1)
     of_head = [lane >= j * dv for j in range(1, p)]
 
-    def along_lanes(first):
-        """Columns ``first .. first + p`` of ``cols_ref``, each on the
-        dv lanes of its head: (dk, p dv)."""
-        x = jnp.broadcast_to(cols_ref[0, 0, :, first:first + 1], (dk, W))
+    def along_lanes(base, first):
+        """The columns of value heads ``first .. first + p`` (their key
+        heads', from column ``base`` of ``cols_ref`` on), each on the dv
+        lanes of its head: (dk, p dv)."""
+        at = lambda j: base + (first + j) // group
+        x = jnp.broadcast_to(cols_ref[0, 0, :, at(0):at(0) + 1], (dk, W))
         for j, mask in enumerate(of_head, 1):
-            x = jnp.where(mask, cols_ref[0, 0, :, first + j:first + j + 1], x)
+            x = jnp.where(mask, cols_ref[0, 0, :, at(j):at(j) + 1], x)
         return x
 
     for h in range(Hb):  # unrolled: a head's tile is what the registers see
         s0 = jnp.where(fresh, 0.0, s_ref[0, 0, h])
-        k, q = along_lanes(h * p), along_lanes((Hb + h) * p)
+        k, q = along_lanes(0, h * p), along_lanes(Hb * p // group, h * p)
         v, a, b, kq = (vec_ref[0, i, h:h + 1, :] for i in range(4))
         u = b * (v - a * jnp.sum(s0 * k, axis=0, keepdims=True))
         o_ref[0, 0, h:h + 1, :] = (
@@ -2146,8 +2149,8 @@ def _gdn_recur_kernel(lay, cnt, fr, cols_ref, vec_ref, s_ref, o_ref, out_ref,
 def gdn_recur_c1(
     states: jnp.ndarray,   # (L, R, H / p, dk, p dv) float32: the layers' stacked states
     layer,                 # int32 scalar (traced): the layer to step
-    q: jnp.ndarray,        # (R, H, dk) float32
-    k: jnp.ndarray,        # (R, H, dk) float32
+    q: jnp.ndarray,        # (R, Hk, dk) float32
+    k: jnp.ndarray,        # (R, Hk, dk) float32
     v: jnp.ndarray,        # (R, H, dv) float32
     a: jnp.ndarray,        # (R, H) float32: each head's decay exp(g)
     b: jnp.ndarray,        # (R, H) float32: its write strength
@@ -2157,33 +2160,44 @@ def gdn_recur_c1(
     """``models/olmo_hybrid.gated_delta`` at one column over the layer
     ``layer`` of the stack, in place: -> (o (R, 1, H / p, p dv)
     float32, ``states``); p, the heads side by side on the state's
-    lanes, is read off the shapes. The stack is aliased to the second
-    result and the layer named by a prefetched scalar in the block
-    index, as :func:`ssm_recur_c1` does; ``o`` is the FIRST result and
-    [slots, 1, ...] for the same reason. What is a token's and small
-    (the decay and the write strength a head, ``k . q``, their spread
-    over a head's lanes, the transpose of k and q) is the caller's XLA:
-    a few KB a row."""
+    lanes, is read off the shapes, and so is the group of H / Hk value
+    heads a key head serves (Olmo-Hybrid: 1; Qwen3-Next: 2): q and k
+    come and are read once a KEY head. The stack is aliased to the
+    second result and the layer named by a prefetched scalar in the
+    block index, as :func:`ssm_recur_c1` does; ``o`` is the FIRST
+    result and [slots, 1, ...] for the same reason. What is a token's
+    and small (the decay and the write strength a head, ``k . q``,
+    their spread over a head's lanes, the transpose of k and q) is the
+    caller's XLA: a few KB a row."""
     L, R, Hp, dk, W = states.shape
-    H = q.shape[1]
+    H, Hk = v.shape[1], q.shape[1]
     p, dv = H // Hp, v.shape[-1]
-    if Hp * p != H or p * dv != W or q.shape[-1] != dk:
+    group = H // Hk
+    if Hp * p != H or p * dv != W or q.shape[-1] != dk or group * Hk != H:
         raise ValueError(f"a state {states.shape} does not hold {H} heads of "
-                         f"{dk} x {dv}, {p} to a row of lanes")
+                         f"{dk} x {dv}, {p} to a row of lanes, {group} to a "
+                         "key head")
     Hb = ssm_recur_block(Hp, dk, W)
     nb = Hp // Hb
+    if Hb * p % group:
+        raise ValueError(f"a block of {Hb * p} value heads splits a group "
+                         f"of {group}")
     f32 = jnp.float32
     q, k, v, a, b = (x.astype(f32) for x in (q, k, v, a, b))
 
-    def columns(x):  # (R, H, dk) -> (R, blocks, dk, the block's heads)
-        return x.reshape(R, nb, Hb * p, dk).swapaxes(2, 3)
+    def columns(x):  # (R, Hk, dk) -> (R, blocks, dk, the block's key heads)
+        return x.reshape(R, nb, Hb * p // group, dk).swapaxes(2, 3)
 
     def on_lanes(x):  # (R, H) -> (R, H / p, p dv): a head's value on its lanes
         return jnp.repeat(x.reshape(R, Hp, p), dv, axis=-1)
 
+    def pair_dots():  # k . q of each value head's key head: (R, H)
+        kq = jnp.sum(k * q, axis=-1)
+        return kq if group == 1 else jnp.repeat(kq, group, axis=-1)
+
     cols = jnp.concatenate([columns(k), columns(q)], axis=-1)
     vec = jnp.stack([v.reshape(R, Hp, W), on_lanes(a), on_lanes(b),
-                     on_lanes(jnp.sum(k * q, axis=-1))], axis=1)
+                     on_lanes(pair_dots())], axis=1)
 
     def of_state(r, h, lay, cnt, fr):
         return (lay[0], r, h, 0, 0)
@@ -2191,12 +2205,12 @@ def gdn_recur_c1(
     block = pl.BlockSpec((1, 1, Hb, dk, W), of_state)
     rows = pl.BlockSpec((1, 1, Hb, W), lambda r, h, *_: (r, 0, h, 0))
     return pl.pallas_call(
-        functools.partial(_gdn_recur_kernel, p=p),
+        functools.partial(_gdn_recur_kernel, p=p, group=group),
         out_shape=[jax.ShapeDtypeStruct((R, 1, Hp, W), f32),
                    jax.ShapeDtypeStruct(states.shape, states.dtype)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(R, nb),
-            in_specs=[pl.BlockSpec((1, 1, dk, 2 * Hb * p),
+            in_specs=[pl.BlockSpec((1, 1, dk, 2 * Hb * p // group),
                                    lambda r, h, *_: (r, h, 0, 0)),
                       pl.BlockSpec((1, 4, Hb, W), lambda r, h, *_: (r, 0, h, 0)),
                       block],

@@ -688,6 +688,95 @@ def test_gdn_recurrence_kernel_compiles_at_the_cells_shapes(chip):
     assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
 
 
+# --- Gated DeltaNet at 2 value heads a key head, gated attention at head
+# --- size 256 and routed experts behind them (Qwen3-Next) ------------------
+
+
+def test_qwen3_next_decode_step_compiles_in_place(chip):
+    """models/qwen3_next.py at published widths (16 key and 32 value
+    heads of 128 x 128, 16 / 2 softmax heads of 256, experts of 512
+    chosen 10 of the router's 512, of which this chip holds 128, a
+    quarter of the vocabulary), one period of four layers, the
+    benchmark cell's 64 slots of 8 pages, the decode step: the delta
+    rule's kernel is the program's FIRST kernel call, once for the run
+    of three recurrent layers, on the state stack in place with ``o``
+    its first result ([slots, 1, ...]: the trace reduction keys the
+    step by it); the ragged paged kernel takes a pool line of 2 heads x
+    256 merged; the grouped expert matmuls run at the 16-row tile over
+    640 pairs' rows (1.25 rows an expert); nothing copies the K/V
+    pools, the state stack, the convolution states or a layer's
+    experts, and the temporaries are a few MB. The state's bytes as an
+    argument are its arithmetic (ROADMAP B's rule for a per-slot
+    float32 state: a minor extent of 128 is whole lane tiles, so
+    ``lane_pack`` is 1): 3 layers x 64 slots x 32 x 128 x 128 x 4 here,
+    9 layers' 1.21 GB at the cell's depth."""
+    from flexflow_tpu.models import qwen3_next as fam
+
+    cfg = fam.config(num_hidden_layers=4, experts_held=(0, 128),
+                     vocab_size=37984, dtype=jnp.bfloat16)
+    slots, pages, cache_len = 64, 8, 1024
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        num_slots=slots, cache_len=cache_len)), chip)
+    assert cache["state"].shape == (3, 64, 32, 128, 128)
+    assert cache["state"].dtype == jnp.float32 and fam.lane_pack(32, 128) == 1
+    assert cache["conv"].shape == (3, 3, 64, 8192)
+    assert cache["k"].shape == (1, slots * pages + 1, PAGE, 2 * 256)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=cache_len, kernels="pallas")
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, 1), jnp.int32),
+        chip((slots, 1), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+    calls = re.findall(
+        r"%(\w+?)(?:\.\d+)* = (.+?) custom-call\(.*tpu_custom_call", text)
+    tm, rows = kernels.grouped_tile(640, 128, 512), _pair_rows(640, 128, 512)
+    assert (tm, rows) == (16, 2560)
+    glu = (f"ff_moe_grouped_glu_t{tm}", f"bf16[{rows},512]")
+    down = (f"ff_moe_grouped_down_t{tm}", f"f32[{rows},2048]")
+    want = [("ff_gdn_recur_c1", "(f32[64,1,32,128]"), glu, down,
+            ("ff_ragged_paged_c1", "bf16[64,1,2,8,256]"), glu, down]
+    assert [name for name, _ in calls] == [name for name, _ in want], calls
+    for (_, shape), (_, starts) in zip(calls, want):
+        assert shape.startswith(starts), calls
+    assert "f32[3,64,32,128,128]" in calls[0][1]   # the stack through the call
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["k"], cache["v"], cache["state"], cache["conv"], experts,
+              jax.ShapeDtypeStruct(cache["state"].shape[1:], jnp.float32),
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+    held, _ = _compile(lambda s: s + 1.0, chip(cache["state"].shape, jnp.float32),
+                       donate=(0,))
+    assert (held.memory_analysis().argument_size_in_bytes
+            == 3 * 64 * 32 * 128 * 128 * 4 == 402_653_184)
+
+
+def test_ragged_kernel_compiles_at_head_size_256(chip):
+    """Mosaic takes ``ff_ragged_paged_c128`` at Qwen3-Next's full layer:
+    64 rows of 128 queries, 16 query heads on 2 K/V heads of 256, a
+    pool line of 512 merged on the minor axis, the cell's 8 pages a
+    row, told each row's real queries; both K/V heads one grid step."""
+    slots, pages, C = 64, 8, 128
+    pool = chip((3 * (slots * pages + 1), PAGE, 2 * 256), jnp.bfloat16)
+    compiled, text = _compile(
+        lambda q, k, v, table, mask, at, n: kernels.ragged_paged_attention(
+            q, k, v, table, mask, row_offset=at, q_len=n),
+        chip((slots, C, 16, 256), jnp.bfloat16), pool, pool,
+        chip((slots, pages), jnp.int32), chip((slots, C, pages * PAGE), jnp.bool_),
+        chip((), jnp.int32), chip((slots,), jnp.int32))
+    call, = re.findall(r"%(\w+?)(?:\.\d+)* = (\S+) custom-call\(.*tpu_custom_call", text)
+    assert call[0] == "ff_ragged_paged_c128" and call[1].startswith("bf16[64,128,2,8,256]")
+
+
 # --- Mamba-2 layers beside attention (Granite 4.0-H) --------------------------
 
 

@@ -22,6 +22,7 @@ from flexflow_tpu.models import (
     mistral,
     mixtral,
     olmo_hybrid,
+    qwen3_next,
     smallthinker,
 )
 from flexflow_tpu.obs import sublayers
@@ -49,6 +50,8 @@ FAMILIES = {
     "granite_hybrid": (granite_hybrid, ALWAYS | {"ff.mixer"}),
     # full and window layers alike, the router at the top of the block
     "smallthinker": (smallthinker, ALWAYS | {"ff.moe.route"}),
+    # routed experts behind a recurrent mixer: both beside attention
+    "qwen3_next": (qwen3_next, ALWAYS | {"ff.mixer", "ff.moe.route"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
