@@ -78,6 +78,14 @@ half the int8 bytes and unpack two nibble codes per byte in VMEM
 backend) before the same scale-folded dots; the fused write side packs
 through the in-kernel twin of ``kv_quant.pack_nibbles``.
 
+Every variant, chunk and family runs ONE attention body, in the layout
+its matmuls give ((KV heads, a head's query rows, lines or head size)),
+on operands in the pool's dtype accumulated in float32, with the
+softmax state replicated along a lane tile
+(:func:`_build_ragged_paged_kernel`); the unfused call is built as one
+jitted function a static shape (:func:`_ragged_call`), so a process
+traces the body once a shape and not once a call site.
+
 Every fused variant is bitwise-identical to its unfused counterpart on
 the same backend: the builder reuses one attention body (same op
 order, same online-softmax accumulation over the same (request, page)
@@ -201,18 +209,20 @@ def _quant_suffix(quant: bool, pack: int) -> str:
     return "_int4" if pack == 2 else "_int8"
 
 
-def _unpack_codes(block: jnp.ndarray, pack: int) -> jnp.ndarray:
-    """Stored code block → f32 code values: identity cast for pack=1
-    (int8), nibble unpack for pack=2 (uint8 int4 pages — op-for-op
-    ``kv_quant.unpack_nibbles``: low nibble = head-dim entries
+def _unpack_codes(block: jnp.ndarray, pack: int,
+                  dtype=jnp.float32) -> jnp.ndarray:
+    """Stored code block → code values in ``dtype`` (float32, or
+    bfloat16, which holds every int8 code exactly): identity cast for
+    pack=1 (int8), nibble unpack for pack=2 (uint8 int4 pages —
+    op-for-op ``kv_quant.unpack_nibbles``: low nibble = head-dim entries
     [0, dk/2), high nibble = [dk/2, dk), bias +8; integer arithmetic,
     so the Pallas and XLA paths decode identical values)."""
     if pack == 1:
-        return block.astype(jnp.float32)
+        return block.astype(dtype)
     b = block.astype(jnp.int32)
     lo = (b & 0xF) - 8
     hi = ((b >> 4) & 0xF) - 8
-    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
+    return jnp.concatenate([lo, hi], axis=-1).astype(dtype)
 
 
 def _pack_codes(codes: jnp.ndarray, dtype, pack: int) -> jnp.ndarray:
@@ -306,6 +316,48 @@ def _rope_rotate(x, cos, sin):
     return out.astype(x.dtype)
 
 
+#: lanes of the softmax state of the ragged paged and the latent kernel:
+#: the running maximum and sum of a row are kept replicated along one
+#: whole lane tile, so that reading them against the scores and the
+#: accumulator moves no data (held as (rows, 1) arrays they cost the
+#: latent kernel as much as the rest of its step, PERF.md section 6, PR
+#: 43; with the group on the lanes, (C, KV, G), the ragged kernel paid a
+#: relayout of the scores to meet them, PR 55)
+STATE_LANES = 128
+
+
+def _along_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """A state replicated along its lanes, (..., STATE_LANES), at
+    ``width`` lanes: whole lane tiles side by side, which moves no
+    data, or its first lanes (a head size under a lane tile; the tests'
+    few lines a page)."""
+    lanes = x.shape[-1]
+    if width % lanes == 0:
+        return jnp.tile(x, (1,) * (x.ndim - 1) + (width // lanes,))
+    if width < lanes:
+        return x[..., :width]
+    return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (width,))
+
+
+def _ragged_scratch(C: int, KV: int, G: int, q, pool) -> list:
+    """The ragged paged kernel's scratch at ``KV`` heads a grid step: the
+    float32 accumulator, running maximum and running sum in the layout
+    its matmuls give, a head's C*G query rows by dk or by a lane tile,
+    and the queries in that layout in the DOTS' dtype: q's and the
+    pool's own where they are one (the cells' bf16, the CPU tests'
+    float32), the wider where they differ, q's over a quantized pool's
+    codes."""
+    dk = q.shape[-1]
+    dots = (q.dtype if jnp.issubdtype(pool.dtype, jnp.integer)
+            else jnp.promote_types(q.dtype, pool.dtype))
+    return [
+        pltpu.VMEM((KV, C * G, dk), jnp.float32),
+        pltpu.VMEM((KV, C * G, STATE_LANES), jnp.float32),
+        pltpu.VMEM((KV, C * G, STATE_LANES), jnp.float32),
+        pltpu.VMEM((KV, C * G, dk), dots),
+    ]
+
+
 def _lanes_to_major(x):
     """(n,) → (n, 1, 1), exactly, without the lane→leading-dim reshape
     Mosaic refuses ("unsupported shape cast"): broadcast the lane vector
@@ -356,82 +408,148 @@ def _build_ragged_paged_kernel(
     :func:`_ragged_paged_attention`), and the body takes each head's dk
     lanes out after the load. ``head_blocks`` (the plain kernel): the
     grid has a leading axis over blocks of KV heads, so rows and pages
-    are its axes 1 and 2 (:func:`_ragged_paged_attention`)."""
+    are its axes 1 and 2 (:func:`_ragged_paged_attention`; since
+    :func:`_vmem_bytes` counts a block as Mosaic tiles it no cell's call
+    is split, Olmo's 30 heads are one grid step, and only
+    tests/test_olmo_hybrid.py, which lowers the ceiling, takes this
+    path: a later simplicity PR may remove it).
+
+    ONE attention body (``_attend``) for every variant, whose tile
+    shapes follow what it sees in its operands (n, G, dk, ps, the
+    pool's rank and dtype). A grid step's values stay in the layout its
+    matmuls give, a KV head's M = G*n query rows (head g of the group
+    at rows g*n on) by the page's lines or the head size: the queries
+    are brought to (KV, M, dk) once a row, at its first page, into a
+    scratch in the dots' dtype; both dots take their operands in the
+    POOL's dtype and accumulate in float32 (a float32 pool stays exact;
+    a quantized pool's codes go to q's dtype, which holds them exactly,
+    and the dots' outputs are scaled), the probabilities cast to it for
+    the second; the mask block, (n, ps), meets the scores as a float32
+    bias, G whole copies of itself down the rows, and not the scores
+    the mask's layout; the accumulator is (KV, C*G, dk) float32 and the
+    running maximum and sum lie replicated along a lane tile, (KV, C*G,
+    STATE_LANES), read against the scores and the accumulator as whole
+    lane tiles (:func:`_along_lanes`). A chunk-wide step runs each KV
+    head as a chain of its own (dot, softmax, dot, update) and a decode
+    or narrow step the heads' dots batched (``_attend`` says why). The
+    one relayout of a (.., G, dk) value a page used to pay three times
+    is the result's, once a row at its last page (``_finalize``: (KV,
+    G*n, dk) to the call's (n, KV, G, dk))."""
     narrow = narrow_query_extent(C)
     row_axis, page_axis = (1, 2) if head_blocks else (0, 1)
 
-    def _heads_major(block):
-        # a loaded page block as (KV, ps, dk) float32
-        x = _unpack_codes(block, pack)
+    def _query_rows(q):
+        # (n, KV, G, dk) -> (KV, G*n, dk): the queries as the rows of a
+        # KV head's matmuls, head g of the group at rows g*n on (so the
+        # (n, ps) mask block meets the scores as G whole copies of
+        # itself, :func:`_score_bias`). Once a row (at its first page,
+        # into ``q_scr``), in q's dtype
+        n, KV, G, dk = q.shape
+        return q.transpose(1, 2, 0, 3).reshape(KV, G * n, dk)
+
+    def _heads_major(block, dtype):
+        # a loaded page block as (KV, ps, dk) in the dots' dtype
+        # (_ragged_scratch): as it is loaded, or a quantized pool's
+        # codes in q's dtype, which holds them exactly
+        x = _unpack_codes(block, pack, dtype) if quant else block.astype(dtype)
         if not merged_heads:
             return x.transpose(1, 0, 2)
         dk = x.shape[-1] // merged_heads
         return jnp.stack([x[:, h * dk:(h + 1) * dk]
                           for h in range(merged_heads)], axis=0)
 
-    def _masked(mask, x, fill):
-        # x (C, KV, G, ps). One mask: (C, ps), every head alike. A mask
-        # a group: a list of KV (C, ps) masks, each loaded from its own
-        # rows of the (KV*C, ps) block and applied to its own group, so
-        # Mosaic sees only the mask layout the one-mask path has (a
-        # (KV, C, ps) block, or a one-row slice of a loaded i1 vector,
-        # is a "layout with implicit dimension" at C=1)
-        if not group_mask:
-            return jnp.where(mask[:, None, None, :], x, fill)
-        return jnp.concatenate(
-            [jnp.where(m[:, None, None, :], x[:, kv:kv + 1], fill)
-             for kv, m in enumerate(mask)], axis=1)
+    def _score_bias(mask, G):
+        # the (n, ps) mask block as what is ADDED to a head's (G*n, ps)
+        # scores, 0 where a query sees a key and NEG_INF where not (a
+        # score plus NEG_INF is NEG_INF to the bit): made on the block,
+        # then G whole copies of it down the rows, which moves no data
+        # (Mosaic broadcasts no boolean along sublanes, and a select
+        # would be a pass more over the scores than the add)
+        return jnp.tile(jnp.where(mask, 0.0, NEG_INF), (G, 1))
 
-    def _attend(q, k, v, ks, vs, mask, o_scr, m_scr, l_scr):
-        # q (n, KV, G, dk) f32; k/v (KV, ps, dk) f32; ks/vs (KV, 1, 1)
-        # f32 (quant only); one batched dot per KV head over the
-        # grouped (KV, n*G, dk) query layout. n is the chunk C or the
-        # narrow body's extent: the accumulators' first n rows are
-        # read and written (slices of the loads and stores; Mosaic
-        # refuses a sliced VIEW of a scratch whose minor extent, G, is
-        # under a lane tile)
-        n, KV, G = q.shape[:3]
-        qkv = q.transpose(1, 0, 2, 3).reshape(KV, n * G, q.shape[-1])
-        scores = jax.lax.dot_general(
-            qkv, k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                           # (KV, n*G, ps)
-        if quant:
-            scores = scores * (ks * scale)          # dequant K
-        else:
-            scores = scores * scale
-        scores = scores.reshape(KV, n, G, -1).transpose(1, 0, 2, 3)
-        scores = _masked(mask, scores, NEG_INF)
-        m_new = jnp.maximum(m_scr[:n], scores.max(axis=-1))
-        prob = jnp.exp(scores - m_new[..., None])
-        prob = _masked(mask, prob, 0.0)
-        corr = jnp.exp(m_scr[:n] - m_new)
-        l_scr[:n] = l_scr[:n] * corr + prob.sum(axis=-1)
-        pk = prob.transpose(1, 0, 2, 3).reshape(KV, n * G, -1)
-        pv = jax.lax.dot_general(
-            pk, v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (KV, n*G, dk)
-        if quant:
-            pv = pv * vs                            # dequant V
-        pv = pv.reshape(KV, n, G, -1).transpose(1, 0, 2, 3)
-        o_scr[:n] = o_scr[:n] * corr[..., None] + pv
-        m_scr[:n] = m_new
+    def _biased(bias, x, h):
+        # x (heads, M, ps), the scores of KV heads h on. One mask, (M,
+        # ps): every head alike. A mask a group: a list of KV such, each
+        # added to its own head's rows
+        if not group_mask:
+            return x + bias[None]
+        return jnp.concatenate(
+            [x[i:i + 1] + bias[h + i][None] for i in range(x.shape[0])],
+            axis=0)
+
+    def _attend(q, k, v, ks, vs, bias, o_scr, m_scr, l_scr):
+        # q (KV, M, dk), M = G*n rows a head; k/v (KV, ps, dk), both in
+        # the dots' dtype; ks/vs (KV, 1, 1) f32 (quant only); bias at the
+        # scores' rows (_score_bias). The scores, the probabilities and
+        # the accumulator stay in the (heads, M, .) layout the dots give;
+        # the running maximum and sum lie replicated along a lane tile,
+        # (KV, M, STATE_LANES). n is the chunk C or the narrow body's
+        # extent: the state's first M rows are read and written. A row
+        # that has seen no key yet (its maximum is NEG_INF still)
+        # gathers probabilities of one, which the first key it sees
+        # wipes (``corr`` is 0 then) and _finalize reads as nothing.
+        #
+        # Where a head's rows fill a page's lines (M >= ps: a chunk-wide
+        # step) each KV head is a chain of its own, dot, softmax, dot,
+        # update, one head after the other; under that (a decode or
+        # narrow step) the heads' dots are one batched dot. Written as
+        # one batched chain over all heads, a chunk-wide step's (KV, M,
+        # ps) intermediates are each many times the vector registers
+        # and the compiler's schedule spills them between the passes:
+        # 4192 vector stores in 4944 instruction bundles at 4 heads x
+        # 1024 rows, the one store a bundle binding, against 3397 in
+        # 3799 a head at a time (5768 bundles with the float32 operands
+        # before PR 55; the compiled kernel's own listing for a v5e,
+        # which the chip's times follow: PERF.md section 6, PR 55)
+        KV, M, dk = q.shape
+        ps = k.shape[1]
+        heads = 1 if M >= ps else KV
+        for h in range(0, KV, heads):
+            hs = slice(h, h + heads)
+            scores = jax.lax.dot_general(
+                q[hs], k[hs],
+                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )                                       # (heads, M, ps)
+            # a quantized pool: dequant K on the dot's output
+            scores = scores * (ks[hs] * scale if quant else scale)
+            scores = _biased(bias, scores, h)
+            m_old = m_scr[hs, :M]
+            m_new = jnp.maximum(m_old, scores.max(axis=-1, keepdims=True))
+            prob = jnp.exp(scores - _along_lanes(m_new, ps)).astype(v.dtype)
+            corr = jnp.exp(m_old - m_new)
+            m_scr[hs, :M] = m_new
+            l_scr[hs, :M] = (l_scr[hs, :M] * corr
+                             + prob.astype(jnp.float32).sum(axis=-1, keepdims=True))
+            pv = jax.lax.dot_general(
+                prob, v[hs],
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )                                       # (heads, M, dk)
+            if quant:
+                pv = pv * vs[hs]                    # dequant V
+            o_scr[hs, :M] = o_scr[hs, :M] * _along_lanes(corr, dk) + pv
 
     def _init(o_scr, m_scr, l_scr, n=C):
-        o_scr[:n] = jnp.zeros((n,) + o_scr.shape[1:], o_scr.dtype)
-        m_scr[:n] = jnp.full((n,) + m_scr.shape[1:], NEG_INF, m_scr.dtype)
-        l_scr[:n] = jnp.zeros((n,) + l_scr.shape[1:], l_scr.dtype)
+        M = n * (o_scr.shape[1] // C)
+        for scr, fill in ((o_scr, 0.0), (m_scr, NEG_INF), (l_scr, 0.0)):
+            scr[:, :M] = jnp.full((scr.shape[0], M, scr.shape[2]), fill,
+                                  scr.dtype)
 
-    def _finalize(p, out_ref, o_scr, l_scr, n=C):
-        # the rows past a narrow body's n never attended and are what
-        # _init and this divide would give them: zero
+    def _finalize(p, out_ref, o_scr, m_scr, l_scr, n=C):
+        # the one relayout of a (.., G, dk) value: the result's, once a
+        # row. A query that saw no key (a padding column) comes out
+        # zero; the rows past a narrow body's n never attended and are
+        # zero too
         @pl.when(p == pl.num_programs(page_axis) - 1)
         def _():
-            l = jnp.maximum(l_scr[:n], 1e-20)
-            out_ref[0, :n] = (o_scr[:n] / l[..., None]).astype(out_ref.dtype)
+            KV, G, dk = out_ref.shape[2:]
+            M = G * n
+            l = jnp.maximum(l_scr[:, :M], 1e-20)
+            o = jnp.where(_along_lanes(m_scr[:, :M], dk) > NEG_INF,
+                          o_scr[:, :M] / _along_lanes(l, dk), 0.0)
+            out_ref[0, :n] = o.reshape(KV, G, n, dk).transpose(
+                2, 0, 1, 3).astype(out_ref.dtype)
             if n < C:
                 out_ref[0, n:] = jnp.zeros(
                     (C - n,) + out_ref.shape[2:], out_ref.dtype)
@@ -484,17 +602,19 @@ def _build_ragged_paged_kernel(
             vs_ref = refs[i]; i += 1
         mask_ref = refs[i]; i += 1      # (1, C, ps)
         out_ref = refs[i]; i += 1       # (1, C, KV, G, dk)
-        o_scr, m_scr, l_scr = refs[i:i + 3]
+        o_scr, m_scr, l_scr, q_scr = refs[i:i + 4]
+        G = q_ref.shape[3]
 
         p = pl.program_id(page_axis)
 
         def step(n, q_len=None):
             # this grid step over the row's first n queries: n is a
-            # leading extent of the query, mask and output blocks and of
-            # the accumulators, so taking their first n rows is free
+            # leading extent of the query, mask and output blocks, and
+            # n*G one of the state's rows, so taking them is free
             @pl.when(p == 0)
             def _():
                 _init(o_scr, m_scr, l_scr, n)
+                q_scr[:, :n * G] = _query_rows(q_ref[0, :n]).astype(q_scr.dtype)
 
             # one (n, ps) mask, or one a KV group from its own rows of
             # the (KV*C, ps) block — already bounded: S_virt = NP*ps
@@ -508,15 +628,16 @@ def _build_ragged_paged_kernel(
 
             @pl.when(some)
             def _():
-                q = q_ref[0, :n].astype(jnp.float32)
-                k = _heads_major(k_ref[0])
-                v = _heads_major(v_ref[0])
+                q = q_scr[:, :n * G]
+                k = _heads_major(k_ref[0], q.dtype)
+                v = _heads_major(v_ref[0], q.dtype)
                 ks = ks_ref[0] if quant else None
                 vs = vs_ref[0] if quant else None
-                _attend(q, k, v, ks, vs, mask if group_mask else mask[0],
+                bias = [_score_bias(m, G) for m in mask]
+                _attend(q, k, v, ks, vs, bias if group_mask else bias[0],
                         o_scr, m_scr, l_scr)
 
-            _finalize(p, out_ref, o_scr, l_scr, n)
+            _finalize(p, out_ref, o_scr, m_scr, l_scr, n)
 
         if q_len_ref is None:
             step(C)
@@ -554,7 +675,7 @@ def _build_ragged_paged_kernel(
             ks_out = refs[i]; i += 1    # (1, 1, KV) aliased scale row
             vs_out = refs[i]; i += 1
         o_scr, m_scr, l_scr = refs[i:i + 3]
-        q_scr = refs[i + 3]             # (C, KV, G, dk) roped q, q dtype
+        q_scr = refs[i + 3]             # (KV, C*G, dk) roped q, q dtype
         k_scr = refs[i + 4]             # (C, KV, dk) roped k, k dtype
 
         r = pl.program_id(0)
@@ -569,14 +690,14 @@ def _build_ragged_paged_kernel(
             if has_rope:
                 cos = cos_ref[0]        # (C, rot) f32
                 sin = sin_ref[0]
-                q_scr[:] = _rope_rotate(
+                q_scr[:] = _query_rows(_rope_rotate(
                     q_ref[0], cos[:, None, None, :], sin[:, None, None, :]
-                )
+                )).astype(q_scr.dtype)
                 k_scr[:] = _rope_rotate(
                     kn_ref[0], cos[:, None, :], sin[:, None, :]
                 )
             else:
-                q_scr[:] = q_ref[0]
+                q_scr[:] = _query_rows(q_ref[0]).astype(q_scr.dtype)
                 k_scr[:] = kn_ref[0]
 
         # ---- prologue: commit this row's fresh K/V lines landing in
@@ -609,14 +730,15 @@ def _build_ragged_paged_kernel(
 
         @pl.when(jnp.any(mask))
         def _():
-            q = q_scr[:].astype(jnp.float32)
+            q = q_scr[:]
             # attention reads the page through the freshly written
             # block — the fresh K/V never left VMEM
-            k = _unpack_codes(k_out[0], pack).transpose(1, 0, 2)
-            v = _unpack_codes(v_out[0], pack).transpose(1, 0, 2)
-            _attend(q, k, v, ks_att, vs_att, mask, o_scr, m_scr, l_scr)
+            k = _heads_major(k_out[0], q.dtype)
+            v = _heads_major(v_out[0], q.dtype)
+            _attend(q, k, v, ks_att, vs_att,
+                    _score_bias(mask, q_ref.shape[3]), o_scr, m_scr, l_scr)
 
-        _finalize(p, out_ref, o_scr, l_scr)
+        _finalize(p, out_ref, o_scr, m_scr, l_scr)
 
     return fused_kernel if fused else plain_kernel
 
@@ -631,12 +753,30 @@ _VMEM_SCOPE_DEFAULT = 16 << 20
 _VMEM_SCOPE_CEILING = 96 << 20
 
 
+#: float32 bytes of the scores of one grid step of the ragged paged
+#: kernel over a pool with merged heads, (heads of the block x C x G,
+#: ps): the head block is bounded by them first and by the VMEM ceiling
+#: second. Mosaic keeps the scores in registers and spill slots, and a
+#: body whose scores outgrow them is refused only after a quarter of an
+#: hour of compiling (the latent kernel's :data:`MLA_SCORES_BYTES`,
+#: PERF.md section 6, PR 43); every cell's call is at 2 MiB or under
+RAGGED_SCORES_BYTES = 4 << 20
+
+
 def _vmem_bytes(shape, dtype) -> int:
-    """VMEM bytes of one buffer: the last two dims pad to the dtype's
-    (sublane, 128-lane) tile; bools are held as int32."""
+    """VMEM bytes of one buffer: the last two dims pad to a (sublane,
+    128-lane) tile, the dtype's own (8 rows of 32 bits, so 16 of bf16)
+    or, for a second-to-last dim under that, the power of two that
+    holds it (Mosaic tiles ``bf16[.., 1, 128]`` as (2, 128): a group of
+    one query head is padded twice over, not sixteen times); bools are
+    held as int32. :func:`mla_paged_attention` states its need through
+    this too: every buffer of its call has its second-to-last dim at or
+    over the tile, so its statement did not move (pinned at the
+    DeepSeek cell's shapes in tests/test_deepseek_v3.py)."""
     item = 4 if dtype == jnp.bool_ else jnp.dtype(dtype).itemsize
     *lead, sub, lane = (1, 1) + tuple(shape)
-    tile = 8 * max(1, 4 // item)
+    pack = max(1, 4 // item)
+    tile = min(8 * pack, max(pack, 1 << (sub - 1).bit_length()))
     return (math.prod(lead) * (-(-sub // tile) * tile)
             * (-(-lane // 128) * 128) * item)
 
@@ -651,16 +791,18 @@ def _scale_rows(scale: jnp.ndarray) -> jnp.ndarray:
 
 def _ragged_vmem_need(specs, arrays, scratch, C, H, width) -> int:
     """VMEM bytes a grid step of a ragged paged kernel: its blocks
-    double-buffered, its scratch, and the attention body's float32
-    intermediates — each one (C, H, max(dk, ps)) tile, about a dozen
-    live at the widest point (q and its grouped transpose, the scores,
-    their masked/exponentiated/transposed forms, pv)."""
+    double-buffered, its scratch (:func:`_ragged_scratch`), and the
+    attention body's float32 intermediates — each one (C * H, max(dk,
+    ps)) tile, about eight live at the widest point (the scores, their
+    masked and exponentiated forms and the mask at their rows, the
+    probabilities in the pool's dtype, pv and the rescaled
+    accumulator)."""
     need = 2 * sum(
         _vmem_bytes(spec.block_shape, a.dtype)
         for spec, a in zip(specs, arrays)
     )
     need += sum(_vmem_bytes(s.shape, s.dtype) for s in scratch)
-    return need + 12 * _vmem_bytes((C * H, width), jnp.float32)
+    return need + 8 * _vmem_bytes((C * H, width), jnp.float32)
 
 
 def _ragged_vmem_limit(specs, arrays, scratch, C, H, width) -> int:
@@ -795,26 +937,21 @@ def _ragged_paged_attention(
     pages that a query may see, not over the context's 128."""
     R, C, H, dk = q.shape
     merged = k_pool.ndim == 3  # (P+1, ps, KV*dk): heads on the minor axis
+    quant = k_scale is not None
     if merged:
-        if k_scale is not None or group_mask:
+        if quant or group_mask:
             raise NotImplementedError(
                 "a pool with merged heads is neither quantized nor read "
                 "under a mask a KV group")
-        ps, KV, dkp = k_pool.shape[1], k_pool.shape[2] // dk, dk
+        KV, dkp = k_pool.shape[2] // dk, dk
     else:
-        _, ps, KV, dkp = k_pool.shape  # dkp = dk / pack (int4 packs 2)
-    NP = page_table.shape[1]
+        KV, dkp = k_pool.shape[2:]  # dkp = dk / pack (int4 packs 2)
     G = H // KV
-    pack = dk // dkp if k_scale is not None else 1
+    pack = dk // dkp if quant else 1
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
-    qg = q.reshape(R, C, KV, G, dk)
-    prefetch = [page_table.astype(jnp.int32)]
-    if row_offset is not None:
-        prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
-    if q_len is not None:  # last, so the index maps' ``base`` stays put
-        prefetch.append(q_len.astype(jnp.int32))
-    operands = [qg, k_pool, v_pool]
-    if k_scale is not None:
+    ps = k_pool.shape[1]
+    operands = [q.reshape(R, C, KV, G, dk), k_pool, v_pool]
+    if quant:
         # per-page scales as (P+1, KV, 1, 1): the block hands the body a
         # (KV, 1, 1) value that broadcasts over the (KV, C*G, ps) scores
         # as it is — Mosaic has no relayout from a (1, KV) lane vector
@@ -823,49 +960,14 @@ def _ragged_paged_attention(
             k_scale.astype(jnp.float32)[:, :, None, None],
             v_scale.astype(jnp.float32)[:, :, None, None],
         ]
-    rows = KV * C if group_mask else C  # (R, KV, C, S) as (R, KV*C, S)
-    operands.append(mask.reshape(R, rows, -1))
-    out_shape = jax.ShapeDtypeStruct((R, C, KV, G, dk), q.dtype)
-
-    def blocks_of(KVb):
-        """(grid, in_specs, out_spec, scratch) with ``KVb`` KV heads a
-        grid step: all of them on the (row, page) grid, or a block of
-        them under a leading grid axis over the blocks (merged pools:
-        a block's lanes of a line are a block of the minor axis)."""
-        split = KVb < KV
-
-        def at(index_map):  # (head block, row, page, *prefetch) -> block
-            if split:
-                return index_map
-            return lambda r, p, *pre: index_map(0, r, p, *pre)
-
-        def page(b, r, p, pt, *base):
-            # the paged gather: block row = page_table[r, p] (+ row_offset)
-            row = pt[r, p] + base[0][0] if row_offset is not None else pt[r, p]
-            return (row, 0, b) if merged else (row,) + (0,) * (k_pool.ndim - 1)
-
-        heads = at(lambda b, r, p, *_: (r, 0, b, 0, 0))
-        lines = (1, ps, KVb * dk) if merged else (1, ps, KV, dkp)
-        in_specs = [
-            pl.BlockSpec((1, C, KVb, G, dk), heads),
-            pl.BlockSpec(lines, at(page)),
-            pl.BlockSpec(lines, at(page)),
-        ]
-        if k_scale is not None:
-            in_specs += [pl.BlockSpec((1, KV, 1, 1), at(page))] * 2
-        in_specs.append(pl.BlockSpec(
-            (1, rows, ps), at(lambda b, r, p, *_: (r, 0, p))))
-        out_spec = pl.BlockSpec((1, C, KVb, G, dk), heads)
-        scratch = [
-            pltpu.VMEM((C, KVb, G, dk), jnp.float32),
-            pltpu.VMEM((C, KVb, G), jnp.float32),
-            pltpu.VMEM((C, KVb, G), jnp.float32),
-        ]
-        grid = (KV // KVb, R, NP) if split else (R, NP)
-        return grid, in_specs, out_spec, scratch
+    # a mask a KV group, (R, KV, C, S), as (R, KV*C, S)
+    operands.append(mask.reshape(R, KV * C if group_mask else C, -1))
+    blocks = functools.partial(
+        _ragged_blocks, operands, page_table.shape[1], quant=quant,
+        has_offset=row_offset is not None)
 
     def vmem(KVb, need=_ragged_vmem_limit):
-        _, in_specs, out_spec, scratch = blocks_of(KVb)
+        _, in_specs, out_spec, scratch, out_shape = blocks(KVb)
         return need(in_specs + [out_spec], operands + [out_shape], scratch,
                     C, KVb * G, max(dk, ps))
 
@@ -875,21 +977,111 @@ def _ragged_paged_attention(
     # them that does, the same kernel name and result
     KVb = KV
     if merged:
-        KVb = next((KV // n for n in range(1, KV + 1) if KV % n == 0
-                    and vmem(KV // n, _ragged_vmem_need) <= _VMEM_SCOPE_CEILING),
-                   1)
-    grid, in_specs, out_spec, scratch = blocks_of(KVb)
+        fits = lambda b: (
+            b * G * C * ps * 4 <= RAGGED_SCORES_BYTES
+            and vmem(b, _ragged_vmem_need) <= _VMEM_SCOPE_CEILING)
+        KVb = next((KV // n for n in range(1, KV + 1)
+                    if KV % n == 0 and fits(KV // n)), 1)
+    call = _ragged_call(
+        ("ff_sparse_paged" if group_mask else "ff_ragged_paged")
+        + f"_c{C}" + _quant_suffix(quant, pack) + tag,
+        float(scale), group_mask, KVb, vmem(KVb), _interpret())
+    if row_offset is not None:  # one trace, a Python int or a traced scalar
+        row_offset = jnp.asarray(row_offset, jnp.int32)
+    return call(page_table, row_offset, q_len, *operands).reshape(R, C, H, dk)
+
+
+def _ragged_blocks(operands, NP: int, KVb: int, *, quant: bool,
+                   has_offset: bool):
+    """(grid, in_specs, out_spec, scratch, out_shape) of the ragged
+    paged call over ``operands`` (q (R, C, KV, G, dk), the pools, a
+    quantized pool's scales, the mask) with ``KVb`` KV heads a grid
+    step: all of them on the (row, page) grid, or a block of them under
+    a leading grid axis over the blocks (merged pools: a block's lanes
+    of a line are a block of the minor axis)."""
+    qg, k_pool, mask = operands[0], operands[1], operands[-1]
+    R, C, KV, G, dk = qg.shape
+    merged = k_pool.ndim == 3
+    ps, rows = k_pool.shape[1], mask.shape[1]
+    split = KVb < KV
+
+    def at(index_map):  # (head block, row, page, *prefetch) -> block
+        if split:
+            return index_map
+        return lambda r, p, *pre: index_map(0, r, p, *pre)
+
+    def page(b, r, p, pt, *base):
+        # the paged gather: block row = page_table[r, p] (+ row_offset)
+        row = pt[r, p] + base[0][0] if has_offset else pt[r, p]
+        return (row, 0, b) if merged else (row,) + (0,) * (k_pool.ndim - 1)
+
+    heads = at(lambda b, r, p, *_: (r, 0, b, 0, 0))
+    lines = (1, ps, KVb * dk) if merged else (1,) + k_pool.shape[1:]
+    in_specs = [
+        pl.BlockSpec((1, C, KVb, G, dk), heads),
+        pl.BlockSpec(lines, at(page)),
+        pl.BlockSpec(lines, at(page)),
+    ]
+    if quant:
+        in_specs += [pl.BlockSpec((1, KV, 1, 1), at(page))] * 2
+    in_specs.append(pl.BlockSpec(
+        (1, rows, ps), at(lambda b, r, p, *_: (r, 0, p))))
+    out_spec = pl.BlockSpec((1, C, KVb, G, dk), heads)
+    scratch = _ragged_scratch(C, KVb, G, qg, k_pool)
+    grid = (KV // KVb, R, NP) if split else (R, NP)
+    return (grid, in_specs, out_spec, scratch,
+            jax.ShapeDtypeStruct(qg.shape, qg.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _ragged_call(name: str, scale: float, group_mask: bool, KVb: int,
+                 vmem_limit: int, interpret: bool):
+    """-> the jitted ``call(page_table, row_offset, q_len, q, k_pool,
+    v_pool, [k_scale, v_scale,] mask)`` of the ragged paged kernel
+    ``name`` (:func:`_ragged_paged_attention`, which brings the
+    operands to the call's form): ONE jitted function a static shape, so
+    every call site of a step program (an unrolled family's three or
+    twelve), every rung, head variant and probe sibling traces the
+    kernel's body once a process and a program lowers it once, where
+    ``pl.pallas_call`` alone hands back a new function, and so a new
+    trace, at every call (PERF.md section 6, PR 55). What else a trace
+    reads is in the operands' shapes and dtypes (the chunk, the heads,
+    a quantized pool and its pack, merged heads) or in which of
+    ``row_offset`` / ``q_len`` are None, and ``jax.jit`` keys its own
+    cache on those. Everything a test patches is in the KEY BY VALUE,
+    so no entry can go stale: ``interpret`` is ``_interpret()`` as the
+    caller read it (tests/test_chip_compile.py's ``chip`` fixture), and
+    the head block ``KVb`` and ``vmem_limit`` are computed by the
+    caller at every call from ``_ragged_vmem_need`` and
+    ``_VMEM_SCOPE_CEILING`` as they stand (tests/test_olmo_hybrid.py)."""
+    return jax.jit(functools.partial(
+        _ragged, name, scale, group_mask, KVb, vmem_limit, interpret))
+
+
+def _ragged(name, scale, group_mask, KVb, vmem_limit, interpret,
+            page_table, row_offset, q_len, *operands):
+    qg, k_pool, _, *scales, _ = operands
+    C, KV, dk = qg.shape[1], qg.shape[2], qg.shape[-1]
+    merged, quant = k_pool.ndim == 3, bool(scales)
+    prefetch = [page_table.astype(jnp.int32)]
+    if row_offset is not None:
+        prefetch.append(row_offset.reshape(1))
+    if q_len is not None:  # last, so the index maps' ``base`` stays put
+        prefetch.append(q_len.astype(jnp.int32))
+    grid, in_specs, out_spec, scratch, out_shape = _ragged_blocks(
+        operands, page_table.shape[1], KVb, quant=quant,
+        has_offset=row_offset is not None)
     body = _build_ragged_paged_kernel(
-        quant=k_scale is not None, fused=False, C=C, scale=scale, pack=pack,
-        group_mask=group_mask, merged_heads=KVb if merged else 0,
-        head_blocks=KVb < KV,
+        quant=quant, fused=False, C=C, scale=scale,
+        pack=dk // k_pool.shape[-1] if quant else 1, group_mask=group_mask,
+        merged_heads=KVb if merged else 0, head_blocks=KVb < KV,
     )
 
     def kernel(*refs):  # the body knows the table and the query lengths
         body(refs[0], *refs[len(prefetch):],
              q_len_ref=None if q_len is None else refs[len(prefetch) - 1])
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -899,14 +1091,10 @@ def _ragged_paged_attention(
             out_specs=out_spec,
             scratch_shapes=scratch,
         ),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem(KVb),
-        ),
-        name=("ff_sparse_paged" if group_mask else "ff_ragged_paged")
-             + f"_c{C}" + _quant_suffix(k_scale is not None, pack) + tag,
-        interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        name=name,
+        interpret=interpret,
     )(*prefetch, *operands)
-    return out.reshape(R, C, H, dk)
 
 
 def sparse_paged_attention(
@@ -1337,13 +1525,8 @@ def fused_rope_paged_attention(
     )
     operands.append(mask)
 
-    scratch = [
-        pltpu.VMEM((C, KV, G, dk), jnp.float32),
-        pltpu.VMEM((C, KV, G), jnp.float32),
-        pltpu.VMEM((C, KV, G), jnp.float32),
-        pltpu.VMEM((C, KV, G, dk), q.dtype),     # roped q
-        pltpu.VMEM((C, KV, dk), k_new.dtype),    # roped k
-    ]
+    scratch = _ragged_scratch(C, KV, G, q, k_pool)  # the last: roped q
+    scratch.append(pltpu.VMEM((C, KV, dk), k_new.dtype))  # roped k
     outs = pl.pallas_call(
         kernel,
         out_shape=out_shapes,
@@ -1712,12 +1895,6 @@ MLA_QUERY_TILE = 32
 #: times slower than at 4 MiB, which 16 x 4 and 32 x 2 both are
 MLA_SCORES_BYTES = 4 << 20
 
-#: lanes of that kernel's softmax state: the running maximum and sum of
-#: a row are kept replicated along one whole lane tile, so that reading
-#: them against the scores and the accumulator moves no data (held as
-#: (rows, 1) arrays they cost as much as the rest of the step)
-MLA_STATE_LANES = 128
-
 
 def mla_block(C: int, NP: int, H: int, ps: int) -> tuple[int, int]:
     """(query columns, pages) of one grid step of
@@ -1732,16 +1909,6 @@ def mla_block(C: int, NP: int, H: int, ps: int) -> tuple[int, int]:
         if KB <= NP and TC * H * KB * ps * 4 <= MLA_SCORES_BYTES:
             return TC, KB
     return TC, 1
-
-
-def _along_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
-    """A state replicated along its lanes, (M, MLA_STATE_LANES), at
-    ``width`` lanes: whole lane tiles side by side, which moves no
-    data, or a broadcast (the tests' few lines a page)."""
-    lanes = x.shape[1]
-    if width % lanes == 0:
-        return jnp.tile(x, (1, width // lanes))
-    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
 
 
 def pair_rope_place(off: jnp.ndarray, page_size: int, width: int):
@@ -1818,7 +1985,7 @@ def mla_paged_attention(
     and between them ONE online-softmax update in float32 a block: one
     row maximum, one exponent pass, one rescale of the (rows, c)
     accumulator. The running maximum and sum lie replicated along a
-    lane tile (:data:`MLA_STATE_LANES`). The tile and the block come
+    lane tile (:data:`STATE_LANES`). The tile and the block come
     from the shapes (:func:`mla_block`).
 
     Work follows the real queries: a page past a tile's last real
@@ -1886,8 +2053,8 @@ def mla_paged_attention(
             @pl.when(b == 0)
             def _():
                 acc[:M] = jnp.zeros((M, V), jnp.float32)
-                m_scr[:M] = jnp.full((M, MLA_STATE_LANES), NEG_INF, jnp.float32)
-                l_scr[:M] = jnp.zeros((M, MLA_STATE_LANES), jnp.float32)
+                m_scr[:M] = jnp.full((M, STATE_LANES), NEG_INF, jnp.float32)
+                l_scr[:M] = jnp.zeros((M, STATE_LANES), jnp.float32)
 
             def attend(cut):
                 # the block's lines side by side, one softmax update
@@ -1952,8 +2119,8 @@ def mla_paged_attention(
     out_shape = jax.ShapeDtypeStruct((R, C, H, V), q_abs.dtype)
     M = TC * H
     scratch = [pltpu.VMEM((M, V), jnp.float32),
-               pltpu.VMEM((M, MLA_STATE_LANES), jnp.float32),
-               pltpu.VMEM((M, MLA_STATE_LANES), jnp.float32)]
+               pltpu.VMEM((M, STATE_LANES), jnp.float32),
+               pltpu.VMEM((M, STATE_LANES), jnp.float32)]
     # blocks double-buffered, the scratch, and the body's intermediates:
     # the block's lines side by side, the float32 scores (M, W) with
     # their masked and exponentiated forms (about six live at the widest

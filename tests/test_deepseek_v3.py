@@ -616,3 +616,42 @@ def test_the_reference_bounds_its_routings():
     one, _, _ = reference.judged_logits(params, file_cfg, tokens, judge, control_bits=8)
     assert one.shape == (2, 2, 1, cfg.vocab_size)
     assert _rms_share(one[0, 0, 0], full[0, 23]) > 1e-3
+
+
+def _whole_tiles(shape, dtype):
+    """``kernels._vmem_bytes`` as it stood before PR 55: the last two
+    dims padded to the dtype's whole (sublane, 128-lane) tile."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, sub, lane = shape
+    tile = 8 * max(1, 4 // item)
+    return (int(np.prod(lead, dtype=np.int64)) * -(-sub // tile) * tile
+            * -(-lane // 128) * 128 * item)
+
+
+@pytest.mark.parametrize("C, NP", [(128, 81), (1, 81)])
+def test_the_latent_kernels_vmem_statement_is_the_one_before_pr_55(C, NP):
+    """``mla_paged_attention`` states its VMEM through
+    ``kernels._vmem_bytes``, which PR 55 changed for the ragged paged
+    kernel: a second-to-last dim UNDER its dtype's tile now pads to the
+    power of two that holds it (Mosaic tiles ``bf16[.., 1, 128]`` as (2,
+    128)), not to the whole tile. Every buffer the latent call states
+    at the DeepSeek cell's shapes (128 heads, latent 512, rope 64, pages
+    of 128 lines, the mixed step's 32 columns x 2 pages and the decode
+    step's 1 x 8) has its second-to-last dim at or over the tile, so
+    its statement is the parent's to the byte."""
+    H, V, dr, ps = 128, 512, 64, 128
+    TC, KB = kernels.mla_block(C, NP, H, ps)
+    assert (TC, KB) == ((32, 2) if C == 128 else (1, 8))
+    M, W = TC * H, KB * ps
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    buffers = ([((1, TC, H, V), bf16), ((1, TC, H, dr), bf16)]   # the queries
+               + [((1, ps, V), bf16), ((1, ps // 2, 2 * dr), bf16)] * KB
+               + [((1, TC, H, V), bf16)]                          # the result
+               + [((M, V), f32), ((M, kernels.STATE_LANES), f32)]  # scratch
+               + [((W, V + dr), bf16), ((M, W), f32), ((M, V), f32)])
+    for shape, dtype in buffers:
+        assert kernels._vmem_bytes(shape, dtype) == _whole_tiles(shape, dtype), shape
+    # and what changed: a group of one query head, twice over and not
+    # sixteen times
+    assert kernels._vmem_bytes((1, 128), bf16) == 2 * 128 * 2
+    assert _whole_tiles((1, 128), bf16) == 16 * 128 * 2

@@ -38,9 +38,37 @@ def test_llama_generation_pallas_equals_xla():
 # real (``q_len``): padding columns keep no page alive, a row of a few
 # real queries takes the narrow body, real queries come out the same.
 
-_VARIANTS = ("plain", "group_mask", "int8")
+#: variant -> what differs from the plain case (float32 pools of KV=4
+#: heads of 16, groups of 2, one mask a row). From "int4" on: the body
+#: in the layout its matmuls give (PR 55) at each family's group, head
+#: size and pool, the cases of ISSUE 55
+_VARIANTS = {
+    "plain": {}, "group_mask": dict(group_mask=True), "int8": dict(quant=1),
+    "int4": dict(quant=2),
+    "g1": dict(H=4),                                    # Olmo's: a head a group
+    "g4-dk128": dict(H=8, KV=2, dk=128),                # Mistral's
+    "g8-dk256": dict(H=8, KV=1, dk=256),                # Qwen3-Next's
+    "g8-group_mask": dict(H=16, KV=2, group_mask=True),  # the sparse call
+    "merged-dk64": dict(dk=64, merged=True),            # LFM2's rank-3 pool
+    "window": dict(window=12, tag="_win"),              # a window layer's call
+    "bf16": dict(dtype=jnp.bfloat16),                   # the cells' pools
+    "bf16-merged-g1": dict(H=4, dk=64, merged=True, dtype=jnp.bfloat16),
+    # Mistral's group and head size in the cells' dtype
+    "bf16-g4-dk128": dict(H=8, KV=2, dk=128, dtype=jnp.bfloat16),
+}
 _PS, _NP = 8, 6                       # page size, logical pages a row
 _CACHE_LEN = _PS * _NP - 1            # the scratch position
+#: |kernel - reference| allowed, the reference in float32 on the same
+#: values: float32 pools to the file's 2e-5 (they read 1.7e-6 at most,
+#: to the digit what the float32 dots of the body before PR 55 read);
+#: bf16 pools to 2e-2 (the result's own rounding to bf16 is 2**-9 of
+#: values up to 2.9: bf16 C=1 reads 3.9e-3, C=16 7.5e-3,
+#: bf16-merged-g1 7.3e-3, and so did the body before PR 55; CHANGES.md)
+_ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _atol(variant):
+    return _ATOL[_VARIANTS[variant].get("dtype", jnp.float32)]
 
 
 def _mixed_rows(C):
@@ -56,9 +84,13 @@ def _mixed_rows(C):
 @functools.lru_cache(maxsize=None)
 def _q_len_case(variant, C):
     """One batch through the kernel with and without ``q_len`` and
-    through the XLA reference: (positions, q_len, mask, outputs)."""
+    through the XLA reference (in float32, on the values the pools
+    hold): (positions, q_len, mask, outputs)."""
     from flexflow_tpu.serve import kernels as K
 
+    v = dict(dict(H=H, KV=KV, dk=dk, dtype=jnp.float32, quant=0, merged=False,
+                  group_mask=False, window=0, tag=""), **_VARIANTS[variant])
+    heads, kv, d, dtype = v["H"], v["KV"], v["dk"], v["dtype"]
     rng = np.random.default_rng(C + len(variant))
     rows = _mixed_rows(C)
     R, P = len(rows), len(rows) * _NP
@@ -66,44 +98,59 @@ def _q_len_case(variant, C):
     for r, (first, n) in enumerate(rows):
         pos[r, :n] = np.arange(first, first + n)
     pos = jnp.asarray(pos)
-    q = jnp.asarray(rng.normal(size=(R, C, H, dk)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(R, C, heads, d)), dtype)
     pt = jnp.asarray(rng.permutation(P).reshape(R, _NP), jnp.int32)
     mask = K.paged_serve_mask(None, pos, _NP, _PS, _CACHE_LEN)   # (R, C, S)
+    if v["window"]:  # a window layer: no key further back than the window
+        keys = jnp.arange(_NP * _PS)
+        mask = mask & (keys[None, None, :] > pos[:, :, None] - v["window"])
     kw = {}
-    if variant == "int8":
-        kp, vp = (jnp.asarray(rng.integers(-127, 128, size=(P + 1, _PS, KV, dk)),
-                              jnp.int8) for _ in range(2))
-        kw = dict(k_scale=jnp.asarray(rng.random((P + 1, KV)) * 0.02, jnp.float32),
-                  v_scale=jnp.asarray(rng.random((P + 1, KV)) * 0.02, jnp.float32))
-    else:
-        kp, vp = (jnp.asarray(rng.normal(size=(P + 1, _PS, KV, dk)), jnp.float32)
+    if v["quant"]:  # int8 codes, or int4's two to a byte
+        pack = v["quant"]
+        codes = (dict(low=-127, high=128, dtype=np.int8) if pack == 1
+                 else dict(low=0, high=256, dtype=np.uint8))
+        kp, vp = (jnp.asarray(rng.integers(size=(P + 1, _PS, kv, d // pack), **codes))
                   for _ in range(2))
-    if variant == "group_mask":
+        kw = dict(k_scale=jnp.asarray(rng.random((P + 1, kv)) * 0.02, jnp.float32),
+                  v_scale=jnp.asarray(rng.random((P + 1, kv)) * 0.02, jnp.float32))
+    else:
+        kp, vp = (jnp.asarray(rng.normal(size=(P + 1, _PS, kv, d)), dtype)
+                  for _ in range(2))
+    f32 = lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x
+    reference = functools.partial(K.ragged_paged_attention_xla, f32(q), f32(kp),
+                                  f32(vp), pt, **kw)
+    if v["merged"]:  # a line's heads side by side on the minor axis
+        kp, vp = (x.reshape(P + 1, _PS, kv * d) for x in (kp, vp))
+    if v["group_mask"]:
         # a mask a KV group: each real query keeps its own page and a
         # random half of the others; a padding query keeps every key,
         # as models/minicpm_sala.choose_blocks leaves it
-        keep = rng.random((R, KV, C, _NP)) < 0.5
+        keep = rng.random((R, kv, C, _NP)) < 0.5
         own = (np.asarray(pos) // _PS)[:, None, :, None] == np.arange(_NP)
         keep = keep | own | (np.asarray(pos) >= _CACHE_LEN)[:, None, :, None]
         mask = mask[:, None] & jnp.asarray(np.repeat(keep, _PS, axis=-1))
         call = functools.partial(K.sparse_paged_attention, q, kp, vp, pt, mask)
         ref = jnp.stack([
-            K.ragged_paged_attention_xla(q, kp, vp, pt, mask[:, g])
-            .reshape(R, C, KV, H // KV, dk)[:, :, g] for g in range(KV)
-        ], axis=2).reshape(R, C, H, dk)
+            reference(mask[:, g]).reshape(R, C, kv, heads // kv, d)[:, :, g]
+            for g in range(kv)], axis=2).reshape(R, C, heads, d)
     else:
-        call = functools.partial(K.ragged_paged_attention, q, kp, vp, pt, mask, **kw)
-        ref = K.ragged_paged_attention_xla(q, kp, vp, pt, mask, **kw)
+        call = functools.partial(K.ragged_paged_attention, q, kp, vp, pt, mask,
+                                 tag=v["tag"], **kw)
+        ref = reference(mask)
     q_len = K.real_query_lengths(pos, _CACHE_LEN)
     outs = dict(
         none=call(), q_len=call(q_len=q_len),
         full=call(q_len=jnp.full((R,), C, jnp.int32)), ref=ref,
     )
     return (np.asarray(pos), np.asarray(q_len), np.asarray(mask),
-            {k: np.asarray(v) for k, v in outs.items()})
+            {k: np.asarray(f32(x)) for k, x in outs.items()})
 
 
-_Q_LEN_CASES = [(v, C) for v in _VARIANTS for C in (1, 16)]
+# the cases before PR 55 at the decode step's chunk and a mixed step's,
+# PR 55's at the mixed step's (the narrow body beside the chunk-wide
+# one) and the bf16 pool at the decode step's too
+_Q_LEN_CASES = [(v, C) for v in _VARIANTS for C in (1, 16)
+                if C == 16 or v in ("plain", "group_mask", "int8", "bf16")]
 q_len_cases = pytest.mark.parametrize("variant, C", _Q_LEN_CASES)
 
 
@@ -123,7 +170,7 @@ def test_q_len_real_queries_unchanged(variant, C):
     for r, n in enumerate(q_len):
         np.testing.assert_array_equal(outs["q_len"][r, :n], outs["none"][r, :n])
         np.testing.assert_allclose(outs["q_len"][r, :n], outs["ref"][r, :n],
-                                   atol=2e-5)
+                                   atol=_atol(variant))
 
 
 @q_len_cases
@@ -141,7 +188,14 @@ def test_q_len_none_is_every_column(variant, C):
     gives its result to the bit, padding columns' too."""
     _, _, _, outs = _q_len_case(variant, C)
     np.testing.assert_array_equal(outs["full"], outs["none"])
-    np.testing.assert_allclose(outs["none"], outs["ref"], atol=2e-5)
+    np.testing.assert_allclose(outs["none"], outs["ref"], atol=_atol(variant))
+
+
+def _jitted_kernel_calls(jaxpr):
+    """The equations of ``jaxpr`` that call a jitted function holding a
+    ``pallas_call`` (``kernels._ragged_call``'s)."""
+    return [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")
+            and "pallas_call" in str(e.params["jaxpr"])]
 
 
 def test_q_len_none_traces_the_old_operands():
@@ -154,12 +208,64 @@ def test_q_len_none_traces_the_old_operands():
             jnp.zeros((2, 16, H, dk)), jnp.zeros((5, _PS, KV, dk)),
             jnp.zeros((5, _PS, KV, dk)), jnp.zeros((2, _NP), jnp.int32),
             jnp.zeros((2, 16, _NP * _PS), bool))
-        call, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        jitted, = _jitted_kernel_calls(jaxpr)  # the call is jitted a shape
+        call, = [e for e in jitted.params["jaxpr"].eqns
+                 if e.primitive.name == "pallas_call"]
         return call.params["grid_mapping"].num_index_operands
 
     assert prefetched() == 1
     assert prefetched(row_offset=3) == 2
     assert prefetched(row_offset=3, q_len=jnp.zeros((2,), jnp.int32)) == 3
+
+
+def test_ragged_calls_are_traced_once_a_shape(monkeypatch):
+    """A step program calls the ragged paged kernel once an attention
+    layer, and a process holds a program a rung, head variant and probe
+    sibling: every call site of one shape, in one jitted function or in
+    the next, shares ONE cached jitted call and ONE traced jaxpr of the
+    body (``kernels._ragged_call``; the twin of
+    ``test_moe.py::test_grouped_calls_are_traced_once_a_shape``).
+    Another name (a tag, a chunk) or ``_interpret()`` is another entry
+    of the cache; ``q_len`` given or not is another trace of the same
+    entry."""
+    from flexflow_tpu.serve import kernels as K
+
+    built, builder = [], K._build_ragged_paged_kernel
+    monkeypatch.setattr(K, "_build_ragged_paged_kernel",
+                        lambda **kw: built.append(kw) or builder(**kw))
+    K._ragged_call.cache_clear()   # and with it every trace kept so far
+    args = lambda C: (
+        jnp.zeros((2, C, H, dk)), jnp.zeros((5, _PS, KV, dk)),
+        jnp.zeros((5, _PS, KV, dk)), jnp.zeros((2, _NP), jnp.int32),
+        jnp.zeros((2, C, _NP * _PS), bool), jnp.zeros((2,), jnp.int32))
+
+    def layers(q, kp, vp, pt, mask, q_len, use=True, **kw):
+        return sum(K.ragged_paged_attention(
+            q + l, kp, vp, pt, mask, q_len=q_len if use else None,
+            row_offset=jnp.int32(l), **kw) for l in range(2))
+
+    def calls(fn, C=16, **kw):
+        return _jitted_kernel_calls(
+            jax.make_jaxpr(functools.partial(fn, **kw))(*args(C)))
+
+    def seen():
+        info = K._ragged_call.cache_info()
+        return info.misses, info.hits, len(built)
+
+    sites = calls(layers) + calls(lambda *a: 2 * layers(*a))  # two programs
+    assert len(sites) == 4
+    assert len({id(e.params["jaxpr"]) for e in sites}) == 1
+    assert seen() == (1, 3, 1)
+    calls(layers, use=False)               # no q_len: the entry's second trace
+    assert seen() == (1, 5, 2)
+    calls(layers, tag="_win")
+    assert seen() == (2, 6, 3)
+    calls(layers, C=8)
+    assert seen() == (3, 7, 4)
+    monkeypatch.setattr(K, "_interpret", lambda: False)   # what `chip` patches
+    other = calls(layers)
+    assert seen() == (4, 8, 5)
+    assert id(other[0].params["jaxpr"]) != id(sites[0].params["jaxpr"])
 
 
 @q_len_cases

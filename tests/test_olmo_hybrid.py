@@ -422,15 +422,17 @@ def test_only_the_pallas_decode_program_holds_the_recurrence_kernel(tiny):
 
 @pytest.mark.parametrize("kernels, chunk, pack, digest", [
     ("xla", 1, None, "1a3a0c54ccc87a58"), ("xla", CHUNK, None, "6d931809afad25a2"),
-    ("xla", CHUNK, 32, "3959f50f4f5213cd"), ("pallas", 1, None, "65b2b9f643e3d2ad"),
-    ("pallas", CHUNK, None, "5e3de41aa961407a"), ("pallas", CHUNK, 32, "017f205cee363c31")])
+    ("xla", CHUNK, 32, "3959f50f4f5213cd"), ("pallas", 1, None, "4876779f103133b1"),
+    ("pallas", CHUNK, None, "6d40ecd8dc527685"), ("pallas", CHUNK, 32, "fb673bde57bac1a3")])
 def test_the_shared_seam_leaves_granites_programs_alone(kernels, chunk, pack, digest):
     """``step_rows`` is shared with ``granite_hybrid``, whose state is
     lane-dense as it is (64 x 128) and whose kernel is its own: its six
     step jaxprs at the tiny preset are PR 47's, by the first 16 hex
-    digits of their SHA-256 (taken on the parent commit, PR 48). A PR
-    that means to change Granite's programs takes the digests anew; one
-    that means to change Olmo's alone does not get here."""
+    digits of their SHA-256 (taken on the parent commit, PR 48; the
+    three Pallas programs' anew by PR 55, whose ragged paged kernel
+    body they print; the XLA programs' stand). A PR that means to
+    change Granite's programs takes the digests anew; one that means to
+    change Olmo's alone does not get here."""
     import hashlib
 
     from flexflow_tpu.models import granite_hybrid
