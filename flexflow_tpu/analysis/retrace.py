@@ -24,14 +24,17 @@ of shipping. ``seal()`` additionally forbids compiles of *new* keys
 Enable via ``ServingConfig(sanitizers=("retrace",))`` (strict) or
 ``("retrace-warn",)`` (record + log only), or ``FF_SANITIZERS=retrace``
 in the environment. Compile events are logged at
-``FF_LOG=serve=debug`` and mirrored into ``SchedulerStats.compiles``/
-``retraces`` when a RequestManager drives the engine.
+``FF_LOG=serve=debug``. The guard keeps what only it does — the
+signatures, ``strict`` and ``seal``: ``SchedulerStats.compiles`` /
+``retraces`` are counted by the engine's build log (obs/builds.py) at
+the same chokepoint, whether or not a guard is set, and a trace the
+guard refuses never reaches that log.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 from ..logging_utils import get_logger
 
@@ -77,16 +80,12 @@ class RetraceGuard:
     """Records every compile of every instrumented step program; in
     strict mode a recompile raises at the offending dispatch."""
 
-    def __init__(self, strict: bool = True,
-                 stats_cb: Optional[Callable[[], Any]] = None):
+    def __init__(self, strict: bool = True):
         self.strict = strict
         self.compiles: Dict[Any, List[str]] = {}
         self.events: List[CompileEvent] = []
         self.retraces = 0
         self._sealed = False
-        # () -> SchedulerStats; wired by the RequestManager so compile
-        # events surface in the serving telemetry (bench + FF_LOG)
-        self.stats_cb = stats_cb
         self._log = get_logger("serve")
 
     # -- engine integration ------------------------------------------------
@@ -114,11 +113,6 @@ class RetraceGuard:
             key=key, signature=sig, count=len(prev), seq=len(self.events)
         )
         self.events.append(event)
-        stats = self.stats_cb() if self.stats_cb is not None else None
-        if stats is not None:
-            stats.compiles += 1
-            if is_retrace:
-                stats.retraces += 1
         self._log.debug(
             "compile key=%r count=%d sig=%s", key, event.count, sig
         )
@@ -140,8 +134,6 @@ class RetraceGuard:
             if not prev:
                 self.compiles.pop(key, None)
             self.events.pop()
-            if stats is not None:
-                stats.compiles -= 1
             raise RetraceError(
                 f"NEW step key {key!r} compiled after seal(): sig={sig}. "
                 "Steady state was declared (seal()) but this dispatch "
@@ -186,10 +178,3 @@ class RetraceGuard:
                     f"{k!r}: {self.compiles[k]}" for k in bad
                 )
             )
-
-    def report(self) -> str:
-        counts = self.compile_counts()
-        return (
-            f"[retrace-guard] {self.total_compiles} compiles over "
-            f"{len(counts)} step keys, {self.retraces} retraces"
-        )

@@ -10,7 +10,7 @@ and aggregated on host with :class:`PerfMetrics`.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -135,14 +135,34 @@ class SchedulerStats:
     cp_shards: int = 0
     ring_steps: int = 0
     shard_balance: float = 1.0
-    # Retrace sentinel (analysis/retrace.py, wired when the engine runs
-    # with ServingConfig.sanitizers=("retrace",)): XLA compiles of step
-    # programs observed at the engine's jit chokepoint, and how many of
-    # them were RE-compiles of an already-compiled step key — the
-    # steady-state perf hazard. Healthy serving: compiles settles after
-    # warmup and retraces stays 0.
+    # The build log (obs/builds.py; note_build): builds of step programs
+    # counted at the engine's jit chokepoint, whose wrapper runs when a
+    # program is traced, with or without a sanitizer — and how many of
+    # them were RE-builds of an already-built step key, the
+    # steady-state perf hazard (``sanitizers=("retrace",)`` makes one
+    # raise). Healthy serving: compiles settles after warmup and
+    # retraces stays 0. Beside them the seconds those builds took by
+    # part — the Python trace, the lowering to MLIR, the backend's
+    # compile or the compilation cache's load, which of the two it was
+    # (a build the cache was not asked about counts as neither) — the
+    # seconds of builds that began while a request was live, so inside
+    # a step that the request waited for, the seconds of every other
+    # build of the process since the engine's construction (jnp helpers
+    # run outside any program, a harness's reference), and the records:
+    # name (``ff_step_c1``; ``ff_step_c1#2`` a retrace) -> the numbers
+    # of obs.builds.Build, ``other`` -> those builds' sums. The dict is
+    # replaced, never updated in place.
     compiles: int = 0
     retraces: int = 0
+    build_trace_s: float = 0.0
+    build_lower_s: float = 0.0
+    build_backend_s: float = 0.0
+    build_cache_hits: int = 0
+    build_cache_misses: int = 0
+    build_in_step_s: float = 0.0
+    build_other_s: float = 0.0
+    builds: Dict[str, Dict[str, Any]] = dataclasses.field(
+        default_factory=dict)
     # A family with per-slot state beside the page pool (the engine's
     # model declares ``SLOT_STATE``; models/minicpm_sala.py): the bytes
     # of that state (a gauge: held at any context length), the rows of
@@ -314,6 +334,31 @@ class SchedulerStats:
         by = self.steps_by_width
         self.steps_by_width = {**by, int(width): by.get(int(width), 0) + 1}
 
+    def note_build(self, label: str, numbers: Dict[str, Any], part: str,
+                   seconds: float) -> None:
+        """Count one event of a build (obs/builds.py): ``part`` is
+        ``begin`` (a step program's traced function returned: one
+        compile, a retrace where its ordinal is over 1), or the part
+        that took ``seconds`` — ``trace``, ``lower``, ``backend``.
+        ``label`` names the record, ``numbers`` is its newest state."""
+        if part == "begin":
+            self.compiles += 1
+            self.retraces += numbers["ordinal"] > 1
+        elif label == "other":
+            self.build_other_s += seconds
+        else:
+            if part == "trace":
+                self.build_trace_s += seconds
+            elif part == "lower":
+                self.build_lower_s += seconds
+            else:
+                self.build_backend_s += seconds
+                self.build_cache_hits += numbers["cache"] == "hit"
+                self.build_cache_misses += numbers["cache"] == "miss"
+            if numbers["in_step"]:
+                self.build_in_step_s += seconds
+        self.builds = {**self.builds, label: numbers}
+
     @property
     def mean_occupancy(self) -> float:
         return self.occupancy_sum / self.steps if self.steps else 0.0
@@ -395,6 +440,18 @@ class SchedulerStats:
             "shard_balance": round(self.shard_balance, 4),
             "compiles": self.compiles,
             "retraces": self.retraces,
+            "build_trace_s": self.build_trace_s,
+            "build_lower_s": self.build_lower_s,
+            "build_backend_s": self.build_backend_s,
+            "build_cache_hits": self.build_cache_hits,
+            "build_cache_misses": self.build_cache_misses,
+            "build_in_step_s": self.build_in_step_s,
+            "build_other_s": self.build_other_s,
+            # without the by-name ``inner``: a snapshot rides every RPC
+            # envelope of a remote replica
+            "builds": {
+                label: {k: v for k, v in rec.items() if k != "inner"}
+                for label, rec in self.builds.items()},
             "step_tokens_real": self.step_tokens_real,
             "step_tokens_width": self.step_tokens_width,
             "pack_fill": round(self.pack_fill, 4),
@@ -429,6 +486,8 @@ class SchedulerStats:
             f"cp={s['cp_shards']} ring={s['ring_steps']} "
             f"bal={s['shard_balance']:.2f} "
             f"compiles={s['compiles']} retraces={s['retraces']} "
+            f"build={s['build_trace_s']:.1f}+{s['build_lower_s']:.1f}"
+            f"+{s['build_backend_s']:.1f}s in_step={s['build_in_step_s']:.1f}s "
             f"greedy_head={s['head_greedy_steps']}/{s['head_steps']} "
             f"pack={s['step_tokens_real']}/{s['step_tokens_width']} by width "
             + (",".join(f"{w}:{n}" for w, n in s["steps_by_width"].items())

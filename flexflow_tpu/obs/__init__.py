@@ -31,6 +31,18 @@ timeline captures — rebuilt TPU-native over the serve stack:
   ``scope_maps()``, which reads ``{program: {HLO instruction:
   sublayer}}`` off the compiled executables for a reader that has only
   instruction names (the benchmark's ``step.sub_ms.*``).
+* :mod:`.builds` — before a program can be dispatched at all: the
+  build log. One set of ``jax.monitoring`` listeners, registered at
+  the first engine's construction, files what each step program's
+  build cost (Python trace, lowering, backend compile or cache load)
+  under the program's name: a record a build on the engine and in
+  ``SchedulerStats.builds``, the ``build_*`` counters (and
+  ``compiles`` / ``retraces``, with or without a sanitizer),
+  ``ff.build.trace`` on the profiler's clock and ``build.trace`` /
+  ``build.lower`` / ``build.backend`` in an attached buffer — the
+  engine's lane of the Chrome export shows a build between the
+  requests it delayed. Its wrapper runs when a program is traced and
+  its listener when one is built; a dispatch runs neither.
 * :mod:`.export` — Chrome/Perfetto ``trace_event`` JSON (one lane per
   replica; a migrated request is ONE trace id hopping lanes) and a
   Prometheus text snapshot mechanically derived from
@@ -64,6 +76,7 @@ from .export import (
     write_chrome_trace,
     write_prometheus,
 )
+from .builds import BuildLog
 from .flight_recorder import REDACTED_ATTRS, FlightRecorder
 from .sublayers import SUBLAYERS, scope_maps, sublayer
 from .tracer import NULL_TRACER, NullTracer, TraceBuffer, Tracer
@@ -74,6 +87,7 @@ __all__ = [
     "NullTracer",
     "NULL_TRACER",
     "FlightRecorder",
+    "BuildLog",
     "REDACTED_ATTRS",
     "chrome_trace",
     "write_chrome_trace",

@@ -30,6 +30,8 @@ import dataclasses
 import json
 from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence
 
+from .tracer import BUILD_SPANS
+
 __all__ = [
     "ExportDriftError",
     "chrome_trace",
@@ -111,6 +113,9 @@ SCHED_COUNTERS = frozenset({
     "spec_rounds", "spec_drafted", "spec_accepted", "spec_resizes",
     "verify_skipped_rounds", "spec_reprobes",
     "ring_steps", "compiles", "retraces",
+    "build_trace_s", "build_lower_s", "build_backend_s",
+    "build_cache_hits", "build_cache_misses", "build_in_step_s",
+    "build_other_s",
     "real_rows", "state_resets", "sparse_rows",
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
     "step_tokens_real", "step_tokens_width",
@@ -135,6 +140,9 @@ SCHED_EXCLUDED = {
     # a by-class dict; the scrape surface carries the window classes'
     # peak, which is what a window frees against
     "pages_live_peak": "window_pages_live_peak",
+    # the build log's records, by program — exported as ONE labeled
+    # series of seconds by part
+    "builds": "flexflow_scheduler_build_seconds{program=...,part=...}",
 }
 #: Derived snapshot() rates exported as gauges alongside the counters.
 SCHED_DERIVED = (
@@ -312,6 +320,11 @@ def prometheus_text(
         for field in sorted(SCHED_GAUGES) + list(SCHED_DERIVED):
             out.add(f"flexflow_scheduler_{field}", "gauge",
                     snap.get(field, 0), labels)
+        for program, rec in sorted((snap.get("builds") or {}).items()):
+            for part in BUILD_SPANS:
+                out.add("flexflow_scheduler_build_seconds", "counter",
+                        rec.get(part + "_s", 0.0),
+                        dict(labels, program=program, part=part))
     if cluster is not None:
         for field in sorted(CLUSTER_COUNTERS):
             out.add(f"flexflow_cluster_{field}", "counter",

@@ -52,7 +52,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 
 __all__ = ["TraceBuffer", "Tracer", "NullTracer", "NULL_TRACER",
-           "STEP_SPANS"]
+           "STEP_SPANS", "BUILD_SPANS", "BUILD_ANNOTATION"]
 
 #: The phases of one ``RequestManager.step`` (serve/request_manager.py).
 #: In a profile they read ``ff.step.admit`` … on the Python thread's
@@ -72,6 +72,22 @@ _ANNOTATION_NAMES = {name: "ff." + name for name in STEP_SPANS}
 # inside ``step.reserve``, and only where the engine's pager has a class
 # of page with a window: the host's freeing behind it
 _ANNOTATION_NAMES["step.trim"] = "ff.step.trim"
+
+
+#: The parts of one step program's build (obs/builds.py), as the trace
+#: buffer names them: the Python trace of the program's function, the
+#: jaxpr's lowering to MLIR (Mosaic's of each Pallas call with it) and
+#: the backend's compile or the compilation cache's load. Keyed by the
+#: part's name in a build's record.
+BUILD_SPANS = {
+    "trace": "build.trace",
+    "lower": "build.lower",
+    "backend": "build.backend",
+}
+#: ... and the one annotation on the profiler's clock, round the traced
+#: function (``InferenceEngine._jit``'s wrapper: trace time only), with
+#: the program's name as its ``program`` argument.
+BUILD_ANNOTATION = "ff.build.trace"
 
 
 def _annotation(name: str) -> jax.profiler.TraceAnnotation:

@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.mesh import DATA_AXIS, MachineSpec, set_mesh as _set_mesh
 from ..obs import sublayers
+from ..obs.builds import BuildLog
 from ..obs.sublayers import sublayer
 from ..obs.tracer import NULL_TRACER
 from .batch_config import BatchConfig
@@ -786,6 +787,10 @@ class InferenceEngine:
         # wrapper as it is traced (step_program_texts)
         self._traced: Dict[str, Tuple[Callable, Any]] = {}
         sublayers.register(self)
+        # what each program's build cost, by name (obs/builds.py): the
+        # process's one set of jax.monitoring listeners is registered
+        # here, at the first engine's construction
+        self.build_log = BuildLog()
         # serving ladders (chunk, sampling head) whose every rung is
         # compiled
         self._ladders_compiled: set = set()
@@ -1215,23 +1220,30 @@ class InferenceEngine:
     def _jit(self, fn: Callable, *, key: Any,
              donate_argnums: Tuple[int, ...] = ()) -> Callable:
         """Every step program (``_steps``/``_commit``) is compiled
-        through this chokepoint, which does two things. It NAMES the
+        through this chokepoint, which does three things. It NAMES the
         program from its key (:func:`program_name`), so a profile's
         ``XLA Modules`` line and the lowered HLO read
         ``jit_ff_step_c1`` / ``jit_ff_step_c128`` instead of the name
-        of whatever closure was jitted. And it lets the retrace
+        of whatever closure was jitted. Its wrapper, which runs when
+        the program is traced and never at a dispatch, opens the
+        build's record in the engine's build log under that name
+        (obs/builds.py: the one count of ``SchedulerStats.compiles`` /
+        ``retraces``, the seconds of each part, the ``ff.build.trace``
+        annotation round the traced function). And it lets the retrace
         sentinel observe it: the guard wraps the function (keeping its
         name) to record each trace — which is exactly one XLA compile —
         under ``key`` and, in strict mode, raises on any recompile of a
-        known key (analysis/retrace.py)."""
+        known key (analysis/retrace.py), before the log has seen it."""
 
         name = program_name(key)
 
         @functools.wraps(fn)
         def program(*args, **kwargs):
-            # trace time only: what step_program_texts lowers again
-            self._traced[name] = (jitted, _abstract((args, kwargs)))
-            return fn(*args, **kwargs)
+            # trace time only
+            with self.build_log.tracing(name, key, self.tracer):
+                # what step_program_texts lowers again
+                self._traced[name] = (jitted, _abstract((args, kwargs)))
+                return fn(*args, **kwargs)
 
         program.__name__ = program.__qualname__ = name
         if self.retrace_guard is not None:

@@ -189,15 +189,16 @@ class RequestManager:
         # rid -> cluster-wide trace id (bound at submission; local runs
         # fall back to the rid itself — see trace_of)
         self._trace_ids: Dict[int, int] = {}
-        # Retrace sentinel telemetry (analysis/retrace.py): compile
-        # events recorded at the engine's jit chokepoint surface in the
-        # scheduler stats (FF_LOG=serve=debug + bench reports). The
-        # callable indirection survives bench-style stat swaps
-        # (rm.stats = SchedulerStats()) the same way the prefix cache's
-        # stats hook does.
-        guard = getattr(engine, "retrace_guard", None)
-        if guard is not None:
-            guard.stats_cb = lambda: self.stats
+        # The build log (obs/builds.py): what each engine's builds cost
+        # surfaces in the scheduler stats (``note_build``), stamped
+        # with this scheduler's step. The callable indirection survives
+        # bench-style stat swaps (rm.stats = SchedulerStats()) the same
+        # way the prefix cache's stats hook does.
+        for i, eng in enumerate(self._engines()):
+            log = getattr(eng, "build_log", None)
+            if log is not None:
+                log.attach(self._build_context,
+                           prefix=f"ssm{i - 1}/" if i else "")
         # Automatic prefix caching (paged layout only — on dense,
         # prefix_caching=True is a documented passthrough: there are no
         # pages to share). The radix tree owns one reference per cached
@@ -418,6 +419,15 @@ class RequestManager:
     @property
     def _paged(self) -> bool:
         return getattr(self.engine, "paged", False)
+
+    def _build_context(self):
+        """For the engines' build logs, read when a program is traced:
+        where the counters go, the step counter (-1 before the first
+        request: nothing is scheduled) and whether a request is live —
+        a build that begins then began inside a step."""
+        live = bool(self.pending) or any(s is not None for s in self.slots)
+        step = self._step_counter if live or self._step_counter else -1
+        return self.stats, step, live
 
     def _engines(self):
         """Every engine whose cache this manager keeps in sync

@@ -1057,6 +1057,7 @@ def _engine_decode_program(fam, cfg, slots, max_seq, head):
     whose decode-head arrays chose ``head``, from the engine's own
     code (an engine that is built allocates its pool)."""
     from flexflow_tpu.core.mesh import MachineSpec
+    from flexflow_tpu.obs import NULL_TRACER, BuildLog
     from flexflow_tpu.serve.engine import InferenceEngine, ServingConfig
 
     eng = object.__new__(InferenceEngine)
@@ -1069,6 +1070,7 @@ def _engine_decode_program(fam, cfg, slots, max_seq, head):
     eng.paged, eng.cp_ring, eng.retrace_guard = True, False, None
     eng._step_counts = getattr(fam, "step_counts", lambda cfg: {})(cfg)
     eng._steps, eng._traced = {}, {}
+    eng.build_log, eng.tracer = BuildLog(), NULL_TRACER  # _jit's wrapper's
     return eng._get_mixed_step(1, False, *head)
 
 

@@ -45,6 +45,7 @@ from flexflow_tpu.obs import (
     write_chrome_trace,
     write_prometheus,
 )
+from flexflow_tpu.obs import builds as obs_builds
 from flexflow_tpu.obs import export as obs_export
 from flexflow_tpu.obs.flight_recorder import redact_event
 from flexflow_tpu.obs.tracer import STEP_SPANS, NullTracer
@@ -265,7 +266,8 @@ def test_flight_recorder_ring_bound_redaction_and_dump(tmp_path):
 # disabled mode is free (the acceptance inverse)
 
 
-def test_disabled_tracing_is_free_on_the_sync_scheduler(tiny):
+@pytest.mark.parametrize("steady", [False, True], ids=["cold", "steady"])
+def test_disabled_tracing_is_free_on_the_sync_scheduler(tiny, steady):
     """With the null tracer: (a) no event dict is ever built — every
     event site guards on ``.enabled`` before building arguments (proven
     by making ``NullTracer.event`` raise) — and the phase spans of a
@@ -274,9 +276,32 @@ def test_disabled_tracing_is_free_on_the_sync_scheduler(tiny):
     open; none is here); (b) the sync scheduler's
     dispatched-programs-per-decode-step count is unchanged vs a traced
     run; (c) no buffer is touched: the step loop keeps NOTHING from
-    obs/ frames."""
+    obs/ frames. ``steady``: the same on a server whose programs are
+    all built, and there the build log (obs/builds.py) does nothing
+    either — its wrapper is not entered (it runs when a program is
+    traced, never at a dispatch), its listener hears no event, its
+    frames keep nothing and its counters stand."""
     kw = dict(kv_layout="dense", continuous_batching=False)
     rm_off = make_rm(tiny, **kw)
+    obs_files = ["*obs*tracer.py", "*obs*export.py",
+                 "*obs*flight_recorder.py"]
+    heard = []
+
+    def listen(event, *a, **k):
+        heard.append(event)
+
+    def no_build(self, name, *a, **k):
+        raise AssertionError(f"{name} traced on a server that had built it")
+
+    old_tracing = obs_builds.BuildLog.tracing
+    if steady:
+        assert all(o.error is None for o in
+                   rm_off.generate(PROMPTS, max_new_tokens=6))
+        obs_files.append("*obs*builds.py")
+        obs_builds.BuildLog.tracing = no_build
+        jax.monitoring.register_event_duration_secs_listener(listen)
+    built = dataclasses.replace(rm_off.stats)
+    dispatches_before = rm_off.engine.dispatch_count
     # (a) a NullTracer.event call anywhere in the step loop would raise
     def _boom(self, *a, **k):
         raise AssertionError(
@@ -302,15 +327,22 @@ def test_disabled_tracing_is_free_on_the_sync_scheduler(tiny):
         tracemalloc.stop()
         NullTracer.event = old_event
         NullTracer.span = old_span
+        if steady:
+            obs_builds.BuildLog.tracing = old_tracing
+            jax.monitoring.unregister_event_duration_listener(listen)
     obs_allocs = snap.filter_traces(
-        [tracemalloc.Filter(True, "*obs*tracer.py"),
-         tracemalloc.Filter(True, "*obs*export.py"),
-         tracemalloc.Filter(True, "*obs*flight_recorder.py")]
+        [tracemalloc.Filter(True, pattern) for pattern in obs_files]
     ).statistics("filename")
     assert not obs_allocs, (
         f"disabled tracing allocated host memory: {obs_allocs}"
     )
-    dispatches_off = rm_off.engine.dispatch_count
+    if steady:
+        assert not heard, f"a built server's steps built: {set(heard)}"
+        s_now = rm_off.stats
+        assert (s_now.compiles, s_now.retraces, s_now.builds) == (
+            built.compiles, built.retraces, built.builds)
+        assert s_now.build_in_step_s == built.build_in_step_s
+    dispatches_off = rm_off.engine.dispatch_count - dispatches_before
     assert all(o.error is None for o in outs_off)
     # the dense layout has no page reservation; prefill goes through
     # the sync step, decode-only iterations through the pipeline
