@@ -627,14 +627,99 @@ def routed_tile(tokens: int, k: int, experts_held: Tuple[int, int],
     return grouped_tile(tokens * k, hi - lo, routed)
 
 
+#: pairs a block of :func:`pair_layout`'s running count: the rows of one
+#: pass through the MXU, and few enough that a count is exact in bfloat16
+_COUNT_BLOCK = 128
+
+
+@functools.partial(jax.jit, static_argnames=("n", "tm", "k"))
+def pair_layout(group, n: int, tm: int, k: int):
+    """Where the grouped matmuls' rows lie, BY COUNTING: ``group`` (P,)
+    int32 names each (token, expert) pair's expert among the ``n`` held,
+    pair ``p`` being token ``p // k``'s, and ``n`` itself a pair that is
+    in no group. Every expert's rows start at a multiple of the row tile
+    ``tm`` (1: the rows lie end to end) and keep the pairs' own order, so
+    a pair's row is its expert's first row plus the pairs of the same
+    expert BEFORE it: what a stable sort by expert gives, with no sort.
+    That count is the exclusive running sum of the pairs' one-hot down
+    the pairs: inside a block of :data:`_COUNT_BLOCK` pairs one matmul
+    against the strictly lower triangle (0 / 1 in bfloat16, sums of at
+    most 127 in float32: exact), and across the blocks a running sum of
+    the blocks' totals (int32, P / 128 rows): work linear in the pairs.
+
+    -> (``counts`` (n,) the pairs of each expert; ``place`` (P,) each
+    pair's row, 0 for a pair in no group; ``source`` (rows,) the token
+    of each row, ONE scatter of the pairs' tokens at their places (no
+    two pairs share a row), 0 in the rows no pair has; ``ends`` (n,)
+    the row after each expert's last aligned row), all int32, ``rows =
+    tiles * tm`` the static bound ``P + n * (tm - 1)`` in whole tiles."""
+    P, B = group.shape[0], _COUNT_BLOCK
+    blocks = -(-P // B)
+    rows = -(-(P + n * (tm - 1)) // tm) * tm
+    hot = jax.nn.one_hot(
+        jnp.pad(group, (0, blocks * B - P), constant_values=n).reshape(blocks, B),
+        n, dtype=jnp.bfloat16)                                   # (blocks, B, n)
+    before = jnp.einsum(
+        "ij,bjn->bin", jnp.tril(jnp.ones((B, B), jnp.bfloat16), -1), hot,
+        preferred_element_type=jnp.float32).astype(jnp.int32)   # in the block
+    totals = jnp.sum(hot, axis=1, dtype=jnp.float32).astype(jnp.int32)
+    upto = jnp.cumsum(totals, axis=0)                            # (blocks, n)
+    counts = upto[-1]
+    aligned = -(-counts // tm) * tm
+    ends = jnp.cumsum(aligned)
+    first = (ends - aligned) + (upto - totals)    # an expert's first row, a block
+    place = jnp.sum(jnp.where(hot > 0, before + first[:, None, :], 0),
+                    axis=-1).reshape(-1)[:P]
+    # a pair in no group goes past the rows, and is dropped
+    source = jnp.zeros((rows,), jnp.int32).at[
+        jnp.where(group < n, place, rows)].set(
+            jnp.arange(P, dtype=jnp.int32) // k, mode="drop")
+    return counts, place, source, ends
+
+
+@jax.jit
+def pairs_to_tokens(rows, place, held, weights):
+    """The experts' results brought back: ``rows`` (R, D) float32 by
+    aligned row, ``place`` (T, k) each pair's row, ``held`` (T, k)
+    whether the pair has a result, ``weights`` (T, k) -> (T, D)
+    float32, token t's ``sum_j weights[t, j] rows[place[t, j]]`` over
+    its held pairs: one gather of the pairs' rows and one sum over k,
+    in float32. A pair in no group has no result: that is said here, by
+    the mask, and not by what a grouped matmul leaves in rows it never
+    wrote."""
+    T, k = place.shape
+    got = jnp.take(rows, place.reshape(-1), axis=0, mode="clip")
+    return jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
+                      jnp.where(held[..., None], got.reshape(T, k, -1), 0.0))
+
+
+@functools.partial(jax.jit, static_argnames=("tiles", "tm"))
+def tile_experts(ends, tiles: int, tm: int):
+    """-> (``tile_group`` (tiles,) the expert each ``tm``-row tile of
+    :func:`pair_layout`'s rows belongs to, ``n_active`` the tiles up to
+    the last expert's last row), int32, from ``ends`` (n,). A tile's
+    expert is the number of experts whose rows end at or before the
+    tile's first row (a compare and a sum: ``searchsorted`` with no
+    loop); the tiles past ``n_active``, which the grouped matmuls skip,
+    repeat the last active tile's, so that they ask for no other
+    weight block."""
+    n_active = ends[-1] // tm
+    tile = jnp.arange(tiles, dtype=jnp.int32)
+    tile_group = jnp.sum(ends[None, :] <= (tile * tm)[:, None], axis=1,
+                         dtype=jnp.int32)
+    last = tile_group[jnp.maximum(n_active - 1, 0)]
+    return jnp.minimum(jnp.where(tile < n_active, tile_group, last),
+                       ends.shape[0] - 1), n_active
+
+
 @sublayer("moe.route")
 def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
                        experts_held: Tuple[int, int], routed=None,
                        layer=None, kernels: str = "xla",
                        activation: str = "silu"):
     """The routed half of a sparse FFN as a GROUPED matmul: the (token,
-    expert) pairs of real tokens sorted by expert, one grouped matmul a
-    projection over the groups, the pairs' results weighted and summed
+    expert) pairs of real tokens laid out by expert, one grouped matmul
+    a projection over the groups, the pairs' results weighted and summed
     back by token. Its FLOPs follow the rows, not rows x experts, and it
     reads the weights of the experts that have rows. Beside
     :func:`_moe_ffn`, which computes every expert for every position.
@@ -643,8 +728,20 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     :func:`serve_step_paged` from 16 pairs an expert on
     (:func:`routes_tokens`, :func:`_routed_ffn`).
 
-    ``kernels="xla"``: ``lax.ragged_dot`` over the sorted rows (on the
-    chip the compiler's own grouped-matmul kernel). ``"pallas"``:
+    The layout is built by counting (:func:`pair_layout`: no sort, one
+    scatter of the pairs' tokens) and each pair's row moves once in (one
+    gather of ``h`` by ``source``) and once out (one gather of the
+    experts' results by ``place``, weighted and summed by token). A row
+    of the layout that no pair has (the alignment to the tile, the
+    static bound's tail) holds token 0's row and not zeros: the grouped
+    matmuls compute it where its tile is active and NOTHING reads its
+    result, since ``place`` names real pairs' rows alone and a pair in
+    no group is masked after the gather; a pass that zeroed those rows
+    wrote and read the whole aligned array once more a layer (PERF.md
+    section 6, PR 57).
+
+    ``kernels="xla"``: ``lax.ragged_dot`` over the rows at a tile of one
+    (on the chip the compiler's own grouped-matmul kernel). ``"pallas"``:
     serve/kernels ``grouped_glu`` / ``grouped_down``
     (``ff_moe_grouped_*``), for which every expert's rows start at a
     multiple of the row tile, so a tile has one expert, named in the
@@ -677,65 +774,44 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     Returns (out (T, D) in h's dtype, counts (hi - lo,) int32: the real
     tokens each held expert was given)."""
     T, k = experts.shape
-    P = T * k
     lo, hi = experts_held
     n = hi - lo
     held = real[:, None] & (experts >= lo) & (experts < hi)
-    group = jnp.where(held, experts - lo, n).reshape(-1)   # n: no group, sorts last
-    order = jnp.argsort(group, stable=True)
-    counts = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
+    group = jnp.where(held, experts - lo, n).reshape(-1)   # n: in no group
     first = 0
     if layer is not None:
         first = layer * n
         w_gate, w_up, w_down = (
             w.reshape((-1,) + w.shape[2:]) for w in (w_gate, w_up, w_down))
     w_gate, w_up, w_down = (_dense_w(w, h.dtype) for w in (w_gate, w_up, w_down))
+    tm = routed_tile(T, k, experts_held, routed) if kernels == "pallas" else 1
+    counts, place, source, ends = pair_layout(group, n, tm, k)
+    rows = h.at[source].get(mode="promise_in_bounds")
     if kernels == "pallas":
         from ..serve import kernels as _pk
 
-        tm = routed_tile(T, k, experts_held, routed)
-        tiles = -(-(P + n * (tm - 1)) // tm)
-        aligned = -(-counts // tm) * tm
-        ends = jnp.cumsum(aligned)
-        by_group = group[order]                               # sorted; n at the tail
-        g = jnp.minimum(by_group, n - 1)
-        rank = jnp.arange(P, dtype=jnp.int32) - (jnp.cumsum(counts) - counts)[g]
-        at = jnp.where(by_group < n, (ends - aligned)[g] + rank, tiles * tm)
-        source = jnp.full((tiles * tm,), T, jnp.int32).at[at].set(
-            (order // k).astype(jnp.int32), mode="drop")
-        rows = jnp.take(h, source, axis=0, mode="fill", fill_value=0)
-        n_active = ends[-1] // tm
-        tile = jnp.arange(tiles, dtype=jnp.int32)
-        tile_group = jnp.searchsorted(ends, tile * tm, side="right")
-        last = tile_group[jnp.maximum(n_active - 1, 0)]
-        tile_group = first + jnp.minimum(
-            jnp.where(tile < n_active, tile_group, last), n - 1)
+        tile_group, n_active = tile_experts(ends, source.shape[0] // tm, tm)
+        tile_group = first + tile_group
         with sublayer("ffn"):
+            # the weight fetches' scalars, once for the layer's two calls
+            fetches = _pk.grouped_fetches(tile_group, n_active)
             act = _pk.grouped_glu(rows, w_gate, w_up, tile_group, n_active,
-                                  tm=tm, activation=activation)
-            out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm)
-        place = jnp.zeros((P,), jnp.int32).at[order].set(at.astype(jnp.int32))
-        out = jnp.take(out, place, axis=0, mode="clip")
+                                  tm=tm, activation=activation,
+                                  fetches=fetches)
+            out = _pk.grouped_down(act, w_down, tile_group, n_active, tm=tm,
+                                   fetches=fetches)
     else:
         sizes = counts
         if layer is not None:
             sizes = lax.dynamic_update_slice(
                 jnp.zeros((w_gate.shape[0],), jnp.int32), counts, (first,))
-        rows = jnp.take(h, order // k, axis=0)               # (P, D), by expert
         dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
                                 preferred_element_type=jnp.float32)
         with sublayer("ffn"):
             act = (getattr(jax.nn, activation)(dot(rows, w_gate))
                    * dot(rows, w_up)).astype(h.dtype)
             out = dot(act, w_down)                           # (P, D) float32
-        place = jnp.zeros((P,), jnp.int32).at[order].set(
-            jnp.arange(P, dtype=jnp.int32))
-        out = jnp.take(out, place, axis=0)
-    # a pair in no group has no result: say so here and not by what a
-    # grouped matmul leaves in rows it never wrote
-    out = out.reshape(T, k, -1)
-    out = jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
-                     jnp.where(held[..., None], out, 0.0))
+    out = pairs_to_tokens(out, place.reshape(T, k), held, weights)
     return out.astype(h.dtype), counts
 
 

@@ -23,12 +23,12 @@ from __future__ import annotations
 import contextlib
 import re
 import weakref
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 
-__all__ = ["SUBLAYERS", "sublayer", "sublayer_of", "parse_scope_map",
-           "scope_maps"]
+__all__ = ["SUBLAYERS", "sublayer", "sublayer_of", "Instruction",
+           "parse_instructions", "parse_scope_map", "scope_maps"]
 
 #: The sublayers of a step, by scope name less its ``ff.`` (PERF.md
 #: section 3 has what is under each and where it sits in the code).
@@ -92,16 +92,27 @@ def sublayer_of(op_name: str) -> Optional[str]:
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+) = ")
+_SHAPE_OPCODE = re.compile(r"(\(.*?\)|\S+) ([\w\-]+)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
-def parse_scope_map(hlo_text: str) -> Dict[str, Optional[str]]:
-    """``{instruction name: sublayer or None}`` over every instruction
-    of a compiled module's text. An instruction with no ``ff.*`` in its
-    own ``op_name`` that calls a computation (a fusion) takes its
-    called computation's root's."""
-    own, roots, calls = {}, {}, {}
+class Instruction(NamedTuple):
+    """One instruction of a compiled module's text."""
+    computation: Optional[str]  # the computation it is written in
+    root: bool                  # that computation's ROOT
+    opcode: str                 # "fusion", "custom-call", ...; "" unread
+    shape: str                  # its result's, less the layout; "" unread
+    op_name: str                # its metadata's; "" where it has none
+    calls: Optional[str]        # the computation it calls (a fusion's body)
+
+
+def parse_instructions(hlo_text: str) -> Dict[str, Instruction]:
+    """``{instruction name: Instruction}`` over every instruction of a
+    compiled module's text, in the text's order: the one reading of the
+    text that :func:`parse_scope_map` and the tools that table a
+    profile by instruction (``scripts/route_ops.py``) share."""
+    out = {}
     computation = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -110,15 +121,27 @@ def parse_scope_map(hlo_text: str) -> Dict[str, Optional[str]]:
             if c is not None:
                 computation = c.group(1)
             continue
-        name = m.group(2)
+        what = _SHAPE_OPCODE.match(line, m.end())
+        shape, opcode = what.groups() if what else ("", "")
         op = _OP_NAME.search(line)
-        own[name] = sublayer_of(op.group(1)) if op else None
-        if m.group(1):
-            roots[computation] = name
-        if own[name] is None:
-            called = _CALLS.search(line)
-            if called is not None:
-                calls[name] = called.group(1)
+        called = _CALLS.search(line)
+        out[m.group(2)] = Instruction(
+            computation, bool(m.group(1)), opcode,
+            re.sub(r"\{[^}]*\}", "", shape), op.group(1) if op else "",
+            called.group(1) if called else None)
+    return out
+
+
+def parse_scope_map(hlo_text: str) -> Dict[str, Optional[str]]:
+    """``{instruction name: sublayer or None}`` over every instruction
+    of a compiled module's text. An instruction with no ``ff.*`` in its
+    own ``op_name`` that calls a computation (a fusion) takes its
+    called computation's root's."""
+    instructions = parse_instructions(hlo_text)
+    own = {name: sublayer_of(i.op_name) for name, i in instructions.items()}
+    roots = {i.computation: name for name, i in instructions.items() if i.root}
+    calls = {name: i.calls for name, i in instructions.items()
+             if own[name] is None and i.calls is not None}
     for name, called in calls.items():
         seen = set()
         while own[name] is None and called in roots and called not in seen:

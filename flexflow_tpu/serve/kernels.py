@@ -1704,12 +1704,14 @@ def fetch_of_step(j, run, runs, blocks):
 
 @functools.lru_cache(maxsize=None)
 def _grouped_call(name, compute, tm: int, tn: int, dtype, interpret: bool):
-    """-> the jitted ``call(rows, weights, tile_group, n_active)``:
-    ``compute(rows' tile, the tile's expert's block of each of
-    weights)`` tile by tile: rows (P, K), ``weights`` stacks (G, K, N)
+    """-> the jitted ``call(rows, weights, tile_group, n_active,
+    fetches)``: ``compute(rows' tile, the tile's expert's block of each
+    of weights)`` tile by tile: rows (P, K), ``weights`` stacks (G, K, N)
     read as (K, tn) blocks, ``tile_group`` (P / tm,) the index into G
-    of each tile's expert, ``n_active`` the tiles that hold a row ->
-    (P, N) ``dtype``. The grid runs the column blocks outermost and the
+    of each tile's expert, ``n_active`` the tiles that hold a row,
+    ``fetches`` :func:`grouped_fetches` of those two where the caller
+    has them already (a layer's two calls share one; None: reckoned
+    here) -> (P, N) ``dtype``. The grid runs the column blocks outermost and the
     tiles innermost. The rows' and the result's blocks change every
     step and are the pipeline's; the weights stay in HBM and the kernel
     copies their blocks itself into two slots a stack, a run ahead
@@ -1738,7 +1740,7 @@ def _grouped_call(name, compute, tm: int, tn: int, dtype, interpret: bool):
 
 
 def _grouped(name, compute, tm, tn, dtype, interpret, rows, weights,
-             tile_group, n_active):
+             tile_group, n_active, fetches=None):
     P, K = rows.shape
     N = weights[0].shape[-1]
     assert P % tm == 0 and N % tn == 0, (P, tm, N, tn)
@@ -1802,7 +1804,7 @@ def _grouped(name, compute, tm, tn, dtype, interpret, rows, weights,
         name=name,
         interpret=interpret,
     )(tile_group.astype(jnp.int32), n_active.astype(jnp.int32).reshape(1),
-      *grouped_fetches(tile_group, n_active), rows, *weights)
+      *(fetches or grouped_fetches(tile_group, n_active)), rows, *weights)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1822,7 +1824,7 @@ def _dot(a, w):
 
 
 def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
-                activation: str = "silu"):
+                activation: str = "silu", fetches=None):
     """``act(rows W_gate[g]) * (rows W_up[g])`` tile by tile, ``g`` the
     expert of the tile and ``act`` the static ``activation``, a name of
     ``jax.nn`` (``silu`` where nothing is said; ``relu``: ReGLU,
@@ -1837,22 +1839,24 @@ def grouped_glu(rows, w_gate, w_up, tile_group, n_active, *, tm: int,
     use (:func:`_grouped_call`), so the weights read are those of the
     experts that have rows, once, and a call costs the larger of their
     read and its matmuls (``tf``: :func:`grouped_block`). -> (P, F) in
-    rows' dtype."""
+    rows' dtype. ``fetches``: :func:`grouped_fetches` of ``tile_group``
+    and ``n_active`` where the caller has reckoned them."""
     D, F = w_gate.shape[-2:]
     return _grouped_call(
         f"ff_moe_grouped_glu_t{tm}", _glu(activation), tm,
         grouped_block(F, D, 2, w_gate.dtype.itemsize), rows.dtype,
-        _interpret())(rows, (w_gate, w_up), tile_group, n_active)
+        _interpret())(rows, (w_gate, w_up), tile_group, n_active, fetches)
 
 
-def grouped_down(act, w_down, tile_group, n_active, *, tm: int):
-    """``act W_down[g]`` tile by tile (see :func:`grouped_glu`): act
-    (P, F), ``w_down`` (G, F, D) -> (P, D) float32."""
+def grouped_down(act, w_down, tile_group, n_active, *, tm: int, fetches=None):
+    """``act W_down[g]`` tile by tile (see :func:`grouped_glu`, whose
+    ``fetches`` it shares): act (P, F), ``w_down`` (G, F, D) -> (P, D)
+    float32."""
     F, D = w_down.shape[-2:]
     return _grouped_call(
         f"ff_moe_grouped_down_t{tm}", _dot, tm,
         grouped_block(D, F, 1, w_down.dtype.itemsize), jnp.float32,
-        _interpret())(act, (w_down,), tile_group, n_active)
+        _interpret())(act, (w_down,), tile_group, n_active, fetches)
 
 
 # ---------------------------------------------------------------------------

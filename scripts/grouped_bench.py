@@ -15,6 +15,7 @@ natural skew), the same draw for every candidate of a case.
 
     chiprun -- python scripts/grouped_bench.py            # every case
     chiprun -- python scripts/grouped_bench.py --rule     # the rule's own
+    chiprun -- python scripts/grouped_bench.py --route    # the layer whole
     python scripts/grouped_bench.py --tiny                 # CPU rehearsal
 
 Beside each case it prints the least times of its weights' read and of
@@ -22,6 +23,14 @@ its FLOPs, their larger (where a call that hides one under the other
 stands) and their sum (where one that hides nothing does), and each
 line carries a ``digest`` of the layer's result, so that two trees'
 runs of one case can be held to each other bit for bit (PR 52).
+
+``--route`` times ``transformer.routed_experts_ffn`` WHOLE at a case's
+shapes under the rule's tile (``--middle-tile`` sets
+``GROUPED_MIDDLE_TILE`` for the run), a drawn routing of ``real / k``
+real tokens, and beside it the two calls alone on that routing's tokens
+per expert: their difference is what the layer costs OUTSIDE its
+kernels (the layout's tables, the rows gathered in, the results
+gathered out and summed; PR 57), with a digest of the layer's result.
 
 Off a TPU whose ``device_kind`` is in ``benchmarks/peaks.json`` it
 exits without a reading unless ``--tiny`` is given (on the CPU the
@@ -90,8 +99,18 @@ CASES = {
     # DeepSeek-V3's held share: 16 experts of 256 at the 256 rung's 2048
     # pairs, a sixteenth of them for the experts here (one tile each)
     "deepseek.256": (16, 7168, 2048, 2048, 128, 2, [(128, None, None)]),
+    # its padded step: 512 places x 8, some 300 real tokens
+    "deepseek.512": (16, 7168, 2048, 4096, 2400, 2, [(128, None, None)]),
+    # Qwen3-Next's held quarter (128 of 512): the decode step (64 rows x
+    # 10) and its widest rung (2048 places)
+    "qwen3next.c1": (128, 2048, 512, 640, 640, 6, [(16, None, None)]),
+    "qwen3next.2048": (128, 2048, 512, 20480, 20000, 6, [(128, None, None)]),
 }
 TINY = {"tiny": (4, 128, 256, 64, 40, 2, [(16, None, None), (64, 128, 128)])}
+#: name -> (experts a token, the router's outputs) of ``--route``; the
+#: experts held are the router's first ``experts`` outputs
+ROUTES = {"smallthinker": (6, 64), "mixtral": (2, 8), "lfm2": (4, 64),
+          "deepseek": (8, 256), "qwen3next": (10, 512), "tiny": (2, 8)}
 
 
 def layout(counts, tile, pairs):
@@ -106,6 +125,12 @@ def layout(counts, tile, pairs):
     group = np.minimum(np.where(np.arange(tiles) < n_active, group,
                                 group[max(n_active - 1, 0)]), n - 1)
     return tiles * tile, group.astype(np.int32), n_active
+
+
+def draw(key, shape):
+    """Seeded bfloat16 weights (or rows) at 0.02 an element."""
+    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(
+        jnp.bfloat16)
 
 
 def device_peaks(tiny):
@@ -132,8 +157,6 @@ def run_case(name, case, reps, out, device, peaks, counts=None):
     real = int(counts.sum())
     key = jax.random.PRNGKey(51)
     kg, ku, kd, kx = jax.random.split(key, 4)
-    draw = lambda k, s: (jax.random.normal(k, s, jnp.float32) * 0.02).astype(
-        jnp.bfloat16)
     w_gate, w_up = draw(kg, (L * E, D, F)), draw(ku, (L * E, D, F))
     w_down = draw(kd, (L * E, F, D))
     print(f"# {name}: {E} experts, D {D}, F {F}, {pairs} static pairs, "
@@ -235,6 +258,60 @@ def run_case(name, case, reps, out, device, peaks, counts=None):
         out.append(line)
 
 
+def run_route(name, case, reps, out, device, peaks):
+    """One line: the layer whole, its two calls alone on the same
+    tokens per expert, their difference, the layer's digest."""
+    from flexflow_tpu.models import transformer
+
+    E, D, F, pairs, real, L, _ = case
+    k, routed = ROUTES[name.split(".")[0]]
+    T = pairs // k
+    rng = np.random.default_rng(57)
+    experts = np.argsort(rng.random((T, routed)), axis=1)[:, :k].astype(np.int32)
+    is_real = np.arange(T) < real // k
+    tile = transformer.routed_tile(T, k, (0, E), routed)
+    counts = np.bincount(experts[is_real].reshape(-1), minlength=routed)[:E]
+    run_case(name, case[:-1] + ([(tile, None, None)],), reps, out, device,
+             peaks, counts)
+    alone = out.pop()
+    key = jax.random.PRNGKey(57)
+    kg, ku, kd, kx = jax.random.split(key, 4)
+    stacks = (draw(kg, (L, E, D, F)), draw(ku, (L, E, D, F)),
+              draw(kd, (L, E, F, D)))
+    h = draw(kx, (T, D)) * 50
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
+    layer = lambda h, experts, l, stacks: transformer.routed_experts_ffn(
+        h, jnp.asarray(is_real), experts, weights, *stacks,
+        experts_held=(0, E), routed=routed, layer=l, kernels="pallas")[0]
+
+    @jax.jit
+    def whole(h, experts, stacks):
+        # every layer routes anew (its experts a rotation of the draw),
+        # as a model's layers do: nothing of the layout leaves the loop
+        return jax.lax.fori_loop(
+            0, L, lambda l, x: x + layer(x, (experts + l) % routed, l, stacks),
+            h)
+
+    args = (h, jnp.asarray(experts), stacks)
+    jax.block_until_ready(whole(*args))
+    t = time.perf_counter()
+    for _ in range(reps):
+        r = whole(*args)
+    jax.block_until_ready(r)
+    whole_ms = (time.perf_counter() - t) / (reps * L) * 1e3
+    y = np.asarray(jax.jit(layer)(h, args[1], jnp.int32(0), stacks).astype(
+        jnp.float32))
+    line = dict(device, case=name, tile=tile, pairs=pairs,
+                real_pairs=int(is_real.sum()) * k, held_pairs=int(counts.sum()),
+                rows=alone.get("rows"), whole_ms=round(whole_ms, 4),
+                kernels_ms=alone.get("layer_ms"))
+    if "layer_ms" in alone:
+        line["route_ms"] = round(whole_ms - alone["layer_ms"], 4)
+    line["digest"] = hashlib.sha256(y.tobytes()).hexdigest()[:16]
+    print(json.dumps(line), flush=True)
+    out.append(line)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cases", nargs="*", default=None)
@@ -245,7 +322,14 @@ def main():
     ap.add_argument("--counts", default=None, help=(
         "a .npy of served tokens per expert, (steps, layers, experts): the "
         "first case named runs on the step of median load, its middle layer"))
+    ap.add_argument("--route", action="store_true", help=(
+        "time routed_experts_ffn whole and its two calls alone: the layer "
+        "outside its kernels"))
+    ap.add_argument("--middle-tile", type=int, default=None, help=(
+        "run under this GROUPED_MIDDLE_TILE (a reading; the rule keeps its own)"))
     args = ap.parse_args()
+    if args.middle_tile:
+        kernels.GROUPED_MIDDLE_TILE = args.middle_tile
     cases = TINY if args.tiny else CASES
     device, peaks = device_peaks(args.tiny)
     print(f"# {device}", flush=True)
@@ -256,6 +340,10 @@ def main():
         step = steps[np.argsort(steps.sum(axis=(1, 2)))[len(steps) // 2]]
         served = step[len(step) // 2].astype(np.int64)
     for name in args.cases or cases:
+        if args.route:
+            run_route(name, cases[name], 2 if args.tiny else args.reps, out,
+                      device, peaks)
+            continue
         if args.rule:
             E, _, _, pairs = cases[name][:4]
             cases[name] = cases[name][:-1] + (

@@ -956,6 +956,72 @@ def test_grouped_expert_matmuls_compile_at_mixtral_widths(chip, tm,
             for call in calls] == [["w_gate", "w_up"], ["w_down"]], calls
 
 
+def _entry(text):
+    """[(result shape less its layout, opcode)] of the instructions of a
+    compiled module's ENTRY computation: what the device runs one by
+    one (a fusion is one; what is fused into it is not listed)."""
+    from flexflow_tpu.obs.sublayers import parse_instructions
+
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", text, re.M).group(1)
+    return [(i.shape, i.opcode) for i in parse_instructions(text).values()
+            if i.computation == entry]
+
+
+@pytest.mark.parametrize("case, T, k, n, routed, D, F", [
+    # SmallThinker's padded step: 6144 pairs over 64 experts, 32-row tiles
+    ("smallthinker", 1024, 6, 64, 64, 2560, 768),
+    # Qwen3-Next's widest rung: 20480 pairs, 128 held of 512
+    ("qwen3_next_2048", 2048, 10, 128, 512, 2048, 512),
+])
+def test_routed_layer_moves_each_row_once_in_and_once_out(chip, case, T, k, n,
+                                                          routed, D, F):
+    """``routed_experts_ffn(kernels="pallas")`` alone, compiled: the
+    layout is COUNTED (no ``sort``, no ``while``: ``searchsorted``'s
+    loop; at most one ``scatter``, of the pairs' tokens), the aligned
+    rows are produced ONCE outside the kernels (the gather of ``h``; a
+    pass that zeroed the rows no pair has was the whole array read and
+    written again), the experts' results gathered once (the pairs'
+    rows, (P, D) float32: what only a kernel's edge can take), and the
+    weight fetches' scalars reckoned once for the two calls
+    (``grouped_fetches``' running sum and minimum are the program's
+    only ``reduce-window``s beside the layout's own). The guard that
+    keeps the routed layer's bytes from coming back (PR 57)."""
+    from flexflow_tpu.models import transformer
+
+    P, tm = T * k, transformer.routed_tile(T, k, (0, n), routed)
+    rows = _pair_rows(P, n, routed)
+    stack = lambda *shape: chip((2, n) + shape, jnp.bfloat16)
+
+    def fn(h, real, experts, weights, w_gate, w_up, w_down):
+        return transformer.routed_experts_ffn(
+            h, real, experts, weights, w_gate, w_up, w_down,
+            experts_held=(0, n), routed=routed, layer=jnp.int32(1),
+            kernels="pallas")
+
+    _, text = _compile(
+        fn, chip((T, D), jnp.bfloat16), chip((T,), jnp.bool_),
+        chip((T, k), jnp.int32), chip((T, k), jnp.float32),
+        stack(D, F), stack(D, F), stack(F, D))
+    assert f"%ff_moe_grouped_glu_t{tm}" in text
+    assert f"%ff_moe_grouped_down_t{tm}" in text
+    assert not re.findall(r" (?:sort|while)\(", text)
+    assert len(re.findall(r" scatter\(", text)) <= 1
+    entry = _entry(text)
+    made = lambda shape: [op for s, op in entry if s == shape
+                          and op not in ("bitcast", "parameter")]
+    assert made(f"bf16[{rows},{D}]") == ["fusion"]      # h's rows, gathered
+    assert made(f"f32[{rows},{D}]") == ["custom-call"]  # grouped_down's
+    assert made(f"f32[{P},{D}]") == ["fusion"]          # gathered back, once
+    # the running sums: the layout's two and the fetches' own, once
+    windows = lambda fn, *args: sum(
+        op == "reduce-window" for _, op in _entry(_compile(fn, *args)[1]))
+    tiles = chip((rows // tm,), jnp.int32)
+    assert sum(op == "reduce-window" for _, op in entry) == (
+        windows(lambda g: transformer.pair_layout(g, n, tm, k),
+                chip((P,), jnp.int32))
+        + windows(kernels.grouped_fetches, tiles, chip((), jnp.int32)))
+
+
 # --- latent attention over a compressed paged line (DeepSeek-V3) ------------
 
 

@@ -475,6 +475,187 @@ def test_grouped_kernels_match_ragged_dot_at_every_tile(tm, T, activation):
     assert not np.asarray(alone)[~real].any()
 
 
+def _sorted_tables(group, n, tm, k, T):
+    """The layout's tables as the SORT built them (``routed_experts_ffn``
+    up to PR 56, kept here as the reference the counting is held to):
+    a stable argsort of the pairs by expert, ``searchsorted`` over the
+    aligned ends, a scatter of the sorted pairs' tokens and a second
+    scatter of their places back to pair order. -> (counts, place,
+    source (``T``: a row no pair has), tile_group, n_active)."""
+    P = group.shape[0]
+    order = jnp.argsort(group, stable=True)
+    counts = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
+    tiles = -(-(P + n * (tm - 1)) // tm)
+    aligned = -(-counts // tm) * tm
+    ends = jnp.cumsum(aligned)
+    by_group = group[order]                               # sorted; n at the tail
+    g = jnp.minimum(by_group, n - 1)
+    rank = jnp.arange(P, dtype=jnp.int32) - (jnp.cumsum(counts) - counts)[g]
+    at = jnp.where(by_group < n, (ends - aligned)[g] + rank, tiles * tm)
+    source = jnp.full((tiles * tm,), T, jnp.int32).at[at].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    n_active = ends[-1] // tm
+    tile = jnp.arange(tiles, dtype=jnp.int32)
+    tile_group = jnp.searchsorted(ends, tile * tm, side="right")
+    last = tile_group[jnp.maximum(n_active - 1, 0)]
+    tile_group = jnp.minimum(
+        jnp.where(tile < n_active, tile_group, last), n - 1)
+    place = jnp.zeros((P,), jnp.int32).at[order].set(at.astype(jnp.int32))
+    return counts, place, source, tile_group, n_active
+
+
+def _token_major_sum(rows, place, held, weights):
+    """The weighted sum as ``routed_experts_ffn`` wrote it up to PR 56:
+    one gather of all the pairs' rows, seen (T, k, D), one einsum over
+    k."""
+    T, k = place.shape
+    got = jnp.take(rows, place.reshape(-1), axis=0, mode="clip").reshape(T, k, -1)
+    return jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
+                      jnp.where(held[..., None], got, 0.0))
+
+
+def _sorted_routed_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
+                       experts_held, routed, layer, activation):
+    """``routed_experts_ffn(kernels="pallas")`` as it stood up to PR 56:
+    the sort-built tables, the aligned rows gathered with the rows no
+    pair has ZEROED, the same two kernels."""
+    T, k = experts.shape
+    lo, hi = experts_held
+    n = hi - lo
+    held = real[:, None] & (experts >= lo) & (experts < hi)
+    group = jnp.where(held, experts - lo, n).reshape(-1)
+    tm = transformer.routed_tile(T, k, experts_held, routed)
+    counts, place, source, tile_group, n_active = _sorted_tables(
+        group, n, tm, k, T)
+    w_gate, w_up, w_down = (
+        w.reshape((-1,) + w.shape[2:]) for w in (w_gate, w_up, w_down))
+    rows = jnp.take(h, source, axis=0, mode="fill", fill_value=0)
+    tile_group = layer * n + tile_group
+    act = serve_kernels.grouped_glu(rows, w_gate, w_up, tile_group, n_active,
+                                    tm=tm, activation=activation)
+    out = serve_kernels.grouped_down(act, w_down, tile_group, n_active, tm=tm)
+    out = _token_major_sum(out, place.reshape(T, k), held, weights)
+    return out.astype(h.dtype), counts
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("tm, T", [(16, 64), (32, 160), (128, 288)])
+def test_routed_ffn_is_the_sorted_layouts_bit_for_bit(tm, T, activation):
+    """The layer over the layout built by counting, with the rows no
+    pair has left as they are gathered, against the layer over the
+    sort-built layout with those rows zeroed (PR 56's), on
+    ``test_grouped_kernels_match_ragged_dot_at_every_tile``'s cases:
+    the kernels are given the same real rows in the same order, no
+    other row's result is read, and the weighted sum is the same one,
+    so the results are EQUAL, bit for bit."""
+    k, routed, (lo, hi), L, D, F = 2, 8, (2, 6), 2, 32, 48
+    experts, real = _pairs_by_count(tm, T, range(lo, hi), (0, 7))
+    rng = np.random.default_rng(tm)
+    h = jnp.asarray(rng.normal(size=(T, D)), jnp.float32)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(L, hi - lo, D, F)) * 0.2,
+                                jnp.float32) for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(L, hi - lo, F, D)) * 0.2, jnp.float32)
+    args = (h, jnp.asarray(real), jnp.asarray(experts), weights, w_gate, w_up,
+            w_down)
+    kw = dict(experts_held=(lo, hi), routed=routed, layer=jnp.int32(1),
+              activation=activation)
+    want, want_counts = _sorted_routed_ffn(*args, **kw)
+    got, counts = transformer.routed_experts_ffn(*args, kernels="pallas", **kw)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.abs(np.asarray(got)[real]).max() > 0
+
+
+@pytest.mark.parametrize("k", [2, 6, 10])
+def test_pairs_to_tokens_sums_the_held_pairs(k):
+    """``pairs_to_tokens`` against the sum written out in float64: each
+    token's held pairs' rows by their weights; a pair that is not held
+    adds nothing whatever its place names (a row a grouped matmul never
+    wrote: NaN here), nor does a token with no held pair."""
+    T, R, D = 24, 40, 16
+    rng = np.random.default_rng(k)
+    rows = rng.normal(size=(R, D)).astype(np.float32)
+    held = rng.random((T, k)) < 0.6
+    held[0] = False
+    place = rng.integers(0, R - 4, size=(T, k)).astype(np.int32)
+    place[~held] = rng.integers(R - 4, R, size=int((~held).sum()))
+    rows[R - 4:] = np.nan                              # rows nobody wrote
+    weights = rng.uniform(0.1, 1.0, size=(T, k)).astype(np.float32)
+    got = np.asarray(transformer.pairs_to_tokens(
+        jnp.asarray(rows), jnp.asarray(place), jnp.asarray(held),
+        jnp.asarray(weights)))
+    want = np.zeros((T, D))
+    for t in range(T):
+        for j in range(k):
+            if held[t, j]:
+                want[t] += np.float64(weights[t, j]) * rows[place[t, j]]
+    assert got.dtype == np.float32 and not got[0].any()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("case, T, k, held, routed, tm, real, on", [
+    # the five routed cells' steps: (places, experts a token, experts
+    # held as a range of the router's outputs, the router's outputs,
+    # the tile ``routed_tile`` gives them), three places in four real
+    ("mixtral", 1024, 2, (0, 8), 8, 128, 0.75, None),
+    ("deepseek", 512, 8, (0, 16), 256, 128, 0.75, None),
+    ("smallthinker", 1024, 6, (0, 64), 64, 32, 0.75, None),
+    ("qwen3_next", 64, 10, (0, 128), 512, 16, 0.75, None),
+    ("lfm2", 64, 4, (0, 64), 64, 16, 0.75, None),
+    # the edges: P = 111 is no multiple of the counting block, and the
+    # held range a strict inner part of the router's outputs
+    ("ragged", 37, 3, (2, 7), 9, 16, 0.75, None),
+    ("no_real_token", 37, 3, (2, 7), 9, 16, 0.0, None),
+    ("every_place_real", 160, 2, (0, 4), 4, 32, 1.0, None),
+    ("every_pair_on_one_expert", 160, 2, (0, 4), 4, 32, 0.75, (1, 1)),
+    ("no_pair_held", 160, 2, (4, 8), 8, 16, 0.75, (0, 3)),
+    # an XLA-path layout: a tile of one row, the rows end to end
+    ("rows_end_to_end", 37, 3, (2, 7), 9, 1, 0.75, None),
+])
+def test_pair_layout_counts_what_the_sort_sorted(case, T, k, held, routed, tm,
+                                                 real, on):
+    """``pair_layout`` and ``tile_experts`` (a one-hot's running count,
+    one scatter, a compare and a sum) against the tables the stable
+    sort, ``searchsorted`` and the two scatters built: the tokens per
+    expert, the tiles' experts and the active tiles EQUAL; every row a
+    pair has names the same token, and a row no pair has names SOME
+    token (it is gathered, computed where its tile is active, and never
+    read); every held pair's place equal."""
+    lo, hi = held
+    n = hi - lo
+    if case in ("mixtral", "deepseek", "smallthinker", "qwen3_next", "lfm2"):
+        assert transformer.routed_tile(T, k, held, routed) == tm
+    rng = np.random.default_rng(len(case))
+    if on is None:   # k distinct outputs of the router a token
+        experts = np.argsort(rng.random((T, routed)), axis=1)[:, :k]
+    else:
+        experts = np.broadcast_to(np.asarray(on), (T, k))
+    is_real = rng.random(T) < real
+    is_held = is_real[:, None] & (experts >= lo) & (experts < hi)
+    group = jnp.asarray(np.where(is_held, experts - lo, n).reshape(-1),
+                        jnp.int32)
+    want_counts, want_place, want_source, want_tiles, want_active = (
+        np.asarray(a) for a in _sorted_tables(group, n, tm, k, T))
+    counts, place, source, ends = transformer.pair_layout(group, n, tm, k)
+    tile_group, n_active = transformer.tile_experts(
+        ends, source.shape[0] // tm, tm)
+    assert all(a.dtype == jnp.int32 for a in (counts, place, source,
+                                              tile_group, n_active))
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    assert int(n_active) == int(want_active)
+    np.testing.assert_array_equal(np.asarray(tile_group), want_tiles)
+    source, place = np.asarray(source), np.asarray(place)
+    assert source.shape == want_source.shape
+    has_pair = want_source < T
+    np.testing.assert_array_equal(source[has_pair], want_source[has_pair])
+    assert ((0 <= source) & (source < T)).all()
+    flat = is_held.reshape(-1)
+    assert int(flat.sum()) == int(has_pair.sum()) == int(want_counts.sum())
+    np.testing.assert_array_equal(place[flat], want_place[flat])
+    assert ((0 <= place) & (place < len(source))).all()
+
+
 #: tokens per expert by the row tile, and the tiles laid out past the
 #: last that holds a row: what the grouped matmuls' weight fetches
 #: (serve/kernels ``grouped_fetches``) are walked over

@@ -203,6 +203,23 @@ def test_parse_scope_map():
                       "ff_ragged_paged_c1.3", "copy.4", "Arg_0.1", "while.6"}
 
 
+def test_parse_instructions():
+    """What ``parse_scope_map`` and ``scripts/route_ops.py`` read of a
+    compiled text: each instruction's computation, opcode, result
+    shape less its layout, own ``op_name`` and called computation."""
+    from flexflow_tpu.obs.sublayers import Instruction, parse_instructions
+
+    got = parse_instructions(HLO)
+    assert list(got) == list(parse_scope_map(HLO))
+    assert got["fusion.9"] == Instruction(
+        "body", False, "fusion", "(f32[4], f32[4])", "", "fused_computation.2")
+    assert got["add.2"] == Instruction(
+        "fused_computation.1", True, "add", "f32[4]",
+        "jit(ff_step_c1)/jit(main)/ff.glue/while/body/ff.ffn/add", None)
+    assert got["ff_ragged_paged_c1.3"].opcode == "custom-call"
+    assert got["while.6"].computation == "main.5" and got["while.6"].root
+
+
 # ---------------------------------------------------------------------------
 # (c) asking for the map traces, compiles and dispatches nothing
 
