@@ -86,6 +86,10 @@ class SchedulerStats:
     failed: int = 0
     prefill_tokens: int = 0       # chunk tokens dispatched
     decode_tokens: int = 0        # decode tokens dispatched
+    # lines of context the dispatched decode rows attend, summed (a row
+    # at position p: p + 1); over ``decode_tokens``: the mean context a
+    # decode row reads, what a full layer's K/V bytes follow
+    decode_context_lines: int = 0
     occupancy_sum: float = 0.0    # active slots / total, summed per step
     budget_fill_sum: float = 0.0  # prefill tokens / budget, per mixed step
     # Automatic prefix caching (serve/prefix_cache.py): admissions that
@@ -243,6 +247,7 @@ class SchedulerStats:
         prefill_tokens: int = 0,
         decode_tokens: int = 0,
         budget: int = 0,
+        decode_context: int = 0,  # the decode rows' positions + 1, summed
     ) -> None:
         self.steps += 1
         if kind == "mixed":
@@ -255,6 +260,7 @@ class SchedulerStats:
             self.sync_steps += 1
         self.prefill_tokens += int(prefill_tokens)
         self.decode_tokens += int(decode_tokens)
+        self.decode_context_lines += int(decode_context)
         if num_slots > 0:
             self.occupancy_sum += active_slots / num_slots
 
@@ -413,6 +419,7 @@ class SchedulerStats:
             "preemptions": self.preemptions,
             "failed": self.failed,
             "prefill_tokens": self.prefill_tokens,
+            "decode_context_lines": self.decode_context_lines,
             "decode_tokens": self.decode_tokens,
             "mean_occupancy": round(self.mean_occupancy, 4),
             "mean_budget_fill": round(self.mean_budget_fill, 4),

@@ -22,7 +22,8 @@ FAMILIES = {
 # The drawn families are served from in-memory (family, cfg, params)
 # on the paged path and have no checkpoint converter, so they are
 # modules to import, not entries above: minicpm_sala, lfm2_moe,
-# deepseek_v3, olmo_hybrid, granite_hybrid, smallthinker, qwen3_next.
+# deepseek_v3, olmo_hybrid, granite_hybrid, smallthinker, qwen3_next,
+# laguna.
 
 __all__ = [
     "llama", "transformer", "opt", "falcon", "mpt", "starcoder", "qwen2",

@@ -93,6 +93,7 @@ from .transformer import (
     routed_experts_ffn,
     run_layers,
     seeded_normal,
+    yarn_inv_freq as _yarn_ramp,
 )
 
 FUSED_DECODE = ()
@@ -280,26 +281,12 @@ def softmax_scale(cfg: DeepseekV3Config) -> float:
 
 
 def yarn_inv_freq(cfg: DeepseekV3Config):
-    """The qk_rope_head_dim / 2 rope frequencies: below the channel where
-    ``rope_original_max`` holds ``beta_fast`` turns the plain ones
-    (theta^(-2i/d)), above the one where it holds ``beta_slow`` the
-    plain ones over ``factor``, a linear ramp between. (numpy float64,
-    cast by the caller.)"""
-    import numpy as np
-
-    d = cfg.qk_rope_head_dim
-    plain = cfg.rope_theta ** -(np.arange(0, d, 2, dtype=np.float64) / d)
-    if cfg.rope_factor <= 1:
-        return plain
-
-    def channel(turns):
-        return d * math.log(cfg.rope_original_max / (turns * 2 * math.pi)) / (
-            2 * math.log(cfg.rope_theta))
-
-    low = max(math.floor(channel(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(channel(cfg.rope_beta_slow)), d - 1)
-    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return plain / cfg.rope_factor * ramp + plain * (1 - ramp)
+    """The qk_rope_head_dim / 2 rope frequencies, by YaRN's ramp
+    (``transformer.yarn_inv_freq``: the one copy, which
+    models/laguna.py's full layers share)."""
+    return _yarn_ramp(
+        cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+        cfg.rope_original_max, cfg.rope_beta_fast, cfg.rope_beta_slow)
 
 
 def rope_cos_sin(cfg: DeepseekV3Config, positions):

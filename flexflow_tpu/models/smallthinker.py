@@ -49,6 +49,18 @@ two classes of page have no such operation yet: prefix caching,
 SpecInfer and beam search, ``kv_quant``, ``fused_decode``,
 ``kv_shard="context"``, the dense layout, a mesh with ``model > 1``.
 
+The two classes of page are written ONCE, here, and serve every family
+that has them (models/laguna.py imports them: window layers of 512
+lines with 64 query heads beside full layers with 48): ``page_classes``
+and its contract, the pools (``init_paged_kv_cache``), ``step_context``
+(a class's table, mask and places, from the step's arrays),
+``attend_class`` (a layer's lines written and attended through ITS
+class, the head count read off the queries), ``_class_places``,
+``_window_mask``, ``_pad_groups``, ``validate_serving`` and the refused
+one-table operations. They read a config's ``sliding_window``,
+``num_key_value_heads``, ``head_dim`` and ``count(kind)`` and nothing
+else of it.
+
 Weight names follow ``benchmarks/harness/model.py::make_params``' rule:
 norm scales hold ``norm_scale``, the projections that write into the
 residual stream are ``wo`` and ``w_down``; groups ``route``
@@ -282,8 +294,8 @@ def expert_routing(cfg: SmallThinkerConfig) -> Tuple[int, Tuple[int, int], int]:
     return cfg.num_experts_per_tok, cfg.held, cfg.num_experts
 
 
-def page_classes(cfg: SmallThinkerConfig):
-    """The classes of page this family's cache is made of, beside
+def page_classes(cfg):
+    """The classes of page this family's (and models/laguna.py's) cache is made of, beside
     ``PAGE_POOLS``: name -> (the class's pools, its window in lines or
     None for a class that keeps every line). The engine keeps a pool
     (sized by ``init_paged_kv_cache``'s ``class_pages``), an allocator
@@ -293,14 +305,15 @@ def page_classes(cfg: SmallThinkerConfig):
             WINDOW: (("k_win", "v_win"), cfg.sliding_window)}
 
 
-def validate_serving(cfg: SmallThinkerConfig, serving, mesh, *,
-                     specinfer: bool = False) -> None:
+def validate_serving(cfg, serving, mesh, *, specinfer: bool = False,
+                     family: str = "smallthinker") -> None:
     """The combinations two classes of page cannot serve yet, refused
-    at engine construction, each naming what is missing."""
+    at engine construction, each naming what is missing. (``family``:
+    the name in the message; models/laguna.py refuses the same seven.)"""
     from ..core.mesh import MODEL_AXIS
 
     def refuse(what, why):
-        raise NotImplementedError(f"smallthinker does not serve {what}: {why}")
+        raise NotImplementedError(f"{family} does not serve {what}: {why}")
 
     if serving.kv_layout != "paged":
         refuse(f"kv_layout={serving.kv_layout!r}",
@@ -334,7 +347,7 @@ def validate_serving(cfg: SmallThinkerConfig, serving, mesh, *,
 
 def _one_table_only(*_a, **_k):
     raise NotImplementedError(
-        "smallthinker keeps two classes of page: committing, copying or "
+        "this family keeps two classes of page: committing, copying or "
         "reordering cache lines goes through one table, and the window "
         "class's has rolled past the lines behind its start")
 
@@ -350,7 +363,7 @@ commit_kv = reorder_slots = _one_table_only
 
 
 def init_paged_kv_cache(
-    cfg: SmallThinkerConfig, num_pages: int, page_size: int, dtype=None,
+    cfg, num_pages: int, page_size: int, dtype=None,
     kv_quant: Optional[str] = None, extra_rows: int = 0, *,
     class_pages: Optional[Dict[str, int]] = None,
 ):
@@ -363,11 +376,11 @@ def init_paged_kv_cache(
     is the full class's and says nothing of the window class's."""
     if kv_quant is not None or extra_rows:
         raise NotImplementedError(
-            "smallthinker's pools are neither quantized nor row-sharded "
+            "the pools of two classes of page are neither quantized nor row-sharded "
             "(validate_serving refuses kv_quant and kv_shard='context')")
     if class_pages is None:
         raise ValueError(
-            "smallthinker keeps a pool a class of page: init_paged_kv_cache "
+            "a pool a class of page: init_paged_kv_cache "
             "needs class_pages (the engine passes its allocators')")
     pages = class_pages
     dt = dtype or cfg.dtype
@@ -379,7 +392,7 @@ def init_paged_kv_cache(
     return cache
 
 
-def paged_kv_cache_pspecs(cfg: SmallThinkerConfig = None, *, pipeline: bool = False,
+def paged_kv_cache_pspecs(cfg=None, *, pipeline: bool = False,
                           kv_quant: Optional[str] = None,
                           kv_shard: Optional[str] = None):
     return {name: P() for name in PAGE_POOLS}
@@ -406,7 +419,8 @@ def _pad_groups(q, kv_heads: int, back: int = 0):
     """(R, C, H, d) queries with each K/V head's GROUP of query heads
     padded with zero heads to a whole float32 sublane tile (8), for the
     ragged paged kernel; ``back``: the kernel's result cut to its
-    ``back`` real heads a group again. The kernel's body works on
+    ``back`` real heads a group again (SmallThinker's group of 7,
+    Laguna's full layers' of 6). The kernel's body works on
     (group x chunk) rows a K/V head, and a group of 7 costs it five
     times a group of 8 (6.31 against 1.19 ms for a window layer's call
     at C=128, 10.06 against 2.17 for a full layer's: my chip runs,
@@ -429,22 +443,21 @@ def _roped(kind: str) -> bool:
     return kind == WINDOW
 
 
-def _attn_block(kind, cfg, ctx, stack, index, x, carried):
+def attend_class(cfg, ctx, kind, carried, index, q, k, v):
+    """One layer's attention over ITS class of page: the step's lines
+    ``k`` / ``v`` (B, T, KV, d) written through the class's table, the
+    queries ``q`` (B, T, H, d) against the class's pool under its mask
+    (the Pallas kernel with each K/V head's group padded to 8 and cut
+    back, tagged ``_win`` for the window class, or its XLA twin).
+    ``H`` is read off ``q``: the layer's own count. -> (attended
+    (B, T, H * d), the class's two pools as written)."""
     from ..serve import kernels as _pk
 
     windowed = kind == WINDOW
     kn, vn = ("k_win", "v_win") if windowed else ("k", "v")
     at = ctx[kind]  # this class's table, write places and mask
-    p = layer_weights(stack, index)
-    B, T, _ = x.shape
-    H, KV, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    h = _norm(cfg, x, p["attn_norm_scale"], None)
-    with sublayer("attn.proj"):
-        q = _mm(h, p["wq"]).reshape(B, T, H, d)
-        k = _mm(h, p["wk"]).reshape(B, T, KV, d)
-        v = _mm(h, p["wv"]).reshape(B, T, KV, d)
-        if _roped(kind):
-            q, k = apply_rope(q, *ctx["rope"]), apply_rope(k, *ctx["rope"])
+    B, T, H, d = q.shape
+    KV = k.shape[2]
     kp, vp, _, _ = _write_kv_lines(
         carried[kn], carried[vn], None, None, index, at["phys"], ctx["off"],
         k.reshape(B, T, KV * d), v.reshape(B, T, KV * d), None)
@@ -465,9 +478,24 @@ def _attn_block(kind, cfg, ctx, stack, index, x, carried):
             o = _serve_attend(cfg, q, k_virt.reshape(split),
                               v_virt.reshape(split), None, at["mask"])
         o = _gather_attended(o, ctx["pack"])
+    return o, {kn: kp, vn: vp}
+
+
+def _attn_block(kind, cfg, ctx, stack, index, x, carried):
+    p = layer_weights(stack, index)
+    B, T, _ = x.shape
+    H, KV, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    h = _norm(cfg, x, p["attn_norm_scale"], None)
+    with sublayer("attn.proj"):
+        q = _mm(h, p["wq"]).reshape(B, T, H, d)
+        k = _mm(h, p["wk"]).reshape(B, T, KV, d)
+        v = _mm(h, p["wv"]).reshape(B, T, KV, d)
+        if _roped(kind):
+            q, k = apply_rope(q, *ctx["rope"]), apply_rope(k, *ctx["rope"])
+    o, pools = attend_class(cfg, ctx, kind, carried, index, q, k, v)
     with sublayer("attn.proj"):
         out = _mm(o, p["wo"])
-    return x + out, dict(carried, **{kn: kp, vn: vp})
+    return x + out, dict(carried, **pools)
 
 
 def _sparse_block(cfg, ctx, stack, index, x, carried):
@@ -510,35 +538,22 @@ def _window_mask(positions, start, lines, window, cache_len):
     return (key_pos <= q) & (key_pos > q - window) & (key_pos < cache_len)
 
 
-@sublayer("glue")
-def serve_step_paged(
-    params: Dict[str, Any],
-    cache: Dict[str, jnp.ndarray],
-    tokens: jnp.ndarray,      # (R, C)
-    positions: jnp.ndarray,   # (R, C); the scratch position is padding
-    logits_idx: jnp.ndarray,  # (R,)
-    mask, cache_positions,
-    page_table,               # {"full": (R, NP), "window": (R, NPw), "window_start": (R,)}
-    *,
-    cfg: SmallThinkerConfig,
-    cache_len: int,
-    all_logits: bool = False,
-    kernels: str = "xla",
-    pack: Optional[int] = None,
-    **unsupported,
-):
-    """The engine's paged step (models/transformer.serve_step_paged's
-    contract, its packed token axis included) over the layer order,
-    with a table a class of page (module docstring; ``window_start``
-    absent: the window table starts at the context's first line, as
-    where the class keeps every page). A row's real positions are its
-    first columns, consecutive. The returned cache also holds
-    ``moe_counts`` (``step_counts``: an output, not an input)."""
-    if mask is not None or cache_positions is not None or any(
-            v for v in unsupported.values()):
-        _one_table_only()
-    if pack is not None and all_logits:
-        raise ValueError("a packed token axis returns one logits row a row")
+def step_context(cache, tokens, positions, page_table, *, window, cache_len,
+                 pack, rope, kernels):
+    """What the blocks of a step over two classes of page read, from
+    the step's (R, C) arrays (a row's real positions are its first
+    columns, consecutive) and its tables ``{"full": (R, NP), "window":
+    (R, NPw), "window_start": (R,)}`` (``window_start`` absent: the
+    window table starts at the context's first line, as where the class
+    keeps every page). The token axis is padded as it comes or PACKED
+    to ``pack`` places (``transformer._pack_tokens``). ``rope``:
+    positions -> the family's rope table or tables, traced under
+    ``ff.attn.proj``. -> (tokens, positions of the token axis, ctx):
+    ``ctx[FULL]`` / ``ctx[WINDOW]`` hold a class's table, its mask over
+    its lines from TRUE positions and the physical page of each place;
+    ``off`` a place's line within its page; ``rope``, ``kernels``,
+    ``q_len``, ``pack``, ``real`` (:func:`attend_class` and
+    ``routed_experts_ffn`` read them)."""
     from ..serve.kernels import paged_serve_mask, real_query_lengths
 
     R, C = tokens.shape
@@ -559,21 +574,56 @@ def serve_step_paged(
         rows = (pack_idx[1] // C)[None]
         real = pos[0] < cache_len
     with sublayer("attn.proj"):
-        rope = rope_freqs(cfg, pos)
+        rope_tables = rope(pos)
     NPw = tables[WINDOW].shape[1]
     masks = {
         FULL: paged_serve_mask(None, positions, tables[FULL].shape[1], ps,
                                cache_len),
-        WINDOW: _window_mask(positions, starts[WINDOW], NPw * ps,
-                             cfg.sliding_window, cache_len),
+        WINDOW: _window_mask(positions, starts[WINDOW], NPw * ps, window,
+                             cache_len),
     }
-    ctx = dict(rope=rope, off=pos % ps, kernels=kernels, q_len=q_len,
+    ctx = dict(rope=rope_tables, off=pos % ps, kernels=kernels, q_len=q_len,
                pack=pack_idx, real=real)
     for kind, k in ((FULL, "k"), (WINDOW, "k_win")):
         ctx[kind] = dict(
             table=tables[kind], mask=masks[kind],
             phys=_class_places(tables[kind], starts[kind], pos, rows, ps,
                                cache_len, cache[k].shape[1] - 1))
+    return tok, pos, ctx
+
+
+@sublayer("glue")
+def serve_step_paged(
+    params: Dict[str, Any],
+    cache: Dict[str, jnp.ndarray],
+    tokens: jnp.ndarray,      # (R, C)
+    positions: jnp.ndarray,   # (R, C); the scratch position is padding
+    logits_idx: jnp.ndarray,  # (R,)
+    mask, cache_positions,
+    page_table,               # {"full": (R, NP), "window": (R, NPw), "window_start": (R,)}
+    *,
+    cfg: SmallThinkerConfig,
+    cache_len: int,
+    all_logits: bool = False,
+    kernels: str = "xla",
+    pack: Optional[int] = None,
+    **unsupported,
+):
+    """The engine's paged step (models/transformer.serve_step_paged's
+    contract, its packed token axis included) over the layer order,
+    with a table a class of page (module docstring;
+    :func:`step_context`). A row's real positions are its
+    first columns, consecutive. The returned cache also holds
+    ``moe_counts`` (``step_counts``: an output, not an input)."""
+    if mask is not None or cache_positions is not None or any(
+            v for v in unsupported.values()):
+        _one_table_only()
+    if pack is not None and all_logits:
+        raise ValueError("a packed token axis returns one logits row a row")
+    tok, pos, ctx = step_context(
+        cache, tokens, positions, page_table, window=cfg.sliding_window,
+        cache_len=cache_len, pack=pack, kernels=kernels,
+        rope=functools.partial(rope_freqs, cfg))
     x = _embed_in(cfg, params, tok, pos)
     N, k = x.shape[0] * x.shape[1], cfg.num_experts_per_tok
     carried = dict(
@@ -591,5 +641,5 @@ def serve_step_paged(
     x, new_cache = run_layers(cfg.kinds, blocks, params, x, carried)
     new_cache = {name: a for name, a in new_cache.items()
                  if not name.startswith("route_")}
-    return _head_logits(cfg, params, x, logits_idx, pack_idx,
+    return _head_logits(cfg, params, x, logits_idx, ctx["pack"],
                         all_logits), new_cache

@@ -208,6 +208,32 @@ def rope_freqs(cfg: DecoderConfig, positions: jnp.ndarray):
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_inv_freq(d: int, theta: float, factor: float = 1.0,
+                  original_max: int = 0, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """The ``d / 2`` rope frequencies of ``d`` rotated channels under
+    YaRN: below the channel where ``original_max`` positions hold
+    ``beta_fast`` turns the plain ones (theta^(-2i/d)), above the one
+    where they hold ``beta_slow`` the plain ones over ``factor``, a
+    linear ramp between; ``factor <= 1``: the plain ones. (numpy
+    float64, cast by the caller. models/deepseek_v3.py's rope channels
+    and models/laguna.py's full layers.)"""
+    import numpy as np
+
+    plain = theta ** -(np.arange(0, d, 2, dtype=np.float64) / d)
+    if factor <= 1:
+        return plain
+
+    def channel(turns):
+        return d * math.log(original_max / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(channel(beta_fast)), 0)
+    high = min(math.ceil(channel(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return plain / factor * ramp + plain * (1 - ramp)
+
+
 def apply_rope(x, cos, sin):
     rot = cos.shape[-1]
     xr, x_pass = x[..., :rot], x[..., rot:]

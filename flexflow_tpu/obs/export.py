@@ -106,7 +106,7 @@ class ExportDriftError(AssertionError):
 SCHED_COUNTERS = frozenset({
     "steps", "mixed_steps", "decode_steps", "sync_steps", "flushes",
     "pipeline_drains", "admitted", "preemptions", "failed",
-    "prefill_tokens", "decode_tokens",
+    "prefill_tokens", "decode_tokens", "decode_context_lines",
     "prefix_hits", "prefix_misses", "prefix_hit_tokens", "prefix_inserts",
     "prefix_evictions", "prefix_cows",
     "spills", "readmits", "host_hit_tokens",

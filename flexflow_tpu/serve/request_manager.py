@@ -1064,6 +1064,7 @@ class RequestManager:
         self.stats.record_step(
             "decode", active_slots=len(decoding), num_slots=R,
             decode_tokens=len(decoding),
+            decode_context=sum(int(positions[r.slot, 0]) + 1 for r in decoding),
         )
         self.stats.note_head(self.engine.step_head[0])
         real = positions[:, 0] != scratch
@@ -1186,6 +1187,8 @@ class RequestManager:
             "mixed", active_slots=int(bc.active.sum()), num_slots=R,
             prefill_tokens=spent, decode_tokens=len(decoding),
             budget=C * max(1, len(prefilling)),
+            decode_context=sum(
+                int(bc.positions[r.slot, 0]) + 1 for r in decoding),
         )
         self.stats.note_head(eng.step_head[0])
         if self._slot_state:
@@ -1451,6 +1454,8 @@ class RequestManager:
                 sum(bc.qlens[r.slot] for r in prefilling)
             ) if prefilling else 0,
             decode_tokens=len(decoding),
+            decode_context=sum(
+                int(bc.positions[r.slot, 0]) + 1 for r in decoding),
         )
         tr = self.tracer
         if tr.enabled:

@@ -1295,3 +1295,75 @@ def test_smallthinker_padded_step_fits_at_the_cells_depth(chip):
     under the step's peak elsewhere: 12.73 still (ISSUE 51)."""
     compiled = _smallthinker_step(chip, 12, 128, None)[0]
     assert _need(compiled) / 1e9 == pytest.approx(12.73, abs=0.02)
+
+
+# --- heads by kind over two classes of page, every expert held (Laguna) ------
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_laguna_step_compiles_at_the_cells_depth(chip, C):
+    """models/laguna.py at published widths and the benchmark cell's
+    depth and serving sizes (five layers: [F, S, S, S, F], the first
+    dense; 16 slots of 133 pages; the window class six pages a slot):
+    the full layers' call at 48 query heads handed over as 8 groups of
+    8 (6 real) under the accepted name and FIRST in the program, the
+    window layers' at 64 under ``_win``, the grouped expert matmuls at
+    256 groups, both classes' pools and the experts carried in place,
+    and the weight and pool argument bytes equal to the configuration's
+    arithmetic (3869.9 M parameters; 2.23 + 0.15 GB of pool)."""
+    from flexflow_tpu.models import laguna as fam
+    from flexflow_tpu.serve.paging import window_table_pages
+
+    slots, max_seq = 16, 16928
+    cfg = fam.config(num_hidden_layers=5, dtype=jnp.bfloat16)
+    pages = -(-(max_seq + 65) // PAGE)
+    win = window_table_pages(cfg.sliding_window, 128, PAGE)
+    assert (pages, win) == (133, 6)
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16,
+        class_pages={"full": slots * pages, "window": slots * win})), chip)
+    table = {"full": chip((slots, pages), jnp.int32),
+             "window": chip((slots, win), jnp.int32),
+             "window_start": chip((slots,), jnp.int32)}
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=max_seq + 64, kernels="pallas",
+            pack=512 if C > 1 else None)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32), table,
+        donate=(1,))
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    matmul = (2 * 29_458_432 + 3 * 37_879_808 + 50_331_648 + 4 * 808_976_384
+              + 2 * 100352 * 2048)
+    assert matmul == 3_869_835_264
+    assert nbytes(params) == 2 * matmul + 2 * (11 * 2048 + 10 * 128) + 4 * 4 * 256
+    assert nbytes(cache) == 4096 * 128 * (2 * (slots * pages + 1) + 3 * (slots * win + 1))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes - nbytes(params) - nbytes(cache) < 1 << 20
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(r"= (\S+) custom-call\(.*tpu_custom_call", entry)
+    assert f"[{slots},{C},8,8,128]" in calls[0], calls[:2]
+    names = set(re.findall(r"%(ff_ragged_paged_c\d+\w*?)(?:\.\d+)* = ", text))
+    assert names == {f"ff_ragged_paged_c{C}", f"ff_ragged_paged_c{C}_win"}, names
+    tokens = 512 if C > 1 else slots
+    tm, rows = kernels.grouped_tile(8 * tokens, 256), _pair_rows(8 * tokens, 256)
+    assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},512\]", text)
+    assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},2048\]", text)
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["k"], cache["k_win"], experts,
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    assert _need(compiled) / 1e9 < 12.0
+    print(f"laguna C={C}: need {_need(compiled) / 1e9:.2f} GB, temp "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, tile {tm}")

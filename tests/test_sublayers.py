@@ -16,6 +16,7 @@ import pytest
 from flexflow_tpu.models import (
     deepseek_v3,
     granite_hybrid,
+    laguna,
     lfm2_moe,
     llama,
     minicpm_sala,
@@ -52,6 +53,8 @@ FAMILIES = {
     "smallthinker": (smallthinker, ALWAYS | {"ff.moe.route"}),
     # routed experts behind a recurrent mixer: both beside attention
     "qwen3_next": (qwen3_next, ALWAYS | {"ff.mixer", "ff.moe.route"}),
+    # heads by kind, a gate a head, a leading dense layer, a shared expert
+    "laguna": (laguna, ALWAYS | {"ff.moe.route"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
