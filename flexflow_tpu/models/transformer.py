@@ -709,14 +709,26 @@ def pairs_to_tokens(rows, place, held, weights):
     aligned row, ``place`` (T, k) each pair's row, ``held`` (T, k)
     whether the pair has a result, ``weights`` (T, k) -> (T, D)
     float32, token t's ``sum_j weights[t, j] rows[place[t, j]]`` over
-    its held pairs: one gather of the pairs' rows and one sum over k,
-    in float32. A pair in no group has no result: that is said here, by
-    the mask, and not by what a grouped matmul leaves in rows it never
-    wrote."""
+    its held pairs. Each product and each addition is float32, and the
+    terms are ADDED IN CHOICE ORDER, j = 0, 1, ..., k - 1, left to
+    right: that order is the layer's bits (tests/test_moe.py pins it).
+    The rows are gathered once, CHOICE-MAJOR (by ``place.T``), so that
+    what the sum reads is (k, T, D) with the tokens on the sublanes and
+    D on the lanes: a token-major (T, k, D) pads k to the 8 sublanes
+    wherever it is no multiple of them, a copy and a padded read a
+    layer (PERF.md section 6, PR 59). A pair in no group has no result:
+    that is said here, by the mask, and not by what a grouped matmul
+    leaves in rows it never wrote."""
     T, k = place.shape
-    got = jnp.take(rows, place.reshape(-1), axis=0, mode="clip")
-    return jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
-                      jnp.where(held[..., None], got.reshape(T, k, -1), 0.0))
+    got = jnp.take(rows, place.T.reshape(-1), axis=0,
+                   mode="clip").reshape(k, T, -1)
+    held, weights = held.T, jnp.where(held, weights, 0.0).T
+    # a choice's slice of the gathered rows times its weights, term by
+    # term: one fusion that reads (k, T, D) once. Slices of an array of
+    # products would keep that array beside the gathered one.
+    return functools.reduce(jnp.add, (
+        jnp.where(held[j, :, None], got[j], 0.0) * weights[j, :, None]
+        for j in range(k)))
 
 
 @functools.partial(jax.jit, static_argnames=("tiles", "tm"))
@@ -757,7 +769,8 @@ def routed_experts_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
     The layout is built by counting (:func:`pair_layout`: no sort, one
     scatter of the pairs' tokens) and each pair's row moves once in (one
     gather of ``h`` by ``source``) and once out (one gather of the
-    experts' results by ``place``, weighted and summed by token). A row
+    experts' results by ``place``, choice-major, weighted and summed by
+    token in choice order: :func:`pairs_to_tokens`). A row
     of the layout that no pair has (the alignment to the tile, the
     static bound's tail) holds token 0's row and not zeros: the grouped
     matmuls compute it where its tile is active and NOTHING reads its

@@ -31,6 +31,10 @@ real tokens, and beside it the two calls alone on that routing's tokens
 per expert: their difference is what the layer costs OUTSIDE its
 kernels (the layout's tables, the rows gathered in, the results
 gathered out and summed; PR 57), with a digest of the layer's result.
+The way out is CHOICE-MAJOR since PR 59 (``transformer.pairs_to_tokens``:
+one gather by ``place.T``, the terms added in choice order): the
+digests of k = 4, 6 and 10 are another sum order's than PR 57's, those
+of k = 2 and 8 are PR 57's (CHANGES.md, PR 59, has both lists).
 
 Off a TPU whose ``device_kind`` is in ``benchmarks/peaks.json`` it
 exits without a reading unless ``--tiny`` is given (on the CPU the

@@ -11,7 +11,11 @@ events by what the instruction IS: its opcode, the JAX primitive that
 made it (the tail of its ``op_name`` past the scope; a fusion's is its
 root's, and the primitives fused into it are listed beside) and its
 result's shape. Twelve layers' copies of one instruction are one row
-(``n`` a step).
+(``n`` a step). Since PR 59 the way out is choice-major: the table
+shows the results' gather (``f32[P, D]``) and ONE fusion that reads it
+as ``[k, T, D]`` and writes ``(T, D)``; a ``reshape`` or ``copy`` to
+``f32[T, k, D]`` (k padded to 8 sublanes) or a ``reduce`` over it in
+``ff.moe.route`` is the token-major way out come back.
 
     chiprun -- python scripts/route_ops.py [--scope ff.moe.route] \
         [--tag parent] --workload <cell> --seed 1 --seconds 50 --trace 1

@@ -972,6 +972,8 @@ def _entry(text):
     ("smallthinker", 1024, 6, 64, 64, 2560, 768),
     # Qwen3-Next's widest rung: 20480 pairs, 128 held of 512
     ("qwen3_next_2048", 2048, 10, 128, 512, 2048, 512),
+    # Mixtral's 1024 rung: 2048 pairs over 8 experts, k = 2
+    ("mixtral_1024", 1024, 2, 8, 8, 4096, 14336),
 ])
 def test_routed_layer_moves_each_row_once_in_and_once_out(chip, case, T, k, n,
                                                           routed, D, F):
@@ -984,8 +986,15 @@ def test_routed_layer_moves_each_row_once_in_and_once_out(chip, case, T, k, n,
     rows, (P, D) float32: what only a kernel's edge can take), and the
     weight fetches' scalars reckoned once for the two calls
     (``grouped_fetches``' running sum and minimum are the program's
-    only ``reduce-window``s beside the layout's own). The guard that
-    keeps the routed layer's bytes from coming back (PR 57)."""
+    only ``reduce-window``s beside the layout's own). The results are
+    gathered CHOICE-MAJOR and summed over the leading axis (PR 59): the
+    program makes no (T, k, D) array (where k is no multiple of the 8
+    sublanes that view is a copy, padded, and the sum reads the
+    padding), and the way out's temporaries, compiled alone, stay under
+    five quarters of the pairs' results (the token-major form: the
+    gather and the padded copy, 146.9 MB against 62.9 at SmallThinker's
+    step). The guard that keeps the routed layer's bytes
+    from coming back (PR 57)."""
     from flexflow_tpu.models import transformer
 
     P, tm = T * k, transformer.routed_tile(T, k, (0, n), routed)
@@ -1012,6 +1021,14 @@ def test_routed_layer_moves_each_row_once_in_and_once_out(chip, case, T, k, n,
     assert made(f"bf16[{rows},{D}]") == ["fusion"]      # h's rows, gathered
     assert made(f"f32[{rows},{D}]") == ["custom-call"]  # grouped_down's
     assert made(f"f32[{P},{D}]") == ["fusion"]          # gathered back, once
+    assert made(f"f32[{T},{k},{D}]") == []              # no token-major copy
+    # the way out alone (inside the layer the kernels' own arrays set
+    # the peak wherever F or the aligned rows are large)
+    way_out, _ = _compile(
+        transformer.pairs_to_tokens, chip((rows, D), jnp.float32),
+        chip((T, k), jnp.int32), chip((T, k), jnp.bool_),
+        chip((T, k), jnp.float32))
+    assert way_out.memory_analysis().temp_size_in_bytes < 1.25 * P * D * 4
     # the running sums: the layout's two and the fetches' own, once
     windows = lambda fn, *args: sum(
         op == "reduce-window" for _, op in _entry(_compile(fn, *args)[1]))

@@ -504,14 +504,22 @@ def _sorted_tables(group, n, tm, k, T):
     return counts, place, source, tile_group, n_active
 
 
+@jax.jit
 def _token_major_sum(rows, place, held, weights):
-    """The weighted sum as ``routed_experts_ffn`` wrote it up to PR 56:
-    one gather of all the pairs' rows, seen (T, k, D), one einsum over
-    k."""
+    """The weighted sum over the gather ``routed_experts_ffn`` made up
+    to PR 58: all the pairs' rows, seen (T, k, D). Its terms are added
+    in ``pairs_to_tokens``' order (choice 0, 1, ..., k - 1, each
+    product and each addition float32) since PR 59. Up to then it was
+    one einsum over k, whose order nobody chose, and k = 2 moved with
+    it on the CPU: there the compiler contracts a product and the
+    addition after it into one rounding (a fused multiply-add), the
+    einsum's dot and the written chain not alike. Jitted, as the
+    layer's own is, so that the two are contracted alike."""
     T, k = place.shape
     got = jnp.take(rows, place.reshape(-1), axis=0, mode="clip").reshape(T, k, -1)
-    return jnp.einsum("tk,tkd->td", jnp.where(held, weights, 0.0),
-                      jnp.where(held[..., None], got, 0.0))
+    terms = (jnp.where(held[..., None], got, 0.0)
+             * jnp.where(held, weights, 0.0)[..., None])
+    return functools.reduce(jnp.add, (terms[:, j] for j in range(k)))
 
 
 def _sorted_routed_ffn(h, real, experts, weights, w_gate, w_up, w_down, *,
@@ -567,12 +575,15 @@ def test_routed_ffn_is_the_sorted_layouts_bit_for_bit(tm, T, activation):
     assert np.abs(np.asarray(got)[real]).max() > 0
 
 
-@pytest.mark.parametrize("k", [2, 6, 10])
+@pytest.mark.parametrize("k", [2, 4, 6, 8, 10])
 def test_pairs_to_tokens_sums_the_held_pairs(k):
     """``pairs_to_tokens`` against the sum written out in float64: each
     token's held pairs' rows by their weights; a pair that is not held
     adds nothing whatever its place names (a row a grouped matmul never
-    wrote: NaN here), nor does a token with no held pair."""
+    wrote: NaN here), nor does a token with no held pair. And its BITS
+    (PR 59): each product and each addition float32, the terms added in
+    choice order j = 0, 1, ..., k - 1, left to right, against the same
+    terms added one by one in NumPy."""
     T, R, D = 24, 40, 16
     rng = np.random.default_rng(k)
     rows = rng.normal(size=(R, D)).astype(np.float32)
@@ -582,16 +593,36 @@ def test_pairs_to_tokens_sums_the_held_pairs(k):
     place[~held] = rng.integers(R - 4, R, size=int((~held).sum()))
     rows[R - 4:] = np.nan                              # rows nobody wrote
     weights = rng.uniform(0.1, 1.0, size=(T, k)).astype(np.float32)
-    got = np.asarray(transformer.pairs_to_tokens(
-        jnp.asarray(rows), jnp.asarray(place), jnp.asarray(held),
-        jnp.asarray(weights)))
+    args = (jnp.asarray(rows), jnp.asarray(place), jnp.asarray(held),
+            jnp.asarray(weights))
+    got = np.asarray(transformer.pairs_to_tokens(*args))
     want = np.zeros((T, D))
+    in_order = np.zeros((T, D), np.float32)
     for t in range(T):
         for j in range(k):
             if held[t, j]:
                 want[t] += np.float64(weights[t, j]) * rows[place[t, j]]
+            term = (rows[place[t, j]] * weights[t, j] if held[t, j]
+                    else np.zeros(D, np.float32))
+            assert term.dtype == np.float32
+            in_order[t] = term if j == 0 else in_order[t] + term
     assert got.dtype == np.float32 and not got[0].any()
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    # The CPU's compiler contracts a product and the addition after it
+    # into one rounding (a fused multiply-add: under
+    # XLA_FLAGS=--xla_cpu_max_isa=AVX the jitted call gives the loop's
+    # bits here too, and on a v5e it gives them as it is, PERF.md
+    # section 6, PR 59), so the bits are read with each jnp operation
+    # dispatched on its own, and the jitted program is held to the same
+    # chain: k - 1 additions, no reduction and no dot whose order a
+    # compiler chooses.
+    with jax.disable_jit():
+        one_by_one = np.asarray(transformer.pairs_to_tokens(*args))
+    np.testing.assert_array_equal(one_by_one, in_order)
+    primitives = [e.primitive.name for e in jax.make_jaxpr(
+        transformer.pairs_to_tokens.__wrapped__)(*args).jaxpr.eqns]
+    assert primitives.count("add") == k - 1, primitives
+    assert not {"reduce_sum", "dot_general"} & set(primitives), primitives
 
 
 @pytest.mark.parametrize("case, T, k, held, routed, tm, real, on", [
