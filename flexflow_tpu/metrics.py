@@ -210,6 +210,14 @@ class SchedulerStats:
     moe_experts_held: int = 0
     moe_load_max: int = 0
     moe_tiles: int = 0
+    # A family some of whose router outputs are no expert's weights
+    # (zero-compute experts that return their input,
+    # models/longcat_flash.py): the real tokens' pairs on those
+    # outputs, and all their pairs (k a real token and layer), summed
+    # like the counts above. ``moe_pairs`` stays the pairs on experts
+    # HELD; the rest of ``moe_routed_pairs`` fell on absent experts.
+    moe_zero_pairs: int = 0
+    moe_routed_pairs: int = 0
     # A family whose page pool holds one compressed line a token and
     # layer (a latent pool, models/deepseek_v3.py): the lines the
     # pipelined steps wrote, real tokens x layers.
@@ -281,13 +289,18 @@ class SchedulerStats:
         if dense_len is not None:
             self.sparse_rows += int((rows & (first + count > dense_len)).sum())
 
-    def note_expert_counts(self, counts, tile: int) -> None:
+    def note_expert_counts(self, counts, tile: int, zero_pairs=(),
+                           routed_pairs=()) -> None:
         """Count one step's routed expert layers: ``counts`` (sparse
         layers, experts held) the real tokens each expert was given,
         ``tile`` the row tile of the step's grouped matmuls
         (``InferenceEngine.step_tile``). A layer that was given none did not
         route (a step that took the all-expert einsum returns zeros)
-        and is not counted."""
+        and is not counted. ``zero_pairs`` / ``routed_pairs`` (sparse
+        layers,): where the family returns them, the real tokens' pairs
+        on outputs that are no expert, and all their pairs."""
+        self.moe_zero_pairs += int(np.sum(zero_pairs))
+        self.moe_routed_pairs += int(np.sum(routed_pairs))
         counts = np.asarray(counts)
         counts = counts[counts.sum(axis=-1) > 0]
         self.moe_pairs += int(counts.sum())

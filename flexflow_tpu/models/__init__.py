@@ -23,7 +23,7 @@ FAMILIES = {
 # on the paged path and have no checkpoint converter, so they are
 # modules to import, not entries above: minicpm_sala, lfm2_moe,
 # deepseek_v3, olmo_hybrid, granite_hybrid, smallthinker, qwen3_next,
-# laguna.
+# laguna, longcat_flash.
 
 __all__ = [
     "llama", "transformer", "opt", "falcon", "mpt", "starcoder", "qwen2",

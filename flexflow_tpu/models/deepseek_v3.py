@@ -56,6 +56,18 @@ What it refuses, at construction (``validate_serving``): prefix caching
 not build), ``kv_quant``, ``fused_decode``, ``kv_shard="context"``, the
 dense layout, a mesh with ``model > 1``.
 
+THE LATENT POOL'S ONE COPY. What any family that keeps the latent pool
+needs of a step lives here and takes the family's own numbers from its
+caller: :func:`latent_pool` (the two arrays, any number of lines a
+token), :func:`step_context`, :func:`latent_line` and
+:func:`absorbed_queries` (each with a constant factor that defaults to
+none), :func:`latent_attention` (the pool's write, the kernel's call
+and the way out, at the pool index and softmax scale it is told),
+:func:`validate_serving` (told who refuses). ``models/longcat_flash.py``
+(two attention sublayers a layer, so two lines, and two factors) is
+the second caller; this family's step programs trace to what they were
+(``str(jax.make_jaxpr(serve_step_paged))`` on two checkouts).
+
 Weight names follow ``benchmarks/harness/model.py::make_params``' rule
 (it zeroes a leaf whose name holds ``bias`` or starts with ``b``, and
 draws ``wo`` / ``w_down`` at the residual scale): norm scales hold
@@ -79,7 +91,6 @@ from .transformer import (
     DecoderConfig,
     _embed_in,
     _ffn,
-    _gather_attended,
     _head_logits,
     _layer_of,
     _mm,
@@ -390,14 +401,16 @@ def expert_routing(cfg: DeepseekV3Config) -> Tuple[int, Tuple[int, int], int]:
     return cfg.num_experts_per_tok, cfg.held, cfg.n_routed_experts
 
 
-def validate_serving(cfg: DeepseekV3Config, serving, mesh, *,
-                     specinfer: bool = False) -> None:
-    """The combinations this family's latent pool cannot serve yet,
-    refused at engine construction, each naming what is missing."""
+def validate_serving(cfg, serving, mesh, *, specinfer: bool = False,
+                     family: str = "deepseek_v3") -> None:
+    """The combinations the latent pool cannot serve yet, refused at
+    engine construction, each naming what is missing (``family``: who
+    refuses: models/longcat_flash.py keeps the same pool and refuses
+    the same)."""
     from ..core.mesh import MODEL_AXIS
 
     def refuse(what, why):
-        raise NotImplementedError(f"deepseek_v3 does not serve {what}: {why}")
+        raise NotImplementedError(f"{family} does not serve {what}: {why}")
 
     if serving.kv_layout != "paged":
         refuse(f"kv_layout={serving.kv_layout!r}",
@@ -410,8 +423,8 @@ def validate_serving(cfg: DeepseekV3Config, serving, mesh, *,
     if specinfer:
         refuse("SpecInfer or beam search",
                "commit_kv / reorder_slots are not written for the latent "
-               "pool, and the draft head the model was trained with "
-               "(num_nextn_predict_layers) is not built")
+               "pool, and the draft module the model was trained with "
+               "(its multi-token-prediction layers) is not built")
     if serving.kv_quant is not None:
         refuse(f"kv_quant={serving.kv_quant!r}",
                "the latent pool has no scale rows and its kernel no "
@@ -430,9 +443,9 @@ def validate_serving(cfg: DeepseekV3Config, serving, mesh, *,
 
 def _no_latent_op(*_a, **_k):
     raise NotImplementedError(
-        "deepseek_v3 keeps a latent page pool: committing, copying, "
-        "gathering or reordering cache lines is not written for it, and it "
-        "has no dense-layout step (validate_serving)")
+        "the latent page pool: committing, copying, gathering or "
+        "reordering cache lines is not written for it, and a family that "
+        "keeps it has no dense-layout step (validate_serving)")
 
 
 commit_kv_paged = reorder_slots_paged = copy_page_kv = _no_latent_op
@@ -459,14 +472,24 @@ def init_paged_kv_cache(
     step's line write (serve/kernels, "Latent paged attention"); the
     bytes are the line's own, kv_lora_rank + qk_rope_head_dim values a
     token and layer."""
+    return latent_pool(cfg, cfg.num_hidden_layers, num_pages, page_size,
+                       dtype, kv_quant, extra_rows)
+
+
+def latent_pool(cfg, lines: int, num_pages: int, page_size: int, dtype=None,
+                kv_quant: Optional[str] = None, extra_rows: int = 0):
+    """The two arrays of :func:`init_paged_kv_cache` with ``lines``
+    lines a token on their leading axis: one a layer here, one an
+    attention SUBLAYER where a layer attends more than once
+    (models/longcat_flash.py: two)."""
     if kv_quant is not None or extra_rows:
         raise NotImplementedError(
-            "deepseek_v3's pool is neither quantized nor row-sharded "
+            "the latent pool is neither quantized nor row-sharded "
             "(validate_serving refuses kv_quant and kv_shard='context')")
     if page_size % 2:
         raise ValueError(f"page_size {page_size} is odd: rope keys lie in pairs")
     dt = dtype or cfg.dtype
-    pages = (cfg.num_hidden_layers, num_pages + 1)
+    pages = (lines, num_pages + 1)
     return {
         "latent": jnp.zeros(pages + (page_size, cfg.kv_lora_rank), dt),
         "latent_rope": jnp.zeros(
@@ -491,65 +514,119 @@ def kv_up_halves(cfg: DeepseekV3Config, w_kvb):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def latent_line(cfg: DeepseekV3Config, p, h, rope):
+def latent_line(cfg, p, h, rope, c_scale: float = 1.0):
     """One token's cached line from its normed input h (..., D):
-    (``rmsnorm(c_raw)``, ``rope(kr_raw)``)."""
+    (``c_scale * rmsnorm(c_raw)``, ``rope(kr_raw)``). ``c_scale``: a
+    constant factor on ``c`` alone, so on every head's keys AND values
+    (models/longcat_flash.py's ``mla_scale_kv_lora``), applied in
+    float32; 1: the line as it was."""
     raw = _mm(h, p["w_kva"])
     c = _norm(cfg, raw[..., :cfg.kv_lora_rank], p["kv_norm_scale"], None)
+    if c_scale != 1.0:
+        c = (c.astype(jnp.float32) * c_scale).astype(c.dtype)
     kr = apply_rope(raw[..., None, cfg.kv_lora_rank:], *rope)[..., 0, :]
     return c, kr
 
 
-def absorbed_queries(cfg: DeepseekV3Config, p, h, rope):
+def absorbed_queries(cfg, p, h, rope, q_scale: float = 1.0):
     """What a head's query is against a cached line: (``q_nope_h
     W_UK_h^T`` (B, T, H, kv_lora_rank), ``rope(q_rope_h)`` (B, T, H,
-    qk_rope))."""
+    qk_rope)). ``q_scale``: a constant factor on the whole query, its
+    rope channels too (``mla_scale_q_lora``); 1: as it was."""
     B, T, _ = h.shape
     cq = _norm(cfg, _mm(h, p["w_qa"]), p["q_norm_scale"], None)
     q = _mm(cq, p["w_qb"]).reshape(B, T, cfg.num_attention_heads, cfg.head_dim)
+    if q_scale != 1.0:
+        q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
     w_uk, _ = kv_up_halves(cfg, p["w_kvb"])
     q_abs = jnp.einsum("bthd,chd->bthc", q[..., :cfg.qk_nope_head_dim], w_uk,
                        preferred_element_type=jnp.float32).astype(h.dtype)
     return q_abs, apply_rope(q[..., cfg.qk_nope_head_dim:], *rope)
 
 
-def _mla_block(cfg, ctx, stack, index, x, carried):
+def latent_attention(cfg, ctx, p, h, carried, line, *, scale: float,
+                     q_scale: float = 1.0, c_scale: float = 1.0):
+    """One latent-attention sublayer of a paged step, for every family
+    that keeps the latent pool (this one; models/longcat_flash.py):
+    ``h`` (B, T, D) the sublayer's normed input, ``p`` its weights,
+    ``line`` the index of its lines on the pool's leading axis (the
+    layer here; ``2 i + j`` where layer i attends twice), ``scale``
+    the softmax's, ``ctx`` the step's (rope table, page places, rows,
+    kernels, pack). Writes the tokens' lines at their places, attends
+    in the absorbed form through ``serve/kernels.mla_paged_attention``
+    (its XLA twin), and projects out. -> (out (B, T, D), carried)."""
     from ..serve import kernels as _pk
 
-    p = layer_weights(stack, index)
-    B, T, _ = x.shape
+    B, T, _ = h.shape
     H = cfg.num_attention_heads
-    h = _norm(cfg, x, p["attn_norm_scale"], None)
     with sublayer("attn.proj"):
-        c, kr = latent_line(cfg, p, h, ctx["rope"])
+        c, kr = latent_line(cfg, p, h, ctx["rope"], c_scale)
     with sublayer("attn.write"):
         cp, krp = carried["latent"], carried["latent_rope"]
         phys, off = ctx["phys"], ctx["off"]
-        cp = cp.at[index, phys, off].set(c.astype(cp.dtype))
-        row, lanes = _pk.pair_rope_place(off, cp.shape[2], cfg.qk_rope_head_dim)
-        krp = krp.at[index, phys[..., None], row[..., None], lanes].set(
-            kr.astype(krp.dtype))
+        cp = cp.at[line, phys, off].set(c.astype(cp.dtype))
+        krp = _pk.write_rope_lines(
+            krp, line, _spread_queries(kr, ctx["pack"]), ctx["page_table"],
+            ctx["q_start"], ctx["q_len"])
     with sublayer("attn.proj"):
-        q = absorbed_queries(cfg, p, h, ctx["rope"])
+        q = absorbed_queries(cfg, p, h, ctx["rope"], q_scale)
     rows = (ctx["page_table"], ctx["q_start"], ctx["q_len"])
     with sublayer("attn.core"):
         q = [_spread_queries(q, ctx["pack"]) for q in q]    # (R, C, H, .)
         if ctx["kernels"] == "pallas":
             o = _pk.mla_paged_attention(
                 *q, *(a.reshape((-1,) + a.shape[2:]) for a in (cp, krp)),
-                *rows, scale=softmax_scale(cfg),
-                row_offset=index * cp.shape[1])
+                *rows, scale=scale, row_offset=line * cp.shape[1])
         else:
             o = _pk.mla_paged_attention_xla(
-                *q, _layer_of(cp, index), _layer_of(krp, index), *rows,
-                scale=softmax_scale(cfg))
-        o = _gather_attended(o, ctx["pack"]).reshape(B, T, H, cfg.kv_lora_rank)
+                *q, _layer_of(cp, line), _layer_of(krp, line), *rows,
+                scale=scale)
+        # back on the token axis with (H, c) kept as they lie: folded
+        # into one axis first, the whole (R, C) result is laid out anew
+        if ctx["pack"] is not None:
+            o = jnp.take(o.reshape((-1,) + o.shape[2:]), ctx["pack"][1],
+                         axis=0, mode="clip")
+        o = o.reshape(B, T, H, cfg.kv_lora_rank)
     with sublayer("attn.proj"):
         _, w_uv = kv_up_halves(cfg, p["w_kvb"])
         o = jnp.einsum("bthc,chd->bthd", o, w_uv,
-                       preferred_element_type=jnp.float32).astype(x.dtype)
+                       preferred_element_type=jnp.float32).astype(h.dtype)
         out = _mm(o.reshape(B, T, H * cfg.v_head_dim), p["wo"])
-    return x + out, dict(carried, latent=cp, latent_rope=krp)
+    return out, dict(carried, latent=cp, latent_rope=krp)
+
+
+def step_context(cache, tokens, positions, page_table, cache_len, pack):
+    """What a paged step over the latent pool derives from its
+    arguments before its layers run: (``token_axis`` (tokens,
+    positions), padded (R, C) or packed (1, width); ``ctx`` without
+    the family's own entries (``rope``, ``kernels``) and the rows'
+    first positions ``q_start``: the lines' places, the rows' real
+    queries, the real places, and ``pack``,
+    :func:`transformer._pack_tokens`' second result, None unpacked)."""
+    from ..serve.kernels import real_query_lengths
+
+    C = tokens.shape[1]
+    ps = cache["latent"].shape[2]
+    q_len = real_query_lengths(positions, cache_len)  # real columns lead
+    if pack is None:
+        token_axis = (tokens, positions)
+        phys, off = _page_lookup(page_table, positions, ps)
+        real = (jnp.arange(C, dtype=jnp.int32)[None] < q_len[:, None]).reshape(-1)
+        pack_idx = None
+    else:
+        (*token_axis, phys, off), pack_idx = _pack_tokens(
+            tokens, positions, q_len, page_table, ps, cache_len, pack)
+        real = token_axis[1][0] < cache_len
+    return token_axis, dict(phys=phys, off=off, page_table=page_table,
+                            q_len=q_len, pack=pack_idx, real=real)
+
+
+def _mla_block(cfg, ctx, stack, index, x, carried):
+    p = layer_weights(stack, index)
+    h = _norm(cfg, x, p["attn_norm_scale"], None)
+    out, carried = latent_attention(cfg, ctx, p, h, carried, index,
+                                    scale=softmax_scale(cfg))
+    return x + out, carried
 
 
 def _dense_block(cfg, ctx, stack, index, x, carried):
@@ -630,27 +707,11 @@ def serve_step_paged(
         _no_latent_op()
     if pack is not None and all_logits:
         raise ValueError("a packed token axis returns one logits row a row")
-    from ..serve.kernels import real_query_lengths
-
-    R, C = tokens.shape
-    ps = cache["latent"].shape[2]
-    q_len = real_query_lengths(positions, cache_len)  # real columns lead
-    if pack is None:
-        token_axis = (tokens, positions)
-        phys, off = _page_lookup(page_table, positions, ps)
-        real = (jnp.arange(C, dtype=jnp.int32)[None] < q_len[:, None]).reshape(-1)
-        pack_idx = None
-    else:
-        (*token_axis, phys, off), pack_idx = _pack_tokens(
-            tokens, positions, q_len, page_table, ps, cache_len, pack)
-        real = token_axis[1][0] < cache_len
+    token_axis, ctx = step_context(
+        cache, tokens, positions, page_table, cache_len, pack)
     with sublayer("attn.proj"):
-        rope = rope_cos_sin(cfg, token_axis[1])
-    ctx = dict(
-        rope=rope, phys=phys, off=off,
-        page_table=page_table, kernels=kernels, q_len=q_len, pack=pack_idx,
-        q_start=positions[:, 0], real=real,
-    )
+        ctx["rope"] = rope_cos_sin(cfg, token_axis[1])
+    ctx.update(kernels=kernels, q_start=positions[:, 0])
     x = _embed_in(cfg, params, *token_axis)
     carried = dict(cache, **{name: jnp.zeros(shape, jnp.int32)
                              for name, shape in step_counts(cfg).items()})
@@ -659,5 +720,5 @@ def serve_step_paged(
         for name, fn in (("mla", _mla_block), ("dense", _dense_block),
                          ("sparse", _sparse_block))}
     x, new_cache = run_layers(cfg.kinds, blocks, params, x, carried)
-    return _head_logits(cfg, params, x, logits_idx, pack_idx,
+    return _head_logits(cfg, params, x, logits_idx, ctx["pack"],
                         all_logits), new_cache

@@ -504,7 +504,8 @@ def _project_out(cfg: DecoderConfig, p, attn):
 
 
 @sublayer("moe.route")
-def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
+def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True,
+                       offset=None, scaling: float = 1.0):
     """A linear router with a softmax (HF ``MixtralSparseMoeBlock``,
     ``Qwen2MoeSparseMoeBlock``), in float32: ``lax.top_k`` of the
     logits ``h W_r`` CHOOSES (among equals the lower index first); the
@@ -512,11 +513,26 @@ def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
     or the chosen entries of the softmax over all experts, verbatim
     (Qwen2-MoE's ``norm_topk_prob=False``). h (..., D) -> (experts
     (..., k) int32, weights (..., k) float32). Beside
-    :func:`route_sigmoid_topk`."""
+    :func:`route_sigmoid_topk`.
+
+    ``offset`` (E,) float32, a selection offset on the SCORES
+    (models/longcat_flash.py): the ``k`` largest of ``p + offset`` are
+    chosen, ``p`` the softmax over all the router's outputs; the offset
+    chooses and does not weigh: the weights are the chosen outputs' own
+    ``p``, over their sum with ``norm_topk``. ``scaling``: a constant
+    on the weights. None and 1: the function traces as it did before
+    it had the arguments."""
     router = jnp.matmul(
         h.astype(jnp.float32), _dense_w(w_router, jnp.float32),
         preferred_element_type=jnp.float32,
     )  # (..., E)
+    if offset is not None:
+        p = jax.nn.softmax(router, axis=-1)
+        _, topi = lax.top_k(p + offset.astype(jnp.float32), k)
+        gate = jnp.take_along_axis(p, topi, axis=-1)
+        if norm_topk:
+            gate = gate / gate.sum(axis=-1, keepdims=True)
+        return topi.astype(jnp.int32), gate * scaling
     topv, topi = lax.top_k(router, k)
     if norm_topk:
         gate = jax.nn.softmax(topv, axis=-1)
@@ -524,7 +540,7 @@ def route_softmax_topk(h, w_router, k: int, *, norm_topk: bool = True):
         gate = jnp.take_along_axis(
             jax.nn.softmax(router, axis=-1), topi, axis=-1
         )
-    return topi, gate
+    return topi, gate if scaling == 1.0 else gate * scaling
 
 
 @sublayer("ffn")

@@ -1915,13 +1915,51 @@ def mla_block(C: int, NP: int, H: int, ps: int) -> tuple[int, int]:
     return TC, 1
 
 
-def pair_rope_place(off: jnp.ndarray, page_size: int, width: int):
-    """Where the rope key of the token at offset ``off`` of its page
-    lies in the paired ``kr`` page (ps/2, 2*width): (row (...,), lanes
-    (..., width))."""
-    half = page_size // 2
-    lanes = (off // half)[..., None] * width + jnp.arange(width, dtype=off.dtype)
-    return off % half, lanes
+def write_rope_lines(kr_pool, line, kr, page_table, q_start, q_len):
+    """The rope keys ``kr`` (R, C, r) of a step's rows (row i's leading
+    ``q_len[i]`` columns are the tokens at positions ``q_start[i]`` on,
+    the kernels' contract) into entry ``line`` of the paired pool
+    (lines, P+1, ps/2, 2r), by WHOLE PAGES: a row's C consecutive
+    positions touch at most ``(C + ps - 2) // ps + 1`` pages, and each
+    is read, given the row's keys where its tokens lie (token ``off``
+    of a page: the r lanes from ``(off // (ps/2)) * r`` of row ``off %
+    (ps/2)``) and written back, one (ps/2, 2r) block of whole lane
+    tiles at a time. A page that takes no token goes to the scratch
+    page as it was. A scatter of the tokens' own r lanes, half a lane
+    tile each, the compiler turns into one update a token over the
+    flattened pool: 3.3 us a token in a 279 MB pool, 13-27 ms of the
+    LongCat cell's step, where this loop is a few pages a row (PERF.md
+    section 6, PR 60)."""
+    half, r = kr_pool.shape[2], kr.shape[-1]
+    ps = 2 * half
+    R, C = kr.shape[:2]
+    NP = page_table.shape[1]
+    G = min(NP, (C + ps - 2) // ps + 1)
+    page = (q_start // ps)[:, None] + jnp.arange(G, dtype=jnp.int32)       # (R, G)
+    col = (page[..., None] * ps + jnp.arange(ps, dtype=jnp.int32)
+           - q_start[:, None, None])                                       # (R, G, ps)
+    new = (col >= 0) & (col < q_len[:, None, None])
+    keys = jnp.take_along_axis(
+        kr.astype(kr_pool.dtype),
+        jnp.clip(col, 0, C - 1).reshape(R, G * ps, 1), axis=1)
+    keys = keys.reshape(R * G, ps, r)
+    new = jnp.broadcast_to(new.reshape(R * G, ps, 1), keys.shape)
+    keys, new = (jnp.concatenate([a[:, :half], a[:, half:]], axis=-1)
+                 for a in (keys, new))                                     # paired
+    phys = jnp.where(
+        new.any(axis=(1, 2)),
+        jnp.take_along_axis(page_table, jnp.clip(page, 0, NP - 1),
+                            axis=1).reshape(-1),
+        kr_pool.shape[1] - 1)
+    zero = jnp.zeros((), jnp.int32)
+
+    def put(i, pool):
+        at = (jnp.asarray(line, jnp.int32), phys[i], zero, zero)
+        old = jax.lax.dynamic_slice(pool, at, (1, 1, half, 2 * r))
+        return jax.lax.dynamic_update_slice(
+            pool, jnp.where(new[i], keys[i], old[0, 0])[None, None], at)
+
+    return jax.lax.fori_loop(0, R * G, put, kr_pool)
 
 
 def unpair_rope_lines(kr: jnp.ndarray) -> jnp.ndarray:
@@ -1929,6 +1967,25 @@ def unpair_rope_lines(kr: jnp.ndarray) -> jnp.ndarray:
     (..., ps, r)."""
     r = kr.shape[-1] // 2
     return jnp.concatenate([kr[..., :r], kr[..., r:]], axis=-2)
+
+
+def mla_work(q_start, q_len, tiles: int, TC: int, W: int, blocks: int):
+    """The work of one :func:`mla_paged_attention` call as a list, from
+    the rows' first positions and real queries (R,): tile t of row r
+    (columns ``t * TC`` on) takes one step for every block of W keys up
+    to its last real query's, and ONE where it has no real query.
+    -> (steps () int32, (row, tile, block) each (R * tiles * blocks,)
+    int32): the list's first ``steps`` entries, rows then tiles then
+    blocks ascending; the rest is not run."""
+    t = jnp.arange(tiles, dtype=jnp.int32)
+    cols = jnp.clip(q_len[:, None] - t * TC, 0, TC)                # (R, tiles)
+    last = q_start[:, None] + t * TC + cols - 1
+    count = jnp.where(cols > 0, last // W + 1, 1).reshape(-1)
+    ends = jnp.cumsum(count)
+    item = jnp.arange(count.size * blocks, dtype=jnp.int32)
+    at = jnp.minimum((item[:, None] >= ends[None]).sum(-1), count.size - 1)
+    block = item - (ends - count)[at]
+    return ends[-1], (at // tiles, at % tiles, block)
 
 
 def mla_paged_attention_xla(
@@ -1978,30 +2035,37 @@ def mla_paged_attention(
     scale: float,
     row_offset=None,          # int32 scalar: pool row of table entry 0
 ) -> jnp.ndarray:
-    """Latent paged attention, ``ff_mla_paged_c<C>``: grid (row, query
-    tile, block of logical pages). A step is handed the block's KB
-    pages of lines through the page table (each pool is passed KB
-    times, one page block an operand; ``row_offset``: the pools are
-    every layer's pages, see :func:`_ragged_paged_attention`) and
-    attends a tile's queries, all H heads of the tile's columns as the
-    rows of its matmuls in the pools' dtype, against the KB x ps lines
-    side by side: scores ``q' c^T + q_rope kr^T``, values over ``c``,
-    and between them ONE online-softmax update in float32 a block: one
-    row maximum, one exponent pass, one rescale of the (rows, c)
-    accumulator. The running maximum and sum lie replicated along a
-    lane tile (:data:`STATE_LANES`). The tile and the block come
-    from the shapes (:func:`mla_block`).
+    """Latent paged attention, ``ff_mla_paged_c<C>``: ONE grid axis over
+    the call's work, a list of (row, query tile, block of logical
+    pages) that the caller's program makes on the device from the rows'
+    positions (:func:`mla_work`): every block a tile's real queries
+    see, in order, and one step for a tile that has none (it writes
+    zeros). The grid's length is the list's, a value of the step and
+    not of its shapes, so a step pays for the blocks it attends: over a
+    (row, tile, block) grid at the table's whole width, 2176 steps of
+    the LongCat cell's call, a call with NO real query took 2.18 ms of
+    a mixed call's 5.33 (PERF.md section 6, PR 60).
 
-    Work follows the real queries: a page past a tile's last real
-    query is neither fetched (its index map repeats the page of that
-    query, and a block whose index repeats is not fetched again; so
-    does a page past the table's end, where ``NP`` is no multiple of
-    KB) nor, by whole blocks, computed; a tile with no real query
-    fetches nothing and writes zeros; a tile whose one real query is
-    its first column (a decode row of a mixed step) runs at one
-    column's rows; the causal mask is computed from the positions and
-    only on the blocks it cuts, where it also hides the block's pages
-    past the last query.
+    A step is handed the block's KB pages of lines through the page
+    table (each pool is passed KB times, one page block an operand;
+    ``row_offset``: the pools are every layer's pages, see
+    :func:`_ragged_paged_attention`) and attends a tile's queries, all
+    H heads of the tile's columns as the rows of its matmuls in the
+    pools' dtype, against the KB x ps lines side by side: scores ``q'
+    c^T + q_rope kr^T``, values over ``c``, and between them ONE
+    online-softmax update in float32 a block: one row maximum, one
+    exponent pass, one rescale of the (rows, c) accumulator. The
+    running maximum and sum lie replicated along a lane tile
+    (:data:`STATE_LANES`). The tile and the block come from the shapes
+    (:func:`mla_block`).
+
+    A page of a tile's last block that lies past its last real query
+    is not fetched (its index map repeats the page of that query, and a
+    block whose index repeats is not fetched again); a tile whose one
+    real query is its first column (a decode row of a mixed step) runs
+    at one column's rows; the causal mask is computed from the
+    positions and only on the blocks it cuts, where it also hides the
+    block's pages past the last query.
     -> (R, C, H, c) in q's dtype, padding columns zero."""
     R, C, H, V = q_abs.shape
     dr = q_rope.shape[-1]
@@ -2010,10 +2074,10 @@ def mla_paged_attention(
     TC, KB = mla_block(C, NP, H, ps)
     if C % TC:
         raise ValueError(f"chunk {C} is no multiple of the query tile {TC}")
-    NB = pl.cdiv(NP, KB)        # blocks of a row's table
     W = KB * ps                 # keys of a block
-    prefetch = [page_table.astype(jnp.int32), q_start.astype(jnp.int32),
-                q_len.astype(jnp.int32)]
+    q_start, q_len = q_start.astype(jnp.int32), q_len.astype(jnp.int32)
+    steps, work = mla_work(q_start, q_len, C // TC, TC, W, pl.cdiv(NP, KB))
+    prefetch = [*work, page_table.astype(jnp.int32), q_start, q_len]
     if row_offset is not None:
         prefetch.append(jnp.asarray(row_offset, jnp.int32).reshape(1))
 
@@ -2021,28 +2085,30 @@ def mla_paged_attention(
         # the last tile of row r that holds a real query, or 0
         return jnp.minimum(t, jnp.maximum(count[r] - 1, 0) // TC)
 
-    def q_block(r, t, b, pt, start, count, *base):
-        return (r, live_tile(r, t, count), 0, 0)
+    def q_block(i, row, tile, blk, pt, start, count, *base):
+        return (row[i], live_tile(row[i], tile[i], count), 0, 0)
 
     def page_block(j):
         # page j of block b, or the page of the tile's last real query
-        # once the block has passed it (a page past the table's end
-        # lies past every query)
-        def index(r, t, b, pt, start, count, *base):
-            t = live_tile(r, t, count)
+        # once the block has passed it
+        def index(i, row, tile, blk, pt, start, count, *base):
+            r = row[i]
+            t = live_tile(r, tile[i], count)
             last = start[r] + jnp.minimum(count[r], (t + 1) * TC) - 1
-            row = pt[r, jnp.minimum(b * KB + j,
-                                    jnp.clip(last // ps, 0, NP - 1))]
+            page = pt[r, jnp.minimum(blk[i] * KB + j,
+                                     jnp.clip(last // ps, 0, NP - 1))]
             if base:
-                row = row + base[0][0]
-            return (row, 0, 0)
+                page = page + base[0][0]
+            return (page, 0, 0)
         return index
 
-    def kernel(pt_ref, start_ref, count_ref, *refs):
+    def kernel(row_ref, tile_ref, blk_ref, pt_ref, start_ref, count_ref,
+               *refs):
         qa_ref, qr_ref, *pages, out_ref, acc, m_scr, l_scr = \
             refs[-(2 * KB + 6):]
         c_refs, kr_refs = pages[:KB], pages[KB:]
-        r, t, b = (pl.program_id(i) for i in range(3))
+        i = pl.program_id(0)
+        r, t, b = row_ref[i], tile_ref[i], blk_ref[i]
         start, count = start_ref[r], count_ref[r]
         cols = jnp.clip(count - t * TC, 0, TC)     # real queries of the tile
         first = start + t * TC                      # position of its column 0
@@ -2092,10 +2158,10 @@ def mla_paged_attention(
             # every query of the n sees every key of the block, or the
             # mask cuts it
             whole = (b * W + W - 1 <= first) & (cols >= n)
-            pl.when((b * W <= last) & whole)(lambda: attend(False))
-            pl.when((b * W <= last) & ~whole)(lambda: attend(True))
+            pl.when(whole)(lambda: attend(False))
+            pl.when(~whole)(lambda: attend(True))
 
-            @pl.when(b == NB - 1)
+            @pl.when(b == last // W)
             def _():
                 o = acc[:M] / _along_lanes(jnp.maximum(l_scr[:M], 1e-20), V)
                 o = o.reshape(n, H, V)
@@ -2110,7 +2176,7 @@ def mla_paged_attention(
             pl.when(cols > 1)(lambda: step(TC))
         pl.when(cols == 1)(lambda: step(1))
 
-        @pl.when((cols == 0) & (b == NB - 1))
+        @pl.when(cols == 0)
         def _():
             out_ref[0] = jnp.zeros((TC, H, V), out_ref.dtype)
 
@@ -2119,7 +2185,8 @@ def mla_paged_attention(
                 + [pl.BlockSpec((1, ps, V), page_block(j)) for j in range(KB)]
                 + [pl.BlockSpec((1, ps // 2, 2 * dr), page_block(j))
                    for j in range(KB)])
-    out_spec = pl.BlockSpec((1, TC, H, V), lambda r, t, b, *_: (r, t, 0, 0))
+    out_spec = pl.BlockSpec((1, TC, H, V),
+                            lambda i, row, tile, *_: (row[i], tile[i], 0, 0))
     out_shape = jax.ShapeDtypeStruct((R, C, H, V), q_abs.dtype)
     M = TC * H
     scratch = [pltpu.VMEM((M, V), jnp.float32),
@@ -2146,13 +2213,13 @@ def mla_paged_attention(
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
-            grid=(R, C // TC, NB),
+            grid=(steps,),
             in_specs=in_specs,
             out_specs=out_spec,
             scratch_shapes=scratch,
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=max(need, _VMEM_SCOPE_DEFAULT),
         ),
         name=f"ff_mla_paged_c{C}",

@@ -1221,7 +1221,10 @@ class RequestManager:
             self.stats.flushes += 1
             if fetch is not None:  # the step's counters ride behind its tokens
                 toks, counts = self.engine.split_fetch(toks)
-                self.stats.note_expert_counts(counts["moe_counts"], tile)
+                self.stats.note_expert_counts(
+                    counts["moe_counts"], tile,
+                    counts.get("moe_zero_pairs", ()),
+                    counts.get("moe_routed_pairs", ()))
             tr = self.tracer
             if tr.enabled:
                 tr.event("flush", entries=len(snapshot))

@@ -11,6 +11,7 @@ only depth is cut. A compile that passes is not a chip run:
 ``chip_smoke.py`` is the run.
 """
 import functools
+import math
 import re
 
 import jax
@@ -1050,8 +1051,9 @@ def test_mla_paged_kernel_compiles(chip, C, monkeypatch):
     one view with a row offset): Mosaic takes the paired rope keys'
     lane halves, the 2048-row tile's accumulators and the page index
     maps that stop at a tile's last real query. ONE call, whose grid is
-    as deep as the table's blocks of pages (``kernels.mla_block``) and
-    whose stated VMEM is under the scope's ceiling."""
+    one axis as long as the step's work list (``kernels.mla_work``: a
+    value of the step, not of its shapes) and whose stated VMEM is
+    under the scope's ceiling."""
     slots, pages, layers = 4, 82, 5
     rows = layers * (slots * 81 + 1)
     tc, kb = kernels.mla_block(C, pages, 128, PAGE)
@@ -1075,7 +1077,8 @@ def test_mla_paged_kernel_compiles(chip, C, monkeypatch):
         chip((slots,), jnp.int32), chip((slots,), jnp.int32))
     assert f"%ff_mla_paged_c{C}" in text
     assert text.count("tpu_custom_call") == 1
-    assert stated["grid"] == (slots, C // tc, -(-pages // kb))
+    (steps,) = stated["grid"]
+    assert not isinstance(steps, int) and steps.shape == ()
     assert stated["vmem"] <= kernels._VMEM_SCOPE_CEILING
 
 
@@ -1383,4 +1386,95 @@ def test_laguna_step_compiles_at_the_cells_depth(chip, C):
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
     assert _need(compiled) / 1e9 < 12.0
     print(f"laguna C={C}: need {_need(compiled) / 1e9:.2f} GB, temp "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, tile {tm}")
+
+
+@pytest.mark.parametrize("C", [1, 128])
+def test_longcat_flash_step_compiles_at_the_cells_depth(chip, C):
+    """models/longcat_flash.py at published widths and the benchmark
+    cell's depth and serving sizes (four layers of two latent attentions,
+    two dense FFNs and the routed block; 16 of 512 experts held under a
+    router of 768 outputs; an eighth of the vocabulary; 16 slots of 133
+    pages, TWO lines a token and layer): the latent kernel at 64 heads
+    under its accepted name and FIRST in the program, the grouped expert
+    matmuls at 16 groups, the pool (eight lines deep) and the experts
+    carried in place, and the weight and pool argument bytes equal to
+    the configuration's arithmetic (5172.6 M parameters; 2.51 GB of
+    pool)."""
+    from flexflow_tpu.models import longcat_flash as fam
+
+    slots, max_seq = 16, 16928
+    cfg = fam.config(num_hidden_layers=4, experts_held=(0, 16),
+                     vocab_size=16384, dtype=jnp.bfloat16)
+    pages = -(-(max_seq + 65) // PAGE)
+    assert pages == 133
+    params = _on(jax.eval_shape(
+        functools.partial(fam.init_params, cfg=cfg), jax.random.PRNGKey(0)),
+        chip)
+    cache = _on(jax.eval_shape(functools.partial(
+        fam.init_paged_kv_cache, cfg, slots * pages, PAGE, jnp.bfloat16)), chip)
+
+    def step(params, cache, tokens, positions, logits_idx, page_table):
+        return fam.serve_step_paged(
+            params, cache, tokens, positions, logits_idx, None, None,
+            page_table, cfg=cfg, cache_len=max_seq + 64, kernels="pallas",
+            pack=512 if C > 1 else None)
+
+    compiled, text = _compile(
+        step, params, cache, chip((slots, C), jnp.int32),
+        chip((slots, C), jnp.int32), chip((slots,), jnp.int32),
+        chip((slots, pages), jnp.int32), donate=(1,))
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+    attention = (6144 * 1536 + 1536 * 64 * 192 + 6144 * 576
+                 + 512 * 64 * 256 + 64 * 128 * 6144)
+    layer = (2 * attention + 2 * 3 * 6144 * 12288 + 6144 * 768
+             + 16 * 3 * 6144 * 2048)
+    matmul = 4 * layer + 2 * 16384 * 6144
+    assert (attention, layer, matmul) == (90_570_752, 1_242_824_704, 5_172_625_408)
+    scales = 4 * (2 * (6144 + 1536 + 512) + 2 * 6144) + 6144
+    assert nbytes(params) == 2 * (matmul + scales) + 4 * 4 * 768
+    assert nbytes(cache) == 8 * (slots * pages + 1) * 128 * 1152
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes - nbytes(params) - nbytes(cache) < 1 << 20
+    # one kind of layer: every kernel call lies in the loop's body, the
+    # first attention's first (the benchmark keys a step program by it)
+    body, = (c for c in text.split("\n\n") if f"%ff_mla_paged_c{C}" in c)
+    calls = re.findall(r"%(ff_\w+?)(?:\.\d+)* = (\S+) custom-call\(.*tpu_custom_call", body)
+    # (where in the body the compiler puts the routed block's two calls
+    # is its own: the shortcut leaves it free up to the layer's last sum;
+    # at C=1 they follow the SECOND attention)
+    tile = 16 if C == 1 else 128
+    assert sorted(name for name, _ in calls) == [
+        f"ff_mla_paged_c{C}", f"ff_mla_paged_c{C}",
+        f"ff_moe_grouped_down_t{tile}", f"ff_moe_grouped_glu_t{tile}"], calls
+    assert calls[0][0] == f"ff_mla_paged_c{C}"
+    assert f"[{slots},{C},64,512]" in calls[0][1], calls[0]
+    tokens = 512 if C > 1 else slots
+    tm = kernels.grouped_tile(12 * tokens, 16, 768)
+    rows = _pair_rows(12 * tokens, 16, 768)
+    assert re.findall(rf"%ff_moe_grouped_glu_t{tm}\S* = bf16\[{rows},2048\]", text)
+    assert re.findall(rf"%ff_moe_grouped_down_t{tm}\S* = f32\[{rows},6144\]", text)
+    experts = params["sparse"]["w_gate"]
+    for a in (cache["latent"], cache["latent_rope"], experts,
+              jax.ShapeDtypeStruct(experts.shape[1:], experts.dtype)):
+        dims = ",".join(map(str, a.shape))
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+    # nothing runs over a whole pool flattened (a scatter of half lane
+    # tiles does: a 279 MB operand, one update a token), and the
+    # kernel's (slots, C) result is not laid out anew on its way back to
+    # the token axis (1.2 ms a call where (H, c) were folded first)
+    for a in (cache["latent"], cache["latent_rope"]):
+        assert f"[{a.size}]" not in text
+    if C > 1:
+        for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text):
+            assert math.prod(map(int, dims.split(","))) < slots * C * 64 * 512, dims
+    # ONE loop carries both of the pool's arrays whole
+    loops = [carry for carry in re.findall(r"= \((.*?)\) while\(", text)
+             if "bf16[8,2129,128,512]" in carry]
+    assert len(loops) == 1 and "bf16[8,2129,64,128]" in loops[0]
+    assert _need(compiled) / 1e9 < 14.0
+    print(f"longcat_flash C={C}: need {_need(compiled) / 1e9:.2f} GB, temp "
           f"{mem.temp_size_in_bytes / 1e9:.2f} GB, tile {tm}")

@@ -19,6 +19,7 @@ from flexflow_tpu.models import (
     laguna,
     lfm2_moe,
     llama,
+    longcat_flash,
     minicpm_sala,
     mistral,
     mixtral,
@@ -55,6 +56,9 @@ FAMILIES = {
     "qwen3_next": (qwen3_next, ALWAYS | {"ff.mixer", "ff.moe.route"}),
     # heads by kind, a gate a head, a leading dense layer, a shared expert
     "laguna": (laguna, ALWAYS | {"ff.moe.route"}),
+    # two attentions and two dense FFNs a layer, the routed block (its
+    # identity outputs' part too) on a shortcut across the second pair
+    "longcat_flash": (longcat_flash, ALWAYS | {"ff.moe.route"}),
 }
 # the operations that do a step's work: none may lie outside the scopes
 WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
@@ -80,6 +84,17 @@ def _serve(mod, *, sanitizers=("retrace",), max_seq=128):
         rm.step()
     rm.drain()
     return eng, rm
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Twelve families' servers compile thousands of small programs,
+    each a few memory maps of its worker's process, which has 65530 (a
+    worker that passes the limit aborts inside a later file's compile;
+    tests/test_longcat_flash.py has the measurement): drop them when
+    the file is done."""
+    yield
+    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")
