@@ -193,8 +193,13 @@ class SchedulerStats:
     # step that is not packed counts slots x chunk), and the steps by
     # width. The dict is REPLACED on every count, never updated in
     # place, so a copy of the stats keeps the counts of its own moment.
+    # ``rung_trims`` are the steps that gave prompt tokens up to stay on
+    # a rung (serve/request_manager.trim_to_rung), ``rung_trim_tokens``
+    # the tokens given up: each went out in its row's next chunk.
     step_tokens_real: int = 0
     step_tokens_width: int = 0
+    rung_trims: int = 0
+    rung_trim_tokens: int = 0
     # The routed expert layers of a sparse family that returns its
     # tokens per expert with each pipelined step (``step_counts``;
     # note_expert_counts, at the flush that fetches them), summed over
@@ -345,11 +350,15 @@ class SchedulerStats:
             self.window_pages_unfreed_peak,
             sum(a.untrimmed_pages for a in windowed))
 
-    def note_step_tokens(self, real: int, width: int) -> None:
+    def note_step_tokens(self, real: int, width: int,
+                         trimmed: int = 0) -> None:
         """Count one mixed step's token axis: the ``real`` tokens it
-        held and the ``width`` it ran at."""
+        held, the ``width`` it ran at, and the prompt tokens it gave up
+        to run there (``trimmed``)."""
         self.step_tokens_real += int(real)
         self.step_tokens_width += int(width)
+        self.rung_trims += trimmed > 0
+        self.rung_trim_tokens += int(trimmed)
         by = self.steps_by_width
         self.steps_by_width = {**by, int(width): by.get(int(width), 0) + 1}
 
@@ -475,6 +484,8 @@ class SchedulerStats:
             "step_tokens_real": self.step_tokens_real,
             "step_tokens_width": self.step_tokens_width,
             "pack_fill": round(self.pack_fill, 4),
+            "rung_trims": self.rung_trims,
+            "rung_trim_tokens": self.rung_trim_tokens,
             "head_steps": self.head_steps,
             "head_greedy_steps": self.head_greedy_steps,
             "steps_by_width": dict(sorted(self.steps_by_width.items())),
@@ -512,6 +523,7 @@ class SchedulerStats:
             f"pack={s['step_tokens_real']}/{s['step_tokens_width']} by width "
             + (",".join(f"{w}:{n}" for w, n in s["steps_by_width"].items())
                or "-")
+            + f" trims={s['rung_trims']}/{s['rung_trim_tokens']}tok"
         )
 
 

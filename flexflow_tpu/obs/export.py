@@ -119,6 +119,7 @@ SCHED_COUNTERS = frozenset({
     "real_rows", "state_resets", "sparse_rows",
     "attn_steps_grid", "attn_steps_live", "attn_steps_narrow",
     "step_tokens_real", "step_tokens_width",
+    "rung_trims", "rung_trim_tokens",
     "moe_pairs", "moe_experts_hit", "moe_experts_held", "moe_load_max",
     "moe_tiles", "moe_zero_pairs", "moe_routed_pairs",
     "latent_lines", "recurrent_updates", "head_steps", "head_greedy_steps",

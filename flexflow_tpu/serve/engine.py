@@ -682,7 +682,10 @@ def pack_widths(slots: int, chunk: int) -> Tuple[int, ...]:
     top because a closed loop of prompts fills about a quarter of a
     mixed step, one at the bottom because a closed loop of decoding
     rows fills slots + chunk whatever the slots: a fiftieth of 64 x
-    128. One rung is no ladder: ``chunk == 1``, a single slot."""
+    128. One rung is no ladder: ``chunk == 1``, a single slot. The
+    scheduler keeps a step under a rung: where the decoding rows' own
+    tokens push whole chunks a few places over one, the newest prompt's
+    chunk gives them up (``request_manager.trim_to_rung``)."""
     top = slots * chunk
     if chunk == 1:
         return (top,)

@@ -62,6 +62,41 @@ class RequestStatus(enum.Enum):
 TERMINAL_STATUSES = (RequestStatus.COMPLETED, RequestStatus.ERROR)
 
 
+def trim_to_rung(ladder: Sequence[int], slots: int, decoding: int,
+                 chunks: Sequence[int], final: Sequence[bool]) -> List[int]:
+    """The prompt tokens a mixed step hands out, held under a rung of
+    the engine's own ladder. ``ladder`` is the engine's packed rungs,
+    ascending (:meth:`InferenceEngine.pack_ladder`), ``decoding`` the
+    step's rows of one token, ``chunks`` the tokens each prefilling row
+    would take, OLDEST admission first, and ``final`` whether that
+    chunk is its prompt's last. Where the step's real tokens pass the
+    widest rung under them by no more than ``slots`` (the most that
+    decoding rows can ever add to a step: prompts in whole chunks sit
+    ON a rung, and the rows' own tokens push them over it), the step
+    gives those few tokens up and runs at that rung and not the next,
+    at least twice as wide: they are taken from the newest prompt
+    first, so the oldest keep their pace, and from a row whose chunk is
+    not its prompt's last before one whose chunk is (a trimmed last
+    chunk costs that request a step of its first token). A row left
+    with nothing is not in the step. Returns the chunks to hand out:
+    they and ``decoding`` sum to the rung where the rule fires, and are
+    ``chunks`` as given where it does not (an empty ladder, a step
+    further over than the slots, a rung that the decoding rows alone
+    fill: a step must hand some prompt a token). A given token is in
+    its row's next chunk; none is dropped."""
+    out = list(chunks)
+    real = decoding + sum(out)
+    rung = max((w for w in ladder if w < real), default=0)
+    give = real - rung
+    if rung <= decoding or give > slots:
+        return out
+    for i in sorted(range(len(out)), key=lambda i: (final[i], -i)):
+        take = min(give, out[i])
+        out[i] -= take
+        give -= take
+    return out
+
+
 @dataclasses.dataclass
 class Request:
     """reference ``Request`` (request_manager.h:92-278)."""
@@ -1126,8 +1161,15 @@ class RequestManager:
             spent = 0
             finals = []  # rows whose sample of this step is their first token
             tr = self.tracer
-            for req in sorted(prefilling, key=lambda r: r.admit_seq):
-                n = min(C, len(req.tokens) - req.n_sched)
+            rows = sorted(prefilling, key=lambda r: r.admit_seq)
+            asked = [max(0, min(C, len(r.tokens) - r.n_sched)) for r in rows]
+            # a step a few tokens over a rung of the engine's ladder
+            # gives them up and runs at the rung (trim_to_rung)
+            chunks = trim_to_rung(
+                eng.pack_ladder(C), R, len(decoding), asked,
+                [r.n_sched + n >= len(r.tokens) for r, n in zip(rows, asked)])
+            trimmed = sum(asked) - sum(chunks)
+            for req, n in zip(rows, chunks):
                 if n <= 0:
                     continue
                 s = req.slot
@@ -1196,13 +1238,13 @@ class RequestManager:
                                  getattr(eng.cfg, "dense_len", None))
         self._note_attn_steps(bc.positions[:, 0], bc.qlens, C)
         real = int(bc.qlens.sum())
-        self.stats.note_step_tokens(real, eng.pack_width(real, C))
+        self.stats.note_step_tokens(real, eng.pack_width(real, C), trimmed)
         self.stats.latent_lines += real * self._latent_layers
         self.stats.recurrent_updates += real * self._recurrent_layers
         if tr.enabled:
             tr.event(
                 "mixed_step", prefill_tokens=spent,
-                decode_rows=len(decoding),
+                decode_rows=len(decoding), trimmed=trimmed,
             )
         self._maybe_log_stats()
 

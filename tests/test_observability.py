@@ -226,6 +226,44 @@ def test_export_drift_guard_catches_missing_and_stale(monkeypatch):
         check_export_coverage()
 
 
+@pytest.mark.parametrize("layout, ladder", [("paged", (8, 16)), ("dense", ())])
+def test_rung_trims_on_every_surface(tiny, layout, ladder):
+    """The steps that gave prompt tokens up to stay on a rung of the
+    engine's ladder (ISSUE 61): in ``snapshot()``, on the report line
+    beside the steps by width, on the scrape surface beside the
+    ``pack_fill`` counters, and on each ``mixed_step`` event; all zero
+    on an engine whose mixed step has no ladder."""
+    rm = make_rm(tiny, kv_layout=layout)
+    assert rm.engine.pack_ladder(8) == ladder
+    buf = attach_observability(rm)
+    rng = np.random.default_rng(61)
+    prompts = [rng.integers(1, 200, 20 + 3 * i).tolist() for i in range(7)]
+    outs = rm.generate(prompts, max_new_tokens=8)
+    assert all(o.error is None for o in outs)
+    s = rm.stats
+    snap = s.snapshot()
+    assert snap["rung_trims"] == s.rung_trims
+    assert snap["rung_trim_tokens"] == s.rung_trim_tokens
+    assert f" trims={s.rung_trims}/{s.rung_trim_tokens}tok" in s.report()
+    text = prometheus_text(scheduler={"0": s})
+    assert "# TYPE flexflow_scheduler_rung_trims counter" in text
+    assert f'flexflow_scheduler_rung_trims{{replica="0"}} {s.rung_trims}' in text
+    assert (f'flexflow_scheduler_rung_trim_tokens{{replica="0"}} '
+            f'{s.rung_trim_tokens}') in text
+    assert {"rung_trims", "rung_trim_tokens", "step_tokens_real",
+            "step_tokens_width"} <= obs_export.SCHED_COUNTERS
+    steps = [e for e in buf.events if e["name"] == "mixed_step"]
+    assert len(steps) == s.mixed_steps > 0
+    given = [e["attrs"]["trimmed"] for e in steps]
+    assert sum(given) == s.rung_trim_tokens
+    assert sum(g > 0 for g in given) == s.rung_trims
+    if ladder:
+        assert 0 < s.rung_trims <= s.rung_trim_tokens
+        assert all(0 <= g <= 4 for g in given)    # the slots at most
+    else:
+        assert s.rung_trims == s.rung_trim_tokens == 0
+
+
 # ---------------------------------------------------------------------------
 # flight recorder units
 

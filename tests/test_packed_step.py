@@ -21,6 +21,7 @@ from flexflow_tpu.metrics import SchedulerStats
 from flexflow_tpu.models import mistral, mixtral, transformer
 from flexflow_tpu.serve import InferenceEngine, RequestManager, ServingConfig
 from flexflow_tpu.serve.engine import pack_widths, program_name
+from flexflow_tpu.serve.request_manager import trim_to_rung
 
 R, C, PS = 6, 8, 8           # ladder (12, 24, 48): two packed rungs
 TOL = dict(rtol=2e-5, atol=2e-5)  # a few float32 ulp of logits of order 1
@@ -197,12 +198,33 @@ def _prompts(n, vocab=250):
 def _serve(model, pack, kernels="xla", n=20, new=lambda i: 5 + i % 4, **kw):
     eng = _engine(model, kernels, pack, sanitizers=("retrace",), **kw)
     rm = RequestManager(eng)
+    rm.mixed = []  # each mixed step's (prompt tokens, decoding rows)
+    record = rm.stats.record_step
+
+    def record_step(kind, **kw):
+        if kind == "mixed":
+            rm.mixed.append((kw["prefill_tokens"], kw["decode_tokens"]))
+        record(kind, **kw)
+
+    rm.stats.record_step = record_step
     rids = [rm.submit(p, max_new_tokens=new(i))
             for i, p in enumerate(_prompts(n))]
     while rm.step():
         pass
     rm.drain()
     return rm, [list(rm.requests[r].output_tokens) for r in rids]
+
+
+def _assert_real_tokens_are_the_mixed_steps_own(rm):
+    """``step_tokens_real`` is what the mixed steps were handed: their
+    prompt tokens and their decoding rows' one token each, as
+    ``record_step`` was told them. (Equal to the padded engine's it is
+    no longer, ISSUE 61: a trimmed step's tokens go out a step later,
+    beside that step's decoding rows.)"""
+    s = rm.stats
+    assert len(rm.mixed) == s.mixed_steps and s.sync_steps == 0
+    assert sum(p for p, _ in rm.mixed) == s.prefill_tokens
+    assert s.step_tokens_real == s.prefill_tokens + sum(d for _, d in rm.mixed)
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
@@ -215,8 +237,11 @@ def test_scheduler_generations_equal_the_padded_engine(model, kernels):
     assert outs == outs0
     s = rm.stats
     assert s.preemptions > 0 and s.prefix_hits > 0 and s.admitted >= 20
-    # the packed engine ran narrower steps; the padded one never
-    assert s.step_tokens_real == rm0.stats.step_tokens_real
+    # the packed engine ran narrower steps; the padded one never, and
+    # has no rung to keep a step under
+    assert s.rung_trims > 0 and rm0.stats.rung_trims == 0
+    _assert_real_tokens_are_the_mixed_steps_own(rm)
+    _assert_real_tokens_are_the_mixed_steps_own(rm0)
     assert set(rm0.stats.steps_by_width) == {R * C}
     assert set(s.steps_by_width) <= {12, 24, 48} and min(s.steps_by_width) < 48
     assert s.step_tokens_width < rm0.stats.step_tokens_width
@@ -253,7 +278,9 @@ def test_a_house_of_decoding_rows_admits_on_the_admission_rung(model):
     assert outs == outs0
     s = rm.stats
     assert set(rm0.stats.steps_by_width) == {R * C}
-    assert s.step_tokens_real == rm0.stats.step_tokens_real
+    assert s.prefill_tokens == rm0.stats.prefill_tokens  # no preemption
+    _assert_real_tokens_are_the_mixed_steps_own(rm)
+    _assert_real_tokens_are_the_mixed_steps_own(rm0)
     assert set(s.steps_by_width) <= set(pack_widths(R, C))
     assert s.steps_by_width[32] > s.mixed_steps / 2
     assert s.steps_by_width == collections.Counter(
@@ -416,6 +443,123 @@ def test_ladder_follows_from_slots_and_chunk(slots, chunk, want):
         slots + chunk <= w < 2 * (slots + chunk) for w in below)
 
 
+def _whole_chunks(k, chunk=128):
+    return [chunk] * k, [False] * k
+
+
+# the cells' geometries and their just-over steps (ISSUE 61): slots,
+# chunk, decoding rows, prefilling rows' chunks oldest first, the width
+# the step runs at (None: the rule leaves it alone)
+JUST_OVER = [
+    (4, 128, 3, 1, 128),        # DeepSeek's cell: 131 -> 128
+    (4, 128, 2, 2, 256),        # 258 -> 256
+    (4, 128, 1, 3, None),       # 385: 129 over 256
+    (16, 128, 12, 4, 512),      # the long-prompt cells: 524 -> 512
+    (16, 128, 8, 8, 1024),      # 1032 -> 1024 (had run the padded 2048)
+    (16, 128, 11, 5, None),     # 651
+    (16, 128, 14, 2, 256),      # 270 -> 256, the admission rung
+    (16, 128, 10, 6, None),     # 778
+    (8, 128, 4, 4, 512),        # SmallThinker's cell: 516 -> 512
+    (8, 128, 6, 2, 256),        # 262 -> 256
+    (8, 128, 2, 6, None),       # 770
+    (64, 128, 62, 2, 256),      # the 64-slot cells: 318 -> 256
+    (64, 128, 48, 16, 2048),    # 2096 -> 2048
+    (64, 128, 63, 1, None),     # 191: under the narrowest rung
+    (6, 8, 5, 1, 12),           # this file's geometry: 13 -> 12
+    (12, 16, 11, 1, None),      # 27 sits in the admission rung's 32
+]
+
+
+@pytest.mark.parametrize("slots, chunk, d, k, width", JUST_OVER, ids=[
+    f"{s}x{c}-{d + k * c}" for s, c, d, k, _ in JUST_OVER])
+def test_a_step_just_over_a_rung_gives_the_tokens_up(slots, chunk, d, k, width):
+    ladder = pack_widths(slots, chunk)[:-1]
+    chunks, final = _whole_chunks(k, chunk)
+    got = trim_to_rung(ladder, slots, d, chunks, final)
+    if width is None:
+        assert got == chunks
+        return
+    assert width in ladder and d + sum(got) == width
+    # the newest prompt alone pays (chunk > slots: one row has enough)
+    assert got[:-1] == chunks[:-1] and got[-1] == chunk - (d + k * chunk - width)
+    # and the step now runs at the rung it was over, not the next
+    nxt = next((w for w in pack_widths(slots, chunk) if w >= d + k * chunk))
+    assert nxt > width
+
+
+def test_a_step_further_over_than_the_slots_is_left_alone():
+    # 64 x 128: 2113 is 65 over 2048 (a partial chunk beside whole ones)
+    ladder = pack_widths(64, 128)[:-1]
+    chunks = [128] * 16 + [17]
+    assert trim_to_rung(ladder, 64, 48, chunks, [False] * 16 + [True]) == chunks
+    chunks[-1] = 16                                  # 2112: 64 over
+    got = trim_to_rung(ladder, 64, 48, chunks, [False] * 16 + [True])
+    assert 48 + sum(got) == 2048 and got[-1] == 16   # the final chunk kept
+    assert got[-2] == 64 and got[:-2] == [128] * 15
+
+
+@pytest.mark.parametrize("ladder", [(), (512,)], ids=["no-ladder", "under-every-rung"])
+def test_no_rung_under_the_step_nothing_given_up(ladder):
+    chunks = [128, 128, 7]
+    assert trim_to_rung(ladder, 16, 13, chunks, [False, False, True]) == chunks
+    assert trim_to_rung(ladder, 16, 0, [], []) == []
+
+
+def test_the_order_of_giving_up():
+    ladder = (6, 12)                     # 6 slots x chunk 4
+    # newest first: the row admitted last gives before the one before it
+    assert trim_to_rung(ladder, 6, 5, [4, 4], [False, False]) == [4, 3]
+    # a row emptied leaves the step, and the next newest gives the rest
+    assert trim_to_rung(ladder, 6, 2, [4, 4, 4, 4], [False] * 4) == [4, 4, 2, 0]
+    # a final chunk gives last, however new its row
+    assert trim_to_rung(ladder, 6, 3, [4, 4, 3], [False, False, True]) == [4, 2, 3]
+    assert trim_to_rung(ladder, 6, 3, [4, 3, 4], [False, True, False]) == [4, 3, 2]
+    # ... but gives where the others have nothing left
+    assert trim_to_rung(ladder, 6, 5, [3, 4], [True, False]) == [1, 0]
+    assert trim_to_rung(ladder, 6, 4, [2, 2], [True, True]) == [2, 0]
+    # the input is not written to
+    chunks = [4, 4]
+    trim_to_rung(ladder, 6, 5, chunks, [False, False])
+    assert chunks == [4, 4]
+
+
+def test_a_rung_the_decoding_rows_fill_is_no_rung_to_stop_at():
+    # 16 slots x chunk 2, ladder (8, 16): nine decoding rows beside one
+    # chunk are 11, 3 over 8, but 8 places would hold no prompt token
+    assert pack_widths(16, 2)[:-1] == (8, 16)
+    assert trim_to_rung((8, 16), 16, 9, [2], [False]) == [2]
+    assert trim_to_rung((8, 16), 16, 8, [2], [False]) == [2]
+    assert trim_to_rung((8, 16), 16, 7, [2], [False]) == [1]   # one stays
+
+
+@pytest.mark.parametrize("slots, chunk", [(4, 128), (16, 128), (8, 128),
+                                          (64, 128), (6, 8), (12, 16), (4, 16)])
+def test_every_step_of_a_geometry_keeps_the_invariant(slots, chunk):
+    """Every (decoding rows, prefilling rows, last chunk's length) of a
+    geometry: where the rule fires the step sits ON a rung, no row
+    gains a token, and some prompt keeps one; where it does not the
+    chunks are untouched; after it no step is over a rung by the slots
+    or less unless that rung held no prompt token."""
+    ladder = pack_widths(slots, chunk)[:-1]
+    fired = 0
+    for d in range(slots):
+        for k in range(1, slots - d + 1):
+            for last in sorted({1, 2, chunk // 2, chunk - 1, chunk}):
+                chunks = [chunk] * (k - 1) + [last]
+                final = [False] * (k - 1) + [last < chunk]
+                got = trim_to_rung(ladder, slots, d, chunks, final)
+                real, now = d + sum(chunks), d + sum(got)
+                assert all(0 <= g <= n for g, n in zip(got, chunks))
+                if got != chunks:
+                    fired += 1
+                    assert now in ladder and 0 < real - now <= slots
+                    assert sum(got) > 0
+                else:
+                    assert not any(0 < real - w <= slots and w > d
+                                   for w in ladder)
+    assert fired
+
+
 def test_step_token_counters_against_a_hand_counted_schedule():
     s = SchedulerStats()
     at_open = dataclasses.replace(s)
@@ -430,8 +574,17 @@ def test_step_token_counters_against_a_hand_counted_schedule():
     assert snap["step_tokens_real"] == 3464 and snap["step_tokens_width"] == 4608
     assert snap["pack_fill"] == round(3464 / 4608, 4)
     assert snap["steps_by_width"] == {512: 3, 1024: 1, 2048: 1}
-    assert "pack=3464/4608 by width 512:3,1024:1,2048:1" in s.report()
-    assert "by width -" in SchedulerStats().report()
+    assert "pack=3464/4608 by width 512:3,1024:1,2048:1 trims=0/0tok" in s.report()
+    assert "by width - trims=0/0tok" in SchedulerStats().report()
+    # the steps that gave tokens up to stay on a rung (ISSUE 61)
+    s.note_step_tokens(512, 512, 12)
+    s.note_step_tokens(1024, 1024, 8)
+    s.note_step_tokens(300, 512, 0)
+    assert (s.rung_trims, s.rung_trim_tokens) == (2, 20)
+    assert (at_open.rung_trims, at_open.rung_trim_tokens) == (0, 0)
+    snap = s.snapshot()
+    assert (snap["rung_trims"], snap["rung_trim_tokens"]) == (2, 20)
+    assert "512:5,1024:2,2048:1 trims=2/20tok" in s.report()
 
 
 @pytest.mark.parametrize("key, name", [
