@@ -36,7 +36,7 @@ SEED = 0
 # Depth. One layer is 436 MB of bf16 weights and 4 KB per cached token;
 # the page pool is the layer loop's carry, held once (PR 28; before, the
 # step program held it twice and N=24 needed 16.8 GB through the XLA
-# reference). Compiled for a described v5e (tests/test_chip_compile.py
+# reference). Compiled for a described v5e (tests/test_chip_compile_*.py
 # has the program, /opt/skills/guides/on-chip-measurement section 2 the
 # method), the C=128 step with a 16k-token pool needs, of the 15.49 GB
 # the chip leaves a program: N=20 10.7 GB through Pallas and 11.8 GB

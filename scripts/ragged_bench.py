@@ -34,7 +34,7 @@ scratch copy of the tree with hooks for it reads that variable, the
 package does not.
 
 ``--bundles`` needs no chip: it compiles each case's call for a
-DESCRIBED v5e (as tests/test_chip_compile.py does) with libtpu's own
+DESCRIBED v5e (as tests/test_chip_compile_*.py does) with libtpu's own
 dump of the kernel's final instruction bundles, and prints the kernel's
 straight-line blocks (between branch targets) with their bundle counts
 and what fills them: vector loads and stores, the vector ALUs, matmul

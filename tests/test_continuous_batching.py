@@ -30,16 +30,6 @@ def tiny():
     return cfg, params
 
 
-def ref_greedy(cfg, params, prompt, n_new):
-    toks = list(prompt)
-    for _ in range(n_new):
-        logits = llama.forward(
-            params, jnp.asarray([toks], dtype=jnp.int32), cfg
-        )
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
 def make_engine(tiny, kv_layout="dense", *, slots=4, max_seq=96, **kw):
     cfg, params = tiny
     sc = ServingConfig(
@@ -125,7 +115,7 @@ def test_mixed_step_logits_bitwise_vs_sync(tiny, kv_layout):
 
 
 @pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_continuous_generate_matches_reference(tiny, kv_layout):
+def test_continuous_generate_matches_reference(tiny, kv_layout, ref_greedy):
     """End-to-end continuous batching (queueing, mixed steps, pipeline)
     produces exactly the single-request greedy outputs."""
     cfg, params = tiny
@@ -145,7 +135,7 @@ def test_continuous_generate_matches_reference(tiny, kv_layout):
     assert rm.stats.sync_steps == 0  # nothing ever took the blocking path
 
 
-def test_admission_mid_decode_no_pipeline_drain(tiny):
+def test_admission_mid_decode_no_pipeline_drain(tiny, ref_greedy):
     """A request admitted while another is in steady-state decode must
     NOT drain the dispatch-ahead pipeline (the flush-on-admit stall this
     scheduler removes). Regression: assert zero full flushes while both
@@ -173,7 +163,7 @@ def test_admission_mid_decode_no_pipeline_drain(tiny):
     assert rm.requests[r2].output_tokens == ref_greedy(cfg, params, p2, 8)
 
 
-def test_preemption_during_continuous_batching(tiny):
+def test_preemption_during_continuous_batching(tiny, ref_greedy):
     """An oversubscribed page pool must preempt + re-admit under the
     pipelined mixed scheduler without changing any output, and reclaim
     every page."""
@@ -196,7 +186,7 @@ def test_preemption_during_continuous_batching(tiny):
     assert rm.engine.pager.free_pages == rm.engine.pager.num_pages
 
 
-def test_unservable_request_errors_instead_of_livelock(tiny):
+def test_unservable_request_errors_instead_of_livelock(tiny, ref_greedy):
     """Live-lock regression: a request whose prompt can never fit the
     configured KV budget must fail with an ERROR status surfaced in its
     GenerationResult — generate() terminates and healthy requests are
@@ -219,7 +209,7 @@ def test_unservable_request_errors_instead_of_livelock(tiny):
     assert rm.engine.pager.free_pages == rm.engine.pager.num_pages
 
 
-def test_prefill_budget_bounds_tokens_per_step(tiny):
+def test_prefill_budget_bounds_tokens_per_step(tiny, ref_greedy):
     """``max_tokens_per_step`` caps the prompt tokens a mixed step may
     carry; the prompt still completes (over more steps) with identical
     output."""
@@ -234,7 +224,7 @@ def test_prefill_budget_bounds_tokens_per_step(tiny):
     assert rm.stats.prefill_tokens == len(prompt)
 
 
-def test_generate_stream_and_profile(tiny):
+def test_generate_stream_and_profile(tiny, ref_greedy):
     """generate_stream yields every token plus one terminal event per
     request; TTFT/TPOT are recorded on the profile."""
     cfg, params = tiny
@@ -296,7 +286,7 @@ def _staggered_prompts(cfg, n):
     ]
 
 
-def test_deterministic_arrival_scheduler_parity(tiny):
+def test_deterministic_arrival_scheduler_parity(tiny, ref_greedy):
     """Tier-1 coverage of the bench scenario: requests arriving every
     few steps produce identical outputs under the continuous and the
     flush-on-admit schedulers — and both match the reference decoder."""
@@ -315,7 +305,7 @@ def test_deterministic_arrival_scheduler_parity(tiny):
 
 
 @pytest.mark.slow
-def test_poisson_arrival_scheduler_parity(tiny):
+def test_poisson_arrival_scheduler_parity(tiny, ref_greedy):
     """The bench workload shape: Poisson arrivals at high churn, more
     requests than slots. Outputs must be identical across schedulers
     and TTFT must be recorded for every request."""

@@ -27,13 +27,16 @@ import pytest
 
 from flexflow_tpu.models import deepseek_v3 as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig, kernels
+from flexflow_tpu.serve import kernels
 from flexflow_tpu.serve.engine import InferenceEngine
 from flexflow_tpu.serve.llm import LLM
 
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-5
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128
+PAGE, CHUNK = 16, 16            # the tiny serving configuration's (conftest.py)
+FAMILIES = {"deepseek_v3": Family(fam, ALWAYS | {"ff.moe.route"})}
 
 
 def _reference():
@@ -76,29 +79,13 @@ def _file_config(cfg, **kw):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
-
-
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def shared(tiny):
-    return _server(tiny)
+@pytest.fixture
+def shared(tiny_servers):
+    return tiny_servers(fam).llm
 
 
 def _release(eng):
@@ -135,7 +122,7 @@ def _rms_share(got, want):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_served_logits_match_the_reference(tiny, shared, kernels):
+def test_served_logits_match_the_reference(tiny, tiny_servers, kernels):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (packed rungs of the
     ladder), then pure decode steps, through the latent paged pool:
@@ -143,7 +130,7 @@ def test_served_logits_match_the_reference(tiny, shared, kernels):
     full forward pass in the expanded form; the step's expert counts
     are the routed pairs of its real tokens."""
     cfg, params = tiny
-    eng = shared.engine if kernels == "xla" else _server(tiny, kernels=kernels).engine
+    eng = tiny_servers(fam, kernels=kernels).engine
     assert eng.pack_ladder(CHUNK) == (16, 32)
     rng = np.random.default_rng(1)
     seqs = {r: rng.integers(0, cfg.vocab_size, 70).tolist() for r in (0, 2)}
@@ -191,12 +178,12 @@ def test_greedy_tokens_through_generate_are_the_references(tiny, shared):
     assert stats.slot_state_bytes == 0
 
 
-def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared):
+def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared, tiny_servers):
     cfg, _ = tiny
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, cfg.vocab_size, 40 + 8 * i).tolist() for i in range(4)]
     want = [o.output_tokens for o in shared.generate(prompts, max_new_tokens=8)]
-    tight = _server(tiny, max_sequence_length=96, max_cached_tokens=128)
+    tight = tiny_servers(fam, fresh=True, max_sequence_length=96, max_cached_tokens=128).llm
     outs = tight.generate(prompts, max_new_tokens=8)
     assert [o.output_tokens for o in outs] == want
     assert tight.rm.stats.preemptions > 0, "the pool was never oversubscribed"
@@ -550,23 +537,23 @@ def test_from_hf_reads_the_catalog_row_and_the_benchmark_configuration():
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
     (dict(kv_layout="dense"), "kv_layout"),
 ], ids=lambda v: v if isinstance(v, str) else "")
-def test_refused_combinations_name_their_reason(tiny, serving, names):
+def test_refused_combinations_name_their_reason(tiny, tiny_servers, serving, names):
     cfg, params = tiny
     with pytest.raises((NotImplementedError, ValueError), match=names):
-        InferenceEngine(fam, cfg, params, _serving(**serving))
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving))
 
 
-def test_a_model_parallel_mesh_is_refused(tiny):
+def test_a_model_parallel_mesh_is_refused(tiny, tiny_servers):
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=2).make_mesh(jax.devices()[:2])
     with pytest.raises(NotImplementedError, match="model > 1"):
-        InferenceEngine(fam, cfg, params, _serving(), mesh)
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(), mesh)
 
 
 @pytest.mark.parametrize("draft", ["ssm", "early_exit"])
-def test_speculation_is_refused(tiny, draft):
+def test_speculation_is_refused(tiny, tiny_servers, draft):
     from flexflow_tpu.serve import SpecConfig
     from flexflow_tpu.serve.llm import SSM
 
@@ -575,7 +562,7 @@ def test_speculation_is_refused(tiny, draft):
     ssms = [SSM(fam, cfg, params=params)] if draft == "ssm" else []
     spec = SpecConfig(draft=draft, draft_layers=1) if draft == "early_exit" else None
     with pytest.raises(NotImplementedError, match="SpecInfer"):
-        llm.compile(_serving(), ssms=ssms, spec=spec)
+        llm.compile(tiny_servers.serving(), ssms=ssms, spec=spec)
 
 
 def test_beam_search_is_refused(shared):

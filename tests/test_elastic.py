@@ -723,10 +723,6 @@ def _spawn_server(serving_dict, index=0, seed=0):
     return proc, port
 
 
-def _serving_dict(**kw):
-    return sc_kwargs(cache_dtype="float32", **kw)
-
-
 @pytest.mark.slow
 def test_subprocess_kill_restart_reconnects(tiny, tmp_path):
     """The flagship multi-process recovery: the manager process dies
@@ -736,7 +732,7 @@ def test_subprocess_kill_restart_reconnects(tiny, tmp_path):
     RPCs at-most-once) and re-admits the journaled requests, bitwise
     the uninterrupted socket run."""
     cfg, params = tiny
-    procs_ports = [_spawn_server(_serving_dict(), index=i)
+    procs_ports = [_spawn_server(sc_kwargs(cache_dtype="float32"), index=i)
                    for i in range(2)]
     try:
         eps = tuple(f"127.0.0.1:{port}" for _, port in procs_ports)
@@ -791,7 +787,7 @@ def test_chaos_sigkill_server_and_manager_crash(tiny, tmp_path):
     scripted manager crash forces a journal recovery in the same run —
     every request terminal, the survivor leak-free."""
     cfg, params = tiny
-    procs_ports = [_spawn_server(_serving_dict(), index=i)
+    procs_ports = [_spawn_server(sc_kwargs(cache_dtype="float32"), index=i)
                    for i in range(2)]
     try:
         eps = tuple(f"127.0.0.1:{port}" for _, port in procs_ports)

@@ -57,14 +57,14 @@ import pytest
 
 from flexflow_tpu.models import granite_hybrid as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig
 from flexflow_tpu.serve.engine import InferenceEngine
-from flexflow_tpu.serve.llm import LLM
+
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCAN_LIMIT = 1e-5
 LOGITS_LIMIT = {jnp.float32: 2e-6, jnp.bfloat16: 0.02}
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128
+PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128   # the tiny serving configuration's (conftest.py)
 H, HP, N = 3, 8, 16
 
 
@@ -102,45 +102,30 @@ def _sharp(params):
     return dict(params, attn=dict(attn, wq=attn["wq"] * 25, wk=attn["wk"] * 25))
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, _sharp(fam.init_params(jax.random.PRNGKey(0), cfg))
+def _draw(key, cfg):
+    return _sharp(fam.init_params(key, cfg))
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
-
-
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
+FAMILIES = {"granite_hybrid": Family(fam, ALWAYS | {"ff.mixer"}, draw=_draw)}
 
 
 @pytest.fixture(scope="module")
-def shared(tiny):
-    """One XLA-path server for the tests that need no option of their
-    own (a server is a set of compiled step programs)."""
-    return _server(tiny)
-
-
-@pytest.fixture(scope="module")
-def shared_pallas(tiny):
-    """The same under ``kernels="pallas"``: its C=1 program runs the
-    recurrence kernel, its mixed programs XLA's recurrence."""
-    return _server(tiny, kernels="pallas")
+def tiny(tiny_servers):
+    return tiny_servers.params(fam, draw=_draw)
 
 
 @pytest.fixture
-def engine_of(request):
-    return lambda kernels: request.getfixturevalue(
-        {"xla": "shared", "pallas": "shared_pallas"}[kernels]).engine
+def served(tiny_servers):
+    """kernels -> the file's kept server (a server is a set of compiled
+    step programs): under ``pallas`` its C=1 program runs the recurrence
+    kernel, its mixed programs XLA's recurrence."""
+    return lambda kernels="xla", **kw: tiny_servers(
+        fam, draw=_draw, kernels=kernels, **kw)
+
+
+@pytest.fixture
+def shared(served):
+    return served().llm
 
 
 def _release(eng):
@@ -311,7 +296,7 @@ def wanted(tiny, seqs):
 @pytest.mark.parametrize("kernels, dtype", [
     ("xla", jnp.float32), ("pallas", jnp.float32), ("pallas", jnp.bfloat16)],
     ids=["xla-f32", "pallas-f32", "pallas-bf16"])
-def test_served_logits_match_the_reference(tiny, shared, seqs, wanted, kernels, dtype):
+def test_served_logits_match_the_reference(tiny, served, seqs, wanted, kernels, dtype):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (packed rungs of the
     ladder: the recurrence for the row of one token, the chunk form for
@@ -324,10 +309,9 @@ def test_served_logits_match_the_reference(tiny, shared, seqs, wanted, kernels, 
         params = _sharp(fam.init_params(jax.random.PRNGKey(0), cfg))
         want = reference.forward(params, _file_config(cfg),
                                  np.asarray([seqs[0], seqs[2]]))
-    if (kernels, dtype) == ("xla", jnp.float32):
-        eng = shared.engine
+        eng = served(kernels, cfg=cfg, params=params, cache_dtype=dtype).engine
     else:
-        eng = _server((cfg, params), kernels=kernels, cache_dtype=dtype).engine
+        eng = served(kernels).engine
     assert eng.pack_ladder(CHUNK) == (16, 32)
     assert eng.cache["state"].dtype == jnp.float32 and eng.cache["conv"].dtype == dtype
     assert eng.cache["state"].shape == (3, SLOTS, H, HP, N)
@@ -355,7 +339,7 @@ def _without(name):
 @pytest.mark.parametrize("name", [
     "embedding_multiplier", "residual_multiplier", "attention_multiplier",
     "logits_scaling", "skip", "conv_bias"])
-def test_a_part_left_out_fails_the_comparison(tiny, seqs, wanted, name):
+def test_a_part_left_out_fails_the_comparison(tiny, served, seqs, wanted, name):
     """The four multipliers, the skip ``D xs`` and the convolution's
     bias each move the logits by over five hundred times the float32
     limit: none hides inside it."""
@@ -363,12 +347,12 @@ def test_a_part_left_out_fails_the_comparison(tiny, seqs, wanted, name):
     changes, zeroed = _without(name)
     params = dict(params, ssm={
         k: jnp.zeros_like(v) if k in zeroed else v for k, v in params["ssm"].items()})
-    eng = _server((dataclasses.replace(cfg, **changes), params)).engine
+    eng = served(cfg=dataclasses.replace(cfg, **changes), params=params).engine
     judged = _drive(eng, seqs)
     assert _worst(judged, wanted) > 500 * LOGITS_LIMIT[jnp.float32]
 
 
-def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
+def test_a_packed_rung_is_the_padded_step(tiny, served, monkeypatch):
     """The same mixed steps with and without the packed token axis: the
     logits and both states agree to float32 rounding (matmuls of another
     extent sum in another order)."""
@@ -378,7 +362,7 @@ def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
     out = []
     for packed in (True, False):
         monkeypatch.setattr(fam, "PACKED_STEP", packed)
-        eng = _server(tiny).engine
+        eng = served(fresh=True).engine   # both states are compared whole
         assert bool(eng.pack_ladder(CHUNK)) == packed
         _feed(eng, {1: (seq[1][:CHUNK], 0)}, CHUNK)
         logits = _feed(eng, {1: (seq[1][CHUNK:CHUNK + 1], CHUNK), 3: (seq[3][:11], 0)}, CHUNK)
@@ -419,13 +403,13 @@ def test_greedy_tokens_through_generate_are_the_references(tiny, shared):
 # --- (c) slot reuse and recompute preemption ---------------------------------
 
 
-def test_a_reused_slot_starts_from_zero_state(tiny):
+def test_a_reused_slot_starts_from_zero_state(tiny, served):
     """One slot, two requests one after the other: the second's logits
     are the reference's for it alone, whatever the first left behind."""
     cfg, params = tiny
     rng = np.random.default_rng(5)
     first, second = (rng.integers(0, cfg.vocab_size, n).tolist() for n in (50, 37))
-    used = _server(tiny, max_requests_per_batch=1)
+    used = served(fresh=True, max_requests_per_batch=1).llm
     used.generate([first], max_new_tokens=4)
     for name in ("state", "conv"):
         assert np.abs(np.asarray(used.engine.cache[name])).max() > 0
@@ -441,14 +425,14 @@ def test_a_reused_slot_starts_from_zero_state(tiny):
     assert _rms_share(got, want) < LOGITS_LIMIT[jnp.float32]
 
 
-def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared):
+def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared, served):
     """An oversubscribed pool preempts and re-admits (recompute from
     position 0, which resets the states): no output changes."""
     cfg, _ = tiny
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, cfg.vocab_size, 40 + 8 * i).tolist() for i in range(4)]
     want = [o.output_tokens for o in shared.generate(prompts, max_new_tokens=8)]
-    tight = _server(tiny, max_sequence_length=96, max_cached_tokens=128)
+    tight = served(fresh=True, max_sequence_length=96, max_cached_tokens=128).llm
     outs = tight.generate(prompts, max_new_tokens=8)
     assert [o.output_tokens for o in outs] == want
     assert tight.rm.stats.preemptions > 0, "the pool was never oversubscribed"
@@ -462,9 +446,9 @@ def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared):
 @pytest.mark.parametrize("chunk, kernels", [
     (CHUNK, "xla"), (1, "xla"), (CHUNK, "pallas"), (1, "pallas")],
     ids=["16", "1", "16-pallas", "1-pallas"])
-def test_a_padded_row_keeps_its_states_bitwise(tiny, engine_of, chunk, kernels):
+def test_a_padded_row_keeps_its_states_bitwise(tiny, served, chunk, kernels):
     cfg, _ = tiny
-    eng = engine_of(kernels)
+    eng = served(kernels).engine
     rng = np.random.default_rng(7)
     _feed(eng, {1: (rng.integers(0, cfg.vocab_size, CHUNK).tolist(), 0)}, CHUNK)
     before = (np.asarray(eng.cache["state"])[:, 1], np.asarray(eng.cache["conv"])[:, :, 1])
@@ -477,13 +461,13 @@ def test_a_padded_row_keeps_its_states_bitwise(tiny, engine_of, chunk, kernels):
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
 def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(
-        tiny, engine_of, kernels):
+        tiny, served, kernels):
     """One real position and fifteen padded ones in the C=16 step leave
     what the C=1 step leaves, to float32 rounding (matmuls of another
     extent). Under ``pallas`` that is the mixed step's XLA recurrence
     against the decode step's kernel."""
     cfg, _ = tiny
-    eng = engine_of(kernels)
+    eng = served(kernels).engine
     rng = np.random.default_rng(8)
     prompt = rng.integers(0, cfg.vocab_size, CHUNK + 3).tolist()
     token = [int(rng.integers(0, cfg.vocab_size))]
@@ -547,20 +531,20 @@ def test_only_the_pallas_decode_program_holds_the_recurrence_kernel(tiny):
     ({}, 2, False, "model > 1"),
 ], ids=["prefix_caching", "specinfer", "kv_quant", "fused_decode", "kv_shard",
         "dense", "model"])
-def test_the_seven_refusals_name_their_reason(tiny, serving, model, specinfer, names):
+def test_the_seven_refusals_name_their_reason(tiny, tiny_servers, serving, model, specinfer, names):
     """``validate_serving``, as the engine calls it at construction."""
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=model).make_mesh(jax.devices()[:model])
     with pytest.raises(NotImplementedError, match=f"granite_hybrid does not serve.*{names}"):
-        fam.validate_serving(cfg, _serving(**serving), mesh, specinfer=specinfer)
+        fam.validate_serving(cfg, tiny_servers.serving(**serving), mesh, specinfer=specinfer)
     if not specinfer:  # and the engine does call it
         # (a fused prologue the family does not advertise is refused
         # before the family is asked)
         with pytest.raises((NotImplementedError, ValueError),
                            match="granite_hybrid does not|does not advertise"):
-            InferenceEngine(fam, cfg, params, _serving(**serving), mesh)
+            InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving), mesh)
 
 
 def test_beam_search_is_refused(shared):
@@ -720,7 +704,7 @@ def _published_logits(model, tokens):
         return model(torch.as_tensor(tokens)).logits.numpy()
 
 
-def test_the_reference_is_the_published_code():
+def test_the_reference_is_the_published_code(tiny_servers):
     """The reference's token-by-token forward pass against the
     installed ``GraniteMoeHybridForCausalLM`` (its naive chunked scan at
     a chunk of 8, a ragged 21 tokens): logits, float32."""
@@ -733,7 +717,7 @@ def test_the_reference_is_the_published_code():
     cfg = fam.from_hf(dict(
         keys, vocab_size=128, hidden_size=48, shared_intermediate_size=96,
         max_position_embeddings=512, mamba_d_conv=4), dtype=jnp.float32)
-    eng = _server((cfg, params)).engine
+    eng = tiny_servers(fam, cfg=cfg, params=params).engine
     served = _feed(eng, {0: (tokens[0, :CHUNK].tolist(), 0)}, CHUNK)
     served = _feed(eng, {0: (tokens[0, CHUNK:].tolist(), CHUNK),
                          1: (tokens[1, :CHUNK].tolist(), 0)}, CHUNK)[0]

@@ -27,13 +27,19 @@ import pytest
 
 from flexflow_tpu.models import laguna as fam
 from flexflow_tpu.models import smallthinker, transformer
-from flexflow_tpu.serve import ServingConfig
-from flexflow_tpu.serve.llm import LLM
+
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
 from flexflow_tpu.serve.paging import window_table_pages
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-5
 PAGE, CHUNK, SLOTS, MAX_SEQ = 4, 8, 2, 96
+# the geometry is the test: a window of 8 lines is two pages of 4, its
+# rolling table five, and a context of 45 lines five and a half windows
+GEOMETRY = dict(page_size=PAGE, prefill_chunk=CHUNK, max_requests_per_batch=SLOTS,
+                max_sequence_length=MAX_SEQ)
+# heads by kind, a gate a head, a leading dense layer, a shared expert
+FAMILIES = {"laguna": Family(fam, ALWAYS | {"ff.moe.route"})}
 ROPES = {
     "full_attention": {"rope_theta": 500000, "rope_type": "yarn", "factor": 4,
                        "original_max_position_embeddings": 16, "beta_slow": 1,
@@ -74,32 +80,16 @@ def _file_config(cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _server(tiny, cfg=None, **kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    llm = LLM(fam, cfg or tiny[0], params=tiny[1])
-    llm.compile(ServingConfig(**d))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def servers(tiny):
-    """kernels -> a server, built on first use and kept."""
-    made = {}
-
-    def get(kernels):
-        if kernels not in made:
-            made[kernels] = _server(tiny, kernels=kernels)
-        return made[kernels]
-
-    return get
+@pytest.fixture
+def servers(tiny_servers):
+    """kernels -> the file's kept server on ``GEOMETRY``; ``fresh=True``
+    or a ``cfg`` of the caller's own for one nobody else sees."""
+    return lambda kernels="xla", **kw: tiny_servers(
+        fam, **{**GEOMETRY, "kernels": kernels, **kw}).llm
 
 
 @pytest.fixture(scope="module")
@@ -512,14 +502,14 @@ def test_from_hf_refuses_what_is_not_built(change, names):
     (dict(kv_quant="int8"), "kv_quant"),
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
 ])
-def test_refused_combinations_name_the_family_and_their_reason(tiny, serving, names):
+def test_refused_combinations_name_the_family_and_their_reason(tiny, serving, names, servers):
     with pytest.raises(NotImplementedError, match=f"laguna does not serve {names}"):
-        _server(tiny, **serving)
+        servers(fresh=True, **serving)
 
 
-def test_the_fused_prologue_is_refused(tiny):
+def test_the_fused_prologue_is_refused(tiny, servers):
     with pytest.raises(ValueError, match="FUSED_DECODE"):
-        _server(tiny, kernels="pallas", fused_decode=("rope_kv_write",))
+        servers("pallas", fresh=True, fused_decode=("rope_kv_write",))
 
 
 # --- the reference's own arms --------------------------------------------------

@@ -14,6 +14,7 @@ each fails by orders. TOKENS: greedy tokens through ``RequestManager``
 are the reference's argmax at every position (teacher-forced), and a
 fresh server's exactly.
 """
+import contextlib
 import dataclasses
 import importlib.util
 import os
@@ -25,13 +26,16 @@ import pytest
 
 from flexflow_tpu.models import lfm2_moe as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig
+from flexflow_tpu.obs import sublayers
 from flexflow_tpu.serve.engine import InferenceEngine
 from flexflow_tpu.serve.llm import LLM
 
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-5
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128
+PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128   # the tiny serving configuration's (conftest.py)
+FAMILIES = {"lfm2_moe": Family(fam, ALWAYS | {"ff.mixer", "ff.moe.route"})}
 
 
 def _reference():
@@ -61,31 +65,15 @@ def _file_config(cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
-
-
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def shared(tiny):
-    """One XLA-path server for the tests that need no option of their
-    own (a server is a set of compiled step programs)."""
-    return _server(tiny)
+@pytest.fixture
+def shared(tiny_servers):
+    """The file's kept XLA-path server, for the tests that need no option
+    of their own (a server is a set of compiled step programs)."""
+    return tiny_servers(fam).llm
 
 
 def _release(eng):
@@ -122,14 +110,14 @@ def _rms_share(got, want):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_served_logits_match_the_reference(tiny, shared, kernels):
+def test_served_logits_match_the_reference(tiny, kernels, tiny_servers):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (packed rungs of the
     ladder), then pure decode steps: every row the server would sample
     from, against the reference's full forward pass; the step's expert
     counts are the routed pairs of its real tokens."""
     cfg, params = tiny
-    eng = shared.engine if kernels == "xla" else _server(tiny, kernels=kernels).engine
+    eng = tiny_servers(fam, kernels=kernels).engine
     assert eng.pack_ladder(CHUNK) == (16, 32)
     rng = np.random.default_rng(1)
     seqs = {r: rng.integers(0, cfg.vocab_size, 70).tolist() for r in (0, 2)}
@@ -159,7 +147,7 @@ def test_served_logits_match_the_reference(tiny, shared, kernels):
     assert len(judged) == 3 + 2 * 3 + 2 * 4 and worst < LOGITS_LIMIT, worst
 
 
-def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
+def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch, tiny_servers):
     """The same mixed steps with and without the packed token axis: the
     logits and the conv states agree to float32 rounding (matmuls of
     another extent sum in another order)."""
@@ -169,7 +157,7 @@ def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
     out = []
     for packed in (True, False):
         monkeypatch.setattr(fam, "PACKED_STEP", packed)
-        eng = _server(tiny).engine
+        eng = tiny_servers(fam, fresh=True).engine   # the states are compared whole
         assert bool(eng.pack_ladder(CHUNK)) == packed
         _feed(eng, {1: (seq[1][:CHUNK], 0)}, CHUNK)
         logits = _feed(eng, {1: (seq[1][CHUNK:CHUNK + 1], CHUNK), 3: (seq[3][:11], 0)}, CHUNK)
@@ -250,13 +238,13 @@ def test_chunked_conv_is_the_whole_sequence_convolution(chunk):
 # --- (c) slot reuse and recompute preemption ---------------------------------
 
 
-def test_a_reused_slot_starts_from_zero_state(tiny):
+def test_a_reused_slot_starts_from_zero_state(tiny, tiny_servers):
     """One slot, two requests one after the other: the second's tokens
     are the reference's for it alone, whatever the first left behind."""
     cfg, _ = tiny
     rng = np.random.default_rng(5)
     first, second = (rng.integers(0, cfg.vocab_size, n).tolist() for n in (50, 37))
-    used = _server(tiny, max_requests_per_batch=1)
+    used = tiny_servers(fam, fresh=True, max_requests_per_batch=1).llm
     used.generate([first], max_new_tokens=4)
     assert np.abs(np.asarray(used.engine.cache["conv"])).max() > 0
     again = used.generate([second], max_new_tokens=6)[0].output_tokens
@@ -264,14 +252,14 @@ def test_a_reused_slot_starts_from_zero_state(tiny):
     assert used.rm.stats.state_resets == 2
 
 
-def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared):
+def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared, tiny_servers):
     """An oversubscribed pool preempts and re-admits (recompute from
     position 0, which resets the state): no output changes."""
     cfg, _ = tiny
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, cfg.vocab_size, 40 + 8 * i).tolist() for i in range(4)]
     want = [o.output_tokens for o in shared.generate(prompts, max_new_tokens=8)]
-    tight = _server(tiny, max_sequence_length=96, max_cached_tokens=128)
+    tight = tiny_servers(fam, fresh=True, max_sequence_length=96, max_cached_tokens=128).llm
     outs = tight.generate(prompts, max_new_tokens=8)
     assert [o.output_tokens for o in outs] == want
     assert tight.rm.stats.preemptions > 0, "the pool was never oversubscribed"
@@ -466,23 +454,23 @@ def test_the_reference_bounds_its_routings():
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
     (dict(kv_layout="dense"), "kv_layout"),
 ], ids=lambda v: v if isinstance(v, str) else "")
-def test_refused_combinations_name_their_reason(tiny, serving, names):
+def test_refused_combinations_name_their_reason(tiny, serving, names, tiny_servers):
     cfg, params = tiny
     with pytest.raises((NotImplementedError, ValueError), match=names):
-        InferenceEngine(fam, cfg, params, _serving(**serving))
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving))
 
 
-def test_a_model_parallel_mesh_is_refused(tiny):
+def test_a_model_parallel_mesh_is_refused(tiny, tiny_servers):
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=2).make_mesh(jax.devices()[:2])
     with pytest.raises(NotImplementedError, match="model > 1"):
-        InferenceEngine(fam, cfg, params, _serving(), mesh)
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(), mesh)
 
 
 @pytest.mark.parametrize("draft", ["ssm", "early_exit"])
-def test_speculation_is_refused(tiny, draft):
+def test_speculation_is_refused(tiny, draft, tiny_servers):
     from flexflow_tpu.serve import SpecConfig
     from flexflow_tpu.serve.llm import SSM
 
@@ -491,7 +479,7 @@ def test_speculation_is_refused(tiny, draft):
     ssms = [SSM(fam, cfg, params=params)] if draft == "ssm" else []
     spec = SpecConfig(draft=draft, draft_layers=1) if draft == "early_exit" else None
     with pytest.raises(NotImplementedError, match="SpecInfer"):
-        llm.compile(_serving(), ssms=ssms, spec=spec)
+        llm.compile(tiny_servers.serving(), ssms=ssms, spec=spec)
 
 
 def test_beam_search_is_refused(shared):
@@ -523,3 +511,41 @@ def test_from_hf_reads_the_benchmark_configuration():
     # a smaller depth takes the first entries: both mixers, both FFN kinds
     two = fam.from_hf(hf, num_hidden_layers=2)
     assert two.kinds == (("conv", "dense"), ("attn", "sparse"))
+
+
+# --- the ``ff.*`` scopes are metadata: the same equations in the same order ---
+
+
+def test_the_scopes_change_no_equation(monkeypatch):
+    mod = fam  # conv, attention, dense and routed layers in one step
+    cfg = mod.tiny(dtype=jnp.float32)
+    params = jax.eval_shape(lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: mod.init_paged_kv_cache(
+        cfg, 2 * SLOTS, PAGE, jnp.float32, num_slots=SLOTS))
+
+    def jaxpr(pack):
+        def step(params, cache, tokens, positions, idx, table):
+            return mod.serve_step_paged(
+                params, cache, tokens, positions, idx, None, None, table,
+                cfg=cfg, cache_len=2 * PAGE, kernels="pallas", pack=pack)
+
+        i32 = jnp.int32
+        return jax.make_jaxpr(step)(
+            params, cache, jax.ShapeDtypeStruct((SLOTS, CHUNK), i32),
+            jax.ShapeDtypeStruct((SLOTS, CHUNK), i32),
+            jax.ShapeDtypeStruct((SLOTS,), i32),
+            jax.ShapeDtypeStruct((SLOTS, 2), i32))
+
+    def scopes(j):
+        return {str(e.source_info.name_stack) for e in j.jaxpr.eqns}
+
+    for pack in (None, 2 * CHUNK):
+        with_scopes = jaxpr(pack)
+        assert any("ff.glue" in s for s in scopes(with_scopes))
+        with monkeypatch.context() as m:
+            m.setattr(sublayers, "_named_scope",
+                      lambda name: contextlib.nullcontext())
+            without = jaxpr(pack)
+        assert not any("ff." in s for s in scopes(without))
+        assert str(with_scopes) == str(without)
+        assert len(with_scopes.jaxpr.eqns) == len(without.jaxpr.eqns)

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.models import llama, mistral, transformer
-from flexflow_tpu.serve import InferenceEngine, ServingConfig
+from flexflow_tpu.serve import InferenceEngine
 from flexflow_tpu.serve.engine import program_name
 
 # ---------------------------------------------------------------------------
@@ -81,15 +81,17 @@ R, C, PS = 6, 8, 8           # ladder (12, 24, 48): two packed rungs
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = llama.LLaMAConfig.tiny(dtype=jnp.float32)
-    return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(llama)
 
 
-def _serving(**kw):
-    return ServingConfig(
+@pytest.fixture
+def serving(tiny_servers):
+    """The geometry is the test (the compile counts below name its rungs);
+    each case owns its engine: it counts compiles or spies on a trace."""
+    return lambda **kw: tiny_servers.serving(
         max_requests_per_batch=R, max_sequence_length=56, prefill_chunk=C,
-        max_spec_tree_tokens=8, cache_dtype=jnp.float32, page_size=PS, **kw)
+        max_spec_tree_tokens=8, page_size=PS, **kw)
 
 
 def _mixed_step(eng, feed):
@@ -109,10 +111,10 @@ def _mixed_step(eng, feed):
         np.zeros(R, np.int32))
 
 
-def test_mixed_step_runs_at_a_packed_rung(tiny):
+def test_mixed_step_runs_at_a_packed_rung(tiny, serving):
     """PR 32, PR 45: the family's engine has the ladder and a step of
     few real tokens runs the rung's program, not the padded one."""
-    eng = InferenceEngine(llama, *tiny, _serving(
+    eng = InferenceEngine(llama, *tiny, serving(
         kv_layout="paged", kernels="pallas", sanitizers=("retrace",)))
     assert eng.pack_ladder(C) == (12, 24) and eng.pack_ladder(1) == ()
     ran = []
@@ -130,7 +132,7 @@ def test_mixed_step_runs_at_a_packed_rung(tiny):
     }
 
 
-def test_kernel_call_is_told_the_real_queries(tiny, monkeypatch):
+def test_kernel_call_is_told_the_real_queries(tiny, monkeypatch, serving):
     """PR 30: the ragged paged kernel of the family's step receives
     ``q_len``, the real queries of each row."""
     from flexflow_tpu.serve import kernels
@@ -143,7 +145,7 @@ def test_kernel_call_is_told_the_real_queries(tiny, monkeypatch):
         return real(*args, q_len=q_len, **kw)
 
     monkeypatch.setattr(kernels, "ragged_paged_attention", spy)
-    eng = InferenceEngine(llama, *tiny, _serving(
+    eng = InferenceEngine(llama, *tiny, serving(
         kv_layout="paged", kernels="pallas"))
     eng.pack_ladder = lambda chunk: ()  # the padded program: one trace
     np.asarray(_mixed_step(eng, {0: 8, 2: 1}))
@@ -151,11 +153,11 @@ def test_kernel_call_is_told_the_real_queries(tiny, monkeypatch):
 
 
 @pytest.mark.parametrize("family", [llama, mistral], ids=["llama", "mistral"])
-def test_dense_layout_refuses_pallas(family):
+def test_dense_layout_refuses_pallas(family, serving):
     """The dense layout is the XLA reference layout: asking it for the
     Pallas kernels fails at construction (before the weights are looked
     at), in a sentence, for every family alike."""
     with pytest.raises(ValueError, match="requires kv_layout='paged'.*dense "
                        "layout is the XLA reference layout"):
         InferenceEngine(family, family.tiny(dtype=jnp.float32), None,
-                        _serving(kv_layout="dense", kernels="pallas"))
+                        serving(kv_layout="dense", kernels="pallas"))

@@ -2,7 +2,7 @@
 against its plain reference (benchmarks/references/longcat_flash.py,
 the one copy; imported by path), at a tiny size on the CPU in float32
 with the family's own seeded weights (a non-zero selection offset), a
-float32 latent pool of TWO lines a token and layer, pages of 4.
+float32 latent pool of TWO lines a token and layer, pages of 16 (conftest.py).
 
 Tolerances, each with its reason. LOGITS: rms(served - reference) /
 rms(reference) under 2e-5 a judged row, DeepSeek's tests' limit: sound
@@ -27,13 +27,17 @@ import pytest
 
 from flexflow_tpu.models import deepseek_v3
 from flexflow_tpu.models import longcat_flash as fam
-from flexflow_tpu.serve import ServingConfig
 from flexflow_tpu.serve.engine import InferenceEngine
 from flexflow_tpu.serve.llm import LLM
 
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-5
-PAGE, CHUNK, SLOTS, MAX_SEQ = 4, 16, 4, 128
+PAGE, CHUNK = 16, 16            # the tiny serving configuration's (conftest.py)
+# two attentions and two dense FFNs a layer, the routed block (its
+# identity outputs' part too) on a shortcut across the second pair
+FAMILIES = {"longcat_flash": Family(fam, ALWAYS | {"ff.moe.route"})}
 
 # the catalog's row (model-configs guide, architectures.jsonl), its
 # ``config`` verbatim
@@ -90,42 +94,14 @@ def _file_config(cfg, **kw):
     return d
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _release_compiled_programs():
-    """This file compiles some seven thousand small programs (two
-    servers' ladders, the reference's jits, two eager steps under
-    ``disable_jit``), each a few memory maps of its worker's process,
-    and the driver's six workers each run a sixth of the suite under a
-    limit of 65530 maps a process (a worker that passes it aborts inside
-    a later file's compile): drop them when the file is done."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
-
-
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def shared(tiny):
-    return _server(tiny)
+@pytest.fixture
+def shared(tiny_servers):
+    return tiny_servers(fam).llm
 
 
 def _release(eng):
@@ -162,7 +138,7 @@ def _rms_share(got, want):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_served_logits_match_the_reference(tiny, shared, kernels):
+def test_served_logits_match_the_reference(tiny, tiny_servers, kernels):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (packed rungs of the
     ladder), then pure decode steps, through the latent pool with two
@@ -170,7 +146,7 @@ def test_served_logits_match_the_reference(tiny, shared, kernels):
     reference's full forward pass in the expanded form; the step's
     counters are the pairs of its real tokens."""
     cfg, params = tiny
-    eng = shared.engine if kernels == "xla" else _server(tiny, kernels=kernels).engine
+    eng = tiny_servers(fam, kernels=kernels).engine
     assert eng.pack_ladder(CHUNK) == (16, 32)
     assert eng.cache["latent"].shape[0] == 2 * cfg.num_hidden_layers
     rng = np.random.default_rng(1)
@@ -232,7 +208,7 @@ def test_the_engine_counts_two_lines_a_layer_from_the_family_arrays(shared):
     cfg = eng.cfg
     per_line = 2 * cfg.num_hidden_layers * cfg.line_dim * 4    # float32 pool
     assert eng.kv_bytes_per_line() == per_line
-    assert eng.pager.ensure(0, 6)
+    assert eng.pager.ensure(0, 20)
     assert eng.kv_allocated_bytes() == 2 * PAGE * per_line
     eng.pager.release(0)
     # the published widths in bf16: 2 x 1152 B a token and layer
@@ -260,7 +236,7 @@ def _prefill(cfg, params, tokens, capture=None):
     """One unpacked prefill step of ``tokens`` in row 0, eagerly where
     ``capture`` wants the routed block's operands: (logits (V,), cache)."""
     T = len(tokens)
-    cache = fam.init_paged_kv_cache(cfg, 8, PAGE, jnp.float32)
+    cache = fam.init_paged_kv_cache(cfg, 8, 4, jnp.float32)   # 8 pages of 4 lines
     table = jnp.arange(8, dtype=jnp.int32)[None]
     args = (params, cache, jnp.asarray([tokens], jnp.int32),
             jnp.arange(T, dtype=jnp.int32)[None], jnp.asarray([T - 1]), None,
@@ -552,28 +528,28 @@ def test_a_share_that_is_not_the_count_is_refused():
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
     (dict(kv_layout="dense"), "longcat_flash.*kv_layout"),
 ], ids=lambda v: v if isinstance(v, str) else "")
-def test_refused_combinations_name_their_reason(tiny, serving, names):
+def test_refused_combinations_name_their_reason(tiny, tiny_servers, serving, names):
     cfg, params = tiny
     with pytest.raises((NotImplementedError, ValueError), match=names):
-        InferenceEngine(fam, cfg, params, _serving(**serving))
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving))
 
 
-def test_a_model_parallel_mesh_is_refused(tiny):
+def test_a_model_parallel_mesh_is_refused(tiny, tiny_servers):
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=2).make_mesh(jax.devices()[:2])
     with pytest.raises(NotImplementedError, match="longcat_flash.*model > 1"):
-        InferenceEngine(fam, cfg, params, _serving(), mesh)
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(), mesh)
 
 
-def test_speculation_and_beam_search_are_refused(tiny, shared):
+def test_speculation_and_beam_search_are_refused(tiny, shared, tiny_servers):
     from flexflow_tpu.serve import GenerationConfig, SpecConfig
 
     cfg, params = tiny
     llm = LLM(fam, cfg, params=params)
     with pytest.raises(NotImplementedError, match="longcat_flash.*SpecInfer"):
-        llm.compile(_serving(), spec=SpecConfig(draft="early_exit", draft_layers=1))
+        llm.compile(tiny_servers.serving(), spec=SpecConfig(draft="early_exit", draft_layers=1))
     with pytest.raises(NotImplementedError, match="latent page pool"):
         shared.generate([[1, 2, 3]], GenerationConfig(num_beams=2, max_new_tokens=2))
 

@@ -14,6 +14,7 @@ bfloat16 after every step 1.1e-3 (my CPU readings, PR 29), so each of
 the three fails by a factor of five or more. TOKENS: greedy tokens through ``RequestManager`` equal a fresh
 server's exactly (same program, same arithmetic).
 """
+import dataclasses
 import importlib.util
 import os
 
@@ -23,13 +24,20 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.models import minicpm_sala as fam
-from flexflow_tpu.serve import ServingConfig
 from flexflow_tpu.serve.engine import InferenceEngine
 from flexflow_tpu.serve.llm import LLM
 
+# the cases every family answers, less the trim: no packed step here
+from family_cases import (  # noqa: F401
+    ALWAYS, Family, family_server, pytest_generate_tests, step_texts,
+    test_every_working_operation_has_a_sublayer)
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-4
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 256
+CHUNK = 16                       # the tiny serving configuration's (conftest.py)
+FAMILIES = {"minicpm_sala": Family(
+    fam, ALWAYS | {"ff.mixer", "ff.attn.select"},
+    serving=dict(max_sequence_length=256))}
 
 
 def _reference():
@@ -62,24 +70,21 @@ def _file_config(cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK)
-    d.update(kw)
-    return ServingConfig(**d)
+@pytest.fixture
+def served(tiny_servers):
+    """kernels -> the file's kept server, 256 positions (two rows past
+    ``dense_len``); ``fresh=True`` for a test that reads whole states."""
+    return lambda kernels="xla", **kw: tiny_servers(
+        fam, **{**FAMILIES["minicpm_sala"].serving, "kernels": kernels, **kw})
 
 
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
+def _release(eng):
+    for r in range(eng.num_slots):
+        eng.pager.release(r)
 
 
 def _feed(eng, rows, chunk):
@@ -111,14 +116,14 @@ def _rms_share(got, want):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_served_logits_match_the_reference(tiny, kernels):
+def test_served_logits_match_the_reference(tiny, kernels, served):
     """Chunked prefill of two rows below and above ``dense_len``, mixed
     steps in which row 0 decodes while row 2 prefills, then pure decode
     steps: every row the server would sample from, against the
     reference's full forward pass; and the block choice of each row's
     last position is the reference's."""
     cfg, params = tiny
-    eng = _server(tiny, kernels=kernels).engine
+    eng = served(kernels).engine
     rng = np.random.default_rng(1)
     seqs = {r: rng.integers(0, cfg.vocab_size, 200).tolist() for r in (0, 2)}
     judged = {}
@@ -145,12 +150,13 @@ def test_served_logits_match_the_reference(tiny, kernels):
     want = np.asarray(hidden @ params["lm_head"].astype(jnp.float32))
     worst = max(_rms_share(got, want[r // 2, t]) for (r, t), got in judged.items())
     assert len(judged) > 25 and worst < LOGITS_LIMIT, worst
-    served = np.asarray(eng.cache["chosen"])
+    choice = np.asarray(eng.cache["chosen"])
+    _release(eng)
     for layer in range(cfg.count(fam.SPARSE)):
         for r in (0, 2):
             ref = np.asarray(chosen[layer])[r // 2, done[r] - 1]
             assert ref.sum(-1).tolist() == [cfg.sparse_topk] * cfg.num_key_value_heads
-            assert (served[layer, r][:, :ref.shape[-1]] == ref).all()
+            assert (choice[layer, r][:, :ref.shape[-1]] == ref).all()
 
 
 def _greedy_reference(cfg, params, prompt, n):
@@ -162,17 +168,19 @@ def _greedy_reference(cfg, params, prompt, n):
     return toks[len(prompt):]
 
 
-def test_request_manager_serves_the_reference_greedy_tokens(tiny):
+def test_request_manager_serves_the_reference_greedy_tokens(tiny, served):
     """Through ``LLM.generate`` (submit/step, chunked prefill, the mixed
     and the decode step programs): the tokens are the reference's own
     greedy continuation of a prompt that crosses ``dense_len``."""
     cfg, params = tiny
-    llm = _server(tiny)
+    llm = served().llm
+    before = dataclasses.replace(llm.rm.stats)
     prompt = np.random.default_rng(2).integers(0, cfg.vocab_size, 120).tolist()
     out = llm.generate([prompt], max_new_tokens=5)[0]
     assert out.output_tokens == _greedy_reference(cfg, params, prompt, 5)
     stats = llm.rm.stats
-    assert stats.state_resets == 1 and stats.sparse_rows > 0
+    assert stats.state_resets == before.state_resets + 1
+    assert stats.sparse_rows > before.sparse_rows
     assert stats.real_rows >= stats.sparse_rows
     assert stats.slot_state_bytes == llm.engine.slot_state_bytes() > 0
     assert llm.engine.kv_cache_bytes() > llm.engine.slot_state_bytes()
@@ -214,31 +222,31 @@ def test_chunked_lightning_is_the_recurrence(chunk):
 # --- (c) slot reuse and recompute preemption ---------------------------------
 
 
-def test_a_reused_slot_starts_from_zero_state(tiny):
+def test_a_reused_slot_starts_from_zero_state(tiny, served):
     """One slot, two requests one after the other: the second's tokens
     and final state are those of a fresh server that saw only it."""
     cfg, _ = tiny
     rng = np.random.default_rng(4)
     first, second = (rng.integers(0, cfg.vocab_size, n).tolist() for n in (130, 110))
-    used = _server(tiny, max_requests_per_batch=1)
+    used = served(fresh=True, max_requests_per_batch=1).llm
     used.generate([first], max_new_tokens=4)
     again = used.generate([second], max_new_tokens=6)[0].output_tokens
-    fresh = _server(tiny, max_requests_per_batch=1)
+    fresh = served(fresh=True, max_requests_per_batch=1).llm
     assert again == fresh.generate([second], max_new_tokens=6)[0].output_tokens
     assert used.rm.stats.state_resets == 2
     np.testing.assert_array_equal(np.asarray(used.engine.cache["state"]),
                                   np.asarray(fresh.engine.cache["state"]))
 
 
-def test_a_preempted_request_recomputes_to_the_same_tokens(tiny):
+def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, served):
     """An oversubscribed pool preempts and re-admits (recompute from
     position 0, which resets the state): no output changes."""
     cfg, _ = tiny
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, cfg.vocab_size, 40 + 8 * i).tolist() for i in range(4)]
-    want = [_server(tiny).generate([p], max_new_tokens=8)[0].output_tokens
+    want = [served().llm.generate([p], max_new_tokens=8)[0].output_tokens
             for p in prompts]
-    tight = _server(tiny, max_sequence_length=96, max_cached_tokens=128)
+    tight = served(fresh=True, max_sequence_length=96, max_cached_tokens=128).llm
     outs = tight.generate(prompts, max_new_tokens=8)
     assert [o.output_tokens for o in outs] == want
     assert tight.rm.stats.preemptions > 0, "the pool was never oversubscribed"
@@ -254,20 +262,21 @@ def _slot_state(eng, row):
 
 
 @pytest.mark.parametrize("chunk", [CHUNK, 1])
-def test_a_padded_row_keeps_its_state_bitwise(tiny, chunk):
+def test_a_padded_row_keeps_its_state_bitwise(tiny, chunk, served):
     cfg, _ = tiny
-    eng = _server(tiny).engine
+    eng = served().engine
     rng = np.random.default_rng(6)
     _feed(eng, {1: (rng.integers(0, cfg.vocab_size, CHUNK).tolist(), 0)}, CHUNK)
     before = _slot_state(eng, 1)
     assert np.abs(before["state"]).max() > 0 and np.abs(before["kbar"]).max() > 0
     _feed(eng, {0: (rng.integers(0, cfg.vocab_size, chunk).tolist(), 0)}, chunk)
     after = _slot_state(eng, 1)
+    _release(eng)
     for name in before:
         np.testing.assert_array_equal(before[name], after[name])
 
 
-def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(tiny):
+def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(tiny, served):
     """One real position and fifteen padded ones in the C=16 step leave
     what the C=1 step leaves: the compressed keys bitwise, the state to
     one float32 rounding (the chunked form's one product and the
@@ -278,7 +287,10 @@ def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(tiny):
     token = [int(rng.integers(0, cfg.vocab_size))]
     states = []
     for chunk in (CHUNK, 1):
-        eng = _server(tiny).engine
+        # the slot's arrays are compared whole, and in the pool's default
+        # bfloat16: a float32 pool keeps the one rounding the two forms of
+        # the compressed keys' sum differ by
+        eng = served(fresh=True, cache_dtype=jnp.bfloat16).engine
         _feed(eng, {0: (prompt[:CHUNK], 0)}, CHUNK)
         _feed(eng, {0: (prompt[CHUNK:], CHUNK)}, CHUNK)
         _feed(eng, {0: (token, len(prompt))}, chunk)   # completes a compressed key
@@ -299,23 +311,23 @@ def test_a_decoding_row_in_a_mixed_step_updates_as_the_decode_step_does(tiny):
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
     (dict(kv_layout="dense"), "kv_layout"),
 ], ids=lambda v: v if isinstance(v, str) else "")
-def test_refused_combinations_name_their_reason(tiny, serving, names):
+def test_refused_combinations_name_their_reason(tiny, serving, names, tiny_servers):
     cfg, params = tiny
     with pytest.raises((NotImplementedError, ValueError), match=names):
-        InferenceEngine(fam, cfg, params, _serving(**serving))
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving))
 
 
-def test_a_model_parallel_mesh_is_refused(tiny):
+def test_a_model_parallel_mesh_is_refused(tiny, tiny_servers):
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=2).make_mesh(jax.devices()[:2])
     with pytest.raises(NotImplementedError, match="model > 1"):
-        InferenceEngine(fam, cfg, params, _serving(), mesh)
+        InferenceEngine(fam, cfg, params, tiny_servers.serving(), mesh)
 
 
 @pytest.mark.parametrize("draft", ["ssm", "early_exit"])
-def test_speculation_is_refused(tiny, draft):
+def test_speculation_is_refused(tiny, draft, tiny_servers):
     from flexflow_tpu.serve import SpecConfig
     from flexflow_tpu.serve.llm import SSM
 
@@ -324,13 +336,13 @@ def test_speculation_is_refused(tiny, draft):
     ssms = [SSM(fam, cfg, params=params)] if draft == "ssm" else []
     spec = SpecConfig(draft=draft, draft_layers=1) if draft == "early_exit" else None
     with pytest.raises(NotImplementedError, match="SpecInfer"):
-        llm.compile(_serving(), ssms=ssms, spec=spec)
+        llm.compile(tiny_servers.serving(), ssms=ssms, spec=spec)
 
 
-def test_beam_search_is_refused(tiny):
+def test_beam_search_is_refused(tiny, served):
     from flexflow_tpu.serve import GenerationConfig
 
-    llm = _server(tiny)
+    llm = served().llm
     with pytest.raises(NotImplementedError, match="recurrent state"):
         llm.generate([[1, 2, 3]], GenerationConfig(num_beams=2, max_new_tokens=2))
 

@@ -42,14 +42,15 @@ import pytest
 
 from flexflow_tpu.models import olmo_hybrid as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig
 from flexflow_tpu.serve.engine import InferenceEngine
-from flexflow_tpu.serve.llm import LLM
+
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DELTA_LIMIT = 1e-5
 LOGITS_LIMIT = {jnp.float32: 2e-5, jnp.bfloat16: 0.15}
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128
+PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 128   # the tiny serving configuration's (conftest.py)
+FAMILIES = {"olmo_hybrid": Family(fam, ALWAYS | {"ff.mixer"})}
 
 
 def _reference():
@@ -77,31 +78,15 @@ def _file_config(cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
-
-
-def _server(tiny, **kw):
-    cfg, params = tiny
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(**kw))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def shared(tiny):
-    """One XLA-path server for the tests that need no option of their
-    own (a server is a set of compiled step programs)."""
-    return _server(tiny)
+@pytest.fixture
+def shared(tiny_servers):
+    """The file's kept XLA-path server, for the tests that need no option
+    of their own (a server is a set of compiled step programs)."""
+    return tiny_servers(fam).llm
 
 
 def _release(eng):
@@ -230,7 +215,7 @@ def test_one_token_a_row_is_the_recurrence_and_bfloat16_state_is_not():
 @pytest.mark.parametrize("kernels, dtype", [
     ("xla", jnp.float32), ("pallas", jnp.float32), ("pallas", jnp.bfloat16)],
     ids=["xla-f32", "pallas-f32", "pallas-bf16"])
-def test_served_logits_match_the_reference(tiny, shared, kernels, dtype):
+def test_served_logits_match_the_reference(tiny, kernels, dtype, tiny_servers):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (packed rungs of the
     ladder: the recurrence for the row of one token, the chunk form for
@@ -240,10 +225,10 @@ def test_served_logits_match_the_reference(tiny, shared, kernels, dtype):
     if dtype == jnp.bfloat16:
         cfg = dataclasses.replace(cfg, dtype=dtype)
         params = fam.init_params(jax.random.PRNGKey(0), cfg)
-    if (kernels, dtype) == ("xla", jnp.float32):
-        eng = shared.engine
+        eng = tiny_servers(fam, cfg=cfg, params=params, kernels=kernels,
+                           cache_dtype=dtype).engine
     else:
-        eng = _server((cfg, params), kernels=kernels, cache_dtype=dtype).engine
+        eng = tiny_servers(fam, kernels=kernels).engine
     assert eng.pack_ladder(CHUNK) == (16, 32)
     assert eng.cache["state"].dtype == jnp.float32 and eng.cache["conv"].dtype == dtype
     rng = np.random.default_rng(1)
@@ -270,7 +255,7 @@ def test_served_logits_match_the_reference(tiny, shared, kernels, dtype):
     assert len(judged) == 3 + 2 * 3 + 2 * 4 and worst < LOGITS_LIMIT[dtype], worst
 
 
-def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
+def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch, tiny_servers):
     """The same mixed steps with and without the packed token axis: the
     logits and both states agree to float32 rounding (matmuls of another
     extent sum in another order)."""
@@ -280,7 +265,7 @@ def test_a_packed_rung_is_the_padded_step(tiny, monkeypatch):
     out = []
     for packed in (True, False):
         monkeypatch.setattr(fam, "PACKED_STEP", packed)
-        eng = _server(tiny).engine
+        eng = tiny_servers(fam, fresh=True).engine   # both states are compared whole
         assert bool(eng.pack_ladder(CHUNK)) == packed
         _feed(eng, {1: (seq[1][:CHUNK], 0)}, CHUNK)
         logits = _feed(eng, {1: (seq[1][CHUNK:CHUNK + 1], CHUNK), 3: (seq[3][:11], 0)}, CHUNK)
@@ -320,13 +305,13 @@ def test_greedy_tokens_through_generate_are_the_references(tiny, shared):
 # --- (c) slot reuse and recompute preemption ---------------------------------
 
 
-def test_a_reused_slot_starts_from_zero_state(tiny):
+def test_a_reused_slot_starts_from_zero_state(tiny, tiny_servers):
     """One slot, two requests one after the other: the second's tokens
     are the reference's for it alone, whatever the first left behind."""
     cfg, _ = tiny
     rng = np.random.default_rng(5)
     first, second = (rng.integers(0, cfg.vocab_size, n).tolist() for n in (50, 37))
-    used = _server(tiny, max_requests_per_batch=1)
+    used = tiny_servers(fam, fresh=True, max_requests_per_batch=1).llm
     used.generate([first], max_new_tokens=4)
     for name in ("state", "conv"):
         assert np.abs(np.asarray(used.engine.cache[name])).max() > 0
@@ -335,14 +320,14 @@ def test_a_reused_slot_starts_from_zero_state(tiny):
     assert used.rm.stats.state_resets == 2
 
 
-def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared):
+def test_a_preempted_request_recomputes_to_the_same_tokens(tiny, shared, tiny_servers):
     """An oversubscribed pool preempts and re-admits (recompute from
     position 0, which resets the states): no output changes."""
     cfg, _ = tiny
     rng = np.random.default_rng(6)
     prompts = [rng.integers(0, cfg.vocab_size, 40 + 8 * i).tolist() for i in range(4)]
     want = [o.output_tokens for o in shared.generate(prompts, max_new_tokens=8)]
-    tight = _server(tiny, max_sequence_length=96, max_cached_tokens=128)
+    tight = tiny_servers(fam, fresh=True, max_sequence_length=96, max_cached_tokens=128).llm
     outs = tight.generate(prompts, max_new_tokens=8)
     assert [o.output_tokens for o in outs] == want
     assert tight.rm.stats.preemptions > 0, "the pool was never oversubscribed"
@@ -461,7 +446,7 @@ def test_lane_pack_is_the_least_divisor_that_fills_the_lanes(H, dv, p):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_a_lane_packed_state_serves_what_the_heads_apart_serve(kernels, monkeypatch):
+def test_a_lane_packed_state_serves_what_the_heads_apart_serve(kernels, monkeypatch, tiny_servers):
     """Four recurrent heads of 8 x 64 pack in pairs (``lane_pack``: 128
     lanes a row). The same prefill chunk, mixed step (a decoding row
     beside a prefilling one) and four decode steps with the state
@@ -469,14 +454,14 @@ def test_a_lane_packed_state_serves_what_the_heads_apart_serve(kernels, monkeypa
     the state agree to float32 rounding (the C = 1 rule sums over dk on
     the packed form, the chunk form unpacks one row around itself)."""
     cfg = fam.tiny(dtype=jnp.float32, linear_num_heads=4, linear_value_head_dim=64)
-    tiny = cfg, fam.init_params(jax.random.PRNGKey(3), cfg)
+    params = fam.init_params(jax.random.PRNGKey(3), cfg)
     rng = np.random.default_rng(5)
     seq = {r: rng.integers(0, cfg.vocab_size, 40).tolist() for r in (1, 3)}
     out = []
     for packed in (True, False):
         if not packed:
             monkeypatch.setattr(fam, "lane_pack", lambda H, dv: 1)
-        eng = _server(tiny, kernels=kernels).engine
+        eng = tiny_servers(fam, cfg=cfg, params=params, kernels=kernels).engine
         p = 2 if packed else 1
         assert eng.cache["state"].shape == (4, SLOTS, 4 // p, 8, p * 64)
         logits = [_feed(eng, {1: (seq[1][:CHUNK], 0)}, CHUNK)[[1]],
@@ -505,20 +490,21 @@ def test_a_lane_packed_state_serves_what_the_heads_apart_serve(kernels, monkeypa
     ({}, 2, False, "model > 1"),
 ], ids=["prefix_caching", "specinfer", "kv_quant", "fused_decode", "kv_shard",
         "dense", "model"])
-def test_the_seven_refusals_name_their_reason(tiny, serving, model, specinfer, names):
+def test_the_seven_refusals_name_their_reason(
+        tiny, serving, model, specinfer, names, tiny_servers):
     """``validate_serving``, as the engine calls it at construction."""
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=model).make_mesh(jax.devices()[:model])
     with pytest.raises(NotImplementedError, match=f"olmo_hybrid does not serve.*{names}"):
-        fam.validate_serving(cfg, _serving(**serving), mesh, specinfer=specinfer)
+        fam.validate_serving(cfg, tiny_servers.serving(**serving), mesh, specinfer=specinfer)
     if not specinfer:  # and the engine does call it
         # (a fused prologue the family does not advertise is refused
         # before the family is asked)
         with pytest.raises((NotImplementedError, ValueError),
                            match="olmo_hybrid does not|does not advertise"):
-            InferenceEngine(fam, cfg, params, _serving(**serving), mesh)
+            InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving), mesh)
 
 
 def test_beam_search_is_refused(shared):

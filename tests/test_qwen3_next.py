@@ -40,14 +40,14 @@ import pytest
 from flexflow_tpu.models import olmo_hybrid
 from flexflow_tpu.models import qwen3_next as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig
 from flexflow_tpu.serve.engine import InferenceEngine
-from flexflow_tpu.serve.llm import LLM
+
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DELTA_LIMIT = 1e-5
 LOGITS_LIMIT = 2e-5
-PAGE, CHUNK, SLOTS, MAX_SEQ = 16, 16, 4, 96
+PAGE, CHUNK, SLOTS = 16, 16, 4   # the tiny serving configuration's (conftest.py)
 
 
 def _reference():
@@ -95,20 +95,19 @@ def _off_zero(tree, key):
     return out
 
 
+def _draw(key, cfg):
+    return jax.jit(lambda key: _off_zero(fam.init_params(key, cfg),
+                                         jax.random.fold_in(key, 5)))(key)
+
+
+# routed experts behind a recurrent mixer: both beside attention
+FAMILIES = {"qwen3_next": Family(
+    fam, ALWAYS | {"ff.mixer", "ff.moe.route"}, draw=_draw)}
+
+
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    draw = jax.jit(lambda key: _off_zero(fam.init_params(key, cfg),
-                                         jax.random.fold_in(key, 5)))
-    return cfg, draw(jax.random.PRNGKey(0))
-
-
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam, draw=_draw)
 
 
 def _feed(eng, rows, chunk):
@@ -140,21 +139,18 @@ def _rms_share(got, want):
 
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
-def test_served_logits_match_the_reference(tiny, kernels, monkeypatch):
+def test_served_logits_match_the_reference(tiny, kernels, tiny_servers):
     """Chunked prefill of one row (a ragged last chunk), mixed steps in
     which it decodes while another prefills (the recurrence for the row
     of one token, the chunk form for the other), then pure decode steps
     (under ``pallas`` the state kernel at 2 value heads a key head,
     interpreted): every row the server would sample from, against the
-    reference's full forward pass. ``xla`` runs the packed rungs of the
-    ladder, ``pallas`` the padded step alone (the kernels' calls are
-    the same in both; two programs fewer to compile)."""
+    reference's full forward pass, on the packed rungs of the ladder
+    (the file's kept servers: the Pallas one is the cross-family
+    cases')."""
     cfg, params = tiny
-    monkeypatch.setattr(fam, "PACKED_STEP", kernels == "xla")
-    llm = LLM(fam, cfg, params=params)
-    llm.compile(_serving(kernels=kernels))
-    eng = llm.engine
-    assert bool(eng.pack_ladder(CHUNK)) == (kernels == "xla")
+    eng = tiny_servers(fam, draw=_draw, kernels=kernels).engine
+    assert eng.pack_ladder(CHUNK) == (16, 32)
     assert eng.cache["state"].shape == (3, SLOTS, 4, 16, 16)
     assert eng.cache["state"].dtype == jnp.float32
     assert eng.cache["conv"].shape == (3, 3, SLOTS, 2 * 2 * 16 + 4 * 16)
@@ -455,15 +451,16 @@ def test_a_range_that_is_not_the_count_held_is_refused():
     ({}, 2, False, "model > 1"),
 ], ids=["prefix_caching", "specinfer", "kv_quant", "fused_decode", "kv_shard",
         "dense", "model"])
-def test_the_seven_refusals_name_their_reason(tiny, serving, model, specinfer, names):
+def test_the_seven_refusals_name_their_reason(
+        tiny, serving, model, specinfer, names, tiny_servers):
     """``validate_serving``, as the engine calls it at construction."""
     from flexflow_tpu.core.mesh import MachineSpec
 
     cfg, params = tiny
     mesh = MachineSpec(model=model).make_mesh(jax.devices()[:model])
     with pytest.raises(NotImplementedError, match=f"qwen3_next does not serve.*{names}"):
-        fam.validate_serving(cfg, _serving(**serving), mesh, specinfer=specinfer)
+        fam.validate_serving(cfg, tiny_servers.serving(**serving), mesh, specinfer=specinfer)
     if not specinfer:  # and the engine does call it
         with pytest.raises((NotImplementedError, ValueError),
                            match="qwen3_next does not|does not advertise"):
-            InferenceEngine(fam, cfg, params, _serving(**serving), mesh)
+            InferenceEngine(fam, cfg, params, tiny_servers.serving(**serving), mesh)

@@ -34,7 +34,9 @@ from flexflow_tpu.serve import (
 from flexflow_tpu.serve import engine as engine_mod
 from flexflow_tpu.serve.engine import program_name
 
-R, C, PS = 4, 8, 8           # ladder (8, 16, 32): two packed rungs
+# the geometry is the test's own: prompts of 5 to 17 tokens are one to
+# three chunks of 8, and the ladder is (8, 16, 32), two packed rungs
+R, C, PS = 4, 8, 8
 
 
 def _config(name):
@@ -47,17 +49,21 @@ def _config(name):
 
 @pytest.fixture(scope="module", params=["llama", "mixtral", "lfm2_moe"])
 def model(request):
-    mod, cfg = _config(request.param)
-    return mod, cfg, mod.init_params(jax.random.PRNGKey(0), cfg)
+    return _config(request.param)[0]
 
 
-def _manager(model, slots=R, chunk=C, **kw):
-    mod, cfg, params = model
-    return RequestManager(InferenceEngine(mod, cfg, params, ServingConfig(
-        max_requests_per_batch=slots, max_sequence_length=48,
-        prefill_chunk=chunk, max_spec_tree_tokens=8,
-        cache_dtype=jnp.float32, kv_layout="paged",
-        page_size=PS, kernels="xla", **kw)))
+@pytest.fixture
+def manager(tiny_servers):
+    """``manager(model, ...)``: a scheduler of its own (its counters start
+    at zero) over the file's kept engine of that shape, or with
+    ``fresh=True`` over an engine nobody else has compiled a head into."""
+    def get(model, slots=R, chunk=C, **kw):
+        return RequestManager(tiny_servers(
+            model, max_requests_per_batch=slots, max_sequence_length=48,
+            prefill_chunk=chunk, max_spec_tree_tokens=8, page_size=PS,
+            **kw).engine)
+
+    return get
 
 
 def _prompts(n, vocab=250):
@@ -65,9 +71,15 @@ def _prompts(n, vocab=250):
             for i in range(n)]
 
 
-def _finish(rm, rids):
-    while rm.step():
-        pass
+def _finish(rm, rids, took=None):
+    """Serve to the end; ``took`` gathers the head of every pipelined
+    step on the way."""
+    more = True
+    while more:
+        before = rm.stats.head_steps
+        more = rm.step()
+        if took is not None and rm.stats.head_steps > before:
+            took.add(rm.engine.step_head)
     rm.drain()
     return [list(rm.requests[r].output_tokens) for r in rids]
 
@@ -99,13 +111,13 @@ def _sorted_shapes(text):
                       re.S)
 
 
-def test_a_greedy_server_compiles_the_argmax_head_alone(model):
+def test_a_greedy_server_compiles_the_argmax_head_alone(model, manager):
     """Default ``ServingConfig``, every request greedy: every pipelined
     key is tagged ``("greedy", 0)``, the programs keep the unmarked
     names, and neither the C=1 nor the C=chunk program sorts its
     logits or draws; the full head of the same engine does both (so the
     reading is of the head, not of how it is read)."""
-    rm = _manager(model)
+    rm = manager(model, fresh=True)     # what it has compiled is the reading
     eng = rm.engine
     assert eng.serving.fused_decode == ()
     _finish(rm, [rm.submit(p, max_new_tokens=5) for p in _prompts(6)])
@@ -153,11 +165,14 @@ def _gens(kind, n):
     return [some[i % len(some)] for i in range(n)]
 
 
-def _serve(model, kind, n=7):
-    rm = _manager(model, sanitizers=("retrace",))
+def _serve(manager, model, kind, n=7):
+    """On the file's kept engine of ``model`` (every head's ladder is
+    compiled into it once, the full head's too): the run's scheduler, its
+    outputs and the heads its steps took."""
+    rm, took = manager(model, sanitizers=("retrace",)), set()
     rids = [rm.submit(p, g, max_new_tokens=6)
             for p, g in zip(_prompts(n), _gens(kind, n))]
-    return rm, _finish(rm, rids)
+    return rm, _finish(rm, rids, took), took
 
 
 @pytest.mark.parametrize("kind, head, others", [
@@ -166,15 +181,15 @@ def _serve(model, kind, n=7):
     ("topk", ("topk", 32), {("topk", 8), ("greedy", 0)}),
     ("topp", ("full", 0), {("greedy", 0)}),
 ])
-def test_generations_are_the_full_heads(model, kind, head, others,
+def test_generations_are_the_full_heads(model, manager, kind, head, others,
                                         monkeypatch):
     """More requests than slots, so admissions come in waves and the
     batch's rows change as requests finish: a run takes ``head``, may
     take ``others`` as its rows come and go, and its tokens are those
     of the run that sorts at every step."""
-    rm, outs = _serve(model, kind)
+    rm, outs, took = _serve(manager, model, kind)
     assert all(len(o) == 6 for o in outs)
-    took = _heads(rm.engine)
+    assert took <= _heads(rm.engine)
     assert head in took and took <= {head} | others, took
     assert rm.engine.retrace_guard.retraces == 0
     s = rm.stats
@@ -186,16 +201,16 @@ def test_generations_are_the_full_heads(model, kind, head, others,
 
     monkeypatch.setattr(engine_mod, "choose_sample_mode",
                         lambda *a: ("full", 0))
-    ref, want = _serve(model, kind)
-    assert _heads(ref.engine) == {("full", 0)}
+    ref, want, took = _serve(manager, model, kind)
+    assert took == {("full", 0)}
     assert ref.stats.head_greedy_steps == 0
     assert outs == want
 
 
-def _mixed_run(model, slots=R, chunk=C):
+def _mixed_run(manager, model, slots=R, chunk=C, fresh=False):
     """Greedy requests decode; a top-k request is admitted among them,
     finishes, and the greedy ones decode on; then a second one."""
-    rm = _manager(model, slots, chunk, sanitizers=("retrace",))
+    rm = manager(model, slots, chunk, fresh=fresh, sanitizers=("retrace",))
     guard = rm.engine.retrace_guard
     prompts = _prompts(5)
     rids = [rm.submit(p, max_new_tokens=20) for p in prompts[:3]]
@@ -224,8 +239,8 @@ def _mixed_run(model, slots=R, chunk=C):
     # (32, 48, 96, 192): each head's ladder holds the admission rung
     # (ISSUE 45), and the admitted top-k row's steps run on it
     (12, 16)], ids=["4x8", "12x16-admission"])
-def test_a_mix_that_changes_mid_run(model, monkeypatch, R, C):
-    rm, outs, log = _mixed_run(model, R, C)
+def test_a_mix_that_changes_mid_run(model, manager, monkeypatch, R, C):
+    rm, outs, log = _mixed_run(manager, model, R, C, fresh=True)  # counts compiles
     assert [len(o) for o in outs] == [20, 20, 20, 3, 3]
     heads = [h for h, _ in log]
     # greedy, then the top-k head while the sampling row is there, then
@@ -267,8 +282,8 @@ def test_a_mix_that_changes_mid_run(model, monkeypatch, R, C):
 
     monkeypatch.setattr(engine_mod, "choose_sample_mode",
                         lambda *a: ("full", 0))
-    ref, want, _ = _mixed_run(model, R, C)
-    assert _heads(ref.engine) == {("full", 0)}
+    ref, want, log = _mixed_run(manager, model, R, C)
+    assert {head for head, _ in log} == {("full", 0)}
     assert outs == want
 
 

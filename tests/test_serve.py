@@ -25,17 +25,6 @@ def tiny():
     return cfg, params
 
 
-def ref_greedy(cfg, params, prompt, n_new):
-    """Naive reference decoder: full forward over the growing sequence."""
-    toks = list(prompt)
-    for _ in range(n_new):
-        logits = llama.forward(
-            params, jnp.asarray([toks], dtype=jnp.int32), cfg
-        )
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
 def make_engine(tiny, **kw):
     cfg, params = tiny
     sc = ServingConfig(
@@ -50,7 +39,7 @@ def make_engine(tiny, **kw):
 
 
 class TestIncrementalDecoding:
-    def test_matches_full_forward_greedy(self, tiny):
+    def test_matches_full_forward_greedy(self, tiny, ref_greedy):
         cfg, params = tiny
         eng = make_engine(tiny)
         rm = RequestManager(eng)
@@ -59,7 +48,7 @@ class TestIncrementalDecoding:
         expect = ref_greedy(cfg, params, prompt, 12)
         assert out.output_tokens == expect
 
-    def test_chunked_prefill_matches(self, tiny):
+    def test_chunked_prefill_matches(self, tiny, ref_greedy):
         """Prompt longer than prefill_chunk → multiple prefill steps, same
         output as the reference loop."""
         cfg, params = tiny
@@ -69,7 +58,7 @@ class TestIncrementalDecoding:
         out = rm.generate([prompt], max_new_tokens=8)[0]
         assert out.output_tokens == ref_greedy(cfg, params, prompt, 8)
 
-    def test_continuous_batching_isolation(self, tiny):
+    def test_continuous_batching_isolation(self, tiny, ref_greedy):
         """Multiple concurrent requests produce exactly the single-request
         outputs (slot reuse + shared cache cannot leak across requests)."""
         cfg, params = tiny
@@ -86,7 +75,7 @@ class TestIncrementalDecoding:
         for p, o in zip(prompts, outs):
             assert o.output_tokens == ref_greedy(cfg, params, p, 6), p
 
-    def test_slot_reuse_no_stale_cache(self, tiny):
+    def test_slot_reuse_no_stale_cache(self, tiny, ref_greedy):
         """A request admitted into a previously-used slot must not read the
         old occupant's KV lines."""
         cfg, params = tiny
@@ -99,7 +88,7 @@ class TestIncrementalDecoding:
             cfg, params, [7] * 8, 4
         )
 
-    def test_dispatch_ahead_pipeline_used(self, tiny):
+    def test_dispatch_ahead_pipeline_used(self, tiny, ref_greedy):
         """Steady-state decode must go through the in-flight pipeline
         (no per-token blocking device_get — reference request_manager.cc
         :2310-2325) and still match the reference loop exactly."""
@@ -189,7 +178,7 @@ class TestSampling:
         }
         assert len(seen) > 1
 
-    def test_eos_stops_generation(self, tiny):
+    def test_eos_stops_generation(self, tiny, ref_greedy):
         cfg, params = tiny
         eng = make_engine(tiny)
         # Find what greedy emits first, then declare it EOS.

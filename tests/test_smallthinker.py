@@ -27,13 +27,18 @@ import pytest
 
 from flexflow_tpu.models import smallthinker as fam
 from flexflow_tpu.models import transformer
-from flexflow_tpu.serve import ServingConfig
-from flexflow_tpu.serve.llm import LLM
+
+from family_cases import *  # noqa: F401,F403 (the cases every family answers)
 from flexflow_tpu.serve.paging import window_table_pages
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGITS_LIMIT = 2e-5
 PAGE, CHUNK, SLOTS, MAX_SEQ = 8, 8, 4, 160
+# the geometry is the test: a window of 24 lines is three pages of 8, its
+# rolling table five, and a context of 140 lines five and a half windows
+GEOMETRY = dict(page_size=PAGE, prefill_chunk=CHUNK, max_sequence_length=MAX_SEQ)
+# full and window layers alike, the router at the top of the block
+FAMILIES = {"smallthinker": Family(fam, ALWAYS | {"ff.moe.route"})}
 
 
 def _reference():
@@ -63,42 +68,22 @@ def _file_config(cfg):
 
 
 @pytest.fixture(scope="module")
-def tiny():
-    cfg = fam.tiny(dtype=jnp.float32)
-    return cfg, fam.init_params(jax.random.PRNGKey(0), cfg)
+def tiny(tiny_servers):
+    return tiny_servers.params(fam)
 
 
-def _serving(**kw):
-    d = dict(kv_layout="paged", kernels="xla", page_size=PAGE,
-             max_requests_per_batch=SLOTS, max_sequence_length=MAX_SEQ,
-             prefill_chunk=CHUNK, cache_dtype=jnp.float32)
-    d.update(kw)
-    return ServingConfig(**d)
+@pytest.fixture
+def served(tiny_servers):
+    """kernels -> the file's kept freeing server on ``GEOMETRY`` (the
+    Pallas one in interpret mode); ``fresh=True`` or a ``cfg`` of the
+    caller's own for one nobody else sees."""
+    return lambda kernels="xla", **kw: tiny_servers(
+        fam, **{**GEOMETRY, "kernels": kernels, **kw})
 
 
-def _server(tiny, cfg=None, **kw):
-    llm = LLM(fam, cfg or tiny[0], params=tiny[1])
-    llm.compile(_serving(**kw))
-    return llm
-
-
-@pytest.fixture(scope="module")
-def shared(tiny):
-    return _server(tiny)
-
-
-@pytest.fixture(scope="module")
-def servers(tiny, shared):
-    """kernels -> a freeing server's engine: the XLA one is ``shared``,
-    the Pallas (interpret) one is built on first use and kept."""
-    made = {"xla": shared.engine}
-
-    def get(kernels):
-        if kernels not in made:
-            made[kernels] = _server(tiny, kernels=kernels).engine
-        return made[kernels]
-
-    return get
+@pytest.fixture
+def shared(served):
+    return served().llm
 
 
 @pytest.fixture(scope="module")
@@ -177,7 +162,7 @@ def _walk(eng, seq, slot=1, prefill=134, decode=6, beside=None):
 
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
 def test_served_logits_match_the_reference_several_windows_on(
-        tiny, servers, sequence, kernels):
+        tiny, served, sequence, kernels):
     """Chunked prefill to a context of 134 (five and a half windows of
     24), a second row prefilling beside it from step 5 on, then decode
     steps: every row the server would sample from against the
@@ -186,7 +171,7 @@ def test_served_logits_match_the_reference_several_windows_on(
     table's 5 pages there, and holds ceil(L / 8) in the full class."""
     cfg, _ = tiny
     seq, want = sequence
-    eng = servers(kernels)
+    eng = served(kernels).engine
     win, full = eng.pager.classes[fam.WINDOW], eng.pager.classes[fam.FULL]
     assert win.pages_per_slot == window_table_pages(24, CHUNK, PAGE) == 5
     before = win.trimmed
@@ -203,7 +188,7 @@ def test_served_logits_match_the_reference_several_windows_on(
 
 @pytest.mark.parametrize("kernels", ["pallas", "xla"])
 def test_freeing_nothing_gives_the_same_logits(
-        tiny, servers, sequence, kernels, monkeypatch):
+        tiny, served, sequence, kernels, monkeypatch):
     """The window class told to free nothing (``_keep_every_page``):
     it keeps every page and a whole context's table, and the mask alone
     hides the lines behind the window. The kernel walks a row's pages in order and skips those
@@ -212,9 +197,9 @@ def test_freeing_nothing_gives_the_same_logits(
     sums a softmax over 168 gathered lines where the other sums over
     40: another order of the same sum, under the limit."""
     seq, _ = sequence
-    freeing = servers(kernels)  # built (or kept) with its window declared
+    freeing = served(kernels).engine  # built (or kept) with its window declared
     _keep_every_page(monkeypatch)
-    kept = _server(tiny, kernels=kernels).engine
+    kept = served(kernels, fresh=True).engine
     assert kept.pager.classes[fam.WINDOW].window is None
     assert "window_start" not in kept.pager.tables()
     a = _walk(freeing, seq, beside=(3, seq[70:131]))
@@ -251,7 +236,7 @@ def _greedy(tiny, llm, prompts, new):
     return [o.output_tokens for o in llm.generate(prompts, max_new_tokens=new)]
 
 
-def test_preempted_requests_recompute_to_the_same_tokens(tiny, sequence):
+def test_preempted_requests_recompute_to_the_same_tokens(tiny, sequence, served):
     """Through ``RequestManager``: a pool too small for three long
     requests at once (the full class runs out: the window class never
     does) preempts the newest, which prefills again from its start; the
@@ -262,10 +247,10 @@ def test_preempted_requests_recompute_to_the_same_tokens(tiny, sequence):
     cfg, params = tiny
     seq, _ = sequence
     prompts = [seq[:90], seq[20:120], seq[40:125]]
-    roomy = _server(tiny)
+    roomy = served().llm
     want = _greedy(tiny, roomy, prompts, 6)
     assert roomy.rm.stats.preemptions == 0
-    tight = _server(tiny, max_cached_tokens=(4 * 5 + 30) * PAGE)
+    tight = served(fresh=True, max_cached_tokens=(4 * 5 + 30) * PAGE).llm
     assert tight.engine.pager.classes[fam.FULL].num_pages == 30
     got = _greedy(tiny, tight, prompts, 6)
     assert got == want
@@ -293,7 +278,7 @@ def _normed_router(h, w, k, **kw):
 @pytest.mark.parametrize("change", [
     "window_a_page_longer", "rope_on_the_full_layers", "router_behind_a_norm",
     "router_behind_attention", "silu_for_relu"])
-def test_the_comparison_fails_on_a_changed_layer(tiny, sequence, monkeypatch, change):
+def test_the_comparison_fails_on_a_changed_layer(tiny, sequence, monkeypatch, change, served):
     """The four things the equations fix, each changed in the program:
     the served logits then leave the reference by orders of the limit."""
     cfg, _ = tiny
@@ -309,7 +294,7 @@ def test_the_comparison_fails_on_a_changed_layer(tiny, sequence, monkeypatch, ch
         monkeypatch.setattr(fam.SmallThinkerConfig, "kinds", property(lambda self: kinds))
     else:
         cfg = dataclasses.replace(cfg, activation="silu")
-    eng = _server(tiny, cfg).engine
+    eng = served(cfg=cfg).engine   # a changed program: nobody else's
     got = _walk(eng, seq, prefill=70, decode=2)
     worst = max(_rms_share(logits, want[pos]) for pos, logits in got.items())
     assert worst > 100 * LOGITS_LIMIT, (change, worst)
@@ -441,15 +426,15 @@ def test_the_reference_judges_rows_with_bounded_routings(tiny, sequence):
     (dict(kv_quant="int8"), "kv_quant"),
     (dict(kv_shard="context", context_shards=2), "kv_shard"),
 ])
-def test_refused_combinations_name_their_reason(tiny, serving, names):
+def test_refused_combinations_name_their_reason(tiny, serving, names, served):
     with pytest.raises(NotImplementedError, match=names):
-        _server(tiny, **serving)
+        served(fresh=True, **serving)
 
 
-def test_the_fused_prologue_is_refused(tiny):
+def test_the_fused_prologue_is_refused(tiny, served):
     """The engine refuses it first: the family advertises no fusion."""
     with pytest.raises(ValueError, match="FUSED_DECODE"):
-        _server(tiny, kernels="pallas", fused_decode=("rope_kv_write",))
+        served("pallas", fresh=True, fused_decode=("rope_kv_write",))
 
 
 def test_from_hf_reads_the_benchmark_configuration():

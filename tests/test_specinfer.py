@@ -34,14 +34,6 @@ def tiny_ssm():
     return cfg, params
 
 
-def ref_greedy(cfg, params, prompt, n_new):
-    toks = list(prompt)
-    for _ in range(n_new):
-        logits = llama.forward(params, jnp.asarray([toks], dtype=jnp.int32), cfg)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
 def make_engine(model_params):
     cfg, params = model_params
     sc = ServingConfig(
@@ -103,7 +95,7 @@ class TestTokenTree:
 
 
 class TestSpecInfer:
-    def test_self_speculation_matches_greedy(self, tiny):
+    def test_self_speculation_matches_greedy(self, tiny, ref_greedy):
         """SSM == LLM: every speculated token is accepted; output must be
         identical to incremental greedy and use far fewer LLM steps."""
         cfg, params = tiny
@@ -119,7 +111,7 @@ class TestSpecInfer:
         assert out.profile.llm_decoding_steps < 12
         assert out.profile.accepted_tokens > 0
 
-    def test_weak_draft_still_matches_greedy(self, tiny, tiny_ssm):
+    def test_weak_draft_still_matches_greedy(self, tiny, tiny_ssm, ref_greedy):
         """A different draft model changes only the speed, never the
         output (the defining spec-decoding invariant)."""
         cfg, params = tiny
@@ -131,7 +123,7 @@ class TestSpecInfer:
             out = mgr2.generate([prompt], max_new_tokens=10)[0]
             assert out.output_tokens == ref_greedy(cfg, params, prompt, 10), prompt
 
-    def test_batch_spec_infer(self, tiny, tiny_ssm):
+    def test_batch_spec_infer(self, tiny, tiny_ssm, ref_greedy):
         cfg, params = tiny
         mgr = SpecInferManager(
             make_engine(tiny), make_engine(tiny_ssm),
@@ -153,7 +145,7 @@ class TestSpecInfer:
         spec = mgr.generate([prompt], max_new_tokens=9)[0]
         assert spec.output_tokens == incr.output_tokens
 
-    def test_two_ssm_tree_merge_matches_greedy(self, tiny, tiny_ssm):
+    def test_two_ssm_tree_merge_matches_greedy(self, tiny, tiny_ssm, ref_greedy):
         """Two different drafts' trees merge (reference merge_dfs_trees)
         — output must still be exactly the greedy tokens."""
         cfg, params = tiny
@@ -168,7 +160,7 @@ class TestSpecInfer:
             out = mgr.generate([prompt], max_new_tokens=10)[0]
             assert out.output_tokens == ref_greedy(cfg, params, prompt, 10), prompt
 
-    def test_two_ssm_acceptance_not_degraded(self, tiny):
+    def test_two_ssm_acceptance_not_degraded(self, tiny, ref_greedy):
         """Adding a second (identical) draft must not LOWER acceptance:
         if the multi-SSM commit corrupted the SSM caches, the drafts
         would attend garbage history from round 2 on and acceptance
@@ -187,7 +179,7 @@ class TestSpecInfer:
         assert dual.profile.accepted_tokens >= single.profile.accepted_tokens
         assert dual.profile.llm_decoding_steps <= single.profile.llm_decoding_steps
 
-    def test_two_ssm_through_llm_api(self, tiny, tiny_ssm):
+    def test_two_ssm_through_llm_api(self, tiny, tiny_ssm, ref_greedy):
         """LLM.compile(ssms=[a, b]) no longer rejects multi-SSM."""
         from flexflow_tpu.core.mesh import MachineSpec
         from flexflow_tpu.serve.llm import LLM, SSM

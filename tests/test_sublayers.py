@@ -1,155 +1,20 @@
-"""Device time by sublayer (ISSUE 42): the ``ff.*`` named scopes inside
-the step programs, the parser of a compiled program's ``op_name``s, and
-the scope map the engine gives on demand without tracing, compiling or
-dispatching anything.
-
-Tiny widths on the CPU; the Pallas kernels in interpret mode, so the
-attention and grouped-matmul call sites are the served ones.
+"""Device time by sublayer (ISSUE 42): the vocabulary of ``ff.*`` named
+scopes and the parser of a compiled program's ``op_name``s. What is asked
+of a served family's step programs is in tests/family_cases.py and runs in
+the family's own file; what is asked of a serving engine's map in
+tests/test_decoder_families.py.
 """
-import contextlib
-import re
-
-import jax
-import jax.numpy as jnp
 import pytest
 
-from flexflow_tpu.models import (
-    deepseek_v3,
-    granite_hybrid,
-    laguna,
-    lfm2_moe,
-    llama,
-    longcat_flash,
-    minicpm_sala,
-    mistral,
-    mixtral,
-    olmo_hybrid,
-    qwen3_next,
-    smallthinker,
-)
-from flexflow_tpu.obs import sublayers
 from flexflow_tpu.obs.sublayers import (
     SUBLAYERS,
     parse_scope_map,
-    scope_maps,
     sublayer,
     sublayer_of,
 )
-from flexflow_tpu.serve import InferenceEngine, RequestManager, ServingConfig
-
-CHUNK, PAGE, SLOTS = 16, 16, 4
-ATTENTION = {"ff.attn.proj", "ff.attn.core", "ff.attn.write"}
-ALWAYS = ATTENTION | {"ff.ffn", "ff.head", "ff.glue"}
-# family -> (module, the sublayers its paged step has)
-FAMILIES = {
-    "dense": (mistral, ALWAYS),
-    "llama": (llama, ALWAYS),
-    "routed": (mixtral, ALWAYS | {"ff.moe.route"}),
-    "minicpm_sala": (minicpm_sala, ALWAYS | {"ff.mixer", "ff.attn.select"}),
-    "lfm2_moe": (lfm2_moe, ALWAYS | {"ff.mixer", "ff.moe.route"}),
-    "deepseek_v3": (deepseek_v3, ALWAYS | {"ff.moe.route"}),
-    "olmo_hybrid": (olmo_hybrid, ALWAYS | {"ff.mixer"}),
-    "granite_hybrid": (granite_hybrid, ALWAYS | {"ff.mixer"}),
-    # full and window layers alike, the router at the top of the block
-    "smallthinker": (smallthinker, ALWAYS | {"ff.moe.route"}),
-    # routed experts behind a recurrent mixer: both beside attention
-    "qwen3_next": (qwen3_next, ALWAYS | {"ff.mixer", "ff.moe.route"}),
-    # heads by kind, a gate a head, a leading dense layer, a shared expert
-    "laguna": (laguna, ALWAYS | {"ff.moe.route"}),
-    # two attentions and two dense FFNs a layer, the routed block (its
-    # identity outputs' part too) on a shortcut across the second pair
-    "longcat_flash": (longcat_flash, ALWAYS | {"ff.moe.route"}),
-}
-# the operations that do a step's work: none may lie outside the scopes
-WORK = ("dot", "convolution", "sort", "scatter", "gather", "custom-call")
-_OPCODE = re.compile(
-    r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (?:\([^=]*\)|\S+) ([\w\-]+)\(", re.M)
-
-
-def _serve(mod, *, sanitizers=("retrace",), max_seq=128):
-    """A tiny paged server of family ``mod`` that has run one prompt of
-    two chunks and a few decode steps: its mixed and C=1 programs are
-    compiled."""
-    cfg = mod.tiny(dtype=jnp.float32)
-    params = mod.init_params(jax.random.PRNGKey(0), cfg)
-    eng = InferenceEngine(mod, cfg, params, ServingConfig(
-        kv_layout="paged", kernels="pallas", page_size=PAGE,
-        max_requests_per_batch=SLOTS, max_sequence_length=max_seq,
-        prefill_chunk=CHUNK, cache_dtype=jnp.float32,
-        sanitizers=sanitizers))
-    rm = RequestManager(eng)
-    rid = rm.submit([(7 * i + 3) % 250 for i in range(CHUNK + 2)],
-                    max_new_tokens=4)
-    while not rm.result(rid).profile.finish_time:
-        rm.step()
-    rm.drain()
-    return eng, rm
-
-
-@pytest.fixture(scope="module", autouse=True)
-def _release_compiled_programs():
-    """Twelve families' servers compile thousands of small programs,
-    each a few memory maps of its worker's process, which has 65530 (a
-    worker that passes the limit aborts inside a later file's compile;
-    tests/test_longcat_flash.py has the measurement): drop them when
-    the file is done."""
-    yield
-    jax.clear_caches()
-
-
-@pytest.fixture(scope="module")
-def served():
-    """family -> (engine, manager, {program: HLO text}, {program: map}),
-    each family built on first use and kept for the module."""
-    made = {}
-
-    def get(family):
-        if family not in made:
-            eng, rm = _serve(FAMILIES[family][0])
-            texts = eng.step_program_texts()
-            made[family] = (eng, rm, texts, {
-                name: parse_scope_map(text) for name, text in texts.items()})
-        return made[family]
-
-    return get
-
 
 # ---------------------------------------------------------------------------
-# (a) every working operation of every family's step lies under a scope
-
-
-@pytest.mark.parametrize("step", ["c1", "mixed"])
-@pytest.mark.parametrize("family", list(FAMILIES))
-def test_every_working_operation_has_a_sublayer(served, family, step):
-    _, _, texts, maps = served(family)
-    names = [n for n in texts if n.startswith("ff_step_c")
-             and (n == "ff_step_c1") == (step == "c1")]
-    assert names, sorted(texts)
-    met = set()
-    for name in names:
-        scopes = maps[name]
-        # an instruction the compiler made of others carries no op_name
-        # at all (XLA:CPU's second dot of a three-operand einsum): that is
-        # what step.sub_ms.unscoped is for, and no scope could reach it
-        named = {m.group(1) for m in _OPCODE.finditer(texts[name])
-                 if "op_name=" in texts[name][m.end():].split("\n", 1)[0]}
-        opcodes = dict(_OPCODE.findall(texts[name]))
-        work = {i: op for i, op in opcodes.items()
-                if op in WORK and i in named}
-        assert work, f"{name}: no working operation parsed"
-        bare = {i: op for i, op in work.items() if scopes[i] is None}
-        assert not bare, f"{name}: under no ff.* scope: {bare}"
-        # a matmul is some sublayer's work, never the step's glue
-        glue = {i: op for i, op in work.items()
-                if op in ("dot", "convolution") and scopes[i] == "ff.glue"}
-        assert not glue, f"{name}: matmuls under ff.glue: {glue}"
-        met |= {s for s in scopes.values() if s is not None}
-    assert met == FAMILIES[family][1]
-    assert met <= {"ff." + s for s in SUBLAYERS}
-
-
-# ---------------------------------------------------------------------------
-# (b) the op_name parser and the vocabulary
+# the op_name parser and the vocabulary
 
 
 @pytest.mark.parametrize("op_name, want", [
@@ -236,79 +101,3 @@ def test_parse_instructions():
         "jit(ff_step_c1)/jit(main)/ff.glue/while/body/ff.ffn/add", None)
     assert got["ff_ragged_paged_c1.3"].opcode == "custom-call"
     assert got["while.6"].computation == "main.5" and got["while.6"].root
-
-
-# ---------------------------------------------------------------------------
-# (c) asking for the map traces, compiles and dispatches nothing
-
-
-def test_asking_for_the_map_is_no_retrace(served):
-    eng, rm, _, _ = served("dense")      # sanitizers=("retrace",): strict
-    counts = dict(eng.retrace_guard.compile_counts())
-    before = (rm.stats.compiles, rm.stats.retraces, eng.dispatch_count,
-              len(eng.retrace_guard.events))
-    assert counts and set(counts.values()) == {1}
-    maps = scope_maps([eng])              # raises under the strict sentinel
-    assert eng.retrace_guard.compile_counts() == counts
-    assert (rm.stats.compiles, rm.stats.retraces, eng.dispatch_count,
-            len(eng.retrace_guard.events)) == before
-    for name in ("jit_ff_step_c1", f"jit_ff_step_c{CHUNK}"):
-        assert set(maps[name].values()) - {None} == FAMILIES["dense"][1]
-    # and the server still serves on the programs it had
-    rid = rm.submit(list(range(1, CHUNK + 3)), max_new_tokens=3)
-    while not rm.result(rid).profile.finish_time:
-        rm.step()
-    rm.drain()
-    assert eng.retrace_guard.compile_counts() == counts
-
-
-def test_an_engine_is_not_kept_alive_by_the_registry():
-    import gc
-    import weakref
-
-    eng, _ = _serve(mistral, sanitizers=())
-    assert sublayers.live_engines()[-1] is eng
-    assert "jit_ff_step_c1" in scope_maps()   # the newest engine's stands
-    ref = weakref.ref(eng)
-    del eng, _
-    gc.collect()
-    assert ref() is None and None not in sublayers.live_engines()
-
-
-# ---------------------------------------------------------------------------
-# (d) the scopes are metadata: the same equations in the same order
-
-
-def test_the_scopes_change_no_equation(monkeypatch):
-    mod = lfm2_moe  # conv, attention, dense and routed layers in one step
-    cfg = mod.tiny(dtype=jnp.float32)
-    params = jax.eval_shape(lambda: mod.init_params(jax.random.PRNGKey(0), cfg))
-    cache = jax.eval_shape(lambda: mod.init_paged_kv_cache(
-        cfg, 2 * SLOTS, PAGE, jnp.float32, num_slots=SLOTS))
-
-    def jaxpr(pack):
-        def step(params, cache, tokens, positions, idx, table):
-            return mod.serve_step_paged(
-                params, cache, tokens, positions, idx, None, None, table,
-                cfg=cfg, cache_len=2 * PAGE, kernels="pallas", pack=pack)
-
-        i32 = jnp.int32
-        return jax.make_jaxpr(step)(
-            params, cache, jax.ShapeDtypeStruct((SLOTS, CHUNK), i32),
-            jax.ShapeDtypeStruct((SLOTS, CHUNK), i32),
-            jax.ShapeDtypeStruct((SLOTS,), i32),
-            jax.ShapeDtypeStruct((SLOTS, 2), i32))
-
-    def scopes(j):
-        return {str(e.source_info.name_stack) for e in j.jaxpr.eqns}
-
-    for pack in (None, 2 * CHUNK):
-        with_scopes = jaxpr(pack)
-        assert any("ff.glue" in s for s in scopes(with_scopes))
-        with monkeypatch.context() as m:
-            m.setattr(sublayers, "_named_scope",
-                      lambda name: contextlib.nullcontext())
-            without = jaxpr(pack)
-        assert not any("ff." in s for s in scopes(without))
-        assert str(with_scopes) == str(without)
-        assert len(with_scopes.jaxpr.eqns) == len(without.jaxpr.eqns)

@@ -796,11 +796,6 @@ def _spawn_server(serving_dict, index=0, seed=0):
     return proc, port
 
 
-def _serving_dict(**kw):
-    base = sc_kwargs(cache_dtype="float32", **kw)
-    return base
-
-
 @pytest.mark.slow
 def test_subprocess_server_bitwise_bare_engine(tiny):
     """True multi-process serving: a subprocess replica (its own
@@ -812,7 +807,7 @@ def test_subprocess_server_bitwise_bare_engine(tiny):
         InferenceEngine(llama, cfg, params, ServingConfig(**sc_kwargs()))
     )
     ref = [r.output_tokens for r in rm.generate(PROMPTS, max_new_tokens=8)]
-    proc, port = _spawn_server(_serving_dict())
+    proc, port = _spawn_server(sc_kwargs(cache_dtype="float32"))
     try:
         sc = ServingConfig(**sc_kwargs(
             replicas=1, replica_transport="socket",
@@ -836,7 +831,7 @@ def test_subprocess_server_survives_malformed_frames(tiny):
     """A hostile/corrupt client drops ITS connection; the server keeps
     serving the next one (and a clean transport still works)."""
     cfg, params = tiny
-    proc, port = _spawn_server(_serving_dict())
+    proc, port = _spawn_server(sc_kwargs(cache_dtype="float32"))
     try:
         evil = socket.create_connection(("127.0.0.1", port), timeout=10)
         evil.sendall(b"garbage that is not a frame at all")
