@@ -180,10 +180,12 @@ class SchedulerStats:
     state_resets: int = 0
     sparse_rows: int = 0
     # The ragged paged attention kernel's grid over the pipelined steps
-    # of a paged engine (note_attn_steps), a layer's call: grid steps
-    # (rows x logical pages), those of them that hold a real query and
-    # a key it may see (the kernel computes these and skips the rest),
-    # and those of the live ones on rows that take the narrow body.
+    # of a paged engine (note_attn_steps), a layer's call: the grid
+    # steps it RUNS (its work list, serve/kernels.ragged_work: the live
+    # entries and one step for each row that has none; rows x logical
+    # pages before PR 63), those of them that hold a real query and a
+    # key it may see, and those of the live ones on rows that take the
+    # narrow body.
     attn_steps_grid: int = 0
     attn_steps_live: int = 0
     attn_steps_narrow: int = 0
@@ -317,21 +319,22 @@ class SchedulerStats:
     def note_attn_steps(self, first, count, page_size: int, num_pages: int,
                         narrow: int, window: int = 0) -> None:
         """Count one step's grid of the ragged paged kernel by the
-        kernel's own rule (serve/kernels._build_ragged_paged_kernel)
-        under the causal mask: ``first`` (R,) each row's first
-        position, ``count`` (R,) its real queries (0: a padding row),
-        ``num_pages`` the table's logical pages a row, ``narrow`` the
-        chunk's ``narrow_query_extent``, ``window`` the model's
-        sliding window (0: none). A page is live when some real query
-        of the row may see a key of it: the pages from the first
-        query's oldest visible key to the last query's own. A block
-        choice on top of the causal mask (models/minicpm_sala.py) can
-        only skip more."""
+        kernel's own rule (serve/kernels.live_pages, from which the
+        device makes the call's work list) under the causal mask:
+        ``first`` (R,) each row's first position, ``count`` (R,) its
+        real queries (0: a padding row), ``num_pages`` the table's
+        entries a row, ``narrow`` the chunk's ``narrow_query_extent``,
+        ``window`` the model's sliding window (0: none). An entry is
+        live when some real query of the row may see a key of its page;
+        the grid runs the live entries and one step for each row that
+        has none. A block choice on top of the causal mask
+        (models/minicpm_sala.py) can only skip more."""
+        from .serve.kernels import live_pages  # Pallas: not at import
+
         first, count = np.asarray(first), np.asarray(count)
-        low = np.maximum(first - window + 1, 0) if window else 0
-        high = np.minimum((first + count - 1) // page_size, num_pages - 1)
-        live = np.where(count > 0, high - low // page_size + 1, 0)
-        self.attn_steps_grid += count.size * num_pages
+        _, live = live_pages(first, first + count - 1, page_size, num_pages,
+                             window)
+        self.attn_steps_grid += int(np.maximum(live, 1).sum())
         self.attn_steps_live += int(live.sum())
         self.attn_steps_narrow += int(live[count <= narrow].sum())
 

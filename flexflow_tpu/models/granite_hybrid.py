@@ -565,7 +565,8 @@ def _attn_block(cfg, ctx, stack, index, x, carried):
             k_rows, v_rows, kw = _pallas_pools(kp, vp, None, None, index)
             o = _pk.ragged_paged_attention(
                 q, k_rows, v_rows, ctx["page_table"], ctx["mask"], scale=scale,
-                row_offset=kw["row_offset"], q_len=ctx["q_len"])
+                row_offset=kw["row_offset"], q_len=ctx["q_len"],
+                work=ctx["work"])
         else:
             k_virt, v_virt = (
                 _pk.gather_pages(_layer_of(pool, index), ctx["page_table"])

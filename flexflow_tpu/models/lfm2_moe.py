@@ -478,7 +478,8 @@ def _attn_block(cfg, ctx, stack, index, x, carried):
             k_rows, v_rows, kw = _pallas_pools(kp, vp, None, None, index)
             o = _pk.ragged_paged_attention(
                 q, k_rows, v_rows, ctx["page_table"], ctx["mask"],
-                row_offset=kw["row_offset"], q_len=ctx["q_len"])
+                row_offset=kw["row_offset"], q_len=ctx["q_len"],
+                work=ctx["work"])
         else:
             k_virt, v_virt = (
                 _pk.gather_pages(_layer_of(pool, index), ctx["page_table"])
@@ -555,7 +556,7 @@ def serve_step_paged(
         _no_state_rollback()
     if pack is not None and all_logits:
         raise ValueError("a packed token axis returns one logits row a row")
-    from ..serve.kernels import paged_serve_mask, real_query_lengths
+    from ..serve.kernels import paged_serve_mask, real_query_lengths, step_work
 
     R, C = tokens.shape
     ps = cache["k"].shape[2]
@@ -581,6 +582,8 @@ def serve_step_paged(
         rope=rope, phys=phys, off=off,
         page_table=page_table, kernels=kernels, q_len=q_len, pack=pack_idx,
         mask=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
+        work=(step_work(positions, q_len, ps, page_table.shape[1])
+              if kernels == "pallas" else None),
         row=row, col=col, real=real, place=place,
         fresh=(q_len > 0) & (positions[:, 0] == 0),
     )

@@ -548,7 +548,8 @@ def _sparse_mixer(cfg, p, u, k_pool, v_pool, kbar, chosen_last, layer, ctx):
             k_rows, v_rows, kw = _pallas_pools(k_pool, v_pool, None, None, layer)
             fn = _pk.sparse_paged_attention if group_mask else _pk.ragged_paged_attention
             return fn(q, k_rows, v_rows, ctx["page_table"], mask,
-                      row_offset=kw["row_offset"], q_len=ctx["q_len"])
+                      row_offset=kw["row_offset"], q_len=ctx["q_len"],
+                      work=ctx["work"])
         k_virt = _pk.gather_pages(_layer_of(k_pool, layer), ctx["page_table"])
         v_virt = _pk.gather_pages(_layer_of(v_pool, layer), ctx["page_table"])
         qg = q.reshape(R, C, KV, H // KV, d)
@@ -634,7 +635,7 @@ def serve_step_paged(
     if mask is not None or cache_positions is not None or any(
             v for v in unsupported.values()):
         _no_state_rollback()
-    from ..serve.kernels import paged_serve_mask, real_query_lengths
+    from ..serve.kernels import paged_serve_mask, real_query_lengths, step_work
 
     R, C = tokens.shape
     ps = cache["k"].shape[2]
@@ -655,6 +656,10 @@ def serve_step_paged(
         last_col=jnp.maximum(last - first, 0),
         q_len=q_len,
         causal=paged_serve_mask(None, positions, page_table.shape[1], ps, cache_len),
+        # the causal range for the sparse call too: the pages no query
+        # CHOSE inside it stay the body's guard's
+        work=(step_work(positions, q_len, ps, page_table.shape[1])
+              if kernels == "pallas" else None),
     )
     fresh = real[:, 0] & (first == 0)
     with sublayer("mixer"):  # the lightning layers alone take RoPE

@@ -726,7 +726,8 @@ def _attn_block(cfg, ctx, stack, index, x, carried):
             k_rows, v_rows, kw = _pallas_pools(kp, vp, None, None, index)
             o = _pk.ragged_paged_attention(
                 q, k_rows, v_rows, ctx["page_table"], ctx["mask"],
-                row_offset=kw["row_offset"], q_len=ctx["q_len"])
+                row_offset=kw["row_offset"], q_len=ctx["q_len"],
+                work=ctx["work"])
         else:
             k_virt, v_virt = (
                 _pk.gather_pages(_layer_of(pool, index), ctx["page_table"])
@@ -755,12 +756,13 @@ def step_context(tokens, positions, page_table, page_size, cache_len,
     their weights: ``((tokens, positions) on the step's token axis,
     ctx)``. The token axis is (R, C) itself or, with ``pack``, the
     packed one (1, pack); ``ctx``: each token's page and offset, the
-    table and the mask for the attention call, each row's real tokens
-    ``q_len`` and whether it starts ``fresh`` (its first position is
-    0), and the token axis's geometry (``row`` / ``col`` of each token,
+    table, the mask and the Pallas kernel's work list
+    (serve/kernels.step_work) for the attention calls, each row's real
+    tokens ``q_len`` and whether it starts ``fresh`` (its first position
+    is 0), and the token axis's geometry (``row`` / ``col`` of each token,
     ``place`` of each (row, column)) for :func:`step_rows` and
     ``lfm2_moe.short_conv``."""
-    from ..serve.kernels import paged_serve_mask, real_query_lengths
+    from ..serve.kernels import paged_serve_mask, real_query_lengths, step_work
 
     R, C = tokens.shape
     q_len = real_query_lengths(positions, cache_len)  # real columns lead
@@ -781,6 +783,8 @@ def step_context(tokens, positions, page_table, page_size, cache_len,
         q_len=q_len, pack=pack_idx,
         mask=paged_serve_mask(None, positions, page_table.shape[1],
                               page_size, cache_len),
+        work=(step_work(positions, q_len, page_size, page_table.shape[1])
+              if kernels == "pallas" else None),
         row=row, col=col, place=place,
         fresh=(q_len > 0) & (positions[:, 0] == 0),
     )

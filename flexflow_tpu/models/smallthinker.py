@@ -468,7 +468,7 @@ def attend_class(cfg, ctx, kind, carried, index, q, k, v):
             o = _pk.ragged_paged_attention(
                 _pad_groups(q, KV), k_rows, v_rows, at["table"], at["mask"],
                 row_offset=kw["row_offset"], q_len=ctx["q_len"],
-                tag="_win" if windowed else "")
+                work=at["work"], tag="_win" if windowed else "")
             o = _pad_groups(o, KV, back=H // KV)
         else:
             k_virt, v_virt = (
@@ -550,11 +550,13 @@ def step_context(cache, tokens, positions, page_table, *, window, cache_len,
     positions -> the family's rope table or tables, traced under
     ``ff.attn.proj``. -> (tokens, positions of the token axis, ctx):
     ``ctx[FULL]`` / ``ctx[WINDOW]`` hold a class's table, its mask over
-    its lines from TRUE positions and the physical page of each place;
+    its lines from TRUE positions, the Pallas kernel's work list over
+    its entries (serve/kernels.step_work, by the same window and table
+    start as the mask) and the physical page of each place;
     ``off`` a place's line within its page; ``rope``, ``kernels``,
     ``q_len``, ``pack``, ``real`` (:func:`attend_class` and
     ``routed_experts_ffn`` read them)."""
-    from ..serve.kernels import paged_serve_mask, real_query_lengths
+    from ..serve.kernels import paged_serve_mask, real_query_lengths, step_work
 
     R, C = tokens.shape
     ps = cache["k"].shape[2]
@@ -587,6 +589,9 @@ def step_context(cache, tokens, positions, page_table, *, window, cache_len,
     for kind, k in ((FULL, "k"), (WINDOW, "k_win")):
         ctx[kind] = dict(
             table=tables[kind], mask=masks[kind],
+            work=(step_work(positions, q_len, ps, tables[kind].shape[1],
+                            window if kind == WINDOW else 0, starts[kind])
+                  if kernels == "pallas" else None),
             phys=_class_places(tables[kind], starts[kind], pos, rows, ps,
                                cache_len, cache[k].shape[1] - 1))
     return tok, pos, ctx

@@ -1834,8 +1834,8 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                       phys, off, page_table, kernels: str = "xla",
                       k_scale=None, v_scale=None, qmax=None,
                       *, fused_rope: bool = False, logical=None,
-                      cp_mesh=None, layer=None, q_len=None, pack=None,
-                      routed=None):
+                      cp_mesh=None, layer=None, q_len=None, work=None,
+                      pack=None, routed=None):
     """Paged twin of :func:`serve_block`: scatter new K/V at the
     table-resolved (page, offset); attend over the virtual cache read
     through the table (``jnp.take`` gather, or the fused ragged paged
@@ -1868,7 +1868,8 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
     ``q_len`` (R,): the real queries of each row
     (serve/kernels.real_query_lengths), for the plain Pallas kernel,
     which then computes for those alone; the other paths take every
-    column.
+    column. ``work`` (serve/kernels.step_work): the table entries that
+    kernel's grid runs, made once a step.
 
     ``pack`` (:func:`_pack_tokens`; the unfused paths without a ring):
     ``x``, ``rope``, ``phys`` and ``off`` are on the packed token axis
@@ -1961,7 +1962,7 @@ def serve_block_paged(cfg, p, x, rope, bias, mask, k_pool, v_pool,
                                                    v_scale, layer)
                 attn = _pk.ragged_paged_attention(
                     _spread_queries(q, pack), k_rows, v_rows, page_table,
-                    mask, q_len=q_len, **kw
+                    mask, q_len=q_len, work=work, **kw
                 )
         attn = _gather_attended(attn, pack)
     attn = _project_out(cfg, p, attn)
@@ -2106,16 +2107,22 @@ def serve_step_paged(
             "explicit mask or cache positions, no all_logits, no fused "
             "RoPE prologue, no ring"
         )
-    if cache_positions is None:
-        cache_positions = positions
     # the causal mask built below follows from the positions, and so do
-    # the real queries of a row; an explicit mask (a token tree) already
-    # leaves its padding columns empty, and every column counts
-    q_len = None
+    # the real queries of a row and the pages they may see (the Pallas
+    # kernel's grid; a window's lower edge only where a line's place is
+    # its position); an explicit mask (a token tree) already leaves its
+    # padding columns empty, and every column and table entry counts
+    q_len = work = None
     if mask is None:
-        from ..serve.kernels import real_query_lengths
+        from ..serve.kernels import real_query_lengths, step_work
 
         q_len = real_query_lengths(positions, cache_len)
+        if kernels == "pallas":
+            window = cfg.sliding_window if cache_positions is None else 0
+            work = step_work(positions, q_len, cache["k"].shape[2],
+                             page_table.shape[1], window)
+    if cache_positions is None:
+        cache_positions = positions
     pack_idx, token_axis = None, (tokens, positions)
     if pack is not None:
         packed, pack_idx = _pack_tokens(
@@ -2179,7 +2186,7 @@ def serve_step_paged(
             cfg, p_l, h, rope, bias, mask, kc, vc, phys, off,
             page_table, kernels, ks, vs, qmax,
             fused_rope=fused_rope, logical=logical, cp_mesh=cp_mesh,
-            layer=l, q_len=q_len, pack=pack_idx,
+            layer=l, q_len=q_len, work=work, pack=pack_idx,
             routed=(real, *given) if given else None,
         ), None
 

@@ -13,9 +13,11 @@ the dense layout is the XLA reference layout and has none.
 :func:`ragged_paged_attention` — the paged-KV variant (PAPERS.md,
 arxiv 2604.15464 Ragged Paged Attention): K/V live in a pool of
 fixed-size token pages and the kernel gathers them **through the page
-table** — the grid is (request, logical page) and the K/V BlockSpec
-index maps read the scalar-prefetched table to DMA the right physical
-page, so no (R, S) virtual cache is ever materialised in HBM. One
+table** — the grid is one axis over a list of (request, table entry)
+made on the device (:func:`ragged_work`: the entries a request's real
+queries may see) and the K/V BlockSpec index maps read the
+scalar-prefetched list and table to DMA the right physical page, so no
+(R, S) virtual cache is ever materialised in HBM. One
 kernel serves decode (C=1), chunked prefill and tree verify (C>1, any
 mask) — the single ragged kernel for mixed batches the paper argues
 for. Told each row's real queries (``q_len``,
@@ -182,6 +184,71 @@ def narrow_query_extent(C: int) -> int:
     the rows that take it (``SchedulerStats.note_attn_steps``) reads it
     here too."""
     return 8 if C > 8 else 0
+
+
+def live_pages(first, last, page_size: int, num_pages: int, window: int = 0,
+               start=None):
+    """THE rule of which table entries a row's call of the ragged paged
+    kernel runs, under the causal mask: ``first`` / ``last`` (R,) the
+    positions of each row's first and last real query (``last < first``:
+    the row has none), ``num_pages`` the table's entries a row,
+    ``window`` the layer's sliding window (0: none), ``start`` (R,) the
+    position of the first line of each row's table (a window class's
+    rolling table, ``window_start``; None: the context's first line).
+    A page is live when some real query may see a key of it: the pages
+    from the first query's oldest visible key to the last query's own.
+    -> (the first live entry, the live entries) (R,) each, 0 entries
+    for a row with no real query. NumPy in, NumPy out: the host counts
+    a step's grid by this (``SchedulerStats.note_attn_steps``) and the
+    device makes the call's work list from it (:func:`step_work`), so
+    the two cannot drift. The count needs no ``start``: a table holds
+    every live page of its row (serve/paging.py) wherever it starts."""
+    xp = np if isinstance(first, np.ndarray) else jnp
+    low = xp.maximum(first - window + 1, 0) if window else xp.zeros_like(first)
+    low = low // page_size
+    count = xp.where(last >= first,
+                     xp.minimum(last // page_size - low + 1, num_pages), 0)
+    if start is not None:
+        low = low - start // page_size
+    return xp.clip(low, 0, num_pages - xp.maximum(count, 1)), count
+
+
+def ragged_work(first, count, num_pages: int):
+    """The work of one ragged paged attention call as a list, from each
+    row's first live table entry and its live entries (R,)
+    (:func:`live_pages`): one step for every live entry, rows ascending
+    and a row's entries ascending, and ONE for a row that has none (an
+    idle slot, a padding row: its step writes zeros). -> (steps ()
+    int32, row, entry (R * num_pages + 1,) int32): the list's first
+    ``steps`` places; the rest is not run and names row R, so that a
+    place's neighbours say whether it opens or closes its row. Built as
+    :func:`mla_work` is, from running sums and a comparison."""
+    R = first.shape[0]
+    count = jnp.maximum(count, 1).astype(jnp.int32)
+    ends = jnp.cumsum(count)
+    item = jnp.arange(R * num_pages + 1, dtype=jnp.int32)
+    row = (item[:, None] >= ends[None]).sum(-1).astype(jnp.int32)
+    at = jnp.minimum(row, R - 1)
+    entry = first.astype(jnp.int32)[at] + item - (ends - count)[at]
+    return ends[-1], row, jnp.where(row < R, entry, 0)
+
+
+def step_work(positions: jnp.ndarray, q_len: jnp.ndarray, page_size: int,
+              num_pages: int, window: int = 0, start=None):
+    """:func:`ragged_work` of a step's calls over one class of page,
+    from the step's positions (R, C) and real queries (R,)
+    (:func:`real_query_lengths`): the ``work`` operand of
+    :func:`ragged_paged_attention`, made ONCE a step and class and
+    handed to every layer's call. The first and last real query are the
+    least and greatest real position of a row, whatever their order."""
+    real = (jnp.arange(positions.shape[1], dtype=jnp.int32)[None]
+            < q_len[:, None])
+    top = jnp.iinfo(jnp.int32).max
+    first = jnp.min(jnp.where(real, positions, top), axis=1)
+    last = jnp.max(jnp.where(real, positions, -1), axis=1)
+    return ragged_work(
+        *live_pages(first, last, page_size, num_pages, window, start),
+        num_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -407,8 +474,8 @@ def _build_ragged_paged_kernel(
     axis (a pool of head size under a lane tile, see
     :func:`_ragged_paged_attention`), and the body takes each head's dk
     lanes out after the load. ``head_blocks`` (the plain kernel): the
-    grid has a leading axis over blocks of KV heads, so rows and pages
-    are its axes 1 and 2 (:func:`_ragged_paged_attention`; since
+    grid has a leading axis over blocks of KV heads, so the work list
+    is its axis 1 (:func:`_ragged_paged_attention`; since
     :func:`_vmem_bytes` counts a block as Mosaic tiles it no cell's call
     is split, Olmo's 30 heads are one grid step, and only
     tests/test_olmo_hybrid.py, which lowers the ceiling, takes this
@@ -436,7 +503,7 @@ def _build_ragged_paged_kernel(
     is the result's, once a row at its last page (``_finalize``: (KV,
     G*n, dk) to the call's (n, KV, G, dk))."""
     narrow = narrow_query_extent(C)
-    row_axis, page_axis = (1, 2) if head_blocks else (0, 1)
+    work_axis = 1 if head_blocks else 0
 
     def _query_rows(q):
         # (n, KV, G, dk) -> (KV, G*n, dk): the queries as the rows of a
@@ -536,12 +603,12 @@ def _build_ragged_paged_kernel(
             scr[:, :M] = jnp.full((scr.shape[0], M, scr.shape[2]), fill,
                                   scr.dtype)
 
-    def _finalize(p, out_ref, o_scr, m_scr, l_scr, n=C):
+    def _finalize(closes, out_ref, o_scr, m_scr, l_scr, n=C):
         # the one relayout of a (.., G, dk) value: the result's, once a
-        # row. A query that saw no key (a padding column) comes out
-        # zero; the rows past a narrow body's n never attended and are
-        # zero too
-        @pl.when(p == pl.num_programs(page_axis) - 1)
+        # row, at the grid step that ``closes`` it. A query that saw no
+        # key (a padding column) comes out zero; the rows past a narrow
+        # body's n never attended and are zero too
+        @pl.when(closes)
         def _():
             KV, G, dk = out_ref.shape[2:]
             M = G * n
@@ -591,9 +658,13 @@ def _build_ragged_paged_kernel(
                 pool_out[0, offs[c]] = q[c]
         return new
 
-    def plain_kernel(*refs, q_len_ref=None):
-        # (pt, q, k, v, [ks, vs], mask) -> out; o/m/l scratch
-        i = 1  # refs[0] is the scalar-prefetched page table
+    def plain_kernel(row_ref, *refs, q_len_ref=None):
+        # (q, k, v, [ks, vs], mask) -> out; o/m/l scratch. ``row_ref``
+        # is the work list's rows (:func:`ragged_work`): this grid step
+        # opens its row where the place before it names another row
+        # and closes it where the place after it does (the list ends in
+        # a place that names no row)
+        i = 0
         q_ref = refs[i]; i += 1         # (1, C, KV, G, dk)
         k_ref = refs[i]; i += 1         # (1, ps, KV, dk) via index map
         v_ref = refs[i]; i += 1
@@ -605,13 +676,16 @@ def _build_ragged_paged_kernel(
         o_scr, m_scr, l_scr, q_scr = refs[i:i + 4]
         G = q_ref.shape[3]
 
-        p = pl.program_id(page_axis)
+        at = pl.program_id(work_axis)
+        row = row_ref[at]
+        opens = (at == 0) | (row_ref[jnp.maximum(at - 1, 0)] != row)
+        closes = row_ref[at + 1] != row
 
         def step(n, q_len=None):
             # this grid step over the row's first n queries: n is a
             # leading extent of the query, mask and output blocks, and
             # n*G one of the state's rows, so taking them is free
-            @pl.when(p == 0)
+            @pl.when(opens)
             def _():
                 _init(o_scr, m_scr, l_scr, n)
                 q_scr[:, :n * G] = _query_rows(q_ref[0, :n]).astype(q_scr.dtype)
@@ -637,12 +711,12 @@ def _build_ragged_paged_kernel(
                 _attend(q, k, v, ks, vs, bias if group_mask else bias[0],
                         o_scr, m_scr, l_scr)
 
-            _finalize(p, out_ref, o_scr, m_scr, l_scr, n)
+            _finalize(closes, out_ref, o_scr, m_scr, l_scr, n)
 
         if q_len_ref is None:
             step(C)
         else:
-            q_len = q_len_ref[pl.program_id(row_axis)]
+            q_len = q_len_ref[row]
             if not narrow:
                 step(C, q_len)
             else:
@@ -738,7 +812,7 @@ def _build_ragged_paged_kernel(
             _attend(q, k, v, ks_att, vs_att,
                     _score_bias(mask, q_ref.shape[3]), o_scr, m_scr, l_scr)
 
-        _finalize(p, out_ref, o_scr, m_scr, l_scr)
+        _finalize(p == pl.num_programs(1) - 1, out_ref, o_scr, m_scr, l_scr)
 
     return fused_kernel if fused else plain_kernel
 
@@ -831,6 +905,7 @@ def ragged_paged_attention(
     v_scale: Optional[jnp.ndarray] = None,
     row_offset=None,          # int32 scalar: pool row of table entry 0
     q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
+    work=None,                # :func:`step_work`: the entries the call runs
     tag: str = "",            # a suffix of the kernel's name in a trace
 ) -> jnp.ndarray:
     """:func:`_ragged_paged_attention` placed on the ambient mesh. The
@@ -866,6 +941,10 @@ def ragged_paged_attention(
         optional.append("q_len")
         operands.append(q_len)
         in_specs.append(P())
+    if work is not None:
+        optional.append("work")
+        operands.append(tuple(work))
+        in_specs.append((P(),) * 3)
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else mesh.shape.get(MODEL_AXIS, 1)
     # (a pool with merged heads, rank 3, is served on one shard: its
@@ -890,14 +969,27 @@ def _ragged_paged_attention(
     row_offset=None,          # int32 scalar: pool row of table entry 0
     group_mask: bool = False,  # mask is (R, KV, C, NP*ps): one a KV group
     q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
+    work=None,                # :func:`step_work`: the entries the call runs
     tag: str = "",
 ) -> jnp.ndarray:
-    """Fused ragged paged attention: grid (request, logical page); the
-    K/V BlockSpec index maps read the scalar-prefetched page table so
-    each step DMAs exactly the physical page that logical position maps
-    to — gathering through the table without materialising the
-    (R, S) virtual cache. One kernel covers decode (C=1), chunked
-    prefill and tree verify (the explicit-mask modes). With
+    """Fused ragged paged attention: ONE grid axis over the call's
+    work, a list of (row, table entry) that the caller's program makes
+    on the device from the rows' positions (``work``,
+    :func:`step_work`: the entries a row's real queries may see, in
+    order, and one for a row that has none, which writes zeros). The
+    grid's length is the list's, a value of the step and not of its
+    shapes, so a call pays for the pages it attends and not for the
+    table's width: a grid step that computes nothing costs 0.30 us, and
+    over a (row, entry) grid 45-73% of every cell's steps were such
+    (Mistral's decode call: 256 steps for some 45 pages; PERF.md
+    section 6, PR 63). ``None`` (an explicit mask: a token tree): every
+    entry of the table is on the list and the body's guard skips what
+    no query sees, the same grid form. The K/V BlockSpec index maps
+    read the scalar-prefetched list and page table so each step DMAs
+    exactly the physical page that entry maps to — gathering through
+    the table without materialising the (R, S) virtual cache. One
+    kernel covers decode (C=1), chunked prefill and tree verify (the
+    explicit-mask modes). With
     ``k_scale``/``v_scale`` the pools hold quantized codes (int8, or
     packed int4 nibbles when the pool's trailing dim is dk/2) and the
     same index maps additionally DMA each page's per-KV-head scales;
@@ -963,11 +1055,11 @@ def _ragged_paged_attention(
     # a mask a KV group, (R, KV, C, S), as (R, KV*C, S)
     operands.append(mask.reshape(R, KV * C if group_mask else C, -1))
     blocks = functools.partial(
-        _ragged_blocks, operands, page_table.shape[1], quant=quant,
+        _ragged_blocks, operands, quant=quant,
         has_offset=row_offset is not None)
 
     def vmem(KVb, need=_ragged_vmem_limit):
-        _, in_specs, out_spec, scratch, out_shape = blocks(KVb)
+        in_specs, out_spec, scratch, out_shape = blocks(KVb)
         return need(in_specs + [out_spec], operands + [out_shape], scratch,
                     C, KVb * G, max(dk, ps))
 
@@ -988,34 +1080,43 @@ def _ragged_paged_attention(
         float(scale), group_mask, KVb, vmem(KVb), _interpret())
     if row_offset is not None:  # one trace, a Python int or a traced scalar
         row_offset = jnp.asarray(row_offset, jnp.int32)
-    return call(page_table, row_offset, q_len, *operands).reshape(R, C, H, dk)
+    if work is None:  # every entry of every row
+        NP = page_table.shape[1]
+        work = ragged_work(jnp.zeros((R,), jnp.int32),
+                           jnp.full((R,), NP, jnp.int32), NP)
+    return call(tuple(work), page_table, row_offset, q_len,
+                *operands).reshape(R, C, H, dk)
 
 
-def _ragged_blocks(operands, NP: int, KVb: int, *, quant: bool,
-                   has_offset: bool):
-    """(grid, in_specs, out_spec, scratch, out_shape) of the ragged
-    paged call over ``operands`` (q (R, C, KV, G, dk), the pools, a
-    quantized pool's scales, the mask) with ``KVb`` KV heads a grid
-    step: all of them on the (row, page) grid, or a block of them under
-    a leading grid axis over the blocks (merged pools: a block's lanes
-    of a line are a block of the minor axis)."""
+def _ragged_blocks(operands, KVb: int, *, quant: bool, has_offset: bool):
+    """(in_specs, out_spec, scratch, out_shape) of the ragged paged
+    call over ``operands`` (q (R, C, KV, G, dk), the pools, a quantized
+    pool's scales, the mask) with ``KVb`` KV heads a grid step: all of
+    them on the grid's one axis, the work list's (:func:`ragged_work`:
+    the index maps read place i's row and table entry from the first
+    two prefetched scalars), or a block of them under a leading grid
+    axis over the blocks (merged pools: a block's lanes of a line are a
+    block of the minor axis)."""
     qg, k_pool, mask = operands[0], operands[1], operands[-1]
     R, C, KV, G, dk = qg.shape
     merged = k_pool.ndim == 3
-    ps, rows = k_pool.shape[1], mask.shape[1]
+    ps = k_pool.shape[1]
     split = KVb < KV
 
-    def at(index_map):  # (head block, row, page, *prefetch) -> block
+    def at(index_map):  # (head block, place, *prefetch) -> block
         if split:
             return index_map
-        return lambda r, p, *pre: index_map(0, r, p, *pre)
+        return lambda i, *pre: index_map(0, i, *pre)
 
-    def page(b, r, p, pt, *base):
-        # the paged gather: block row = page_table[r, p] (+ row_offset)
-        row = pt[r, p] + base[0][0] if has_offset else pt[r, p]
-        return (row, 0, b) if merged else (row,) + (0,) * (k_pool.ndim - 1)
+    def page(b, i, row, entry, pt, *base):
+        # the paged gather: block = page_table[row, entry] (+ row_offset)
+        at_pool = pt[row[i], entry[i]]
+        if has_offset:
+            at_pool = at_pool + base[0][0]
+        return ((at_pool, 0, b) if merged
+                else (at_pool,) + (0,) * (k_pool.ndim - 1))
 
-    heads = at(lambda b, r, p, *_: (r, 0, b, 0, 0))
+    heads = at(lambda b, i, row, *_: (row[i], 0, b, 0, 0))
     lines = (1, ps, KVb * dk) if merged else (1,) + k_pool.shape[1:]
     in_specs = [
         pl.BlockSpec((1, C, KVb, G, dk), heads),
@@ -1025,19 +1126,18 @@ def _ragged_blocks(operands, NP: int, KVb: int, *, quant: bool,
     if quant:
         in_specs += [pl.BlockSpec((1, KV, 1, 1), at(page))] * 2
     in_specs.append(pl.BlockSpec(
-        (1, rows, ps), at(lambda b, r, p, *_: (r, 0, p))))
+        (1, mask.shape[1], ps),
+        at(lambda b, i, row, entry, *_: (row[i], 0, entry[i]))))
     out_spec = pl.BlockSpec((1, C, KVb, G, dk), heads)
     scratch = _ragged_scratch(C, KVb, G, qg, k_pool)
-    grid = (KV // KVb, R, NP) if split else (R, NP)
-    return (grid, in_specs, out_spec, scratch,
-            jax.ShapeDtypeStruct(qg.shape, qg.dtype))
+    return in_specs, out_spec, scratch, jax.ShapeDtypeStruct(qg.shape, qg.dtype)
 
 
 @functools.lru_cache(maxsize=None)
 def _ragged_call(name: str, scale: float, group_mask: bool, KVb: int,
                  vmem_limit: int, interpret: bool):
-    """-> the jitted ``call(page_table, row_offset, q_len, q, k_pool,
-    v_pool, [k_scale, v_scale,] mask)`` of the ragged paged kernel
+    """-> the jitted ``call(work, page_table, row_offset, q_len, q,
+    k_pool, v_pool, [k_scale, v_scale,] mask)`` of the ragged paged kernel
     ``name`` (:func:`_ragged_paged_attention`, which brings the
     operands to the call's form): ONE jitted function a static shape, so
     every call site of a step program (an unrolled family's three or
@@ -1059,25 +1159,26 @@ def _ragged_call(name: str, scale: float, group_mask: bool, KVb: int,
 
 
 def _ragged(name, scale, group_mask, KVb, vmem_limit, interpret,
-            page_table, row_offset, q_len, *operands):
+            work, page_table, row_offset, q_len, *operands):
     qg, k_pool, _, *scales, _ = operands
     C, KV, dk = qg.shape[1], qg.shape[2], qg.shape[-1]
     merged, quant = k_pool.ndim == 3, bool(scales)
-    prefetch = [page_table.astype(jnp.int32)]
+    steps, *places = work
+    prefetch = [*places, page_table.astype(jnp.int32)]
     if row_offset is not None:
         prefetch.append(row_offset.reshape(1))
     if q_len is not None:  # last, so the index maps' ``base`` stays put
         prefetch.append(q_len.astype(jnp.int32))
-    grid, in_specs, out_spec, scratch, out_shape = _ragged_blocks(
-        operands, page_table.shape[1], KVb, quant=quant,
-        has_offset=row_offset is not None)
+    in_specs, out_spec, scratch, out_shape = _ragged_blocks(
+        operands, KVb, quant=quant, has_offset=row_offset is not None)
+    grid = (KV // KVb, steps) if KVb < KV else (steps,)
     body = _build_ragged_paged_kernel(
         quant=quant, fused=False, C=C, scale=scale,
         pack=dk // k_pool.shape[-1] if quant else 1, group_mask=group_mask,
         merged_heads=KVb if merged else 0, head_blocks=KVb < KV,
     )
 
-    def kernel(*refs):  # the body knows the table and the query lengths
+    def kernel(*refs):  # the body knows the list's rows and the query lengths
         body(refs[0], *refs[len(prefetch):],
              q_len_ref=None if q_len is None else refs[len(prefetch) - 1])
 
@@ -1091,7 +1192,9 @@ def _ragged(name, scale, group_mask, KVb, vmem_limit, interpret,
             out_specs=out_spec,
             scratch_shapes=scratch,
         ),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * len(grid),
+            vmem_limit_bytes=vmem_limit),
         name=name,
         interpret=interpret,
     )(*prefetch, *operands)
@@ -1106,6 +1209,7 @@ def sparse_paged_attention(
     *,
     row_offset=None,
     q_len: Optional[jnp.ndarray] = None,  # (R,) int32 real queries a row
+    work=None,                # :func:`step_work` under the causal mask
 ) -> jnp.ndarray:
     """Block-sparse paged attention (``ff_sparse_paged_c<C>``): the
     ragged paged kernel with one mask a KV group, for layers whose
@@ -1120,7 +1224,7 @@ def sparse_paged_attention(
     over a ``model`` axis."""
     return _ragged_paged_attention(
         q, k_pool, v_pool, page_table, mask, row_offset=row_offset,
-        group_mask=True, q_len=q_len,
+        group_mask=True, q_len=q_len, work=work,
     )
 
 

@@ -11,8 +11,11 @@ row's live logical pages name pages of the pool and every other entry
 names the scratch page (``serve/paging.PageAllocator``), whose block is
 fetched once and not again, so a grid step no query sees costs what it
 costs in the cell and not a page's read; an idle slot's row is all
-scratch. The pool is two layers' pages read through ``row_offset``, as
-the layer loop reads its carried pool. (PR 55's first cases drew a page
+scratch. The call is handed the rows' work list as a step makes it
+(``kernels.step_work``, from the positions; a tree from before PR 63
+has none and walks the table's whole width). The pool is two layers'
+pages read through ``row_offset``, as the layer loop reads its carried
+pool. (PR 55's first cases drew a page
 for every entry and one prefilling row, and promised +18% where the
 served call read -24%: PERF.md section 6.)
 
@@ -96,15 +99,32 @@ def _cases(tiny: bool):
     c["sala.sparse"] = dict(
         R=4, C=128, H=32, KV=2, dk=128, NP=146, group_mask=True, keep=0.4,
         rows=[(4096, 128), (8320, 128), (12544, 126), (17000, 1)])
+    # mistral-7b.decode-closed (ledger, PR 62: prompts 64-128, answers
+    # 384-640, 16 slots all decoding, a table of 16 entries for
+    # max_sequence_length 2048): 16 rows at 70-700 lines, 50 pages of
+    # the table's 256 entries
+    c["mistral.decode"] = dict(
+        R=16, C=1, H=32, KV=8, dk=128, NP=16,
+        rows=[(n, 1) for n in (70, 101, 133, 166, 198, 231, 263, 296, 330,
+                               365, 402, 441, 483, 530, 590, 700)])
+    # laguna-xs.2.agent12k-closed, a full layer's call (PERF.md section
+    # 5, PR 58: 13 rows decoding at 12.5 k beside 3 prefilling prompts
+    # of 8-16 k; 48 query heads over 8, each group of 6 padded to 8 as
+    # ``smallthinker._pad_groups`` hands them over)
+    c["laguna.full"] = dict(
+        R=16, C=128, H=64, KV=8, dk=128, NP=133,
+        rows=[(2048, 128), (6144, 128), (11008, 128)]
+        + [(12000 + 80 * i, 1) for i in range(13)])
     if tiny:  # the same code at a size the interpreter finishes
         for case in c.values():
             case.update(R=2, H=case["H"] // case["KV"] * 2, KV=2, NP=4,
-                        rows=[(128, 128), (300, 1)], window=256 * bool(
+                        rows=[(128, case["C"]), (300, 1)], window=256 * bool(
                             case.get("window")))
     return c
 
 
-def _operands(case, part, rng, np, jnp, K):
+def _operands(case, part, rng, np, jax, K):
+    jnp = jax.numpy
     R, C, H, KV, dk, NP = (case[k] for k in ("R", "C", "H", "KV", "dk", "NP"))
     keep_row = {"": lambda n: True, "wide": lambda n: n > 1,
                 "narrow": lambda n: n == 1, "idle": lambda n: False,
@@ -146,8 +166,12 @@ def _operands(case, part, rng, np, jnp, K):
     pools = [jnp.asarray(rng.normal(size=(layers * (P + 1), PAGE, KV, dk)),
                          jnp.bfloat16) for _ in range(2)]
     q_len = jnp.asarray([n for _, n in rows], jnp.int32)
+    work = None
+    if hasattr(K, "step_work"):  # the step's list, made once a step
+        work = jax.jit(lambda: K.step_work(
+            pos, q_len, PAGE, NP, window, jnp.asarray(start, jnp.int32)))()
     return (q, *pools, jnp.asarray(table), mask, q_len,
-            jnp.int32(layer * (P + 1)))
+            jnp.int32(layer * (P + 1)), work)
 
 
 #: an operation of a bundle -> the unit whose slot it takes
@@ -181,11 +205,13 @@ def _bundles(args) -> None:
                   (pool, jnp.bfloat16), ((R, NP), jnp.int32),
                   ((R, KV, C, NP * PAGE) if gm else (R, C, NP * PAGE), jnp.bool_),
                   ((R,), jnp.int32), ((), jnp.int32)]
+        if hasattr(K, "step_work"):
+            shapes += [((), jnp.int32)] + [((R * NP + 1,), jnp.int32)] * 2
 
-        def one(q, kp, vp, table, mask, q_len, offset):
+        def one(q, kp, vp, table, mask, q_len, offset, *work):
             return K._ragged_paged_attention(
                 q, kp, vp, table, mask, q_len=q_len, group_mask=gm, tag=tag,
-                row_offset=offset)
+                row_offset=offset, **(dict(work=work) if work else {}))
 
         jax.jit(one).lower(*[jax.ShapeDtypeStruct(s, d, sharding=chip)
                              for s, d in shapes]).compile()
@@ -264,12 +290,12 @@ def main() -> None:
         name, _, part = want.partition(":")
         case = cases[name]
         gm, tag = case.get("group_mask", False), case.get("tag", "")
-        ops = _operands(case, part, np.random.default_rng(55), np, jnp, K)
+        ops = _operands(case, part, np.random.default_rng(55), np, jax, K)
 
-        def one(q, kp, vp, table, mask, q_len, offset):
+        def one(q, kp, vp, table, mask, q_len, offset, work):
             return K._ragged_paged_attention(
                 q, kp, vp, table, mask, q_len=q_len, group_mask=gm, tag=tag,
-                row_offset=offset)
+                row_offset=offset, **({} if work is None else dict(work=work)))
 
         def loop(q, *rest):
             def body(_, q):  # chained through one element: no pass over q
@@ -277,21 +303,27 @@ def main() -> None:
                 return q.at[0, 0, 0, :].add((o[0, 0, 0, :] * 1e-6).astype(q.dtype))
             return jax.lax.fori_loop(0, args.loop, body, q)
 
-        q, kp, vp, table, mask, q_len, offset = ops
+        q, kp, vp, table, mask, q_len, offset, work = ops
         f32 = lambda x: x.astype(jnp.float32)
         layer = lambda x: f32(jax.lax.dynamic_slice_in_dim(
             x, offset, x.shape[0] // 2))
         R, C, H, dk = q.shape
         KV = case["KV"]
-        if gm:
-            ref = jnp.stack([
+        k32, v32 = layer(kp), layer(vp)
+
+        @jax.jit
+        def ref_row(q, k32, v32, table, mask):  # a row at a time: (C, H, S) scores
+            if not gm:
+                return K.ragged_paged_attention_xla(f32(q), k32, v32, table, mask)
+            return jnp.stack([
                 K.ragged_paged_attention_xla(
-                    f32(q), layer(kp), layer(vp), table, mask[:, g]
-                ).reshape(R, C, KV, H // KV, dk)[:, :, g]
-                for g in range(KV)], axis=2).reshape(R, C, H, dk)
-        else:
-            ref = K.ragged_paged_attention_xla(
-                f32(q), layer(kp), layer(vp), table, mask)
+                    f32(q), k32, v32, table, mask[:, g]
+                ).reshape(1, C, KV, H // KV, dk)[:, :, g]
+                for g in range(KV)], axis=2).reshape(1, C, H, dk)
+
+        ref = np.concatenate([np.asarray(ref_row(
+            q[r:r + 1], k32, v32, table[r:r + 1], mask[r:r + 1]))
+            for r in range(R)])
         live = np.arange(C)[None, :] < np.asarray(q_len)[:, None]
         for abl in args.ablate.split(";"):
             if args.ablate:
@@ -302,7 +334,7 @@ def main() -> None:
                     clear()
                 jax.clear_caches()  # ``one`` is traced anew
             out = np.asarray(f32(jax.jit(one)(*ops)))
-            err = float(np.abs(out - np.asarray(ref))[live].max()) if live.any() else 0.0
+            err = float(np.abs(out - ref)[live].max()) if live.any() else 0.0
             fn = jax.jit(loop)
             fn(*ops).block_until_ready()
             times = []
@@ -315,6 +347,7 @@ def main() -> None:
                 side=args.label + (f"[{abl}]" if abl else ""),
                 ms=round(float(np.median(times)), 5),
                 ms_min=round(min(times), 5), err_vs_f32_ref=err,
+                steps=R * case["NP"] if work is None else int(work[0]),
                 digest=float(np.abs(out[live]).sum()) if live.any() else 0.0)
             print(json.dumps(line), flush=True)
             with open(args.out, "a") as f:

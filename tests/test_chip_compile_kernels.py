@@ -36,28 +36,42 @@ def test_ragged_paged_attention_bf16_compiles(chip, C):
 def test_ragged_paged_attention_with_query_lengths_compiles(
     chip, C, monkeypatch
 ):
-    """The kernel told each row's real queries (``q_len``: a third
-    prefetched scalar, the padding-blind guard, at C=128 the narrow
-    body beside the chunk-wide one) at Mistral-7B widths, under the
-    ``vmem_limit_bytes`` the kernel states without it: the limit may
-    not rise."""
+    """The kernel as a step calls it, told each row's real queries
+    (``q_len``: the padding-blind guard, at C=128 the narrow body beside
+    the chunk-wide one) and the table entries they may see (``work``,
+    made from the positions as the step makes it), at Mistral-7B
+    widths, under the ``vmem_limit_bytes`` the kernel states without
+    them: the limit may not rise. ONE ``tpu_custom_call`` either way,
+    whose grid is one axis as long as the work list
+    (``kernels.ragged_work``: a value of the step, not of its
+    shapes)."""
     cfg = mistral.mistral_7b(dtype=jnp.bfloat16)
-    limits = []
-    stated = kernels._ragged_vmem_limit
+    limits, grids = [], []
+    stated, spec = kernels._ragged_vmem_limit, kernels.pltpu.PrefetchScalarGridSpec
     monkeypatch.setattr(
         kernels, "_ragged_vmem_limit",
         lambda *a: limits.append(stated(*a)) or limits[-1],
     )
-    args = _attention_args(chip, C, cfg) + (chip((R,), jnp.int32),)
+    monkeypatch.setattr(
+        kernels.pltpu, "PrefetchScalarGridSpec",
+        lambda **kw: grids.append(kw["grid"]) or spec(**kw))
+    kernels._ragged_call.cache_clear()  # the grid is read as the call traces
+    args = _attention_args(chip, C, cfg) + (chip((R, C), jnp.int32),)
 
-    def fn(q, kp, vp, pt, mask, q_len, use):
-        return kernels.ragged_paged_attention(
-            q, kp, vp, pt, mask, q_len=q_len if use else None)
+    def fn(q, kp, vp, pt, mask, positions, use):
+        told = {}
+        if use:
+            q_len = kernels.real_query_lengths(positions, CACHE_LEN)
+            told = dict(q_len=q_len, work=kernels.step_work(
+                positions, q_len, PAGE, PAGES_PER_SLOT))
+        return kernels.ragged_paged_attention(q, kp, vp, pt, mask, **told)
 
-    _compile(functools.partial(fn, use=False), *args)
-    _, text = _compile(functools.partial(fn, use=True), *args)
-    assert text.count("tpu_custom_call") == 1
-    assert f"%ff_ragged_paged_c{C}" in text
+    for use in (False, True):
+        _, text = _compile(functools.partial(fn, use=use), *args)
+        assert text.count("tpu_custom_call") == 1
+        assert f"%ff_ragged_paged_c{C}" in text
+        (steps,) = grids[-1]
+        assert not isinstance(steps, int) and steps.shape == ()
     assert limits[1] <= limits[0]
 
 

@@ -83,9 +83,11 @@ def _mixed_rows(C):
 
 @functools.lru_cache(maxsize=None)
 def _q_len_case(variant, C):
-    """One batch through the kernel with and without ``q_len`` and
-    through the XLA reference (in float32, on the values the pools
-    hold): (positions, q_len, mask, outputs)."""
+    """One batch through the kernel with and without ``q_len``, with
+    the step's work list (``work``: every other call runs every entry
+    of the table, the grid before PR 63) and through the XLA reference
+    (in float32, on the values the pools hold): (positions, q_len,
+    mask, outputs)."""
     from flexflow_tpu.serve import kernels as K
 
     v = dict(dict(H=H, KV=KV, dk=dk, dtype=jnp.float32, quant=0, merged=False,
@@ -141,6 +143,8 @@ def _q_len_case(variant, C):
     outs = dict(
         none=call(), q_len=call(q_len=q_len),
         full=call(q_len=jnp.full((R,), C, jnp.int32)), ref=ref,
+        work=call(q_len=q_len, work=K.step_work(pos, q_len, _PS, _NP,
+                                                v["window"])),
     )
     return (np.asarray(pos), np.asarray(q_len), np.asarray(mask),
             {k: np.asarray(f32(x)) for k, x in outs.items()})
@@ -199,8 +203,10 @@ def _jitted_kernel_calls(jaxpr):
 
 
 def test_q_len_none_traces_the_old_operands():
-    """A caller that passes no ``q_len`` gets the old ``pallas_call``:
-    one prefetched scalar (two with a row offset), no per-row branch."""
+    """A caller that passes no ``q_len`` gets the ``pallas_call``
+    without it: the work list's two prefetched scalars and the table
+    (one more with a row offset), no per-row branch; and ONE grid axis
+    whose length is a value, whatever is passed."""
     from flexflow_tpu.serve import kernels as K
 
     def prefetched(**kw):
@@ -211,11 +217,15 @@ def test_q_len_none_traces_the_old_operands():
         jitted, = _jitted_kernel_calls(jaxpr)  # the call is jitted a shape
         call, = [e for e in jitted.params["jaxpr"].eqns
                  if e.primitive.name == "pallas_call"]
+        grid, = call.params["grid_mapping"].grid
+        assert not isinstance(grid, int)
         return call.params["grid_mapping"].num_index_operands
 
-    assert prefetched() == 1
-    assert prefetched(row_offset=3) == 2
-    assert prefetched(row_offset=3, q_len=jnp.zeros((2,), jnp.int32)) == 3
+    assert prefetched() == 3
+    assert prefetched(row_offset=3) == 4
+    assert prefetched(row_offset=3, q_len=jnp.zeros((2,), jnp.int32)) == 5
+    work = K.ragged_work(jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32), _NP)
+    assert prefetched(row_offset=3, work=work) == 4
 
 
 def test_ragged_calls_are_traced_once_a_shape(monkeypatch):
@@ -289,7 +299,8 @@ def test_attn_step_counters_equal_the_masks_count(variant, C, window):
     stats = SchedulerStats()
     for _ in range(2):  # counters add up over steps
         stats.note_attn_steps(pos[:, 0], q_len, _PS, _NP, narrow, window)
-    assert stats.attn_steps_grid == 2 * len(q_len) * _NP
+    # what the call runs: the live entries, one step a row that has none
+    assert stats.attn_steps_grid == 2 * np.maximum(live, 1).sum()
     assert stats.attn_steps_live == 2 * live.sum()
     assert stats.attn_steps_narrow == 2 * live[q_len <= narrow].sum()
     assert 0 < stats.attn_steps_live < stats.attn_steps_grid
@@ -314,8 +325,117 @@ def test_paged_pallas_step_counts_its_attention_grid():
         stats[kern] = m.rm.stats
     assert outs["xla"] == outs["pallas"], outs
     s = stats["pallas"]
-    assert s.attn_steps_grid == s.steps * 4 * m.engine.serving.pages_per_slot
+    # three prompts in four slots: every step runs its live entries and
+    # a step for each idle row, far fewer than the tables hold
+    assert s.attn_steps_live + s.steps <= s.attn_steps_grid
+    assert s.attn_steps_grid < s.steps * 4 * m.engine.serving.pages_per_slot
     assert 0 < s.attn_steps_narrow <= s.attn_steps_live < s.attn_steps_grid
+
+
+# --- the call's grid as a work list made on the device (PR 63) ---------------
+
+
+def _work_loop(first, count, num_pages):
+    """``kernels.ragged_work`` as a loop: (steps, rows, entries)."""
+    rows, entries = [], []
+    for r, (f, n) in enumerate(zip(first, count)):
+        for j in range(max(int(n), 1)):
+            rows.append(r)
+            entries.append(int(f) + j)
+    tail = len(first) * num_pages + 1 - len(rows)
+    return len(rows), rows + [len(first)] * tail, entries + [0] * tail
+
+
+def _check_work_list(what):
+    """``ragged_work`` against the loop; the ranges of ``live_pages``
+    against the entries a query of the row sees through the mask."""
+    from flexflow_tpu.serve import kernels as K
+
+    if what == "rolling-window":
+        # a window class's table: entry 0 is the page of ``start``, not
+        # of line 0 (serve/paging.py); ps 8, window 12, steps of 16
+        # lines: ``window_table_pages`` gives 5 entries a row
+        ps, NP, window = 8, 5, 12
+        first = np.array([100, 57, 40, 0, 3], np.int32)
+        last = np.array([100, 72, 39, -1, 9], np.int32)     # rows 2, 3: none
+        start = np.array([80, 40, 16, 0, 0], np.int32)
+        lo, n = K.live_pages(first, last, ps, NP, window, start)
+        for r in range(len(first)):
+            seen = {(k - start[r]) // ps for q in range(first[r], last[r] + 1)
+                    for k in range(max(q - window + 1, 0), q + 1)}
+            assert sorted(seen) == list(range(lo[r], lo[r] + n[r])), r
+        assert min(lo) >= 0 and max(lo + n) <= NP and lo[0] > 0
+    else:
+        NP = 4
+        lo, n = {"idle-rows": ([0, 0, 2, 3], [3, 0, 1, 0]),
+                 "whole-table": ([0, 0, 0], [NP, NP, NP])}[what]
+    steps, rows, entries = K.ragged_work(jnp.asarray(lo, jnp.int32),
+                                         jnp.asarray(n, jnp.int32), NP)
+    want = _work_loop(lo, n, NP)
+    assert (int(steps), rows.tolist(), entries.tolist()) == want
+    assert rows.dtype == entries.dtype == jnp.int32 and steps.dtype == jnp.int32
+
+
+def _check_work_steps(what):
+    """The device's ``steps`` of a step's list is the host's
+    ``attn_steps_grid`` for the same positions and real queries, and
+    the list's live places its ``attn_steps_live``."""
+    from flexflow_tpu.metrics import SchedulerStats
+    from flexflow_tpu.serve import kernels as K
+
+    C, window = what
+    start, NP = None, _NP
+    rows = _mixed_rows(C)
+    if window == "rolling":  # long contexts over a rolling table
+        window = 12
+        NP = -(-(window + C) // _PS) + 1    # paging.window_table_pages
+        rows = [(f + 40 * r, n) for r, (f, n) in enumerate(rows)]
+        start = np.array([max(f + n - 1 - window + 1 - C, 0) // _PS * _PS
+                          for f, n in rows], np.int32)
+    pos = np.full((len(rows), C), 1 << 20, np.int32)
+    for r, (first, n) in enumerate(rows):
+        pos[r, :n] = np.arange(first, first + n)
+    q_len = np.array([n for _, n in rows], np.int32)
+    stats = SchedulerStats()
+    stats.note_attn_steps(pos[:, 0], q_len, _PS, NP, K.narrow_query_extent(C),
+                          window)
+    steps, row, _ = K.step_work(
+        jnp.asarray(pos), jnp.asarray(q_len), _PS, NP, window,
+        None if start is None else jnp.asarray(start))
+    run = np.asarray(row)[:int(steps)]
+    assert len(run) == stats.attn_steps_grid
+    assert len(run) - int((q_len == 0).sum()) == stats.attn_steps_live
+    assert (np.diff(run) >= 0).all() and set(run) == set(range(len(rows)))
+    assert 0 < stats.attn_steps_live < stats.attn_steps_grid < len(rows) * NP
+
+
+def _check_work_kernel(what):
+    """The kernel over the step's work list (the entries a row's real
+    queries may see) is the kernel over every entry of the table, the
+    grid before PR 63, TO THE BIT, padding columns and idle rows too,
+    and the XLA reference's within this file's tolerance."""
+    variant, C = what
+    _, q_len, _, outs = _q_len_case(variant, C)
+    np.testing.assert_array_equal(outs["work"], outs["q_len"])
+    for r, n in enumerate(q_len):
+        np.testing.assert_allclose(outs["work"][r, :n], outs["ref"][r, :n],
+                                   atol=_atol(variant))
+
+
+_WORK_CHECKS = {"list": _check_work_list, "steps": _check_work_steps,
+                "kernel": _check_work_kernel}
+_WORK_CASES = (
+    [("list", w) for w in ("idle-rows", "whole-table", "rolling-window")]
+    + [("steps", (C, w)) for C in (1, 16) for w in (0, 12, "rolling")]
+    + [("kernel", case) for case in _Q_LEN_CASES])
+
+
+@pytest.mark.parametrize(
+    "kind, what", _WORK_CASES,
+    ids=[f"{k}-{w if isinstance(w, str) else '-'.join(map(str, w))}"
+         for k, w in _WORK_CASES])
+def test_the_work_list(kind, what):
+    _WORK_CHECKS[kind](what)
 
 
 # --- the Mamba-2 recurrence of the decode step (ff_ssm_recur_c1) -------------
